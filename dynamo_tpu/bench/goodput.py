@@ -13,7 +13,9 @@ docs/benchmarks/benchmarking.md:449), not raw decode throughput.
 Modes:
 - aggregated (default): N workers, each prefill+decode
 - --disagg: decode worker(s) plus a prefill worker pool (the reference's
-  P/D split; on one chip both engines share the accelerator)
+  P/D split)
+Real engines are one-device replicas: worker i runs on device i, and more
+workers than devices is an error.
 - --mocker: SimRunner workers — measures the serving plane itself
   (frontend+router+transport ceiling, SURVEY §2.9 hardening item)
 """
@@ -94,7 +96,10 @@ class Stack:
                 _os.environ["DYN_NATS_URL"] = self.nats_env_prev
 
 
-def _make_engine(args, mocker: bool):
+def _make_engine(args, mocker: bool, replica: int = 0):
+    """One worker engine. Real runners are single-device replicas: replica
+    i takes device i, so `--workers 4` on a four-chip host is four chips
+    and not four runners stacked on chip 0."""
     from dynamo_tpu.engine.engine import InferenceEngine
 
     if mocker:
@@ -111,11 +116,20 @@ def _make_engine(args, mocker: bool):
             spec_accept_rate=getattr(args, "spec_accept_rate", None),
         )
     else:
+        import jax
+
         from dynamo_tpu.engine.model_runner import ModelRunner
         from dynamo_tpu.models.config import get_config
 
+        devices = jax.devices()
+        if replica >= len(devices):
+            raise ValueError(
+                f"replica {replica} needs its own device; JAX reports "
+                f"{len(devices)}"
+            )
         runner = ModelRunner(
             get_config(args.model),
+            devices=devices[replica : replica + 1],
             num_pages=args.num_pages,
             page_size=args.page_size,
             max_pages_per_seq=args.max_pages_per_seq,
@@ -203,7 +217,7 @@ async def _boot_rest(args, mocker, disagg, plane, realm, card,
             discovery=MemDiscovery(realm=realm), event_transport="inproc",
             request_plane=plane,
         )
-        engine = _make_engine(args, mocker)
+        engine = _make_engine(args, mocker, replica=len(workers))
         w = await serve_worker(
             rt, engine, card, component=component, disagg_role=role,
             digest_period_s=getattr(args, "digest_period", 0.0),
@@ -329,8 +343,13 @@ async def run_goodput(args) -> GoodputReport:
         compile_stats = {}
         sim_stats = {}
         spec_stats = {}
+        worker_devices = []  # per real worker: the devices its mesh holds
         for w in stack.workers:
             runner = getattr(w.engine, "runner", None)
+            if hasattr(runner, "mesh"):
+                worker_devices.append(
+                    [f"{d.platform}:{d.id}" for d in runner.mesh.devices.flat]
+                )
             if hasattr(runner, "compile_stats"):
                 for fam, st in runner.compile_stats().items():
                     agg = compile_stats.setdefault(
@@ -370,6 +389,8 @@ async def run_goodput(args) -> GoodputReport:
         }
     if sim_stats:
         report.extras["sim"] = sim_stats
+    if worker_devices:
+        report.extras["worker_devices"] = worker_devices
     if spec_stats.get("verify_iters"):
         report.extras["spec"] = {
             **spec_stats,
@@ -583,6 +604,10 @@ def parse_args(argv=None):
 
 def main(argv=None) -> GoodputReport:
     args = parse_args(argv)
+    if not args.mocker:
+        import dynamo_tpu
+
+        dynamo_tpu.enable_compilation_cache()
     report = asyncio.run(run_goodput(args))
     print(report.to_json())
     return report

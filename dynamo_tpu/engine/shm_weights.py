@@ -31,9 +31,9 @@ stores a model-config fingerprint and refuses a stage whose fingerprint
 disagrees — sharing a stage name across different models is recovered,
 not crashed on).
 
-Pairs with the persistent XLA compilation cache (worker
---compilation-cache): together a warm restart skips both recompiles and
-weight I/O. Linux-only by construction (tmpfs rename); on hosts without
+Pairs with the persistent XLA compilation cache
+(dynamo_tpu.enable_compilation_cache): together a warm restart skips both
+recompiles and weight I/O. Linux-only by construction (tmpfs rename); on hosts without
 /dev/shm the tier reports unavailable and workers load cold.
 """
 

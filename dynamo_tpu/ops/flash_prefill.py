@@ -13,9 +13,13 @@ the compute for pages that are entirely masked:
   block index repeats and Pallas elides the copy (same trick as the decode
   kernel). A causal chunk therefore costs ~half the rectangular DMA.
 
-Layout: q arrives [B, Hk, S, G, D] (wrapper transposes from the model's
-[B, S, Hk, G, D]) so a block is [Hk, Sq, G, D] and the matmul runs as one
-Hk-batched [Sq*G, D] x [D, PS] — MXU-shaped at Sq=128.
+Layout: q arrives [B, Hk, S*G, D] (wrapper transposes from the model's
+[B, S, Hk, G, D] and merges the group axis into the rows) so a block is
+[Hk, Sq*G, D] and the matmul runs as one Hk-batched [Sq*G, D] x [D, PS] —
+MXU-shaped at Sq=128. The merge happens in XLA, not in the kernel: a
+[.., G, D] block pads G up to a full sublane tile in VMEM (G=1 in bf16 is
+16x, past the scoped VMEM limit at Hk=32), and Mosaic cannot shape-cast
+every (Sq, G) split.
 
 Positions contract (same as models/llama.py paged_attention_jnp): flat
 context index c IS absolute position c; query token s of sequence b sits at
@@ -39,6 +43,12 @@ from dynamo_tpu.parallel.mesh import AXIS_MODEL, prefill_attention_specs
 
 NEG_INF = -1e30
 
+# All Hk heads of a q block are resident at once: at Hk=32, Sq=128 the
+# blocks, the f32 accumulators and the score temporaries come to ~19 MiB,
+# past Mosaic's 16 MiB default scoped limit (a compiler default — a v5e
+# core has 128 MiB of VMEM).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
 
 def _prefill_kernel_body(
     # scalar prefetch
@@ -49,12 +59,12 @@ def _prefill_kernel_body(
     win_ref,  # [1] int32 sliding window (0 = global) or None (no-window
     #   compile) — Gemma-2 alternates per layer with a traced scalar
     # blocks
-    q_ref,  # [Hk, Sq, G, D]
+    q_ref,  # [Hk, Sq*G, D] (row r is query token r // G, group r % G)
     k_ref,  # [PS, Hk, D] one token-major page (one contiguous DMA)
     v_ref,  # [PS, Hk, D]
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
-    o_ref,  # [Hk, Sq, G, D]
+    o_ref,  # [Hk, Sq*G, D]
     # scratch (persist across the page loop)
     m_ref,  # [Hk, Sq*G, 1] f32
     l_ref,  # [Hk, Sq*G, 1] f32
@@ -97,8 +107,7 @@ def _prefill_kernel_body(
 
     @pl.when(needed)
     def _compute():
-        Hk, Sq, G, D = q_ref.shape
-        q = q_ref[...].astype(jnp.float32).reshape(Hk, Sq * G, D)
+        q = q_ref[...].astype(jnp.float32)  # [Hk, Sq*G, D]
         k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
         s = lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
@@ -139,9 +148,8 @@ def _prefill_kernel_body(
 
     @pl.when(i == n_pages - 1)
     def _finalize():
-        Hk, Sq, G, D = o_ref.shape
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype).reshape(Hk, Sq, G, D)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def _prefill_kernel(pt, qs, ql, kl, q, k, v, o, m, l, acc, **kw):
@@ -243,7 +251,7 @@ def prefill_paged_attention(
     windowed = window is not None
     n_prefetch = 5 if windowed else 4
 
-    qt = q.transpose(0, 2, 1, 3, 4)  # [B, Hk, S, G, D]
+    qt = q.transpose(0, 2, 1, 3, 4).reshape(B, Hk, S * G, D)
 
     def _clamp(b, sb, i, pt, qs, ql, kl, *rest):
         # clamp to the page range this q-block can actually see (causal
@@ -270,9 +278,9 @@ def prefill_paged_attention(
         return kv_index(b, sb, i, pt, qs, ql, kl, *rest)[:3]
 
     def q_index(b, sb, i, pt, qs, ql, kl, *rest):
-        return (b, 0, sb, 0, 0)
+        return (b, 0, sb, 0)
 
-    q_spec = pl.BlockSpec((None, Hk, q_block, G, D), q_index)
+    q_spec = pl.BlockSpec((None, Hk, q_block * G, D), q_index)
     # one token-major page = one contiguous PS*Hk*D slab (single DMA)
     kv_spec = pl.BlockSpec((None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, q_block=q_block, n_groups=G, scale=scale,
@@ -297,7 +305,7 @@ def prefill_paged_attention(
         num_scalar_prefetch=n_prefetch,  # pt, q_start, q_len, kv (+ window)
         grid=(B, n_sblk, MP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, Hk, q_block, G, D), q_index),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
             pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
@@ -313,7 +321,9 @@ def prefill_paged_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, S, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, S * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*prefetch, *operands)
-    return out.transpose(0, 2, 1, 3, 4)  # [B, S, Hk, G, D]
+    # [B, Hk, S*G, D] -> [B, S, Hk, G, D]
+    return out.reshape(B, Hk, S, G, D).transpose(0, 2, 1, 3, 4)

@@ -39,7 +39,7 @@ def _mla_kernel_body(
     kv_lens_ref,  # [B] int32 (SMEM)
     q_ref,  # [H, Dl] absorbed+rope query for seq b
     lat_ref,  # [PS, Dl] one latent page (single contiguous DMA)
-    ls_ref,  # [PS] f32 per-token latent scales (int8 pool) or None
+    ls_ref,  # [1, PS] f32 per-token latent scales (int8 pool) or None
     o_ref,  # [H, dc]
     m_ref,  # [H, 1] f32 running max
     l_ref,  # [H, 1] f32 running denom
@@ -73,7 +73,7 @@ def _mla_kernel_body(
         if ls_ref is not None:
             # int8 latent: fold the per-token scale into the scores —
             # one [1, PS] multiply instead of dequantizing over Dl
-            s = s * ls_ref[...][None, :]
+            s = s * ls_ref[...]
         valid = lax.broadcasted_iota(jnp.int32, s.shape, 1) < n_valid
         s = jnp.where(valid, s, NEG_INF)
 
@@ -85,7 +85,7 @@ def _mla_kernel_body(
         if ls_ref is not None:
             # same scale dequantizes the VALUE side (values are the
             # latent's first d_c columns of the same vector)
-            p = p * ls_ref[...][None, :]
+            p = p * ls_ref[...]
         pv = lax.dot_general(
             p, lat[:, :dc], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -135,7 +135,7 @@ def decode_mla_attention(
         return (pt[b, jnp.minimum(i, last)], 0, 0)
 
     def scale_index(b, i, pt, kl):
-        return lat_index(b, i, pt, kl)[:2]
+        return lat_index(b, i, pt, kl)
 
     in_specs = [
         pl.BlockSpec((None, H, Dl), lambda b, i, pt, kl: (b, 0, 0)),
@@ -144,8 +144,10 @@ def decode_mla_attention(
     operands = (q, lat)
     kernel = _mla_kernel
     if quantized:
-        in_specs.append(pl.BlockSpec((None, PS), scale_index))
-        operands = operands + (lat_pool_l["s"].reshape(NP, PS),)
+        # [NP, 1, PS]: a rank-1 (PS,) block is not a legal TPU tile; a
+        # (1, PS) block whose dims equal the array's is
+        in_specs.append(pl.BlockSpec((None, 1, PS), scale_index))
+        operands = operands + (lat_pool_l["s"].reshape(NP, 1, PS),)
         kernel = _mla_kernel_int8
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -189,12 +189,12 @@ def _ragged_kernel_body(
     kvl_ref,  # [SEG] int32 per-segment context length
     win_ref,  # [1] int32 sliding window (0 = global) or None
     # blocks
-    q_ref,  # [Hk, QB, G, D]
+    q_ref,  # [Hk, QB*G, D] (row r is block token r // G, group r % G)
     k_ref,  # [PS, Hk, D] one token-major page
     v_ref,  # [PS, Hk, D]
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
-    o_ref,  # [Hk, QB, G, D]
+    o_ref,  # [Hk, QB*G, D]
     # scratch (persist across the page loop)
     m_ref,  # [Hk, QB*G, 1] f32
     l_ref,  # [Hk, QB*G, 1] f32
@@ -231,8 +231,7 @@ def _ragged_kernel_body(
 
     @pl.when(needed)
     def _compute():
-        Hk, QB, G, D = q_ref.shape
-        q = q_ref[...].astype(jnp.float32).reshape(Hk, QB * G, D)
+        q = q_ref[...].astype(jnp.float32)  # [Hk, QB*G, D]
         k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
         s = lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
@@ -279,15 +278,12 @@ def _ragged_kernel_body(
         # read-modify-write ONLY this unit's row band: units sharing the
         # block run back to back on the same resident out buffer, each
         # masking in its own rows (increasing-row emission order)
-        Hk, QB, G, D = o_ref.shape
         denom = jnp.maximum(l_ref[...], 1e-30)
         res = acc_ref[...] / denom  # [Hk, QB*G, D]
         row = lax.broadcasted_iota(jnp.int32, res.shape, 1) // n_groups
         keep = (row >= row_start) & (row < row_start + n_rows)
-        prev = o_ref[...].astype(jnp.float32).reshape(Hk, QB * G, D)
-        o_ref[...] = (
-            jnp.where(keep, res, prev).astype(o_ref.dtype).reshape(Hk, QB, G, D)
-        )
+        prev = o_ref[...].astype(jnp.float32)
+        o_ref[...] = jnp.where(keep, res, prev).astype(o_ref.dtype)
 
 
 def _ragged_kernel(meta, pt, kl, q, k, v, o, m, l, acc, **kw):
@@ -417,7 +413,10 @@ def ragged_paged_attention(
     windowed = window is not None
     n_prefetch = 4 if windowed else 3
 
-    qt = q.transpose(1, 0, 2, 3)  # [Hk, T, G, D]
+    # group axis merged into the rows HERE, in XLA: a [.., G, D] block
+    # pads G up to a full sublane tile in VMEM and Mosaic cannot
+    # shape-cast every (QB, G) split (G == 1 fails to lower)
+    qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
 
     def _clamp(w, i, mt, pt, kl, *rest):
         # clamp dead pages (causal top, kv_len, window low bound) to a
@@ -445,9 +444,9 @@ def ragged_paged_attention(
         return kv_index(w, i, mt, pt, kl, *rest)[:3]
 
     def q_index(w, i, mt, pt, kl, *rest):
-        return (0, mt[1, w], 0, 0)
+        return (0, mt[1, w], 0)
 
-    q_spec = pl.BlockSpec((Hk, q_block, G, D), q_index)
+    q_spec = pl.BlockSpec((Hk, q_block * G, D), q_index)
     # one token-major page = one contiguous PS*Hk*D slab (single DMA)
     kv_spec = pl.BlockSpec((None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, n_groups=G, scale=scale, softcap=softcap)
@@ -470,7 +469,7 @@ def ragged_paged_attention(
         num_scalar_prefetch=n_prefetch,  # meta, seg_pt, seg_kvl (+ window)
         grid=(NW, MP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((Hk, q_block, G, D), q_index),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
             pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
@@ -486,7 +485,8 @@ def ragged_paged_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hk, T, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hk, T * G, D), q.dtype),
         interpret=interpret,
     )(*prefetch, *operands)
-    return out.transpose(1, 0, 2, 3)  # [T, Hk, G, D]
+    # [Hk, T*G, D] -> [T, Hk, G, D]
+    return out.reshape(Hk, T, G, D).transpose(1, 0, 2, 3)

@@ -77,9 +77,6 @@ def initialize(spec: MultihostSpec) -> None:
             ).strip()
     import jax
 
-    import dynamo_tpu
-
-    dynamo_tpu.ensure_platform()
     jax.distributed.initialize(
         coordinator_address=spec.coordinator,
         num_processes=spec.num_processes,

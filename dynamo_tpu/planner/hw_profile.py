@@ -237,7 +237,7 @@ def main(argv=None) -> None:
 
     import dynamo_tpu
 
-    dynamo_tpu.ensure_platform()
+    dynamo_tpu.enable_compilation_cache()
     profile = run_hw_sweep(
         args.model,
         checkpoint=args.checkpoint,

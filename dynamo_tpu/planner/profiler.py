@@ -43,8 +43,11 @@ from dynamo_tpu.worker_common import serve_worker
 
 @dataclass
 class TpuPerfModel:
-    """Single-chip step-time baseline + parallelism scaling. Baselines are
-    the flagship's measured v5e numbers (bench.py); override per model."""
+    """Single-chip step-time baseline + parallelism scaling. The defaults
+    are HAND-SET constants (the same ones as mocker/sim.py SimTiming), not
+    measurements: nothing here was ever fitted to a chip (ROADMAP S9).
+    Override per model, or fit from a hardware profile
+    (planner/hw_profile.py) before trusting a recommendation."""
 
     decode_base_s: float = 0.004
     decode_per_seq_s: float = 0.0003

@@ -51,7 +51,7 @@ async def async_main(args) -> None:
 def main(argv=None) -> None:
     import dynamo_tpu
 
-    dynamo_tpu.ensure_platform()  # honor JAX_PLATFORMS before any jit
+    dynamo_tpu.enable_compilation_cache()  # before any jit
     try:
         asyncio.run(async_main(parse_args(argv)))
     except KeyboardInterrupt:

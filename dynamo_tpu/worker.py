@@ -11,7 +11,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
-from typing import Optional
 
 from dynamo_tpu.engine.engine import InferenceEngine
 from dynamo_tpu.engine.model_runner import ModelRunner
@@ -43,13 +42,6 @@ def parse_args(argv=None):
                    help="params snapshot dir: load if present, else save "
                         "after build (fast worker restarts — the snapshot-"
                         "restore role of the reference's fast-restart path)")
-    p.add_argument("--compilation-cache", default=None,
-                   help="persistent XLA compilation cache dir (also env "
-                        "JAX_COMPILATION_CACHE_DIR): a restarted worker "
-                        "reuses compiled step programs instead of paying "
-                        "the 20-40s TPU compile again — the TPU analog of "
-                        "the reference's CRIU/GMS fast-restart stack "
-                        "(SURVEY.md §5.4)")
     p.add_argument("--namespace", default="dyn")
     p.add_argument("--component", default="tpu-worker")
     p.add_argument("--endpoint", default="generate")
@@ -272,14 +264,15 @@ def _lora_kwargs(args, config) -> dict:
     }
 
 
-def enable_compilation_cache(path: Optional[str]) -> Optional[str]:
-    """Worker-facing wrapper over dynamo_tpu.enable_compilation_cache
-    (kept importable from here for the CLI's callers/tests)."""
-    import dynamo_tpu
+def _jax_versions() -> dict:
+    from importlib import metadata
 
-    out = dynamo_tpu.enable_compilation_cache(path)
-    if out:
-        log.info("persistent compilation cache at %s", out)
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[pkg] = metadata.version(pkg)
+        except metadata.PackageNotFoundError:
+            out[pkg] = None
     return out
 
 
@@ -640,6 +633,17 @@ async def async_main(args) -> None:
             status.add_timeline(
                 lambda last_n=None: to_chrome_trace(_rec.snapshot(last_n))
             )
+        _runner = getattr(engine, "runner", None)
+        if hasattr(_runner, "device_report"):
+            # GET /debug/device: the platform, devices and dispatch paths
+            # actually in effect (chip_smoke.py asserts on it — a worker
+            # that came up on the CPU must not pass for one on the chip)
+            status.add_debug("device", lambda _q: {
+                **_runner.device_report(),
+                "fused_mixed": bool(engine.fused_mixed),
+                "jax": _jax_versions(),
+                "compile": _runner.compile_stats(),
+            })
         _san = getattr(engine, "sanitizer", None)
         if _san is not None:
             # GET /debug/sanitizer: violations + counters (layout_checked
@@ -760,11 +764,12 @@ async def async_main(args) -> None:
 def main(argv=None) -> None:
     import dynamo_tpu
 
-    dynamo_tpu.ensure_platform()
     args = parse_args(argv)
     # before ANY jit: every process (leader, followers, single) must see
     # the cache so a restarted replica skips recompilation
-    enable_compilation_cache(args.compilation_cache)
+    cache = dynamo_tpu.enable_compilation_cache()
+    configure_logging()
+    log.info("persistent compilation cache at %s", cache)
     if args.mh_coordinator and args.mh_num_processes > 1:
         from dynamo_tpu.parallel import multihost as mh
 

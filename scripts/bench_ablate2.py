@@ -16,6 +16,9 @@ from dynamo_tpu.models import llama
 from bench_ablate import make_runner, time_decode  # noqa: E402
 from dynamo_tpu.models.config import get_config
 
+import dynamo_tpu  # noqa: E402
+
+dynamo_tpu.enable_compilation_cache()
 cfg = get_config("llama-3.2-3b")
 
 base = time_decode(make_runner(cfg), cfg)

@@ -119,6 +119,9 @@ async def test_goodput_real_engine_disagg():
     ))
     assert rep.n_ok == 12
     assert rep.goodput_tok_s > 0
+    # one replica per device: the decode and the prefill engine must not
+    # both land on device 0 (tests run on 8 virtual CPU devices)
+    assert sorted(rep.extras["worker_devices"]) == [["cpu:0"], ["cpu:1"]]
 
 
 async def test_goodput_mocker_plane_ceiling():

@@ -15,13 +15,14 @@ from tests.test_weights import _write_hf_checkpoint
 
 _SCRIPT = r"""
 import json, os, sys, time
-import jax
-jax.config.update("jax_platforms", "cpu")
 t0 = time.time()
-from dynamo_tpu.worker import build_runner, enable_compilation_cache, parse_args
+import dynamo_tpu
+from dynamo_tpu.worker import build_runner, parse_args
 
-cache_dir, snap_dir, ckpt_dir = sys.argv[1:4]
-enable_compilation_cache(cache_dir)
+snap_dir, ckpt_dir = sys.argv[1:3]
+# the cache is placed from outside (JAX_COMPILATION_CACHE_DIR), as for a
+# deployed worker
+assert dynamo_tpu.enable_compilation_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 warm = os.path.isdir(snap_dir) and bool(os.listdir(snap_dir))
 args = parse_args([
     "--checkpoint", ckpt_dir, "--orbax-cache", snap_dir,
@@ -45,9 +46,12 @@ print(json.dumps({
 
 def _run(cache_dir, snap_dir, ckpt_dir):
     out = subprocess.run(
-        [sys.executable, "-c", _SCRIPT, cache_dir, snap_dir, ckpt_dir],
+        [sys.executable, "-c", _SCRIPT, snap_dir, ckpt_dir],
         capture_output=True, text=True, timeout=600,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="1",  # conftest turns it off
+                 JAX_COMPILATION_CACHE_DIR=cache_dir),
     )
     assert out.returncode == 0, out.stderr[-3000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -72,3 +76,28 @@ def test_restart_warm_start_skips_parse_and_recompile(tmp_path):
     assert set(os.listdir(cache)) == entries, (
         "warm start must not recompile any program"
     )
+
+
+def test_compilation_cache_rule(monkeypatch, tmp_path):
+    """One rule for every JAX entry point: JAX_COMPILATION_CACHE_DIR set →
+    no directory is set in code (JAX reads the variable itself); unset →
+    one fixed path inside the checkout, never a temp name."""
+    import jax
+
+    import dynamo_tpu
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert dynamo_tpu.enable_compilation_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in updates
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+    updates.clear()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    assert dynamo_tpu.enable_compilation_cache() == fixed
+    assert updates["jax_compilation_cache_dir"] == fixed
