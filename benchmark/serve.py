@@ -1,0 +1,547 @@
+"""The one process that holds the cell's chips: N one-chip replicas of the
+configuration, each a ModelRunner + InferenceEngine registered with
+serve_worker over file discovery and the TCP request plane, exactly as
+`python -m dynamo_tpu.worker` builds them (its own argument parser and
+build_engine are used, so every flag not in the configuration file is the
+worker's default). run.py starts it; it never prints the result line.
+
+Files in --run-dir, the only channel to run.py:
+  ready.json     written when weights, replicas, warm-up and the reference
+                 check are done (device, correctness, compile counts)
+  window.json    written by run.py: wall-clock start and end of the window
+  counters.json  written after the window: counters at its start and end,
+                 the iterations and finished requests inside it
+  stop           written by run.py: shut down
+  final.json     written last: memory peak, trace reduction (traced runs)
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import importlib.util
+import json
+import os
+import sys
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+T0 = time.monotonic()
+
+
+def log(msg: str) -> None:
+    print(f"[serve +{time.monotonic() - T0:6.1f}s] {msg}", flush=True)
+
+
+def write_json(path: str, obj) -> None:
+    tmp = path + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump(obj, f)
+    os.replace(tmp, path)
+
+
+def load_module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# -- weights ---------------------------------------------------------------
+
+
+def make_params(config, seed: int, device, dtype):
+    """The whole parameter tree on `device` from the seed in ONE jitted
+    call, in the type it is served in: no host arrays, no eager per-leaf
+    programs, and f32 temporaries of one layer's leaf at most (the program's
+    own init_params peaks at 13.48 GB for a 6.4 GB model, PERF.md PR 21).
+    Same recipe as models/llama.py: normal x fan_in^-0.5, norms 1.0."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.models import llama
+
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.PRNGKey(0), dtype))
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+
+    def build(key):
+        out = []
+        for i, (path, sd) in enumerate(leaves):
+            k = jax.random.fold_in(key, i)
+            name = getattr(path[-1], "key", str(path[-1]))
+            if sd.dtype == jnp.float32:  # norm weights
+                out.append(jnp.ones(sd.shape, sd.dtype))
+                continue
+            fan_in = sd.shape[-1] if name == "embed" else sd.shape[-2]
+
+            def one(kk, shape=sd.shape[-2:], fan_in=fan_in, dt=sd.dtype):
+                return (jax.random.normal(kk, shape, jnp.float32)
+                        * (fan_in ** -0.5)).astype(dt)
+
+            if len(sd.shape) == 3:  # [L, in, out]: one layer's f32 at a time
+                out.append(jax.lax.map(one, jax.random.split(k, sd.shape[0])))
+            else:
+                out.append(one(k))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    key = jax.random.key(int(seed) % (2**31 - 1), impl="rbg")
+    fn = jax.jit(build, out_shardings=SingleDeviceSharding(device))
+    return fn(jax.device_put(key, device))
+
+
+# -- warm-up ---------------------------------------------------------------
+
+
+def _samp(n: int) -> dict:
+    return {"temperature": [0.0] * n, "top_k": [0] * n, "top_p": [1.0] * n,
+            "seeds": [0] * n, "rep": [1.0] * n, "freq": [0.0] * n,
+            "presence": [0.0] * n}
+
+
+def warm_lattice(engine) -> dict:
+    """Compile (or load from the persistent cache) every step-program
+    variant the engine's flags allow, by walking the lattice and not by
+    hoping traffic meets it:
+      decode_loop  decode bucket <= max_batch  x  n_steps 1..decode_steps
+      ragged       T bucket <= max_batch + mixed_prefill_tokens
+      decode_loop  again, chained on a ragged step: bucket x n_steps-1
+      forward      prefill bucket <= chunk_size  x  prior context or none
+    and the eager slices of the ragged path's results (see below).
+    Inputs are dummies that write page 0..n of an empty pool."""
+    from dynamo_tpu.engine.model_runner import _next_bucket
+
+    r, s = engine.runner, engine.scheduler
+    ps = r.page_size
+    t0 = time.monotonic()
+    b_max = _next_bucket(r.decode_buckets, s.max_batch)
+    for b in [x for x in r.decode_buckets if x <= b_max]:
+        for n in range(1, s.decode_steps + 1):
+            r.decode_multi(n, [1] * b, [0] * b, [[0]] * b, _samp(b), 1)
+    if s.mixed_prefill_tokens > 0 and r.ragged_mixed and engine.fused_mixed:
+        t_max = _next_bucket(r.ragged_buckets, s.max_batch + s.mixed_prefill_tokens)
+        for t in [x for x in r.ragged_buckets if x <= t_max]:
+            pages = list(range(1, 2 + (t - 1) // ps))
+            r.decode_multi_with_prefill(
+                1, [1], [0], [[0]], _samp(1), 1,
+                [1] * (t - 1), 0, pages, 0)
+        # a mixed iteration's steps 2..n run through decode_loop chained on
+        # the ragged step's device-resident tokens: other variants than the
+        # host-token ones above (they compiled inside the window at first)
+        for b in [x for x in r.decode_buckets if x <= b_max]:
+            for n in range(2, s.decode_steps + 1):
+                r.decode_multi_with_prefill(
+                    n, [1] * b, [0] * b, [[0]] * b, _samp(b), 1,
+                    [1] * 8, 0, [1], 0)
+        # the ragged path also runs small eager programs on its results,
+        # one per shape: `sampled[:B]` per (T bucket, decode bucket) and
+        # `seg_logits[n_dec : n_dec + k]` plus the walk over its k rows per
+        # (T bucket, chunks packed). They are in no family and no counter,
+        # and a fresh cache compiles them inside the window one by one
+        # (the first run of a checkout read 50 % slower), so meet them here
+        t_buckets = [x for x in r.ragged_buckets if x <= t_max]
+        for t in t_buckets:
+            for b in [x for x in r.decode_buckets if x <= b_max and x < t]:
+                n = t - b
+                r.decode_multi_with_prefill(
+                    1, [1] * b, [0] * b, [[0]] * b, _samp(b), 1,
+                    [1] * n, 0, list(range(1, 2 + n // ps)), 0)
+            for k in range(2, s.mixed_prefill_seqs + 1):
+                sizes = [(t - 1) // k] * k
+                sizes[-1] += (t - 1) - sum(sizes)
+                per = 1 + max(sizes) // ps
+                chunks = [{"tokens": [1] * n, "start": 0, "prior": 0, "adapter": 0,
+                           "table": list(range(1 + j * per, 1 + (j + 1) * per))}
+                          for j, n in enumerate(sizes)]
+                _, rows = r.decode_multi_with_prefills(
+                    1, [1], [0], [[0]], _samp(1), 1, chunks)
+                for _, lg in zip(chunks, rows):
+                    r.sample_one(lg, _samp(1), 1)
+    s_max = _next_bucket(r.prefill_buckets, s.chunk_size)
+    logits = None
+    for sb in [x for x in r.prefill_buckets if x <= s_max]:
+        pages = list(range(1, 3 + sb // ps))
+        logits = r.prefill([1] * sb, 0, pages, prior_len=0)
+        logits = r.prefill([1] * sb, ps, pages, prior_len=ps)
+    if logits is not None:
+        r.sample_one(logits, _samp(1), 1)
+    return {"warm_s": time.monotonic() - t0, "compile": r.compile_stats()}
+
+
+# -- correctness -----------------------------------------------------------
+
+
+def check_prompts(config, sched, rng, rehearse: bool):
+    """The sample the reference check serves: [(prompt ids, tokens asked)].
+    Prompt 0 is the lead: it is started alone and decodes while the others
+    prefill, long enough to outlast them. Then three short prompts and one
+    long one, longer than a mixed-prefill chunk and, where the model has a
+    sliding window, longer than the window, so that its chunks and its decode
+    steps attend across the window's edge."""
+    n_prompt, n_out = (12, 5) if rehearse else (48, 9)
+    shorts = [n_prompt + 7 * j for j in range(1, 4)]
+    chunk = max(int(sched.mixed_prefill_tokens), 16)
+    win = int(getattr(config, "sliding_window", 0) or 0)
+    long = (win + 153) if win else (3 * chunk + 17)
+    long = min(long, int(config.max_seq_len) - n_out - 1)
+    iters = -(-(sum(shorts) + long) // chunk) + 2
+    n_lead = n_out + int(sched.decode_steps) * iters
+    sizes = [(n_prompt, n_lead)] + [(n, n_out) for n in shorts + [long]]
+    return [(rng.integers(1, config.vocab_size, size=n).tolist(), k) for n, k in sizes]
+
+
+async def served(engine, sample, logprobs: bool):
+    """Serve the whole sample through the engine at once, greedy:
+    [(tokens, logprobs)] per prompt. The lead starts alone; the rest are
+    submitted when its first token is out, so their prefills meet a live
+    decode row and all of them then decode side by side in one batch."""
+    from dynamo_tpu.runtime.context import Context
+
+    lead_out = asyncio.Event()
+
+    async def one(i: int, ids, n_out: int):
+        if i > 0:
+            await lead_out.wait()
+        toks, lps = [], []
+        payload = {"token_ids": list(ids),
+                   "sampling": {"temperature": 0.0, **({"logprobs": 0} if logprobs else {})},
+                   "stop": {"max_tokens": n_out, "stop_ids": [], "ignore_eos": True}}
+        try:
+            async for item in engine.generate(payload, Context()):
+                toks += list(item.get("token_ids") or [])
+                lps += [e["logprob"] for e in item.get("logprobs") or []]
+                if toks:
+                    lead_out.set()
+                if item.get("finish_reason"):
+                    if item["finish_reason"] == "error":
+                        raise RuntimeError(f"engine error: {item.get('error')}")
+                    break
+        finally:
+            lead_out.set()  # a lead that fails must not hang the rest
+        return toks, lps
+
+    return await asyncio.gather(*(one(i, ids, k) for i, (ids, k) in enumerate(sample)))
+
+
+def check_against_reference(ref, model: dict, params, sample, got, tol: float) -> dict:
+    """Teacher-force the plain float32 reference on prompt + served tokens.
+    Where the engine gave logprobs, compare the logprob of each served token
+    (`max`, `mean`); in every case measure how far the served token lies
+    under the reference's best one (`gap`): a greedy token picked from logits
+    that are each within `tol` lies within 2 x tol of it."""
+    import numpy as np
+
+    worst, total, gap, n, short, per = 0.0, 0.0, 0.0, 0, False, []
+    for (ids, n_out), (toks, lps) in zip(sample, got):
+        if len(toks) != n_out or len(lps) not in (0, n_out):
+            short = True
+            continue
+        seq = np.asarray(list(ids) + toks[:-1], np.int32)
+        at = list(range(len(ids) - 1, len(seq)))
+        logp = ref.logprobs_at(model, params, seq, at)  # [len(at), V] f32
+        want = logp[np.arange(len(toks)), np.asarray(toks)]
+        under = float((logp.max(axis=-1) - want).max())
+        row = {"prompt": len(ids), "tokens": len(toks), "gap": round(under, 4)}
+        if lps:
+            err = np.abs(want - np.asarray(lps))
+            worst, total = max(worst, float(err.max())), total + float(err.sum())
+            row.update(max=round(float(err.max()), 4), mean=round(float(err.mean()), 4))
+        gap, n = max(gap, under), n + len(toks)
+        per.append(row)
+    mean = total / max(n, 1)
+    # the bound on the worst token is `tol`; the mean over tokens is held to
+    # a third of it, which is the steadier of the two readings
+    return {"max_abs_logprob_err": worst, "mean_abs_logprob_err": mean,
+            "max_gap_under_best": gap, "tokens": n, "tolerance": tol, "per_prompt": per,
+            "ok": bool(n > 0 and not short and worst <= tol and mean <= tol / 3
+                       and gap <= 2 * tol)}
+
+
+async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
+                          rehearse: bool) -> dict:
+    """One replica's check, in two passes over samples of the same shape.
+    With logprobs: each served token's logprob against the reference. A
+    request that asks for logprobs never rides the fused mixed step (the
+    engine splits such an iteration into a prefill and a decode dispatch),
+    so this pass checks prefill chunks on a prior context, the decode loop at
+    several rows and the window's edge, and not the ragged program. Without
+    logprobs: the same drive goes through the ragged mixed step every request
+    takes under load, and the served greedy tokens are held to the
+    reference's best token. A pass that never decoded two rows at once, or a
+    second pass that missed the ragged program where the engine runs it, has
+    checked too little and fails."""
+    import numpy as np
+
+    ref = load_module(os.path.join(HERE, "reference", cfg["reference"] + ".py"),
+                      "bench_reference")
+    r, s = engine.runner, engine.scheduler
+    mixed_on = bool(s.mixed_prefill_tokens > 0 and r.ragged_mixed and engine.fused_mixed)
+    out = {"ok": True}
+    for name, logprobs in (("logprobs", True), ("ragged", False)):
+        sample = check_prompts(engine.runner.config, s,
+                               np.random.default_rng([seed, int(logprobs)]), rehearse)
+        calls0 = {k: v.get("calls", 0) for k, v in r.compile_stats().items()}
+        t0 = time.time()
+        got = await served(engine, sample, logprobs)
+        res = check_against_reference(ref, model, r.params, sample, got, tol)
+        res["calls"] = {k: v.get("calls", 0) - calls0.get(k, 0)
+                        for k, v in r.compile_stats().items()}
+        res["max_decode_rows"] = max(
+            (rec.decode_seqs for rec in engine.recorder.snapshot() if rec.ts >= t0), default=0)
+        res["ok"] = bool(res["ok"] and res["max_decode_rows"] > 1 and (
+            logprobs or not mixed_on or res["calls"].get("ragged", 0) > 0))
+        out[name] = res
+        out["ok"] = out["ok"] and res["ok"]
+    return out
+
+
+# -- the run ---------------------------------------------------------------
+
+
+def parse_args(argv=None):
+    p = argparse.ArgumentParser("benchmark/serve.py")
+    p.add_argument("--config", required=True)
+    p.add_argument("--run-dir", required=True)
+    p.add_argument("--seed", type=int, required=True)
+    p.add_argument("--chips", type=int, default=1)
+    p.add_argument("--trace", type=int, default=0)
+    p.add_argument("--trace-seconds", type=float, default=1.0)
+    p.add_argument("--trace-captures", type=int, default=3)
+    p.add_argument("--tokenizer", required=True)
+    p.add_argument("--rehearse", action="store_true")
+    return p.parse_args(argv)
+
+
+def snapshot(engines) -> list:
+    out = []
+    for e in engines:
+        st = e.runner.compile_stats()
+        out.append({
+            "compile": {k: {"variants": v["variants"],
+                            "compile_s": v.get("compile_s", 0.0),
+                            "calls": v.get("calls", 0)} for k, v in st.items()},
+            "reused_prefix_tokens": e.scheduler.reused_prefix_tokens,
+            "prompt_tokens_total": e.scheduler.prompt_tokens_total,
+            "wall": time.time(),
+        })
+    return out
+
+
+async def amain(args) -> int:
+    with open(args.config) as f:
+        cfg = json.load(f)
+    model = dict(cfg["model"])
+    flags = dict(cfg["server_flags"])
+    tol = float(cfg["correct_tolerance"])
+    if args.rehearse:
+        with open(os.path.join(HERE, "rehearse.json")) as f:
+            reh = json.load(f)
+        ratio = model["n_heads"] // model["n_kv_heads"]
+        model.update(reh["model"])
+        model["n_kv_heads"] = max(1, model["n_heads"] // ratio)
+        flags.update(reh["server_flags"])
+        tol = float(reh["correct_tolerance"])
+
+    import jax
+    import jax.numpy as jnp
+
+    import dynamo_tpu
+    from dynamo_tpu import worker as worker_mod
+    from dynamo_tpu.engine.model_runner import ModelRunner
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.runtime.distributed import DistributedRuntime
+    from dynamo_tpu.worker_common import serve_worker
+
+    cache_dir = dynamo_tpu.enable_compilation_cache()
+    devices = jax.devices()
+    plat = devices[0].platform
+    log(f"jax {jax.__version__} platform={plat} kind={devices[0].device_kind!r} "
+        f"devices={len(devices)} cache={cache_dir}")
+    if plat != "tpu" and not args.rehearse:
+        log("no TPU: refusing to measure (use --rehearse to debug the harness)")
+        return 3
+    if len(devices) < args.chips:
+        log(f"the cell asks for {args.chips} chips, JAX reports {len(devices)}")
+        return 3
+    devices = devices[: args.chips]
+
+    config = ModelConfig(**model)
+    wargs = worker_mod.parse_args(
+        [x for k, v in flags.items() for x in (f"--{k}", str(v))]
+        + ["--tokenizer", args.tokenizer, "--model-name", config.name,
+           "--discovery-backend", "file",
+           "--discovery-root", os.path.join(args.run_dir, "discovery")])
+    mpps = -(-wargs.max_seq_len // wargs.page_size)
+
+    def build(i: int):
+        t = time.monotonic()
+        params = make_params(config, args.seed, devices[i], jnp.bfloat16)
+        jax.block_until_ready(params)
+        t_w = time.monotonic() - t
+        runner = ModelRunner(
+            config, None, devices=[devices[i]], num_pages=wargs.num_pages,
+            page_size=wargs.page_size, max_pages_per_seq=mpps, params=params,
+            spec_gamma=wargs.spec_gamma, quantize=wargs.quantize,
+            kv_quantize=wargs.kv_quantize)
+        engine, card = worker_mod.build_engine(wargs, runner=runner)
+        warm = warm_lattice(engine)
+        log(f"replica {i}: weights {t_w:.1f}s, lattice {warm['warm_s']:.1f}s "
+            f"{ {k: v['variants'] for k, v in warm['compile'].items()} }")
+        return engine, card, {"weights_s": t_w, **warm}
+
+    built = [None] * len(devices)
+
+    def build_into(i: int) -> None:
+        built[i] = build(i)
+
+    # replicas build side by side: compiles and cache loads release the GIL
+    threads = [threading.Thread(target=build_into, args=(i,)) for i in range(len(devices))]
+    for t in threads:
+        t.start()
+    while any(t.is_alive() for t in threads):
+        await asyncio.sleep(0.2)
+    if any(b is None for b in built):
+        log("a replica failed to build")
+        return 4
+    engines = [b[0] for b in built]
+    report = engines[0].runner.device_report()
+    log("device_report " + json.dumps({k: report[k] for k in (
+        "platform", "device_kind", "attn_impl", "attn_impl_reason", "ragged_mixed")}
+        | {"fused_mixed": bool(engines[0].fused_mixed)}))
+
+    # the reference check, outside the timed window and before serving
+    import numpy as np
+
+    t = time.monotonic()
+    checks = [await reference_check(cfg, model, e, args.seed, tol, args.rehearse)
+              for e in engines]
+    log(f"reference check {time.monotonic() - t:.1f}s: {json.dumps(checks)}")
+
+    # phase spine of every finished request, with the wall time it ended
+    phases_log: list = []
+    for i, e in enumerate(engines):
+        def on_phases(ph, i=i):
+            phases_log.append({"replica": i, "wall": time.time(), **{
+                k: v for k, v in ph.items() if isinstance(v, (int, float))}})
+        e.on_phases(on_phases)
+
+    runtimes, workers = [], []
+    for i, (engine, card, _) in enumerate(built):
+        rt = DistributedRuntime(discovery_backend="file", root=wargs.discovery_root)
+        w = await serve_worker(rt, engine, card, namespace=wargs.namespace,
+                               component=wargs.component, endpoint=wargs.endpoint,
+                               digest_period_s=wargs.digest_period)
+        runtimes.append(rt)
+        workers.append(w)
+
+    def mem() -> dict:
+        out = {}
+        for d in devices:
+            st = d.memory_stats() or {}
+            out[str(d.id)] = {k: int(st[k]) for k in (
+                "bytes_in_use", "peak_bytes_in_use", "bytes_limit") if k in st}
+        return out
+
+    sched = engines[0].scheduler
+    write_json(os.path.join(args.run_dir, "ready.json"), {
+        "device": {"platform": plat, "kind": devices[0].device_kind, "count": len(devices)},
+        "model": config.name, "correct": checks, "replicas": [b[2] for b in built],
+        "device_report": report, "memory": mem(), "ready_s": time.monotonic() - T0,
+        "engine": {"max_batch": sched.max_batch, "decode_steps": sched.decode_steps,
+                   "mixed_prefill_tokens": sched.mixed_prefill_tokens,
+                   "chunk_size": sched.chunk_size, "page_size": wargs.page_size,
+                   "num_pages": wargs.num_pages},
+    })
+    log("ready")
+
+    stop_path = os.path.join(args.run_dir, "stop")
+    window_path = os.path.join(args.run_dir, "window.json")
+    trace_dir = os.path.join(args.run_dir, "trace")
+    window = None
+    while not os.path.exists(stop_path):
+        if os.path.exists(window_path):
+            with open(window_path) as f:
+                window = json.load(f)
+            break
+        await asyncio.sleep(0.1)
+    trace_info = None
+    if window is not None:
+        await asyncio.sleep(max(0.0, window["t0_wall"] - time.time()))
+        at0 = snapshot(engines)
+        if args.trace:
+            # the device side of a capture holds about half a second however
+            # long it is left open, so several short ones, one after another
+            # from a quarter of the window on, each in a directory of its own
+            span = window["t1_wall"] - window["t0_wall"]
+            await asyncio.sleep(max(0.0, window["t0_wall"] + span / 4 - time.time()))
+            caps = []
+            while len(caps) < args.trace_captures and \
+                    time.time() + args.trace_seconds + 1 < window["t1_wall"]:
+                ts = time.time()
+                await asyncio.to_thread(jax.profiler.start_trace,
+                                        os.path.join(trace_dir, f"c{len(caps)}"))
+                await asyncio.sleep(args.trace_seconds)
+                te = time.time()
+                await asyncio.to_thread(jax.profiler.stop_trace)
+                caps.append({"start_wall": ts, "stop_wall": te, "written_s": time.time() - te})
+                await asyncio.sleep(0.5)
+            trace_info = {"captures": caps}
+            log(f"trace captures {json.dumps(caps)}")
+        await asyncio.sleep(max(0.0, window["t1_wall"] - time.time()))
+        at1 = snapshot(engines)
+        iters = []
+        for i, e in enumerate(engines):
+            for rec in e.recorder.snapshot():
+                if window["t0_wall"] <= rec.ts < window["t1_wall"]:
+                    iters.append({"replica": i, "ts": rec.ts, "wall_s": rec.wall_s,
+                                  "kind": rec.kind, "decode_seqs": rec.decode_seqs,
+                                  "decode_steps": rec.decode_steps,
+                                  "n_chunks": rec.n_chunks,
+                                  "chunk_tokens": rec.chunk_tokens,
+                                  "ragged": rec.ragged, "n_waiting": rec.n_waiting,
+                                  "n_running": rec.n_running, "kv_usage": rec.kv_usage})
+        write_json(os.path.join(args.run_dir, "counters.json"),
+                   {"at0": at0, "at1": at1, "iterations": iters, "trace": trace_info})
+        while not os.path.exists(stop_path):
+            await asyncio.sleep(0.1)
+
+    final = {"memory": mem(), "phases": phases_log}
+    if trace_info is not None:
+        reduce_mod = load_module(os.path.join(HERE, "trace_reduce.py"), "bench_trace_reduce")
+        try:
+            final["trace"] = reduce_mod.reduce_dir(trace_dir, n_devices=len(devices))
+        except Exception as e:  # the run still reports what it has
+            log(f"trace reduction failed: {type(e).__name__}: {e}")
+            final["trace_error"] = f"{type(e).__name__}: {e}"
+    write_json(os.path.join(args.run_dir, "final.json"), final)
+    log("final written; shutting down")
+    for e in engines:
+        e.stop()
+    for rt in runtimes:
+        try:
+            await asyncio.wait_for(rt.shutdown(drain_timeout=1), timeout=5)
+        except Exception:
+            pass
+    return 0
+
+
+def main() -> None:
+    args = parse_args()
+    os.makedirs(args.run_dir, exist_ok=True)
+    if args.trace:
+        # host spans (engine.decode / engine.mixed / engine.prefill) ride the
+        # profiler's own clock, so idle gaps on the device find an owner
+        os.environ["DYN_ENABLE_JAX_TRACE"] = "1"
+    rc = asyncio.run(amain(args))
+    sys.stdout.flush()
+    os._exit(rc)  # engine step threads and zmq sockets must not hold the exit
+
+
+if __name__ == "__main__":
+    main()

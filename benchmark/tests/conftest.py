@@ -1,0 +1,7 @@
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (BENCH, os.path.join(BENCH, "layers")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
