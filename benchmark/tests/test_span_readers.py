@@ -1,0 +1,100 @@
+"""The seven readers that use the program's spans, its `other` compile
+family and its spine keys (PERF.md section 3), each on a hand-made ctx:
+present, absent (None: the line leaves the metric out, as with a program
+that has none of them), and the edge each has."""
+
+import importlib.util
+import os
+
+import pytest
+
+import loadgen
+
+LAYERS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "layers")
+
+
+def reader(name):
+    spec = importlib.util.spec_from_file_location(
+        "layer_" + name.replace(".", "_"), os.path.join(LAYERS, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def trace_ctx(idle_gaps, window_s=4.0, modules=None):
+    return {"trace": {"window_s": window_s, "busy_s": window_s - sum(v for _, v in idle_gaps),
+                      "idle_gaps": idle_gaps, "modules": modules or {}},
+            "percentile": loadgen.percentile}
+
+
+GAPS = [["engine.schedule", 0.08], ["engine.emit", 0.06], ["engine.prep", 0.04],
+        ["engine.wait", 0.04], ["engine.readback", 0.02], ["engine.stage", 0.02],
+        ["engine.inbox", 0.01], ["engine.publish", 0.01], ["engine.mixed", 0.004],
+        ["unattributed", 0.002]]
+IDLE = ["sched.idle_plan_pct", "engine.idle_prep_pct", "engine.idle_emit_pct",
+        "device.idle_unowned_pct"]
+
+
+def test_idle_shares_add_up_to_the_idle_time():
+    ctx = trace_ctx(GAPS)
+    got = {n: reader(n)(ctx) for n in IDLE}
+    assert got["sched.idle_plan_pct"] == pytest.approx(100 * 0.09 / 4)
+    assert got["engine.idle_prep_pct"] == pytest.approx(100 * 0.06 / 4)  # no dispatch owner: 0
+    assert got["engine.idle_emit_pct"] == pytest.approx(100 * 0.09 / 4)
+    assert got["device.idle_unowned_pct"] == pytest.approx(100 * 0.006 / 4)
+    wait = 100 * 0.04 / 4
+    assert sum(got.values()) + wait == pytest.approx(reader("device.idle_pct")(ctx))
+
+
+@pytest.mark.parametrize("name", IDLE[:3])
+def test_idle_share_of_a_program_without_the_spans_is_left_out(name):
+    old = trace_ctx([["engine.decode", 0.12], ["unattributed", 0.04], ["engine.mixed", 0.03]])
+    assert reader(name)(old) is None
+    assert reader(name)({"trace": None}) is None and reader(name)({}) is None
+    # one of the spans is enough: a listed owner counts, a missing one is 0
+    assert reader(name)(trace_ctx([["engine.wait", 1.0]])) == 0.0
+
+
+def test_a_bare_parent_owning_time_is_unowned():
+    old = trace_ctx([["engine.decode", 0.12], ["unattributed", 0.04], ["engine.mixed", 0.03]])
+    assert reader("device.idle_unowned_pct")(old) == pytest.approx(100 * 0.19 / 4)
+    tiled = trace_ctx([["engine.prefill_packed", 0.01], ["engine.spec_verify", 0.01],
+                       ["engine.emit", 0.5], ["engine.wait", 0.5]])
+    assert reader("device.idle_unowned_pct")(tiled) == pytest.approx(100 * 0.02 / 4)
+    assert reader("device.idle_unowned_pct")({"trace": None}) is None
+
+
+def _snap(other, decode_loop=24):
+    c = {"decode_loop": {"variants": decode_loop, "compile_s": 0.0, "calls": 9}}
+    if other is not None:
+        c["other"] = {"variants": other, "compile_s": 0.0, "calls": other}
+    return {"compile": c}
+
+
+def test_eager_compiles_over_two_replicas():
+    read = reader("runner.eager_compiles_in_window")
+    ctx = {"counters": {"at0": [_snap(3), _snap(0)], "at1": [_snap(5), _snap(1, 25)]}}
+    assert read(ctx) == 3.0  # `other` only: the decode_loop variant is not eager
+    assert reader("runner.compiles_in_window")(ctx) == 4.0  # the older reader sums all
+    quiet = {"counters": {"at0": [_snap(3), _snap(0)], "at1": [_snap(3), _snap(0)]}}
+    assert read(quiet) == 0.0
+    old = {"counters": {"at0": [_snap(None)], "at1": [_snap(None)]}}
+    assert read(old) is None and read({"counters": {"at0": [], "at1": []}}) is None
+
+
+def _spine_ctx(phases):
+    return {"final": {"phases": [dict(p, wall=100.0 + i, e2e_s=1.0)
+                                 for i, p in enumerate(phases)]},
+            "w0_wall": 99.0, "w1_wall": 150.0, "percentile": loadgen.percentile}
+
+
+def test_prefill_median_and_preempted_share():
+    ctx = _spine_ctx([{"prefill_s": 0.2, "preemptions": 0}, {"prefill_s": 0.4, "preemptions": 1},
+                      {"prefill_s": 0.3, "preemptions": 0}, {"prefill_s": 0.9, "preemptions": 2}])
+    assert reader("sched.prefill_p50_ms")(ctx) == pytest.approx(350.0)
+    assert reader("sched.preempted_pct")(ctx) == pytest.approx(50.0)
+    ctx["w1_wall"] = 101.5  # the window's edge cuts by arrival
+    assert reader("sched.preempted_pct")(ctx) == pytest.approx(100 / 3)
+    old = _spine_ctx([{"ttft_s": 0.5, "queue_wait_s": 0.1}])
+    assert reader("sched.prefill_p50_ms")(old) is None
+    assert reader("sched.preempted_pct")(old) is None

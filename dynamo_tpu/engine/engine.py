@@ -638,6 +638,12 @@ class InferenceEngine:
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
         if self._thread is None:
+            # what the step loop imports on its first line, imported here:
+            # the package pulls in jax (~0.7 s cold), and a loop that
+            # spends its first second importing serves nothing meanwhile
+            # (a mocker fleet's planner saw an idle fleet and scaled down)
+            import dynamo_tpu.parallel.multihost  # noqa: F401
+
             self._thread = threading.Thread(target=self._loop, name="engine-step", daemon=True)
             self._thread.start()
 
@@ -936,6 +942,11 @@ class InferenceEngine:
 
         log.info("engine step loop started (fused_mixed=%s)",
                  self.fused_mixed)
+        name_thread = getattr(self.runner, "name_step_thread", None)
+        if name_thread is not None:
+            # compiles on this thread that no step family sees count in
+            # this runner's compile_stats()["other"]
+            name_thread()
         while not self._stop.is_set():
             try:
                 self._loop_once()
@@ -965,12 +976,17 @@ class InferenceEngine:
     def _loop_once(self) -> None:
         from dynamo_tpu.parallel.multihost import GroupBroken
 
-        self._drain_inbox()  # dynlint: disable=DYN-J006 — embed readback (.tolist in _run_embeds) is a request-boundary transfer; sanitizer allowlists it as "embed_readback"
-        self._propose_drafts()
-        plan = self.scheduler.step_plan()
+        sched = self.scheduler
+        with annotate("engine.inbox"):
+            self._drain_inbox()  # dynlint: disable=DYN-J006 — embed readback (.tolist in _run_embeds) is a request-boundary transfer; sanitizer allowlists it as "embed_readback"
+            self._propose_drafts()
+        with annotate("engine.schedule", waiting=len(sched.waiting),
+                      running=len(sched.active)):
+            plan = sched.step_plan()
         if plan is None:
-            if not self.scheduler.has_work():
-                time.sleep(self.idle_sleep_s)
+            if not sched.has_work():
+                with annotate("engine.wait"):
+                    time.sleep(self.idle_sleep_s)
             return
         t0 = time.monotonic()
         t_start, ts_wall = t0, time.time()
@@ -1133,16 +1149,17 @@ class InferenceEngine:
                     log.exception("failed to fail sequence %s", seq.request_id)
             self._recover_poisoned_pools()
             return
-        if self.sanitizer is not None:
-            # arms the transfer guard + freezes the compiled-family
-            # baseline after warmup; a new variant past that is a leak
-            self.sanitizer.note_step(self.runner)
-        self._publish_fpm(kind, time.monotonic() - t0, n_tok)
-        self._publish_kv_events()
-        self._record_iteration(
-            ts_wall, time.monotonic() - t_start,
-            "mixed" if isinstance(plan, MixedPlan) else kind, rinfo,
-        )
+        with annotate("engine.publish"):
+            if self.sanitizer is not None:
+                # arms the transfer guard + freezes the compiled-family
+                # baseline after warmup; a new variant past that is a leak
+                self.sanitizer.note_step(self.runner)
+            self._publish_fpm(kind, time.monotonic() - t0, n_tok)
+            self._publish_kv_events()
+            self._record_iteration(
+                ts_wall, time.monotonic() - t_start,
+                "mixed" if isinstance(plan, MixedPlan) else kind, rinfo,
+            )
 
     def _record_iteration(self, ts: float, wall: float, kind: str,
                           rinfo: Dict[str, Any]) -> None:
@@ -1621,13 +1638,15 @@ class InferenceEngine:
                 }
                 for p in plans
             ])
-            for plan, lg in zip(plans, logits_rows):
-                self.scheduler.complete_prefill(plan)
-                self._finish_prefill(plan, lg)
+            with annotate("engine.emit"):
+                for plan, lg in zip(plans, logits_rows):
+                    self.scheduler.complete_prefill(plan)
+                    self._finish_prefill(plan, lg)
 
     def _run_prefill_inner(self, plan: PrefillPlan) -> None:
         seq = plan.seq
-        mm_chunk = self._mm_chunk(seq, plan.start_pos, len(plan.chunk))
+        with annotate("engine.prep"):
+            mm_chunk = self._mm_chunk(seq, plan.start_pos, len(plan.chunk))
         logits = self.runner.prefill(
             plan.chunk,
             plan.start_pos,
@@ -1645,8 +1664,9 @@ class InferenceEngine:
                 plan.chunk, plan.start_pos, seq.pages, prior_len=plan.start_pos,
                 mm=mm_chunk,
             )
-        self.scheduler.complete_prefill(plan)
-        self._finish_prefill(plan, logits)
+        with annotate("engine.emit"):
+            self.scheduler.complete_prefill(plan)
+            self._finish_prefill(plan, logits)
 
     def _finish_prefill(self, plan: PrefillPlan, logits) -> None:
         """Post-chunk bookkeeping shared by the standalone and fused mixed
@@ -1792,24 +1812,25 @@ class InferenceEngine:
         decode half)."""
         from dynamo_tpu.parallel.multihost import GroupBroken
 
-        for pplan, lg in zip(prefills, chunk_logits):
-            try:
-                self.scheduler.complete_prefill(pplan)
-                self._finish_prefill(pplan, lg)
-            except GroupBroken:
-                raise
-            except Exception:
-                log.exception(
-                    "packed chunk bookkeeping failed; erroring %s",
-                    pplan.seq.request_id,
-                )
+        with annotate("engine.emit"):
+            for pplan, lg in zip(prefills, chunk_logits):
                 try:
-                    self._emit(pplan.seq, [], "error")
-                    self.scheduler.abort(pplan.seq.request_id)
+                    self.scheduler.complete_prefill(pplan)
+                    self._finish_prefill(pplan, lg)
+                except GroupBroken:
+                    raise
                 except Exception:
-                    log.exception("failed to fail sequence %s",
-                                  pplan.seq.request_id)
-                self._recover_poisoned_pools()
+                    log.exception(
+                        "packed chunk bookkeeping failed; erroring %s",
+                        pplan.seq.request_id,
+                    )
+                    try:
+                        self._emit(pplan.seq, [], "error")
+                        self.scheduler.abort(pplan.seq.request_id)
+                    except Exception:
+                        log.exception("failed to fail sequence %s",
+                                      pplan.seq.request_id)
+                    self._recover_poisoned_pools()
 
     # -- speculative decoding (n-gram drafting + ragged verify) -------------
     def _warn_spec_once(self, rid: str, what: str) -> None:
@@ -1980,84 +2001,84 @@ class InferenceEngine:
             BucketOverflowError = ()
 
         seqs = dplan.seqs
-        drafts = [list(s.spec_draft) for s in seqs]
-        trees = [list(s.spec_tree) for s in seqs]
-        for s in seqs:
-            s.spec_draft = []  # consumed (or shed) either way
-            s.spec_tree = []
-        tokens = [s.tokens[-1] for s in seqs]
-        positions = [s.computed_len for s in seqs]
-        tables = [s.pages for s in seqs]
-        step0 = self._next_step()
-        chunks = [
-            {
-                "tokens": p.chunk, "start": p.start_pos,
-                "table": p.seq.pages, "prior": p.start_pos,
-                "adapter": p.seq.adapter_idx,
-            }
-            for p in prefills
-        ]
-        n_drafted = sum(len(d) for d in drafts)
-        # tree speculation: each extra branch is an INDEPENDENT verify
-        # segment on a forked page table — trunk (committed) pages are
-        # ref-shared, only the speculative tail is fresh, so branch KV
-        # writes never collide with the primary row's. Branch rows are
-        # appended AFTER every primary row, which keeps the row-indexed
-        # mask/bias dicts below valid, and they reuse the owning
-        # sequence's sampling params + seed: identical branch prefixes
-        # then yield identical target samples, the trie invariant
-        # accept_tree's walk relies on.
-        sp = _sampling_params(seqs)
-        branch_rows: List[List[int]] = [[] for _ in seqs]
-        forks: List[List[List[int]]] = [[] for _ in seqs]
-        n_branch_tok = 0
-        if any(trees):
-            PS = self.pool.page_size
-            for i, s in enumerate(seqs):
-                if not drafts[i]:
-                    trees[i] = []  # branches never ride without a primary
-                for b in trees[i]:
-                    try:
-                        fork = self.pool.fork_table(
-                            s.pages, n_shared=s.computed_len // PS
-                        )
-                    except NoSpace:
-                        break  # pool pressure: shed remaining branches
-                    branch_rows[i].append(len(tokens))
-                    forks[i].append(fork)
-                    tokens.append(s.tokens[-1])
-                    positions.append(s.computed_len)
-                    tables.append(fork)
-                    drafts.append([int(t) for t in b])
-                    n_branch_tok += len(b) + 1
-                    for kf in sp:
-                        sp[kf].append(sp[kf][i])
-                trees[i] = trees[i][: len(forks[i])]
-
-        def _release_forks(i: int) -> None:
-            for f in forks[i]:
-                if f is not None:
-                    self.pool.release(f)
-            forks[i] = []
-        # guided/bias rows never draft (_propose_drafts), so each owns
-        # exactly ONE verify position; its mask/bias rides the dispatch's
-        # always-present sampling operands (row-aligned dicts)
-        vkw: Dict[str, Any] = {}
-        masks = {
-            i: self._guided_mask(s)
-            for i, s in enumerate(seqs) if s.guided_m is not None
-        }
-        if masks:
-            vkw["masks"] = masks
-        brows = _batch_biases(seqs, self.runner)
-        if brows is not None:
-            vkw["biases"] = {
-                i: brows[i] for i, s in enumerate(seqs) if s.logit_bias
-            }
-        n_branch_rows = sum(len(r) for r in branch_rows)
         with annotate("engine.spec_verify", batch=len(seqs),
-                      drafted=n_drafted, chunks=len(chunks),
-                      branches=n_branch_rows):
+                      chunks=len(prefills)):
+            with annotate("engine.prep"):
+                drafts = [list(s.spec_draft) for s in seqs]
+                trees = [list(s.spec_tree) for s in seqs]
+                for s in seqs:
+                    s.spec_draft = []  # consumed (or shed) either way
+                    s.spec_tree = []
+                tokens = [s.tokens[-1] for s in seqs]
+                positions = [s.computed_len for s in seqs]
+                tables = [s.pages for s in seqs]
+                step0 = self._next_step()
+                chunks = [
+                    {
+                        "tokens": p.chunk, "start": p.start_pos,
+                        "table": p.seq.pages, "prior": p.start_pos,
+                        "adapter": p.seq.adapter_idx,
+                    }
+                    for p in prefills
+                ]
+                n_drafted = sum(len(d) for d in drafts)
+                # tree speculation: each extra branch is an INDEPENDENT verify
+                # segment on a forked page table — trunk (committed) pages are
+                # ref-shared, only the speculative tail is fresh, so branch KV
+                # writes never collide with the primary row's. Branch rows are
+                # appended AFTER every primary row, which keeps the row-indexed
+                # mask/bias dicts below valid, and they reuse the owning
+                # sequence's sampling params + seed: identical branch prefixes
+                # then yield identical target samples, the trie invariant
+                # accept_tree's walk relies on.
+                sp = _sampling_params(seqs)
+                branch_rows: List[List[int]] = [[] for _ in seqs]
+                forks: List[List[List[int]]] = [[] for _ in seqs]
+                n_branch_tok = 0
+                if any(trees):
+                    PS = self.pool.page_size
+                    for i, s in enumerate(seqs):
+                        if not drafts[i]:
+                            trees[i] = []  # branches never ride without a primary
+                        for b in trees[i]:
+                            try:
+                                fork = self.pool.fork_table(
+                                    s.pages, n_shared=s.computed_len // PS
+                                )
+                            except NoSpace:
+                                break  # pool pressure: shed remaining branches
+                            branch_rows[i].append(len(tokens))
+                            forks[i].append(fork)
+                            tokens.append(s.tokens[-1])
+                            positions.append(s.computed_len)
+                            tables.append(fork)
+                            drafts.append([int(t) for t in b])
+                            n_branch_tok += len(b) + 1
+                            for kf in sp:
+                                sp[kf].append(sp[kf][i])
+                        trees[i] = trees[i][: len(forks[i])]
+
+                def _release_forks(i: int) -> None:
+                    for f in forks[i]:
+                        if f is not None:
+                            self.pool.release(f)
+                    forks[i] = []
+                # guided/bias rows never draft (_propose_drafts), so each owns
+                # exactly ONE verify position; its mask/bias rides the dispatch's
+                # always-present sampling operands (row-aligned dicts)
+                vkw: Dict[str, Any] = {}
+                masks = {
+                    i: self._guided_mask(s)
+                    for i, s in enumerate(seqs) if s.guided_m is not None
+                }
+                if masks:
+                    vkw["masks"] = masks
+                brows = _batch_biases(seqs, self.runner)
+                if brows is not None:
+                    vkw["biases"] = {
+                        i: brows[i] for i, s in enumerate(seqs) if s.logit_bias
+                    }
+                n_branch_rows = sum(len(r) for r in branch_rows)
             try:
                 with self._san_scope("spec_verify"):
                     rows, chunk_logits = self.runner.verify_spec(
@@ -2072,43 +2093,44 @@ class InferenceEngine:
                     "this iteration's drafts", e,
                 )
                 return None
-            n_rows = sum(1 for d in drafts[: len(seqs)] if d)
-            accepted = emitted_spec = tree_sw = 0
-            for i, seq in enumerate(seqs):
-                if forks[i]:
-                    emitted, winner = accept_tree(
-                        [drafts[i]] + trees[i],
-                        [rows[i]] + [rows[r] for r in branch_rows[i]],
-                    )
-                    if winner > 0:
-                        # adopt the winning branch's forked table BEFORE
-                        # committing: its fresh tail pages hold the KV of
-                        # the accepted suffix (the primary's tail is stale
-                        # past the first divergence). Trunk pages are
-                        # shared, so the swap moves one reference; the old
-                        # table's speculative tail goes back to the pool.
-                        old = seq.pages
-                        seq.pages = forks[i][winner - 1]
-                        forks[i][winner - 1] = None
-                        self.pool.release(old)
-                        tree_sw += 1
-                    _release_forks(i)  # losers (and fork-side trunk refs)
-                else:
-                    emitted = accept_deterministic(drafts[i], rows[i])
-                if drafts[i]:
-                    accepted += len(emitted) - 1
-                    emitted_spec += len(emitted)
-                emit: List[int] = []
-                reason = None
-                for token in emitted:
-                    reason = self.scheduler.complete_decode(seq, token)
-                    if not reason:
-                        self._guided_advance(seq, token)
-                    if reason != "stop":
-                        emit.append(token)
-                    if reason:
-                        break
-                self._emit(seq, emit, reason)
+            with annotate("engine.emit"):
+                n_rows = sum(1 for d in drafts[: len(seqs)] if d)
+                accepted = emitted_spec = tree_sw = 0
+                for i, seq in enumerate(seqs):
+                    if forks[i]:
+                        emitted, winner = accept_tree(
+                            [drafts[i]] + trees[i],
+                            [rows[i]] + [rows[r] for r in branch_rows[i]],
+                        )
+                        if winner > 0:
+                            # adopt the winning branch's forked table BEFORE
+                            # committing: its fresh tail pages hold the KV of
+                            # the accepted suffix (the primary's tail is stale
+                            # past the first divergence). Trunk pages are
+                            # shared, so the swap moves one reference; the old
+                            # table's speculative tail goes back to the pool.
+                            old = seq.pages
+                            seq.pages = forks[i][winner - 1]
+                            forks[i][winner - 1] = None
+                            self.pool.release(old)
+                            tree_sw += 1
+                        _release_forks(i)  # losers (and fork-side trunk refs)
+                    else:
+                        emitted = accept_deterministic(drafts[i], rows[i])
+                    if drafts[i]:
+                        accepted += len(emitted) - 1
+                        emitted_spec += len(emitted)
+                    emit: List[int] = []
+                    reason = None
+                    for token in emitted:
+                        reason = self.scheduler.complete_decode(seq, token)
+                        if not reason:
+                            self._guided_advance(seq, token)
+                        if reason != "stop":
+                            emit.append(token)
+                        if reason:
+                            break
+                    self._emit(seq, emit, reason)
         st = self.spec_stats
         st["verify_iters"] += 1
         st["verify_rows"] += n_rows
@@ -2183,46 +2205,49 @@ class InferenceEngine:
         prefills = list(plan.prefills)
         with annotate("engine.mixed", batch=len(seqs), steps=T,
                       chunks=len(plan.prefills), chunk=n_chunk_tok):
-            tokens = [s.tokens[-1] for s in seqs]
-            positions = [s.computed_len for s in seqs]
-            tables = [s.pages for s in seqs]
-            step0 = self._step_counter + 1
-            self._step_counter += T
-            # guided rows ride the fused program: step 0 samples under the
-            # ragged step's mask operand; steps 1..T-1 fetch per-step masks
-            # through the decode loop's host callback, which advances a
-            # COPY of each row's DFA state by the device-sampled feedback
-            # token (pending_advance: step 0's token was sampled on device
-            # and not yet folded into the authoritative engine state)
-            mixkw: Dict[str, Any] = {}
-            guided_rows = [
-                i for i, s in enumerate(seqs) if s.guided_m is not None
-            ]
-            if guided_rows:
-                vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
-                masks = np.ones((len(seqs), vocab), bool)
-                for i in guided_rows:
-                    masks[i] = self._guided_mask(seqs[i])
-                mixkw["masks"] = masks
-                if T > 1:
-                    # tail steps after the ragged step 0: device DFA plan
-                    # when every schema fits the table budget (the runner
-                    # forces pending_advance — step 0's token was sampled
-                    # on device and not yet folded into the states), host
-                    # callback otherwise
-                    gdev = self._guided_device_plan(seqs)
-                    if gdev is not None:
-                        mixkw["guided_dev"] = gdev
-                    else:
-                        mixkw["mask_fn"] = GuidedMaskContext(
-                            len(seqs), vocab,
-                            [(i, seqs[i].guided_m, seqs[i].guided_s)
-                             for i in guided_rows],
-                            pending_advance=True,
-                        )
-            biases = _batch_biases(seqs, self.runner)
-            if biases is not None:
-                mixkw["biases"] = biases
+            with annotate("engine.prep"):
+                tokens = [s.tokens[-1] for s in seqs]
+                positions = [s.computed_len for s in seqs]
+                tables = [s.pages for s in seqs]
+                step0 = self._step_counter + 1
+                self._step_counter += T
+                # guided rows ride the fused program: step 0 samples under the
+                # ragged step's mask operand; steps 1..T-1 fetch per-step masks
+                # through the decode loop's host callback, which advances a
+                # COPY of each row's DFA state by the device-sampled feedback
+                # token (pending_advance: step 0's token was sampled on device
+                # and not yet folded into the authoritative engine state)
+                mixkw: Dict[str, Any] = {}
+                guided_rows = [
+                    i for i, s in enumerate(seqs) if s.guided_m is not None
+                ]
+                if guided_rows:
+                    vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
+                    masks = np.ones((len(seqs), vocab), bool)
+                    for i in guided_rows:
+                        masks[i] = self._guided_mask(seqs[i])
+                    mixkw["masks"] = masks
+                    if T > 1:
+                        # tail steps after the ragged step 0: device DFA plan
+                        # when every schema fits the table budget (the runner
+                        # forces pending_advance — step 0's token was sampled
+                        # on device and not yet folded into the states), host
+                        # callback otherwise
+                        gdev = self._guided_device_plan(seqs)
+                        if gdev is not None:
+                            mixkw["guided_dev"] = gdev
+                        else:
+                            mixkw["mask_fn"] = GuidedMaskContext(
+                                len(seqs), vocab,
+                                [(i, seqs[i].guided_m, seqs[i].guided_s)
+                                 for i in guided_rows],
+                                pending_advance=True,
+                            )
+                biases = _batch_biases(seqs, self.runner)
+                if biases is not None:
+                    mixkw["biases"] = biases
+                sp = _sampling_params(seqs)
+                adapters = [s.adapter_idx for s in seqs]
             while True:
                 # Bucket-overflow degradation: a pack the runner can't
                 # shape (pack/chunk/T bucket exceeded) sheds its newest
@@ -2236,11 +2261,10 @@ class InferenceEngine:
                     if len(prefills) == 1:
                         pplan = prefills[0]
                         sampled, lg = self.runner.decode_multi_with_prefill(
-                            T, tokens, positions, tables,
-                            _sampling_params(seqs),
+                            T, tokens, positions, tables, sp,
                             step0, pplan.chunk, pplan.start_pos,
                             pplan.seq.pages, pplan.start_pos,
-                            adapters=[s.adapter_idx for s in seqs],
+                            adapters=adapters,
                             chunk_adapter=pplan.seq.adapter_idx,
                             **mixkw,
                         )
@@ -2248,8 +2272,7 @@ class InferenceEngine:
                     else:
                         sampled, chunk_logits = (
                             self.runner.decode_multi_with_prefills(
-                                T, tokens, positions, tables,
-                                _sampling_params(seqs),
+                                T, tokens, positions, tables, sp,
                                 step0,
                                 [
                                     {
@@ -2261,7 +2284,7 @@ class InferenceEngine:
                                     }
                                     for p in prefills
                                 ],
-                                adapters=[s.adapter_idx for s in seqs],
+                                adapters=adapters,
                                 **mixkw,
                             )
                         )
@@ -2275,19 +2298,20 @@ class InferenceEngine:
                         "deferring chunk of %s to the next iteration",
                         e, shed.seq.request_id,
                     )
-            for i, seq in enumerate(seqs):
-                emit: List[int] = []
-                reason = None
-                for j in range(T):
-                    token = int(sampled[i, j])
-                    reason = self.scheduler.complete_decode(seq, token)
-                    if not reason:
-                        self._guided_advance(seq, token)
-                    if reason != "stop":
-                        emit.append(token)
-                    if reason:
-                        break
-                self._emit(seq, emit, reason)
+            with annotate("engine.emit"):
+                for i, seq in enumerate(seqs):
+                    emit: List[int] = []
+                    reason = None
+                    for j in range(T):
+                        token = int(sampled[i, j])
+                        reason = self.scheduler.complete_decode(seq, token)
+                        if not reason:
+                            self._guided_advance(seq, token)
+                        if reason != "stop":
+                            emit.append(token)
+                        if reason:
+                            break
+                    self._emit(seq, emit, reason)
         return chunk_logits
 
     def _run_decode(self, plan: DecodePlan) -> None:
@@ -2300,32 +2324,33 @@ class InferenceEngine:
         """Fused multi-step decode: plan.n_steps iterations in one jit with
         on-device token feedback (one host sync per plan, not per token).
         Tokens sampled past a stop are discarded host-side."""
-        seqs = plan.seqs
-        T = plan.n_steps
-        tokens = [s.tokens[-1] for s in seqs]
-        positions = [s.computed_len for s in seqs]
-        page_tables = [s.pages for s in seqs]
-        step0 = self._step_counter + 1
-        gamma = getattr(self.runner, "spec_gamma", 0)
-        use_draft_spec = getattr(self.runner, "has_draft", False)
-        if use_draft_spec and (
-            _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs)
-        ):
-            # the speculative verify distribution can't honor
-            # logprobs/penalties: warn once per offending request and
-            # fall back to the PLAIN decode path below, which does. The
-            # draft model's KV pools skip these positions — that costs
-            # draft acceptance on later iterations (verify still
-            # corrects every token), never correctness.
-            for s in seqs:
-                if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
-                    self._warn_spec_once(
-                        s.request_id,
-                        "logprobs/penalties are incompatible with "
-                        "speculative verification — falling back to "
-                        "non-speculative decode",
-                    )
-            use_draft_spec = False
+        with annotate("engine.prep"):
+            seqs = plan.seqs
+            T = plan.n_steps
+            tokens = [s.tokens[-1] for s in seqs]
+            positions = [s.computed_len for s in seqs]
+            page_tables = [s.pages for s in seqs]
+            step0 = self._step_counter + 1
+            gamma = getattr(self.runner, "spec_gamma", 0)
+            use_draft_spec = getattr(self.runner, "has_draft", False)
+            if use_draft_spec and (
+                _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs)
+            ):
+                # the speculative verify distribution can't honor
+                # logprobs/penalties: warn once per offending request and
+                # fall back to the PLAIN decode path below, which does. The
+                # draft model's KV pools skip these positions — that costs
+                # draft acceptance on later iterations (verify still
+                # corrects every token), never correctness.
+                for s in seqs:
+                    if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
+                        self._warn_spec_once(
+                            s.request_id,
+                            "logprobs/penalties are incompatible with "
+                            "speculative verification — falling back to "
+                            "non-speculative decode",
+                        )
+                use_draft_spec = False
         if use_draft_spec:
             # (guided requests were rejected at admission on draft workers,
             # so no mask handling is needed on this path)
@@ -2344,73 +2369,77 @@ class InferenceEngine:
                 R, tokens, positions, page_tables, _sampling_params(seqs), step0,
                 gamma=gamma, adapters=[s.adapter_idx for s in seqs],
             )
-            for i, seq in enumerate(seqs):
-                emit: List[int] = []
-                reason = None
-                for r in range(R):
-                    for j in range(int(counts[i, r])):
-                        token = int(toks[i, r, j])
-                        reason = self.scheduler.complete_decode(seq, token)
-                        if reason != "stop":
-                            emit.append(token)
+            with annotate("engine.emit"):
+                for i, seq in enumerate(seqs):
+                    emit: List[int] = []
+                    reason = None
+                    for r in range(R):
+                        for j in range(int(counts[i, r])):
+                            token = int(toks[i, r, j])
+                            reason = self.scheduler.complete_decode(seq, token)
+                            if reason != "stop":
+                                emit.append(token)
+                            if reason:
+                                break
                         if reason:
                             break
-                    if reason:
-                        break
-                self._emit(seq, emit, reason)
+                    self._emit(seq, emit, reason)
             return
-        masks = None
-        mask_fn = None
-        guided_dev = None
-        guided_rows = [i for i, s in enumerate(seqs) if s.guided_m is not None]
-        if guided_rows:
-            vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
-            if T > 1 and getattr(self.runner, "guided_fused", False):
-                # constrained rows need a fresh mask per sampled token.
-                # Preferred: the device-resident DFA plan — state advance
-                # and mask gather happen in-XLA inside the fused loop,
-                # ZERO host syncs per step. Fallback (schema over the
-                # device-table budget): a host callback that advances a
-                # COPY of each row's DFA state by the device-sampled
-                # feedback token between fused steps — guided rows still
-                # ride the full decode_steps loop either way, and both
-                # paths produce byte-identical masks on bounded schemas
-                # (pinned by tests/test_guided.py)
-                guided_dev = self._guided_device_plan(seqs)
-                if guided_dev is None:
-                    mask_fn = GuidedMaskContext(
-                        len(seqs), vocab,
-                        [(i, seqs[i].guided_m, seqs[i].guided_s)
-                         for i in guided_rows],
-                    )
-            else:
-                # runners without callback plumbing (PP loop) keep the
-                # legacy one-step masked dispatch
-                T = 1
-                masks = np.ones((len(seqs), vocab), bool)
-                for i in guided_rows:
-                    masks[i] = self._guided_mask(seqs[i])
-        biases = _batch_biases(seqs, self.runner)
-        self._step_counter += T
-        n_lp = _batch_logprobs(seqs)
-        histories = (
-            [list(s.tokens) for s in seqs] if _batch_penalties(seqs) else None
-        )
-        if (n_lp >= 0 or histories is not None) and getattr(
-            self.runner, "pp", False
-        ):
-            # the PP decode loop has no logprob/penalty wiring yet — drop
-            # the extras with a warning (same contract as spec decode
-            # above) instead of letting a raise inside the shared dispatch
-            # error EVERY sequence in the plan
-            for s in seqs:
-                if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
-                    self._warn_spec_once(
-                        s.request_id,
-                        "logprobs/penalties are unsupported on "
-                        "pipeline-parallel workers and were ignored",
-                    )
-            n_lp, histories = -1, None
+        with annotate("engine.prep"):
+            masks = None
+            mask_fn = None
+            guided_dev = None
+            guided_rows = [i for i, s in enumerate(seqs) if s.guided_m is not None]
+            if guided_rows:
+                vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
+                if T > 1 and getattr(self.runner, "guided_fused", False):
+                    # constrained rows need a fresh mask per sampled token.
+                    # Preferred: the device-resident DFA plan — state advance
+                    # and mask gather happen in-XLA inside the fused loop,
+                    # ZERO host syncs per step. Fallback (schema over the
+                    # device-table budget): a host callback that advances a
+                    # COPY of each row's DFA state by the device-sampled
+                    # feedback token between fused steps — guided rows still
+                    # ride the full decode_steps loop either way, and both
+                    # paths produce byte-identical masks on bounded schemas
+                    # (pinned by tests/test_guided.py)
+                    guided_dev = self._guided_device_plan(seqs)
+                    if guided_dev is None:
+                        mask_fn = GuidedMaskContext(
+                            len(seqs), vocab,
+                            [(i, seqs[i].guided_m, seqs[i].guided_s)
+                             for i in guided_rows],
+                        )
+                else:
+                    # runners without callback plumbing (PP loop) keep the
+                    # legacy one-step masked dispatch
+                    T = 1
+                    masks = np.ones((len(seqs), vocab), bool)
+                    for i in guided_rows:
+                        masks[i] = self._guided_mask(seqs[i])
+            biases = _batch_biases(seqs, self.runner)
+            self._step_counter += T
+            n_lp = _batch_logprobs(seqs)
+            histories = (
+                [list(s.tokens) for s in seqs] if _batch_penalties(seqs) else None
+            )
+            if (n_lp >= 0 or histories is not None) and getattr(
+                self.runner, "pp", False
+            ):
+                # the PP decode loop has no logprob/penalty wiring yet — drop
+                # the extras with a warning (same contract as spec decode
+                # above) instead of letting a raise inside the shared dispatch
+                # error EVERY sequence in the plan
+                for s in seqs:
+                    if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
+                        self._warn_spec_once(
+                            s.request_id,
+                            "logprobs/penalties are unsupported on "
+                            "pipeline-parallel workers and were ignored",
+                        )
+                n_lp, histories = -1, None
+            sp = _sampling_params(seqs)
+            adapters = [s.adapter_idx for s in seqs]
         lp = None
         if (n_lp >= 0 or histories is not None) and hasattr(
             self.runner, "decode_multi_ex"
@@ -2423,8 +2452,8 @@ class InferenceEngine:
             if biases is not None:
                 mkw["biases"] = biases
             sampled, lp = self.runner.decode_multi_ex(
-                T, tokens, positions, page_tables, _sampling_params(seqs), step0,
-                adapters=[s.adapter_idx for s in seqs],
+                T, tokens, positions, page_tables, sp, step0,
+                adapters=adapters,
                 n_logprobs=n_lp, histories=histories,
                 prompt_lens=[s.n_prompt0 for s in seqs],
                 **mkw,
@@ -2438,26 +2467,27 @@ class InferenceEngine:
             if biases is not None:
                 mkw["biases"] = biases
             sampled = self.runner.decode_multi(
-                T, tokens, positions, page_tables, _sampling_params(seqs), step0,
-                adapters=[s.adapter_idx for s in seqs],
+                T, tokens, positions, page_tables, sp, step0,
+                adapters=adapters,
                 **mkw,
             )
-        for i, seq in enumerate(seqs):
-            emit: List[int] = []
-            lp_entries: List[Dict[str, Any]] = []
-            reason = None
-            for j in range(T):
-                token = int(sampled[i, j])
-                reason = self.scheduler.complete_decode(seq, token)
-                if not reason:
-                    self._guided_advance(seq, token)
-                if reason != "stop":
-                    emit.append(token)
-                    if lp is not None and seq.sampling.get("logprobs") is not None:
-                        lp_entries.append(_lp_entry(lp, i, j, seq))
-                if reason:
-                    break
-            self._emit(seq, emit, reason, logprobs=lp_entries or None)
+        with annotate("engine.emit"):
+            for i, seq in enumerate(seqs):
+                emit: List[int] = []
+                lp_entries: List[Dict[str, Any]] = []
+                reason = None
+                for j in range(T):
+                    token = int(sampled[i, j])
+                    reason = self.scheduler.complete_decode(seq, token)
+                    if not reason:
+                        self._guided_advance(seq, token)
+                    if reason != "stop":
+                        emit.append(token)
+                        if lp is not None and seq.sampling.get("logprobs") is not None:
+                            lp_entries.append(_lp_entry(lp, i, j, seq))
+                    if reason:
+                        break
+                self._emit(seq, emit, reason, logprobs=lp_entries or None)
 
     def _guided_advance(self, seq: Sequence, token: int) -> None:
         """Advance a sequence's constraint DFA past an accepted token. A
@@ -2492,7 +2522,15 @@ class InferenceEngine:
             now = time.monotonic()
             if "ttft_s" not in seq.phases:
                 if seq.arrival:
-                    seq.phases["ttft_s"] = max(0.0, now - seq.arrival)
+                    ph = seq.phases
+                    ph["ttft_s"] = max(0.0, now - seq.arrival)
+                    # what admission did not explain: first admission to
+                    # the first emitted token (the prompt's chunks, the
+                    # iterations between them, a re-prefill after a
+                    # preemption), so that ttft_s = queue_wait_s +
+                    # kv_onboard_s + prefill_s by construction
+                    ph["prefill_s"] = max(0.0, ph["ttft_s"] - ph.get(
+                        "queue_wait_s", 0.0) - ph.get("kv_onboard_s", 0.0))
             elif seq.t_last_emit and len(seq.itl) < _ITL_CAP:
                 # a multi-token group (fused steps, accepted speculative
                 # drafts) contributes ONE ITL sample PER TOKEN — the step
@@ -2510,6 +2548,7 @@ class InferenceEngine:
             # final item carries the request's phase spine downstream
             # (loadgen/goodput aggregate it; the frontend adds span events)
             phases = dict(seq.phases)
+            phases["preemptions"] = seq.n_preemptions
             if seq.arrival:
                 phases["e2e_s"] = max(0.0, time.monotonic() - seq.arrival)
             if seq.itl:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import threading
 import time
 from collections import OrderedDict
 from functools import partial
@@ -32,6 +33,7 @@ from dynamo_tpu.engine.sampling import SamplingParams, sample
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
+from dynamo_tpu.runtime.annotations import annotate
 
 log = logging.getLogger("dynamo_tpu.engine.runner")
 
@@ -391,17 +393,73 @@ class _GuidedMaskTrampoline:
         return np.asarray(ctx(int(t), np.asarray(prev_tokens)), dtype=bool)
 
 
-class _CompiledFamily:
-    """Wraps one jitted step-function family to count distinct compiled
-    variants (jit cache growth) and the cumulative wall seconds of calls
-    that compiled (trace+lower+compile — the host-side stall each new
-    bucket costs). The ragged path's compile-cardinality collapse is
-    invisible without this; compile_stats() feeds the worker /metrics
-    gauges and the goodput report's extras["compile"]."""
+def _named(fn, name: Optional[str] = None):
+    """`fn` under a stable `__name__`, so that jax calls its program
+    `jit_<name>` in HLO dumps and on the device trace's module line. A
+    functools.partial has no name and traces as `jit__unknown`; the
+    default is the wrapped function's own name less its leading
+    underscores (`_decode_loop` -> `jit_decode_loop`). The name goes on a
+    fresh partial, never on the shared module-level function."""
+    p = partial(fn)
+    p.__name__ = p.__qualname__ = (
+        name or getattr(fn, "func", fn).__name__.lstrip("_")
+    )
+    return p
 
-    def __init__(self, name: str, fn):
+
+# -- compile accounting ------------------------------------------------------
+# What a thread is compiling for: `family` is the _CompiledFamily whose
+# call is on this thread's stack (it counts its own growth), `other` the
+# catch-all family of the runner this thread serves (name_step_thread).
+_compile_tls = threading.local()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+# fires once per program XLA is asked for, compiled or read back from the
+# persistent cache (jax 0.9.0: pxla._cached_compilation wraps
+# compile_or_get_cached in it); an in-memory jit cache hit fires nothing
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
+    """Every program no family saw, once, to the runner its thread
+    serves: the eager slice and index programs of the serving path. A
+    thread that named no runner (warm-up, tests, the asyncio side) is
+    charged to nobody, so N replicas in one process never count one
+    program N times."""
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    if getattr(_compile_tls, "family", None) is not None:
+        return
+    other = getattr(_compile_tls, "other", None)
+    if other is not None:
+        other.variants += 1
+        other.calls += 1
+        other.compile_s += duration_secs
+
+
+def _ensure_compile_listener() -> None:
+    """One process-wide listener, registered with the first runner."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if not _compile_listener_on:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _compile_listener_on = True
+
+
+class _CompiledFamily:
+    """One jitted step-function family. Jits `fn` under the function's own
+    name (see _named), counts distinct compiled variants (jit cache
+    growth) and the cumulative wall seconds of calls that compiled
+    (trace+lower+compile — the host-side stall each new bucket costs),
+    and marks each call `engine.dispatch` on the profiler's timeline.
+    compile_stats() feeds the worker's /metrics gauges and the
+    benchmark's `runner.compiles_in_window`. The catch-all family
+    `other` has no function: _on_compile_event counts into it."""
+
+    def __init__(self, name: str, fn=None, **jit_kwargs):
         self.name = name
-        self._fn = fn
+        self._fn = None if fn is None else jax.jit(_named(fn), **jit_kwargs)
         self.variants = 0
         self.compile_s = 0.0
         self.calls = 0
@@ -415,8 +473,14 @@ class _CompiledFamily:
     def __call__(self, *args, **kwargs):
         self.calls += 1
         before = self._cache_size()
+        outer = getattr(_compile_tls, "family", None)
+        _compile_tls.family = self
         t0 = time.monotonic()
-        out = self._fn(*args, **kwargs)
+        try:
+            with annotate("engine.dispatch", family=self.name):
+                out = self._fn(*args, **kwargs)
+        finally:
+            _compile_tls.family = outer
         after = self._cache_size()
         if before is not None and after is not None and after > before:
             self.variants += after - before
@@ -786,23 +850,30 @@ class ModelRunner:
         # seconds); see _CompiledFamily / compile_stats()
         self._families: Dict[str, _CompiledFamily] = {}
 
-        def _family(name, fn):
-            fam = _CompiledFamily(name, fn)
+        # programs no family calls (eager slices of step results, index
+        # math) compiled on the thread that serves this runner; not in
+        # _families, whose growth after warm-up the sanitizer calls a leak
+        self._other = _CompiledFamily("other")
+        _ensure_compile_listener()
+
+        def _family(name, fn, **jit_kwargs):
+            fam = _CompiledFamily(name, fn, **jit_kwargs)
             self._families[name] = fam
             return fam
 
-        self._jit_forward = _family("forward", jax.jit(
-            partial(llama.forward, self.config),
+        self._jit_forward = _family(
+            "forward", partial(llama.forward, self.config),
             donate_argnums=(3, 4),  # k_pool, v_pool
             static_argnames=("attn_impl", "mesh", "sp_has_prior"),
-        ))
+        )
         self._jit_sample = jax.jit(sample)
-        self._jit_decode_loop = _family("decode_loop", jax.jit(
+        self._jit_decode_loop = _family(
+            "decode_loop",
             partial(_decode_loop, self.config, self.attn_impl, self._fwd_mesh),
             static_argnums=(0, 1),  # n_steps, n_logprobs
             static_argnames=("mask_fn",),  # guided per-step mask callback
             donate_argnums=(8, 9),  # k_pool, v_pool
-        ))
+        )
         # one trampoline per runner: static-arg identity keys the jit
         # cache, so the guided-callback program compiles once per bucket
         self._mask_tramp = _GuidedMaskTrampoline()
@@ -840,27 +911,29 @@ class ModelRunner:
                 donate_argnums=(5, 6),  # k_pool, v_pool
             )
         if not self.pp:
-            self._jit_mixed = _family("mixed", jax.jit(
+            self._jit_mixed = _family(
+                "mixed",
                 partial(_mixed_loop, self.config, self.attn_impl,
                         self._fwd_mesh),
                 static_argnums=(0,),  # n_steps
                 donate_argnums=(10, 11),  # k_pool, v_pool
-            ))
-            self._jit_ragged = _family("ragged", jax.jit(
+            )
+            self._jit_ragged = _family(
+                "ragged",
                 partial(_ragged_step, self.config, self.attn_impl,
                         self._fwd_mesh),
                 donate_argnums=(9, 10),  # k_pool, v_pool
-            ))
+            )
         # device n-gram draft ring (_draft_ring_step): registered
         # UNCONDITIONALLY so spec-on and spec-off runners expose the same
         # family set (pinned by test_spec_decode); it compiles only when
         # the engine enables device drafting (ensure_draft_ring warms it
         # before the sanitizer's recompile-tripwire freeze)
-        self._jit_draft_ring = _family("draft", jax.jit(
-            _draft_ring_step,
+        self._jit_draft_ring = _family(
+            "draft", _draft_ring_step,
             static_argnums=(4, 5),  # k, max_match
             donate_argnums=(0, 1),  # hist, lens
-        ))
+        )
         self._draft_ring = None  # (hist_dev, lens_dev) once ensured
         self._draft_ring_host = None  # (np hist, np lens) mirror
         self._draft_ring_dirty = False  # mirror edited → restage
@@ -967,7 +1040,10 @@ class ModelRunner:
         `prior_len` is the context length already in the pool (prefix-cache
         hits + earlier chunks). `mm` injects multimodal embeddings at
         chunk-local offsets. Returns last-token logits [V] (device)."""
-        tok, pos, pt, kv_lens, n = self._prep_prefill(tokens, start_pos, page_table_row, prior_len)
+        with annotate("engine.stage"):
+            tok, pos, pt, kv_lens, n = self._prep_prefill(tokens, start_pos, page_table_row, prior_len)
+            if not self.pp:
+                mm_embeds, mm_mask = self._mm_arrays(mm, tok.shape[1])
         if self.pp:
             if mm is not None:
                 raise NotImplementedError(
@@ -979,7 +1055,6 @@ class ModelRunner:
             )
             return logits[0, n - 1]
         impl = "ring" if self.sp_enabled else self.attn_impl
-        mm_embeds, mm_mask = self._mm_arrays(mm, tok.shape[1])
         logits, self.k_pool, self.v_pool = self._jit_forward(
             self.params, tok, pos, self.k_pool, self.v_pool, pt, kv_lens,
             jnp.int32(n - 1), attn_impl=impl,
@@ -1109,7 +1184,7 @@ class ModelRunner:
             n_steps, tokens, positions, page_tables, sampling, step, adapters,
             masks=masks, biases=biases, mask_fn=mask_fn, guided_dev=guided_dev,
         )
-        with self._allow("token_readback"):
+        with self._allow("token_readback"), annotate("engine.readback"):
             return np.asarray(jax.device_get(toks))
 
     def decode_multi_ex(
@@ -1141,7 +1216,7 @@ class ModelRunner:
             n_logprobs=n_logprobs, histories=histories, prompt_lens=prompt_lens,
             masks=masks, biases=biases, mask_fn=mask_fn, guided_dev=guided_dev,
         )
-        with self._allow("token_readback"):
+        with self._allow("token_readback"), annotate("engine.readback"):
             if n_logprobs >= 0:
                 toks, _, lp = out
                 toks_h, lp_h = jax.device_get((toks, lp))
@@ -1182,90 +1257,89 @@ class ModelRunner:
         engine overlaps its bookkeeping the same way).
         With n_logprobs >= 0 the return grows to (toks, last, lp) — see
         decode_multi_ex."""
-        n = len(positions)
-        B = _next_bucket(self.decode_buckets, n)
-        pt = self._pad_page_table(page_tables, B)
-        MP = pt.shape[1]
-        # one packed transfer for all per-dispatch ints (see _decode_loop)
-        packed = np.zeros(B * (1 + MP) + (B if self.lora is not None else 0) + 1,
-                          np.int32)
-        packed[:B] = -1
-        packed[:n] = positions
-        packed[B : B + B * MP] = pt.ravel()
-        if self.lora is not None and adapters:
-            packed[B + B * MP : B + B * MP + len(adapters)] = adapters
-        packed[-1] = step
+        with annotate("engine.stage"):
+            n = len(positions)
+            B = _next_bucket(self.decode_buckets, n)
+            pt = self._pad_page_table(page_tables, B)
+            MP = pt.shape[1]
+            # one packed transfer for all per-dispatch ints (see _decode_loop)
+            packed = np.zeros(B * (1 + MP) + (B if self.lora is not None else 0) + 1,
+                              np.int32)
+            packed[:B] = -1
+            packed[:n] = positions
+            packed[B : B + B * MP] = pt.ravel()
+            if self.lora is not None and adapters:
+                packed[B + B * MP : B + B * MP + len(adapters)] = adapters
+            packed[-1] = step
 
-        if isinstance(tokens, jax.Array):
-            if tokens.shape[0] != B:
-                raise ValueError(
-                    f"chained token array has batch {tokens.shape[0]}, "
-                    f"dispatch bucket is {B} — chaining requires a stable "
-                    "bucket (sync to host when the batch re-buckets)"
-                )
-            tok = tokens  # pass through untouched: no eager slice programs
-        else:
-            tok_h = np.zeros(B, np.int32)
-            tok_h[:n] = tokens
-            with self._allow("decode_staging"):
-                tok = jnp.asarray(tok_h)
+            if isinstance(tokens, jax.Array):
+                if tokens.shape[0] != B:
+                    raise ValueError(
+                        f"chained token array has batch {tokens.shape[0]}, "
+                        f"dispatch bucket is {B} — chaining requires a stable "
+                        "bucket (sync to host when the batch re-buckets)"
+                    )
+                tok = tokens  # pass through untouched: no eager slice programs
+            else:
+                tok_h = np.zeros(B, np.int32)
+                tok_h[:n] = tokens
+                with self._allow("decode_staging"):
+                    tok = jnp.asarray(tok_h)
 
-        hist = None
-        if histories is not None:
-            # bucketed so history growth re-compiles per bucket, not per
-            # token; pad token == vocab_size scatters drop in _decode_loop
-            H = max(8, max((len(h) for h in histories), default=1))
-            H = -(-H // 128) * 128
-            hist_h = np.full((B, H), self.config.vocab_size, np.int32)
-            plen_h = np.zeros(B, np.int32)
-            for i, h in enumerate(histories):
-                hist_h[i, : len(h)] = h
-                plen_h[i] = (
-                    prompt_lens[i] if prompt_lens is not None else len(h)
-                )
-            with self._allow("decode_staging"):
-                hist = (jnp.asarray(hist_h), jnp.asarray(plen_h))
+            hist = None
+            if histories is not None:
+                # bucketed so history growth re-compiles per bucket, not per
+                # token; pad token == vocab_size scatters drop in _decode_loop
+                H = max(8, max((len(h) for h in histories), default=1))
+                H = -(-H // 128) * 128
+                hist_h = np.full((B, H), self.config.vocab_size, np.int32)
+                plen_h = np.zeros(B, np.int32)
+                for i, h in enumerate(histories):
+                    hist_h[i, : len(h)] = h
+                    plen_h[i] = (
+                        prompt_lens[i] if prompt_lens is not None else len(h)
+                    )
+                with self._allow("decode_staging"):
+                    hist = (jnp.asarray(hist_h), jnp.asarray(plen_h))
 
-        mask_dev = None
-        if masks is not None:
-            m = np.ones((B, self.config.vocab_size), bool)
-            m[: masks.shape[0]] = masks  # pad rows stay all-allowed
-            with self._allow("decode_staging"):
-                mask_dev = jnp.asarray(m)
+            mask_dev = None
+            if masks is not None:
+                m = np.ones((B, self.config.vocab_size), bool)
+                m[: masks.shape[0]] = masks  # pad rows stay all-allowed
+                with self._allow("decode_staging"):
+                    mask_dev = jnp.asarray(m)
 
-        if self.pp:
-            if n_logprobs >= 0 or hist is not None or biases is not None \
-                    or mask_fn is not None or guided_dev is not None:
+            if self.pp and (
+                    n_logprobs >= 0 or hist is not None or biases is not None
+                    or mask_fn is not None or guided_dev is not None):
                 raise NotImplementedError(
                     "logprobs/penalties/logit_bias/multi-step guided masks "
                     "are not wired on the pipeline-parallel decode path yet"
                 )
+
+            bias_dev = None
+            if biases is not None:
+                bz = np.zeros((B, self.config.vocab_size), np.float32)
+                bz[: biases.shape[0]] = biases  # pad rows stay unbiased
+                with self._allow("decode_staging"):
+                    bias_dev = jnp.asarray(bz)
+
+            mkw = {}
+            if mask_fn is not None:
+                mask_fn.B = B  # callback mask rows must match the padded bucket
+                self.set_guided_ctx(mask_fn)
+                mkw["mask_fn"] = self._mask_tramp
+            elif guided_dev is not None:
+                mkw["guided"] = self._guided_op(guided_dev, B)
             with self._allow("decode_staging"):
                 packed_dev = jnp.asarray(packed)
                 samp = self._device_sampling(sampling, B)
+        if self.pp:
             toks, last, self.k_pool, self.v_pool = self._jit_pp_decode(
                 n_steps, self.params, tok, packed_dev, mask_dev,
                 self.k_pool, self.v_pool, samp,
             )
             return toks, last
-
-        bias_dev = None
-        if biases is not None:
-            bz = np.zeros((B, self.config.vocab_size), np.float32)
-            bz[: biases.shape[0]] = biases  # pad rows stay unbiased
-            with self._allow("decode_staging"):
-                bias_dev = jnp.asarray(bz)
-
-        mkw = {}
-        if mask_fn is not None:
-            mask_fn.B = B  # callback mask rows must match the padded bucket
-            self.set_guided_ctx(mask_fn)
-            mkw["mask_fn"] = self._mask_tramp
-        elif guided_dev is not None:
-            mkw["guided"] = self._guided_op(guided_dev, B)
-        with self._allow("decode_staging"):
-            packed_dev = jnp.asarray(packed)
-            samp = self._device_sampling(sampling, B)
         toks, last, lp, self.k_pool, self.v_pool = self._jit_decode_loop(
             n_steps, n_logprobs, self.params, tok, packed_dev, hist,
             mask_dev, bias_dev, self.k_pool, self.v_pool,
@@ -1328,9 +1402,30 @@ class ModelRunner:
             raise NotImplementedError(
                 "guided masks / logit bias require the ragged mixed path"
             )
-        ptok, ppos, ppt, pkvl, n = self._prep_prefill(
-            chunk_tokens, chunk_start, chunk_table, chunk_prior
+        with annotate("engine.stage"):
+            ptok, ppos, ppt, pkvl, n = self._prep_prefill(
+                chunk_tokens, chunk_start, chunk_table, chunk_prior
+            )
+            plast = jnp.int32(n - 1)
+            padapter = (
+                jnp.asarray([chunk_adapter], jnp.int32)
+                if self.lora is not None else None
+            )
+            tok_dev, packed_dev, samp = self._stage_padded_decode_half(
+                tokens, positions, page_tables, sampling, step, adapters)
+        toks, _, chunk_logits, self.k_pool, self.v_pool = self._jit_mixed(
+            n_steps, self.params, ptok, ppos, ppt, pkvl, plast,
+            padapter, tok_dev, packed_dev,
+            self.k_pool, self.v_pool, samp, self.lora,
         )
+        with annotate("engine.readback"):
+            return np.asarray(jax.device_get(toks)), chunk_logits
+
+    def _stage_padded_decode_half(self, tokens, positions, page_tables,
+                                  sampling, step, adapters):
+        """Device inputs of the decode half of a padded mixed dispatch
+        (_mixed_loop): (tokens [B], packed pos|pt|adapters|step, sampling
+        params), all at the decode bucket."""
         B = _next_bucket(self.decode_buckets, len(positions))
         pt = self._pad_page_table(page_tables, B)
         MP = pt.shape[1]
@@ -1345,17 +1440,8 @@ class ModelRunner:
         packed[-1] = step
         tok_h = np.zeros(B, np.int32)
         tok_h[: len(positions)] = tokens
-        padapter = (
-            jnp.asarray([chunk_adapter], jnp.int32)
-            if self.lora is not None else None
-        )
-        toks, _, chunk_logits, self.k_pool, self.v_pool = self._jit_mixed(
-            n_steps, self.params, ptok, ppos, ppt, pkvl, jnp.int32(n - 1),
-            padapter, jnp.asarray(tok_h), jnp.asarray(packed),
-            self.k_pool, self.v_pool, self._device_sampling(sampling, B),
-            self.lora,
-        )
-        return np.asarray(jax.device_get(toks)), chunk_logits
+        return (jnp.asarray(tok_h), jnp.asarray(packed),
+                self._device_sampling(sampling, B))
 
     def _prep_prefill_packed(self, chunks: List[Dict[str, Any]]):
         """Bucket-pad a packed chunk set into ragged [N, S] device inputs,
@@ -1439,30 +1525,18 @@ class ModelRunner:
                 "guided masks / logit bias require the ragged mixed path "
                 "(the engine's _mixed_fusible gates on it)"
             )
-        ptok, ppos, ppt, pkvl, plast, padapter = self._prep_prefill_packed(
-            chunks
-        )
-        B = _next_bucket(self.decode_buckets, len(positions))
-        pt = self._pad_page_table(page_tables, B)
-        MP = pt.shape[1]
-        packed = np.zeros(
-            B * (1 + MP) + (B if self.lora is not None else 0) + 1, np.int32
-        )
-        packed[:B] = -1
-        packed[: len(positions)] = positions
-        packed[B : B + B * MP] = pt.ravel()
-        if self.lora is not None and adapters:
-            packed[B + B * MP : B + B * MP + len(adapters)] = adapters
-        packed[-1] = step
-        tok_h = np.zeros(B, np.int32)
-        tok_h[: len(positions)] = tokens
+        with annotate("engine.stage"):
+            ptok, ppos, ppt, pkvl, plast, padapter = (
+                self._prep_prefill_packed(chunks))
+            tok_dev, packed_dev, samp = self._stage_padded_decode_half(
+                tokens, positions, page_tables, sampling, step, adapters)
         toks, _, chunk_logits, self.k_pool, self.v_pool = self._jit_mixed(
             n_steps, self.params, ptok, ppos, ppt, pkvl, plast,
-            padapter, jnp.asarray(tok_h), jnp.asarray(packed),
-            self.k_pool, self.v_pool, self._device_sampling(sampling, B),
-            self.lora,
+            padapter, tok_dev, packed_dev,
+            self.k_pool, self.v_pool, samp, self.lora,
         )
-        return np.asarray(jax.device_get(toks)), chunk_logits
+        with annotate("engine.readback"):
+            return np.asarray(jax.device_get(toks)), chunk_logits
 
     # -- guided sampling masks --------------------------------------------
     def _true_mask(self, rows: int) -> jax.Array:
@@ -1669,59 +1743,68 @@ class ModelRunner:
         Returns the same (sampled [B_bucket, n_steps] host, chunk logits
         [N, V] device) contract as decode_multi_with_prefills."""
         n_dec = len(positions)
-        (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
-         seg_cap) = self._prep_ragged(tokens, positions, page_tables, chunks)
-        row_seq, row_j = self._identity_rows(seg_cap)
+        with annotate("engine.stage"):
+            (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
+             seg_cap) = self._prep_ragged(tokens, positions, page_tables, chunks)
+            row_seq, row_j = self._identity_rows(seg_cap)
+            samp = self._device_sampling(sampling, seg_cap)
+            step_dev = jnp.int32(step)
+            seg_mask = self._seg_mask(masks, seg_cap)
+            seg_bias = self._seg_bias(biases, seg_cap)
         sampled, seg_logits, self.k_pool, self.v_pool = self._jit_ragged(
             self.params, ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl,
             meta, gather, self.k_pool, self.v_pool,
-            self._device_sampling(sampling, seg_cap), row_seq, row_j,
-            jnp.int32(step),
-            self._seg_mask(masks, seg_cap),
-            self._seg_bias(biases, seg_cap),
+            samp, row_seq, row_j, step_dev, seg_mask, seg_bias,
         )
         B = _next_bucket(self.decode_buckets, n_dec)
+        # both slices are eager programs enqueued behind the ragged step,
+        # before the host blocks: the device never waits for them
         tok0 = sampled[:B]  # decode rows lead the segment order
+        chunk_logits = seg_logits[n_dec : n_dec + len(chunks)]  # [N, V]
         if n_steps > 1:
-            pt = self._pad_page_table(page_tables, B)
-            MP = pt.shape[1]
-            packed = np.zeros(B * (1 + MP) + 1, np.int32)
-            packed[:B] = -1
-            packed[:n_dec] = [p + 1 for p in positions]
-            packed[B : B + B * MP] = pt.ravel()
-            packed[-1] = step + 1
-            mkw = {}
-            if mask_fn is not None:
-                # guided rows continue through the fused tail: the host
-                # callback advances each DFA copy by tok0 (still device-
-                # resident here) before masking inner step 0
-                mask_fn.B = B
-                self.set_guided_ctx(mask_fn)
-                mkw["mask_fn"] = self._mask_tramp
-            elif guided_dev is not None:
-                g_tables, g_rows, _ = guided_dev
-                mkw["guided"] = self._guided_op((g_tables, g_rows, True), B)
-            bias_dev = None
-            if biases is not None:
-                bz = np.zeros((B, self.config.vocab_size), np.float32)
-                bz[: biases.shape[0]] = biases
-                bias_dev = jnp.asarray(bz)
+            with annotate("engine.stage"):
+                pt = self._pad_page_table(page_tables, B)
+                MP = pt.shape[1]
+                packed = np.zeros(B * (1 + MP) + 1, np.int32)
+                packed[:B] = -1
+                packed[:n_dec] = [p + 1 for p in positions]
+                packed[B : B + B * MP] = pt.ravel()
+                packed[-1] = step + 1
+                mkw = {}
+                if mask_fn is not None:
+                    # guided rows continue through the fused tail: the host
+                    # callback advances each DFA copy by tok0 (still device-
+                    # resident here) before masking inner step 0
+                    mask_fn.B = B
+                    self.set_guided_ctx(mask_fn)
+                    mkw["mask_fn"] = self._mask_tramp
+                elif guided_dev is not None:
+                    g_tables, g_rows, _ = guided_dev
+                    mkw["guided"] = self._guided_op((g_tables, g_rows, True), B)
+                bias_dev = None
+                if biases is not None:
+                    bz = np.zeros((B, self.config.vocab_size), np.float32)
+                    bz[: biases.shape[0]] = biases
+                    bias_dev = jnp.asarray(bz)
+                packed_dev = jnp.asarray(packed)
+                samp = self._device_sampling(sampling, B)
             # n_steps is the scheduler's fixed multi-step count, so
             # n_steps-1 adds exactly ONE decode_loop variant alongside the
             # legacy path's n_steps — bounded by design (ragged two-
             # dispatch split, docs/ragged_attention.md)
             rest, _, _, self.k_pool, self.v_pool = self._jit_decode_loop(  # dynlint: disable=DYN-J004
-                n_steps - 1, -1, self.params, tok0, jnp.asarray(packed),
+                n_steps - 1, -1, self.params, tok0, packed_dev,
                 None, None, bias_dev, self.k_pool, self.v_pool,
-                self._device_sampling(sampling, B), None, **mkw,
+                samp, None, **mkw,
             )
-            tok0_h, rest_h = jax.device_get((tok0, rest))
+            with annotate("engine.readback"):
+                tok0_h, rest_h = jax.device_get((tok0, rest))
             toks = np.concatenate(
                 [np.asarray(tok0_h)[:, None], np.asarray(rest_h)], axis=1
             )
         else:
-            toks = np.asarray(jax.device_get(tok0))[:, None]
-        chunk_logits = seg_logits[n_dec : n_dec + len(chunks)]  # [N, V]
+            with annotate("engine.readback"):
+                toks = np.asarray(jax.device_get(tok0))[:, None]
         return toks, chunk_logits
 
     def verify_spec(
@@ -1773,105 +1856,106 @@ class ModelRunner:
             RAGGED_MAX_SEGS, build_ragged_metadata, ragged_seg_cap,
         )
 
-        chunks = list(chunks)
-        n_rows = len(positions)
-        row_lens = [len(d) + 1 for d in drafts]
-        q_lens = row_lens + [len(c["tokens"]) for c in chunks]
-        q_starts = list(positions) + [c["start"] for c in chunks]
-        kv_lens = [p + ln for p, ln in zip(positions, row_lens)] + [
-            c["prior"] + len(c["tokens"]) for c in chunks
-        ]
-        rows = list(page_tables) + [c["table"] for c in chunks]
-        n_seg = len(q_lens)
-        t_real = sum(q_lens)
-        t_bucket = _next_bucket(self.ragged_buckets, t_real)
-        seg_cap = ragged_seg_cap(t_bucket)
-        entries = sum(row_lens) + len(chunks)
-        if n_seg > RAGGED_MAX_SEGS or entries > seg_cap:
-            raise BucketOverflowError(max(n_seg, entries), (seg_cap,))
-        md = build_ragged_metadata(
-            q_lens, q_starts, kv_lens, rows, t_bucket,
-            q_block=self.ragged_q_block, max_pages=self.max_pages_per_seq,
-        )
-        flat = np.zeros(t_bucket, np.int32)
-        off = 0
-        for tok, d in zip(tokens, drafts):
-            flat[off] = tok
-            flat[off + 1 : off + 1 + len(d)] = d
-            off += len(d) + 1
-        for c in chunks:
-            flat[off : off + len(c["tokens"])] = c["tokens"]
-            off += len(c["tokens"])
-        cu = md["cu_q_lens"]
-        gather = np.zeros(seg_cap, np.int32)
-        w = 0
-        for i in range(n_rows):
-            gather[w : w + row_lens[i]] = np.arange(cu[i], cu[i + 1])
-            w += row_lens[i]
-        chunk_entry0 = w
-        for s in range(n_rows, n_seg):
-            gather[w] = cu[s + 1] - 1
-            w += 1
-        # per-entry sampling expansion happens IN-XLA (_ragged_step's
-        # row_seq/row_j gather+seed-fold): the staged base is the per-
-        # SEQUENCE params — stable across verify iterations, so
-        # _device_sampling cache-hits instead of rebuilding + re-staging
-        # a fresh per-entry expansion every dispatch. Chunk (and pad)
-        # entries point at a padding base row: greedy, seed 0 — exactly
-        # the params the host expansion gave them.
-        row_seq = np.zeros(seg_cap, np.int32)
-        row_j = np.zeros(seg_cap, np.int32)
-        w2 = 0
-        for i in range(n_rows):
-            row_seq[w2 : w2 + row_lens[i]] = i
-            row_j[w2 : w2 + row_lens[i]] = np.arange(row_lens[i])
-            w2 += row_lens[i]
-        # chunk entries (and trailing pad rows) sample with padding
-        # params; n_rows < seg_cap whenever chunk entries exist (entries
-        # = sum(row_lens) + len(chunks) <= seg_cap and row_lens >= 1)
-        row_seq[w2:] = min(n_rows, seg_cap - 1)
-        row_masks = None
-        if masks:
-            # guided rows ride the verify dispatch as draft-less q_len=1
-            # segments (per-sequence speculation pause): mask only their
-            # verify position, every other entry stays all-allowed
-            row_masks = np.ones(
-                (sum(row_lens), self.config.vocab_size), bool
+        with annotate("engine.stage"):
+            chunks = list(chunks)
+            n_rows = len(positions)
+            row_lens = [len(d) + 1 for d in drafts]
+            q_lens = row_lens + [len(c["tokens"]) for c in chunks]
+            q_starts = list(positions) + [c["start"] for c in chunks]
+            kv_lens = [p + ln for p, ln in zip(positions, row_lens)] + [
+                c["prior"] + len(c["tokens"]) for c in chunks
+            ]
+            rows = list(page_tables) + [c["table"] for c in chunks]
+            n_seg = len(q_lens)
+            t_real = sum(q_lens)
+            t_bucket = _next_bucket(self.ragged_buckets, t_real)
+            seg_cap = ragged_seg_cap(t_bucket)
+            entries = sum(row_lens) + len(chunks)
+            if n_seg > RAGGED_MAX_SEGS or entries > seg_cap:
+                raise BucketOverflowError(max(n_seg, entries), (seg_cap,))
+            md = build_ragged_metadata(
+                q_lens, q_starts, kv_lens, rows, t_bucket,
+                q_block=self.ragged_q_block, max_pages=self.max_pages_per_seq,
             )
-            offs = np.concatenate([[0], np.cumsum(row_lens)])
-            for i, m in masks.items():
-                row_masks[offs[i]] = m
-        row_biases = None
-        if biases:
-            row_biases = np.zeros(
-                (sum(row_lens), self.config.vocab_size), np.float32
-            )
-            offs = np.concatenate([[0], np.cumsum(row_lens)])
-            for i, b in biases.items():
-                row_biases[offs[i]] = b
-        with self._allow("verify_staging"):
-            staged = (
-                jnp.asarray(flat[None]),
-                jnp.asarray(md["tok_positions"])[None],
-                jnp.asarray(md["tok_page_table"]),
-                jnp.asarray(md["tok_kv_lens"]),
-                jnp.asarray(md["seg_page_table"]),
-                jnp.asarray(md["seg_kv_lens"]),
-                jnp.asarray(md["meta"]),
-                jnp.asarray(gather),
-            )
-            samp = self._device_sampling(sampling, seg_cap)
-            row_seq_d = jnp.asarray(row_seq)
-            row_j_d = jnp.asarray(row_j)
-            step_d = jnp.int32(step)
-            seg_mask = self._seg_mask(row_masks, seg_cap)
-            seg_bias = self._seg_bias(row_biases, seg_cap)
+            flat = np.zeros(t_bucket, np.int32)
+            off = 0
+            for tok, d in zip(tokens, drafts):
+                flat[off] = tok
+                flat[off + 1 : off + 1 + len(d)] = d
+                off += len(d) + 1
+            for c in chunks:
+                flat[off : off + len(c["tokens"])] = c["tokens"]
+                off += len(c["tokens"])
+            cu = md["cu_q_lens"]
+            gather = np.zeros(seg_cap, np.int32)
+            w = 0
+            for i in range(n_rows):
+                gather[w : w + row_lens[i]] = np.arange(cu[i], cu[i + 1])
+                w += row_lens[i]
+            chunk_entry0 = w
+            for s in range(n_rows, n_seg):
+                gather[w] = cu[s + 1] - 1
+                w += 1
+            # per-entry sampling expansion happens IN-XLA (_ragged_step's
+            # row_seq/row_j gather+seed-fold): the staged base is the per-
+            # SEQUENCE params — stable across verify iterations, so
+            # _device_sampling cache-hits instead of rebuilding + re-staging
+            # a fresh per-entry expansion every dispatch. Chunk (and pad)
+            # entries point at a padding base row: greedy, seed 0 — exactly
+            # the params the host expansion gave them.
+            row_seq = np.zeros(seg_cap, np.int32)
+            row_j = np.zeros(seg_cap, np.int32)
+            w2 = 0
+            for i in range(n_rows):
+                row_seq[w2 : w2 + row_lens[i]] = i
+                row_j[w2 : w2 + row_lens[i]] = np.arange(row_lens[i])
+                w2 += row_lens[i]
+            # chunk entries (and trailing pad rows) sample with padding
+            # params; n_rows < seg_cap whenever chunk entries exist (entries
+            # = sum(row_lens) + len(chunks) <= seg_cap and row_lens >= 1)
+            row_seq[w2:] = min(n_rows, seg_cap - 1)
+            row_masks = None
+            if masks:
+                # guided rows ride the verify dispatch as draft-less q_len=1
+                # segments (per-sequence speculation pause): mask only their
+                # verify position, every other entry stays all-allowed
+                row_masks = np.ones(
+                    (sum(row_lens), self.config.vocab_size), bool
+                )
+                offs = np.concatenate([[0], np.cumsum(row_lens)])
+                for i, m in masks.items():
+                    row_masks[offs[i]] = m
+            row_biases = None
+            if biases:
+                row_biases = np.zeros(
+                    (sum(row_lens), self.config.vocab_size), np.float32
+                )
+                offs = np.concatenate([[0], np.cumsum(row_lens)])
+                for i, b in biases.items():
+                    row_biases[offs[i]] = b
+            with self._allow("verify_staging"):
+                staged = (
+                    jnp.asarray(flat[None]),
+                    jnp.asarray(md["tok_positions"])[None],
+                    jnp.asarray(md["tok_page_table"]),
+                    jnp.asarray(md["tok_kv_lens"]),
+                    jnp.asarray(md["seg_page_table"]),
+                    jnp.asarray(md["seg_kv_lens"]),
+                    jnp.asarray(md["meta"]),
+                    jnp.asarray(gather),
+                )
+                samp = self._device_sampling(sampling, seg_cap)
+                row_seq_d = jnp.asarray(row_seq)
+                row_j_d = jnp.asarray(row_j)
+                step_d = jnp.int32(step)
+                seg_mask = self._seg_mask(row_masks, seg_cap)
+                seg_bias = self._seg_bias(row_biases, seg_cap)
         sampled, seg_logits, self.k_pool, self.v_pool = self._jit_ragged(
             self.params, *staged,
             self.k_pool, self.v_pool,
             samp, row_seq_d, row_j_d, step_d, seg_mask, seg_bias,
         )
-        with self._allow("token_readback"):
+        with self._allow("token_readback"), annotate("engine.readback"):
             sampled_h = np.asarray(jax.device_get(sampled))  # one bulk sync
         out: List[np.ndarray] = []
         w = 0
@@ -1977,12 +2061,20 @@ class ModelRunner:
         return np.asarray(d_h), np.asarray(n_h)
 
     def compile_stats(self) -> Dict[str, Dict[str, Any]]:
-        """Per step-function family: compiled-variant count, cumulative
-        compile seconds, call count. Ships as worker gauges
-        (worker_common) and the goodput report's extras["compile"] so
-        the ragged path's cache-cardinality collapse is a CI artifact,
-        not a claim."""
-        return {name: fam.stats() for name, fam in self._families.items()}
+        """Per step-function family, and `other` for every program no
+        family saw: compiled-variant count, cumulative compile seconds,
+        call count. Ships as worker gauges (worker_common) and is what
+        the benchmark subtracts over its window, so a compile after
+        warm-up is a count, not a guess."""
+        out = {name: fam.stats() for name, fam in self._families.items()}
+        out["other"] = self._other.stats()
+        return out
+
+    def name_step_thread(self) -> None:
+        """The calling thread serves this runner from here on (the
+        engine's step thread, once, at the start of its loop): a program
+        compiled on it outside any family counts in `other`."""
+        _compile_tls.other = self._other
 
     def _device_sampling(self, sampling, B: int) -> SamplingParams:
         """Device-resident cache of padded sampling params. Batches resend
@@ -2113,7 +2205,7 @@ class ModelRunner:
                 pt_d, samp, step_d, self.lora, adapt_d,
             )
         )
-        with self._allow("token_readback"):
+        with self._allow("token_readback"), annotate("engine.readback"):
             toks_h, counts_h = jax.device_get((toks, counts))
         return np.asarray(toks_h), np.asarray(counts_h)
 
@@ -2164,7 +2256,7 @@ class ModelRunner:
         (tok_lp, top_ids list, top_lps list) for the sampled position."""
         if not hasattr(self, "_jit_sample_one_ex"):
             self._jit_sample_one_ex = jax.jit(
-                partial(_sample_one_ex, self.config.vocab_size),
+                _named(partial(_sample_one_ex, self.config.vocab_size)),
                 static_argnums=(0,),
             )
         hist = None
@@ -2292,7 +2384,8 @@ class ModelRunner:
                         )
                     return p.at[:, d].set(p[:, s])
                 return one(kp), one(vp)
-            self._jit_copy_page = jax.jit(_cp, donate_argnums=(0, 1))
+            self._jit_copy_page = jax.jit(
+                _named(_cp, "copy_page"), donate_argnums=(0, 1))
         self.k_pool, self.v_pool = self._jit_copy_page(
             self.k_pool, self.v_pool, src, dst
         )

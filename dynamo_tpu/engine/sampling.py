@@ -144,16 +144,17 @@ def sample(
     caller guarantees every live row keeps at least one allowed token.
     `bias` [B, V] f32 (OpenAI logit_bias) adds to the logits before
     filtering — ±100 effectively forces/bans per the OpenAI contract."""
-    if bias is not None:
-        logits = logits + bias
-    if mask is not None:
-        logits = jnp.where(mask, logits, -1e30)
-    idx, scaled = _filtered_scaled(logits, params)
+    with jax.named_scope("sample"):  # HLO metadata only
+        if bias is not None:
+            logits = logits + bias
+        if mask is not None:
+            logits = jnp.where(mask, logits, -1e30)
+        idx, scaled = _filtered_scaled(logits, params)
 
-    def draw(key_data, row):
-        key = jax.random.wrap_key_data(key_data, impl="threefry2x32")
-        return jax.random.categorical(jax.random.fold_in(key, step), row)
+        def draw(key_data, row):
+            key = jax.random.wrap_key_data(key_data, impl="threefry2x32")
+            return jax.random.categorical(jax.random.fold_in(key, step), row)
 
-    choice = jax.vmap(draw)(params.key, scaled).astype(jnp.int32)
-    pick = jnp.where(params.temperature <= 0.0, 0, choice)  # idx 0 = argmax
-    return jnp.take_along_axis(idx, pick[:, None], axis=1)[:, 0].astype(jnp.int32)
+        choice = jax.vmap(draw)(params.key, scaled).astype(jnp.int32)
+        pick = jnp.where(params.temperature <= 0.0, 0, choice)  # idx 0 = argmax
+        return jnp.take_along_axis(idx, pick[:, None], axis=1)[:, 0].astype(jnp.int32)

@@ -497,6 +497,9 @@ class Scheduler:
     def complete_prefill(self, plan: PrefillPlan) -> None:
         seq = plan.seq
         seq.computed_len += len(plan.chunk)
+        # latency spine: iterations in which a chunk of this prompt ran
+        # (a count, not a duration: no `_s`)
+        seq.phases["prefill_iters"] = seq.phases.get("prefill_iters", 0) + 1
         self._register_complete_pages(seq)
         if plan.is_last_chunk:
             seq.state = SeqState.RUNNING

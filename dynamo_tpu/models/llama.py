@@ -322,54 +322,61 @@ def forward(
             z = jnp.einsum("bsi,bir->bsr", x, Ag)
             return y + jnp.einsum("bsr,bro->bso", z, Bg)
 
+        # named scopes mark the parts of a layer in HLO metadata (an HLO
+        # dump and xprof then say which part a fusion belongs to); they
+        # change no computation
         if c.is_mla:
-            attn, k_pool = _mla_attention(
-                c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
-                kv_lens, attn_impl=attn_impl, mesh=mesh,
-                q_start=q_start, q_len=q_len,
-            )
-            h = h + mm(attn, lp["wo"])
-            x = rms_norm(h, lp["mlp_norm"], c.norm_eps)
-            if use_moe:
-                h = h + _moe_block(c, lp, x, mesh)
-            else:
-                gate = jax.nn.silu(mm(x, lp["w_gate"]))
-                h = h + mm(gate * mm(x, lp["w_up"]), lp["w_down"])
+            with jax.named_scope("attn.kernel"):
+                attn, k_pool = _mla_attention(
+                    c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
+                    kv_lens, attn_impl=attn_impl, mesh=mesh,
+                    q_start=q_start, q_len=q_len,
+                )
+            with jax.named_scope("attn.proj"):
+                h = h + mm(attn, lp["wo"])
+            with jax.named_scope("ffn"):
+                x = rms_norm(h, lp["mlp_norm"], c.norm_eps)
+                if use_moe:
+                    h = h + _moe_block(c, lp, x, mesh)
+                else:
+                    gate = jax.nn.silu(mm(x, lp["w_gate"]))
+                    h = h + mm(gate * mm(x, lp["w_up"]), lp["w_down"])
             return (h, k_pool, v_pool), None
 
         zc = c.norm_zero_centered
-        # OLMo-2 (pre_norms=False): the sublayer reads the raw residual
-        x = (rms_norm(h, lp["attn_norm"], c.norm_eps, zero_centered=zc)
-             if c.pre_norms else h)
-        q = lproj(mm(x, lp["wq"]), x, "wq")
-        k = lproj(mm(x, lp["wk"]), x, "wk")
-        v = lproj(mm(x, lp["wv"]), x, "wv")
-        if c.attn_bias:  # Qwen2 projection biases
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        if c.qk_norm and c.qk_norm_wide:
-            # OLMo-2: RMS statistics over the FULL projection width,
-            # before the head reshape (per-head norm is a different op)
-            q = rms_norm(q, lp["q_norm"], c.norm_eps, zero_centered=zc)
-            k = rms_norm(k, lp["k_norm"], c.norm_eps, zero_centered=zc)
-        q = q.reshape(B, S, c.n_heads, hd)
-        k = k.reshape(B, S, c.n_kv_heads, hd)
-        v = v.reshape(B, S, c.n_kv_heads, hd)
-        if c.qk_norm and not c.qk_norm_wide:
-            # Qwen3/Gemma-3 per-head RMSNorm before RoPE
-            q = rms_norm(q, lp["q_norm"], c.norm_eps, zero_centered=zc)
-            k = rms_norm(k, lp["k_norm"], c.norm_eps, zero_centered=zc)
-        if c.rope_local_theta:
-            # Gemma-3 dual rope: sliding layers rotate with the local
-            # base, global layers with rope_theta (+ its scaling). Both
-            # tables are static; the per-layer pick is one [hd/2] select
-            # riding the scan — still one compiled body.
-            is_global = (l_idx % c.sw_period) == c.sw_global_residue
-            iv = jnp.where(is_global, rope_if_global, rope_if_local)
-            q = rope(q, safe_pos, c.rope_theta, inv_freq=iv)
-            k = rope(k, safe_pos, c.rope_theta, inv_freq=iv)
-        else:
-            q = rope(q, safe_pos, c.rope_theta, config=c)
-            k = rope(k, safe_pos, c.rope_theta, config=c)
+        with jax.named_scope("attn.proj"):
+            # OLMo-2 (pre_norms=False): the sublayer reads the raw residual
+            x = (rms_norm(h, lp["attn_norm"], c.norm_eps, zero_centered=zc)
+                 if c.pre_norms else h)
+            q = lproj(mm(x, lp["wq"]), x, "wq")
+            k = lproj(mm(x, lp["wk"]), x, "wk")
+            v = lproj(mm(x, lp["wv"]), x, "wv")
+            if c.attn_bias:  # Qwen2 projection biases
+                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            if c.qk_norm and c.qk_norm_wide:
+                # OLMo-2: RMS statistics over the FULL projection width,
+                # before the head reshape (per-head norm is a different op)
+                q = rms_norm(q, lp["q_norm"], c.norm_eps, zero_centered=zc)
+                k = rms_norm(k, lp["k_norm"], c.norm_eps, zero_centered=zc)
+            q = q.reshape(B, S, c.n_heads, hd)
+            k = k.reshape(B, S, c.n_kv_heads, hd)
+            v = v.reshape(B, S, c.n_kv_heads, hd)
+            if c.qk_norm and not c.qk_norm_wide:
+                # Qwen3/Gemma-3 per-head RMSNorm before RoPE
+                q = rms_norm(q, lp["q_norm"], c.norm_eps, zero_centered=zc)
+                k = rms_norm(k, lp["k_norm"], c.norm_eps, zero_centered=zc)
+            if c.rope_local_theta:
+                # Gemma-3 dual rope: sliding layers rotate with the local
+                # base, global layers with rope_theta (+ its scaling). Both
+                # tables are static; the per-layer pick is one [hd/2] select
+                # riding the scan — still one compiled body.
+                is_global = (l_idx % c.sw_period) == c.sw_global_residue
+                iv = jnp.where(is_global, rope_if_global, rope_if_local)
+                q = rope(q, safe_pos, c.rope_theta, inv_freq=iv)
+                k = rope(k, safe_pos, c.rope_theta, inv_freq=iv)
+            else:
+                q = rope(q, safe_pos, c.rope_theta, config=c)
+                k = rope(k, safe_pos, c.rope_theta, config=c)
 
         # surgical in-place scatter into the carried pools (no pool copy)
         if ragged is not None:
@@ -386,180 +393,185 @@ def forward(
         else:
             k_pool = _write_kv(k_pool, l_idx, k, page_table, positions)
             v_pool = _write_kv(v_pool, l_idx, v, page_table, positions)
-        k_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
-        v_pool_l = jax.tree.map(lambda a: a[l_idx], v_pool)
+        with jax.named_scope("attn.kv_slab"):
+            # one layer's slab of each pool, as the attention call reads it
+            k_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
+            v_pool_l = jax.tree.map(lambda a: a[l_idx], v_pool)
 
-        qg = q.reshape(B, S, c.n_kv_heads, G, hd)
-        tp = mesh is not None and mesh.shape.get("model", 1) > 1
-        gemma_attn = (
-            c.attn_logit_softcap > 0 or c.sliding_window > 0
-            or c.query_pre_attn_scalar > 0 or c.attn_scale > 0
-        )
-        if gemma_attn and attn_impl == "ring":
-            # the ring kernel has no window/softcap operands: falling
-            # through to the dense jnp path would silently replace the
-            # seq-sharded prefill with a replicated gather (huge slowdown
-            # or OOM on exactly the long prompts SP exists for)
-            raise NotImplementedError(
-                "sequence-parallel ring attention does not support "
-                "sliding-window/softcap models (Mistral/Gemma); run this "
-                "model without --seq-parallel"
+        with jax.named_scope("attn.kernel"):
+            qg = q.reshape(B, S, c.n_kv_heads, G, hd)
+            tp = mesh is not None and mesh.shape.get("model", 1) > 1
+            gemma_attn = (
+                c.attn_logit_softcap > 0 or c.sliding_window > 0
+                or c.query_pre_attn_scalar > 0 or c.attn_scale > 0
             )
-        # Gemma-family extras (softcap / sliding-window / scalar scale)
-        # collapse to the kernel/jnp defaults for every other config, so
-        # ONE decode dispatch covers all families. window_l rides the
-        # scan: Gemma-2 alternates sliding (even) / global (odd) — the
-        # kernel takes it as a scalar-prefetch operand so the alternation
-        # stays one compiled body.
-        win = None
-        if gemma_attn and c.sliding_window > 0:
-            # global iff l % sw_period == sw_global_residue (Gemma-2:
-            # even sliding / odd global; Gemma-3: 5 local : 1 global)
-            win = jnp.where(
-                (l_idx % c.sw_period) == c.sw_global_residue,
-                jnp.int32(0), jnp.int32(c.sliding_window),
-            )
-        g_scale = (
-            c.query_pre_attn_scalar ** -0.5
-            if c.query_pre_attn_scalar > 0 else None
-        )
-        if c.attn_scale:  # Granite: the softmax scale given directly
-            g_scale = c.attn_scale
-        if ragged is not None:
-            seg_pt, seg_kvl, rmeta = ragged
-            if attn_impl == "pallas":
-                from dynamo_tpu.ops.ragged_paged_attention import (
-                    ragged_paged_attention,
-                    ragged_paged_attention_sharded,
+            if gemma_attn and attn_impl == "ring":
+                # the ring kernel has no window/softcap operands: falling
+                # through to the dense jnp path would silently replace the
+                # seq-sharded prefill with a replicated gather (huge slowdown
+                # or OOM on exactly the long prompts SP exists for)
+                raise NotImplementedError(
+                    "sequence-parallel ring attention does not support "
+                    "sliding-window/softcap models (Mistral/Gemma); run this "
+                    "model without --seq-parallel"
                 )
+            # Gemma-family extras (softcap / sliding-window / scalar scale)
+            # collapse to the kernel/jnp defaults for every other config, so
+            # ONE decode dispatch covers all families. window_l rides the
+            # scan: Gemma-2 alternates sliding (even) / global (odd) — the
+            # kernel takes it as a scalar-prefetch operand so the alternation
+            # stays one compiled body.
+            win = None
+            if gemma_attn and c.sliding_window > 0:
+                # global iff l % sw_period == sw_global_residue (Gemma-2:
+                # even sliding / odd global; Gemma-3: 5 local : 1 global)
+                win = jnp.where(
+                    (l_idx % c.sw_period) == c.sw_global_residue,
+                    jnp.int32(0), jnp.int32(c.sliding_window),
+                )
+            g_scale = (
+                c.query_pre_attn_scalar ** -0.5
+                if c.query_pre_attn_scalar > 0 else None
+            )
+            if c.attn_scale:  # Granite: the softmax scale given directly
+                g_scale = c.attn_scale
+            if ragged is not None:
+                seg_pt, seg_kvl, rmeta = ragged
+                if attn_impl == "pallas":
+                    from dynamo_tpu.ops.ragged_paged_attention import (
+                        ragged_paged_attention,
+                        ragged_paged_attention_sharded,
+                    )
 
-                kwr = dict(scale=g_scale, softcap=c.attn_logit_softcap)
-                if tp:
-                    attn = ragged_paged_attention_sharded(
-                        qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
-                        mesh, window=win, **kwr,
-                    )[None]
+                    kwr = dict(scale=g_scale, softcap=c.attn_logit_softcap)
+                    if tp:
+                        attn = ragged_paged_attention_sharded(
+                            qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
+                            mesh, window=win, **kwr,
+                        )[None]
+                    else:
+                        attn = ragged_paged_attention(
+                            qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
+                            win, **kwr,
+                        )[None]  # [1, T, Hk, G, hd]
                 else:
-                    attn = ragged_paged_attention(
-                        qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
-                        win, **kwr,
-                    )[None]  # [1, T, Hk, G, hd]
-            else:
-                # per-token B=T, S=1 rows of the canonical jnp reference;
-                # gemma extras collapse to the defaults for other configs
+                    # per-token B=T, S=1 rows of the canonical jnp reference;
+                    # gemma extras collapse to the defaults for other configs
+                    attn = paged_attention_jnp(
+                        qg[0][:, None], k_pool_l, v_pool_l, page_table,
+                        safe_pos.reshape(S, 1), kv_lens,
+                        scale=g_scale, softcap=c.attn_logit_softcap, window=win,
+                    )[:, 0][None]
+            elif attn_impl == "pallas" and S == 1:
+                from dynamo_tpu.ops.paged_attention import (
+                    decode_paged_attention,
+                    decode_paged_attention_sharded,
+                )
+
+                kwg = dict(scale=g_scale, softcap=c.attn_logit_softcap)
+                if tp:
+                    attn = decode_paged_attention_sharded(
+                        qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
+                        mesh, window=win, **kwg,
+                    )[:, None]
+                else:
+                    attn = decode_paged_attention(
+                        qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
+                        win, **kwg,
+                    )[:, None]  # [B, 1, Hk, G, hd]
+            elif attn_impl == "pallas":
+                # flash prefill carries the gemma extras the same way the
+                # decode kernel does (softcap/scale static, window as a
+                # scalar-prefetch operand) — one dispatch for all families
+                from dynamo_tpu.ops.flash_prefill import (
+                    prefill_paged_attention,
+                    prefill_paged_attention_sharded,
+                )
+
+                kwp = dict(scale=g_scale, softcap=c.attn_logit_softcap)
+                if tp:
+                    attn = prefill_paged_attention_sharded(
+                        qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
+                        mesh, window=win, **kwp,
+                    )
+                else:
+                    attn = prefill_paged_attention(
+                        qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
+                        win, **kwp,
+                    )
+            elif gemma_attn:
+                # non-pallas gemma runs: jnp path
                 attn = paged_attention_jnp(
-                    qg[0][:, None], k_pool_l, v_pool_l, page_table,
-                    safe_pos.reshape(S, 1), kv_lens,
-                    scale=g_scale, softcap=c.attn_logit_softcap, window=win,
-                )[:, 0][None]
-        elif attn_impl == "pallas" and S == 1:
-            from dynamo_tpu.ops.paged_attention import (
-                decode_paged_attention,
-                decode_paged_attention_sharded,
-            )
+                    qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens,
+                    scale=g_scale,
+                    softcap=c.attn_logit_softcap,
+                    window=win,
+                )
+            elif attn_impl == "ring":
+                # sequence-parallel prefill: ring attention over this chunk's
+                # fresh K/V (seq-sharded, ppermute over ICI) merged with paged
+                # attention over prior context (prefix-cache hits / earlier
+                # chunks, read from the seq-replicated pool) via online-softmax
+                # stats — exact full-context softmax, no dense gather of the
+                # chunk
+                from dynamo_tpu.ops.ring_attention import ring_attention
 
-            kwg = dict(scale=g_scale, softcap=c.attn_logit_softcap)
-            if tp:
-                attn = decode_paged_attention_sharded(
-                    qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
-                    mesh, window=win, **kwg,
-                )[:, None]
+                kv_sentinel = jnp.where(positions >= 0, positions, jnp.int32(2**30))
+                out_r, m_r, l_r = ring_attention(
+                    qg, k, v, positions, kv_sentinel, mesh, return_stats=True
+                )
+                if not sp_has_prior:
+                    attn = out_r  # fresh prefill: chunk IS the full context
+                else:
+                    prior_lens = jnp.maximum(kv_lens - q_len, 0)
+                    out_p, m_p, l_p = paged_attention_jnp(
+                        qg, k_pool_l, v_pool_l, page_table, safe_pos, prior_lens,
+                        return_stats=True,
+                    )
+                    m_star = jnp.maximum(m_r, m_p)
+                    w_r = l_r * jnp.exp(m_r - m_star)
+                    w_p = l_p * jnp.exp(m_p - m_star)
+                    denom = jnp.maximum(w_r + w_p, 1e-30)
+                    attn = (
+                        (out_r.astype(jnp.float32) * w_r + out_p.astype(jnp.float32) * w_p)
+                        / denom
+                    ).astype(h.dtype)
             else:
-                attn = decode_paged_attention(
-                    qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
-                    win, **kwg,
-                )[:, None]  # [B, 1, Hk, G, hd]
-        elif attn_impl == "pallas":
-            # flash prefill carries the gemma extras the same way the
-            # decode kernel does (softcap/scale static, window as a
-            # scalar-prefetch operand) — one dispatch for all families
-            from dynamo_tpu.ops.flash_prefill import (
-                prefill_paged_attention,
-                prefill_paged_attention_sharded,
-            )
-
-            kwp = dict(scale=g_scale, softcap=c.attn_logit_softcap)
-            if tp:
-                attn = prefill_paged_attention_sharded(
-                    qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
-                    mesh, window=win, **kwp,
+                attn = paged_attention_jnp(qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens)
+        with jax.named_scope("attn.proj"):
+            attn = attn.reshape(B, S, c.n_heads * hd)
+            attn_out = lproj(mm(attn, lp["wo"]), attn, "wo")
+            if c.post_norms:  # Gemma-2: norm the branch before the residual
+                attn_out = rms_norm(
+                    attn_out, lp["post_attn_norm"], c.norm_eps, zero_centered=zc
                 )
+            if c.residual_multiplier != 1.0:  # Granite branch scaling
+                attn_out = attn_out * jnp.asarray(
+                    c.residual_multiplier, attn_out.dtype
+                )
+            h = h + attn_out
+
+        with jax.named_scope("ffn"):
+            x = (rms_norm(h, lp["mlp_norm"], c.norm_eps, zero_centered=zc)
+                 if c.pre_norms else h)
+            rm = c.residual_multiplier
+            if use_moe:
+                ffw = _moe_block(c, lp, x, mesh)
             else:
-                attn = prefill_paged_attention(
-                    qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
-                    win, **kwp,
+                act = (
+                    partial(jax.nn.gelu, approximate=True)
+                    if c.act == "gelu_tanh" else jax.nn.silu
                 )
-        elif gemma_attn:
-            # non-pallas gemma runs: jnp path
-            attn = paged_attention_jnp(
-                qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens,
-                scale=g_scale,
-                softcap=c.attn_logit_softcap,
-                window=win,
-            )
-        elif attn_impl == "ring":
-            # sequence-parallel prefill: ring attention over this chunk's
-            # fresh K/V (seq-sharded, ppermute over ICI) merged with paged
-            # attention over prior context (prefix-cache hits / earlier
-            # chunks, read from the seq-replicated pool) via online-softmax
-            # stats — exact full-context softmax, no dense gather of the
-            # chunk
-            from dynamo_tpu.ops.ring_attention import ring_attention
-
-            kv_sentinel = jnp.where(positions >= 0, positions, jnp.int32(2**30))
-            out_r, m_r, l_r = ring_attention(
-                qg, k, v, positions, kv_sentinel, mesh, return_stats=True
-            )
-            if not sp_has_prior:
-                attn = out_r  # fresh prefill: chunk IS the full context
-            else:
-                prior_lens = jnp.maximum(kv_lens - q_len, 0)
-                out_p, m_p, l_p = paged_attention_jnp(
-                    qg, k_pool_l, v_pool_l, page_table, safe_pos, prior_lens,
-                    return_stats=True,
-                )
-                m_star = jnp.maximum(m_r, m_p)
-                w_r = l_r * jnp.exp(m_r - m_star)
-                w_p = l_p * jnp.exp(m_p - m_star)
-                denom = jnp.maximum(w_r + w_p, 1e-30)
-                attn = (
-                    (out_r.astype(jnp.float32) * w_r + out_p.astype(jnp.float32) * w_p)
-                    / denom
-                ).astype(h.dtype)
-        else:
-            attn = paged_attention_jnp(qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens)
-        attn = attn.reshape(B, S, c.n_heads * hd)
-        attn_out = lproj(mm(attn, lp["wo"]), attn, "wo")
-        if c.post_norms:  # Gemma-2: norm the branch before the residual
-            attn_out = rms_norm(
-                attn_out, lp["post_attn_norm"], c.norm_eps, zero_centered=zc
-            )
-        if c.residual_multiplier != 1.0:  # Granite branch scaling
-            attn_out = attn_out * jnp.asarray(
-                c.residual_multiplier, attn_out.dtype
-            )
-        h = h + attn_out
-
-        x = (rms_norm(h, lp["mlp_norm"], c.norm_eps, zero_centered=zc)
-             if c.pre_norms else h)
-        rm = c.residual_multiplier
-        if use_moe:
-            ffw = _moe_block(c, lp, x, mesh)
-        else:
-            act = (
-                partial(jax.nn.gelu, approximate=True)
-                if c.act == "gelu_tanh" else jax.nn.silu
-            )
-            gate = act(lproj(mm(x, lp["w_gate"]), x, "w_gate"))
-            up = lproj(mm(x, lp["w_up"]), x, "w_up")
-            ffw = lproj(mm(gate * up, lp["w_down"]), gate * up, "w_down")
-            if c.post_norms:
-                ffw = rms_norm(
-                    ffw, lp["post_mlp_norm"], c.norm_eps, zero_centered=zc
-                )
-        if rm != 1.0:  # Granite branch scaling
-            ffw = ffw * jnp.asarray(rm, ffw.dtype)
-        h = h + ffw
+                gate = act(lproj(mm(x, lp["w_gate"]), x, "w_gate"))
+                up = lproj(mm(x, lp["w_up"]), x, "w_up")
+                ffw = lproj(mm(gate * up, lp["w_down"]), gate * up, "w_down")
+                if c.post_norms:
+                    ffw = rms_norm(
+                        ffw, lp["post_mlp_norm"], c.norm_eps, zero_centered=zc
+                    )
+            if rm != 1.0:  # Granite branch scaling
+                ffw = ffw * jnp.asarray(rm, ffw.dtype)
+            h = h + ffw
         return (h, k_pool, v_pool), None
 
     dense_stack = params.get("layers_dense")
@@ -590,34 +602,35 @@ def forward(
              jnp.arange(c.n_layers, dtype=jnp.int32)),
         )
 
-    h = rms_norm(h, params["norm_f"], c.norm_eps,
-                 zero_centered=c.norm_zero_centered)
-    if last_index is not None:
-        if getattr(last_index, "ndim", 0) >= 1 and ragged is not None:
-            # flat-segment forward: indices are flat token positions of
-            # each segment's last token — gather them all from the one row
-            h = jnp.take_along_axis(
-                h, last_index.reshape(1, -1, 1), axis=1
-            )  # [1, NSEG, E]
-        elif getattr(last_index, "ndim", 0) >= 1:
-            # ragged packed prefill: each batch row is a different chunk
-            # with its own last valid position
-            h = jnp.take_along_axis(
-                h, last_index.reshape(-1, 1, 1), axis=1
-            )  # [B, 1, E]
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h, params["norm_f"], c.norm_eps,
+                     zero_centered=c.norm_zero_centered)
+        if last_index is not None:
+            if getattr(last_index, "ndim", 0) >= 1 and ragged is not None:
+                # flat-segment forward: indices are flat token positions of
+                # each segment's last token — gather them all from the one row
+                h = jnp.take_along_axis(
+                    h, last_index.reshape(1, -1, 1), axis=1
+                )  # [1, NSEG, E]
+            elif getattr(last_index, "ndim", 0) >= 1:
+                # ragged packed prefill: each batch row is a different chunk
+                # with its own last valid position
+                h = jnp.take_along_axis(
+                    h, last_index.reshape(-1, 1, 1), axis=1
+                )  # [B, 1, E]
+            else:
+                h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)  # [B, 1, E]
+        lm_head = params.get("lm_head")
+        if lm_head is None:  # tied embeddings
+            logits = tied_logits(h, params["embed"])
         else:
-            h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)  # [B, 1, E]
-    lm_head = params.get("lm_head")
-    if lm_head is None:  # tied embeddings
-        logits = tied_logits(h, params["embed"])
-    else:
-        logits = mm(h, lm_head)
-    logits = logits.astype(jnp.float32)
-    if c.logits_divider != 1.0:  # Granite
-        logits = logits / c.logits_divider
-    if c.final_logit_softcap:
-        cap = c.final_logit_softcap
-        logits = cap * jnp.tanh(logits / cap)
+            logits = mm(h, lm_head)
+        logits = logits.astype(jnp.float32)
+        if c.logits_divider != 1.0:  # Granite
+            logits = logits / c.logits_divider
+        if c.final_logit_softcap:
+            cap = c.final_logit_softcap
+            logits = cap * jnp.tanh(logits / cap)
     return logits, k_pool, v_pool
 
 

@@ -3,10 +3,13 @@ NVTX integration (lib/runtime/Cargo.toml:24-27, src/nvtx.rs: Nsight
 ranges, compile-time + `DYN_ENABLE_RUST_NVTX` runtime gated, ~1ns off).
 
 On TPU the profiler is XLA's: `jax.profiler.start_server` exposes the
-worker to TensorBoard/xprof capture, and `TraceAnnotation` ranges mark
-engine phases (prefill/decode/sample) on the captured host+device
-timeline. Gated by `DYN_ENABLE_JAX_TRACE=1`; when off, `annotate` is a
-shared no-op context manager (one attribute read per call)."""
+worker to TensorBoard/xprof capture, and `TraceAnnotation` ranges put
+the engine iteration's host work (`engine.wait/inbox/schedule`, a step
+parent `engine.decode/mixed/prefill/...` tiled by `engine.prep/stage/
+dispatch/readback/emit`, then `engine.publish`) on the captured
+host+device timeline, so every idle gap on the device has an owner.
+Gated by `DYN_ENABLE_JAX_TRACE=1`; when off, `annotate` returns one
+shared no-op context manager (a cached check, no allocation per call)."""
 
 from __future__ import annotations
 
@@ -41,15 +44,11 @@ def annotate(name: str, **kwargs):
     return TraceAnnotation(name, **kwargs)
 
 
-def start_profiler_server(port: int) -> bool:
+def start_profiler_server(port: int) -> None:
     """Start the XLA profiler server (TensorBoard 'capture profile'
-    target). Returns False if unavailable (CPU-only builds)."""
-    try:
-        import jax
+    target). Raises what jax raises: a worker asked for `--profiler-port`
+    that cannot serve it must not come up without one."""
+    import jax
 
-        jax.profiler.start_server(port)
-        log.info("jax profiler server on port %d", port)
-        return True
-    except Exception:  # pragma: no cover
-        log.exception("profiler server failed to start")
-        return False
+    jax.profiler.start_server(port)
+    log.info("jax profiler server on port %d", port)

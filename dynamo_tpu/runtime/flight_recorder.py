@@ -51,11 +51,19 @@ class IterationRecord:
     """One engine iteration, as the scheduler composed and the runner
     executed it. All counters that read "cumulative" are monotonically
     increasing process totals sampled at append time (deltas between
-    consecutive records give per-iteration rates)."""
+    consecutive records give per-iteration rates).
+
+    `wall_s` is the step, not its enqueue: the engine reads the clock
+    after `step_plan()` returns and again after `_publish_kv_events()`,
+    around a blocking `device_get` of the sampled tokens. So it is input
+    prep + staging + dispatch + the device's time + readback + emit +
+    publish (the `engine.<parent>` and `engine.publish` spans, less the
+    record's own append), and it leaves out the inbox, the scheduler and
+    any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`)."""
 
     seq: int               # engine iteration number (monotonic)
     ts: float              # wall clock (time.time()) at iteration start
-    wall_s: float          # dispatch + host-sync wall time
+    wall_s: float          # the step's wall time, device included (above)
     kind: str              # "prefill" | "decode" | "mixed"
     decode_seqs: int       # decode batch rows this iteration
     decode_steps: int      # fused decode steps (T)

@@ -472,12 +472,23 @@ async def serve_worker(
                         phase="itl")
                     for s in val:
                         h.observe(float(s))
-                elif isinstance(val, (int, float)):
+                elif not isinstance(val, (int, float)):
+                    continue
+                elif key.endswith("_s"):
                     _pm.histogram(
                         "request_phase_seconds",
                         "per-request latency spine phase durations",
                         phase=key.removesuffix("_s"),
                     ).observe(float(val))
+                else:
+                    # the spine's counts (preemptions, prefill_iters,
+                    # migration_attempts) are no durations: summed here,
+                    # never observed into a seconds histogram
+                    _pm.counter(
+                        "request_phase_count",
+                        "per-request latency spine counts, summed",
+                        phase=key,
+                    ).inc(float(val))
 
         engine.on_phases(_observe_phases)
 

@@ -111,14 +111,21 @@ async def test_multiprocess_frontend_reuse_port(tmp_path):
     try:
         base = f"http://127.0.0.1:{port}"
         async with aiohttp.ClientSession() as s:
-            for _ in range(120):
+            # ready when BOTH acceptors list the model: each probe takes a
+            # connection of its own (the kernel picks the acceptor per
+            # connection; a kept-alive one would ask the same process every
+            # time while the other had not discovered the worker yet)
+            seen = 0
+            for _ in range(240):
                 try:
-                    async with s.get(f"{base}/v1/models") as r:
-                        if (await r.json()).get("data"):
-                            break
+                    async with s.get(f"{base}/v1/models",
+                                     headers={"Connection": "close"}) as r:
+                        seen = seen + 1 if (await r.json()).get("data") else 0
                 except Exception:
-                    pass
-                await asyncio.sleep(0.5)
+                    seen = 0
+                if seen >= 12:
+                    break
+                await asyncio.sleep(0.05 if seen else 0.5)
             else:
                 raise AssertionError("frontend never ready")
 

@@ -390,3 +390,58 @@ def test_simple_metrics_text_exposition():
     assert 'dynamo_component="engine"' in hist_line
     # shared store: children share series, render is idempotent
     assert m.render() == m.render()
+
+
+async def test_spine_counts_stay_out_of_the_seconds_histogram():
+    """worker_common._observe_phases: only a `_s` key of the spine is a
+    duration; its counts (preemptions, prefill_iters, migration_attempts)
+    go to request_phase_count, summed, and never into a seconds histogram."""
+    import re
+
+    from dynamo_tpu.frontend.protocols import ModelCard
+    from dynamo_tpu.runtime.discovery import MemDiscovery
+    from dynamo_tpu.runtime.distributed import DistributedRuntime
+    from dynamo_tpu.worker_common import serve_worker
+
+    class _Eng:
+        def __init__(self):
+            self.listeners = []
+
+        def on_kv_event(self, cb): pass
+        def on_fpm(self, cb): pass
+        def on_phases(self, cb): self.listeners.append(cb)
+
+        async def generate(self, req, ctx):
+            yield {"token_ids": [], "finish_reason": "stop"}
+
+        def start(self): pass
+        def stop(self): pass
+
+    eng = _Eng()
+    rt = DistributedRuntime(discovery=MemDiscovery(realm="spine-counts"),
+                            event_transport="inproc")
+    try:
+        w = await serve_worker(rt, eng, ModelCard(name="m"), digest_period_s=0,
+                               publish_kv_events=False, publish_fpm=False)
+        spine = {"ttft_s": 0.25, "prefill_s": 0.2, "prefill_iters": 3,
+                 "preemptions": 1, "migration_attempts": 2.0,
+                 "itl_s": [0.01, 0.03], "trace_id": "ab"}
+        for cb in eng.listeners:
+            cb(spine)
+            cb(dict(spine, preemptions=0))
+        lines = rt.metrics.render().decode().splitlines()
+        await w.stop()
+    finally:
+        await rt.shutdown(drain_timeout=1)
+
+    def values(prefix):
+        return {re.search(r'phase="([^"]+)"', ln).group(1):
+                float(ln.rsplit(" ", 1)[1])
+                for ln in lines if ln.startswith(prefix)}
+
+    seconds = values("dynamo_request_phase_seconds_count{")
+    assert seconds == {"ttft": 2.0, "prefill": 2.0, "itl": 4.0}, seconds
+    counts = values("dynamo_request_phase_count_total{") or values(
+        "dynamo_request_phase_count{")
+    assert counts == {"prefill_iters": 6.0, "preemptions": 1.0,
+                      "migration_attempts": 4.0}, counts

@@ -226,12 +226,17 @@ def test_trace_annotations_gate(monkeypatch):
     ann._enabled.cache_clear()
     cm = ann.annotate("x", n=1)
     assert isinstance(cm, contextlib.nullcontext)
+    # one shared object, whatever the name and arguments: a span that is
+    # off allocates nothing per call
+    assert cm is ann.annotate("engine.dispatch", family="ragged") is ann._NULL
 
     monkeypatch.setenv("DYN_ENABLE_JAX_TRACE", "1")
     ann._enabled.cache_clear()
     try:
         with ann.annotate("engine.decode", batch=2):  # must not raise on CPU
-            pass
+            with ann.annotate("engine.prep"):  # children nest
+                pass
+        assert ann.annotate("engine.emit") is not ann._NULL
     finally:
         ann._enabled.cache_clear()
 
