@@ -428,7 +428,9 @@ def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
     program N times."""
     if event != _BACKEND_COMPILE_EVENT:
         return
-    if getattr(_compile_tls, "family", None) is not None:
+    family = getattr(_compile_tls, "family", None)
+    if family is not None:
+        family._asked_xla = True
         return
     other = getattr(_compile_tls, "other", None)
     if other is not None:
@@ -450,10 +452,15 @@ def _ensure_compile_listener() -> None:
 class _CompiledFamily:
     """One jitted step-function family. Jits `fn` under the function's own
     name (see _named), counts distinct compiled variants (jit cache
-    growth) and the cumulative wall seconds of calls that compiled
-    (trace+lower+compile — the host-side stall each new bucket costs),
-    and marks each call `engine.dispatch` on the profiler's timeline.
-    compile_stats() feeds the worker's /metrics gauges and the
+    growth in a call that asked XLA for a program) and the cumulative
+    wall seconds of calls that compiled (trace+lower+compile — the
+    host-side stall each new bucket costs), and marks each call
+    `engine.dispatch` on the profiler's timeline. A cache entry that
+    asked XLA for nothing is a call signature, not a variant: the first
+    call on a fresh KV pool sees the sharding the pool was allocated
+    under, every later one the equivalent sharding a step program
+    returned it with, and the second resolves to the program the first
+    built. compile_stats() feeds the worker's /metrics gauges and the
     benchmark's `runner.compiles_in_window`. The catch-all family
     `other` has no function: _on_compile_event counts into it."""
 
@@ -463,6 +470,8 @@ class _CompiledFamily:
         self.variants = 0
         self.compile_s = 0.0
         self.calls = 0
+        self._asked_xla = False  # set by _on_compile_event inside a call
+        _ensure_compile_listener()
 
     def _cache_size(self):
         try:
@@ -475,6 +484,7 @@ class _CompiledFamily:
         before = self._cache_size()
         outer = getattr(_compile_tls, "family", None)
         _compile_tls.family = self
+        self._asked_xla = False
         t0 = time.monotonic()
         try:
             with annotate("engine.dispatch", family=self.name):
@@ -482,7 +492,8 @@ class _CompiledFamily:
         finally:
             _compile_tls.family = outer
         after = self._cache_size()
-        if before is not None and after is not None and after > before:
+        if (self._asked_xla and before is not None and after is not None
+                and after > before):
             self.variants += after - before
             self.compile_s += time.monotonic() - t0
         return out
@@ -854,7 +865,6 @@ class ModelRunner:
         # math) compiled on the thread that serves this runner; not in
         # _families, whose growth after warm-up the sanitizer calls a leak
         self._other = _CompiledFamily("other")
-        _ensure_compile_listener()
 
         def _family(name, fn, **jit_kwargs):
             fam = _CompiledFamily(name, fn, **jit_kwargs)

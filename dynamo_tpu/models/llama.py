@@ -393,10 +393,15 @@ def forward(
         else:
             k_pool = _write_kv(k_pool, l_idx, k, page_table, positions)
             v_pool = _write_kv(v_pool, l_idx, v, page_table, positions)
-        with jax.named_scope("attn.kv_slab"):
-            # one layer's slab of each pool, as the attention call reads it
-            k_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
-            v_pool_l = jax.tree.map(lambda a: a[l_idx], v_pool)
+
+        def kv_slab():
+            # one layer's slab of each pool, for the paths that gather
+            # pages in jnp. The Pallas kernels take the stacked pools and
+            # l_idx instead: a custom call cannot read a view, so a slab
+            # in front of one is a copy of it, every layer of every step.
+            with jax.named_scope("attn.kv_slab"):
+                return (jax.tree.map(lambda a: a[l_idx], k_pool),
+                        jax.tree.map(lambda a: a[l_idx], v_pool))
 
         with jax.named_scope("attn.kernel"):
             qg = q.reshape(B, S, c.n_kv_heads, G, hd)
@@ -446,19 +451,19 @@ def forward(
                     kwr = dict(scale=g_scale, softcap=c.attn_logit_softcap)
                     if tp:
                         attn = ragged_paged_attention_sharded(
-                            qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
-                            mesh, window=win, **kwr,
+                            qg[0], k_pool, v_pool, seg_pt, seg_kvl, rmeta,
+                            mesh, window=win, layer=l_idx, **kwr,
                         )[None]
                     else:
                         attn = ragged_paged_attention(
-                            qg[0], k_pool_l, v_pool_l, seg_pt, seg_kvl, rmeta,
-                            win, **kwr,
+                            qg[0], k_pool, v_pool, seg_pt, seg_kvl, rmeta,
+                            win, l_idx, **kwr,
                         )[None]  # [1, T, Hk, G, hd]
                 else:
                     # per-token B=T, S=1 rows of the canonical jnp reference;
                     # gemma extras collapse to the defaults for other configs
                     attn = paged_attention_jnp(
-                        qg[0][:, None], k_pool_l, v_pool_l, page_table,
+                        qg[0][:, None], *kv_slab(), page_table,
                         safe_pos.reshape(S, 1), kv_lens,
                         scale=g_scale, softcap=c.attn_logit_softcap, window=win,
                     )[:, 0][None]
@@ -471,13 +476,13 @@ def forward(
                 kwg = dict(scale=g_scale, softcap=c.attn_logit_softcap)
                 if tp:
                     attn = decode_paged_attention_sharded(
-                        qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
-                        mesh, window=win, **kwg,
+                        qg[:, 0], k_pool, v_pool, page_table, kv_lens,
+                        mesh, window=win, layer=l_idx, **kwg,
                     )[:, None]
                 else:
                     attn = decode_paged_attention(
-                        qg[:, 0], k_pool_l, v_pool_l, page_table, kv_lens,
-                        win, **kwg,
+                        qg[:, 0], k_pool, v_pool, page_table, kv_lens,
+                        win, l_idx, **kwg,
                     )[:, None]  # [B, 1, Hk, G, hd]
             elif attn_impl == "pallas":
                 # flash prefill carries the gemma extras the same way the
@@ -491,18 +496,18 @@ def forward(
                 kwp = dict(scale=g_scale, softcap=c.attn_logit_softcap)
                 if tp:
                     attn = prefill_paged_attention_sharded(
-                        qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
-                        mesh, window=win, **kwp,
+                        qg, k_pool, v_pool, page_table, q_start, q_len, kv_lens,
+                        mesh, window=win, layer=l_idx, **kwp,
                     )
                 else:
                     attn = prefill_paged_attention(
-                        qg, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
-                        win, **kwp,
+                        qg, k_pool, v_pool, page_table, q_start, q_len, kv_lens,
+                        win, l_idx, **kwp,
                     )
             elif gemma_attn:
                 # non-pallas gemma runs: jnp path
                 attn = paged_attention_jnp(
-                    qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens,
+                    qg, *kv_slab(), page_table, safe_pos, kv_lens,
                     scale=g_scale,
                     softcap=c.attn_logit_softcap,
                     window=win,
@@ -525,7 +530,7 @@ def forward(
                 else:
                     prior_lens = jnp.maximum(kv_lens - q_len, 0)
                     out_p, m_p, l_p = paged_attention_jnp(
-                        qg, k_pool_l, v_pool_l, page_table, safe_pos, prior_lens,
+                        qg, *kv_slab(), page_table, safe_pos, prior_lens,
                         return_stats=True,
                     )
                     m_star = jnp.maximum(m_r, m_p)
@@ -537,7 +542,7 @@ def forward(
                         / denom
                     ).astype(h.dtype)
             else:
-                attn = paged_attention_jnp(qg, k_pool_l, v_pool_l, page_table, safe_pos, kv_lens)
+                attn = paged_attention_jnp(qg, *kv_slab(), page_table, safe_pos, kv_lens)
         with jax.named_scope("attn.proj"):
             attn = attn.reshape(B, S, c.n_heads * hd)
             attn_out = lproj(mm(attn, lp["wo"]), attn, "wo")

@@ -2,7 +2,9 @@
 
 The prefill hot op: a chunk of S new query tokens per sequence attends over
 the full paged context (prior prefix-cache/chunk pages + this chunk's own
-pages, already written to the pool). The jnp path materializes
+pages, already written to the pool). The pool operand is the layer-stacked
+pool [L, NP, PS, Hk, D], read at a scalar-prefetched layer (see
+ops/paged_attention.py). The jnp path materializes
 [B, Hk, G, S, C] fp32 scores in HBM — O(S·C) traffic that dominates long
 prompts. This kernel streams K/V pages HBM→VMEM once per (q-block, page)
 pair with flash online softmax in VMEM scratch, and skips both the DMA and
@@ -39,6 +41,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.paged_attention import (
+    scalar_operands, split_scales, stacked_pools,
+)
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, prefill_attention_specs
 
 NEG_INF = -1e30
@@ -56,6 +61,7 @@ def _prefill_kernel_body(
     q_start_ref,  # [B] int32 absolute position of query token 0
     q_len_ref,  # [B] int32 number of valid query tokens
     kv_lens_ref,  # [B] int32 context length (incl. this chunk)
+    #   (the pool's layer [1] rides next; only the index maps read it)
     win_ref,  # [1] int32 sliding window (0 = global) or None (no-window
     #   compile) — Gemma-2 alternates per layer with a traced scalar
     # blocks
@@ -152,31 +158,33 @@ def _prefill_kernel_body(
         o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _prefill_kernel(pt, qs, ql, kl, q, k, v, o, m, l, acc, **kw):
+def _prefill_kernel(pt, qs, ql, kl, ly, q, k, v, o, m, l, acc, **kw):
     _prefill_kernel_body(pt, qs, ql, kl, None, q, k, v, None, None,
                          o, m, l, acc, **kw)
 
 
-def _prefill_kernel_win(pt, qs, ql, kl, win, q, k, v, o, m, l, acc, **kw):
+def _prefill_kernel_win(pt, qs, ql, kl, ly, win, q, k, v, o, m, l, acc,
+                        **kw):
     _prefill_kernel_body(pt, qs, ql, kl, win, q, k, v, None, None,
                          o, m, l, acc, **kw)
 
 
-def _prefill_kernel_int8(pt, qs, ql, kl, q, k, ks, v, vs, o, m, l, acc, **kw):
+def _prefill_kernel_int8(pt, qs, ql, kl, ly, q, k, ks, v, vs, o, m, l, acc,
+                         **kw):
     _prefill_kernel_body(pt, qs, ql, kl, None, q, k, v, ks, vs,
                          o, m, l, acc, **kw)
 
 
-def _prefill_kernel_int8_win(pt, qs, ql, kl, win, q, k, ks, v, vs, o, m, l,
-                             acc, **kw):
+def _prefill_kernel_int8_win(pt, qs, ql, kl, ly, win, q, k, ks, v, vs, o, m,
+                             l, acc, **kw):
     _prefill_kernel_body(pt, qs, ql, kl, win, q, k, v, ks, vs,
                          o, m, l, acc, **kw)
 
 
 def prefill_paged_attention_sharded(
     q: jax.Array,  # [B, S, Hk, G, D] heads sharded over `axis_name`
-    k_pool_l: jax.Array,  # [NP, PS, Hk, D] (token-major)
-    v_pool_l: jax.Array,
+    k_pool: jax.Array,  # [L, NP, PS, Hk, D] (token-major, stacked)
+    v_pool: jax.Array,
     page_table: jax.Array,
     q_start: jax.Array,
     q_len: jax.Array,
@@ -184,6 +192,7 @@ def prefill_paged_attention_sharded(
     mesh,
     axis_name: str = AXIS_MODEL,
     window=None,  # traced int32 scalar (see prefill_paged_attention)
+    layer=None,  # traced int32 scalar, replicated
     *,
     q_block: int = 128,
     scale=None,
@@ -195,25 +204,28 @@ def prefill_paged_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     heads, pool, scales = prefill_attention_specs(axis_name)
-    if isinstance(k_pool_l, dict):  # int8 KV: scales [NP, PS, Hk] shard
+    if isinstance(k_pool, dict):  # int8 KV: scales [L, NP, PS, Hk] shard
         # the same head axis
         pool = {"q": pool, "s": scales}
-    part = functools.partial(
-        prefill_paged_attention, q_block=q_block, scale=scale,
-        softcap=softcap, interpret=interpret,
-    )
-    base_specs = (heads, pool, pool, P(None, None), P(None), P(None), P(None))
-    extra = (
-        () if window is None
-        else (jnp.asarray(window, jnp.int32).reshape(1),)
-    )
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    scalars = scalar_operands(layer, window)
+
+    def part(q, k_pool, v_pool, page_table, q_start, q_len, kv_lens, layer,
+             window=None):
+        return prefill_paged_attention(
+            q, k_pool, v_pool, page_table, q_start, q_len, kv_lens, window,
+            layer, q_block=q_block, scale=scale, softcap=softcap,
+            interpret=interpret,
+        )
+
     fn = jax.shard_map(
         part, mesh=mesh,
-        in_specs=base_specs + ((P(),) if extra else ()),
+        in_specs=(heads, pool, pool, P(None, None), P(None), P(None), P(None))
+        + (P(),) * len(scalars),
         out_specs=heads, check_vma=False,
     )
-    return fn(q, k_pool_l, v_pool_l, page_table, q_start, q_len, kv_lens,
-              *extra)
+    return fn(q, k_pool, v_pool, page_table, q_start, q_len, kv_lens,
+              *scalars)
 
 
 @functools.partial(
@@ -221,14 +233,16 @@ def prefill_paged_attention_sharded(
 )
 def prefill_paged_attention(
     q: jax.Array,  # [B, S, Hk, G, D]
-    k_pool_l: jax.Array,  # [NP, PS, Hk, D] (token-major)
-    v_pool_l: jax.Array,
+    k_pool: jax.Array,  # [L, NP, PS, Hk, D] stacked token-major pool (or
+    #   one layer's [NP, PS, Hk, D]: see stacked_pools)
+    v_pool: jax.Array,
     page_table: jax.Array,  # [B, MP] int32
     q_start: jax.Array,  # [B] int32 absolute position of query token 0
     q_len: jax.Array,  # [B] int32 valid query tokens (rest are padding)
     kv_lens: jax.Array,  # [B] int32 context length incl. this chunk
     window=None,  # None = no-window compile; else traced int32 scalar
     #   (0 = global at runtime) — see decode_paged_attention
+    layer=None,  # traced int32 scalar: the stacked pool's layer to read
     *,
     q_block: int = 128,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
@@ -238,9 +252,10 @@ def prefill_paged_attention(
     """Returns [B, S, Hk, G, D]; padding rows (s >= q_len[b]) return 0.
     The chunk's own K/V must already be written to the pool."""
     B, S, Hk, G, D = q.shape
-    quantized = isinstance(k_pool_l, dict)
-    kq = k_pool_l["q"] if quantized else k_pool_l
-    NP, PS, _, _ = kq.shape
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    kq, vq, ks, vs = split_scales(k_pool, v_pool, layer)
+    quantized = ks is not None
+    _, NP, PS, _, _ = kq.shape
     MP = page_table.shape[1]
     q_block = min(q_block, S)
     while S % q_block:  # largest divisor of S at most the requested block
@@ -249,11 +264,10 @@ def prefill_paged_attention(
     if scale is None:
         scale = D**-0.5
     windowed = window is not None
-    n_prefetch = 5 if windowed else 4
 
     qt = q.transpose(0, 2, 1, 3, 4).reshape(B, Hk, S * G, D)
 
-    def _clamp(b, sb, i, pt, qs, ql, kl, *rest):
+    def _clamp(b, sb, i, pt, qs, ql, kl, ly, *rest):
         # clamp to the page range this q-block can actually see (causal
         # top, kv_len, and — with a window — the sliding low bound):
         # repeated indices across grid steps → Pallas skips the DMA
@@ -271,18 +285,20 @@ def prefill_paged_attention(
             i_eff = jnp.maximum(i_eff, jnp.minimum(lo // PS, last))
         return i_eff
 
-    def kv_index(b, sb, i, pt, qs, ql, kl, *rest):
-        return (pt[b, _clamp(b, sb, i, pt, qs, ql, kl, *rest)], 0, 0, 0)
+    def kv_index(b, sb, i, pt, qs, ql, kl, ly, *rest):
+        return (ly[0], pt[b, _clamp(b, sb, i, pt, qs, ql, kl, ly, *rest)],
+                0, 0, 0)
 
-    def scale_index(b, sb, i, pt, qs, ql, kl, *rest):
-        return kv_index(b, sb, i, pt, qs, ql, kl, *rest)[:3]
+    def scale_index(b, sb, i, pt, qs, ql, kl, ly, *rest):
+        return kv_index(b, sb, i, pt, qs, ql, kl, ly, *rest)[1:4]
 
-    def q_index(b, sb, i, pt, qs, ql, kl, *rest):
+    def q_index(b, sb, i, pt, qs, ql, kl, ly, *rest):
         return (b, 0, sb, 0)
 
     q_spec = pl.BlockSpec((None, Hk, q_block * G, D), q_index)
-    # one token-major page = one contiguous PS*Hk*D slab (single DMA)
-    kv_spec = pl.BlockSpec((None, PS, Hk, D), kv_index)
+    # one token-major page of one layer = one contiguous PS*Hk*D slab
+    # (single DMA)
+    kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, q_block=q_block, n_groups=G, scale=scale,
               softcap=softcap)
     if quantized:
@@ -293,16 +309,19 @@ def prefill_paged_attention(
         # (None, PS, Hk): minor dims are full array dims — legal tile
         s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
         in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
-        operands = (qt, kq, k_pool_l["s"], v_pool_l["q"], v_pool_l["s"])
+        operands = (qt, kq, ks, vq, vs)
     else:
         kernel = functools.partial(
             _prefill_kernel_win if windowed else _prefill_kernel, **kw
         )
         in_specs = [q_spec, kv_spec, kv_spec]
-        operands = (qt, kq, v_pool_l)
+        operands = (qt, kq, vq)
 
+    prefetch = (page_table, q_start, q_len, kv_lens) + scalar_operands(
+        layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # pt, q_start, q_len, kv (+ window)
+        num_scalar_prefetch=len(prefetch),  # pt, q_start, q_len, kv, layer
+        #   (+ window)
         grid=(B, n_sblk, MP),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -313,11 +332,6 @@ def prefill_paged_attention(
         ],
     )
 
-    prefetch = (page_table, q_start, q_len, kv_lens)
-    if windowed:
-        prefetch = prefetch + (
-            jnp.asarray(window, jnp.int32).reshape(1),
-        )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

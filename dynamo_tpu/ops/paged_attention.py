@@ -2,9 +2,13 @@
 
 The decode hot op: one query token per sequence attends over that
 sequence's paged KV (pages scattered in a global HBM pool, owned via a page
-table). The jnp reference path (models/llama.py paged_attention_jnp)
-gathers all pages into a dense [B, ctx] tensor per layer — an extra HBM
-round trip of the whole KV working set. This kernel streams each page
+table). The pool operand is the layer-STACKED pool [L, NP, PS, Hk, D] that
+the layer scan carries, read at a scalar-prefetched layer: a Pallas call
+takes whole buffers, so handing it `pool[l]` with a traced l makes XLA
+copy that slab out first, once per pool per layer per step. The jnp
+reference path (models/llama.py paged_attention_jnp) gathers all pages into
+a dense [B, ctx] tensor per layer — an extra HBM round trip of the whole
+KV working set. This kernel streams each page
 HBM→VMEM once via BlockSpec index_maps driven by the scalar-prefetched page
 table and accumulates flash-attention-style online softmax in VMEM scratch.
 
@@ -36,6 +40,50 @@ from jax.experimental.pallas import tpu as pltpu
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
 
 NEG_INF = -1e30
+
+
+def stacked_pools(k_pool, v_pool, layer):
+    """The attention kernels' pool operands: (k_pool, v_pool, layer[1]).
+
+    A pool is layer-stacked [L, NP, PS, Hk, D] (int8 KV: a dict of "q"
+    [L, NP, PS, Hk, D] and "s" [L, NP, PS, Hk]) and `layer` a traced int32
+    scalar. One layer's pool [NP, PS, Hk, D] is the one-layer stack
+    `a[None]` read at layer 0 (a free reshape), so both ranks run the same
+    program."""
+    kq = k_pool["q"] if isinstance(k_pool, dict) else k_pool
+    if kq.ndim == 4:
+        if layer is not None:
+            raise ValueError("a per-layer pool [NP, PS, Hk, D] takes no layer")
+        k_pool, v_pool = jax.tree.map(lambda a: a[None], (k_pool, v_pool))
+        layer = 0
+    elif layer is None:
+        raise ValueError("a stacked pool [L, NP, PS, Hk, D] needs its layer")
+    return k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def scalar_operands(layer, window):
+    """The operands that ride behind the page table and the lengths, as
+    scalar prefetch and through shard_map: layer[1] (+ window[1])."""
+    if window is None:
+        return (layer,)
+    return (layer, jnp.asarray(window, jnp.int32).reshape(1))
+
+
+def split_scales(k_pool, v_pool, layer):
+    """(k, v, k_scales, v_scales) as the pallas_call takes them: the data
+    stacked [L, NP, PS, Hk, D], the int8 pools' f32 scales as THIS layer's
+    [NP, PS, Hk] (None for dense pools). The scales are sliced here, in
+    XLA, on purpose: Mosaic takes an operand row-major, which pads their
+    minor Hk to 128 lanes (4-64x), while the `_write_kv` scatter keeps the
+    stacked scales tokens-minor — handed the whole stack, XLA converted it
+    between the two layouts twice a pool in EVERY layer (v5e HLO, PR 25).
+    A layer's scales are 1/D of its data; the copy that hurt was the
+    data's."""
+    if not isinstance(k_pool, dict):
+        return k_pool, v_pool, None, None
+    ks, vs = (lax.dynamic_index_in_dim(p["s"], layer[0], keepdims=False)
+              for p in (k_pool, v_pool))
+    return k_pool["q"], v_pool["q"], ks, vs
 
 
 def _decode_kernel_body(
@@ -128,7 +176,7 @@ def _decode_kernel_body(
         o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _decode_kernel(pt, kl, q, k, v, o, m, l, acc, *, page_size, scale,
+def _decode_kernel(pt, kl, ly, q, k, v, o, m, l, acc, *, page_size, scale,
                    softcap=0.0):
     _decode_kernel_body(
         pt, kl, None, q, k, v, None, None, o, m, l, acc,
@@ -136,24 +184,24 @@ def _decode_kernel(pt, kl, q, k, v, o, m, l, acc, *, page_size, scale,
     )
 
 
-def _decode_kernel_win(pt, kl, win, q, k, v, o, m, l, acc, *, page_size,
-                       scale, softcap=0.0):
+def _decode_kernel_win(pt, kl, ly, win, q, k, v, o, m, l, acc, *,
+                       page_size, scale, softcap=0.0):
     _decode_kernel_body(
         pt, kl, win, q, k, v, None, None, o, m, l, acc,
         page_size=page_size, scale=scale, softcap=softcap,
     )
 
 
-def _decode_kernel_int8(pt, kl, q, k, ks, v, vs, o, m, l, acc, *, page_size,
-                        scale, softcap=0.0):
+def _decode_kernel_int8(pt, kl, ly, q, k, ks, v, vs, o, m, l, acc, *,
+                        page_size, scale, softcap=0.0):
     _decode_kernel_body(
         pt, kl, None, q, k, v, ks, vs, o, m, l, acc,
         page_size=page_size, scale=scale, softcap=softcap,
     )
 
 
-def _decode_kernel_int8_win(pt, kl, win, q, k, ks, v, vs, o, m, l, acc, *,
-                            page_size, scale, softcap=0.0):
+def _decode_kernel_int8_win(pt, kl, ly, win, q, k, ks, v, vs, o, m, l, acc,
+                            *, page_size, scale, softcap=0.0):
     _decode_kernel_body(
         pt, kl, win, q, k, v, ks, vs, o, m, l, acc,
         page_size=page_size, scale=scale, softcap=softcap,
@@ -162,13 +210,14 @@ def _decode_kernel_int8_win(pt, kl, win, q, k, ks, v, vs, o, m, l, acc, *,
 
 def decode_paged_attention_sharded(
     q: jax.Array,  # [B, Hk, G, D] heads sharded over `axis_name`
-    k_pool_l: jax.Array,  # [NP, PS, Hk, D] heads sharded over `axis_name`
-    v_pool_l: jax.Array,
+    k_pool: jax.Array,  # [L, NP, PS, Hk, D] heads sharded over `axis_name`
+    v_pool: jax.Array,
     page_table: jax.Array,  # [B, MP] replicated
     kv_lens: jax.Array,  # [B] replicated
     mesh,
     axis_name: str = AXIS_MODEL,
     window=None,  # traced int32 scalar (see decode_paged_attention)
+    layer=None,  # traced int32 scalar, replicated
     *,
     scale=None,
     softcap: float = 0.0,
@@ -181,33 +230,27 @@ def decode_paged_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     heads, pool, scales = attention_specs(axis_name)
-    if isinstance(k_pool_l, dict):  # int8 KV: scales [NP, PS, Hk] shard
+    if isinstance(k_pool, dict):  # int8 KV: scales [L, NP, PS, Hk] shard
         # the same head axis
         pool = {"q": pool, "s": scales}
-    rep2 = P(None, None)
-    rep1 = P(None)
-    part = functools.partial(
-        decode_paged_attention, scale=scale, softcap=softcap,
-        interpret=interpret,
-    )
-    if window is None:
-        fn = jax.shard_map(
-            part,
-            mesh=mesh,
-            in_specs=(heads, pool, pool, rep2, rep1),
-            out_specs=heads,
-            check_vma=False,
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    scalars = scalar_operands(layer, window)
+
+    def part(q, k_pool, v_pool, page_table, kv_lens, layer, window=None):
+        return decode_paged_attention(
+            q, k_pool, v_pool, page_table, kv_lens, window, layer,
+            scale=scale, softcap=softcap, interpret=interpret,
         )
-        return fn(q, k_pool_l, v_pool_l, page_table, kv_lens)
+
     fn = jax.shard_map(
         part,
         mesh=mesh,
-        in_specs=(heads, pool, pool, rep2, rep1, P()),
+        in_specs=(heads, pool, pool, P(None, None), P(None))
+        + (P(),) * len(scalars),
         out_specs=heads,
         check_vma=False,
     )
-    return fn(q, k_pool_l, v_pool_l, page_table, kv_lens,
-              jnp.asarray(window, jnp.int32).reshape(1))
+    return fn(q, k_pool, v_pool, page_table, kv_lens, *scalars)
 
 
 @functools.partial(
@@ -215,12 +258,15 @@ def decode_paged_attention_sharded(
 )
 def decode_paged_attention(
     q: jax.Array,  # [B, Hk, G, D]
-    k_pool_l: jax.Array,  # [NP, PS, Hk, D] one layer's token-major key pool
-    v_pool_l: jax.Array,
+    k_pool: jax.Array,  # [L, NP, PS, Hk, D] the stacked token-major key
+    #   pool (or one layer's [NP, PS, Hk, D]: see stacked_pools)
+    v_pool: jax.Array,
     page_table: jax.Array,  # [B, MP] int32
     kv_lens: jax.Array,  # [B] int32 (context length incl. current token)
     window=None,  # None = no-window compile; else a traced int32 scalar
     #   (0 = global at runtime) — Gemma-2 alternates per layer in the scan
+    layer=None,  # traced int32 scalar: the layer of the stacked pool to
+    #   read; rides the scan as a prefetch operand like `window`
     *,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
     softcap: float = 0.0,  # Gemma-2 logit soft capping (static; 0 = off)
@@ -229,16 +275,16 @@ def decode_paged_attention(
     """Returns [B, Hk, G, D]. KV for the current token must already be
     written to the pool (same contract as paged_attention_jnp)."""
     B, Hk, G, D = q.shape
-    quantized = isinstance(k_pool_l, dict)
-    kq = k_pool_l["q"] if quantized else k_pool_l
-    NP, PS, _, _ = kq.shape
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    kq, vq, ks, vs = split_scales(k_pool, v_pool, layer)
+    quantized = ks is not None
+    _, NP, PS, _, _ = kq.shape
     MP = page_table.shape[1]
     if scale is None:
         scale = D**-0.5
     windowed = window is not None
-    n_prefetch = 3 if windowed else 2
 
-    def _clamp(b, i, pt, kl, *rest):
+    def _clamp(b, i, pt, kl, ly, *rest):
         # clamp past-the-end pages to the last valid page: the block index
         # then repeats across those grid steps and Pallas skips the DMA,
         # so a 128-token context in an 8192-token table costs 2 page
@@ -253,19 +299,19 @@ def decode_paged_attention(
             i_eff = jnp.maximum(i_eff, jnp.minimum(lo // PS, last))
         return i_eff
 
-    def kv_index(b, i, pt, kl, *rest):
-        return (pt[b, _clamp(b, i, pt, kl, *rest)], 0, 0, 0)
+    def kv_index(b, i, pt, kl, ly, *rest):
+        return (ly[0], pt[b, _clamp(b, i, pt, kl, ly, *rest)], 0, 0, 0)
 
-    def scale_index(b, i, pt, kl, *rest):
-        return kv_index(b, i, pt, kl, *rest)[:3]
+    def scale_index(b, i, pt, kl, ly, *rest):
+        return kv_index(b, i, pt, kl, ly, *rest)[1:4]
 
-    def fixed_index(b, i, pt, kl, *rest):
+    def fixed_index(b, i, pt, kl, ly, *rest):
         return (b, 0, 0, 0)
 
     q_spec = pl.BlockSpec((None, Hk, G, D), fixed_index)
-    # one token-major page = one contiguous PS*Hk*D slab: a single DMA,
-    # with a legal (PS, Hk, D) tile (minor dims (Hk, D))
-    kv_spec = pl.BlockSpec((None, PS, Hk, D), kv_index)
+    # one token-major page of one layer = one contiguous PS*Hk*D slab: a
+    # single DMA, with a legal (PS, Hk, D) tile (minor dims (Hk, D))
+    kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, scale=scale, softcap=softcap)
     if quantized:
         kernel = functools.partial(
@@ -274,16 +320,18 @@ def decode_paged_attention(
         # (None, PS, Hk): minor dims are full array dims — legal tile
         s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
         in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
-        operands = (q, kq, k_pool_l["s"], v_pool_l["q"], v_pool_l["s"])
+        operands = (q, kq, ks, vq, vs)
     else:
         kernel = functools.partial(
             _decode_kernel_win if windowed else _decode_kernel, **kw
         )
         in_specs = [q_spec, kv_spec, kv_spec]
-        operands = (q, kq, v_pool_l)
+        operands = (q, kq, vq)
 
+    prefetch = (page_table, kv_lens) + scalar_operands(layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # page_table, kv_lens (+ window)
+        num_scalar_prefetch=len(prefetch),  # page_table, kv_lens, layer
+        #   (+ window)
         grid=(B, MP),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, Hk, G, D), fixed_index),
@@ -294,11 +342,6 @@ def decode_paged_attention(
         ],
     )
 
-    prefetch = (page_table, kv_lens)
-    if windowed:
-        prefetch = prefetch + (
-            jnp.asarray(window, jnp.int32).reshape(1),
-        )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -29,7 +29,8 @@ block keep the q and out blocks resident (same block index -> Pallas elides
 the DMA), and each unit read-modify-writes ONLY its rows of the out block
 under a row mask at finalize. Units are emitted in increasing-row order so
 a later unit never clobbers an earlier one's rows. K/V pages stream exactly
-as in the decode kernel (ops/paged_attention.py): the index_map clamps dead
+as in the decode kernel (ops/paged_attention.py), from the layer-stacked
+pool [L, NP, PS, Hk, D] at a scalar-prefetched layer: the index_map clamps dead
 pages (causal top, kv_len, window low bound) to a repeated index so their
 copies are elided, and a `needed` guard skips their compute.
 
@@ -57,6 +58,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.paged_attention import (
+    scalar_operands, split_scales, stacked_pools,
+)
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
 
 NEG_INF = -1e30
@@ -187,6 +191,7 @@ def _ragged_kernel_body(
     meta_ref,  # [5, NW] int32 (seg, qblk, rs, rows, qpos0)
     pt_ref,  # [SEG, MP] int32 per-segment page-table rows
     kvl_ref,  # [SEG] int32 per-segment context length
+    #   (the pool's layer [1] rides next; only the index maps read it)
     win_ref,  # [1] int32 sliding window (0 = global) or None
     # blocks
     q_ref,  # [Hk, QB*G, D] (row r is block token r // G, group r % G)
@@ -286,22 +291,23 @@ def _ragged_kernel_body(
         o_ref[...] = jnp.where(keep, res, prev).astype(o_ref.dtype)
 
 
-def _ragged_kernel(meta, pt, kl, q, k, v, o, m, l, acc, **kw):
+def _ragged_kernel(meta, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
     _ragged_kernel_body(meta, pt, kl, None, q, k, v, None, None,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_win(meta, pt, kl, win, q, k, v, o, m, l, acc, **kw):
+def _ragged_kernel_win(meta, pt, kl, ly, win, q, k, v, o, m, l, acc, **kw):
     _ragged_kernel_body(meta, pt, kl, win, q, k, v, None, None,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_int8(meta, pt, kl, q, k, ks, v, vs, o, m, l, acc, **kw):
+def _ragged_kernel_int8(meta, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc,
+                        **kw):
     _ragged_kernel_body(meta, pt, kl, None, q, k, v, ks, vs,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_int8_win(meta, pt, kl, win, q, k, ks, v, vs, o, m, l,
+def _ragged_kernel_int8_win(meta, pt, kl, ly, win, q, k, ks, v, vs, o, m, l,
                             acc, **kw):
     _ragged_kernel_body(meta, pt, kl, win, q, k, v, ks, vs,
                         o, m, l, acc, **kw)
@@ -340,14 +346,15 @@ def ragged_attention_reference(
 
 def ragged_paged_attention_sharded(
     q: jax.Array,  # [T, Hk, G, D] heads sharded over `axis_name`
-    k_pool_l,
-    v_pool_l,
+    k_pool,  # [L, NP, PS, Hk, D] heads sharded over `axis_name`
+    v_pool,
     seg_page_table: jax.Array,
     seg_kv_lens: jax.Array,
     meta: jax.Array,
     mesh,
     axis_name: str = AXIS_MODEL,
     window=None,
+    layer=None,  # traced int32 scalar, replicated
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -359,24 +366,26 @@ def ragged_paged_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     heads, pool, scales = attention_specs(axis_name)
-    if isinstance(k_pool_l, dict):  # int8 KV: scales [NP, PS, Hk]
+    if isinstance(k_pool, dict):  # int8 KV: scales [L, NP, PS, Hk]
         pool = {"q": pool, "s": scales}
-    part = functools.partial(
-        ragged_paged_attention, q_block=q_block, scale=scale,
-        softcap=softcap, interpret=interpret,
-    )
-    base_specs = (heads, pool, pool, P(None, None), P(None), P(None, None))
-    extra = (
-        () if window is None
-        else (jnp.asarray(window, jnp.int32).reshape(1),)
-    )
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    scalars = scalar_operands(layer, window)
+
+    def part(q, k_pool, v_pool, seg_pt, seg_kvl, meta, layer, window=None):
+        return ragged_paged_attention(
+            q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, layer,
+            q_block=q_block, scale=scale, softcap=softcap,
+            interpret=interpret,
+        )
+
     fn = jax.shard_map(
         part, mesh=mesh,
-        in_specs=base_specs + ((P(),) if extra else ()),
+        in_specs=(heads, pool, pool, P(None, None), P(None), P(None, None))
+        + (P(),) * len(scalars),
         out_specs=heads, check_vma=False,
     )
-    return fn(q, k_pool_l, v_pool_l, seg_page_table, seg_kv_lens, meta,
-              *extra)
+    return fn(q, k_pool, v_pool, seg_page_table, seg_kv_lens, meta,
+              *scalars)
 
 
 @functools.partial(
@@ -384,12 +393,14 @@ def ragged_paged_attention_sharded(
 )
 def ragged_paged_attention(
     q: jax.Array,  # [T, Hk, G, D] flat query tokens (all segments)
-    k_pool_l,  # [NP, PS, Hk, D] token-major (or int8 {"q","s"} dict)
-    v_pool_l,
+    k_pool,  # [L, NP, PS, Hk, D] stacked token-major pool (or int8
+    #   {"q","s"} dict; or one layer's [NP, PS, Hk, D]: stacked_pools)
+    v_pool,
     seg_page_table: jax.Array,  # [SEG, MP] int32
     seg_kv_lens: jax.Array,  # [SEG] int32
     meta: jax.Array,  # [5, NW] int32 work units (build_ragged_metadata)
     window=None,  # None = no-window compile; else traced int32 scalar
+    layer=None,  # traced int32 scalar: the stacked pool's layer to read
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -401,9 +412,10 @@ def ragged_paged_attention(
     to the pool. The compile key is (T, NW, SEG, q_block) — all functions
     of the T bucket, so variants stay at |T buckets|."""
     T, Hk, G, D = q.shape
-    quantized = isinstance(k_pool_l, dict)
-    kq = k_pool_l["q"] if quantized else k_pool_l
-    NP, PS, _, _ = kq.shape
+    k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
+    kq, vq, ks, vs = split_scales(k_pool, v_pool, layer)
+    quantized = ks is not None
+    _, NP, PS, _, _ = kq.shape
     MP = seg_page_table.shape[1]
     if T % q_block:
         raise ValueError(f"T {T} not a multiple of q_block {q_block}")
@@ -411,14 +423,13 @@ def ragged_paged_attention(
     if scale is None:
         scale = D**-0.5
     windowed = window is not None
-    n_prefetch = 4 if windowed else 3
 
     # group axis merged into the rows HERE, in XLA: a [.., G, D] block
     # pads G up to a full sublane tile in VMEM and Mosaic cannot
     # shape-cast every (QB, G) split (G == 1 fails to lower)
     qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
 
-    def _clamp(w, i, mt, pt, kl, *rest):
+    def _clamp(w, i, mt, pt, kl, ly, *rest):
         # clamp dead pages (causal top, kv_len, window low bound) to a
         # repeated index so Pallas elides their DMA — flash-prefill trick,
         # per work unit instead of per (b, sb)
@@ -436,19 +447,20 @@ def ragged_paged_attention(
             i_eff = jnp.maximum(i_eff, jnp.minimum(lo // PS, last))
         return seg, i_eff
 
-    def kv_index(w, i, mt, pt, kl, *rest):
-        seg, i_eff = _clamp(w, i, mt, pt, kl, *rest)
-        return (pt[seg, i_eff], 0, 0, 0)
+    def kv_index(w, i, mt, pt, kl, ly, *rest):
+        seg, i_eff = _clamp(w, i, mt, pt, kl, ly, *rest)
+        return (ly[0], pt[seg, i_eff], 0, 0, 0)
 
-    def scale_index(w, i, mt, pt, kl, *rest):
-        return kv_index(w, i, mt, pt, kl, *rest)[:3]
+    def scale_index(w, i, mt, pt, kl, ly, *rest):
+        return kv_index(w, i, mt, pt, kl, ly, *rest)[1:4]
 
-    def q_index(w, i, mt, pt, kl, *rest):
+    def q_index(w, i, mt, pt, kl, ly, *rest):
         return (0, mt[1, w], 0)
 
     q_spec = pl.BlockSpec((Hk, q_block * G, D), q_index)
-    # one token-major page = one contiguous PS*Hk*D slab (single DMA)
-    kv_spec = pl.BlockSpec((None, PS, Hk, D), kv_index)
+    # one token-major page of one layer = one contiguous PS*Hk*D slab
+    # (single DMA)
+    kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, n_groups=G, scale=scale, softcap=softcap)
     if quantized:
         kernel = functools.partial(
@@ -457,16 +469,19 @@ def ragged_paged_attention(
         )
         s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
         in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
-        operands = (qt, kq, k_pool_l["s"], v_pool_l["q"], v_pool_l["s"])
+        operands = (qt, kq, ks, vq, vs)
     else:
         kernel = functools.partial(
             _ragged_kernel_win if windowed else _ragged_kernel, **kw
         )
         in_specs = [q_spec, kv_spec, kv_spec]
-        operands = (qt, kq, v_pool_l)
+        operands = (qt, kq, vq)
 
+    prefetch = (meta, seg_page_table, seg_kv_lens) + scalar_operands(
+        layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # meta, seg_pt, seg_kvl (+ window)
+        num_scalar_prefetch=len(prefetch),  # meta, seg_pt, seg_kvl, layer
+        #   (+ window)
         grid=(NW, MP),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -477,11 +492,6 @@ def ragged_paged_attention(
         ],
     )
 
-    prefetch = (meta, seg_page_table, seg_kv_lens)
-    if windowed:
-        prefetch = prefetch + (
-            jnp.asarray(window, jnp.int32).reshape(1),
-        )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

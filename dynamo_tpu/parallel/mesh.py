@@ -49,13 +49,15 @@ SPEC_SEQ_ACT = P(None, AXIS_SEQ, None)
 
 # attention wrappers (ops/*_attention.py): flat-token / decode q
 # [T|B, Hk, G, D] and prefill q [B, S, Hk, G, D] shard kv-heads on
-# `model`; per-layer paged KV [NP, PS, Hk, D] + int8 scales [NP, PS, Hk]
+# `model`, and so does the layer-stacked pool they read
 SPEC_HEADS_TOK = P(None, AXIS_MODEL, None, None)
 SPEC_HEADS_BATCH = P(None, None, AXIS_MODEL, None, None)
+# one layer's paged KV [NP, PS, Hk, D] (the prefill→decode handoff)
 SPEC_KV_PAGES = P(None, None, AXIS_MODEL, None)
-SPEC_KV_SCALES = P(None, None, AXIS_MODEL)
-# layer-stacked pools [L, NP, PS, Hk, D] (ops/block_copy.py exports)
+# layer-stacked pools [L, NP, PS, Hk, D] + int8 scales [L, NP, PS, Hk]
+# (the attention wrappers' operands; ops/block_copy.py exports)
 SPEC_KV_POOL = P(None, None, None, AXIS_MODEL, None)
+SPEC_KV_POOL_SCALES = P(None, None, None, AXIS_MODEL)
 # MLA latent pool [NP, PS, 1, Dl]: Hk == 1 by construction (the cache is
 # per-token latent, not per-head), so it CANNOT shard kv-heads and is
 # small enough to replicate — deliberately, hence a named declaration
@@ -81,19 +83,19 @@ def ring_specs(axis: str = AXIS_SEQ) -> Tuple[P, P, P]:
 
 
 def attention_specs(axis: str = AXIS_MODEL) -> Tuple[P, P, P]:
-    """(heads, kv_pages, kv_scales) for flat-token/decode attention."""
+    """(heads, kv_pool, kv_pool_scales) for flat-token/decode attention."""
     if axis == AXIS_MODEL:
-        return SPEC_HEADS_TOK, SPEC_KV_PAGES, SPEC_KV_SCALES
-    return (P(None, axis, None, None), P(None, None, axis, None),
-            P(None, None, axis))
+        return SPEC_HEADS_TOK, SPEC_KV_POOL, SPEC_KV_POOL_SCALES
+    return (P(None, axis, None, None), kv_pool_specs(axis),
+            P(None, None, None, axis))
 
 
 def prefill_attention_specs(axis: str = AXIS_MODEL) -> Tuple[P, P, P]:
-    """(heads, kv_pages, kv_scales) for batched [B, S, ...] prefill."""
+    """(heads, kv_pool, kv_pool_scales) for batched [B, S, ...] prefill."""
     if axis == AXIS_MODEL:
-        return SPEC_HEADS_BATCH, SPEC_KV_PAGES, SPEC_KV_SCALES
-    return (P(None, None, axis, None, None), P(None, None, axis, None),
-            P(None, None, axis))
+        return SPEC_HEADS_BATCH, SPEC_KV_POOL, SPEC_KV_POOL_SCALES
+    return (P(None, None, axis, None, None), kv_pool_specs(axis),
+            P(None, None, None, axis))
 
 
 def moe_specs(axis: str = AXIS_EXPERT,

@@ -14,8 +14,12 @@ GQA checks — the cross product, every one REQUIRED to pass compiled:
 The ragged layout packs three decode segments and a chunk start into one
 q block (the finalize read-modify-write), chunks that cross block
 boundaries, a chunk continuing a long prior context, and a padded tail.
+Each of these reads a layer-STACKED pool [L, NP, PS, Hk, D] at a nonzero
+traced layer, as the model's layer scan does; every layer holds other
+data, so a kernel that indexed the wrong layer misses the reference.
 
-Also run: Gemma geometry (G 2, PS 16) decode/prefill softcap+window, MLA
+Also run: Gemma geometry (G 2, PS 16) decode/prefill softcap+window on one
+layer's pool [NP, PS, Hk, D] (the kernels' rank-4 view), MLA
 decode/prefill, MLA int8-latent decode (gates DYN_MLA_INT8_KERNEL), and
 the batched page copy/permute/scatter roundtrip (gates DYN_KV_COPY_KERNEL).
 
@@ -66,6 +70,7 @@ VARIANTS = {
     "int8-kv+window+softcap": (True, True),
 }
 SOFTCAP = 30.0
+LAYER = 2  # the layer the stacked pools are read at: the last of three
 
 
 def _max_err(out, ref) -> float:
@@ -75,18 +80,29 @@ def _max_err(out, ref) -> float:
 
 
 class _Pool:
-    """Random K/V pools plus a page allocator: every sequence gets its own
-    pages, and page-table entries past its context stay 0 (the kernels
-    clamp them away)."""
+    """Random layer-stacked K/V pools [3, NP, PS, Hk, D] plus a
+    page allocator: every sequence gets its own pages, and page-table
+    entries past its context stay 0 (the kernels clamp them away). The
+    kernels read layer LAYER; the reference reads `slab()`, that layer's
+    [NP, PS, Hk, D]."""
 
     def __init__(self, rng, geom, n_pages: int, quantized: bool):
         shape = (n_pages, geom["PS"], geom["Hk"], geom["D"])
-        self.k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-        self.v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        self.k = self._stack(jnp.asarray(rng.standard_normal(shape), jnp.bfloat16))
+        self.v = self._stack(jnp.asarray(rng.standard_normal(shape), jnp.bfloat16))
         if quantized:
             self.k, self.v = kv_pool_quantize(self.k), kv_pool_quantize(self.v)
         self._free = list(rng.permutation(n_pages))
         self.PS, self.MP = geom["PS"], geom["MP"]
+
+    @staticmethod
+    def _stack(slab):
+        """The drawn slab as layer LAYER, under two layers of other data
+        made from it on the device (negated; pages rolled by one)."""
+        return jnp.stack([-slab, jnp.roll(slab, 1, axis=0), slab])
+
+    def slab(self):
+        return jax.tree.map(lambda a: a[LAYER], (self.k, self.v))
 
     def table(self, kv_lens) -> np.ndarray:
         pt = np.zeros((len(kv_lens), self.MP), np.int32)
@@ -102,7 +118,7 @@ def _ref(q32, pool, pt, positions, kv, window, softcap):
     # truth. Only the page-table columns any context reaches are gathered.
     need = max(1, -(-int(np.max(kv)) // pool.PS))
     return paged_attention_jnp(
-        q32, pool.k, pool.v, jnp.asarray(pt[:, :need]), jnp.asarray(positions),
+        q32, *pool.slab(), jnp.asarray(pt[:, :need]), jnp.asarray(positions),
         jnp.asarray(kv), softcap=softcap,
         window=None if window is None else jnp.int32(window),
     )
@@ -125,7 +141,7 @@ def check_decode(geom, variant) -> float:
     q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
     out = decode_paged_attention(
         q, pool.k, pool.v, jnp.asarray(pt), jnp.asarray(kv),
-        None if window is None else jnp.int32(window),
+        None if window is None else jnp.int32(window), jnp.int32(LAYER),
         softcap=softcap, interpret=INTERPRET,
     )
     ref = _ref(q.astype(jnp.float32)[:, None], pool, pt, (kv - 1)[:, None],
@@ -149,7 +165,7 @@ def check_prefill(geom, variant) -> float:
     out = prefill_paged_attention(
         q, pool.k, pool.v, jnp.asarray(pt), jnp.asarray(qs), jnp.asarray(ql),
         jnp.asarray(kv), None if window is None else jnp.int32(window),
-        softcap=softcap, interpret=INTERPRET,
+        jnp.int32(LAYER), softcap=softcap, interpret=INTERPRET,
     )
     pos = qs[:, None] + np.arange(S, dtype=np.int32)[None, :]
     ref = _ref(q.astype(jnp.float32), pool, pt, pos, kv, window, softcap)
@@ -178,7 +194,7 @@ def check_ragged(geom, variant) -> float:
     out = ragged_paged_attention(
         q, pool.k, pool.v, jnp.asarray(md["seg_page_table"]),
         jnp.asarray(md["seg_kv_lens"]), jnp.asarray(md["meta"]),
-        None if window is None else jnp.int32(window),
+        None if window is None else jnp.int32(window), jnp.int32(LAYER),
         softcap=softcap, interpret=INTERPRET,
     )
     # reference per segment (a B=1, S=q_len row of paged_attention_jnp —

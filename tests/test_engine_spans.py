@@ -312,6 +312,33 @@ def test_compile_counter_sees_eager_programs_once():
     assert set(after["other"]) == set(after["forward"])
 
 
+def test_family_counts_programs_not_call_signatures():
+    """The first step on a fresh KV pool sees the sharding the pool was
+    allocated under; the step hands it back under the equivalent sharding
+    XLA reports, so the same step again is a new entry of jit's dispatch
+    cache that resolves to the program already built. That is a call
+    signature, not a compiled variant (on the chip it read
+    `runner.compiles_in_window` 1 with nothing compiled, PERF.md section
+    7, PR 25); a new bucket still counts."""
+    r = _runner()
+    samp = {"temperature": [0.0], "top_k": [0], "top_p": [1.0], "seeds": [1]}
+    fam = r._families["decode_loop"]
+
+    def step(tokens):
+        n = len(tokens)
+        r.decode_multi(1, tokens, [0] * n, [[i] for i in range(n)],
+                       {k: v * n for k, v in samp.items()}, 0)
+        return fam._cache_size(), fam.stats()
+
+    size1, first = step([3])
+    assert first["variants"] == 1 and first["compile_s"] > 0
+    size2, again = step([3])
+    assert size2 == size1 + 1, "the pool came back under the same sharding"
+    assert (again["variants"], again["compile_s"]) == (1, first["compile_s"])
+    assert step([3])[0] == size2
+    assert step([3, 5])[1]["variants"] == 2
+
+
 def test_spine_decomposes_ttft(recorded):
     """A finished request's spine: prefill_s, prefill_iters, preemptions,
     and ttft_s as the sum of its three parts."""

@@ -921,3 +921,281 @@ def test_build_ragged_metadata_overflow():
     with pytest.raises(ValueError):
         build_ragged_metadata([1] * 5, [0] * 5, [1] * 5, [[0]] * 5, 8,
                               max_segs=4)
+
+
+# -- layer-stacked pools: the kernels read [L, NP, PS, Hk, D] at a layer ----
+_STACK_L = 4
+
+
+def _stacked_case(kernel, *, quant=False, window=None, softcap=0.0,
+                  sharded=False, seed=31):
+    """One kernel over a stacked pool with different data in every layer.
+    Returns (run, ref): run(k_pool, v_pool, layer) calls the kernel
+    (`layer` None for a per-layer pool), ref(layer) is the jnp path on
+    that layer's slab; both give the rows that hold real tokens."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        ragged_attention_reference, ragged_paged_attention,
+        ragged_paged_attention_sharded,
+    )
+
+    rng = np.random.default_rng(seed)
+    L, Hk, G, D, NP, PS, MP = _STACK_L, 2, 3, 64, 48, 8, 8
+    kp = jnp.asarray(rng.standard_normal((L, NP, PS, Hk, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((L, NP, PS, Hk, D)), jnp.bfloat16)
+    if quant:
+        kp, vp = _q_pools(kp, vp)
+    win = None if window is None else jnp.int32(window)
+    kw = dict(softcap=softcap, interpret=True)
+    if sharded:
+        from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+        mesh = make_mesh(MeshConfig(model=2))
+    slab = lambda pool, l: jax.tree.map(lambda a: a[l], pool)  # noqa: E731
+
+    if kernel == "decode":
+        from dynamo_tpu.ops.paged_attention import (
+            decode_paged_attention_sharded,
+        )
+
+        B = 4
+        q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
+        pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP)
+                         .astype(np.int32))
+        kv = jnp.asarray([5, 17, 64, 1], jnp.int32)
+
+        def run(k, v, layer):
+            if sharded:
+                return decode_paged_attention_sharded(
+                    q, k, v, pt, kv, mesh, window=win, layer=layer, **kw)
+            return decode_paged_attention(q, k, v, pt, kv, win, layer, **kw)
+
+        def ref(l):
+            return paged_attention_jnp(
+                q[:, None], slab(kp, l), slab(vp, l), pt, (kv - 1)[:, None],
+                kv, softcap=softcap, window=win)[:, 0]
+
+    elif kernel == "prefill":
+        from dynamo_tpu.ops.flash_prefill import (
+            prefill_paged_attention_sharded,
+        )
+
+        B, S = 2, 16
+        q = jnp.asarray(rng.standard_normal((B, S, Hk, G, D)), jnp.bfloat16)
+        pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP)
+                         .astype(np.int32))
+        qs, ql = jnp.asarray([24, 0], jnp.int32), jnp.asarray([16, 16], jnp.int32)
+        kv = qs + ql
+        pos = qs[:, None] + jnp.arange(S)[None]
+
+        def run(k, v, layer):
+            if sharded:
+                return prefill_paged_attention_sharded(
+                    q, k, v, pt, qs, ql, kv, mesh, window=win, layer=layer,
+                    q_block=8, **kw)
+            return prefill_paged_attention(
+                q, k, v, pt, qs, ql, kv, win, layer, q_block=8, **kw)
+
+        def ref(l):
+            return paged_attention_jnp(
+                q, slab(kp, l), slab(vp, l), pt, pos, kv, softcap=softcap,
+                window=win)
+
+    else:
+        q, _kp, _vp, md, (q_lens, *_rest) = _ragged_case(seed, NP=NP, PS=PS,
+                                                         MP=MP)
+        T = int(sum(q_lens))
+        seg = [jnp.asarray(md[k]) for k in
+               ("seg_page_table", "seg_kv_lens", "meta")]
+        tok = [jnp.asarray(md[k]) for k in
+               ("tok_page_table", "tok_positions", "tok_kv_lens")]
+
+        def run(k, v, layer):
+            if sharded:
+                return ragged_paged_attention_sharded(
+                    q, k, v, *seg, mesh, window=win, layer=layer, **kw)[:T]
+            return ragged_paged_attention(q, k, v, *seg, win, layer, **kw)[:T]
+
+        def ref(l):
+            return ragged_attention_reference(
+                q, slab(kp, l), slab(vp, l), *tok, softcap=softcap,
+                window=win)[:T]
+
+    return kp, vp, run, ref
+
+
+_STACKED_CASES = {
+    "decode": dict(kernel="decode"),
+    "prefill": dict(kernel="prefill"),
+    "ragged": dict(kernel="ragged"),
+    "decode-int8": dict(kernel="decode", quant=True),
+    "prefill-int8-window": dict(kernel="prefill", quant=True, window=6,
+                                softcap=15.0),
+    "ragged-int8": dict(kernel="ragged", quant=True),
+    "decode-window": dict(kernel="decode", window=11, softcap=20.0),
+    "ragged-window": dict(kernel="ragged", window=16, softcap=30.0),
+    "decode-sharded": dict(kernel="decode", sharded=True),
+    "prefill-sharded-window": dict(kernel="prefill", sharded=True, window=5),
+    "ragged-sharded-int8": dict(kernel="ragged", sharded=True, quant=True),
+}
+
+
+@pytest.mark.parametrize("layer", [0, 2, _STACK_L - 1])
+@pytest.mark.parametrize("case", list(_STACKED_CASES))
+def test_kernels_read_stacked_pool_at_layer(case, layer):
+    """Each kernel takes the stacked pool and a traced layer (a
+    scalar-prefetch operand beside page_table/kv_lens/window) and must
+    read THAT layer's pages: every layer holds different data, so a
+    kernel that read another layer, or took the window for the layer,
+    misses the jnp reference on pool[layer]."""
+    cfg = _STACKED_CASES[case]
+    if cfg.get("sharded") and len(jax.devices()) < 2:
+        pytest.skip("needs multi-device mesh")
+    kp, vp, run, ref = _stacked_case(**cfg)
+    out = run(kp, vp, jnp.int32(layer))
+    want = ref(layer)
+    d = np.abs(np.asarray(out, np.float32) - np.asarray(want, np.float32)).max()
+    assert d < 3e-2, d
+    other = ref((layer + 1) % _STACK_L)
+    d_other = np.abs(np.asarray(out, np.float32)
+                     - np.asarray(other, np.float32)).max()
+    assert d_other > 0.2, d_other  # the layers really differ
+
+
+@pytest.mark.parametrize(
+    "case", ["decode", "prefill", "ragged", "decode-int8", "ragged-window",
+             "decode-sharded"],
+)
+def test_per_layer_pool_is_the_one_layer_stack(case):
+    """A pool of rank 4 is viewed as `pool[None]` at layer 0: the same
+    program, so the two calls agree bit for bit."""
+    cfg = _STACKED_CASES[case]
+    if cfg.get("sharded") and len(jax.devices()) < 2:
+        pytest.skip("needs multi-device mesh")
+    kp, vp, run, _ref = _stacked_case(**cfg)
+    k1, v1 = jax.tree.map(lambda a: a[1], (kp, vp))
+    per_layer = run(k1, v1, None)
+    stacked = run(*jax.tree.map(lambda a: a[None], (k1, v1)), jnp.int32(0))
+    np.testing.assert_array_equal(np.asarray(per_layer, np.float32),
+                                  np.asarray(stacked, np.float32))
+    # and the same bits as reading layer 1 of the full stack
+    np.testing.assert_array_equal(
+        np.asarray(per_layer, np.float32),
+        np.asarray(run(kp, vp, jnp.int32(1)), np.float32))
+
+
+def test_stacked_pool_operand_contract():
+    """A stacked pool without a layer, or a per-layer pool with one, is a
+    caller's slip, not a default."""
+    kp, vp, run, _ref = _stacked_case("decode")
+    with pytest.raises(ValueError):
+        run(kp, vp, None)
+    with pytest.raises(ValueError):
+        run(kp[0], vp[0], jnp.int32(0))
+
+
+# -- the model's layer scan hands the kernels the stacked pool --------------
+def _interpret_attention_kernels(monkeypatch):
+    import dynamo_tpu.ops.flash_prefill as fp_ops
+    import dynamo_tpu.ops.paged_attention as pa_ops
+    import dynamo_tpu.ops.ragged_paged_attention as rg_ops
+
+    for mod, name in ((pa_ops, "decode_paged_attention"),
+                      (fp_ops, "prefill_paged_attention"),
+                      (rg_ops, "ragged_paged_attention")):
+        monkeypatch.setattr(
+            mod, name, functools.partial(getattr(mod, name), interpret=True))
+
+
+def _three_layer_forward_case(mode):
+    """forward() inputs for a 3-layer model in one of the three Pallas
+    modes, over a pool that already holds a 6-token context for row 0.
+    Returns (slab shape, call) with call(attn_impl) -> (logits, k_pool,
+    v_pool)."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import get_config
+    from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
+
+    c = get_config("tiny").with_(n_layers=3)
+    # f32 weights and pool: the two paths then differ by reduction order
+    # alone, and a wrong layer or page cannot hide in bf16 rounding
+    p = llama.init_params(c, jax.random.PRNGKey(4), dtype=jnp.float32)
+    NP, PS, MP = 16, 4, 4
+    k0, v0 = llama.make_kv_pool(c, NP, PS, dtype=jnp.float32)
+    rows = [[3, 9, 1, 12], [7, 2, 14, 5]]
+    pt = jnp.asarray(rows, jnp.int32)
+    ctx = [5, 9, 2, 7, 1, 3]
+    _, k0, v0 = llama.forward(
+        c, p, jnp.asarray([ctx]), jnp.arange(6)[None], k0, v0, pt[:1],
+        jnp.asarray([6]),
+    )
+    if mode == "decode":
+        args = (jnp.asarray([[8]]), jnp.asarray([[6]]), k0, v0, pt[:1],
+                jnp.asarray([7]))
+        kw = {}
+    elif mode == "prefill":  # a chunk on prior context + a fresh, padded one
+        toks = jnp.asarray([[8, 4, 6, 2, 9, 1, 7, 3], [6, 5, 4, 3, 2, 0, 0, 0]])
+        pos = jnp.asarray([list(range(6, 14)), [0, 1, 2, 3, 4, -1, -1, -1]])
+        args = (toks, pos, k0, v0, pt, jnp.asarray([14, 5]))
+        kw = {}
+    else:  # ragged: row 0 decodes one token, row 1 brings a 9-token chunk
+        md = build_ragged_metadata([1, 9], [6, 0], [7, 9], rows, 16,
+                                   max_pages=MP)
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :10] = [8, 6, 5, 4, 3, 2, 9, 1, 7, 3]
+        args = (jnp.asarray(toks), jnp.asarray(md["tok_positions"])[None],
+                k0, v0, jnp.asarray(md["tok_page_table"]),
+                jnp.asarray(md["tok_kv_lens"]))
+        kw = dict(
+            last_index=jnp.asarray(md["last_index"]),
+            ragged=tuple(jnp.asarray(md[k]) for k in
+                         ("seg_page_table", "seg_kv_lens", "meta")),
+        )
+
+    def call(attn_impl):
+        return llama.forward(c, p, *args, attn_impl=attn_impl, **kw)
+
+    return (NP, PS, c.n_kv_heads, c.head_dim), call
+
+
+def _slab_equations(jaxpr, slab, found=None):
+    """Every equation, at any depth, whose output is one layer's slab of a
+    pool ([NP, PS, Hk, D], with or without a leading 1)."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            shape = tuple(getattr(v.aval, "shape", ()))
+            if shape in (slab, (1,) + slab):
+                found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _slab_equations(sub, slab, found)
+    return found
+
+
+@pytest.mark.parametrize("mode", ["decode", "prefill", "ragged"])
+def test_pallas_layer_scan_slices_no_pool_slab(mode, monkeypatch):
+    """The mechanism, where a CPU can see it: with attn_impl="pallas" the
+    traced layer scan produces no value of a pool slab's shape (the
+    kernels index the stacked pool themselves); the jnp path, which
+    gathers pages from `pool[l]`, does — so the check can see one."""
+    _interpret_attention_kernels(monkeypatch)
+    slab, call = _three_layer_forward_case(mode)
+    on_jnp = _slab_equations(jax.make_jaxpr(lambda: call("jnp"))().jaxpr, slab)
+    assert any(n in ("dynamic_slice", "gather") for n in on_jnp), on_jnp
+    on_pallas = _slab_equations(
+        jax.make_jaxpr(lambda: call("pallas"))().jaxpr, slab)
+    assert on_pallas == []
+
+
+@pytest.mark.parametrize("mode", ["decode", "prefill", "ragged"])
+def test_three_layer_forward_pallas_matches_jnp(mode, monkeypatch):
+    """A whole forward over 3 layers through each Pallas kernel (interpret)
+    == the jnp path: logits, and the pools both wrote."""
+    _interpret_attention_kernels(monkeypatch)
+    _slab, call = _three_layer_forward_case(mode)
+    ref, k_ref, v_ref = call("jnp")
+    got, k_got, v_got = call("pallas")
+    if mode == "prefill":  # padded rows of the fresh chunk carry garbage
+        ref, got = (jnp.concatenate([x[0], x[1, :5]]) for x in (ref, got))
+    for a, b in ((got, ref), (k_got, k_ref), (v_got, v_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
