@@ -38,6 +38,7 @@ ROOT = os.path.dirname(HERE)
 sys.path[:0] = [HERE, os.path.join(HERE, "layers")]  # loadgen, generators/, costs, readers
 
 import loadgen  # noqa: E402
+import rehearsal  # noqa: E402
 
 CHILDREN: list = []
 
@@ -185,9 +186,8 @@ def run(args) -> dict:
     model = dict(cfg["model"])
     divisor = 1
     if args.rehearse:
-        reh = read_json(os.path.join(HERE, "rehearse.json"))
-        model.update(reh["model"])
-        divisor = int(reh["length_divisor"])
+        reh = rehearsal.rehearsal_sizes(cfg, HERE)
+        model, divisor = reh["model"], reh["length_divisor"]
     vocab = int(model["vocab_size"])
 
     run_dir = os.path.join(ROOT, ".bench_runs", args.workload)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import importlib.util
 import json
 import os
@@ -55,44 +56,79 @@ def load_module(path: str, name: str):
 # -- weights ---------------------------------------------------------------
 
 
-def make_params(config, seed: int, device, dtype):
-    """The whole parameter tree on `device` from the seed in ONE jitted
-    call, in the type it is served in: no host arrays, no eager per-leaf
-    programs, and f32 temporaries of one layer's leaf at most (the program's
-    own init_params peaks at 13.48 GB for a 6.4 GB model, PERF.md PR 21).
-    Same recipe as models/llama.py: normal x fan_in^-0.5, norms 1.0."""
+def drawn_leaves(config, dtype) -> list:
+    """For each leaf of `llama.init_params(config, key, dtype)`, in flatten
+    order: does its value depend on the key? Read off the program's own
+    jaxpr (traced at the full configuration, nothing computed): an output
+    that no equation connects to the key is one the program fills (norm
+    weights at 1.0 or 0.0, `router_bias`, projection biases). No list of
+    leaf names is kept here."""
+    import jax
+
+    from dynamo_tpu.models import llama
+
+    jaxpr = jax.make_jaxpr(lambda k: llama.init_params(config, k, dtype))(
+        jax.random.PRNGKey(0)).jaxpr
+    keyed = {id(v) for v in jaxpr.invars}
+    for eqn in jaxpr.eqns:
+        if any(id(v) in keyed for v in eqn.invars):
+            keyed.update(id(v) for v in eqn.outvars)
+    return [id(v) in keyed for v in jaxpr.outvars]
+
+
+def params_program(config, dtype):
+    """`build(key) -> tree`: the function make_params jits. A leaf the
+    program draws: normal x fan_in^-0.5 (fan_in `shape[-2]`, `embed`:
+    `shape[-1]`), every leading axis (layers, experts) mapped over with a key
+    split for it, so one `[in, out]` f32 temporary exists at a time. A leaf
+    the program fills (`drawn_leaves`): the program's own value, taken from
+    `init_params` inside the same program, where XLA drops the draws nobody
+    reads."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
     from dynamo_tpu.models import llama
 
     shapes = jax.eval_shape(
         lambda: llama.init_params(config, jax.random.PRNGKey(0), dtype))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    drawn = drawn_leaves(config, dtype)
 
     def build(key):
+        fills = jax.tree_util.tree_leaves(
+            llama.init_params(config, jax.random.PRNGKey(0), dtype))
         out = []
         for i, (path, sd) in enumerate(leaves):
+            if not drawn[i]:
+                out.append(fills[i])
+                continue
             k = jax.random.fold_in(key, i)
             name = getattr(path[-1], "key", str(path[-1]))
-            if sd.dtype == jnp.float32:  # norm weights
-                out.append(jnp.ones(sd.shape, sd.dtype))
-                continue
             fan_in = sd.shape[-1] if name == "embed" else sd.shape[-2]
 
-            def one(kk, shape=sd.shape[-2:], fan_in=fan_in, dt=sd.dtype):
-                return (jax.random.normal(kk, shape, jnp.float32)
-                        * (fan_in ** -0.5)).astype(dt)
+            def leaf(kk, lead=sd.shape[:-2], shape=sd.shape[-2:], fan_in=fan_in, dt=sd.dtype):
+                if not lead:
+                    return (jax.random.normal(kk, shape, jnp.float32)
+                            * (fan_in ** -0.5)).astype(dt)
+                return jax.lax.map(lambda kj: leaf(kj, lead[1:]),
+                                   jax.random.split(kk, lead[0]))
 
-            if len(sd.shape) == 3:  # [L, in, out]: one layer's f32 at a time
-                out.append(jax.lax.map(one, jax.random.split(k, sd.shape[0])))
-            else:
-                out.append(one(k))
+            out.append(leaf(k))
         return jax.tree_util.tree_unflatten(treedef, out)
 
+    return build
+
+
+def make_params(config, seed: int, device, dtype):
+    """The whole parameter tree on `device` from the seed in ONE jitted
+    call, in the type it is served in: no host arrays, no eager per-leaf
+    programs, and f32 temporaries of one matrix at most (the program's own
+    init_params peaks at 13.48 GB for a 6.4 GB model, PERF.md PR 21)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
     key = jax.random.key(int(seed) % (2**31 - 1), impl="rbg")
-    fn = jax.jit(build, out_shardings=SingleDeviceSharding(device))
+    fn = jax.jit(params_program(config, dtype), out_shardings=SingleDeviceSharding(device))
     return fn(jax.device_put(key, device))
 
 
@@ -113,7 +149,9 @@ def warm_lattice(engine) -> dict:
       ragged       T bucket <= max_batch + mixed_prefill_tokens
       decode_loop  again, chained on a ragged step: bucket x n_steps-1
       forward      prefill bucket <= chunk_size  x  prior context or none
-    and the eager slices of the ragged path's results (see below).
+    and the eager slices of the ragged path's results (see below); where the
+    runner has no ragged program for the model, the padded mixed program's
+      mixed        decode bucket x n_steps x prefill bucket x chunks packed
     Inputs are dummies that write page 0..n of an empty pool."""
     from dynamo_tpu.engine.model_runner import _next_bucket
 
@@ -163,6 +201,36 @@ def warm_lattice(engine) -> dict:
                     1, [1], [0], [[0]], _samp(1), 1, chunks)
                 for _, lg in zip(chunks, rows):
                     r.sample_one(lg, _samp(1), 1)
+    elif s.mixed_prefill_tokens > 0 and engine.fused_mixed and not r.pp:
+        # a model the ragged program shuts out (latent attention) rides the
+        # padded mixed program: decode bucket x n_steps x prefill bucket of
+        # the longest chunk x chunks packed (one chunk is a program of its
+        # own, two and more pad to a pack bucket). Traffic reaches every
+        # point: the scheduler fuses decode_steps steps, fewer when a row
+        # nears its max_tokens; any number of rows up to max_batch decode;
+        # a prompt's last chunk has any length up to the budget; up to
+        # mixed_prefill_seqs chunks share it. So the configuration's flags
+        # are the bound, and a configuration of this kind has to set them:
+        # --max-batch 16 --mixed-prefill-tokens 256 --mixed-prefill-seqs 1
+        # is 5 x 4 x 5 = 100 programs, the worker's defaults 560
+        c_max = _next_bucket(r.prefill_buckets, s.mixed_prefill_tokens)
+        k_max = _next_bucket(r.pack_buckets, s.mixed_prefill_seqs)
+        packs = [k for k in r.pack_buckets if 2 <= k <= k_max] if s.mixed_prefill_seqs > 1 else []
+        bs = [x for x in r.decode_buckets if x <= b_max]
+        sbs = [x for x in r.prefill_buckets if x <= c_max]
+        log(f"padded mixed lattice: {len(bs)} decode buckets x {s.decode_steps} steps x "
+            f"{len(sbs)} prefill buckets x {1 + len(packs)} packings")
+        for b in bs:
+            for n in range(1, s.decode_steps + 1):
+                for sb in sbs:
+                    per = 1 + sb // ps
+                    dec = (n, [1] * b, [0] * b, [[0]] * b, _samp(b), 1)
+                    r.decode_multi_with_prefill(*dec, [1] * sb, 0, list(range(1, 1 + per)), 0)
+                    for k in packs:
+                        r.decode_multi_with_prefills(*dec, [
+                            {"tokens": [1] * sb, "start": 0, "prior": 0, "adapter": 0,
+                             "table": list(range(1 + j * per, 1 + (j + 1) * per))}
+                            for j in range(k)])
     s_max = _next_bucket(r.prefill_buckets, s.chunk_size)
     logits = None
     for sb in [x for x in r.prefill_buckets if x <= s_max]:
@@ -229,42 +297,61 @@ async def served(engine, sample, logprobs: bool):
     return await asyncio.gather(*(one(i, ids, k) for i, (ids, k) in enumerate(sample)))
 
 
-def check_against_reference(ref, model: dict, params, sample, got, tol: float) -> dict:
+MOST_LEFT_OUT = 0.5  # a check that leaves out more than half has checked too little
+
+
+def check_against_reference(ref, model: dict, params, sample, got, tol: float,
+                            routing_tie: float = 0.0) -> dict:
     """Teacher-force the plain float32 reference on prompt + served tokens.
     Where the engine gave logprobs, compare the logprob of each served token
     (`max`, `mean`); in every case measure how far the served token lies
     under the reference's best one (`gap`): a greedy token picked from logits
-    that are each within `tol` lies within 2 x tol of it."""
+    that are each within `tol` lies within 2 x tol of it. A reference of a
+    routed model offers `check_at` (logprobs and each position's routing
+    margin); a configuration that states a `correct_routing_tie` has the
+    tokens under it left out, counted in `left_out`, and they may be at most
+    half of all."""
     import numpy as np
 
-    worst, total, gap, n, short, per = 0.0, 0.0, 0.0, 0, False, []
+    check_at = getattr(ref, "check_at", None) if routing_tie > 0 else None
+    worst, total, gap, n, short, per, left_out = 0.0, 0.0, 0.0, 0, False, [], 0
     for (ids, n_out), (toks, lps) in zip(sample, got):
         if len(toks) != n_out or len(lps) not in (0, n_out):
             short = True
             continue
         seq = np.asarray(list(ids) + toks[:-1], np.int32)
         at = list(range(len(ids) - 1, len(seq)))
-        logp = ref.logprobs_at(model, params, seq, at)  # [len(at), V] f32
-        want = logp[np.arange(len(toks)), np.asarray(toks)]
-        under = float((logp.max(axis=-1) - want).max())
-        row = {"prompt": len(ids), "tokens": len(toks), "gap": round(under, 4)}
+        if check_at:
+            logp, margin = check_at(model, params, seq, at)
+            keep = np.asarray(margin) >= routing_tie
+        else:
+            logp, keep = ref.logprobs_at(model, params, seq, at), np.ones(len(at), bool)
+        left_out += int((~keep).sum())
+        if not keep.any():
+            continue
+        want = logp[np.arange(len(toks)), np.asarray(toks)]  # logp [len(at), V] f32
+        under = float((logp.max(axis=-1) - want)[keep].max())
+        row = {"prompt": len(ids), "tokens": int(keep.sum()), "gap": round(under, 4)}
         if lps:
-            err = np.abs(want - np.asarray(lps))
+            err = np.abs(want - np.asarray(lps))[keep]
             worst, total = max(worst, float(err.max())), total + float(err.sum())
             row.update(max=round(float(err.max()), 4), mean=round(float(err.mean()), 4))
-        gap, n = max(gap, under), n + len(toks)
+        gap, n = max(gap, under), n + int(keep.sum())
         per.append(row)
     mean = total / max(n, 1)
     # the bound on the worst token is `tol`; the mean over tokens is held to
     # a third of it, which is the steadier of the two readings
-    return {"max_abs_logprob_err": worst, "mean_abs_logprob_err": mean,
-            "max_gap_under_best": gap, "tokens": n, "tolerance": tol, "per_prompt": per,
-            "ok": bool(n > 0 and not short and worst <= tol and mean <= tol / 3
-                       and gap <= 2 * tol)}
+    out = {"max_abs_logprob_err": worst, "mean_abs_logprob_err": mean,
+           "max_gap_under_best": gap, "tokens": n, "tolerance": tol, "per_prompt": per,
+           "ok": bool(n > 0 and not short and worst <= tol and mean <= tol / 3
+                      and gap <= 2 * tol and left_out <= MOST_LEFT_OUT * (n + left_out))}
+    if check_at:
+        out.update(left_out=left_out, left_out_limit=MOST_LEFT_OUT * (n + left_out))
+    return out
 
 
 async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
-                          rehearse: bool) -> dict:
+                          rehearse: bool, routing_tie: float = 0.0) -> dict:
     """One replica's check, in two passes over samples of the same shape.
     With logprobs: each served token's logprob against the reference. A
     request that asks for logprobs never rides the fused mixed step (the
@@ -273,8 +360,9 @@ async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
     several rows and the window's edge, and not the ragged program. Without
     logprobs: the same drive goes through the ragged mixed step every request
     takes under load, and the served greedy tokens are held to the
-    reference's best token. A pass that never decoded two rows at once, or a
-    second pass that missed the ragged program where the engine runs it, has
+    reference's best token (a model the ragged program shuts out rides the
+    padded mixed program there). A pass that never decoded two rows at once,
+    or a second pass that missed the fused program the engine runs, has
     checked too little and fails."""
     import numpy as np
 
@@ -282,6 +370,8 @@ async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
                       "bench_reference")
     r, s = engine.runner, engine.scheduler
     mixed_on = bool(s.mixed_prefill_tokens > 0 and r.ragged_mixed and engine.fused_mixed)
+    padded_on = bool(s.mixed_prefill_tokens > 0 and engine.fused_mixed and not mixed_on
+                     and not r.pp)
     out = {"ok": True}
     for name, logprobs in (("logprobs", True), ("ragged", False)):
         sample = check_prompts(engine.runner.config, s,
@@ -289,13 +379,14 @@ async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
         calls0 = {k: v.get("calls", 0) for k, v in r.compile_stats().items()}
         t0 = time.time()
         got = await served(engine, sample, logprobs)
-        res = check_against_reference(ref, model, r.params, sample, got, tol)
+        res = check_against_reference(ref, model, r.params, sample, got, tol, routing_tie)
         res["calls"] = {k: v.get("calls", 0) - calls0.get(k, 0)
                         for k, v in r.compile_stats().items()}
         res["max_decode_rows"] = max(
             (rec.decode_seqs for rec in engine.recorder.snapshot() if rec.ts >= t0), default=0)
+        fused = "ragged" if mixed_on else "mixed" if padded_on else None
         res["ok"] = bool(res["ok"] and res["max_decode_rows"] > 1 and (
-            logprobs or not mixed_on or res["calls"].get("ragged", 0) > 0))
+            logprobs or fused is None or res["calls"].get(fused, 0) > 0))
         out[name] = res
         out["ok"] = out["ok"] and res["ok"]
     return out
@@ -339,14 +430,12 @@ async def amain(args) -> int:
     model = dict(cfg["model"])
     flags = dict(cfg["server_flags"])
     tol = float(cfg["correct_tolerance"])
+    routing_tie = float(cfg.get("correct_routing_tie", 0.0))
     if args.rehearse:
-        with open(os.path.join(HERE, "rehearse.json")) as f:
-            reh = json.load(f)
-        ratio = model["n_heads"] // model["n_kv_heads"]
-        model.update(reh["model"])
-        model["n_kv_heads"] = max(1, model["n_heads"] // ratio)
-        flags.update(reh["server_flags"])
-        tol = float(reh["correct_tolerance"])
+        reh = load_module(os.path.join(HERE, "rehearsal.py"),
+                          "bench_rehearsal").rehearsal_sizes(cfg, HERE)
+        model, flags, tol = reh["model"], reh["server_flags"], reh["correct_tolerance"]
+        routing_tie = reh["correct_routing_tie"]
 
     import jax
     import jax.numpy as jnp
@@ -419,7 +508,7 @@ async def amain(args) -> int:
     import numpy as np
 
     t = time.monotonic()
-    checks = [await reference_check(cfg, model, e, args.seed, tol, args.rehearse)
+    checks = [await reference_check(cfg, model, e, args.seed, tol, args.rehearse, routing_tie)
               for e in engines]
     log(f"reference check {time.monotonic() - t:.1f}s: {json.dumps(checks)}")
 
@@ -499,13 +588,11 @@ async def amain(args) -> int:
         for i, e in enumerate(engines):
             for rec in e.recorder.snapshot():
                 if window["t0_wall"] <= rec.ts < window["t1_wall"]:
-                    iters.append({"replica": i, "ts": rec.ts, "wall_s": rec.wall_s,
-                                  "kind": rec.kind, "decode_seqs": rec.decode_seqs,
-                                  "decode_steps": rec.decode_steps,
-                                  "n_chunks": rec.n_chunks,
-                                  "chunk_tokens": rec.chunk_tokens,
-                                  "ragged": rec.ragged, "n_waiting": rec.n_waiting,
-                                  "n_running": rec.n_running, "kv_usage": rec.kv_usage})
+                    # every scalar field of the record, so that a counter a
+                    # later PR adds reaches its reader with no edit here
+                    iters.append({"replica": i, **{
+                        k: v for k, v in dataclasses.asdict(rec).items()
+                        if isinstance(v, (bool, int, float, str))}})
         write_json(os.path.join(args.run_dir, "counters.json"),
                    {"at0": at0, "at1": at1, "iterations": iters, "trace": trace_info})
         while not os.path.exists(stop_path):

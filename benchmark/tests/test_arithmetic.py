@@ -121,3 +121,51 @@ def test_a_traffic_mix_can_extend_another(tmp_path, monkeypatch):
     monkeypatch.setattr(run, "HERE", str(tmp_path))
     assert run.read_traffic("faster") == {"kind": "open_loop", "rate_rps": 2.5, "lead_in_s": 10}
     assert run.read_traffic("base")["rate_rps"] == 1.0
+
+
+# -- bytes a step moves, by architecture (costs.py) --------------------------
+
+PHI3 = {"vocab_size": 32064, "dim": 3072, "n_layers": 32, "n_heads": 32, "n_kv_heads": 32,
+        "ffn_dim": 8192, "sliding_window": 2047}
+# Moonlight-16B-A3B's published sizes (config.json, deepseek_v3), all 27 layers
+MOONLIGHT = {"vocab_size": 163840, "dim": 2048, "n_layers": 27, "n_heads": 16, "n_kv_heads": 16,
+             "ffn_dim": 11264, "attn_type": "mla", "kv_lora_rank": 512, "q_lora_rank": 0,
+             "qk_rope_head_dim": 64, "qk_nope_head_dim": 128, "v_head_dim": 128, "n_experts": 64,
+             "n_experts_active": 6, "moe_ffn_dim": 1408, "n_shared_experts": 2, "n_dense_layers": 1}
+
+
+def test_cost_integers_of_a_dense_decoder_are_what_they_were():
+    import costs
+
+    assert costs.weight_stream_bytes(PHI3) == 7_444_758_528
+    assert costs.kv_bytes_per_token(PHI3) == 393_216
+    # an explicit head size is honoured; without experts `experts_hit` is idle
+    assert costs.attn_params({**PHI3, "head_dim_override": 128}) == 4 * 3072 * 32 * 128
+    assert costs.weight_stream_bytes(PHI3, experts_hit=64) == 7_444_758_528
+
+
+def test_cost_integers_of_latent_attention_and_experts():
+    import costs
+
+    assert costs.attn_params(MOONLIGHT) == 13_762_560
+    assert 3 * MOONLIGHT["dim"] * MOONLIGHT["ffn_dim"] == 69_206_016
+    assert costs.expert_layer_params(MOONLIGHT, 0) == 17_432_576
+    assert costs.expert_layer_params(MOONLIGHT, 1) - 17_432_576 == 8_650_752
+    assert MOONLIGHT["dim"] * MOONLIGHT["vocab_size"] == 335_544_320
+    assert costs.weight_stream_bytes(MOONLIGHT) == 5_158_207_488  # the floor: 6 experts a layer
+    assert costs.weight_stream_bytes(MOONLIGHT, experts_hit=64) == 31_248_875_520
+    assert costs.kv_bytes_per_token(MOONLIGHT) == 31_104
+    # more experts hit, more bytes; never past all of them, never under none
+    got = [costs.weight_stream_bytes(MOONLIGHT, experts_hit=n) for n in range(0, 70)]
+    assert all(a < b for a, b in zip(got[:64], got[1:65])) and got[64] == got[69]
+    assert costs.weight_stream_bytes(MOONLIGHT, experts_hit=-3) == got[0]
+    # a compressed query: two matrices in place of one
+    q = {**MOONLIGHT, "q_lora_rank": 1536}
+    assert costs.attn_params(q) - costs.attn_params(MOONLIGHT) == \
+        2048 * 1536 + 1536 * 16 * 192 - 2048 * 16 * 192
+    # a shared expert of a stated width (Qwen2-MoE) wins over count x width
+    assert costs.expert_layer_params({**MOONLIGHT, "shared_expert_ffn_dim": 5000}, 0) == \
+        2048 * 64 + 3 * 2048 * 5000
+    # no window on a latent cache: every live token counts
+    assert costs.decode_step_bytes(MOONLIGHT, [100, 5000], experts_hit=8) == \
+        costs.weight_stream_bytes(MOONLIGHT, experts_hit=8) + 5100 * 31_104

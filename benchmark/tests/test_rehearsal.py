@@ -1,7 +1,11 @@
 """run.py --rehearse end to end on the CPU at a tiny size, through serve.py
-and `python -m dynamo_tpu.frontend`, every cell of BENCHMARK.json. A
-rehearsal prints names and counts and no value of any metric; without
---rehearse and without a TPU the command fails and prints no result."""
+and `python -m dynamo_tpu.frontend`, every cell of BENCHMARK.json, and one
+cell that is in no BENCHMARK.json: a latent-attention expert configuration
+(tests/data/fixture-mla-moe.json, with its own `rehearse` group) under
+`chat-steady`, run from a scratch root that holds its own BENCHMARK.json, to
+show that such a cell comes in as files and entries. A rehearsal prints names
+and counts and no value of any metric; without --rehearse and without a TPU
+the command fails and prints no result."""
 
 import json
 import os
@@ -18,10 +22,10 @@ def _cells():
         return [w["name"] for w in json.load(f)["workloads"]]
 
 
-def _run(*extra, timeout=900):
+def _run(*extra, timeout=900, root=ROOT):
     return subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), *extra],
-        cwd=ROOT, capture_output=True, text=True, timeout=timeout)
+        [sys.executable, os.path.join(root, "benchmark", "run.py"), *extra],
+        cwd=root, capture_output=True, text=True, timeout=timeout)
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -43,3 +47,43 @@ def test_without_a_tpu_it_fails_and_prints_no_result():
              timeout=300)
     assert r.returncode != 0
     assert not r.stdout.strip().splitlines()[-1].startswith("{")
+
+
+@pytest.fixture(scope="module")
+def scratch_root(tmp_path_factory):
+    """A root with the program and the benchmark linked in and a
+    BENCHMARK.json of its own: the repo's, with the fixture configuration and
+    one cell of it in place of the repo's configurations and cells."""
+    root = tmp_path_factory.mktemp("bench_root")
+    for name in ("dynamo_tpu", "benchmark"):
+        os.symlink(os.path.join(ROOT, name), root / name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"] = [{
+        "name": "fixture-mla-moe", "source": "benchmark/tests/data/fixture-mla-moe.json",
+        "file": "benchmark/tests/data/fixture-mla-moe.json", "reduced": [],
+        "why": "latent attention, routed and shared experts, one dense layer"}]
+    bench["workloads"] = [{"name": "fixture-chat-steady", "config": "fixture-mla-moe",
+                           "traffic": "chat-steady", "chips": 1, "why": "harness fixture"}]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(root)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_latent_attention_expert_cell_comes_in_as_files(scratch_root, trace):
+    r = _run("--workload", "fixture-chat-steady", "--seed", str(2**31 + 17), "--seconds", "6",
+             "--trace", str(trace), "--rehearse", root=scratch_root)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["rehearsal"] is True and last["correct"] is True
+    assert last["attempted"] > 0 and last["failed"] == 0
+    # the check went through the latent-attention expert reference, left the
+    # routing near-ties out and counted them, and pass two rode the padded
+    # mixed program (the ragged one shuts latent attention out)
+    line = next(ln for ln in r.stdout.splitlines() if "] reference check " in ln)
+    check = json.loads(line.split("reference check ", 1)[1])[0]
+    for name in ("logprobs", "ragged"):
+        assert 0 <= check[name]["left_out"] <= check[name]["left_out_limit"]
+    assert check["ragged"]["calls"]["mixed"] > 0 and check["ragged"]["calls"]["ragged"] == 0
+    assert "runner.compiles_in_window" in last["metric_names"] if trace else \
+        "setup_s" in last["metric_names"]

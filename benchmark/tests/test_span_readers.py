@@ -98,3 +98,49 @@ def test_prefill_median_and_preempted_share():
     old = _spine_ctx([{"ttft_s": 0.5, "queue_wait_s": 0.1}])
     assert reader("sched.prefill_p50_ms")(old) is None
     assert reader("sched.preempted_pct")(old) is None
+
+
+# -- the decode loop, found by its program's name ---------------------------
+
+
+def _loop_ctx(modules, n_layers):
+    return {"trace": {"modules": modules}, "model": {"n_layers": n_layers},
+            "percentile": loadgen.percentile}
+
+
+def test_decode_loop_labelled_by_an_expert_kernel_is_found_and_its_steps_counted():
+    """27 layers, latent attention once a layer, an expert kernel twice: the
+    module is labelled by the expert kernel, and a step is 27 attention
+    calls whatever else ran."""
+    calls = lambda steps: {"decode_mla_attention": 27 * steps, "grouped_experts": 54 * steps}
+    mods = {
+        "jit_decode_loop[grouped_experts]": {
+            "durations_ms": [80.0, 40.0, 60.0], "kernel_calls": [216, 108, 162],
+            "kernels": [calls(4), calls(2), calls(3)]},
+        # the same program on an execution the capture cut: no whole number of steps
+        "jit_decode_loop[-]": {"durations_ms": [3.0], "kernel_calls": [0], "kernels": [{}]},
+        # another program that calls the same kernels is not the loop
+        "jit_mixed_loop[grouped_experts]": {
+            "durations_ms": [500.0], "kernel_calls": [54], "kernels": [calls(1)]},
+    }
+    assert reader("runner.decode_step_ms")(_loop_ctx(mods, 27)) == pytest.approx(20.0)
+    # with the top kernel's count alone the steps would come out doubled
+    assert [c // 27 for c in mods["jit_decode_loop[grouped_experts]"]["kernel_calls"]] == [8, 4, 6]
+
+
+def test_decode_loop_of_the_dense_decoder_reads_as_before():
+    """phi-3's shape: 32 layers, one decode_paged_attention a layer, four and
+    three steps. A program of another name that calls the same kernel is not
+    the loop: the finder goes by the program's name alone."""
+    new = {"jit_decode_loop[decode_paged_attention]": {
+               "durations_ms": [64.0, 48.0, 70.0], "kernel_calls": [128, 96, 130],
+               "kernels": [{"decode_paged_attention": 128}, {"decode_paged_attention": 96},
+                           {"decode_paged_attention": 130}]},
+           "jit_ragged_step[ragged_paged_attention]": {
+               "durations_ms": [126.0], "kernel_calls": [32],
+               "kernels": [{"ragged_paged_attention": 32}]}}
+    assert reader("runner.decode_step_ms")(_loop_ctx(new, 32)) == pytest.approx(16.0)
+    other = {k.replace("jit_decode_loop", "jit_other"): v for k, v in new.items()}
+    assert reader("runner.decode_step_ms")(_loop_ctx(other, 32)) is None
+    assert reader("runner.decode_step_ms")(_loop_ctx({}, 32)) is None
+    assert reader("runner.decode_step_ms")({"trace": None, "model": {"n_layers": 32}}) is None

@@ -1,8 +1,9 @@
 """From a JAX profiler trace (.xplane.pb) to numbers: device busy and idle
-time, the durations of each jitted module, the device operations that took
-most time, and who on the host owned the longest idle gaps. Read with nothing
-but jax (jax.profiler.ProfileData). Checked on the small recorded trace in
-benchmark/tests/data/.
+time, the durations of each jitted module and the kernels each execution
+called, device time per kernel, the device operations that took most time,
+the program's host spans, and who on the host owned the longest idle gaps.
+Read with nothing but jax (jax.profiler.ProfileData). Checked on the small
+recorded trace in benchmark/tests/data/.
 
 What a TPU trace holds (one plane per chip, `/device:TPU:<n>`):
   line "XLA Modules"  one event per execution of a jitted program
@@ -89,10 +90,13 @@ def reduce_planes(planes: list, n_devices: int = 0,
                   kernel_pattern: str = KERNEL_PATTERN) -> dict:
     """`planes`: [{"name", "lines": [{"name", "events": [(name, start_ns,
     dur_ns)]}]}] — the shape read_planes builds from ProfileData, and the
-    shape the tests hand-make. A module execution is classed by the kernel
-    it calls most (`jit__unknown[decode_paged_attention]`): the program's
-    jitted steps are functools.partial objects and all trace as
-    `jit__unknown`."""
+    shape the tests hand-make. A module is labelled `<program>[<the kernel
+    it calls most>]` (`jit_decode_loop[decode_paged_attention]`; a program
+    whose jitted partial lost its name traces as `jit__unknown`), and each
+    execution keeps the calls of every kernel by name. `kernels` is device
+    time per kernel over all custom calls (seconds averaged over the chips,
+    like `op_time`; calls and durations over all of them), `host_spans` the
+    program's spans by name."""
     kre, hre = re.compile(kernel_pattern), re.compile(HOST_SPAN_PATTERN)
     dev = [p for p in planes if p["name"].startswith("/device:TPU:")]
     dev.sort(key=lambda p: int(p["name"].rsplit(":", 1)[1]))
@@ -111,14 +115,18 @@ def reduce_planes(planes: list, n_devices: int = 0,
 
     out = {"planes": [p["name"] for p in planes], "n_devices": len(dev), "per_device": {},
            "modules": {}, "window_s": 0.0, "busy_s": 0.0, "kernel_s": 0.0,
-           "op_time": {}, "gap_owner": {}}
+           "op_time": {}, "gap_owner": {}, "kernels": {}, "host_spans": {}}
+    for s0, e0, name in host_spans:
+        h = out["host_spans"].setdefault(name, {"n": 0, "total_s": 0.0})
+        h["n"], h["total_s"] = h["n"] + 1, h["total_s"] + (e0 - s0) / 1e9
     if not dev:
         return out
     evs = [(s, s + d) for p in dev for ln in p["lines"] for _, s, d in ln["events"]]
     lo, hi = min(a for a, _ in evs), max(b for _, b in evs)
     out["window_s"] = (hi - lo) / 1e9
     op_time, gap_owner = defaultdict(float), defaultdict(float)
-    modules = defaultdict(lambda: {"durations_ms": [], "kernel_calls": []})
+    modules = defaultdict(lambda: {"durations_ms": [], "kernel_calls": [], "kernels": []})
+    kernels = defaultdict(lambda: {"total_s": 0.0, "durations_us": []})
     for p in dev:
         lines = {ln["name"]: ln["events"] for ln in p["lines"]}
         ops = sorted(lines.get("XLA Ops", []), key=lambda e: (e[1], -e[2]))
@@ -138,6 +146,7 @@ def reduce_planes(planes: list, n_devices: int = 0,
             labels.append(label)
             modules[label]["durations_ms"].append(d / 1e6)
             modules[label]["kernel_calls"].append(top[1])
+            modules[label]["kernels"].append(dict(calls))
         mstarts = [m[1] for m in mods]
         for (n, s, d), st in zip(ops, selfs):
             i = bisect.bisect_right(mstarts, s) - 1
@@ -145,12 +154,14 @@ def reduce_planes(planes: list, n_devices: int = 0,
             op_time[(labels[i] if inside else "-") + "/" + op_id(n)] += st / 1e9 / len(dev)
             if kre.search(n):
                 out["kernel_s"] += d / 1e9 / len(dev)
+                kernels[op_base(n)]["total_s"] += d / 1e9 / len(dev)
+                kernels[op_base(n)]["durations_us"].append(d / 1e3)
         for a, e in gaps_of(iv, lo, hi):
             gap_owner[owner((a + e) / 2)] += (e - a) / 1e9 / len(dev)
         out["busy_s"] += b / 1e9 / len(dev)
         out["per_device"][p["name"]] = {"busy_s": b / 1e9, "n_ops": len(ops),
                                         "lines": sorted(lines)}
-    out["modules"] = dict(modules)
+    out["modules"], out["kernels"] = dict(modules), dict(kernels)
     out["op_time"], out["gap_owner"] = dict(op_time), dict(gap_owner)
     return out
 
@@ -164,7 +175,9 @@ def merge(parts: list) -> dict:
            "busy_s": sum(p["busy_s"] for p in parts),
            "kernel_s": sum(p["kernel_s"] for p in parts), "modules": {}}
     op_time, gap_owner = defaultdict(float), defaultdict(float)
-    mods = defaultdict(lambda: {"durations_ms": [], "kernel_calls": []})
+    mods = defaultdict(lambda: {"durations_ms": [], "kernel_calls": [], "kernels": []})
+    kernels = defaultdict(lambda: {"total_s": 0.0, "durations_us": []})
+    spans = defaultdict(lambda: {"n": 0, "total_s": 0.0})
     for p in parts:
         for k, v in p["op_time"].items():
             op_time[k] += v
@@ -173,11 +186,21 @@ def merge(parts: list) -> dict:
         for k, m in p["modules"].items():
             mods[k]["durations_ms"] += m["durations_ms"]
             mods[k]["kernel_calls"] += m["kernel_calls"]
+            mods[k]["kernels"] += m["kernels"]
+        for k, v in p["kernels"].items():
+            kernels[k]["total_s"] += v["total_s"]
+            kernels[k]["durations_us"] += v["durations_us"]
+        for k, v in p["host_spans"].items():
+            spans[k]["n"], spans[k]["total_s"] = spans[k]["n"] + v["n"], spans[k]["total_s"] + v["total_s"]
     for name, m in mods.items():
         out["modules"][name] = {
             "n": len(m["durations_ms"]), "total_s": sum(m["durations_ms"]) / 1e3,
             "median_ms": median(m["durations_ms"]),
-            "durations_ms": m["durations_ms"][:4000], "kernel_calls": m["kernel_calls"][:4000]}
+            "durations_ms": m["durations_ms"][:4000], "kernel_calls": m["kernel_calls"][:4000],
+            "kernels": m["kernels"][:4000]}
+    out["kernels"] = {k: {"calls": len(v["durations_us"]), "total_s": v["total_s"],
+                          "median_us": median(v["durations_us"])} for k, v in kernels.items()}
+    out["host_spans"] = dict(spans)
     ranked = sorted(op_time.items(), key=lambda kv: -kv[1])
     out["device_ops"] = [[k, v] for k, v in ranked[:10]]
     out["idle_gaps"] = [[k, v] for k, v in
@@ -227,5 +250,5 @@ if __name__ == "__main__":
 
     r = reduce_file(sys.argv[1])
     for m in r["modules"].values():
-        m.pop("durations_ms"), m.pop("kernel_calls")
+        m.pop("durations_ms"), m.pop("kernel_calls"), m.pop("kernels")
     print(json.dumps(r, indent=1))
