@@ -400,6 +400,18 @@ class InferenceEngine:
             anomaly_profile_ms=anomaly_profile_ms,
         )
         self._rec_prev_charged = 0  # runner packed_tokens_charged watermark
+        # routed experts (docs/observability.md): whether the runner's
+        # step programs hand out the router's picks; the picks fetched for
+        # the asking requests of this iteration, rid -> the item's
+        # `routed_experts`, until _emit_item sends them; the last
+        # iteration's record while its expert-load counters are still on
+        # the device; and what /metrics shows of them (worker_common)
+        self._routed_ok = bool(getattr(runner, "routed", False))
+        self._routed_out: Dict[str, tuple] = {}  # rid -> (item field,
+        #   whether it holds one position per emitted token: a decode row)
+        self._rec_late: Optional[tuple] = None  # (IterationRecord, MoeLoad)
+        self.moe_totals = {"token_slots_total": 0, "experts_hit": 0.0,
+                           "load_max_share": 0.0}
         # sick peers for cross-worker pulls: instance -> retry-after time
         self._remote_fetch_backoff: Dict[int, float] = {}
         # disaggregation state
@@ -652,6 +664,7 @@ class InferenceEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+            self._flush_late_record()
         if self.prefetch is not None:
             self.prefetch.stop()
         if self.sanitizer is not None:
@@ -761,6 +774,18 @@ class InferenceEngine:
             }
             self._streams.pop(rid, None)
             return
+        if seq.sampling.get("routed_experts"):
+            why = self._routed_refusal()
+            if why:
+                # a path with no output for the picks refuses by name; a
+                # stream that silently lacked them would read as a gap
+                yield {
+                    "finish_reason": "error",
+                    "error": f"routed_experts is unsupported on this worker: {why}",
+                    "token_ids": [],
+                }
+                self._streams.pop(rid, None)
+                return
         if seq.guided and getattr(self.runner, "has_draft", False):
             # speculative verify can't honor per-token masks; silently
             # dropping the constraint would hand back schema-invalid output
@@ -878,6 +903,22 @@ class InferenceEngine:
             if not finished:
                 self._inbox.put(("abort", rid))
 
+    def _routed_refusal(self) -> Optional[str]:
+        """Why this worker cannot stream `routed_experts` (None: it can)."""
+        r = self.runner
+        if getattr(r, "pp", False):
+            return "the pipeline-parallel programs do not return the picks"
+        if getattr(r, "sp_enabled", False):
+            return "sequence-parallel prefill does not return the picks"
+        if getattr(r, "has_draft", False):
+            return "speculative decoding with a draft model does not return the picks"
+        if self._spec_on:
+            return ("speculative verify (n-gram drafting, the draft ring) "
+                    "does not return the picks")
+        if not self._routed_ok:
+            return "the model it serves has no routed experts"
+        return None
+
     async def _pull_remote_host(self, hint: Dict[str, Any]) -> None:
         """Best-effort remote-G2 pull (reference onboarding session
         search→pull, lib/kvbm-engine/docs/architecture.md). Failures fall
@@ -947,6 +988,10 @@ class InferenceEngine:
             # compiles on this thread that no step family sees count in
             # this runner's compile_stats()["other"]
             name_thread()
+        if self._routed_ok:
+            # what the runner holds of dispatches that were not this
+            # engine's (a warm-up walk) is none of its first iteration's load
+            self.runner.take_moe_load()
         while not self._stop.is_set():
             try:
                 self._loop_once()
@@ -984,6 +1029,7 @@ class InferenceEngine:
                       running=len(sched.active)):
             plan = sched.step_plan()
         if plan is None:
+            self._flush_late_record()
             if not sched.has_work():
                 with annotate("engine.wait"):
                     time.sleep(self.idle_sleep_s)
@@ -1166,7 +1212,12 @@ class InferenceEngine:
         """Assemble and append this iteration's flight record (step
         thread; cheap field reads only — see DYN-R004)."""
         rec = self.recorder
+        self._flush_late_record()
+        # a routed model's expert-load counters came back with the sampled
+        # tokens; taken every iteration so the runner forgets the dispatch
+        load = self.runner.take_moe_load() if self._routed_ok else None
         if not rec.enabled:
+            self._settle_record(None, load)
             return
         st = self.scheduler.stats
         g2 = g3 = 0
@@ -1199,7 +1250,7 @@ class InferenceEngine:
                 pctx = tracing.parse_traceparent(s.tp)
                 if pctx is not None and pctx.trace_id not in trace_ids:
                     trace_ids.append(pctx.trace_id)
-        rec.append(IterationRecord(
+        record = IterationRecord(
             seq=self._step_counter,
             ts=ts,
             wall_s=wall,
@@ -1227,7 +1278,40 @@ class InferenceEngine:
             tree_hit_blocks=self.pool.match_hit_blocks,
             forks=self.pool.forks,
             trace_ids=trace_ids,
-        ))
+        )
+        self._settle_record(record, load)
+
+    def _settle_record(self, record, load) -> None:
+        """Append an iteration's record (None: the recorder is off) with
+        its expert-load counters (`load` None: a dense model), which also
+        go to the /metrics totals. Where a prefill chunk that sampled
+        nothing was never read back, its counters are still on the
+        device: the record is held until the next iteration has
+        synchronised, or the engine idles or stops (_flush_late_record),
+        instead of blocking on the device here."""
+        if load is not None and not load.ready:
+            self._rec_late = (record, load)
+            return
+        if load is not None:
+            slots, hit, share = load.result()
+            t = self.moe_totals
+            t["token_slots_total"] += slots
+            t["experts_hit"], t["load_max_share"] = hit, share
+            if record is not None:
+                record.moe_token_slots = slots
+                record.moe_experts_hit = hit
+                record.moe_load_max_share = share
+        if record is not None:
+            self.recorder.append(record)
+
+    def _flush_late_record(self) -> None:
+        """Append the record _settle_record held back, in its place:
+        before any later iteration's."""
+        if self._rec_late is not None:
+            record, load = self._rec_late
+            self._rec_late = None
+            load.result()  # now it may wait: the device has moved on
+            self._settle_record(record, load)
 
     def _recover_poisoned_pools(self) -> None:
         """A step that fails AFTER its jit dispatch consumed the donated
@@ -1605,6 +1689,37 @@ class InferenceEngine:
         return {"embeds": np.ascontiguousarray(seq.mm_embeds[list(rows)]),
                 "offsets": list(offs)}
 
+    def _collect_routed(self, seqs, n_steps: int, prefills) -> None:
+        """After a dispatch and before its emits: where a request of it
+        asked (`sampling.routed_experts`), fetch the dispatch's picks and
+        keep each asking request's share for the item _emit_item sends
+        next: {"start": p, "ids": [position][expert layer][k]} for the
+        consecutive positions p, p+1, ... whose forward just ran (a decode
+        row: the positions of its n_steps input tokens, of which
+        _emit_item keeps those that produced an emitted token). Nothing is
+        fetched, and no dispatch differs, when nobody asked."""
+        if not self._routed_ok:
+            return
+        want_d = [i for i, s in enumerate(seqs)
+                  if s.sampling.get("routed_experts")]
+        want_c = [i for i, p in enumerate(prefills)
+                  if p.seq.sampling.get("routed_experts")]
+        if not (want_d or want_c):
+            return
+        decode, chunks = self.runner.routed_picks()
+        for i in want_d:  # decode [steps, L_moe, rows, k]
+            s = seqs[i]
+            self._routed_out[s.request_id] = ({
+                "start": s.computed_len,
+                "ids": _nested_ints(decode[:n_steps, :, i]),
+            }, True)
+        for i in want_c:  # chunks[i] [L_moe, n, k]
+            p = prefills[i]
+            self._routed_out[p.seq.request_id] = ({
+                "start": p.start_pos,
+                "ids": _nested_ints(chunks[i].transpose(1, 0, 2)),
+            }, False)
+
     def _run_prefill(self, plan: PrefillPlan) -> None:
         with annotate("engine.prefill", tokens=len(plan.chunk)):
             self._run_prefill_inner(plan)
@@ -1664,6 +1779,7 @@ class InferenceEngine:
                 plan.chunk, plan.start_pos, seq.pages, prior_len=plan.start_pos,
                 mm=mm_chunk,
             )
+        self._collect_routed([], 0, [plan])
         with annotate("engine.emit"):
             self.scheduler.complete_prefill(plan)
             self._finish_prefill(plan, logits)
@@ -1675,6 +1791,10 @@ class InferenceEngine:
         the sequence RUNNING."""
         seq = plan.seq
         if not plan.is_last_chunk:
+            if seq.request_id in self._routed_out:
+                # an asking request hears of every chunk: an item with no
+                # token, only the chunk's `routed_experts`
+                self._emit_item(seq, engine_output([], None))
             return
         bias1 = None
         if seq.logit_bias:
@@ -2298,6 +2418,7 @@ class InferenceEngine:
                         "deferring chunk of %s to the next iteration",
                         e, shed.seq.request_id,
                     )
+            self._collect_routed(seqs, T, prefills)
             with annotate("engine.emit"):
                 for i, seq in enumerate(seqs):
                     emit: List[int] = []
@@ -2471,6 +2592,7 @@ class InferenceEngine:
                 adapters=adapters,
                 **mkw,
             )
+        self._collect_routed(seqs, T, [])
         with annotate("engine.emit"):
             for i, seq in enumerate(seqs):
                 emit: List[int] = []
@@ -2544,6 +2666,18 @@ class InferenceEngine:
         self._emit_item(seq, engine_output(token_ids, finish, **extra))
 
     def _emit_item(self, seq: Sequence, item: Dict[str, Any]) -> None:
+        routed = None
+        if self._routed_out:  # empty unless a request of this dispatch asked
+            routed, per_token = self._routed_out.pop(
+                seq.request_id, (None, False))
+        if routed is not None:
+            if per_token:
+                # a decode row: one position per emitted token (a step
+                # whose token was dropped at a stop, or that ran past the
+                # stop, has no reader)
+                routed["ids"] = routed["ids"][: len(item["token_ids"])]
+            if routed["ids"]:
+                item["routed_experts"] = routed
         if item.get("finish_reason"):
             # final item carries the request's phase spine downstream
             # (loadgen/goodput aggregate it; the frontend adds span events)
@@ -2995,6 +3129,13 @@ def _batch_logprobs(seqs: List[Sequence]) -> int:
         if mx <= b:
             return b
     return 20
+
+
+def _nested_ints(picks) -> List[List[List[int]]]:
+    """A host array of expert ids [positions, L_moe, k] as the stream
+    carries it: nested lists of Python ints. (Not `.tolist()`: dynlint
+    reads that name as a device sync inside the step loop, DYN-J006.)"""
+    return [[[int(e) for e in ids] for ids in pos] for pos in picks]
 
 
 def _first_lp_entry(first_lp, seq: Sequence) -> Dict[str, Any]:

@@ -19,7 +19,7 @@ import contextlib
 import logging
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -32,10 +32,44 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from dynamo_tpu.engine.sampling import SamplingParams, sample
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
 from dynamo_tpu.runtime.annotations import annotate
 
 log = logging.getLogger("dynamo_tpu.engine.runner")
+
+
+# A routed model's step programs (config.is_moe) return one more output
+# than a dense model's, always and last: a dict of the router's picks as
+# the program laid them out (`decode` [n_steps, L_moe, B, k], `chunks`
+# [L_moe, N, S, k], `flat` [L_moe, T, k]; int32 expert ids) and `load`,
+# the f32 [3] expert-load counters of its forwards over real tokens
+# (models/moe.routing_stats, summed over the forwards). A dense model's
+# programs return what they always did. See ModelRunner._note_routed.
+
+
+def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
+             page_table, kv_lens, last_index=None, attn_impl: str = "jnp",
+             mesh=None, sp_has_prior: bool = True, lora=None,
+             adapter_idx=None, mm_embeds=None, mm_mask=None):
+    """The prefill step program of a routed model: llama.forward, with the
+    picks of its chunk rows beside the pools. (A dense model's is
+    llama.forward itself.)"""
+    logits, k_pool, v_pool, sel = llama.forward(
+        config, params, tokens, positions, k_pool, v_pool, page_table,
+        kv_lens, last_index, attn_impl=attn_impl, mesh=mesh,
+        sp_has_prior=sp_has_prior, lora=lora, adapter_idx=adapter_idx,
+        mm_embeds=mm_embeds, mm_mask=mm_mask, return_routed=True,
+    )
+    return logits, k_pool, v_pool, {
+        "chunks": sel, "load": _chunk_load(config, sel, positions >= 0)}
+
+
+def _chunk_load(config: ModelConfig, sel, valid):
+    """routing_stats of one [N, S] prefill forward: sel [L_moe, N, S, k]."""
+    L, N, S, k = sel.shape
+    return routing_stats(sel.reshape(L, N * S, k), valid.reshape(N * S),
+                         config.n_experts)
 
 
 def _decode_loop(
@@ -80,8 +114,10 @@ def _decode_loop(
     one exception: it is batch×history sized, so it rides as its own array
     only when a request actually uses penalties.
     Returns (tokens [B, n_steps], last [B], lp, k_pool, v_pool) where lp is
-    None or (tok_lp [B, T], top_ids [B, T, K], top_lps [B, T, K])."""
+    None or (tok_lp [B, T], top_ids [B, T, K], top_lps [B, T, K]); a routed
+    model's adds {"decode", "load"} (see _forward above)."""
     B = sampling.temperature.shape[0]
+    routed = config.is_moe
     n_fields = 2 if lora is not None else 1
     MP = (packed.shape[0] - 1 - n_fields * B) // B
     positions0 = packed[:B]
@@ -123,9 +159,10 @@ def _decode_loop(
             (tok, kp, vp), cnt, cnt_out = carry, None, None
         pos = jnp.where(positions0 < 0, -1, positions0 + t)
         kvl = jnp.where(positions0 < 0, 0, positions0 + t + 1)
-        logits, kp, vp = llama.forward(
+        logits, kp, vp, *sel = llama.forward(
             config, params, tok[:, None], pos[:, None], kp, vp, page_table, kvl,
             attn_impl=attn_impl, mesh=mesh, lora=lora, adapter_idx=adapter_idx,
+            return_routed=routed,
         )
         raw = logits[:, 0, :]
         l = raw
@@ -161,6 +198,10 @@ def _decode_loop(
             from dynamo_tpu.engine.sampling import top_logprobs
 
             outs = (s,) + top_logprobs(raw, s, n_logprobs)
+        if routed:
+            picks = sel[0][:, :, 0]  # [L_moe, B, k]
+            outs = outs + (picks, routing_stats(
+                picks, positions0 >= 0, config.n_experts))
         if use_pen:
             r = jnp.arange(B, dtype=jnp.int32)
             cnt = cnt.at[r, s].add(1.0)
@@ -185,7 +226,10 @@ def _decode_loop(
     # `last` (== toks[:, -1]) is returned as its own output so a chaining
     # caller can feed it straight into the next dispatch — slicing the
     # token matrix caller-side would be an extra eager device program
-    return toks.T, last, lp, k_pool, v_pool  # [B, n_steps], [B]
+    out = (toks.T, last, lp, k_pool, v_pool)  # [B, n_steps], [B]
+    if routed:
+        out += ({"decode": ys[-2], "load": ys[-1].sum(0)},)
+    return out
 
 
 def _mixed_loop(
@@ -206,6 +250,8 @@ def _mixed_loop(
     v_pool,
     sampling: SamplingParams,
     lora=None,
+    prows=None,  # routed models only: int32 scalar, the real chunk rows
+    # (rows past it replicate row 0 and are left out of the load counters)
 ):
     """One fused engine iteration under mixed scheduling: the token-
     budgeted prefill chunk set (one ragged segment per batch row) AND
@@ -215,12 +261,15 @@ def _mixed_loop(
     different sequence (disjoint pages) than the decode batch and its
     packed siblings, so ordering inside the program is free for XLA to
     choose. Returns (toks [B, n_steps], last [B], chunk_logits — [V]
-    for the legacy scalar plast, else [N, V] — k_pool, v_pool)."""
-    logits, k_pool, v_pool = llama.forward(
+    for the legacy scalar plast, else [N, V] — k_pool, v_pool); a routed
+    model's adds {"chunks", "decode", "load"} (see _forward above)."""
+    routed = config.is_moe
+    logits, k_pool, v_pool, *sel = llama.forward(
         config, params, ptok, ppos, k_pool, v_pool, ppt, pkvl, plast,
         attn_impl=attn_impl, mesh=mesh, lora=lora, adapter_idx=padapter,
+        return_routed=routed,
     )
-    toks, last, _, k_pool, v_pool = _decode_loop(
+    toks, last, _, k_pool, v_pool, *dec = _decode_loop(
         config, attn_impl, mesh, n_steps, -1, params, tokens0, packed,
         None, None, None, k_pool, v_pool, sampling, lora,
     )
@@ -228,7 +277,13 @@ def _mixed_loop(
         chunk_logits = logits[:, 0]  # [N, V], one row per packed chunk
     else:
         chunk_logits = logits[0, 0]  # [V], legacy single-chunk caller
-    return toks, last, chunk_logits, k_pool, v_pool
+    out = (toks, last, chunk_logits, k_pool, v_pool)
+    if routed:
+        real = jnp.arange(ptok.shape[0], dtype=jnp.int32)[:, None] < prows
+        load = _chunk_load(config, sel[0], (ppos >= 0) & real)
+        out += ({"chunks": sel[0], "decode": dec[0]["decode"],
+                 "load": load + dec[0]["load"]},)
+    return out
 
 
 def _ragged_step(
@@ -281,11 +336,14 @@ def _ragged_step(
     tokens — its variants are the plain decode-bucket set the engine
     already pays for, and sampling row seeds/steps line up exactly with
     the legacy fused path (sample() derives randomness per row from the
-    sequence seed and the step counter only)."""
-    logits, k_pool, v_pool = llama.forward(
+    sequence seed and the step counter only). Returns (toks [SEG_CAP],
+    seg_logits, k_pool, v_pool); a routed model's adds {"flat", "load"}
+    (see _forward above)."""
+    routed = config.is_moe
+    logits, k_pool, v_pool, *sel = llama.forward(
         config, params, tokens, positions, k_pool, v_pool, tok_pt, tok_kvl,
         last_index=gather_idx, attn_impl=attn_impl, mesh=mesh,
-        ragged=(seg_pt, seg_kvl, meta),
+        ragged=(seg_pt, seg_kvl, meta), return_routed=routed,
     )
     seg_logits = logits[0]  # [SEG_CAP, V]
     # in-XLA sampling expansion: gather each row's base (per-seq) params,
@@ -304,7 +362,12 @@ def _ragged_step(
     )
     exp = exp._replace(key=key)
     toks = sample(seg_logits, exp, step, mask=mask, bias=bias)  # [SEG_CAP]
-    return toks, seg_logits, k_pool, v_pool
+    out = (toks, seg_logits, k_pool, v_pool)
+    if routed:
+        flat = sel[0][:, 0]  # [L_moe, T, k]
+        out += ({"flat": flat, "load": routing_stats(
+            flat, positions[0] >= 0, config.n_experts)},)
+    return out
 
 
 # device n-gram draft ring width: history tokens kept per slot. Smaller
@@ -504,6 +567,63 @@ class _CompiledFamily:
             "compile_s": round(self.compile_s, 4),
             "calls": self.calls,
         }
+
+
+class _RoutedPart:
+    """One dispatch's routed output (see _forward) and how its rows map
+    to the plan: `picks` the device arrays by layout (None once
+    routed_picks() took them), `load` the counters (device until a
+    readback brought them), `forwards` the forward passes the dispatch
+    ran, `n_dec` the real decode rows, `chunk_lens` the real tokens of
+    each real chunk in plan order."""
+
+    __slots__ = ("picks", "load", "forwards", "n_dec", "chunk_lens")
+
+    def __init__(self, routed, forwards, n_dec, chunk_lens):
+        self.picks = {k: v for k, v in routed.items() if k != "load"}
+        self.load = routed["load"]
+        self.forwards = forwards
+        self.n_dec = n_dec
+        self.chunk_lens = tuple(chunk_lens)
+
+
+def _device_get_with_loads(parts, x=None):
+    """jax.device_get(x) and, in the same call, of the expert-load
+    counters of `parts` that no readback has brought yet."""
+    late = [p for p in parts if not isinstance(p.load, np.ndarray)]
+    if not late:
+        return jax.device_get(x)
+    out, loads = jax.device_get((x, [p.load for p in late]))
+    for p, h in zip(late, loads):
+        p.load = np.asarray(h)
+    return out
+
+
+class MoeLoad:
+    """An iteration's expert-load counters, as ModelRunner.take_moe_load()
+    hands them to the engine. `ready` says every dispatch's counters came
+    back with some readback of the iteration; where one did not (a
+    prefill chunk that sampled nothing is never read back) `result()`
+    fetches it, which the engine puts off until the next iteration has
+    synchronised anyway."""
+
+    def __init__(self, parts, n_layers: int):
+        self._parts = parts
+        self._n_layers = n_layers
+
+    @property
+    def ready(self) -> bool:
+        return all(isinstance(p.load, np.ndarray) for p in self._parts)
+
+    def result(self) -> Tuple[int, float, float]:
+        """(moe_token_slots, moe_experts_hit, moe_load_max_share): see
+        runtime/flight_recorder.IterationRecord."""
+        _device_get_with_loads(self._parts)
+        units = sum(p.forwards for p in self._parts) * self._n_layers
+        if not units:
+            return 0, 0.0, 0.0
+        tot = np.sum([p.load for p in self._parts], axis=0, dtype=np.float64)
+        return int(round(tot[0])), float(tot[1] / units), float(tot[2] / units)
 
 
 # Wire layout version for P→D / cross-worker KV payloads. v2 = token-major
@@ -871,8 +991,16 @@ class ModelRunner:
             self._families[name] = fam
             return fam
 
+        # a routed model's step programs hand out the router's picks and
+        # the expert-load counters (see _forward); the pipeline-parallel
+        # programs have no such output
+        self.routed = bool(self.config.is_moe) and not self.pp
+        # the dispatches since the engine last took them (_note_routed);
+        # bounded for callers that never do (warm-up walks, benches)
+        self._routed_parts: "deque[_RoutedPart]" = deque(maxlen=64)
         self._jit_forward = _family(
-            "forward", partial(llama.forward, self.config),
+            "forward",
+            partial(_forward if self.routed else llama.forward, self.config),
             donate_argnums=(3, 4),  # k_pool, v_pool
             static_argnames=("attn_impl", "mesh", "sp_has_prior"),
         )
@@ -1065,7 +1193,7 @@ class ModelRunner:
             )
             return logits[0, n - 1]
         impl = "ring" if self.sp_enabled else self.attn_impl
-        logits, self.k_pool, self.v_pool = self._jit_forward(
+        logits, self.k_pool, self.v_pool, *routed = self._jit_forward(
             self.params, tok, pos, self.k_pool, self.v_pool, pt, kv_lens,
             jnp.int32(n - 1), attn_impl=impl,
             mesh=self.mesh if impl == "ring" else self._fwd_mesh,
@@ -1074,7 +1202,70 @@ class ModelRunner:
             adapter_idx=jnp.asarray([adapter], jnp.int32) if self.lora is not None else None,
             mm_embeds=mm_embeds, mm_mask=mm_mask,
         )
+        self._note_routed(routed, 1, chunk_lens=[n])
         return logits[0, 0]
+
+    # -- routed experts ------------------------------------------------------
+    def _note_routed(self, routed, forwards: int, n_dec: int = 0,
+                     chunk_lens: Sequence[int] = (),
+                     chained: bool = False) -> None:
+        """Keep a dispatch's routed output (a routed model's step programs
+        return it last; `routed` is what followed the fixed outputs, empty
+        for a dense model) until the engine takes it. The picks of earlier
+        dispatches nobody took are let go, so routed_picks() answers for
+        the newest dispatch alone (`chained`: and the one it continues)."""
+        if not routed:
+            return
+        if not chained:
+            for p in self._routed_parts:
+                p.picks = None
+        self._routed_parts.append(
+            _RoutedPart(routed[0], forwards, n_dec, chunk_lens))
+
+    def _readback(self, x):
+        """jax.device_get of a dispatch's results and, in the same call,
+        of the expert-load counters no readback has brought yet (a dense
+        model has none: the plain device_get it always was)."""
+        if not self._routed_parts:
+            return jax.device_get(x)
+        return _device_get_with_loads(self._routed_parts, x)
+
+    def routed_picks(self):
+        """The experts every token of the newest dispatch (a ragged step
+        and the decode loop chained on it are one) was routed to,
+        fetched in one device_get and cut to the plan's real rows:
+        (decode, int32 [steps, L_moe, n_dec, k] or None: the decode rows'
+        picks step by step; chunks: one int32 [L_moe, n, k] per prefill
+        chunk in dispatch order). The engine calls this only
+        in an iteration where a request asked (`routed_experts`); the
+        arrays otherwise never leave the device."""
+        parts = [p for p in self._routed_parts if p.picks is not None]
+        with self._allow("token_readback"), annotate("engine.readback"):
+            host = self._readback([p.picks for p in parts])
+        dec: List[np.ndarray] = []
+        chunks: List[np.ndarray] = []
+        for p, h in zip(parts, host):
+            p.picks = None
+            if "flat" in h:  # [L_moe, T, k]: decode rows, then the chunks
+                if p.n_dec:
+                    dec.append(np.asarray(h["flat"][None, :, : p.n_dec]))
+                off = p.n_dec
+                for n in p.chunk_lens:
+                    chunks.append(np.asarray(h["flat"][:, off : off + n]))
+                    off += n
+            if "chunks" in h:  # [L_moe, N, S, k]: one padded row a chunk
+                for i, n in enumerate(p.chunk_lens):
+                    chunks.append(np.asarray(h["chunks"][:, i, :n]))
+            if "decode" in h:  # [n_steps, L_moe, B, k]
+                dec.append(np.asarray(h["decode"][:, :, : p.n_dec]))
+        return (np.concatenate(dec) if dec else None), chunks
+
+    def take_moe_load(self) -> MoeLoad:
+        """The expert-load counters of the dispatches since the last call
+        (the engine: once an iteration), and forget those dispatches."""
+        parts = list(self._routed_parts)
+        self._routed_parts.clear()
+        return MoeLoad(parts, self.config.n_layers - self.config.n_dense_layers)
 
     def _mm_arrays(self, mm: Optional[Dict[str, Any]], S: int):
         """(mm_embeds [1,S,E], mm_mask [1,S]) padded to the bucket, or
@@ -1195,7 +1386,7 @@ class ModelRunner:
             masks=masks, biases=biases, mask_fn=mask_fn, guided_dev=guided_dev,
         )
         with self._allow("token_readback"), annotate("engine.readback"):
-            return np.asarray(jax.device_get(toks))
+            return np.asarray(self._readback(toks))
 
     def decode_multi_ex(
         self,
@@ -1229,10 +1420,10 @@ class ModelRunner:
         with self._allow("token_readback"), annotate("engine.readback"):
             if n_logprobs >= 0:
                 toks, _, lp = out
-                toks_h, lp_h = jax.device_get((toks, lp))
+                toks_h, lp_h = self._readback((toks, lp))
                 return np.asarray(toks_h), tuple(np.asarray(a) for a in lp_h)
             toks, _ = out
-            return np.asarray(jax.device_get(toks)), None
+            return np.asarray(self._readback(toks)), None
 
     def decode_multi_async(
         self,
@@ -1350,11 +1541,12 @@ class ModelRunner:
                 self.k_pool, self.v_pool, samp,
             )
             return toks, last
-        toks, last, lp, self.k_pool, self.v_pool = self._jit_decode_loop(
+        toks, last, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
             n_steps, n_logprobs, self.params, tok, packed_dev, hist,
             mask_dev, bias_dev, self.k_pool, self.v_pool,
             samp, self.lora, **mkw,
         )
+        self._note_routed(routed, n_steps, n_dec=n)
         if n_logprobs >= 0:
             return toks, last, lp
         return toks, last
@@ -1423,13 +1615,26 @@ class ModelRunner:
             )
             tok_dev, packed_dev, samp = self._stage_padded_decode_half(
                 tokens, positions, page_tables, sampling, step, adapters)
-        toks, _, chunk_logits, self.k_pool, self.v_pool = self._jit_mixed(
-            n_steps, self.params, ptok, ppos, ppt, pkvl, plast,
-            padapter, tok_dev, packed_dev,
-            self.k_pool, self.v_pool, samp, self.lora,
+        return self._dispatch_padded_mixed(
+            n_steps, (ptok, ppos, ppt, pkvl, plast, padapter),
+            (tok_dev, packed_dev, samp), len(positions), [n])
+
+    def _dispatch_padded_mixed(self, n_steps, chunk_half, decode_half,
+                               n_dec: int, chunk_lens: List[int]):
+        """The padded mixed program on staged inputs, and the readback of
+        its sampled tokens: (sampled [B_bucket, n_steps] host, chunk
+        logits device)."""
+        tok_dev, packed_dev, samp = decode_half
+        kw = {}
+        if self.routed:  # rows past the real chunks replicate row 0
+            kw["prows"] = jnp.int32(len(chunk_lens))
+        toks, _, chunk_logits, self.k_pool, self.v_pool, *routed = self._jit_mixed(
+            n_steps, self.params, *chunk_half, tok_dev, packed_dev,
+            self.k_pool, self.v_pool, samp, self.lora, **kw,
         )
+        self._note_routed(routed, 1 + n_steps, n_dec, chunk_lens)
         with annotate("engine.readback"):
-            return np.asarray(jax.device_get(toks)), chunk_logits
+            return np.asarray(self._readback(toks)), chunk_logits
 
     def _stage_padded_decode_half(self, tokens, positions, page_tables,
                                   sampling, step, adapters):
@@ -1536,17 +1741,12 @@ class ModelRunner:
                 "(the engine's _mixed_fusible gates on it)"
             )
         with annotate("engine.stage"):
-            ptok, ppos, ppt, pkvl, plast, padapter = (
-                self._prep_prefill_packed(chunks))
-            tok_dev, packed_dev, samp = self._stage_padded_decode_half(
+            chunk_half = self._prep_prefill_packed(chunks)
+            decode_half = self._stage_padded_decode_half(
                 tokens, positions, page_tables, sampling, step, adapters)
-        toks, _, chunk_logits, self.k_pool, self.v_pool = self._jit_mixed(
-            n_steps, self.params, ptok, ppos, ppt, pkvl, plast,
-            padapter, tok_dev, packed_dev,
-            self.k_pool, self.v_pool, samp, self.lora,
-        )
-        with annotate("engine.readback"):
-            return np.asarray(jax.device_get(toks)), chunk_logits
+        return self._dispatch_padded_mixed(
+            n_steps, chunk_half, decode_half, len(positions),
+            [len(c["tokens"]) for c in chunks])
 
     # -- guided sampling masks --------------------------------------------
     def _true_mask(self, rows: int) -> jax.Array:
@@ -1761,11 +1961,13 @@ class ModelRunner:
             step_dev = jnp.int32(step)
             seg_mask = self._seg_mask(masks, seg_cap)
             seg_bias = self._seg_bias(biases, seg_cap)
-        sampled, seg_logits, self.k_pool, self.v_pool = self._jit_ragged(
+        sampled, seg_logits, self.k_pool, self.v_pool, *routed = self._jit_ragged(
             self.params, ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl,
             meta, gather, self.k_pool, self.v_pool,
             samp, row_seq, row_j, step_dev, seg_mask, seg_bias,
         )
+        self._note_routed(routed, 1, n_dec,
+                          [len(c["tokens"]) for c in chunks])
         B = _next_bucket(self.decode_buckets, n_dec)
         # both slices are eager programs enqueued behind the ragged step,
         # before the host blocks: the device never waits for them
@@ -1802,19 +2004,20 @@ class ModelRunner:
             # n_steps-1 adds exactly ONE decode_loop variant alongside the
             # legacy path's n_steps — bounded by design (ragged two-
             # dispatch split, docs/ragged_attention.md)
-            rest, _, _, self.k_pool, self.v_pool = self._jit_decode_loop(  # dynlint: disable=DYN-J004
+            rest, _, _, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(  # dynlint: disable=DYN-J004
                 n_steps - 1, -1, self.params, tok0, packed_dev,
                 None, None, bias_dev, self.k_pool, self.v_pool,
                 samp, None, **mkw,
             )
+            self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
             with annotate("engine.readback"):
-                tok0_h, rest_h = jax.device_get((tok0, rest))
+                tok0_h, rest_h = self._readback((tok0, rest))
             toks = np.concatenate(
                 [np.asarray(tok0_h)[:, None], np.asarray(rest_h)], axis=1
             )
         else:
             with annotate("engine.readback"):
-                toks = np.asarray(jax.device_get(tok0))[:, None]
+                toks = np.asarray(self._readback(tok0))[:, None]
         return toks, chunk_logits
 
     def verify_spec(
@@ -1960,13 +2163,16 @@ class ModelRunner:
                 step_d = jnp.int32(step)
                 seg_mask = self._seg_mask(row_masks, seg_cap)
                 seg_bias = self._seg_bias(row_biases, seg_cap)
-        sampled, seg_logits, self.k_pool, self.v_pool = self._jit_ragged(
+        sampled, seg_logits, self.k_pool, self.v_pool, *routed = self._jit_ragged(
             self.params, *staged,
             self.k_pool, self.v_pool,
             samp, row_seq_d, row_j_d, step_d, seg_mask, seg_bias,
         )
+        # the counters hold for a verify dispatch too; its picks have no
+        # reader (a worker that speculates refuses `routed_experts`)
+        self._note_routed([{"load": r["load"]} for r in routed], 1)
         with self._allow("token_readback"), annotate("engine.readback"):
-            sampled_h = np.asarray(jax.device_get(sampled))  # one bulk sync
+            sampled_h = np.asarray(self._readback(sampled))  # one bulk sync
         out: List[np.ndarray] = []
         w = 0
         for ln in row_lens:
@@ -2249,7 +2455,7 @@ class ModelRunner:
             mask=jnp.asarray(mask[None, :]) if mask is not None else None,
             bias=jnp.asarray(bias[None, :]) if bias is not None else None,
         )
-        return int(jax.device_get(out)[0])
+        return int(self._readback(out)[0])
 
     def sample_one_ex(
         self,
@@ -2280,7 +2486,7 @@ class ModelRunner:
             jnp.asarray(mask[None, :]) if mask is not None else None,
             jnp.asarray(bias[None, :]) if bias is not None else None,
         )
-        out = jax.device_get(out)
+        out = self._readback(out)
         tok = int(out[0][0])
         if n_logprobs < 0:
             return tok, None

@@ -235,7 +235,9 @@ def forward(
     #   exactly correct for arbitrary segment layouts, while the pallas
     #   branch uses the seg-level arrays (SMEM-sized). last_index holds
     #   FLAT per-segment last-token indices.
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    return_routed: bool = False,  # static: a routed model's step programs
+    #   set it and get the router's picks as a fourth output
+) -> Tuple[jax.Array, ...]:
     """One forward pass (covers prefill chunks S>1 and decode S=1).
 
     Writes this step's K/V into the pool pages, attends over the full
@@ -244,9 +246,17 @@ def forward(
     With `last_index` (prefill), the vocab projection runs on that single
     position only — logits come back [B, 1, V], skipping S-1 lm_head
     matmuls over a 100k+ vocab.
+
+    With `return_routed` (routed models only) a fourth output follows the
+    pools: int32 [L_moe, B, S, k], the experts each token was routed to,
+    expert layers in model order (the leading dense layers have none).
+    They are the `ys` of the expert-layer scan, so they cost one small
+    output and no second pass.
     """
     c = config
     B, S = tokens.shape
+    if return_routed and not c.is_moe:
+        raise ValueError("return_routed needs a model with routed experts")
     if ragged is not None:
         if B != 1:
             raise ValueError("ragged forward takes a single flat [1, T] row")
@@ -325,23 +335,26 @@ def forward(
         # named scopes mark the parts of a layer in HLO metadata (an HLO
         # dump and xprof then say which part a fusion belongs to); they
         # change no computation
+        sel = None  # the router's picks, an expert layer's scan output
         if c.is_mla:
-            with jax.named_scope("attn.kernel"):
-                attn, k_pool = _mla_attention(
-                    c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
-                    kv_lens, attn_impl=attn_impl, mesh=mesh,
-                    q_start=q_start, q_len=q_len,
-                )
+            # _mla_attention names its own parts (attn.proj / absorb /
+            # kernel / lift), matching the GQA path below
+            attn, k_pool = _mla_attention(
+                c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
+                kv_lens, attn_impl=attn_impl, mesh=mesh,
+                q_start=q_start, q_len=q_len,
+            )
             with jax.named_scope("attn.proj"):
                 h = h + mm(attn, lp["wo"])
             with jax.named_scope("ffn"):
                 x = rms_norm(h, lp["mlp_norm"], c.norm_eps)
                 if use_moe:
-                    h = h + _moe_block(c, lp, x, mesh)
+                    ffw, sel = _moe_block(c, lp, x, mesh)
+                    h = h + ffw
                 else:
                     gate = jax.nn.silu(mm(x, lp["w_gate"]))
                     h = h + mm(gate * mm(x, lp["w_up"]), lp["w_down"])
-            return (h, k_pool, v_pool), None
+            return (h, k_pool, v_pool), (sel if return_routed else None)
 
         zc = c.norm_zero_centered
         with jax.named_scope("attn.proj"):
@@ -561,7 +574,7 @@ def forward(
                  if c.pre_norms else h)
             rm = c.residual_multiplier
             if use_moe:
-                ffw = _moe_block(c, lp, x, mesh)
+                ffw, sel = _moe_block(c, lp, x, mesh)
             else:
                 act = (
                     partial(jax.nn.gelu, approximate=True)
@@ -577,7 +590,7 @@ def forward(
             if rm != 1.0:  # Granite branch scaling
                 ffw = ffw * jnp.asarray(rm, ffw.dtype)
             h = h + ffw
-        return (h, k_pool, v_pool), None
+        return (h, k_pool, v_pool), (sel if return_routed else None)
 
     dense_stack = params.get("layers_dense")
     if dense_stack is not None:
@@ -593,14 +606,14 @@ def forward(
             (h, k_pool, v_pool),
             (dense_stack, {}, jnp.arange(kD, dtype=jnp.int32)),
         )
-        (h, k_pool, v_pool), _ = lax.scan(
+        (h, k_pool, v_pool), routed = lax.scan(
             make_layer(True),
             (h, k_pool, v_pool),
             (params["layers"], {},
              jnp.arange(kD, c.n_layers, dtype=jnp.int32)),
         )
     else:
-        (h, k_pool, v_pool), _ = lax.scan(
+        (h, k_pool, v_pool), routed = lax.scan(
             make_layer(c.is_moe),
             (h, k_pool, v_pool),
             (params["layers"], lora_layers,
@@ -636,6 +649,8 @@ def forward(
         if c.final_logit_softcap:
             cap = c.final_logit_softcap
             logits = cap * jnp.tanh(logits / cap)
+    if return_routed:
+        return logits, k_pool, v_pool, routed  # [L_moe, B, S, k]
     return logits, k_pool, v_pool
 
 
@@ -693,7 +708,7 @@ def encode(
         h = h + mm(attn, lp["wo"])
         x = rms_norm(h, lp["mlp_norm"], c.norm_eps)
         if c.is_moe:
-            h = h + _moe_block(c, lp, x)
+            h = h + _moe_block(c, lp, x)[0]
         else:
             h = h + mm(jax.nn.silu(mm(x, lp["w_gate"])) * mm(x, lp["w_up"]), lp["w_down"])
         return h, None

@@ -35,35 +35,60 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
 
     RoPE uses this module's half-rotation convention; HF DeepSeek
     checkpoints interleave — engine/weights.py must permute on import.
-    Returns (attn [B, S, H*d_v], k_pool)."""
+    Returns (attn [B, S, H*d_v], k_pool).
+
+    Named scopes, as on the GQA path (models/llama.py): `attn.proj` the
+    query and latent projections with their norms, RoPE and the cache
+    write; `attn.absorb` W_UK folded into the query; `attn.kernel` the
+    attention over the latent cache; `attn.lift` W_UV back to per-head
+    values. The output projection is the caller's `attn.proj`."""
     B, S = positions.shape
     H = c.n_heads
     dn, dr, dv, dc = (c.qk_nope_head_dim, c.qk_rope_head_dim,
                       c.v_head_dim, c.kv_lora_rank)
 
-    x = rms_norm(h, lp["attn_norm"], c.norm_eps)
-    if c.q_lora_rank:
-        q_lat = rms_norm(mm(x, lp["wq_lat"]), lp["q_lat_norm"], c.norm_eps)
-        q = mm(q_lat, lp["wq_up"])
-    else:
-        q = mm(x, lp["wq"])
-    q = q.reshape(B, S, H, dn + dr)
-    q_nope, q_r = q[..., :dn], q[..., dn:]
-    q_r = rope(q_r, safe_pos, c.rope_theta, config=c)
+    with jax.named_scope("attn.proj"):
+        x = rms_norm(h, lp["attn_norm"], c.norm_eps)
+        if c.q_lora_rank:
+            q_lat = rms_norm(mm(x, lp["wq_lat"]), lp["q_lat_norm"], c.norm_eps)
+            q = mm(q_lat, lp["wq_up"])
+        else:
+            q = mm(x, lp["wq"])
+        q = q.reshape(B, S, H, dn + dr)
+        q_nope, q_r = q[..., :dn], q[..., dn:]
+        q_r = rope(q_r, safe_pos, c.rope_theta, config=c)
 
-    kv = mm(x, lp["wkv_a"])  # [B, S, d_c + d_rh]
-    c_kv = rms_norm(kv[..., :dc], lp["kv_norm"], c.norm_eps)
-    k_r = rope(kv[..., None, dc:], safe_pos, c.rope_theta, config=c)[..., 0, :]
-    lat = jnp.concatenate([c_kv, k_r], axis=-1)[:, :, None, :]  # [B,S,1,D]
-    k_pool = _write_kv(k_pool, l_idx, lat, page_table, positions)
+        kv = mm(x, lp["wkv_a"])  # [B, S, d_c + d_rh]
+        c_kv = rms_norm(kv[..., :dc], lp["kv_norm"], c.norm_eps)
+        k_r = rope(kv[..., None, dc:], safe_pos, c.rope_theta, config=c)[..., 0, :]
+        lat = jnp.concatenate([c_kv, k_r], axis=-1)[:, :, None, :]  # [B,S,1,D]
+        k_pool = _write_kv(k_pool, l_idx, lat, page_table, positions)
     quantized = isinstance(k_pool, dict)  # int8 latent cache
-    lat_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
 
     wkv_b = lp["wkv_b"].reshape(dc, H, dn + dv)
     w_uk, w_uv = wkv_b[..., :dn], wkv_b[..., dn:]
-    q_abs = jnp.einsum("bshn,chn->bshc", q_nope, w_uk)  # [B,S,H,d_c]
+    with jax.named_scope("attn.absorb"):
+        q_abs = jnp.einsum("bshn,chn->bshc", q_nope, w_uk)  # [B,S,H,d_c]
     scale = attn_score_scale(c, dn + dr)
     tp = mesh is not None and mesh.shape.get("model", 1) > 1
+    with jax.named_scope("attn.kernel"):
+        attn_lat = _latent_attention(
+            k_pool, l_idx, q_abs, q_r, page_table, safe_pos, kv_lens,
+            quantized=quantized, attn_impl=attn_impl, tp=tp, mesh=mesh,
+            q_start=q_start, q_len=q_len, dc=dc, scale=scale)
+    with jax.named_scope("attn.lift"):
+        attn = jnp.einsum("bshc,chv->bshv", attn_lat, w_uv)
+    return attn.reshape(B, S, H * dv), k_pool
+
+
+def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
+                      kv_lens, *, quantized, attn_impl, tp, mesh, q_start,
+                      q_len, dc, scale):
+    """Attention of the absorbed query over the layer's latent pages, by
+    the path the pool's dtype, the platform and the step's shape select.
+    Returns the attended latent [B, S, H, d_c]."""
+    S = q_abs.shape[1]
+    lat_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
     if quantized:
         # int8 latent pages. Decode can ride the Pallas kernel (scales
         # fold into scores/values per token) — opt-in via
@@ -137,5 +162,4 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
             qg, lat_pool_l, lat_pool_l[..., :dc], page_table, safe_pos,
             kv_lens, scale=scale,
         )[:, :, 0]  # [B, S, H, d_c]
-    attn = jnp.einsum("bshc,chv->bshv", attn_lat, w_uv)
-    return attn.reshape(B, S, H * dv), k_pool
+    return attn_lat

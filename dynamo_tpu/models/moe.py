@@ -1,10 +1,21 @@
-"""Mixture-of-experts block: token-choice top-k routing with the wide-EP
-all-to-all dispatch (ops/moe_dispatch.py) on expert meshes, dense
-every-expert fallback elsewhere. Shared experts (DeepSeek/Qwen2-MoE)
-stay out of the dispatch entirely.
+"""Mixture-of-experts block: token-choice top-k routing. On a mesh with
+an expert axis tokens go to their experts by the wide-EP all-to-all
+dispatch (ops/moe_dispatch.py). Everywhere else, a single chip included,
+every expert is computed for every token and the routed ones are picked
+out afterwards: n_experts / n_experts_active times the routed FLOPs and
+every expert's weights read each step (128/6 = 21x at 128 experts, six a
+token), which is ROADMAP S4's open item. Shared experts (DeepSeek /
+Qwen2-MoE) stay out of the dispatch entirely.
+
+The block hands the router's picks out beside its output, and
+`routing_stats` reduces a forward's picks to the three expert-load
+counters of an engine iteration (docs/observability.md, "Routed
+experts").
 """
 
 from __future__ import annotations
+
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -13,12 +24,14 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.quant import mm
 
 
-def _moe_block(c: ModelConfig, lp, x: jax.Array, mesh=None) -> jax.Array:
+def _moe_block(c: ModelConfig, lp, x: jax.Array,
+               mesh=None) -> Tuple[jax.Array, jax.Array]:
     """Token-choice top-k MoE. With an expert mesh axis (and unquantized
     experts), tokens dispatch to their experts with one all_to_all over ICI
     and return with a second (ops/moe_dispatch.py — wide-EP); otherwise the
     dense path computes every expert under GSPMD expert sharding. x:
-    [B, S, E] → [B, S, E]."""
+    [B, S, E] → ([B, S, E], sel int32 [B, S, k]: the experts the router
+    picked for each token, the same on both paths)."""
     from dynamo_tpu.models.quant import is_quantized
 
     B, S, E = x.shape
@@ -27,17 +40,18 @@ def _moe_block(c: ModelConfig, lp, x: jax.Array, mesh=None) -> jax.Array:
     # of the EP all_to_all entirely
     shared = 0.0
     if c.n_shared_experts:
-        gate = jax.nn.silu(mm(x, lp["ws_gate"]))
-        shared = mm(gate * mm(x, lp["ws_up"]), lp["ws_down"])
-        if "ws_gatectl" in lp:  # qwen2-moe: sigmoid-gated shared expert
-            shared = shared * jax.nn.sigmoid(x @ lp["ws_gatectl"])
+        with jax.named_scope("moe.shared"):
+            gate = jax.nn.silu(mm(x, lp["ws_gate"]))
+            shared = mm(gate * mm(x, lp["ws_up"]), lp["ws_down"])
+            if "ws_gatectl" in lp:  # qwen2-moe: sigmoid-gated shared expert
+                shared = shared * jax.nn.sigmoid(x @ lp["ws_gatectl"])
     ep = mesh is not None and mesh.shape.get("expert", 1) > 1
     if ep and not is_quantized(lp["we_gate"]) and (B * S) % mesh.shape["expert"] == 0:
         from dynamo_tpu.ops.moe_dispatch import moe_ep
 
         model_axis = "model" if mesh.shape.get("model", 1) > 1 else None
         cf = c.moe_capacity_factor or (c.n_experts / c.n_experts_active)
-        y = moe_ep(
+        y, sel = moe_ep(
             x.reshape(B * S, E),
             lp["w_router"], lp["we_gate"], lp["we_up"], lp["we_down"],
             mesh, c.n_experts_active,
@@ -50,27 +64,54 @@ def _moe_block(c: ModelConfig, lp, x: jax.Array, mesh=None) -> jax.Array:
             n_groups=c.n_expert_groups,
             topk_groups=c.topk_groups,
         )
-        return y.reshape(B, S, E) + shared
+        return y.reshape(B, S, E) + shared, sel.reshape(B, S, -1)
     from dynamo_tpu.ops.moe_dispatch import router_topk
 
-    router_logits = (x @ lp["w_router"]).astype(jnp.float32)  # [B,S,n_exp]
-    weights, sel = router_topk(
-        router_logits, c.n_experts_active, c.moe_scoring, c.moe_norm_topk,
-        bias=lp.get("router_bias"), routed_scale=c.moe_routed_scale,
-        n_groups=c.n_expert_groups, topk_groups=c.topk_groups,
-    )
-    weights = weights.astype(x.dtype)
+    with jax.named_scope("moe.route"):
+        router_logits = (x @ lp["w_router"]).astype(jnp.float32)  # [B,S,n_exp]
+        weights, sel = router_topk(
+            router_logits, c.n_experts_active, c.moe_scoring, c.moe_norm_topk,
+            bias=lp.get("router_bias"), routed_scale=c.moe_routed_scale,
+            n_groups=c.n_expert_groups, topk_groups=c.topk_groups,
+        )
+        weights = weights.astype(x.dtype)
+        sel = sel.astype(jnp.int32)
 
-    # compute every expert on every token (fine at test scale; EP replaces it)
+    # every expert on every token, then the routed ones picked out: the
+    # one-chip path's cost is n_experts / n_experts_active times the
+    # routed FLOPs (ROADMAP S4); an expert mesh takes moe_ep above
     def one_expert(we_gate, we_up, we_down):
         gate = jax.nn.silu(mm(x, we_gate))
         return mm(gate * mm(x, we_up), we_down)  # [B,S,E]
 
-    expert_out = jax.vmap(one_expert)(lp["we_gate"], lp["we_up"], lp["we_down"])
-    # expert_out: [n_exp, B, S, E]; select & mix
-    sel_out = jnp.take_along_axis(
-        expert_out.transpose(1, 2, 0, 3),  # [B,S,n_exp,E]
-        sel[..., None].astype(jnp.int32),
-        axis=2,
-    )  # [B,S,k,E]
-    return jnp.sum(sel_out * weights[..., None], axis=2) + shared
+    with jax.named_scope("moe.experts"):
+        expert_out = jax.vmap(one_expert)(lp["we_gate"], lp["we_up"], lp["we_down"])
+        # expert_out: [n_exp, B, S, E]; select & mix
+        sel_out = jnp.take_along_axis(
+            expert_out.transpose(1, 2, 0, 3),  # [B,S,n_exp,E]
+            sel[..., None],
+            axis=2,
+        )  # [B,S,k,E]
+        routed = jnp.sum(sel_out * weights[..., None], axis=2)
+    return routed + shared, sel
+
+
+def routing_stats(sel: jax.Array, valid: jax.Array, n_experts: int) -> jax.Array:
+    """One forward's expert load, reduced on the device. sel int32
+    [L_moe, T, k] (any token layout flattened to T), valid bool [T]
+    (False = padding, not counted). Returns f32 [3]:
+      [0] routed token-slots: real tokens x k (one layer's; the same in all)
+      [1] experts selected at least once, summed over the expert layers
+      [2] the share of the real tokens that picked a layer's fullest
+          expert (k / n_experts is even, 1.0 is one straggler), summed
+          over the expert layers (0 in a forward with no real token)
+    The caller sums [1] and [2] over an iteration's forwards and divides by
+    forwards x L_moe (engine `_record_iteration`)."""
+    onehot = (sel[..., None] == jnp.arange(n_experts, dtype=sel.dtype)) \
+        & valid[None, :, None, None]
+    load = jnp.sum(onehot, axis=(1, 2), dtype=jnp.int32)  # [L_moe, n_experts]
+    tokens = jnp.sum(valid, dtype=jnp.int32)
+    hit = jnp.sum(load > 0, dtype=jnp.int32)
+    share = jnp.sum(jnp.max(load, axis=-1) / jnp.maximum(tokens, 1))
+    return jnp.stack([(tokens * sel.shape[-1]).astype(jnp.float32),
+                      hit.astype(jnp.float32), share.astype(jnp.float32)])

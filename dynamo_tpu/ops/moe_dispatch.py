@@ -14,9 +14,12 @@ dropped (contribute zero), standard Switch/GShard semantics — with
 capacity_factor ≥ n_experts/k the dispatch is lossless and matches the
 dense reference exactly.
 
-Engine integration note: models/llama.py currently computes MoE densely
-with expert-sharded weights (GSPMD all-gather EP); this op replaces that
-path once engine activations are token-sharded over `expert` (round 2).
+Engine integration note: models/moe.py takes this path on a mesh with an
+expert axis (unquantized experts, token count divisible by the ranks);
+everywhere else it computes every expert for every token.
+
+Both paths hand the router's picks (`sel`) out beside the output: the
+step programs return them (docs/observability.md, "Routed experts").
 """
 
 from __future__ import annotations
@@ -92,18 +95,20 @@ def _local_moe(x, w_router, we_gate, we_up, we_down, k: int, capacity: int, axis
                router_bias=None, routed_scale: float = 1.0,
                n_groups: int = 0, topk_groups: int = 0):
     """Per-shard body. x: [T, E] local tokens; we_*: [n_local, ...] resident
-    experts; router weights replicated. Returns [T, E]."""
+    experts; router weights replicated. Returns ([T, E], sel [T, k])."""
     n_ranks = lax.psum(1, axis)
     rank = lax.axis_index(axis)
     T, E = x.shape
     n_local = we_gate.shape[0]
     n_experts = n_local * n_ranks
 
-    logits = (x @ w_router).astype(jnp.float32)  # [T, n_experts]
-    weights, sel = router_topk(logits, k, scoring, norm_topk,
-                               bias=router_bias, routed_scale=routed_scale,
-                               n_groups=n_groups, topk_groups=topk_groups)
-    weights = weights.astype(x.dtype)
+    with jax.named_scope("moe.route"):
+        logits = (x @ w_router).astype(jnp.float32)  # [T, n_experts]
+        weights, sel = router_topk(
+            logits, k, scoring, norm_topk, bias=router_bias,
+            routed_scale=routed_scale, n_groups=n_groups,
+            topk_groups=topk_groups)
+        weights = weights.astype(x.dtype)
 
     # flatten (token, choice) pairs and bucket by destination rank
     flat_sel = sel.reshape(-1)  # [T*k] expert ids
@@ -126,8 +131,9 @@ def _local_moe(x, w_router, we_gate, we_up, we_down, k: int, capacity: int, axis
     disp_expert = disp_expert.at[slot_r, slot_c].set(flat_sel % n_local, mode="drop")
 
     # exchange: [R, C, E] → every rank receives its inbound tokens
-    recv_x = lax.all_to_all(disp_x, axis, split_axis=0, concat_axis=0, tiled=False)
-    recv_expert = lax.all_to_all(disp_expert, axis, split_axis=0, concat_axis=0, tiled=False)
+    with jax.named_scope("moe.dispatch"):
+        recv_x = lax.all_to_all(disp_x, axis, split_axis=0, concat_axis=0, tiled=False)
+        recv_expert = lax.all_to_all(disp_expert, axis, split_axis=0, concat_axis=0, tiled=False)
     # recv_x: [R, C, E] — row r = tokens sent by rank r to us
 
     rx = recv_x.reshape(n_ranks * capacity, E)
@@ -140,24 +146,26 @@ def _local_moe(x, w_router, we_gate, we_up, we_down, k: int, capacity: int, axis
     def expert_fn(wg, wu, wd):
         return (jax.nn.silu(rx @ wg) * (rx @ wu)) @ wd  # [RC, E]
 
-    all_out = jax.vmap(expert_fn)(we_gate, we_up, we_down)  # [n_local, RC, E]
-    if model_axis is not None:
-        all_out = lax.psum(all_out, model_axis)
-    out_tok = jnp.take_along_axis(
-        all_out.transpose(1, 0, 2), re_[:, None, None], axis=1
-    )[:, 0]  # [RC, E]
+    with jax.named_scope("moe.experts"):
+        all_out = jax.vmap(expert_fn)(we_gate, we_up, we_down)  # [n_local, RC, E]
+        if model_axis is not None:
+            all_out = lax.psum(all_out, model_axis)
+        out_tok = jnp.take_along_axis(
+            all_out.transpose(1, 0, 2), re_[:, None, None], axis=1
+        )[:, 0]  # [RC, E]
 
     # send results back
-    back = lax.all_to_all(
-        out_tok.reshape(n_ranks, capacity, E), axis, split_axis=0, concat_axis=0
-    )  # [R, C, E] — row r = results for pairs we sent to rank r
+    with jax.named_scope("moe.combine"):
+        back = lax.all_to_all(
+            out_tok.reshape(n_ranks, capacity, E), axis, split_axis=0, concat_axis=0
+        )  # [R, C, E] — row r = results for pairs we sent to rank r
 
     # combine: scatter-add weighted results back to source tokens
     y = jnp.zeros((T, E), jnp.float32)
     gathered = back[slot_r.clip(0, n_ranks - 1), slot_c.clip(0, capacity - 1)]
     gathered = jnp.where(keep[:, None], gathered.astype(jnp.float32), 0.0)
     y = y.at[flat_tok].add(gathered * flat_w[:, None].astype(jnp.float32))
-    return y.astype(x.dtype)
+    return y.astype(x.dtype), sel.astype(jnp.int32)
 
 
 def moe_ep(
@@ -177,8 +185,9 @@ def moe_ep(
     routed_scale: float = 1.0,
     n_groups: int = 0,  # group-limited selection (DeepSeek-V3)
     topk_groups: int = 0,
-) -> jax.Array:
-    """Token-dispatch EP MoE. Returns [T, E] with x's sharding."""
+) -> Tuple[jax.Array, jax.Array]:
+    """Token-dispatch EP MoE. Returns ([T, E], sel int32 [T, k]: each
+    token's routed experts), both with x's token sharding."""
     n_ranks = mesh.shape[axis]
     T_local = x.shape[0] // n_ranks
     n_experts = we_gate.shape[0]
@@ -211,7 +220,8 @@ def moe_ep(
         in_specs.append(SPEC_REPLICATED)
         args.append(router_bias)
     fn = jax.shard_map(
-        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=tok_spec
+        body, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=(tok_spec, tok_spec),  # sel rides out on the token axis
     )
     return fn(*args)
 

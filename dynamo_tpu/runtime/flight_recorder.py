@@ -59,7 +59,21 @@ class IterationRecord:
     prep + staging + dispatch + the device's time + readback + emit +
     publish (the `engine.<parent>` and `engine.publish` spans, less the
     record's own append), and it leaves out the inbox, the scheduler and
-    any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`)."""
+    any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`).
+
+    The three `moe_*` fields are a routed model's expert load, reduced on
+    the device from the router's picks over real rows (padding masked)
+    and read back with the sampled tokens; 0 / 0.0 for a dense model:
+    `moe_token_slots` [token-slots] real tokens x experts a token, over
+    the iteration's forwards (rows x steps x k + chunk tokens x k);
+    `moe_experts_hit` [experts, of n_experts] how many were picked at
+    least once in a forward, mean over expert layers and forwards (what
+    a step reads of the expert weights where only the picked are read);
+    `moe_load_max_share` [fraction] the share of a forward's real tokens
+    that picked the layer's fullest expert, the same mean (k / n_experts
+    is an even load, 1.0 is every token on one expert: a straggler).
+    A forward is one pass of the layers: a decode step, a prefill chunk
+    set, a ragged step."""
 
     seq: int               # engine iteration number (monotonic)
     ts: float              # wall clock (time.time()) at iteration start
@@ -92,6 +106,10 @@ class IterationRecord:
     guided_rows: int = 0       # constraint-masked decode rows this iteration
     tree_hit_blocks: int = 0   # cumulative blocks served warm by match_prefix
     forks: int = 0             # cumulative fork-on-branch fan-outs
+    # routed experts (see the docstring; engine `_record_iteration`)
+    moe_token_slots: int = 0
+    moe_experts_hit: float = 0.0
+    moe_load_max_share: float = 0.0
     # causal tracing: trace ids of the requests this iteration served
     # (bounded by the engine at append time) — joins the per-iteration
     # timeline to the distributed span rings and incident bundles

@@ -456,6 +456,35 @@ async def serve_worker(
         engine.on_fpm(_update_compile_gauges)
         _update_compile_gauges()
 
+    # routed experts -> /metrics: the engine's expert-load counters
+    # (IterationRecord.moe_*; docs/observability.md "Routed experts") as
+    # two gauges of the newest iteration and one running total. Only a
+    # worker whose step programs hand out the picks has the series.
+    if getattr(_runner, "routed", False) and hasattr(engine, "moe_totals"):
+        _mm = runtime.metrics.child(dynamo_namespace=namespace)
+        _moe_sent = {"slots": 0}
+
+        def _update_moe_gauges(_m=None) -> None:
+            t = engine.moe_totals
+            _mm.gauge(
+                "moe_experts_hit",
+                "experts picked at least once in a forward, mean over "
+                "expert layers and the last iteration's forwards",
+            ).set(t["experts_hit"])
+            _mm.gauge(
+                "moe_load_max_share",
+                "share of a forward's tokens on the fullest expert, mean "
+                "over expert layers and the last iteration's forwards",
+            ).set(t["load_max_share"])
+            _mm.counter(
+                "moe_token_slots_total",
+                "routed token-slots served (real tokens x experts a token)",
+            ).inc(t["token_slots_total"] - _moe_sent["slots"])
+            _moe_sent["slots"] = t["token_slots_total"]
+
+        engine.on_fpm(_update_moe_gauges)
+        _update_moe_gauges()
+
     # latency spine -> /metrics: per-finished-request phase durations as
     # histograms labeled by phase (queue_wait/ttft/kv_onboard/...; ITL
     # samples fold into one phase="itl" histogram). Fired from the engine

@@ -29,18 +29,19 @@ def test_moe_ep_matches_dense(ep):
     x, wr, wg, wu, wd = _setup()
     k = 2
     # capacity_factor = n_experts/k guarantees losslessness
-    out = moe_ep(x, wr, wg, wu, wd, mesh, n_experts_active=k,
-                 capacity_factor=wg.shape[0] / k, axis="expert")
+    out, sel = moe_ep(x, wr, wg, wu, wd, mesh, n_experts_active=k,
+                      capacity_factor=wg.shape[0] / k, axis="expert")
     ref = moe_dense_reference(x, wr, wg, wu, wd, k)
     d = np.abs(np.asarray(out) - np.asarray(ref)).max()
     assert d < 1e-4, d
+    assert sel.shape == (x.shape[0], k) and sel.dtype == jnp.int32
 
 
 def test_moe_ep_tight_capacity_drops_not_corrupts():
     mesh = make_mesh(MeshConfig(expert=4, data=2))
     x, wr, wg, wu, wd = _setup(seed=3)
-    out = moe_ep(x, wr, wg, wu, wd, mesh, n_experts_active=2,
-                 capacity_factor=0.5, axis="expert")
+    out, _ = moe_ep(x, wr, wg, wu, wd, mesh, n_experts_active=2,
+                    capacity_factor=0.5, axis="expert")
     ref = moe_dense_reference(x, wr, wg, wu, wd, 2)
     # some tokens dropped → not equal, but finite and bounded
     assert np.isfinite(np.asarray(out)).all()
