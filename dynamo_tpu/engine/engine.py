@@ -1050,6 +1050,9 @@ class InferenceEngine:
         rinfo["guided_rows"] = sum(
             1 for s in _dseqs if s.guided_m is not None
         )
+        if _dseqs and self.recorder.enabled:
+            rinfo["pages_live"] = self._decode_pages_live(
+                _dseqs, getattr(plan, "decode", plan).n_steps)
         decode_done = False
         try:
             if isinstance(plan, PrefillPlan):
@@ -1270,6 +1273,7 @@ class InferenceEngine:
             prefetch_hits=hits,
             compile_variants=variants,
             compile_calls=calls,
+            decode_pages_live=rinfo.get("pages_live", 0),
             accepted_per_step=(
                 rinfo.get("spec_emitted", 0) / rinfo["spec_rows"]
                 if rinfo.get("spec_rows") else 0.0
@@ -1280,6 +1284,29 @@ class InferenceEngine:
             trace_ids=trace_ids,
         )
         self._settle_record(record, load)
+
+    def _decode_pages_live(self, seqs, n_steps: int) -> int:
+        """IterationRecord.decode_pages_live for these decode rows, from
+        their positions before the step: at fused step t a row's context
+        is computed_len + t + 1 tokens, and the device runs every row for
+        all n_steps (tokens past a stop are dropped on the host)."""
+        ps = self.pool.page_size
+        c = getattr(self.runner, "config", None)
+        window = getattr(c, "sliding_window", 0) or 0
+        n_global = 0
+        if window:
+            n_global = sum(l % c.sw_period == c.sw_global_residue
+                           for l in range(c.n_layers))
+        full = sliding = 0
+        for s in seqs:
+            for n in range(s.computed_len + 1, s.computed_len + n_steps + 1):
+                last = (n - 1) // ps
+                full += last + 1
+                sliding += last - max(n - window, 0) // ps + 1
+        if not window:
+            return full
+        return round((full * n_global + sliding * (c.n_layers - n_global))
+                     / c.n_layers)
 
     def _settle_record(self, record, load) -> None:
         """Append an iteration's record (None: the recorder is off) with

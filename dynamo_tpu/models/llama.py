@@ -300,6 +300,24 @@ def forward(
 
         h = lax.with_sharding_constraint(h, NamedSharding(mesh, SPEC_SEQ_ACT))
 
+    # the Pallas decode kernel walks a list of its rows' live pages, which
+    # hangs on the lengths and the window alone: built here, once a step,
+    # because XLA leaves it in the layer scan's body (three small fusions
+    # a layer). A model whose layers alternate sliding and global gets
+    # both lists and each layer picks one.
+    walk_sliding = walk_global = None
+    if attn_impl == "pallas" and S == 1 and ragged is None and not c.is_mla:
+        from dynamo_tpu.ops.paged_attention import decode_work_list
+
+        walk_shape = (jax.tree.leaves(k_pool)[0].shape[2], page_table.shape[1])
+        if c.sliding_window > 0:
+            walk_sliding = decode_work_list(
+                kv_lens, jnp.int32(c.sliding_window), *walk_shape)
+        if c.sliding_window <= 0 or any(
+                l % c.sw_period == c.sw_global_residue
+                for l in range(c.n_layers)):
+            walk_global = decode_work_list(kv_lens, None, *walk_shape)
+
     lora_layers = (lora or {}).get("layers", {})
     if lora_layers and c.is_mla:
         # the MLA branch never consults the LoRA factors; failing loudly
@@ -487,15 +505,20 @@ def forward(
                 )
 
                 kwg = dict(scale=g_scale, softcap=c.attn_logit_softcap)
+                walk = walk_global if walk_sliding is None else walk_sliding
+                if walk_sliding is not None and walk_global is not None:
+                    walk = jax.tree.map(
+                        lambda g, s: jnp.where(win > 0, s, g),
+                        walk_global, walk_sliding)
                 if tp:
                     attn = decode_paged_attention_sharded(
                         qg[:, 0], k_pool, v_pool, page_table, kv_lens,
-                        mesh, window=win, layer=l_idx, **kwg,
+                        mesh, window=win, layer=l_idx, work=walk, **kwg,
                     )[:, None]
                 else:
                     attn = decode_paged_attention(
                         qg[:, 0], k_pool, v_pool, page_table, kv_lens,
-                        win, l_idx, **kwg,
+                        win, l_idx, walk, **kwg,
                     )[:, None]  # [B, 1, Hk, G, hd]
             elif attn_impl == "pallas":
                 # flash prefill carries the gemma extras the same way the

@@ -12,14 +12,29 @@ KV working set. This kernel streams each page
 HBM→VMEM once via BlockSpec index_maps driven by the scalar-prefetched page
 table and accumulates flash-attention-style online softmax in VMEM scratch.
 
-Grid: (B, MP) — page index innermost so the per-sequence running softmax
-state lives across the page loop; all kv heads are processed per step. A
-token-major page [PS, Hk, D] is one CONTIGUOUS slab in the pool, so each
-grid step issues a single large DMA (the head-major layout needed Hk
-strided chunks per page). Ragged contexts cost
-only what they use: the index_map clamps pages past kv_len to the last
-valid page, so consecutive grid steps see an unchanged block index and
-Pallas elides the HBM→VMEM copy (and pl.when skips the compute).
+Grid: a WORK LIST of live pages, one grid step each, its length a traced
+bound. A call costs what its rows hold, not what the page table could:
+`decode_work_list` builds, in XLA from the lengths and the sliding window
+alone, the (row, page) of every live page — row b's run from the first
+page its window shows to the page of its newest token, a pad row
+(kv_len 0) has none — rows in order and pages ascending, so a row's
+running softmax state lives across its pages: it is initialised on the
+row's first live page and written out on its last. The index maps read
+the list, then the page table; a step that is never taken costs nothing
+(under the old grid (B, MP) a 450-token row in a 4096-token table took 64
+steps for 8 pages). A row with no live page is never visited and its
+output is defined by the wrapper (0). No shape of the call depends on the
+lengths: one program whatever the rows hold. A caller that runs many
+layers on one set of lengths builds the list once and hands it in (`work`;
+models/llama.py does, above its layer scan).
+
+All kv heads are processed per step. A token-major page [PS, Hk, D] is one
+CONTIGUOUS slab in the pool, so each grid step issues a single large DMA
+(the head-major layout needed Hk strided chunks per page). What a step
+does with its page is chosen from the static shapes the kernel sees, one
+walk either way: `_page_by_heads`, a batched MXU product a kv head (GQA:
+G query heads share the page), or at G = 1 `_page_by_rows`, every query
+row against the page read as one [PS * Hk, D] matrix.
 
 The reference framework ships CUDA kernels for its block engine
 (lib/llm/src/kernels/block_copy.cu, lib/kvbm-kernels/cuda/
@@ -33,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -86,126 +102,237 @@ def split_scales(k_pool, v_pool, layer):
     return k_pool["q"], v_pool["q"], ks, vs
 
 
+def decode_work_list(kv_lens, window, page_size: int, max_pages: int):
+    """The decode kernel's grid as a list of live pages, built in XLA from
+    the lengths (and the window) alone: (work[W] int32, n_work int32).
+
+    Row b's live pages run from `lo // PS` (the first page its window
+    shows; 0 without one) to `(kv_len - 1) // PS`; a pad row (kv_len 0)
+    has none. Entry w < n_work is `row * MP + page` of the w-th live page,
+    rows in order and each row's pages ascending; W = B * MP is the static
+    bound and the entries past n_work are never visited. One packed list:
+    scalar-prefetch operands live in SMEM, where the worker's default
+    shape (bucket 64, MP 256) already keeps a 64 KB page table."""
+    B = kv_lens.shape[0]
+    first, last = _live_pages(kv_lens, window, page_size, max_pages)
+    count = jnp.where(kv_lens > 0, last - first + 1, 0)
+    ends = jnp.cumsum(count)
+    # row of entry w = how many rows end at or before it (a row with no
+    # live page ends where it starts and is stepped over); its page =
+    # first[row] + (w - start[row]). Both as [W, B] compares-and-sums:
+    # one small fusion, no gather and no sort
+    w = lax.iota(jnp.int32, B * max_pages)
+    before = w[:, None] >= ends[None, :]
+    row = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), B - 1)
+    here = lax.iota(jnp.int32, B)[None, :] == row[:, None]
+    shift = first - (ends - count)
+    page = w + jnp.sum(jnp.where(here, shift[None, :], 0), axis=1)
+    page = jnp.clip(page, 0, max_pages - 1)
+    return row * max_pages + page, ends[-1]
+
+
+def _live_pages(kv_len, window, page_size: int, max_pages: int):
+    """(first, last) live page of a row that holds kv_len tokens: the
+    decode query sits at position kv_len - 1, so with a sliding window
+    only positions >= lo = kv_len - window are visible and the pages
+    wholly below lo are dead. Shared by the work list (XLA, [B] arrays)
+    and the kernel (SMEM scalars) so the two cannot drift apart. Both
+    stay inside the page table whatever kv_len says: a length past
+    MP * PS walks the table's MP pages, as the old grid did, and never
+    makes the list longer than its W entries."""
+    last = jnp.minimum(
+        _div(jnp.maximum(kv_len - 1, 0), page_size), max_pages - 1)
+    return jnp.minimum(_div(_window_lo(kv_len, window), page_size), last), last
+
+
+def _div(a, b: int):
+    """a // b for a >= 0 and a static b > 0. `//` and `%` on traced ints
+    are floor_divide / remainder, a dozen equations each for signs that
+    cannot occur here; the walk's index maps and body hold ten of them,
+    traced and lowered again for every step program (42 for the cell)."""
+    return lax.div(a, np.int32(b))
+
+
+def _rem(a, b: int):
+    return lax.rem(a, np.int32(b))
+
+
+def _window_lo(kv_len, window):
+    if window is None:
+        return jnp.zeros_like(kv_len)
+    return jnp.where(window > 0, jnp.maximum(kv_len - window, 0), 0)
+
+
 def _decode_kernel_body(
+    work_ref,  # [B * MP] int32 (SMEM): row * MP + page of each live page
     page_table_ref,  # [B, MP] int32 (SMEM)
     kv_lens_ref,  # [B] int32 (SMEM)
     win_ref,  # [1] int32 sliding window (0 = global) or None (no-window
     #   compile: Gemma-2 alternates sliding/global per layer with a
     #   TRACED scalar, so the window rides as a prefetch operand)
-    q_ref,  # [Hk, G, D] all query heads for seq b
+    q_ref,  # [Hk, G, D] all query heads of the row ([Hk, D] by rows)
     k_ref,  # [PS, Hk, D] one token-major page of keys (one contiguous DMA)
     v_ref,  # [PS, Hk, D]
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
-    o_ref,  # [Hk, G, D]
-    # scratch (persist across the page loop)
-    m_ref,  # [Hk, G, 1] f32 running max
-    l_ref,  # [Hk, G, 1] f32 running denom
-    acc_ref,  # [Hk, G, D] f32 running numerator
+    o_ref,  # like q_ref
+    # scratch (persist across a row's pages)
+    m_ref,  # f32 running max: [Hk, G, 1] ([Hk, 1] by rows)
+    l_ref,  # f32 running denom, like m_ref
+    acc_ref,  # f32 running numerator, like q_ref
     *,
     page_size: int,
+    max_pages: int,
     scale: float,
     softcap: float = 0.0,  # Gemma-2 attention-score soft capping (0 = off)
+    by_rows: bool = False,  # the per-page routine (see decode_paged_attention)
 ):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    entry = work_ref[pl.program_id(0)]
+    b = _div(entry, max_pages)
+    i = _rem(entry, max_pages)
+    kv_len = kv_lens_ref[b]
+    window = None if win_ref is None else win_ref[0]
+    first, last = _live_pages(kv_len, window, page_size, max_pages)
 
-    @pl.when(i == 0)
+    @pl.when(i == first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = kv_lens_ref[b]
-    n_valid = jnp.clip(kv_len - i * page_size, 0, page_size)
-    # sliding window: the decode query sits at position kv_len-1, so only
-    # positions >= lo = kv_len - window are visible. Pages wholly below lo
-    # contribute nothing (their DMA is already elided by the index_map's
-    # low clamp); partially-covered pages mask their leading slots.
-    lo = jnp.int32(0)
-    if win_ref is not None:
-        w = win_ref[0]
-        lo = jnp.where(w > 0, jnp.maximum(kv_len - w, 0), 0)
-    lo_in_page = jnp.clip(lo - i * page_size, 0, page_size)
+    # every page the walk visits is live; its first and last may be
+    # partly so: slots past kv_len, and slots below the window's lo
+    n_valid = jnp.minimum(kv_len - i * page_size, page_size)
+    lo_in_page = jnp.clip(
+        _window_lo(kv_len, window) - i * page_size, 0, page_size)
+    page = _page_by_rows if by_rows else _page_by_heads
+    page(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+         n_valid, lo_in_page, scale=scale, softcap=softcap)
 
-    @pl.when((n_valid > 0) & (lo_in_page < n_valid))
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)  # [Hk, G, D]
-        k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
-        # s[h, g, p] = q[h, g, :] · k[p, h, :] (batch dim Hk sits at k
-        # axis 1 — dot_general takes batch dims at any position)
-        s = lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        ) * scale  # [Hk, G, PS]
-        if ks_ref is not None:
-            # int8 KV: fold the per-(token, head) K scale into the scores
-            # instead of dequantizing K over D (one [Hk, 1, PS] multiply
-            # replaces a [PS, Hk, D] one); the (PS, Hk) block transposes
-            # in-register — 2 KiB, negligible next to the page DMA
-            s = s * ks_ref[...].T[:, None, :]
-        if softcap:
-            # applied to the TRUE score (after any int8 scale fold),
-            # matching paged_attention_jnp's order
-            s = softcap * jnp.tanh(s / softcap)
-        pos = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        valid = (pos < n_valid) & (pos >= lo_in_page)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]  # [Hk, G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [Hk, G, PS]
-        alpha = jnp.exp(m_prev - m_new)
-
-        l_add = jnp.sum(p, axis=2, keepdims=True)  # BEFORE any V scaling:
-        # the softmax denominator sums raw probabilities
-        if vs_ref is not None:
-            # fold the V scale into p before the PV matmul (same trick)
-            p = p * vs_ref[...].T[:, None, :]
-        v = v_ref[...].astype(jnp.float32)  # [PS, Hk, D]
-        pv = lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        )  # [Hk, G, D]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        l_ref[...] = l_ref[...] * alpha + l_add
-        m_ref[...] = m_new
-
-    @pl.when(i == n_pages - 1)
+    @pl.when(i == last)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _decode_kernel(pt, kl, ly, q, k, v, o, m, l, acc, *, page_size, scale,
-                   softcap=0.0):
-    _decode_kernel_body(
-        pt, kl, None, q, k, v, None, None, o, m, l, acc,
-        page_size=page_size, scale=scale, softcap=softcap,
-    )
+def _page_by_heads(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
+                   acc_ref, n_valid, lo_in_page, *, scale, softcap):
+    """One page into the running softmax, a batched MXU product a kv head:
+    [G, D] x [D, PS] and [G, PS] x [PS, D]. Right where G query heads share
+    each page of K (GQA) and for any Hk."""
+    q = q_ref[...].astype(jnp.float32)  # [Hk, G, D]
+    k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
+    # s[h, g, p] = q[h, g, :] · k[p, h, :] (batch dim Hk sits at k
+    # axis 1 — dot_general takes batch dims at any position)
+    s = lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
+    ) * scale  # [Hk, G, PS]
+    if ks_ref is not None:
+        # int8 KV: fold the per-(token, head) K scale into the scores
+        # instead of dequantizing K over D (one [Hk, 1, PS] multiply
+        # replaces a [PS, Hk, D] one); the (PS, Hk) block transposes
+        # in-register — 2 KiB, negligible next to the page DMA
+        s = s * ks_ref[...].T[:, None, :]
+    if softcap:
+        # applied to the TRUE score (after any int8 scale fold),
+        # matching paged_attention_jnp's order
+        s = softcap * jnp.tanh(s / softcap)
+    pos = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    valid = (pos < n_valid) & (pos >= lo_in_page)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_ref[...]  # [Hk, G, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [Hk, G, PS]
+    alpha = jnp.exp(m_prev - m_new)
+
+    l_add = jnp.sum(p, axis=2, keepdims=True)  # BEFORE any V scaling:
+    # the softmax denominator sums raw probabilities
+    if vs_ref is not None:
+        # fold the V scale into p before the PV matmul (same trick)
+        p = p * vs_ref[...].T[:, None, :]
+    v = v_ref[...].astype(jnp.float32)  # [PS, Hk, D]
+    pv = lax.dot_general(
+        p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
+    )  # [Hk, G, D]
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    l_ref[...] = l_ref[...] * alpha + l_add
+    m_ref[...] = m_new
 
 
-def _decode_kernel_win(pt, kl, ly, win, q, k, v, o, m, l, acc, *,
-                       page_size, scale, softcap=0.0):
-    _decode_kernel_body(
-        pt, kl, win, q, k, v, None, None, o, m, l, acc,
-        page_size=page_size, scale=scale, softcap=softcap,
-    )
+def _page_by_rows(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
+                  acc_ref, n_valid, lo_in_page, *, scale, softcap):
+    """One page into the running softmax at G = 1 (MHA), where a batched
+    product would load the MXU a head for ONE row of output and move the
+    page head-major first. Here the page stays as it lies: [PS, Hk, D]
+    read as the matrix [PS * Hk, D] (free: Hk fills whole sublane tiles),
+    all Hk query rows against all of it in ONE product, s[r, (p, h)] =
+    q[r] · k[p, h], of which the entries with h == r are the scores and
+    the rest are masked like dead slots. Softmax then runs on [Hk, PS * Hk]
+    with every lane in use and state [Hk, 1]; the masked probabilities ARE
+    the block-diagonal left operand of the PV product [Hk, PS * Hk] x
+    [PS * Hk, D]. K and V go to the MXU in the pool's dtype, never cast:
+    bf16 x bf16 products are exact in the f32 accumulator, and the f32
+    probabilities go as three bf16 terms (8 + 8 + 8 mantissa bits, exact),
+    stacked on rows so V is loaded once. The MXU does Hk times the useful
+    products; it has them to spare, the VPU and the relayouts did not."""
+    del ks_ref, vs_ref  # dense pools only (decode_paged_attention)
+    PS, Hk, D = k_ref.shape
+    N = PS * Hk
+    q = q_ref[...]  # [Hk, D]
+    k = k_ref[...].reshape(N, D)
+    s = lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # [Hk, N]
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)  # p * Hk + h
+    row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    valid = ((_rem(col, Hk) == row) & (col < n_valid * Hk)
+             & (col >= lo_in_page * Hk))
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_ref[...]  # [Hk, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [Hk, N]
+    alpha = jnp.exp(m_prev - m_new)
+    l_add = jnp.sum(p, axis=1, keepdims=True)
+
+    v = v_ref[...].reshape(N, D)
+    if v.dtype == jnp.float32:
+        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+    else:
+        terms = []
+        for _ in range(3):
+            terms.append(p.astype(v.dtype))
+            p = p - terms[-1].astype(jnp.float32)
+        pv = jnp.dot(jnp.concatenate(terms, axis=0), v,
+                     preferred_element_type=jnp.float32)  # [3 * Hk, D]
+        pv = pv[:Hk] + pv[Hk:2 * Hk] + pv[2 * Hk:]
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    l_ref[...] = l_ref[...] * alpha + l_add
+    m_ref[...] = m_new
 
 
-def _decode_kernel_int8(pt, kl, ly, q, k, ks, v, vs, o, m, l, acc, *,
-                        page_size, scale, softcap=0.0):
-    _decode_kernel_body(
-        pt, kl, None, q, k, v, ks, vs, o, m, l, acc,
-        page_size=page_size, scale=scale, softcap=softcap,
-    )
+def _decode_kernel(wk, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
+    _decode_kernel_body(wk, pt, kl, None, q, k, v, None, None, o, m, l, acc,
+                        **kw)
 
 
-def _decode_kernel_int8_win(pt, kl, ly, win, q, k, ks, v, vs, o, m, l, acc,
-                            *, page_size, scale, softcap=0.0):
-    _decode_kernel_body(
-        pt, kl, win, q, k, v, ks, vs, o, m, l, acc,
-        page_size=page_size, scale=scale, softcap=softcap,
-    )
+def _decode_kernel_win(wk, pt, kl, ly, win, q, k, v, o, m, l, acc, **kw):
+    _decode_kernel_body(wk, pt, kl, win, q, k, v, None, None, o, m, l, acc,
+                        **kw)
+
+
+def _decode_kernel_int8(wk, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc, **kw):
+    _decode_kernel_body(wk, pt, kl, None, q, k, v, ks, vs, o, m, l, acc,
+                        **kw)
+
+
+def _decode_kernel_int8_win(wk, pt, kl, ly, win, q, k, ks, v, vs, o, m, l,
+                            acc, **kw):
+    _decode_kernel_body(wk, pt, kl, win, q, k, v, ks, vs, o, m, l, acc,
+                        **kw)
 
 
 def decode_paged_attention_sharded(
@@ -218,6 +345,7 @@ def decode_paged_attention_sharded(
     axis_name: str = AXIS_MODEL,
     window=None,  # traced int32 scalar (see decode_paged_attention)
     layer=None,  # traced int32 scalar, replicated
+    work=None,  # decode_work_list's pair, replicated (see below)
     *,
     scale=None,
     softcap: float = 0.0,
@@ -235,22 +363,27 @@ def decode_paged_attention_sharded(
         pool = {"q": pool, "s": scales}
     k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
     scalars = scalar_operands(layer, window)
+    if work is None:  # the same on every shard: built once, outside
+        PS = jax.tree.leaves(k_pool)[0].shape[2]
+        work = decode_work_list(kv_lens, window, PS, page_table.shape[1])
 
-    def part(q, k_pool, v_pool, page_table, kv_lens, layer, window=None):
+    def part(q, k_pool, v_pool, page_table, kv_lens, work, n_work, layer,
+             window=None):
         return decode_paged_attention(
             q, k_pool, v_pool, page_table, kv_lens, window, layer,
-            scale=scale, softcap=softcap, interpret=interpret,
+            (work, n_work), scale=scale, softcap=softcap,
+            interpret=interpret,
         )
 
     fn = jax.shard_map(
         part,
         mesh=mesh,
-        in_specs=(heads, pool, pool, P(None, None), P(None))
+        in_specs=(heads, pool, pool, P(None, None), P(None), P(None), P())
         + (P(),) * len(scalars),
         out_specs=heads,
         check_vma=False,
     )
-    return fn(q, k_pool, v_pool, page_table, kv_lens, *scalars)
+    return fn(q, k_pool, v_pool, page_table, kv_lens, *work, *scalars)
 
 
 @functools.partial(
@@ -267,6 +400,9 @@ def decode_paged_attention(
     #   (0 = global at runtime) — Gemma-2 alternates per layer in the scan
     layer=None,  # traced int32 scalar: the layer of the stacked pool to
     #   read; rides the scan as a prefetch operand like `window`
+    work=None,  # decode_work_list(kv_lens, window, PS, MP), for a caller
+    #   that runs many layers on one set of lengths and builds it once;
+    #   None = built here
     *,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
     softcap: float = 0.0,  # Gemma-2 logit soft capping (static; 0 = off)
@@ -283,36 +419,42 @@ def decode_paged_attention(
     if scale is None:
         scale = D**-0.5
     windowed = window is not None
+    if windowed:
+        window = jnp.asarray(window, jnp.int32).reshape(())
+    work, n_work = work or decode_work_list(kv_lens, window, PS, MP)
+    # the per-page routine, from what the shapes say: at G = 1 each kv
+    # head serves one query row, and when Hk fills whole sublane tiles of
+    # the pool's dtype the page reads as a matrix for free (_page_by_rows)
+    by_rows = (G == 1 and not quantized
+               and Hk % (32 // kq.dtype.itemsize) == 0)
 
-    def _clamp(b, i, pt, kl, ly, *rest):
-        # clamp past-the-end pages to the last valid page: the block index
-        # then repeats across those grid steps and Pallas skips the DMA,
-        # so a 128-token context in an 8192-token table costs 2 page
-        # copies, not 128. With a sliding window, pages wholly below the
-        # window likewise clamp UP to the first live page.
-        last = jnp.maximum(kl[b] - 1, 0) // PS
-        i_eff = jnp.minimum(i, last)
-        if rest:
-            (win,) = rest
-            w = win[0]
-            lo = jnp.where(w > 0, jnp.maximum(kl[b] - w, 0), 0)
-            i_eff = jnp.maximum(i_eff, jnp.minimum(lo // PS, last))
-        return i_eff
+    def row_of(w, wk):
+        return _div(wk[w], MP)
 
-    def kv_index(b, i, pt, kl, ly, *rest):
-        return (ly[0], pt[b, _clamp(b, i, pt, kl, ly, *rest)], 0, 0, 0)
+    def kv_index(w, wk, pt, kl, ly, *rest):
+        return (ly[0], pt[row_of(w, wk), _rem(wk[w], MP)], 0, 0, 0)
 
-    def scale_index(b, i, pt, kl, ly, *rest):
-        return kv_index(b, i, pt, kl, ly, *rest)[1:4]
+    def scale_index(w, wk, pt, kl, ly, *rest):
+        return kv_index(w, wk, pt, kl, ly, *rest)[1:4]
 
-    def fixed_index(b, i, pt, kl, ly, *rest):
-        return (b, 0, 0, 0)
+    if by_rows:
+        q = q.reshape(B, Hk, D)
+        qo_block, state = (None, Hk, D), (Hk, 1)
 
-    q_spec = pl.BlockSpec((None, Hk, G, D), fixed_index)
+        def qo_index(w, wk, *rest):
+            return (row_of(w, wk), 0, 0)
+    else:
+        qo_block, state = (None, Hk, G, D), (Hk, G, 1)
+
+        def qo_index(w, wk, *rest):
+            return (row_of(w, wk), 0, 0, 0)
+
+    q_spec = pl.BlockSpec(qo_block, qo_index)
     # one token-major page of one layer = one contiguous PS*Hk*D slab: a
     # single DMA, with a legal (PS, Hk, D) tile (minor dims (Hk, D))
     kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
-    kw = dict(page_size=PS, scale=scale, softcap=softcap)
+    kw = dict(page_size=PS, max_pages=MP, scale=scale, softcap=softcap,
+              by_rows=by_rows)
     if quantized:
         kernel = functools.partial(
             _decode_kernel_int8_win if windowed else _decode_kernel_int8, **kw
@@ -328,24 +470,27 @@ def decode_paged_attention(
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, kq, vq)
 
-    prefetch = (page_table, kv_lens) + scalar_operands(layer, window)
+    prefetch = (work, page_table, kv_lens) + scalar_operands(layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # page_table, kv_lens, layer
-        #   (+ window)
-        grid=(B, MP),
+        num_scalar_prefetch=len(prefetch),  # work, page_table, kv_lens,
+        #   layer (+ window)
+        grid=(n_work,),  # a traced bound: the live pages, not B * MP
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, Hk, G, D), fixed_index),
+        out_specs=pl.BlockSpec(qo_block, qo_index),
         scratch_shapes=[
-            pltpu.VMEM((Hk, G, 1), jnp.float32),
-            pltpu.VMEM((Hk, G, 1), jnp.float32),
-            pltpu.VMEM((Hk, G, D), jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(q.shape[1:], jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(*prefetch, *operands)
-    return out
+    # a row with no live page (a pad row) is never visited, so its output
+    # block is never written: define it, as 0
+    return jnp.where((kv_lens > 0)[:, None, None, None],
+                     out.reshape(B, Hk, G, D), 0)

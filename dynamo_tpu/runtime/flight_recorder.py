@@ -73,7 +73,16 @@ class IterationRecord:
     that picked the layer's fullest expert, the same mean (k / n_experts
     is an even load, 1.0 is every token on one expert: a straggler).
     A forward is one pass of the layers: a decode step, a prefill chunk
-    set, a ragged step."""
+    set, a ragged step.
+
+    `decode_pages_live` [pages] is the decode kernel's unit of work: what
+    one layer's calls walked this iteration, the sum over decode rows and
+    fused steps of the pages a row's context holds (from the first page
+    its sliding window shows to the page of its newest token; a model
+    whose layers alternate sliding and global counts the mean over
+    layers). From the positions the engine holds on the host: no device
+    work. Beside it `decode_seqs x decode_steps` gives rows, and the
+    kernel's device seconds over it the cost of a live page."""
 
     seq: int               # engine iteration number (monotonic)
     ts: float              # wall clock (time.time()) at iteration start
@@ -96,6 +105,7 @@ class IterationRecord:
     compile_variants: int  # cumulative compiled jit variants (all families)
     compile_calls: int     # cumulative jitted calls (calls - variants
     #   growth = compile-cache hits)
+    decode_pages_live: int = 0  # live KV pages walked (see the docstring)
     anomaly: bool = False  # this iteration fired the EWMA trigger
     # speculative decoding: mean tokens emitted per speculating row this
     # iteration (accepted drafts + the verified/bonus token; 0.0 when no

@@ -4,8 +4,11 @@ compiled Mosaic lowering is a different code path and must be revalidated
 whenever a chip is available). chip_smoke.py runs it as its first phase.
 
 GQA checks — the cross product, every one REQUIRED to pass compiled:
-  kernels     decode (ops/paged_attention) · prefill (ops/flash_prefill) ·
-              ragged (ops/ragged_paged_attention, the default mixed path)
+  kernels     decode (ops/paged_attention; a batch of drawn lengths, and a
+              ragged batch: a pad row, 1 token, whole pages, one token
+              past them, the whole page table) · prefill
+              (ops/flash_prefill) · ragged (ops/ragged_paged_attention,
+              the default mixed path)
   variants    bf16 · window+softcap · int8-KV · int8-KV+window+softcap
   geometries  llama-3.2-3b  Hk 8,  G 3, D 128, PS 64, page table 64 wide
               phi-3-mini-4k Hk 32, G 1, D 96,  PS 64, window 2047 with
@@ -27,7 +30,9 @@ Every check runs even after a failure; an exception (a compiler refusal)
 is a failure carrying the compiler's message. The last stdout line is one
 JSON object {"ok", "device", "checks": [...]}. Exit 0 = all within
 tolerance; 1 = a mismatch or error; 2 = no accelerator (never a skip).
-`--interpret` runs the same checks through the Pallas interpreter on any
+`--only TEXT` runs the checks whose name holds TEXT (after a change to one
+kernel; the gate proper is the whole list). `--interpret` runs the same
+checks through the Pallas interpreter on any
 backend: a rehearsal of this script's own logic, labelled as such, that
 says nothing about Mosaic.
 """
@@ -130,12 +135,11 @@ def _variant(geom, variant):
             SOFTCAP if windowed else 0.0)
 
 
-def check_decode(geom, variant) -> float:
+def _decode_err(geom, variant, rng, kv) -> float:
+    """Decode kernel against the f32 reference on rows of `kv` tokens; a
+    pad row (kv_len 0) reads no page and must come back finite."""
     quantized, window, softcap = _variant(geom, variant)
-    rng = np.random.default_rng(0)
-    B, Hk, G, D = 8, geom["Hk"], geom["G"], geom["D"]
-    kv = rng.integers(1, geom["ctx"], B).astype(np.int32)
-    kv[0], kv[1] = geom["ctx"], 1  # longest (past the window) and shortest
+    B, Hk, G, D = len(kv), geom["Hk"], geom["G"], geom["D"]
     pool = _Pool(rng, geom, int(np.sum(-(-kv // geom["PS"]))) + 4, quantized)
     pt = pool.table(kv)
     q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
@@ -144,9 +148,30 @@ def check_decode(geom, variant) -> float:
         None if window is None else jnp.int32(window), jnp.int32(LAYER),
         softcap=softcap, interpret=INTERPRET,
     )
-    ref = _ref(q.astype(jnp.float32)[:, None], pool, pt, (kv - 1)[:, None],
-               kv, window, softcap)[:, 0]
-    return _max_err(out, ref)
+    ref = _ref(q.astype(jnp.float32)[:, None], pool, pt,
+               np.maximum(kv - 1, 0)[:, None], kv, window, softcap)[:, 0]
+    out = np.asarray(out, np.float32)
+    if not np.isfinite(out[kv == 0]).all():
+        return float("inf")
+    return _max_err(out[kv > 0], np.asarray(ref)[kv > 0])
+
+
+def check_decode(geom, variant) -> float:
+    rng = np.random.default_rng(0)
+    kv = rng.integers(1, geom["ctx"], 8).astype(np.int32)
+    kv[0], kv[1] = geom["ctx"], 1  # longest (past the window) and shortest
+    return _decode_err(geom, variant, rng, kv)
+
+
+def check_decode_ragged(geom, variant) -> float:
+    """The page walk's edges in one batch: a pad row, 1 token, whole
+    pages, one token into a page, the whole page table, and (windowed
+    variants) a window that cuts leading pages and one that starts
+    mid-page."""
+    PS, MP = geom["PS"], geom["MP"]
+    kv = np.asarray([0, 1, PS * 3, PS * 3 + 1, PS * MP, geom["ctx"] + 17,
+                     PS, 0], np.int32)
+    return _decode_err(geom, variant, np.random.default_rng(7), kv)
 
 
 def check_prefill(geom, variant) -> float:
@@ -359,7 +384,9 @@ def all_checks():
     """(name, thunk) for every check, GQA cross product first."""
     checks = []
     for gname, geom in GEOMETRIES.items():
-        for kname, fn in (("decode", check_decode), ("prefill", check_prefill),
+        for kname, fn in (("decode", check_decode),
+                          ("decode ragged-batch", check_decode_ragged),
+                          ("prefill", check_prefill),
                           ("ragged", check_ragged)):
             for variant in VARIANTS:
                 checks.append((f"{kname} {variant} @{gname}",
@@ -394,8 +421,9 @@ def main(argv=None) -> int:
         print("FAIL: no accelerator backend (this gate checks compiled "
               "Mosaic; a CPU run proves nothing)", flush=True)
         return 2
+    only = argv[argv.index("--only") + 1] if "--only" in argv else ""
     results = []
-    for name, fn in all_checks():
+    for name, fn in [c for c in all_checks() if only in c[0]]:
         t0 = time.monotonic()
         row = {"name": name, "tol": TOL}
         try:

@@ -138,6 +138,45 @@ async def test_record_fields_vs_sim_mixed_plan():
             assert r.decode_seqs == 0 and r.n_chunks == 1
 
 
+@pytest.mark.parametrize("window,n_global", [(0, 0), (6, 0), (6, 1)])
+async def test_record_counts_live_decode_pages(window, n_global):
+    """decode_pages_live: over an iteration's decode rows and fused steps,
+    the pages a row's context holds, from the first its sliding window
+    shows (mean over layers where sliding and global alternate); 0 on a
+    record with no decode half. One request alone, so every decode
+    iteration's count follows from the tokens it emitted."""
+    engine = _mk_engine()
+    engine.runner.config = types.SimpleNamespace(
+        sliding_window=window, n_layers=2, sw_period=2 if n_global else 1,
+        sw_global_residue=1)
+    prompt, n_out, ps = list(range(300, 311)), 9, 4
+    engine.start()
+    try:
+        toks, _ = await _gen(engine, prompt, n_out)
+    finally:
+        engine.stop()
+    assert len(toks) == n_out
+
+    def pages(n, w):
+        return (n - 1) // ps - (max(n - w, 0) // ps if w else 0) + 1
+
+    recs = engine.recorder.snapshot()
+    n, seen = len(prompt), 0  # context before the first decode step
+    for r in recs:
+        if not r.decode_seqs:
+            assert r.decode_pages_live == 0
+            continue
+        lens = range(n + 1, n + r.decode_steps + 1)
+        sliding = sum(pages(m, window) for m in lens)
+        full = sum(pages(m, 0) for m in lens)
+        want = round((full * n_global + sliding * (2 - n_global)) / 2)
+        assert r.decode_pages_live == want, (r, n)
+        assert r.decode_pages_live <= full
+        n += r.decode_steps
+        seen += 1
+    assert seen >= 2
+
+
 # -- latency spine ----------------------------------------------------------
 
 

@@ -1,0 +1,94 @@
+"""Mosaic compiles of the decode attention kernel at real widths, for a
+described (not attached) TPU v5e: what interpret mode cannot show — a
+slice off the tiling, a scratch too large for VMEM, scalar-prefetch
+operands too large for SMEM, a kernel that cannot be partitioned. Nothing
+runs, so nothing here says anything about results or times.
+
+One file on purpose: the process that describes the topology loads the
+TPU library and holds it, so these live where one xdist worker gets them
+all. The topology is described in a fixture, never at import.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.ops.paged_attention import (
+    decode_paged_attention,
+    decode_paged_attention_sharded,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+# name -> B, Hk, G, D, L, NP, PS, MP, windowed, int8 KV
+_DECODE_SHAPES = {
+    # the benchmark cell: MHA at D 96 (the by-rows routine), window live
+    "phi3-b4": (4, 32, 1, 96, 32, 176, 64, 64, True, False),
+    "phi3-b32": (32, 32, 1, 96, 32, 176, 64, 64, True, False),
+    "mistral-b32": (32, 8, 4, 128, 32, 1408, 64, 64, False, False),
+    # the worker's default shape: the work list (64 x 256 entries) beside
+    # a 64 KB page table in SMEM
+    "llama3.2-default-b64": (64, 8, 3, 128, 28, 2048, 16, 256, False, False),
+    "gemma2-ps16": (8, 4, 2, 256, 26, 512, 16, 256, True, False),
+    "mha-d128-ps16": (8, 32, 1, 128, 4, 256, 16, 128, False, False),
+    "llama3.2-int8": (8, 8, 3, 128, 28, 256, 64, 64, True, True),
+    "phi3-int8": (8, 32, 1, 96, 4, 176, 64, 64, True, True),
+}
+
+
+def _operands(shape, spec_of):
+    B, Hk, G, D, L, NP, PS, MP, windowed, int8 = shape
+
+    def s(dims, dtype, kind):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=spec_of(kind))
+
+    pool = s((L, NP, PS, Hk, D), jnp.int8 if int8 else jnp.bfloat16, "pool")
+    if int8:
+        pool = {"q": pool, "s": s((L, NP, PS, Hk), jnp.float32, "scales")}
+    scalar = s((), jnp.int32, "rep")
+    return (s((B, Hk, G, D), jnp.bfloat16, "heads"), pool, pool,
+            s((B, MP), jnp.int32, "rep"), s((B,), jnp.int32, "rep"),
+            scalar if windowed else None, scalar)
+
+
+@pytest.mark.parametrize("name", list(_DECODE_SHAPES))
+def test_decode_kernel_compiles_for_v5e(topo, name):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = _operands(_DECODE_SHAPES[name], lambda kind: one_chip)
+    text = jax.jit(decode_paged_attention).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("name", ["phi3-b4", "mistral-b32", "llama3.2-int8"])
+def test_sharded_decode_kernel_compiles_for_v5e_2x2(topo, name):
+    """Heads over four chips: each shard walks the same list on its own
+    heads, and no collective appears."""
+    from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), (AXIS_MODEL,))
+    heads, pool, scales = attention_specs(AXIS_MODEL)
+    specs = {"heads": heads, "pool": pool, "scales": scales, "rep": P()}
+    q, k, v, pt, kl, window, layer = _operands(
+        _DECODE_SHAPES[name], lambda kind: NamedSharding(mesh, specs[kind]))
+
+    def fn(q, k, v, pt, kl, window, layer):
+        return decode_paged_attention_sharded(
+            q, k, v, pt, kl, mesh, window=window, layer=layer)
+
+    text = jax.jit(fn).lower(q, k, v, pt, kl, window, layer).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-reduce(" not in text and "all-gather(" not in text

@@ -216,6 +216,195 @@ def test_decode_paged_attention_sharded_int8_kv():
     assert d < 3e-2, d
 
 
+# -- the decode kernel's page walk (its grid is a work list of live pages) ---
+# name -> (Hk, G, D, PS, MP): G 1 with Hk filling bf16's 16-row tiles is
+# the by-rows routine (phi-3's MHA at D 96), the rest the batched one
+_WALK_GEOMS = {
+    "mha-d96": (16, 1, 96, 16, 6),
+    "gqa-g3": (2, 3, 128, 8, 6),
+    "gqa-g4": (2, 4, 128, 8, 6),
+    "gemma-ps16": (4, 2, 128, 16, 6),
+}
+# name -> (window or None, softcap, int8 KV)
+_WALK_VARIANTS = {
+    "plain": (None, 0.0, False),
+    "window-cuts-pages": ("pages", 0.0, False),
+    "window-mid-page": ("mid", 0.0, False),
+    "int8": (None, 0.0, True),
+    "softcap": (None, 30.0, False),
+    "int8-window-softcap": ("mid", 30.0, True),
+}
+
+
+def _walk_lens(PS, MP):
+    """One batch with every edge of the walk: a pad row, one token, whole
+    pages, one token into the next page, the whole page table, a pad row
+    between live ones."""
+    return np.asarray([0, 1, PS * 2, PS * 2 + 1, 0, PS * MP], np.int32)
+
+
+def _walk_case(geom, variant, seed=21, layers=None):
+    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
+    win, softcap, quant = _WALK_VARIANTS[variant]
+    # "pages": lo lands on a page boundary for the longest row (leading
+    # pages dead, the first live page whole); "mid": inside a page
+    window = {None: None, "pages": PS * 2, "mid": PS * 2 + 3}[win]
+    rng = np.random.default_rng(seed)
+    kv = _walk_lens(PS, MP)
+    B = len(kv)
+    NP = B * MP + 2
+    shape = (NP, PS, Hk, D) if layers is None else (layers, NP, PS, Hk, D)
+    q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    pt = rng.permutation(NP - 2)[: B * MP].reshape(B, MP).astype(np.int32)
+    if quant:
+        kp, vp = _q_pools(kp, vp)
+    return q, kp, vp, pt, kv, window, softcap
+
+
+def _walk_ref(q, kp, vp, pt, kv, window, softcap):
+    return paged_attention_jnp(
+        q[:, None], kp, vp, jnp.asarray(pt),
+        jnp.asarray(np.maximum(kv - 1, 0))[:, None], jnp.asarray(kv),
+        softcap=softcap, window=None if window is None else jnp.int32(window),
+    )[:, 0]
+
+
+def _walk_close(out, ref, kv):
+    out = np.asarray(out, np.float32)
+    assert np.all(out[kv == 0] == 0.0)  # a pad row: defined, and zero
+    d = np.abs(out - np.asarray(ref, np.float32))[kv > 0].max()
+    assert d < 3e-2, d
+
+
+@pytest.mark.parametrize("variant", list(_WALK_VARIANTS))
+@pytest.mark.parametrize("geom", list(_WALK_GEOMS))
+def test_decode_walk_matches_reference(geom, variant):
+    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
+    out = decode_paged_attention(
+        q, kp, vp, jnp.asarray(pt), jnp.asarray(kv),
+        None if window is None else jnp.int32(window),
+        softcap=softcap, interpret=True,
+    )
+    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, softcap), kv)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+def test_decode_walk_reads_stacked_pool_layer(geom, layer):
+    q, kp, vp, pt, kv, window, softcap = _walk_case(
+        geom, "window-mid-page", layers=3)
+    out = decode_paged_attention(
+        q, kp, vp, jnp.asarray(pt), jnp.asarray(kv), jnp.int32(window),
+        jnp.int32(layer), interpret=True,
+    )
+    _walk_close(out, _walk_ref(q, kp[layer], vp[layer], pt, kv, window,
+                               softcap), kv)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
+@pytest.mark.parametrize("geom", ["gqa-g4", "gemma-ps16"])
+def test_decode_walk_sharded(geom):
+    from dynamo_tpu.ops.paged_attention import decode_paged_attention_sharded
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, "window-mid-page")
+    out = decode_paged_attention_sharded(
+        q, kp, vp, jnp.asarray(pt), jnp.asarray(kv),
+        make_mesh(MeshConfig(model=2)), window=jnp.int32(window),
+        interpret=True,
+    )
+    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, softcap), kv)
+
+
+@pytest.mark.parametrize("variant", ["plain", "window-mid-page"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+def test_decode_walk_reads_only_live_pages(geom, variant):
+    """Every pool page outside the rows' live ranges holds NaN, and every
+    page-table entry outside a row's live range names an unowned (NaN)
+    page: the result does not change, so no such page was read."""
+    from dynamo_tpu.ops.paged_attention import decode_work_list
+
+    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
+    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
+    win = None if window is None else jnp.int32(window)
+    clean = decode_paged_attention(q, kp, vp, jnp.asarray(pt),
+                                   jnp.asarray(kv), win, interpret=True)
+    work, n_work = decode_work_list(jnp.asarray(kv), win, PS, MP)
+    live = np.zeros(pt.shape, bool)
+    live.reshape(-1)[np.asarray(work)[: int(n_work)]] = True
+    NP = kp.shape[0]
+    dead_pages = np.setdiff1d(np.arange(NP), pt[live])
+    assert NP - 1 in dead_pages  # nobody's: what dead entries point at
+    poison = jnp.asarray(dead_pages)
+    kp_n = kp.at[poison].set(jnp.nan)
+    vp_n = vp.at[poison].set(jnp.nan)
+    pt_n = np.where(live, pt, NP - 1).astype(np.int32)
+    out = decode_paged_attention(q, kp_n, vp_n, jnp.asarray(pt_n),
+                                 jnp.asarray(kv), win, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(clean, np.float32))
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_decode_walk_steps_follow_live_pages_not_table_width(window):
+    """The grid is a traced bound, the count of live pages: the same for
+    the same rows under a page table 8 and 64 wide."""
+    from dynamo_tpu.ops.paged_attention import decode_work_list
+
+    PS = 16
+    kv = np.asarray([0, 1, 16, 17, 100, 128, 0, 33], np.int32)
+    win = None if window is None else jnp.int32(window)
+    lo = np.maximum(kv - window, 0) if window else np.zeros_like(kv)
+    expect = int(np.sum(np.where(kv > 0, (kv - 1) // PS - lo // PS + 1, 0)))
+    steps = {}
+    for MP in (8, 64):
+        work, n_work = decode_work_list(jnp.asarray(kv), win, PS, MP)
+        work = np.asarray(work)[: int(n_work)]
+        steps[MP] = [(int(e) // MP, int(e) % MP) for e in work]
+    assert len(steps[8]) == len(steps[64]) == expect
+    assert steps[8] == steps[64]  # the same (row, page) walk
+    assert steps[8] == sorted(steps[8])  # rows in order, pages ascending
+
+    def grid_of(MP):
+        q = jnp.zeros((len(kv), 2, 2, 64), jnp.bfloat16)
+        pool = jnp.zeros((4, PS, 2, 64), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            decode_paged_attention, interpret=True))(
+            q, pool, pool, jnp.zeros((len(kv), MP), jnp.int32),
+            jnp.asarray(kv), win)
+        eqns = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                if e.primitive.name == "pallas_call"]
+        (gm,) = [e.params["grid_mapping"] for e in eqns]
+        return gm.grid, gm.num_dynamic_grid_bounds
+
+    # one dynamic grid dimension, and nothing static of the table's width
+    assert grid_of(8) == grid_of(64)
+    assert grid_of(8)[1] == 1 and len(grid_of(8)[0]) == 1
+
+
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+def test_decode_walk_stays_inside_the_page_table(geom):
+    """A length past MP * PS (nothing the engine sends) walks the table's
+    MP pages like a full row: the list never outgrows its W entries and
+    the row is still finalized."""
+    from dynamo_tpu.ops.paged_attention import decode_work_list
+
+    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
+    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, "plain")
+    over = np.where(kv == PS * MP, PS * MP + 5, kv).astype(np.int32)
+    _, n_full = decode_work_list(jnp.asarray(kv), None, PS, MP)
+    _, n_over = decode_work_list(jnp.asarray(over), None, PS, MP)
+    assert int(n_over) == int(n_full) <= len(kv) * MP
+    out = decode_paged_attention(q, kp, vp, jnp.asarray(pt),
+                                 jnp.asarray(over), interpret=True)
+    full = decode_paged_attention(q, kp, vp, jnp.asarray(pt),
+                                  jnp.asarray(kv), interpret=True)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(full, np.float32))
+
+
 # -- MLA decode kernel -------------------------------------------------------
 
 
