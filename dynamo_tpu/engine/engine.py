@@ -23,12 +23,9 @@ from typing import Any, AsyncIterator, Dict, List, Optional
 
 import numpy as np
 
-from typing import TYPE_CHECKING
-
 from dynamo_tpu.engine.kv_pool import KvEvent, NoSpace, PagePool
 
-if TYPE_CHECKING:  # jax stays un-imported in mocker processes
-    from dynamo_tpu.engine.model_runner import ModelRunner
+from dynamo_tpu.engine.runner_api import BucketOverflowError, Runner
 from dynamo_tpu.engine.scheduler import (
     DecodePlan,
     MixedPlan,
@@ -137,7 +134,7 @@ class GuidedMaskContext:
 class InferenceEngine:
     def __init__(
         self,
-        runner: "ModelRunner",
+        runner: Runner,
         *,
         max_batch: int = 64,
         chunk_size: int = 512,
@@ -222,21 +219,15 @@ class InferenceEngine:
         elif _fuse_env in ("0", "false", "off", "no"):
             self.fused_mixed = False
         else:
-            try:
-                platform = runner.mesh.devices.flat[0].platform
-            except AttributeError:  # SimRunner (no mesh, no fused method)
-                platform = "cpu"
-            self.fused_mixed = platform != "cpu"
+            self.fused_mixed = runner.platform != "cpu"
         # cross-worker KVBM onboarding: worker_common injects an async
         # callable(hint) -> payload that pulls blocks from a peer's
         # kv_host_fetch endpoint (None = feature off)
         self.remote_kv_fetch = None
         self.pool = PagePool(runner.num_pages, runner.page_size)
         # fork-on-branch CoW: the pool copies a forked tail page's device
-        # KV through the runner (None = runner can't copy; forks then
-        # share garbage tails, which only matters once a runner that
-        # writes real KV omits copy_pages — both real+sim define it)
-        self.pool.copy_hook = getattr(runner, "copy_pages", None)
+        # KV through the runner
+        self.pool.copy_hook = runner.copy_pages
         self.host_pool = None
         self._host_events: List[KvEvent] = []
         self.kv_tier_quantize = bool(kv_tier_quantize)
@@ -301,9 +292,7 @@ class InferenceEngine:
             max_batch=max_batch,
             chunk_size=chunk_size,
             max_seq_pages=runner.max_pages_per_seq,
-            max_seq_tokens=getattr(
-                getattr(runner, "config", None), "max_seq_len", 0
-            ) or 0,
+            max_seq_tokens=runner.max_seq_len,
             decode_steps=decode_steps,
             enable_prefix_cache=enable_prefix_cache,
             mixed_prefill_tokens=mixed_prefill_tokens,
@@ -312,14 +301,7 @@ class InferenceEngine:
             host_tier=self.host_pool,
             host_onboard=self._onboard_from_host if self.host_pool is not None else None,
             spec_max_tokens=spec_max_tokens,
-            # ragged runners sample at most seg_cap rows per dispatch;
-            # budgeting verify tokens to RAGGED_MAX_SEGS (= 96, minus one
-            # slot per decode row / chunk) keeps every verify dispatch
-            # inside the gather the compiled program already has — the
-            # no-new-compile-families invariant (docs/ragged_attention.md)
-            spec_seg_budget=(
-                96 if hasattr(runner, "ensure_ragged_bucket") else 0
-            ),
+            spec_seg_budget=runner.spec_seg_budget,
         )
         # n-gram speculative decoding (docs/spec_decode.md): drafts ride
         # the mixed dispatch as ragged verify rows, so both the runner
@@ -329,13 +311,13 @@ class InferenceEngine:
         self._spec_on = (
             self.spec_ngram
             and mixed_prefill_tokens > 0
-            and hasattr(runner, "verify_spec")
+            and runner.has_verify_spec
         )
         if self.spec_ngram and not self._spec_on:
             log.warning(
                 "spec_ngram requested but unavailable "
                 "(runner verify_spec=%s, mixed_prefill_tokens=%d); disabled",
-                hasattr(runner, "verify_spec"), mixed_prefill_tokens,
+                runner.has_verify_spec, mixed_prefill_tokens,
             )
         # tree speculation: extra candidate branches per sequence ride the
         # same verify dispatch as independent segments on forked page
@@ -345,9 +327,9 @@ class InferenceEngine:
         # the draft_step ring (ModelRunner jitted gather / SimRunner numpy
         # twin); the host scan remains as fallback and for A/Bs
         if spec_device_draft is None:
-            spec_device_draft = hasattr(runner, "draft_step")
+            spec_device_draft = runner.has_draft_ring
         self._spec_device_draft = (
-            bool(spec_device_draft) and hasattr(runner, "draft_step")
+            bool(spec_device_draft) and runner.has_draft_ring
         )
         self._draft_slots: Dict[str, int] = {}  # rid -> history-ring slot
         self._draft_free: List[int] = []
@@ -370,8 +352,7 @@ class InferenceEngine:
         # as a ragged T bucket makes the token budget BE the compile
         # bucket: a full mixed iteration compiles (and reuses) one ragged
         # variant instead of rounding up to the next power of two.
-        if hasattr(runner, "ensure_ragged_bucket"):
-            runner.ensure_ragged_bucket(mixed_prefill_tokens + max_batch)
+        runner.ensure_ragged_bucket(mixed_prefill_tokens + max_batch)
         # planner retune ceilings: the ragged bucket registered above and
         # the draft ring sized below are compile-time commitments — a
         # live retune (engine.retune) may move knobs DOWN and back up to
@@ -406,7 +387,7 @@ class InferenceEngine:
         # `routed_experts`, until _emit_item sends them; the last
         # iteration's record while its expert-load counters are still on
         # the device; and what /metrics shows of them (worker_common)
-        self._routed_ok = bool(getattr(runner, "routed", False))
+        self._routed_ok = bool(runner.routed)
         self._routed_out: Dict[str, tuple] = {}  # rid -> (item field,
         #   whether it holds one position per emitted token: a decode row)
         self._rec_late: Optional[tuple] = None  # (IterationRecord, MoeLoad)
@@ -445,8 +426,7 @@ class InferenceEngine:
             self._lifter_lock = san.wrap_lock(
                 self._lifter_lock, "engine.lifter"
             )
-            if hasattr(runner, "attach_sanitizer"):
-                runner.attach_sanitizer(san)
+            runner.attach_sanitizer(san)
         # called (from the step thread) on unrecoverable engine failure
         # (multi-host GroupBroken): the worker wires it to process exit
         self._fatal_cb = None
@@ -488,8 +468,7 @@ class InferenceEngine:
         for)."""
         sched = self.scheduler
         if mixed_prefill_tokens is not None:
-            cap = (self._mixed_tokens_init
-                   if hasattr(self.runner, "ensure_ragged_bucket")
+            cap = (self._mixed_tokens_init if self.runner.static_shapes
                    else max(self._mixed_tokens_init, mixed_prefill_tokens))
             sched.mixed_prefill_tokens = max(0, min(int(mixed_prefill_tokens),
                                                     cap))
@@ -577,12 +556,9 @@ class InferenceEngine:
                 from dynamo_tpu.frontend.tokenizer import load_tokenizer
                 from dynamo_tpu.guided.token_mask import TokenLifter
 
-                cfg = getattr(self.runner, "config", None)
-                vocab = (
-                    cfg.vocab_size if cfg is not None else self.runner.vocab_size
-                )
                 self._guided_lifter = TokenLifter.for_tokenizer(
-                    load_tokenizer(self.tokenizer_spec), vocab,
+                    load_tokenizer(self.tokenizer_spec),
+                    self.runner.vocab_size,
                 )
             return self._guided_lifter
 
@@ -759,9 +735,9 @@ class InferenceEngine:
         if seq.disagg is None:
             seq.n_branches = max(1, min(16, int(seq.sampling.get("n") or 1)))
         if seq.logit_bias and (
-            getattr(self.runner, "has_draft", False)
-            or getattr(self.runner, "pp", False)
-            or not getattr(self.runner, "supports_logit_bias", False)
+            self.runner.has_draft
+            or self.runner.pp
+            or not self.runner.supports_logit_bias
         ):
             # spec-decode verify can't honor a biased target distribution,
             # the PP loop has no bias operand, and sim runners have no
@@ -786,7 +762,7 @@ class InferenceEngine:
                 }
                 self._streams.pop(rid, None)
                 return
-        if seq.guided and getattr(self.runner, "has_draft", False):
+        if seq.guided and self.runner.has_draft:
             # speculative verify can't honor per-token masks; silently
             # dropping the constraint would hand back schema-invalid output
             # with finish_reason "stop" — reject up front instead
@@ -851,7 +827,7 @@ class InferenceEngine:
         if seq.adapter:
             try:
                 seq.adapter_idx = self.runner.adapter_slot(seq.adapter)
-            except (KeyError, AttributeError):
+            except KeyError:
                 yield {
                     "finish_reason": "error",
                     "error": f"unknown LoRA adapter {seq.adapter!r}",
@@ -906,11 +882,11 @@ class InferenceEngine:
     def _routed_refusal(self) -> Optional[str]:
         """Why this worker cannot stream `routed_experts` (None: it can)."""
         r = self.runner
-        if getattr(r, "pp", False):
+        if r.pp:
             return "the pipeline-parallel programs do not return the picks"
-        if getattr(r, "sp_enabled", False):
+        if r.sp_enabled:
             return "sequence-parallel prefill does not return the picks"
-        if getattr(r, "has_draft", False):
+        if r.has_draft:
             return "speculative decoding with a draft model does not return the picks"
         if self._spec_on:
             return ("speculative verify (n-gram drafting, the draft ring) "
@@ -983,11 +959,9 @@ class InferenceEngine:
 
         log.info("engine step loop started (fused_mixed=%s)",
                  self.fused_mixed)
-        name_thread = getattr(self.runner, "name_step_thread", None)
-        if name_thread is not None:
-            # compiles on this thread that no step family sees count in
-            # this runner's compile_stats()["other"]
-            name_thread()
+        # compiles on this thread that no step family sees count in this
+        # runner's compile_stats()["other"]
+        self.runner.name_step_thread()
         if self._routed_ok:
             # what the runner holds of dispatches that were not this
             # engine's (a warm-up walk) is none of its first iteration's load
@@ -1075,7 +1049,7 @@ class InferenceEngine:
                             decode_steps=1,
                             n_chunks=len(served),
                             chunk_tokens=sum(len(p.chunk) for p in served),
-                            fused=True, ragged=True, **sinfo,
+                            fused=True, **sinfo,
                         )
                         decode_done = True
                         self._finish_packed_prefills(served, chunk_logits)
@@ -1109,29 +1083,25 @@ class InferenceEngine:
                 if spec:
                     pass  # served above
                 elif self._mixed_fusible(plan):
-                    chunk_logits = self._run_mixed_dispatch(plan)
-                    served = plan.prefills[:len(chunk_logits)]
+                    served, out = self._run_mixed_dispatch(plan)
+                    n_chunk_tok = sum(len(p.chunk) for p in served)
                     rinfo.update(
                         decode_seqs=len(plan.decode.seqs),
                         decode_steps=plan.decode.n_steps,
                         n_chunks=len(served),
-                        chunk_tokens=sum(len(p.chunk) for p in served),
-                        fused=True,
-                        # the packed multi-chunk program is the ragged
-                        # flat-token path; single-chunk fused rides the
-                        # padded decode_multi_with_prefill fallback
-                        ragged=len(served) > 1,
+                        chunk_tokens=n_chunk_tok,
+                        fused=True, ragged=out.ragged,
                     )
                     # decode tokens are emitted: from here on a failure
                     # (e.g. in a chunk's sampling extras) must only
                     # fail the prefill sequences
                     decode_done = True
-                    self._finish_packed_prefills(plan.prefills, chunk_logits)
+                    self._finish_packed_prefills(served, out[1])
                     # one dispatch ran both halves — a per-kind wall split
                     # doesn't exist; observers ignore the mixed kind
                     kind = "mixed"
                     n_tok = (len(plan.decode.seqs) * plan.decode.n_steps
-                             + sum(len(p.chunk) for p in plan.prefills))
+                             + n_chunk_tok)
                 else:
                     # decode first: ITL never waits behind prompt
                     # processing. Publish the halves as separate FPM
@@ -1230,17 +1200,15 @@ class InferenceEngine:
                 g3 = len(self.host_pool.disk)
         hits = self.prefetch.stats["hits"] if self.prefetch is not None else 0
         variants = calls = 0
-        fams = getattr(self.runner, "_families", None)
-        if fams:
-            for fam in fams.values():
-                variants += fam.variants
-                calls += fam.calls
+        for fam in self.runner.compile_families().values():
+            variants += fam.variants
+            calls += fam.calls
         charged = rinfo["chunk_tokens"]
-        rstats = getattr(self.runner, "stats", None)
-        if isinstance(rstats, dict) and "packed_tokens_charged" in rstats:
-            # SimRunner keeps an honest cumulative padded-charge counter;
-            # its per-iteration delta is the real charged-token figure
-            cum = int(rstats.get("packed_tokens_charged") or 0)
+        cum = self.runner.charged_tokens()
+        if cum is not None:
+            # a cost model (SimRunner) keeps an honest cumulative
+            # padded-charge counter; its per-iteration delta is the real
+            # charged-token figure
             delta = cum - self._rec_prev_charged
             self._rec_prev_charged = cum
             if delta > 0:
@@ -1291,8 +1259,8 @@ class InferenceEngine:
         is computed_len + t + 1 tokens, and the device runs every row for
         all n_steps (tokens past a stop are dropped on the host)."""
         ps = self.pool.page_size
-        c = getattr(self.runner, "config", None)
-        window = getattr(c, "sliding_window", 0) or 0
+        c = self.runner.config
+        window = c.sliding_window if c is not None else 0
         n_global = 0
         if window:
             n_global = sum(l % c.sw_period == c.sw_global_residue
@@ -1348,7 +1316,7 @@ class InferenceEngine:
         pools, and fail everything whose device KV was lost (waiting
         sequences keep: they own no pages yet and prefill from scratch).
         Host/disk tiers keep their copies — those bytes are real."""
-        if not getattr(self.runner, "pools_deleted", lambda: False)():
+        if not self.runner.pools_deleted():
             return
         log.error("KV pools were consumed by a failed step; rebuilding "
                   "(all device-cached blocks lost)")
@@ -1497,8 +1465,8 @@ class InferenceEngine:
 
         if payload.get("device"):
             return None
-        page_shape = getattr(self.runner, "kv_page_shape", None)
-        wire_dtype = getattr(self.runner, "kv_wire_dtype", None)
+        page_shape = self.runner.kv_page_shape
+        wire_dtype = self.runner.kv_wire_dtype
         parts = payload.get("chunks") or ([payload] if payload.get("data") else [])
         for p in parts:
             if not p.get("k"):
@@ -1580,7 +1548,7 @@ class InferenceEngine:
                 self.runner.import_pages(seq.pages[lo:hi], lo - off, ch)
         elif target and payload.get("data"):
             self.runner.import_pages(target, seq.n_shared_pages, payload)
-        if getattr(self.runner, "has_draft", False):
+        if self.runner.has_draft:
             # transferred KV covers the target model only; rebuild the
             # draft pools by (cheap) draft prefill — starting after the
             # prefix-cache-shared pages, whose draft KV the sequence
@@ -1757,9 +1725,8 @@ class InferenceEngine:
         dispatch for the whole set) get all chunks in one call; others
         (PP, interpreter fallback) run the chunks sequentially —
         scheduling still packs, only the dispatch is serial."""
-        packed = getattr(self.runner, "prefill_packed", None)
-        if (packed is None or len(plans) <= 1
-                or getattr(self.runner, "has_draft", False)
+        if (not self.runner.has_prefill_packed or len(plans) <= 1
+                or self.runner.has_draft
                 or any(
                     self._mm_chunk(p.seq, p.start_pos, len(p.chunk))
                     is not None
@@ -1770,7 +1737,7 @@ class InferenceEngine:
             return
         with annotate("engine.prefill_packed", chunks=len(plans),
                       tokens=sum(len(p.chunk) for p in plans)):
-            logits_rows = packed([
+            logits_rows = self.runner.prefill_packed([
                 {
                     "tokens": p.chunk,
                     "start": p.start_pos,
@@ -1797,7 +1764,7 @@ class InferenceEngine:
             adapter=seq.adapter_idx,
             mm=mm_chunk,
         )
-        if getattr(self.runner, "has_draft", False) and seq.disagg != "prefill":
+        if self.runner.has_draft and seq.disagg != "prefill":
             # keep the draft model's KV pools in lockstep so spec decode
             # can propose over the full context (skipped on disagg-prefill
             # workers: draft KV isn't exported — the decode worker rebuilds
@@ -1831,21 +1798,16 @@ class InferenceEngine:
         first_lp = None
         mask1 = self._guided_mask(seq)
         n_lp1 = _batch_logprobs([seq])
-        if (n_lp1 >= 0 or _batch_penalties([seq])) and hasattr(
-            self.runner, "sample_one_ex"
-        ):
-            kw1 = {"mask": mask1} if mask1 is not None else {}
-            if bias1 is not None:
-                kw1["bias"] = bias1
+        kw1 = {"mask": mask1} if mask1 is not None else {}
+        if bias1 is not None:
+            kw1["bias"] = bias1
+        if n_lp1 >= 0 or _batch_penalties([seq]):
             token, first_lp = self.runner.sample_one_ex(
                 logits, _sampling_params([seq]), self._next_step(),
                 history=list(seq.tokens) if _batch_penalties([seq]) else None,
                 n_logprobs=n_lp1, **kw1,
             )
         else:
-            kw1 = {"mask": mask1} if mask1 is not None else {}
-            if bias1 is not None:
-                kw1["bias"] = bias1
             token = self.runner.sample_one(
                 logits, _sampling_params([seq]), self._next_step(), **kw1,
             )
@@ -2022,8 +1984,6 @@ class InferenceEngine:
                     "this request is in the batch",
                 )
             return
-        oracle = getattr(self.runner, "spec_draft", None)
-        tree_oracle = getattr(self.runner, "spec_draft_tree", None)
         free: List[Sequence] = []
         for s in running:
             if s.guided_m is not None or s.logit_bias:
@@ -2041,12 +2001,10 @@ class InferenceEngine:
             # every suffix-match site, which the device ring's
             # single-winner gather doesn't surface)
             for s in free:
-                tree = None
-                if tree_oracle is not None:
-                    tree = tree_oracle(
-                        s.tokens[-1], s.computed_len,
-                        self.spec_k, self.spec_branches,
-                    )
+                tree = self.runner.spec_draft_tree(
+                    s.tokens[-1], s.computed_len,
+                    self.spec_k, self.spec_branches,
+                )
                 if tree is None:
                     tree = ngram_propose_tree(
                         s.tokens, self.spec_k, self.spec_branches
@@ -2061,14 +2019,13 @@ class InferenceEngine:
                     ]
             return
         # linear K: an oracle (SimRunner A/B knob) answers first, per row
-        # (it returns None when unset); rows it declines go through ONE
+        # (None where there is none); rows it declines go through ONE
         # fused device-ring proposal when the runner carries the ring,
         # with the host suffix scan as the last fallback
         pending: List[Sequence] = []
         for s in free:
-            draft = None
-            if oracle is not None:
-                draft = oracle(s.tokens[-1], s.computed_len, self.spec_k)
+            draft = self.runner.spec_draft(
+                s.tokens[-1], s.computed_len, self.spec_k)
             if draft is None:
                 pending.append(s)
             else:
@@ -2140,13 +2097,6 @@ class InferenceEngine:
         Returns (chunk_logits, rinfo_spec) or None when the runner
         can't shape the dispatch (drafts are dropped; the caller reruns
         the plain path)."""
-        if hasattr(self.runner, "ensure_ragged_bucket"):
-            from dynamo_tpu.engine.model_runner import BucketOverflowError
-        else:
-            # SimRunner buckets saturate instead of overflowing, and the
-            # mocker process must stay jax-free — catch nothing there
-            BucketOverflowError = ()
-
         seqs = dplan.seqs
         with annotate("engine.spec_verify", batch=len(seqs),
                       chunks=len(prefills)):
@@ -2228,7 +2178,7 @@ class InferenceEngine:
                 n_branch_rows = sum(len(r) for r in branch_rows)
             try:
                 with self._san_scope("spec_verify"):
-                    rows, chunk_logits = self.runner.verify_spec(
+                    out = self.runner.verify_spec(
                         tokens, positions, tables, drafts,
                         sp, step0, chunks=chunks, **vkw,
                     )
@@ -2240,9 +2190,11 @@ class InferenceEngine:
                     "this iteration's drafts", e,
                 )
                 return None
+            rows, chunk_logits = out
             with annotate("engine.emit"):
                 n_rows = sum(1 for d in drafts[: len(seqs)] if d)
                 accepted = emitted_spec = tree_sw = 0
+                taken: List[List[int]] = []  # per sequence, what it accepts
                 for i, seq in enumerate(seqs):
                     if forks[i]:
                         emitted, winner = accept_tree(
@@ -2267,17 +2219,8 @@ class InferenceEngine:
                     if drafts[i]:
                         accepted += len(emitted) - 1
                         emitted_spec += len(emitted)
-                    emit: List[int] = []
-                    reason = None
-                    for token in emitted:
-                        reason = self.scheduler.complete_decode(seq, token)
-                        if not reason:
-                            self._guided_advance(seq, token)
-                        if reason != "stop":
-                            emit.append(token)
-                        if reason:
-                            break
-                    self._emit(seq, emit, reason)
+                    taken.append(emitted)
+                self._commit_decoded(seqs, taken)
         st = self.spec_stats
         st["verify_iters"] += 1
         st["verify_rows"] += n_rows
@@ -2293,37 +2236,20 @@ class InferenceEngine:
             # the dispatch, exactly what the scheduler charged (_spec_cost)
             "spec_drafted": n_drafted + n_branch_tok,
             "spec_emitted": emitted_spec,
+            "ragged": out.ragged,
         }
 
     def _mixed_fusible(self, plan: MixedPlan) -> bool:
         """Whether this MixedPlan can run as ONE dispatch (runner
-        decode_multi_with_prefill). Feature planes the fused program
-        doesn't carry fall back to the two-dispatch path."""
-        runner = self.runner
-        if (not self.fused_mixed
-                or not hasattr(runner, "decode_multi_with_prefill")
-                or getattr(runner, "has_draft", False)
-                or getattr(runner, "pp", False)
-                or getattr(runner, "sp_enabled", False)):
-            # SP runners prefill with ring attention on the full mesh —
-            # the fused program's plain attn_impl would miscompute the
-            # chunk's KV there
-            return False
-        if len(plan.prefills) > 1 and not hasattr(
-            runner, "decode_multi_with_prefills"
-        ):
-            return False  # packed ragged program unavailable on this runner
+        decode_multi_with_prefills). What the runner's programs carry is
+        the runner's to say (can_fuse); what these requests need beyond
+        that keeps the two-dispatch path."""
         seqs = plan.decode.seqs
-        if any(s.guided_m is not None or s.logit_bias for s in seqs):
-            # masks and bias exist only as ragged-step / decode-loop
-            # operands: guided or biased decode rows fuse iff this plan
-            # rides the ragged flat-token program (never the padded
-            # [N, S] fallback, which would silently drop the constraint)
-            use_ragged = getattr(runner, "_use_ragged", None)
-            if (use_ragged is None
-                    or not getattr(runner, "guided_fused", False)
-                    or not use_ragged(len(seqs), len(plan.prefills))):
-                return False
+        if not self.fused_mixed or not self.runner.can_fuse(
+                len(seqs), len(plan.prefills),
+                constrained=any(s.guided_m is not None or s.logit_bias
+                                for s in seqs)):
+            return False
         if _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs):
             return False
         if any(p.seq.logit_bias for p in plan.prefills):
@@ -2335,64 +2261,103 @@ class InferenceEngine:
                 return False  # multimodal chunks ride the standalone prefill
         return True
 
+    def _decode_extras(self, seqs: List[Sequence], T: int,
+                       pending_advance: bool):
+        """The guided-mask and logit-bias keywords of a decode batch's
+        dispatch, and the steps it may fuse: (T, kw).
+
+        Constrained rows need a fresh mask per sampled token. Over a
+        multi-step loop they get it from the device-resident DFA plan —
+        state advance and mask gather in-XLA, ZERO host syncs per step —
+        or, for a schema over the device-table budget, from a host
+        callback that advances a COPY of each row's DFA state by the
+        device-sampled feedback token between fused steps; both produce
+        byte-identical masks on bounded schemas (tests/test_guided.py).
+        A runner without that plumbing (guided_fused: the PP loop) runs
+        one step under a static mask, and T comes back 1.
+
+        `pending_advance`: step 0 is not the loop's own (a mixed
+        dispatch: the ragged step samples it under `masks`, and its token
+        is not yet folded into the states the loop starts from)."""
+        kw: Dict[str, Any] = {}
+        guided_rows = [i for i, s in enumerate(seqs) if s.guided_m is not None]
+        if guided_rows:
+            vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
+            loop = T > 1 and self.runner.guided_fused
+            if not loop:
+                T = 1
+            if pending_advance or not loop:
+                masks = np.ones((len(seqs), vocab), bool)
+                for i in guided_rows:
+                    masks[i] = self._guided_mask(seqs[i])
+                kw["masks"] = masks
+            if loop:
+                gdev = self._guided_device_plan(seqs)
+                if gdev is not None:
+                    kw["guided_dev"] = gdev
+                else:
+                    kw["mask_fn"] = GuidedMaskContext(
+                        len(seqs), vocab,
+                        [(i, seqs[i].guided_m, seqs[i].guided_s)
+                         for i in guided_rows],
+                        pending_advance=pending_advance,
+                    )
+        biases = _batch_biases(seqs, self.runner)
+        if biases is not None:
+            kw["biases"] = biases
+        return T, kw
+
+    def _commit_decoded(self, seqs: List[Sequence], rows, lp=None) -> None:
+        """The one commit of a decode batch's tokens (plain, mixed, and
+        both speculations), inside the caller's engine.emit span: rows[i]
+        holds, in order, the tokens sequence i may take from this
+        dispatch. Each is committed until one finishes the sequence
+        (tokens sampled past a stop are dropped here), the guided DFA
+        follows every token that did not, and the row leaves as one item.
+        `lp`: the decode loop's stacked logprob report, indexed like rows."""
+        for i, seq in enumerate(seqs):
+            emit: List[int] = []
+            lp_entries: Optional[List[Dict[str, Any]]] = None
+            if lp is not None and seq.sampling.get("logprobs") is not None:
+                lp_entries = []
+            reason = None
+            for j, token in enumerate(rows[i]):
+                token = int(token)
+                reason = self.scheduler.complete_decode(seq, token)
+                if not reason:
+                    self._guided_advance(seq, token)
+                if reason != "stop":
+                    emit.append(token)
+                    if lp_entries is not None:
+                        lp_entries.append(_lp_entry(lp, i, j, seq))
+                if reason:
+                    break
+            self._emit(seq, emit, reason, logprobs=lp_entries or None)
+
     def _run_mixed_dispatch(self, plan: MixedPlan):
         """The fused dispatch + decode-half bookkeeping: the decode
         batch's fused steps and the packed prefill chunk set share a
         single jitted program — one dispatch and one host sync per
-        iteration instead of 1 + n_chunks. Returns the per-chunk
-        last-token logits
-        (one row per packed chunk); the caller finishes the prefill half
-        separately so a failure THERE only fails prefill sequences (the
-        decode tokens are already emitted)."""
-        from dynamo_tpu.engine.model_runner import BucketOverflowError
-
+        iteration instead of 1 + n_chunks. Returns (the chunks served,
+        the runner's MixedOut: their last-token logits and which program
+        ran); the caller finishes the prefill half separately so a
+        failure THERE only fails prefill sequences (the decode tokens are
+        already emitted)."""
         seqs = plan.decode.seqs
-        T = plan.decode.n_steps
-        n_chunk_tok = sum(len(p.chunk) for p in plan.prefills)
         prefills = list(plan.prefills)
-        with annotate("engine.mixed", batch=len(seqs), steps=T,
-                      chunks=len(plan.prefills), chunk=n_chunk_tok):
+        with annotate("engine.mixed", batch=len(seqs),
+                      steps=plan.decode.n_steps, chunks=len(prefills),
+                      chunk=sum(len(p.chunk) for p in prefills)):
             with annotate("engine.prep"):
                 tokens = [s.tokens[-1] for s in seqs]
                 positions = [s.computed_len for s in seqs]
                 tables = [s.pages for s in seqs]
+                # guided rows ride the fused program: step 0 samples under
+                # the ragged step's mask operand, steps 1..T-1 under the
+                # decode loop's per-step masks (_decode_extras)
+                T, mixkw = self._decode_extras(seqs, plan.decode.n_steps, True)
                 step0 = self._step_counter + 1
                 self._step_counter += T
-                # guided rows ride the fused program: step 0 samples under the
-                # ragged step's mask operand; steps 1..T-1 fetch per-step masks
-                # through the decode loop's host callback, which advances a
-                # COPY of each row's DFA state by the device-sampled feedback
-                # token (pending_advance: step 0's token was sampled on device
-                # and not yet folded into the authoritative engine state)
-                mixkw: Dict[str, Any] = {}
-                guided_rows = [
-                    i for i, s in enumerate(seqs) if s.guided_m is not None
-                ]
-                if guided_rows:
-                    vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
-                    masks = np.ones((len(seqs), vocab), bool)
-                    for i in guided_rows:
-                        masks[i] = self._guided_mask(seqs[i])
-                    mixkw["masks"] = masks
-                    if T > 1:
-                        # tail steps after the ragged step 0: device DFA plan
-                        # when every schema fits the table budget (the runner
-                        # forces pending_advance — step 0's token was sampled
-                        # on device and not yet folded into the states), host
-                        # callback otherwise
-                        gdev = self._guided_device_plan(seqs)
-                        if gdev is not None:
-                            mixkw["guided_dev"] = gdev
-                        else:
-                            mixkw["mask_fn"] = GuidedMaskContext(
-                                len(seqs), vocab,
-                                [(i, seqs[i].guided_m, seqs[i].guided_s)
-                                 for i in guided_rows],
-                                pending_advance=True,
-                            )
-                biases = _batch_biases(seqs, self.runner)
-                if biases is not None:
-                    mixkw["biases"] = biases
                 sp = _sampling_params(seqs)
                 adapters = [s.adapter_idx for s in seqs]
             while True:
@@ -2401,40 +2366,24 @@ class InferenceEngine:
                 # chunk and retries. Shed chunks were never
                 # complete_prefill'd, so the scheduler re-plans them
                 # verbatim next iteration (planning is side-effect-free;
-                # their pages are already held). The caller's
-                # zip(plan.prefills, chunk_logits) pairs only the served
-                # prefix — chunks are shed strictly from the tail.
+                # their pages are already held). Chunks are shed strictly
+                # from the tail: what is served is a prefix of the plan's.
                 try:
-                    if len(prefills) == 1:
-                        pplan = prefills[0]
-                        sampled, lg = self.runner.decode_multi_with_prefill(
-                            T, tokens, positions, tables, sp,
-                            step0, pplan.chunk, pplan.start_pos,
-                            pplan.seq.pages, pplan.start_pos,
-                            adapters=adapters,
-                            chunk_adapter=pplan.seq.adapter_idx,
-                            **mixkw,
-                        )
-                        chunk_logits = [lg]
-                    else:
-                        sampled, chunk_logits = (
-                            self.runner.decode_multi_with_prefills(
-                                T, tokens, positions, tables, sp,
-                                step0,
-                                [
-                                    {
-                                        "tokens": p.chunk,
-                                        "start": p.start_pos,
-                                        "table": p.seq.pages,
-                                        "prior": p.start_pos,
-                                        "adapter": p.seq.adapter_idx,
-                                    }
-                                    for p in prefills
-                                ],
-                                adapters=adapters,
-                                **mixkw,
-                            )
-                        )
+                    out = self.runner.decode_multi_with_prefills(
+                        T, tokens, positions, tables, sp, step0,
+                        [
+                            {
+                                "tokens": p.chunk,
+                                "start": p.start_pos,
+                                "table": p.seq.pages,
+                                "prior": p.start_pos,
+                                "adapter": p.seq.adapter_idx,
+                            }
+                            for p in prefills
+                        ],
+                        adapters=adapters,
+                        **mixkw,
+                    )
                     break
                 except BucketOverflowError as e:
                     if len(prefills) <= 1:
@@ -2447,20 +2396,8 @@ class InferenceEngine:
                     )
             self._collect_routed(seqs, T, prefills)
             with annotate("engine.emit"):
-                for i, seq in enumerate(seqs):
-                    emit: List[int] = []
-                    reason = None
-                    for j in range(T):
-                        token = int(sampled[i, j])
-                        reason = self.scheduler.complete_decode(seq, token)
-                        if not reason:
-                            self._guided_advance(seq, token)
-                        if reason != "stop":
-                            emit.append(token)
-                        if reason:
-                            break
-                    self._emit(seq, emit, reason)
-        return chunk_logits
+                self._commit_decoded(seqs, out[0])
+        return prefills, out
 
     def _run_decode(self, plan: DecodePlan) -> None:
         with annotate("engine.decode", batch=len(plan.seqs),
@@ -2479,8 +2416,8 @@ class InferenceEngine:
             positions = [s.computed_len for s in seqs]
             page_tables = [s.pages for s in seqs]
             step0 = self._step_counter + 1
-            gamma = getattr(self.runner, "spec_gamma", 0)
-            use_draft_spec = getattr(self.runner, "has_draft", False)
+            gamma = self.runner.spec_gamma
+            use_draft_spec = self.runner.has_draft
             if use_draft_spec and (
                 _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs)
             ):
@@ -2518,62 +2455,19 @@ class InferenceEngine:
                 gamma=gamma, adapters=[s.adapter_idx for s in seqs],
             )
             with annotate("engine.emit"):
-                for i, seq in enumerate(seqs):
-                    emit: List[int] = []
-                    reason = None
-                    for r in range(R):
-                        for j in range(int(counts[i, r])):
-                            token = int(toks[i, r, j])
-                            reason = self.scheduler.complete_decode(seq, token)
-                            if reason != "stop":
-                                emit.append(token)
-                            if reason:
-                                break
-                        if reason:
-                            break
-                    self._emit(seq, emit, reason)
+                self._commit_decoded(seqs, [
+                    [t for r in range(R) for t in toks[i, r, : counts[i, r]]]
+                    for i in range(len(seqs))
+                ])
             return
         with annotate("engine.prep"):
-            masks = None
-            mask_fn = None
-            guided_dev = None
-            guided_rows = [i for i, s in enumerate(seqs) if s.guided_m is not None]
-            if guided_rows:
-                vocab = seqs[guided_rows[0]].guided_m.lifter.vocab_size
-                if T > 1 and getattr(self.runner, "guided_fused", False):
-                    # constrained rows need a fresh mask per sampled token.
-                    # Preferred: the device-resident DFA plan — state advance
-                    # and mask gather happen in-XLA inside the fused loop,
-                    # ZERO host syncs per step. Fallback (schema over the
-                    # device-table budget): a host callback that advances a
-                    # COPY of each row's DFA state by the device-sampled
-                    # feedback token between fused steps — guided rows still
-                    # ride the full decode_steps loop either way, and both
-                    # paths produce byte-identical masks on bounded schemas
-                    # (pinned by tests/test_guided.py)
-                    guided_dev = self._guided_device_plan(seqs)
-                    if guided_dev is None:
-                        mask_fn = GuidedMaskContext(
-                            len(seqs), vocab,
-                            [(i, seqs[i].guided_m, seqs[i].guided_s)
-                             for i in guided_rows],
-                        )
-                else:
-                    # runners without callback plumbing (PP loop) keep the
-                    # legacy one-step masked dispatch
-                    T = 1
-                    masks = np.ones((len(seqs), vocab), bool)
-                    for i in guided_rows:
-                        masks[i] = self._guided_mask(seqs[i])
-            biases = _batch_biases(seqs, self.runner)
+            T, mkw = self._decode_extras(seqs, T, False)
             self._step_counter += T
             n_lp = _batch_logprobs(seqs)
             histories = (
                 [list(s.tokens) for s in seqs] if _batch_penalties(seqs) else None
             )
-            if (n_lp >= 0 or histories is not None) and getattr(
-                self.runner, "pp", False
-            ):
+            if (n_lp >= 0 or histories is not None) and self.runner.pp:
                 # the PP decode loop has no logprob/penalty wiring yet — drop
                 # the extras with a warning (same contract as spec decode
                 # above) instead of letting a raise inside the shared dispatch
@@ -2586,57 +2480,21 @@ class InferenceEngine:
                             "pipeline-parallel workers and were ignored",
                         )
                 n_lp, histories = -1, None
+            if n_lp >= 0 or histories is not None:
+                mkw.update(n_logprobs=n_lp, histories=histories,
+                           prompt_lens=[s.n_prompt0 for s in seqs])
             sp = _sampling_params(seqs)
             adapters = [s.adapter_idx for s in seqs]
+        sampled = self.runner.decode_multi(
+            T, tokens, positions, page_tables, sp, step0,
+            adapters=adapters, **mkw,
+        )
         lp = None
-        if (n_lp >= 0 or histories is not None) and hasattr(
-            self.runner, "decode_multi_ex"
-        ):
-            mkw = {"masks": masks} if masks is not None else {}
-            if mask_fn is not None:
-                mkw["mask_fn"] = mask_fn
-            if guided_dev is not None:
-                mkw["guided_dev"] = guided_dev
-            if biases is not None:
-                mkw["biases"] = biases
-            sampled, lp = self.runner.decode_multi_ex(
-                T, tokens, positions, page_tables, sp, step0,
-                adapters=adapters,
-                n_logprobs=n_lp, histories=histories,
-                prompt_lens=[s.n_prompt0 for s in seqs],
-                **mkw,
-            )
-        else:
-            mkw = {"masks": masks} if masks is not None else {}
-            if mask_fn is not None:
-                mkw["mask_fn"] = mask_fn
-            if guided_dev is not None:
-                mkw["guided_dev"] = guided_dev
-            if biases is not None:
-                mkw["biases"] = biases
-            sampled = self.runner.decode_multi(
-                T, tokens, positions, page_tables, sp, step0,
-                adapters=adapters,
-                **mkw,
-            )
+        if n_lp >= 0:
+            sampled, lp = sampled
         self._collect_routed(seqs, T, [])
         with annotate("engine.emit"):
-            for i, seq in enumerate(seqs):
-                emit: List[int] = []
-                lp_entries: List[Dict[str, Any]] = []
-                reason = None
-                for j in range(T):
-                    token = int(sampled[i, j])
-                    reason = self.scheduler.complete_decode(seq, token)
-                    if not reason:
-                        self._guided_advance(seq, token)
-                    if reason != "stop":
-                        emit.append(token)
-                        if lp is not None and seq.sampling.get("logprobs") is not None:
-                            lp_entries.append(_lp_entry(lp, i, j, seq))
-                    if reason:
-                        break
-                self._emit(seq, emit, reason, logprobs=lp_entries or None)
+            self._commit_decoded(seqs, sampled, lp)
 
     def _guided_advance(self, seq: Sequence, token: int) -> None:
         """Advance a sequence's constraint DFA past an accepted token. A
@@ -2910,7 +2768,7 @@ class InferenceEngine:
                                 exc_info=True)
                     n = 0
                     k = v = None
-                if k is None and hasattr(self.runner, "export_pages_device"):
+                if k is None and self.runner.holds_kv:
                     # real engine with hash-only entries (data lost, e.g. a
                     # shared G4 object deleted): advertising n>0 without
                     # data would spread phantom residency cluster-wide
@@ -2934,9 +2792,7 @@ class InferenceEngine:
             # stored into G2 would otherwise pass host_pool and explode as
             # an unhandled KvWireLayoutMismatch at onboard time
             arrays = kv_payload_to_arrays(
-                payload,
-                getattr(self.runner, "kv_page_shape", None),
-                getattr(self.runner, "kv_wire_dtype", None),
+                payload, self.runner.kv_page_shape, self.runner.kv_wire_dtype,
             )
         except Exception:
             # mixed-version peer (KvWireLayoutMismatch) or corrupt bytes:
@@ -3001,7 +2857,7 @@ class InferenceEngine:
             # takes wall time, so charge the import (SimRunner sleeps it;
             # without this, mocker prefetch A/Bs would credit the
             # synchronous path with a free onboard)
-            if hasattr(self.runner, "export_pages_device"):
+            if self.runner.holds_kv:
                 log.info("lower-tier block has no data; recomputing")
                 return False
             self.runner.import_pages(
@@ -3019,7 +2875,7 @@ class InferenceEngine:
         G2-resident, the tier quantizes, and the device pools are int8
         (kv_quantize) — else None (dense path). Raises KeyError on
         eviction races like host_pool.get."""
-        if not getattr(self.runner, "kv_quantize", None):
+        if not self.runner.kv_quantize:
             return None
         host = getattr(self.host_pool, "host", self.host_pool)
         if not getattr(host, "quantize", False):
@@ -3105,14 +2961,10 @@ def _sampling_params(seqs: List[Sequence]) -> Dict[str, list]:
 def _batch_biases(seqs: List[Sequence], runner):
     """[n, V] f32 additive logit-bias rows for the batch, or None when no
     sequence carries one (out-of-range token ids are ignored — the
-    preprocessor validates, but the wire is untrusted). The vocab lookup
-    happens only when a bias exists: sim runners expose vocab_size
-    directly and have no .config."""
+    preprocessor validates, but the wire is untrusted)."""
     if not any(s.logit_bias for s in seqs):
         return None
-    vocab_size = getattr(
-        getattr(runner, "config", None), "vocab_size", None
-    ) or getattr(runner, "vocab_size")
+    vocab_size = runner.vocab_size
     rows = np.zeros((len(seqs), vocab_size), np.float32)
     for i, s in enumerate(seqs):
         if not s.logit_bias:

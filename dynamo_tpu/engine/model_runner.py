@@ -29,6 +29,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from dynamo_tpu.engine.runner_api import (
+    BucketOverflowError,
+    MixedOut,
+    Runner,
+)
 from dynamo_tpu.engine.sampling import SamplingParams, sample
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
@@ -238,11 +243,11 @@ def _mixed_loop(
     mesh,
     n_steps: int,
     params,
-    ptok,  # [N, S] packed prefill chunk tokens (bucket-padded; N=1 legacy)
+    ptok,  # [N, S] packed prefill chunk tokens (bucket-padded)
     ppos,  # [N, S] positions (-1 padding)
     ppt,  # [N, MP] per-chunk page tables
     pkvl,  # [N] per-chunk kv lens
-    plast,  # scalar (N=1) or [N]: last valid index per chunk row
+    plast,  # [N]: last valid index per chunk row
     padapter,  # [N] LoRA slot per chunk's sequence (None w/o LoRA)
     tokens0,
     packed,
@@ -260,9 +265,9 @@ def _mixed_loop(
     launches one program per chunk). Every chunk belongs to a
     different sequence (disjoint pages) than the decode batch and its
     packed siblings, so ordering inside the program is free for XLA to
-    choose. Returns (toks [B, n_steps], last [B], chunk_logits — [V]
-    for the legacy scalar plast, else [N, V] — k_pool, v_pool); a routed
-    model's adds {"chunks", "decode", "load"} (see _forward above)."""
+    choose. Returns (toks [B, n_steps], last [B], chunk_logits [N, V],
+    k_pool, v_pool); a routed model's adds {"chunks", "decode", "load"}
+    (see _forward above)."""
     routed = config.is_moe
     logits, k_pool, v_pool, *sel = llama.forward(
         config, params, ptok, ppos, k_pool, v_pool, ppt, pkvl, plast,
@@ -273,10 +278,7 @@ def _mixed_loop(
         config, attn_impl, mesh, n_steps, -1, params, tokens0, packed,
         None, None, None, k_pool, v_pool, sampling, lora,
     )
-    if getattr(plast, "ndim", 0) >= 1:
-        chunk_logits = logits[:, 0]  # [N, V], one row per packed chunk
-    else:
-        chunk_logits = logits[0, 0]  # [V], legacy single-chunk caller
+    chunk_logits = logits[:, 0]  # [N, V], one row per packed chunk
     out = (toks, last, chunk_logits, k_pool, v_pool)
     if routed:
         real = jnp.arange(ptok.shape[0], dtype=jnp.int32)[:, None] < prows
@@ -765,18 +767,6 @@ def kv_payload_to_arrays(payload: Dict[str, Any], page_shape=None, dtype=None):
     return k, v
 
 
-class BucketOverflowError(ValueError):
-    """A dispatch needs a shape past the largest configured bucket. Carries
-    what overflowed so the engine can degrade gracefully — shed chunks
-    from the pack and defer them to the next iteration — instead of
-    failing every sequence in the plan mid-iteration."""
-
-    def __init__(self, n: int, buckets: Sequence[int]):
-        super().__init__(f"{n} exceeds largest bucket {buckets[-1]}")
-        self.n = n
-        self.largest = buckets[-1]
-
-
 def _next_bucket(buckets: Sequence[int], n: int) -> int:
     for b in buckets:
         if b >= n:
@@ -784,8 +774,24 @@ def _next_bucket(buckets: Sequence[int], n: int) -> int:
     raise BucketOverflowError(n, buckets)
 
 
-class ModelRunner:
+def _chunk_rows(chunk_logits: jax.Array, n: int) -> List[jax.Array]:
+    """The last-token logits of a mixed dispatch's n real chunks, one
+    device row each, split once its tokens are back (the device is idle
+    either way). A lone chunk is indexed and several are unstacked: the
+    eager programs these paths have always run, and the ones
+    benchmark/serve.py's walk meets (one chunk through
+    decode_multi_with_prefill, several by iterating the rows)."""
+    if n == 1:
+        return [chunk_logits[0]]
+    return list(chunk_logits)[:n]
+
+
+class ModelRunner(Runner):
     supports_logit_bias = True  # engine gates biased requests on this
+    static_shapes = True
+    holds_kv = True
+    has_verify_spec = True
+    has_draft_ring = True
 
     def __init__(
         self,
@@ -813,9 +819,12 @@ class ModelRunner:
         kv_quantize: Optional[str] = None,  # "int8" → quantized KV pools
     ):
         self.config = config
+        self.vocab_size = config.vocab_size
+        self.max_seq_len = config.max_seq_len
         self._sanitizer = None  # set by attach_sanitizer (engine opt-in)
         self.mesh_config = mesh_config or MeshConfig()
         self.mesh = make_mesh(self.mesh_config, devices)
+        self.platform = self.mesh.devices.flat[0].platform
         self.policy = ShardingPolicy(self.mesh)
         # pipeline parallelism: layer-stacked params and the KV pool shard
         # their leading [L] axis over `pipe`; step functions run the GPipe
@@ -865,6 +874,14 @@ class ModelRunner:
         # bucket (full mixed iterations never round up).
         self.ragged_buckets = tuple(sorted(ragged_buckets))
         self.ragged_q_block = 8
+        # a verify dispatch samples at most seg_cap rows; budgeting verify
+        # tokens to RAGGED_MAX_SEGS (minus one slot per decode row / chunk)
+        # keeps every verify dispatch inside the gather the compiled
+        # program already has — the no-new-compile-families invariant
+        # (docs/ragged_attention.md)
+        from dynamo_tpu.ops.ragged_paged_attention import RAGGED_MAX_SEGS
+
+        self.spec_seg_budget = RAGGED_MAX_SEGS
         self.dtype = dtype
 
         t0 = time.monotonic()
@@ -1076,20 +1093,12 @@ class ModelRunner:
         self._draft_ring_host = None  # (np hist, np lens) mirror
         self._draft_ring_dirty = False  # mirror edited → restage
         self._draft_ring_shape = None  # (slots, window, delta_cap)
-        # ragged flat-token mixed dispatch: default ON wherever the fused
-        # mixed path runs; DYN_RAGGED_MIXED=0 forces the legacy [N, S]
-        # padded path (the A/B baseline), =1 forces it on. PP/SP keep the
-        # legacy fallback; LoRA batches carry per-row adapters the single
-        # flat row cannot, and MLA has no ragged attention yet.
-        _renv = os.environ.get("DYN_RAGGED_MIXED", "").lower()
-        if _renv in ("1", "true", "on", "yes"):
-            _ragged_ok = True
-        elif _renv in ("0", "false", "off", "no"):
-            _ragged_ok = False
-        else:
-            _ragged_ok = True
+        # ragged flat-token mixed dispatch wherever the fused mixed path
+        # runs. PP/SP keep the padded [N, S] program; LoRA batches carry
+        # per-row adapters the single flat row cannot, and MLA has no
+        # ragged attention yet.
         self.ragged_mixed = (
-            _ragged_ok and not self.pp and not self.sp_enabled
+            not self.pp and not self.sp_enabled
             and self.lora is None and not config.is_mla
         )
         # device-resident sampling cache: batches re-send identical sampling
@@ -1293,21 +1302,6 @@ class ModelRunner:
         kv_lens = np.asarray([prior_len + n], np.int32)
         return jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(pt), jnp.asarray(kv_lens), n
 
-    def decode(
-        self,
-        tokens: List[int],
-        positions: List[int],
-        page_tables: List[List[int]],
-        kv_lens: List[int],
-        sampling,  # SamplingParams or dict of host lists
-        step: int,
-    ) -> np.ndarray:
-        """One decode step (thin wrapper over the fused loop so single-step
-        and multi-step use the identical compiled path and attn_impl).
-        Returns sampled token ids [B_bucket] (host numpy)."""
-        out = self.decode_multi(1, tokens, positions, page_tables, sampling, step)
-        return out[:, 0]
-
     def attach_sanitizer(self, san) -> None:
         """Adopt the engine's runtime sanitizer: staging / readback sites
         below run inside named allow_transfer scopes so the engine can
@@ -1364,6 +1358,55 @@ class ModelRunner:
             idx[: len(adapters)] = adapters
         return jnp.asarray(idx)
 
+    def _stage_decode_rows(self, tokens, positions, page_tables, step,
+                           adapters, B: int):
+        """The decode rows of a dispatch as _decode_loop takes them, at
+        decode bucket B: (tokens [B], packed pos|pt|adapters|step) on the
+        device, all per-dispatch dynamic ints in ONE transfer (see
+        _decode_loop). `tokens` may be a device array [B] — the ragged
+        tail chains on the tokens the ragged step sampled — and passes
+        through untouched: no readback, no eager slice program."""
+        n = len(positions)
+        pt = self._pad_page_table(page_tables, B)
+        MP = pt.shape[1]
+        packed = np.zeros(
+            B * (1 + MP) + (B if self.lora is not None else 0) + 1, np.int32)
+        packed[:B] = -1
+        packed[:n] = positions
+        packed[B : B + B * MP] = pt.ravel()
+        if self.lora is not None and adapters:
+            packed[B + B * MP : B + B * MP + len(adapters)] = adapters
+        packed[-1] = step
+        with self._allow("decode_staging"):
+            if isinstance(tokens, jax.Array):
+                tok = tokens
+            else:
+                tok_h = np.zeros(B, np.int32)
+                tok_h[:n] = tokens
+                tok = jnp.asarray(tok_h)
+            return tok, jnp.asarray(packed)
+
+    def _pad_rows(self, rows: np.ndarray, B: int, fill, dtype) -> jax.Array:
+        """[n, V] guided masks (fill True) or logit-bias rows (fill 0) at
+        B rows on the device; the pad rows stay all-allowed / unbiased."""
+        out = np.full((B, self.config.vocab_size), fill, dtype)
+        out[: rows.shape[0]] = rows
+        with self._allow("decode_staging"):
+            return jnp.asarray(out)
+
+    def _guided_kw(self, mask_fn, guided_dev, B: int) -> Dict[str, Any]:
+        """_decode_loop's keyword for rows whose guided masks change from
+        step to step: the host callback (mask_fn, which wins when both are
+        given: the fallback for schemas past the device-table budget) or
+        the device-resident DFA plan."""
+        if mask_fn is not None:
+            mask_fn.B = B  # callback mask rows must match the padded bucket
+            self.set_guided_ctx(mask_fn)
+            return {"mask_fn": self._mask_tramp}
+        if guided_dev is not None:
+            return {"guided": self._guided_op(guided_dev, B)}
+        return {}
+
     def decode_multi(
         self,
         n_steps: int,
@@ -1373,120 +1416,43 @@ class ModelRunner:
         sampling,  # SamplingParams or dict of host lists
         step: int,
         adapters: Optional[List[int]] = None,
-        masks: Optional[np.ndarray] = None,
-        biases: Optional[np.ndarray] = None,
-        mask_fn=None,
-        guided_dev=None,
-    ) -> np.ndarray:
-        """n_steps fused decode iterations (one host sync total). Page
-        tables must already cover positions[i] + n_steps slots. Returns
-        sampled tokens [B_bucket, n_steps]."""
-        toks, _ = self.decode_multi_async(
-            n_steps, tokens, positions, page_tables, sampling, step, adapters,
-            masks=masks, biases=biases, mask_fn=mask_fn, guided_dev=guided_dev,
-        )
-        with self._allow("token_readback"), annotate("engine.readback"):
-            return np.asarray(self._readback(toks))
-
-    def decode_multi_ex(
-        self,
-        n_steps: int,
-        tokens: List[int],
-        positions: List[int],
-        page_tables: List[List[int]],
-        sampling,
-        step: int,
-        adapters: Optional[List[int]] = None,
-        n_logprobs: int = -1,
-        histories: Optional[List[List[int]]] = None,
-        prompt_lens: Optional[List[int]] = None,
-        masks: Optional[np.ndarray] = None,
-        biases: Optional[np.ndarray] = None,
-        mask_fn=None,
-        guided_dev=None,
-    ):
-        """decode_multi with the sampling extras: `histories` (per-sequence
-        prompt+generated token ids) switches on repetition/frequency/
-        presence penalties — `prompt_lens[i]` marks where generated output
-        starts in histories[i] (frequency/presence are output-only; absent
-        = whole history is prompt); `n_logprobs` >= 0 additionally returns
-        (tok_lp [B, T], top_ids [B, T, K], top_lps [B, T, K]) host arrays.
-        Returns (sampled [B, T], lp | None)."""
-        out = self.decode_multi_async(
-            n_steps, tokens, positions, page_tables, sampling, step, adapters,
-            n_logprobs=n_logprobs, histories=histories, prompt_lens=prompt_lens,
-            masks=masks, biases=biases, mask_fn=mask_fn, guided_dev=guided_dev,
-        )
-        with self._allow("token_readback"), annotate("engine.readback"):
-            if n_logprobs >= 0:
-                toks, _, lp = out
-                toks_h, lp_h = self._readback((toks, lp))
-                return np.asarray(toks_h), tuple(np.asarray(a) for a in lp_h)
-            toks, _ = out
-            return np.asarray(self._readback(toks)), None
-
-    def decode_multi_async(
-        self,
-        n_steps: int,
-        tokens,  # List[int] OR device int32 [>=B] (previous out[:, -1])
-        positions: List[int],
-        page_tables: List[List[int]],
-        sampling,
-        step: int,
-        adapters: Optional[List[int]] = None,
-        n_logprobs: int = -1,
-        histories: Optional[List[List[int]]] = None,
-        prompt_lens: Optional[List[int]] = None,
         masks: Optional[np.ndarray] = None,  # [n, V] bool guided masks
         biases: Optional[np.ndarray] = None,  # [n, V] f32 logit_bias rows
         mask_fn=None,  # GuidedMaskContext: per-step host-advanced masks,
         # letting constrained rows ride full n_steps fused loops (the
-        # static `mask` covers step 0 semantics when mask_fn is None)
+        # static `masks` covers step 0 semantics when mask_fn is None)
         guided_dev=None,  # (tables, row_entries, pending): device-resident
         # guided DFA plan — tables a deduped List[DeviceGuidedTable],
         # row_entries[i] None (unguided row) or (table_idx, local_state).
         # Replaces mask_fn's per-step io_callback with an in-XLA
-        # advance+gather for bounded schemas (see _decode_loop `guided`);
-        # mask_fn wins when both are given (the host fallback).
+        # advance+gather for bounded schemas (see _decode_loop `guided`)
+        n_logprobs: int = -1,
+        histories: Optional[List[List[int]]] = None,
+        prompt_lens: Optional[List[int]] = None,
     ):
-        """decode_multi without the host sync: returns (toks, last) DEVICE
-        arrays — toks [B_bucket, n_steps] and last [B_bucket] (the final
-        column, produced inside the jit). `tokens` may be the previous
-        dispatch's `last`, so consecutive dispatches pipeline on device
-        with no round trip between them — the caller device_gets token
-        batches one dispatch behind the chip (the continuous-batching
-        engine overlaps its bookkeeping the same way).
-        With n_logprobs >= 0 the return grows to (toks, last, lp) — see
-        decode_multi_ex."""
+        """n_steps fused decode iterations (one host sync total). Page
+        tables must already cover positions[i] + n_steps slots. Returns
+        sampled tokens [B_bucket, n_steps] (host).
+
+        The sampling extras: `histories` (per-sequence prompt+generated
+        token ids) switches on repetition/frequency/presence penalties —
+        `prompt_lens[i]` marks where generated output starts in
+        histories[i] (frequency/presence are output-only; absent = whole
+        history is prompt); with `n_logprobs` >= 0 the return is
+        (sampled, (tok_lp [B, T], top_ids [B, T, K], top_lps [B, T, K]))
+        host arrays."""
+        if self.pp and (
+                n_logprobs >= 0 or histories is not None or biases is not None
+                or mask_fn is not None or guided_dev is not None):
+            raise NotImplementedError(
+                "logprobs/penalties/logit_bias/multi-step guided masks "
+                "are not wired on the pipeline-parallel decode path yet"
+            )
         with annotate("engine.stage"):
             n = len(positions)
             B = _next_bucket(self.decode_buckets, n)
-            pt = self._pad_page_table(page_tables, B)
-            MP = pt.shape[1]
-            # one packed transfer for all per-dispatch ints (see _decode_loop)
-            packed = np.zeros(B * (1 + MP) + (B if self.lora is not None else 0) + 1,
-                              np.int32)
-            packed[:B] = -1
-            packed[:n] = positions
-            packed[B : B + B * MP] = pt.ravel()
-            if self.lora is not None and adapters:
-                packed[B + B * MP : B + B * MP + len(adapters)] = adapters
-            packed[-1] = step
-
-            if isinstance(tokens, jax.Array):
-                if tokens.shape[0] != B:
-                    raise ValueError(
-                        f"chained token array has batch {tokens.shape[0]}, "
-                        f"dispatch bucket is {B} — chaining requires a stable "
-                        "bucket (sync to host when the batch re-buckets)"
-                    )
-                tok = tokens  # pass through untouched: no eager slice programs
-            else:
-                tok_h = np.zeros(B, np.int32)
-                tok_h[:n] = tokens
-                with self._allow("decode_staging"):
-                    tok = jnp.asarray(tok_h)
-
+            tok, packed_dev = self._stage_decode_rows(
+                tokens, positions, page_tables, step, adapters, B)
             hist = None
             if histories is not None:
                 # bucketed so history growth re-compiles per bucket, not per
@@ -1502,161 +1468,54 @@ class ModelRunner:
                     )
                 with self._allow("decode_staging"):
                     hist = (jnp.asarray(hist_h), jnp.asarray(plen_h))
-
-            mask_dev = None
-            if masks is not None:
-                m = np.ones((B, self.config.vocab_size), bool)
-                m[: masks.shape[0]] = masks  # pad rows stay all-allowed
-                with self._allow("decode_staging"):
-                    mask_dev = jnp.asarray(m)
-
-            if self.pp and (
-                    n_logprobs >= 0 or hist is not None or biases is not None
-                    or mask_fn is not None or guided_dev is not None):
-                raise NotImplementedError(
-                    "logprobs/penalties/logit_bias/multi-step guided masks "
-                    "are not wired on the pipeline-parallel decode path yet"
-                )
-
-            bias_dev = None
-            if biases is not None:
-                bz = np.zeros((B, self.config.vocab_size), np.float32)
-                bz[: biases.shape[0]] = biases  # pad rows stay unbiased
-                with self._allow("decode_staging"):
-                    bias_dev = jnp.asarray(bz)
-
-            mkw = {}
-            if mask_fn is not None:
-                mask_fn.B = B  # callback mask rows must match the padded bucket
-                self.set_guided_ctx(mask_fn)
-                mkw["mask_fn"] = self._mask_tramp
-            elif guided_dev is not None:
-                mkw["guided"] = self._guided_op(guided_dev, B)
+            mask_dev = (None if masks is None
+                        else self._pad_rows(masks, B, True, bool))
+            bias_dev = (None if biases is None
+                        else self._pad_rows(biases, B, 0.0, np.float32))
+            mkw = self._guided_kw(mask_fn, guided_dev, B)
             with self._allow("decode_staging"):
-                packed_dev = jnp.asarray(packed)
                 samp = self._device_sampling(sampling, B)
         if self.pp:
-            toks, last, self.k_pool, self.v_pool = self._jit_pp_decode(
+            toks, _, self.k_pool, self.v_pool = self._jit_pp_decode(
                 n_steps, self.params, tok, packed_dev, mask_dev,
                 self.k_pool, self.v_pool, samp,
             )
-            return toks, last
-        toks, last, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
-            n_steps, n_logprobs, self.params, tok, packed_dev, hist,
-            mask_dev, bias_dev, self.k_pool, self.v_pool,
-            samp, self.lora, **mkw,
-        )
-        self._note_routed(routed, n_steps, n_dec=n)
-        if n_logprobs >= 0:
-            return toks, last, lp
-        return toks, last
-
-    def decode_multi_with_prefill(
-        self,
-        n_steps: int,
-        tokens: List[int],
-        positions: List[int],
-        page_tables: List[List[int]],
-        sampling,
-        step: int,
-        chunk_tokens: List[int],
-        chunk_start: int,
-        chunk_table: List[int],
-        chunk_prior: int,
-        adapters: Optional[List[int]] = None,
-        chunk_adapter: int = 0,
-        masks: Optional[np.ndarray] = None,
-        mask_fn=None,
-        biases: Optional[np.ndarray] = None,
-        guided_dev=None,
-    ) -> Tuple[np.ndarray, jax.Array]:
-        """Fused mixed iteration (_mixed_loop): the decode batch's fused
-        n_steps AND one bounded prefill chunk in a single dispatch.
-        Returns (sampled [B_bucket, n_steps] host, chunk last-token
-        logits [V] device). The engine falls back to the two-dispatch
-        path for feature planes this doesn't carry (logprobs/penalties/
-        guided masks/spec decode/multimodal chunks/PP meshes)."""
-        if self.pp:
-            raise NotImplementedError("fused mixed step has no PP path")
-        if self._use_ragged(len(positions), 1):
-            chunk = {
-                "tokens": chunk_tokens, "start": chunk_start,
-                "table": chunk_table, "prior": chunk_prior,
-                "adapter": chunk_adapter,
-            }
-            try:
-                toks, chunk_logits = self._decode_multi_with_prefills_ragged(
-                    n_steps, tokens, positions, page_tables, sampling,
-                    step, [chunk], masks=masks, mask_fn=mask_fn,
-                    biases=biases, guided_dev=guided_dev,
-                )
-                return toks, chunk_logits[0]
-            except BucketOverflowError as e:
-                if masks is not None or mask_fn is not None \
-                        or biases is not None or guided_dev is not None:
-                    raise
-                log.warning(
-                    "mixed plan (%d tokens) overflows ragged T buckets "
-                    "(largest %d); using the padded fallback", e.n, e.largest,
-                )
-        elif masks is not None or mask_fn is not None or biases is not None \
-                or guided_dev is not None:
-            raise NotImplementedError(
-                "guided masks / logit bias require the ragged mixed path"
+        else:
+            toks, _, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
+                n_steps, n_logprobs, self.params, tok, packed_dev, hist,
+                mask_dev, bias_dev, self.k_pool, self.v_pool,
+                samp, self.lora, **mkw,
             )
-        with annotate("engine.stage"):
-            ptok, ppos, ppt, pkvl, n = self._prep_prefill(
-                chunk_tokens, chunk_start, chunk_table, chunk_prior
-            )
-            plast = jnp.int32(n - 1)
-            padapter = (
-                jnp.asarray([chunk_adapter], jnp.int32)
-                if self.lora is not None else None
-            )
-            tok_dev, packed_dev, samp = self._stage_padded_decode_half(
-                tokens, positions, page_tables, sampling, step, adapters)
-        return self._dispatch_padded_mixed(
-            n_steps, (ptok, ppos, ppt, pkvl, plast, padapter),
-            (tok_dev, packed_dev, samp), len(positions), [n])
+            self._note_routed(routed, n_steps, n_dec=n)
+        with self._allow("token_readback"), annotate("engine.readback"):
+            if n_logprobs >= 0:
+                toks_h, lp_h = self._readback((toks, lp))
+                return np.asarray(toks_h), tuple(np.asarray(a) for a in lp_h)
+            return np.asarray(self._readback(toks))
 
-    def _dispatch_padded_mixed(self, n_steps, chunk_half, decode_half,
-                               n_dec: int, chunk_lens: List[int]):
-        """The padded mixed program on staged inputs, and the readback of
-        its sampled tokens: (sampled [B_bucket, n_steps] host, chunk
-        logits device)."""
-        tok_dev, packed_dev, samp = decode_half
-        kw = {}
-        if self.routed:  # rows past the real chunks replicate row 0
-            kw["prows"] = jnp.int32(len(chunk_lens))
-        toks, _, chunk_logits, self.k_pool, self.v_pool, *routed = self._jit_mixed(
-            n_steps, self.params, *chunk_half, tok_dev, packed_dev,
-            self.k_pool, self.v_pool, samp, self.lora, **kw,
-        )
-        self._note_routed(routed, 1 + n_steps, n_dec, chunk_lens)
-        with annotate("engine.readback"):
-            return np.asarray(self._readback(toks)), chunk_logits
+    def can_fuse(self, n_decode: int, n_chunks: int, *,
+                 constrained: bool) -> bool:
+        if self.pp or self.sp_enabled or self.has_draft:
+            # SP runners prefill with ring attention on the full mesh —
+            # the fused program's plain attn_impl would miscompute the
+            # chunk's KV there
+            return False
+        # masks and bias exist only as ragged-step / decode-loop operands:
+        # guided or biased decode rows fuse iff the plan rides the ragged
+        # flat-token program (never the padded [N, S] one, which would
+        # silently drop the constraint)
+        return not constrained or self._use_ragged(n_decode, n_chunks)
 
-    def _stage_padded_decode_half(self, tokens, positions, page_tables,
-                                  sampling, step, adapters):
-        """Device inputs of the decode half of a padded mixed dispatch
-        (_mixed_loop): (tokens [B], packed pos|pt|adapters|step, sampling
-        params), all at the decode bucket."""
-        B = _next_bucket(self.decode_buckets, len(positions))
-        pt = self._pad_page_table(page_tables, B)
-        MP = pt.shape[1]
-        packed = np.zeros(
-            B * (1 + MP) + (B if self.lora is not None else 0) + 1, np.int32
-        )
-        packed[:B] = -1
-        packed[: len(positions)] = positions
-        packed[B : B + B * MP] = pt.ravel()
-        if self.lora is not None and adapters:
-            packed[B + B * MP : B + B * MP + len(adapters)] = adapters
-        packed[-1] = step
-        tok_h = np.zeros(B, np.int32)
-        tok_h[: len(positions)] = tokens
-        return (jnp.asarray(tok_h), jnp.asarray(packed),
-                self._device_sampling(sampling, B))
+    def decode_multi_with_prefill(self, n_steps, tokens, positions, page_tables,
+                                  sampling, step, chunk_tokens, chunk_start,
+                                  chunk_table, chunk_prior, chunk_adapter=0, **kw):
+        # Kept for benchmark/serve.py's warm-up walk alone (no program PR may
+        # edit it; ROADMAP D10); the engine calls decode_multi_with_prefills.
+        chunk = {"tokens": chunk_tokens, "start": chunk_start, "table": chunk_table,
+                 "prior": chunk_prior, "adapter": chunk_adapter}
+        toks, rows = self.decode_multi_with_prefills(
+            n_steps, tokens, positions, page_tables, sampling, step, [chunk], **kw)
+        return toks, rows[0]
 
     def _prep_prefill_packed(self, chunks: List[Dict[str, Any]]):
         """Bucket-pad a packed chunk set into ragged [N, S] device inputs,
@@ -1706,16 +1565,22 @@ class ModelRunner:
         mask_fn=None,  # GuidedMaskContext for the fused tail steps 1..n-1
         biases: Optional[np.ndarray] = None,  # [n_dec, V] logit-bias rows
         guided_dev=None,  # device guided DFA plan for the fused tail
-    ) -> Tuple[np.ndarray, jax.Array]:
-        """Packed fused mixed iteration: the decode batch's fused n_steps
-        AND the whole token-budgeted prefill chunk set in a SINGLE
-        dispatch (the ragged chunks ride as rows of one [N, S] prefill
-        batch). Returns (sampled [B_bucket, n_steps] host, per-chunk
-        last-token logits [N_bucket, V] device — row i belongs to
-        chunks[i], rows past len(chunks) are padding). Same feature-plane
-        limits as decode_multi_with_prefill."""
+    ) -> MixedOut:
+        """Fused mixed iteration: the decode batch's n_steps AND the
+        token-budgeted prefill chunk set, one chunk or many, in one
+        dispatch (two on the ragged path, chained on the device). The one
+        place that picks the program: the ragged flat-token step where
+        the runner has it and the plan fits its segments; the padded
+        [N, S] program (_mixed_loop, each chunk a row) otherwise, and
+        when an unconstrained plan overflows the ragged T buckets.
+        Returns MixedOut(sampled [B_bucket, n_steps] host, last-token
+        logits [V] device per chunk, which program ran). The engine
+        takes the two-dispatch path for the feature planes this doesn't
+        carry (can_fuse; logprobs/penalties/multimodal chunks)."""
         if self.pp:
             raise NotImplementedError("fused mixed step has no PP path")
+        constrained = (masks is not None or mask_fn is not None
+                       or biases is not None or guided_dev is not None)
         if self._use_ragged(len(positions), len(chunks)):
             try:
                 return self._decode_multi_with_prefills_ragged(
@@ -1724,8 +1589,7 @@ class ModelRunner:
                     guided_dev=guided_dev,
                 )
             except BucketOverflowError as e:
-                if masks is not None or mask_fn is not None \
-                        or biases is not None or guided_dev is not None:
+                if constrained:
                     # the padded fallback has no mask/bias plane; the
                     # engine sheds chunks and retries rather than dropping
                     # a guided row's constraint or a bias ban
@@ -1734,19 +1598,31 @@ class ModelRunner:
                     "mixed plan (%d tokens) overflows ragged T buckets "
                     "(largest %d); using the padded fallback", e.n, e.largest,
                 )
-        elif masks is not None or mask_fn is not None or biases is not None \
-                or guided_dev is not None:
+        elif constrained:
             raise NotImplementedError(
                 "guided masks / logit bias require the ragged mixed path "
-                "(the engine's _mixed_fusible gates on it)"
+                "(can_fuse gates on it)"
             )
+        n_dec = len(positions)
         with annotate("engine.stage"):
             chunk_half = self._prep_prefill_packed(chunks)
-            decode_half = self._stage_padded_decode_half(
-                tokens, positions, page_tables, sampling, step, adapters)
-        return self._dispatch_padded_mixed(
-            n_steps, chunk_half, decode_half, len(positions),
-            [len(c["tokens"]) for c in chunks])
+            B = _next_bucket(self.decode_buckets, n_dec)
+            tok_dev, packed_dev = self._stage_decode_rows(
+                tokens, positions, page_tables, step, adapters, B)
+            samp = self._device_sampling(sampling, B)
+        kw = {}
+        if self.routed:  # rows past the real chunks replicate row 0
+            kw["prows"] = jnp.int32(len(chunks))
+        toks, _, chunk_logits, self.k_pool, self.v_pool, *routed = self._jit_mixed(
+            n_steps, self.params, *chunk_half, tok_dev, packed_dev,
+            self.k_pool, self.v_pool, samp, self.lora, **kw,
+        )
+        self._note_routed(routed, 1 + n_steps, n_dec,
+                          [len(c["tokens"]) for c in chunks])
+        with annotate("engine.readback"):
+            sampled = np.asarray(self._readback(toks))
+            rows = _chunk_rows(chunk_logits, len(chunks))
+        return MixedOut(sampled, rows, False)
 
     # -- guided sampling masks --------------------------------------------
     def _true_mask(self, rows: int) -> jax.Array:
@@ -1766,9 +1642,7 @@ class ModelRunner:
         all-allowed); None = the cached all-True operand."""
         if masks is None:
             return self._true_mask(seg_cap)
-        m = np.ones((seg_cap, self.config.vocab_size), bool)
-        m[: masks.shape[0]] = masks
-        return jnp.asarray(m)
+        return self._pad_rows(masks, seg_cap, True, bool)
 
     def _zero_bias(self, rows: int) -> jax.Array:
         """Device-resident all-zero [rows, V] logit bias — the cached
@@ -1785,9 +1659,7 @@ class ModelRunner:
         rows zero); None = the cached all-zero operand."""
         if biases is None:
             return self._zero_bias(seg_cap)
-        b = np.zeros((seg_cap, self.config.vocab_size), np.float32)
-        b[: biases.shape[0]] = biases
-        return jnp.asarray(b)
+        return self._pad_rows(biases, seg_cap, 0.0, np.float32)
 
     def _identity_rows(self, seg_cap: int) -> Tuple[jax.Array, jax.Array]:
         """Cached identity (row_seq, row_j) maps: non-verify ragged
@@ -1938,11 +1810,10 @@ class ModelRunner:
         masks: Optional[np.ndarray] = None,
         mask_fn=None,
         biases: Optional[np.ndarray] = None,
-        guided_dev=None,  # device guided DFA plan (decode_multi_async):
-        # step 0 rides the ragged mask operand (`masks`), the fused tail
-        # rides the in-XLA advance with pending=True (tok0 was sampled
-        # on device and is not yet folded into the row states)
-    ) -> Tuple[np.ndarray, jax.Array]:
+        guided_dev=None,  # device guided DFA plan (decode_multi): step 0
+        # rides the ragged mask operand (`masks`), the fused tail rides
+        # the in-XLA advance
+    ) -> MixedOut:
         """Ragged mixed iteration, two dispatches with T-bucket-only and
         decode-bucket-only compile keys respectively:
         1. _ragged_step: flat forward over [T] (decode step 0 + all
@@ -1950,8 +1821,7 @@ class ModelRunner:
         2. steps 1..n-1 through the UNCHANGED _decode_loop, chained on
            the step-0 tokens (positions/step advanced by one, so row
            seeds and step indices match the legacy fused loop exactly).
-        Returns the same (sampled [B_bucket, n_steps] host, chunk logits
-        [N, V] device) contract as decode_multi_with_prefills."""
+        Returns decode_multi_with_prefills' MixedOut."""
         n_dec = len(positions)
         with annotate("engine.stage"):
             (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
@@ -1975,30 +1845,19 @@ class ModelRunner:
         chunk_logits = seg_logits[n_dec : n_dec + len(chunks)]  # [N, V]
         if n_steps > 1:
             with annotate("engine.stage"):
-                pt = self._pad_page_table(page_tables, B)
-                MP = pt.shape[1]
-                packed = np.zeros(B * (1 + MP) + 1, np.int32)
-                packed[:B] = -1
-                packed[:n_dec] = [p + 1 for p in positions]
-                packed[B : B + B * MP] = pt.ravel()
-                packed[-1] = step + 1
-                mkw = {}
-                if mask_fn is not None:
-                    # guided rows continue through the fused tail: the host
-                    # callback advances each DFA copy by tok0 (still device-
-                    # resident here) before masking inner step 0
-                    mask_fn.B = B
-                    self.set_guided_ctx(mask_fn)
-                    mkw["mask_fn"] = self._mask_tramp
-                elif guided_dev is not None:
-                    g_tables, g_rows, _ = guided_dev
-                    mkw["guided"] = self._guided_op((g_tables, g_rows, True), B)
-                bias_dev = None
-                if biases is not None:
-                    bz = np.zeros((B, self.config.vocab_size), np.float32)
-                    bz[: biases.shape[0]] = biases
-                    bias_dev = jnp.asarray(bz)
-                packed_dev = jnp.asarray(packed)
+                # guided rows continue through the fused tail: tok0 was
+                # sampled on the device and is not yet folded into the row
+                # states, so the host callback advances each DFA copy by it
+                # before masking inner step 0, and the device plan runs
+                # with pending set
+                if guided_dev is not None:
+                    guided_dev = (guided_dev[0], guided_dev[1], True)
+                mkw = self._guided_kw(mask_fn, guided_dev, B)
+                bias_dev = (None if biases is None
+                            else self._pad_rows(biases, B, 0.0, np.float32))
+                tok0, packed_dev = self._stage_decode_rows(
+                    tok0, [p + 1 for p in positions], page_tables, step + 1,
+                    None, B)
                 samp = self._device_sampling(sampling, B)
             # n_steps is the scheduler's fixed multi-step count, so
             # n_steps-1 adds exactly ONE decode_loop variant alongside the
@@ -2012,13 +1871,15 @@ class ModelRunner:
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
             with annotate("engine.readback"):
                 tok0_h, rest_h = self._readback((tok0, rest))
+                rows = _chunk_rows(chunk_logits, len(chunks))
             toks = np.concatenate(
                 [np.asarray(tok0_h)[:, None], np.asarray(rest_h)], axis=1
             )
         else:
             with annotate("engine.readback"):
                 toks = np.asarray(self._readback(tok0))[:, None]
-        return toks, chunk_logits
+                rows = _chunk_rows(chunk_logits, len(chunks))
+        return MixedOut(toks, rows, True)
 
     def verify_spec(
         self,
@@ -2034,7 +1895,7 @@ class ModelRunner:
         # (guided rows never draft, so exactly one position each)
         biases: Optional[Dict[int, np.ndarray]] = None,  # row index ->
         # [V] f32 logit-bias row, same draft-less single-position contract
-    ) -> Tuple[List[np.ndarray], jax.Array]:
+    ) -> MixedOut:
         """One speculative-verify iteration through the SAME _jit_ragged
         program as the mixed path — zero new compile families or
         variants, by construction.
@@ -2188,7 +2049,7 @@ class ModelRunner:
         else:
             chunk_logits = []  # no slice at all: a zero-length take would
             # still stage its bounds and trip the strict transfer guard
-        return out, chunk_logits
+        return MixedOut(out, chunk_logits, True)
 
     # -- device n-gram draft ring ------------------------------------------
     def ensure_draft_ring(
@@ -2276,6 +2137,9 @@ class ModelRunner:
             d_h, n_h = jax.device_get((drafts, n_prop))
         return np.asarray(d_h), np.asarray(n_h)
 
+    def compile_families(self) -> Dict[str, _CompiledFamily]:
+        return self._families
+
     def compile_stats(self) -> Dict[str, Dict[str, Any]]:
         """Per step-function family, and `other` for every program no
         family saw: compiled-variant count, cumulative compile seconds,
@@ -2354,7 +2218,6 @@ class ModelRunner:
         return self.draft_config is not None
 
     # -- multi-LoRA registry ------------------------------------------------
-    @property
     def adapter_names(self) -> List[str]:
         return list(self._adapter_slots)
 

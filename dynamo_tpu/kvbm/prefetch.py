@@ -325,14 +325,14 @@ class PrefetchManager:
         self._pump()
 
     def _sim_runner(self) -> bool:
-        return not hasattr(self.engine.runner, "export_pages_device")
+        return not self.engine.runner.holds_kv
 
     def _tier_byte_ratio(self) -> float:
         """Stored-bytes scale for hash-only (sim) budget charges: 1.0 for
         dense tiers, the int8+scales ratio when the tier quantizes."""
         if not getattr(self.tiered.host, "quantize", False):
             return 1.0
-        shape = getattr(self.engine.runner, "kv_page_shape", None)
+        shape = self.engine.runner.kv_page_shape
         if shape:
             return quantized_ratio(int(shape[-1]))
         return quantized_ratio(128)

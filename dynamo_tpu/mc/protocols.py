@@ -21,6 +21,7 @@ import asyncio
 import logging
 from typing import Any, Dict, List, Optional
 
+from dynamo_tpu.engine.runner_api import Runner
 from dynamo_tpu.mc.faults import Fault, cancel_task
 from dynamo_tpu.mc.spec import (
     InvariantViolation,
@@ -187,8 +188,8 @@ class _FakeInbox:
             self.env.loop.call_soon(self.mgr.on_disk_read, *payload)
 
 
-class _SimRunner:
-    # no export_pages_device attr => PrefetchManager runs in sim mode
+class _SimRunner(Runner):
+    # holds_kv stays False => PrefetchManager runs in sim mode
     def import_pages(self, pages, seq, payload) -> None:
         pass
 

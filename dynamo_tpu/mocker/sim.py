@@ -3,7 +3,7 @@
 The mocker philosophy mirrors the reference (lib/mocker/src/lib.rs:4-9):
 run the REAL scheduling stack — PagePool prefix caching, continuous-batching
 Scheduler, KV events, FPM — and fake only the accelerator. SimRunner
-implements ModelRunner's interface (prefill / decode_multi / sample_one),
+implements the engine's Runner (engine/runner_api.py) as ModelRunner does,
 sleeping per a linear step-time model instead of dispatching XLA programs,
 so router/planner/frontend tests and CI run with zero TPUs while exercising
 every byte of the orchestration path.
@@ -27,6 +27,8 @@ from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
+
+from dynamo_tpu.engine.runner_api import MixedOut, Runner
 
 
 @dataclass
@@ -279,13 +281,16 @@ def _sim_token(seed: int, position: int, vocab: int = 50000) -> int:
     return (seed * 1103515245 + position * 2654435761) % (vocab - 16) + 16
 
 
-class SimRunner:
+class SimRunner(Runner):
     """Drop-in for ModelRunner inside InferenceEngine (no JAX)."""
 
     # guided rows ride full multi-step loops: decode_multi honors the
     # engine's host-callback mask context between fused steps, so the
     # scheduler never collapses a constrained plan to n_steps=1
     guided_fused = True
+    has_verify_spec = True
+    has_draft_ring = True
+    has_prefill_packed = True
 
     def __init__(
         self,
@@ -341,7 +346,10 @@ class SimRunner:
         self._onboard_ready_t = 0.0
         self._onboard_rest_s = 0.0
 
-    # -- ModelRunner interface ---------------------------------------------
+    def charged_tokens(self) -> int:
+        return self.stats["packed_tokens_charged"]
+
+    # -- Runner steps ------------------------------------------------------
     def prefill(self, tokens: List[int], start_pos: int, page_table_row, prior_len: int, adapter: int = 0, mm=None):
         t = self.timing
         self.stats["prefill_tokens_real"] += len(tokens)
@@ -389,11 +397,17 @@ class SimRunner:
                 tok = int(allowed[tok % len(allowed)])
         return tok
 
+    def sample_one_ex(self, logits, sampling, step: int, history=None,
+                      n_logprobs: int = -1, mask=None):
+        # no penalties and no logprob report in the sim: the plain token
+        return self.sample_one(logits, sampling, step, mask=mask), None
+
     def decode_multi(
         self, n_steps: int, tokens: List[int], positions: List[int],
         page_tables, sampling, step: int, adapters=None, masks=None,
-        mask_fn=None, guided_dev=None,
-    ) -> np.ndarray:
+        mask_fn=None, guided_dev=None, n_logprobs: int = -1,
+        histories=None, prompt_lens=None,
+    ):
         t = self.timing
         t.sleep(
             t.dispatch_overhead_s
@@ -449,7 +463,8 @@ class SimRunner:
                         tok = int(allowed[tok % len(allowed)])
                 out[i, j] = tok
                 prev[i] = tok
-        return out
+        # no penalties and no logprob report in the sim (see sample_one_ex)
+        return (out, None) if n_logprobs >= 0 else out
 
     # -- speculative decoding (n-gram / oracle drafting) --------------------
     def spec_draft(self, last_token: int, pos: int, k: int):
@@ -603,10 +618,7 @@ class SimRunner:
             toks = c["tokens"]
             seed = toks[-1] if toks else 0
             chunk_logits.append(("sim-logits", seed, c["start"] + len(toks)))
-        return rows, chunk_logits
-
-    def decode(self, tokens, positions, page_tables, kv_lens, sampling, step):
-        return self.decode_multi(1, tokens, positions, page_tables, sampling, step)[:, 0]
+        return MixedOut(rows, chunk_logits, t.prefill_cost == "ragged")
 
     def copy_pages(self, src: int, dst: int) -> None:
         """Fork-on-branch CoW page duplication — pure billing in the sim

@@ -41,6 +41,8 @@ from typing import Any, Dict, List, Optional
 import msgpack
 import numpy as np
 
+from dynamo_tpu.engine.runner_api import DEVICE_STEPS, MixedOut, Runner
+
 log = logging.getLogger("dynamo_tpu.multihost")
 
 _HDR = struct.Struct("<I")
@@ -237,39 +239,34 @@ def follower_connect(host: str, port: int, process_id: int,
 # replayed prefill produced the bit-identical replicated value.
 _PREV_LOGITS = "__prev_logits__"
 
-# Methods whose execution must happen on every process (they enqueue XLA
-# programs / mutate device state). Everything else (adapter_slot,
-# kv_pool_bytes, pools_deleted...) is host-local bookkeeping.
-REPLICATED_METHODS = (
-    "prefill",
-    "draft_prefill",
-    "sample_one",
-    "sample_one_ex",
-    "decode_multi",
-    "decode_multi_ex",
-    "spec_decode_multi",
-    "embed",
-    "import_pages",
-    "export_pages",
-    "reset_kv_pools",
-    "register_adapter",
-)
+
+_COLOCATED_ONLY = ("export_pages_device", "import_pages_device")
 
 
-class ReplicatingRunner:
+class ReplicatingRunner(Runner):
     """Wraps the leader's ModelRunner: broadcast first, then execute
-    locally. Device-array arguments cannot cross the wire — the only one
-    the engine passes is prefill logits into sample_one, replaced by the
+    locally. What is broadcast is what the declared door marks as a
+    device step (engine/runner_api.DEVICE_STEPS: the methods that enqueue
+    XLA programs or change device state, so that every process must run
+    them); every other name (adapter_slot, kv_pool_bytes, pools_deleted,
+    the facts...) is host-local and reads through to the wrapped runner.
+    Device-array arguments cannot cross the wire — the only ones the
+    engine passes are logits into sample_one, replaced by the
     _PREV_LOGITS sentinel (the follower substitutes its own replica)."""
 
     def __init__(self, runner, plane: StepPlaneLeader):
         self._runner = runner
         self._plane = plane
 
-    def __getattr__(self, name):
-        attr = getattr(self._runner, name)
-        if name not in REPLICATED_METHODS:
+    def __getattribute__(self, name):
+        # every public name belongs to the wrapped runner, the base's
+        # defaults included: this class only stands in the door
+        if name.startswith("_") or name in _COLOCATED_ONLY:
+            return object.__getattribute__(self, name)
+        attr = getattr(object.__getattribute__(self, "_runner"), name)
+        if name not in DEVICE_STEPS:
             return attr
+        plane = object.__getattribute__(self, "_plane")
 
         def call(*args, **kwargs):
             import jax
@@ -277,14 +274,10 @@ class ReplicatingRunner:
             wire_args = tuple(
                 _PREV_LOGITS if isinstance(a, jax.Array) else a for a in args
             )
-            self._plane.broadcast(name, wire_args, kwargs)
+            plane.broadcast(name, wire_args, kwargs)
             return attr(*args, **kwargs)
 
         return call
-
-    def decode(self, tokens, positions, page_tables, kv_lens, sampling, step):
-        out = self.decode_multi(1, tokens, positions, page_tables, sampling, step)
-        return out[:, 0]
 
     # device-handle paths are colocated-process-only by construction; a
     # multi-process group must use the host-staged wire format
@@ -325,6 +318,8 @@ def follower_loop(runner, sock: socket.socket) -> None:
             continue
         if method == "prefill":
             last_logits = out
+        elif isinstance(out, MixedOut) and len(out[1]):
+            last_logits = out[1][-1]  # a mixed step's chunks sample next
 
 
 # -- worker-group entrypoint helpers -----------------------------------------
@@ -409,7 +404,7 @@ def selftest_main(argv=None) -> None:
     tok0 = runner.sample_one(logits, s, 0)
     runner.decode_multi(2, [tok0], [5], [[0, 1, 2]], s, 1)
     if args.axis == "pipe":
-        # each process is one GPipe stage; the _ex sampling extras are not
+        # each process is one GPipe stage; the sampling extras are not
         # wired on the PP path, so the group signature is the plain tokens
         out = runner.decode_multi(3, [tok0], [7], [[0, 1, 2]], s, 3)
         payload = runner.export_pages([0, 1])  # replicated-gather path
@@ -418,13 +413,13 @@ def selftest_main(argv=None) -> None:
         print(f"MULTIHOST_SELFTEST pipe {[tok0] + out[0].tolist()}{guard}",
               flush=True)
         return
-    # ... then the _ex variants (penalties + logprobs), REPLICATED_METHODS
-    # too — group replay must cover the paths the engine prefers whenever
-    # a request carries logprobs/penalties
+    # ... then the sampling extras (penalties + logprobs) — group replay
+    # must cover the paths the engine takes whenever a request carries
+    # logprobs/penalties
     tok, lp1 = runner.sample_one_ex(
         logits, s, 0, history=[1, 2, 3, 4, 5], n_logprobs=2
     )
-    out, lp = runner.decode_multi_ex(
+    out, lp = runner.decode_multi(
         3, [tok], [7], [[0, 1, 2]], s, 3,
         n_logprobs=2, histories=[[1, 2, 3, 4, 5, tok]], prompt_lens=[5],
     )

@@ -288,13 +288,15 @@ class DigestBuilder:
                     k: v for k, v in getattr(pf, "stats", {}).items()
                 }
             runner = getattr(engine, "runner", None)
-            if hasattr(runner, "compile_stats"):
+            if runner is not None:
                 try:
-                    digest["compile"] = {
-                        fam: {"variants": st.get("variants", 0),
-                              "calls": st.get("calls", 0)}
-                        for fam, st in runner.compile_stats().items()
-                    }
+                    stats = runner.compile_stats()
+                    if stats:  # a runner that compiles nothing: no block
+                        digest["compile"] = {
+                            fam: {"variants": st.get("variants", 0),
+                                  "calls": st.get("calls", 0)}
+                            for fam, st in stats.items()
+                        }
                 except Exception:
                     log.debug("compile stats probe failed", exc_info=True)
             spec = getattr(engine, "spec_stats", None)

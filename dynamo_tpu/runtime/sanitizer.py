@@ -14,7 +14,7 @@ in the AST; this module is the dynamic complement, armed with
   itself a violation, so the allowlist IS the documentation of every
   sanctioned transfer (see docs/static_analysis.md).
 - **recompile tripwire** — after ``warmup_steps`` engine iterations the
-  compiled-family variant counts (`ModelRunner._families`) must be
+  compiled-family variant counts (`ModelRunner.compile_families()`) must be
   frozen; any new family or variant afterwards is a compile-cache leak
   (shape churn) and fires a violation.
 - **lock-order recorder** — :meth:`wrap_lock` proxies a lock and records
@@ -283,10 +283,8 @@ class Sanitizer:
         `warmup_steps`; any later growth is a compile-cache leak."""
         self._steps += 1
         self.counters["steps"] = self._steps
-        fams = getattr(runner, "_families", None)
-        variants = (
-            {name: fam.variants for name, fam in fams.items()} if fams else {}
-        )
+        fams = runner.compile_families() if runner is not None else {}
+        variants = {name: fam.variants for name, fam in fams.items()}
         if not self._warm:
             if self._steps >= self.warmup_steps:
                 self.mark_warm()

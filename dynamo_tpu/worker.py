@@ -634,7 +634,7 @@ async def async_main(args) -> None:
                 lambda last_n=None: to_chrome_trace(_rec.snapshot(last_n))
             )
         _runner = getattr(engine, "runner", None)
-        if hasattr(_runner, "device_report"):
+        if _runner is not None and _runner.device_report():
             # GET /debug/device: the platform, devices and dispatch paths
             # actually in effect (chip_smoke.py asserts on it — a worker
             # that came up on the CPU must not pass for one on the chip)

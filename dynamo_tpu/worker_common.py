@@ -79,8 +79,8 @@ class DisaggDecodeAdapter:
         if (
             local is not None
             and local is not self.engine
-            and hasattr(local.runner, "export_pages_device")
-            and hasattr(self.engine.runner, "import_pages_device")
+            and local.runner.holds_kv
+            and self.engine.runner.holds_kv
         ):
             # device-resident transfer: gather on the prefill engine's step
             # thread, scatter on ours — no bytes touch the host
@@ -316,10 +316,10 @@ async def serve_worker(
             if not name:
                 yield {"error": "load_adapter needs 'name'"}
                 return
-            if runner is None or getattr(runner, "lora", None) is None:
+            if runner is None or runner.lora is None:
                 yield {"error": "worker built without --lora slots"}
                 return
-            if name in getattr(runner, "_adapter_slots", {}):
+            if name in runner.adapter_names():
                 # register_adapter would return the existing slot WITHOUT
                 # touching its factors — reporting success while serving
                 # stale weights. Make rollover explicit: new name, or
@@ -437,7 +437,7 @@ async def serve_worker(
     # when someone scrapes after a step completed. The ragged mixed path's
     # cardinality collapse (variants <= |T buckets|) is read off these.
     _runner = getattr(engine, "runner", None)
-    if hasattr(_runner, "compile_stats"):
+    if _runner is not None and _runner.compile_stats():
         _cm = runtime.metrics.child(dynamo_namespace=namespace)
 
         def _update_compile_gauges(_m=None) -> None:
@@ -460,7 +460,7 @@ async def serve_worker(
     # (IterationRecord.moe_*; docs/observability.md "Routed experts") as
     # two gauges of the newest iteration and one running total. Only a
     # worker whose step programs hand out the picks has the series.
-    if getattr(_runner, "routed", False) and hasattr(engine, "moe_totals"):
+    if _runner is not None and _runner.routed:
         _mm = runtime.metrics.child(dynamo_namespace=namespace)
         _moe_sent = {"slots": 0}
 

@@ -330,7 +330,7 @@ async def test_engine_stale_layout_kv_import_recomputes(tiny_engine):
 
 async def test_fused_mixed_dispatch_matches_sequential(monkeypatch):
     """Concurrent requests drive MixedPlan through the FUSED single-
-    dispatch path (runner.decode_multi_with_prefill); greedy outputs must
+    dispatch path (runner.decode_multi_with_prefills); greedy outputs must
     be identical to each prompt served alone (scheduling must never
     change results), and the fused path must actually engage. (Fusion
     defaults off on cpu — forced on here.)"""
@@ -354,14 +354,14 @@ async def test_fused_mixed_dispatch_matches_sequential(monkeypatch):
                                  mixed_prefill_tokens=8)
         engine.start()
         fused_calls = 0
-        orig = runner.decode_multi_with_prefill
+        orig = runner.decode_multi_with_prefills
 
         def counting(*a, **k):
             nonlocal fused_calls
             fused_calls += 1
             return orig(*a, **k)
 
-        runner.decode_multi_with_prefill = counting
+        runner.decode_multi_with_prefills = counting
         try:
             async def one(p):
                 toks = []
@@ -429,12 +429,12 @@ def test_uncapped_generation_stops_at_model_context():
     eng.stop()
 
 
-def test_decode_multi_async_chains_without_intermediate_readback():
-    """Double-buffered dispatch primitive: dispatch N+1 may consume
-    dispatch N's `last` DEVICE array as its token input — two chained
-    async dispatches with ONE readback at the end must produce the same
-    stream as one fused dispatch of the combined length, and a chained
-    array whose bucket does not match must be rejected loudly."""
+def test_ragged_tail_chains_on_device_tokens_without_readback():
+    """A mixed iteration on the ragged path is two dispatches: the ragged
+    step samples step 0, the decode loop runs steps 1..n-1. The loop must
+    take the step-0 tokens as the DEVICE array the ragged step produced —
+    nothing read back, nothing re-staged from the host between the two —
+    and the one readback comes after both are enqueued."""
     import jax
     import numpy as np
     from dynamo_tpu.engine.model_runner import ModelRunner
@@ -443,6 +443,7 @@ def test_decode_multi_async_chains_without_intermediate_readback():
     runner = ModelRunner(get_config("tiny"), num_pages=64, page_size=4,
                          max_pages_per_seq=16, decode_buckets=(1, 2, 4),
                          prefill_buckets=(8, 16), seed=3)
+    assert runner.ragged_mixed
     prompts = [[5, 6, 7, 8], [9, 1, 2, 3]]
     samp = {"temperature": [0.0, 0.0], "top_k": [0, 0],
             "top_p": [1.0, 1.0], "seeds": [11, 12]}
@@ -453,25 +454,36 @@ def test_decode_multi_async_chains_without_intermediate_readback():
         pts.append(pt)
         first.append(int(np.argmax(np.asarray(logits))))
     positions = [len(p) for p in prompts]
+    chunk = {"tokens": [3, 1, 4, 1, 5], "start": 0, "table": [8, 9],
+             "prior": 0, "adapter": 0}
 
-    # one fused 8-step dispatch (the reference stream)
-    want = runner.decode_multi(
-        8, first, positions, pts, samp, 0)[:2, :]
+    events = []
 
-    # two chained 4-step async dispatches, no host sync in between
-    toks_a, last = runner.decode_multi_async(
-        4, first, positions, pts, samp, 0)
-    assert isinstance(last, jax.Array)
-    toks_b, _ = runner.decode_multi_async(
-        4, last, [p + 4 for p in positions], pts, samp, 4)
-    got = np.concatenate(
-        [np.asarray(jax.device_get(t))[:2] for t in (toks_a, toks_b)],
-        axis=1)
-    assert (got == np.asarray(want)).all(), (got, want)
+    def spy(fam, name):
+        fn = fam._fn
 
-    # a chained array from a different bucket must fail loudly, not
-    # silently re-bucket (the pipeline contract is a stable bucket)
-    with pytest.raises(ValueError, match="bucket"):
-        runner.decode_multi_async(2, last, [positions[0] + 4],
-                                  [pts[0]], {"temperature": [0.0], "top_k": [0],
-                                             "top_p": [1.0], "seeds": [11]}, 4)
+        def call(*a, **k):
+            events.append((name, a))
+            return fn(*a, **k)
+
+        call._cache_size = fn._cache_size
+        fam._fn = call
+
+    spy(runner._jit_ragged, "ragged")
+    spy(runner._jit_decode_loop, "decode_loop")
+    readback = runner._readback
+    runner._readback = lambda x: (events.append(("readback", ())), readback(x))[1]
+
+    # an implicit device-to-host transfer anywhere in the call would raise
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = runner.decode_multi_with_prefills(
+            4, first, positions, pts, samp, 0, [chunk])
+    toks, rows = out
+    assert out.ragged and toks.shape == (2, 4) and len(rows) == 1
+    assert [e[0] for e in events] == ["ragged", "decode_loop", "readback"]
+    n_steps, _, _, tokens0 = events[1][1][:4]
+    assert n_steps == 3
+    assert isinstance(tokens0, jax.Array) and tokens0.shape == (2,)
+    # the chained loop continues the stream a single fused loop gives
+    want = runner.decode_multi(4, first, positions, pts, samp, 0)[:2]
+    assert (toks == want).all(), (toks, want)

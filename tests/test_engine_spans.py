@@ -232,10 +232,9 @@ class _LowerSpy:
 def test_step_programs_carry_their_names(monkeypatch):
     """Each family's lowered module is `jit_<family function>`, none
     `jit__unknown`, and so are the un-familied jits of the serving path."""
-    monkeypatch.setenv("DYN_RAGGED_MIXED", "1")
     r = _runner()
     spies = {}
-    for name, fam in r._families.items():
+    for name, fam in r.compile_families().items():
         spies[name] = fam._fn = _LowerSpy(fam._fn)
     pts = [list(range(i * 4, (i + 1) * 4)) for i in range(3)]
     samp = {"temperature": [0.0], "top_k": [0], "top_p": [1.0], "seeds": [1]}
@@ -322,7 +321,7 @@ def test_family_counts_programs_not_call_signatures():
     7, PR 25); a new bucket still counts."""
     r = _runner()
     samp = {"temperature": [0.0], "top_k": [0], "top_p": [1.0], "seeds": [1]}
-    fam = r._families["decode_loop"]
+    fam = r.compile_families()["decode_loop"]
 
     def step(tokens):
         n = len(tokens)

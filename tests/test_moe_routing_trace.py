@@ -308,7 +308,7 @@ def test_dense_programs_return_what_they_returned(fused):
         mix = jax.eval_shape(
             lambda *a, **k: runner_mod._mixed_loop(config, "jnp", None, 2, *a, **k),
             r.params, ptok, ptok, jnp.zeros((1, MP), jnp.int32), jnp.ones(1, jnp.int32),
-            jnp.int32(7), None, jnp.zeros(B, jnp.int32), packed, r.k_pool, r.v_pool, sp,
+            jnp.full(1, 7, jnp.int32), None, jnp.zeros(B, jnp.int32), packed, r.k_pool, r.v_pool, sp,
             **({"prows": jnp.int32(1)} if extra else {}))
         assert len(mix) == 5 + extra
         fwd = jax.eval_shape(lambda *a: r._jit_forward._fn(*a, attn_impl="jnp"),

@@ -131,9 +131,9 @@ async def test_multihost_group_matches_single_process(tmp_path):
         await _wait_line(ref, "worker serving")
         frt, svc, base = await _http_stack(droot_ref)
         ref_body = await _completion(base, prompt, max_tokens=6)
-        # penalties+logprobs route through decode_multi_ex/sample_one_ex,
-        # which must be REPLICATED_METHODS (ADVICE r3 high): a group whose
-        # leader runs the _ex programs alone deadlocks on the collectives
+        # penalties+logprobs route through decode_multi's extras and
+        # sample_one_ex, which the group must replay (ADVICE r3 high): a
+        # leader that runs those programs alone deadlocks on the collectives
         ref_ex = await _completion(
             base, prompt, max_tokens=6, frequency_penalty=0.5, logprobs=2
         )

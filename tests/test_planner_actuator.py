@@ -479,12 +479,12 @@ def test_engine_retune_clamps_to_compile_time_commitments():
         ["--speed", "0", "--mixed-prefill-tokens", "256",
          "--spec-ngram", "--spec-k", "4"]))
     try:
-        # SimRunner has no ragged-bucket registry: tokens move freely
+        # SimRunner compiles no bucket (static_shapes): tokens move freely
         out = engine.retune(mixed_prefill_tokens=512, spec_k=2)
         assert out["mixed_prefill_tokens"] == 512 and out["spec_k"] == 2
         assert engine.scheduler.mixed_prefill_tokens == 512
         # a compiled runner caps tokens at the init-registered bucket
-        engine.runner.ensure_ragged_bucket = lambda n: None
+        engine.runner.static_shapes = True
         out = engine.retune(mixed_prefill_tokens=100000)
         assert out["mixed_prefill_tokens"] == 256
         # a device-draft runner caps K at the init ring size

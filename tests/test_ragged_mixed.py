@@ -37,12 +37,14 @@ def test_next_bucket_overflow_error():
 
 
 def _mk_runner(monkeypatch, ragged):
-    monkeypatch.setenv("DYN_RAGGED_MIXED", "1" if ragged else "0")
-    return ModelRunner(
+    r = ModelRunner(
         get_config("tiny"), num_pages=96, page_size=4,
         max_pages_per_seq=16, decode_buckets=(1, 2, 4),
         prefill_buckets=(8, 16), seed=7,
     )
+    if not ragged:
+        r.ragged_mixed = False  # the padded [N, S] program
+    return r
 
 
 def _run_mixed_plan(r):
@@ -166,6 +168,39 @@ async def test_engine_ragged_dispatch_byte_identity(monkeypatch):
     conc = await _serve(r, concurrent=True)
     assert solo == conc, (solo, conc)
     assert ragged_calls > 0, "burst never engaged the ragged program"
+
+
+@pytest.mark.parametrize("ragged", [True, False])
+def test_iteration_record_says_which_mixed_program_ran(monkeypatch, ragged):
+    """The flight record's `ragged` flag comes from the runner's return:
+    a single-chunk mixed iteration records ragged=True on a ragged runner
+    (it read False until PR 30: the engine guessed from the chunk count)
+    and False on a padded one."""
+    import time
+
+    from dynamo_tpu.engine.engine import InferenceEngine
+    from dynamo_tpu.engine.scheduler import Sequence
+
+    monkeypatch.setenv("DYN_FUSED_MIXED", "1")
+    engine = InferenceEngine(_mk_runner(monkeypatch, ragged), max_batch=4,
+                             chunk_size=8, mixed_prefill_tokens=8)
+
+    def add(rid, prompt):
+        engine._inbox.put(("add", Sequence(
+            request_id=rid, prompt=prompt, sampling={"temperature": 0.0},
+            stop={"max_tokens": 16, "stop_ids": []},
+            arrival=time.monotonic())))
+
+    add("a", _PROMPTS[0])
+    engine._loop_once()  # a's prefill
+    engine._loop_once()  # a decodes alone
+    add("b", _PROMPTS[1])
+    engine._loop_once()  # a's decode + b's only chunk, one dispatch
+    engine._flush_late_record()
+    rec = engine.recorder.snapshot()[-1]
+    assert (rec.kind, rec.fused, rec.n_chunks, rec.decode_seqs) == (
+        "mixed", True, 1, 1), rec
+    assert rec.ragged is ragged
 
 
 async def test_engine_pack_overflow_defers_chunks(monkeypatch):

@@ -124,6 +124,9 @@ class _FakeRunner:
     def __init__(self):
         self._families = {"decode": _Fam(2), "prefill": _Fam(3)}
 
+    def compile_families(self):
+        return self._families
+
 
 def test_recompile_tripwire_fires_once_per_leak():
     san = Sanitizer(strict=False, transfer_guard=False, warmup_steps=2)
@@ -156,7 +159,9 @@ def test_recompile_tripwire_strict_raises_and_sim_runner_noop():
     with pytest.raises(SanitizerViolation, match="recompile"):
         san.note_step(r)
 
-    class _NoFamilies:  # SimRunner has no _families: tripwire must no-op
+    from dynamo_tpu.engine.runner_api import Runner
+
+    class _NoFamilies(Runner):  # a SimRunner compiles nothing: a no-op
         pass
 
     san2 = Sanitizer(strict=True, transfer_guard=False, warmup_steps=1)

@@ -493,7 +493,6 @@ async def test_real_runner_spec_byte_identity_and_zero_new_variants(
     """Tentpole acceptance: n-gram speculation on the REAL runner rides
     the existing ragged program — greedy outputs byte-identical to plain
     decoding and ZERO new compile families/variants vs spec-off."""
-    monkeypatch.setenv("DYN_RAGGED_MIXED", "1")
     monkeypatch.setenv("DYN_FUSED_MIXED", "1")
     prompts = [[4, 2] * 4, [9, 8, 7, 1] * 2, [1, 2, 3] * 3]
 
