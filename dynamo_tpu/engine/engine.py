@@ -42,6 +42,7 @@ from dynamo_tpu.engine.ngram_draft import (
     propose_tree as ngram_propose_tree,
 )
 from dynamo_tpu.frontend.protocols import engine_output
+from dynamo_tpu.models.config import mean_over_layers
 from dynamo_tpu.runtime.annotations import annotate
 from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.flight_recorder import FlightRecorder, IterationRecord
@@ -1027,6 +1028,8 @@ class InferenceEngine:
         if _dseqs and self.recorder.enabled:
             rinfo["pages_live"] = self._decode_pages_live(
                 _dseqs, getattr(plan, "decode", plan).n_steps)
+            # step 0 apart: a ragged program walks it, not the decode kernel
+            rinfo["pages_step0"] = self._decode_pages_live(_dseqs, 1)
         decode_done = False
         try:
             if isinstance(plan, PrefillPlan):
@@ -1091,6 +1094,7 @@ class InferenceEngine:
                         n_chunks=len(served),
                         chunk_tokens=n_chunk_tok,
                         fused=True, ragged=out.ragged,
+                        ragged_pages_live=out.pages_live,
                     )
                     # decode tokens are emitted: from here on a failure
                     # (e.g. in a chunk's sampling extras) must only
@@ -1241,7 +1245,9 @@ class InferenceEngine:
             prefetch_hits=hits,
             compile_variants=variants,
             compile_calls=calls,
-            decode_pages_live=rinfo.get("pages_live", 0),
+            decode_pages_live=rinfo.get("pages_live", 0) - (
+                rinfo.get("pages_step0", 0) if rinfo["ragged"] else 0),
+            ragged_pages_live=rinfo.get("ragged_pages_live", 0),
             accepted_per_step=(
                 rinfo.get("spec_emitted", 0) / rinfo["spec_rows"]
                 if rinfo.get("spec_rows") else 0.0
@@ -1261,20 +1267,13 @@ class InferenceEngine:
         ps = self.pool.page_size
         c = self.runner.config
         window = c.sliding_window if c is not None else 0
-        n_global = 0
-        if window:
-            n_global = sum(l % c.sw_period == c.sw_global_residue
-                           for l in range(c.n_layers))
         full = sliding = 0
         for s in seqs:
             for n in range(s.computed_len + 1, s.computed_len + n_steps + 1):
                 last = (n - 1) // ps
                 full += last + 1
                 sliding += last - max(n - window, 0) // ps + 1
-        if not window:
-            return full
-        return round((full * n_global + sliding * (c.n_layers - n_global))
-                     / c.n_layers)
+        return mean_over_layers(c, full, sliding) if window else full
 
     def _settle_record(self, record, load) -> None:
         """Append an iteration's record (None: the recorder is off) with
@@ -2237,6 +2236,7 @@ class InferenceEngine:
             "spec_drafted": n_drafted + n_branch_tok,
             "spec_emitted": emitted_spec,
             "ragged": out.ragged,
+            "ragged_pages_live": out.pages_live,
         }
 
     def _mixed_fusible(self, plan: MixedPlan) -> bool:

@@ -36,7 +36,7 @@ from dynamo_tpu.engine.runner_api import (
 )
 from dynamo_tpu.engine.sampling import SamplingParams, sample
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import ModelConfig, mean_over_layers
 from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
 from dynamo_tpu.runtime.annotations import annotate
@@ -1796,7 +1796,22 @@ class ModelRunner(Runner):
             jnp.asarray(md["meta"]),
             jnp.asarray(gather),
             seg_cap,
+            self._ragged_pages_live(md),
         )
+
+    def _ragged_pages_live(self, md) -> int:
+        """MixedOut.pages_live of a ragged dispatch, from its host
+        metadata: the live (work unit, page) pairs one layer's kernel call
+        walks (the mean over layers where sliding and global alternate)."""
+        from dynamo_tpu.ops.ragged_paged_attention import ragged_live_pairs
+
+        def pairs(window):
+            return ragged_live_pairs(md["meta"], md["seg_kv_lens"], window,
+                                     self.page_size, self.max_pages_per_seq)
+
+        window = self.config.sliding_window
+        return mean_over_layers(self.config, pairs(0),
+                                pairs(window) if window else 0)
 
     def _decode_multi_with_prefills_ragged(
         self,
@@ -1825,7 +1840,8 @@ class ModelRunner(Runner):
         n_dec = len(positions)
         with annotate("engine.stage"):
             (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
-             seg_cap) = self._prep_ragged(tokens, positions, page_tables, chunks)
+             seg_cap, pages_live) = self._prep_ragged(
+                 tokens, positions, page_tables, chunks)
             row_seq, row_j = self._identity_rows(seg_cap)
             samp = self._device_sampling(sampling, seg_cap)
             step_dev = jnp.int32(step)
@@ -1879,7 +1895,7 @@ class ModelRunner(Runner):
             with annotate("engine.readback"):
                 toks = np.asarray(self._readback(tok0))[:, None]
                 rows = _chunk_rows(chunk_logits, len(chunks))
-        return MixedOut(toks, rows, True)
+        return MixedOut(toks, rows, True, pages_live)
 
     def verify_spec(
         self,
@@ -2049,7 +2065,7 @@ class ModelRunner(Runner):
         else:
             chunk_logits = []  # no slice at all: a zero-length take would
             # still stage its bounds and trip the strict transfer guard
-        return MixedOut(out, chunk_logits, True)
+        return MixedOut(out, chunk_logits, True, self._ragged_pages_live(md))
 
     # -- device n-gram draft ring ------------------------------------------
     def ensure_draft_ring(

@@ -33,12 +33,17 @@ class MixedOut(tuple):
     """What a step that can carry prefill chunks returns: the pair
     `(sampled, chunk_logits)` — chunk_logits one row per chunk served, in
     plan order — and `ragged`, whether the flat-token program ran it (the
-    padded [N, S] one otherwise). A pair with an attribute and not a
-    triple: benchmark/serve.py unpacks two (ROADMAP D10)."""
+    padded [N, S] one otherwise), with `pages_live`, the live (work unit,
+    page) pairs one layer's call of its attention kernel walked
+    (IterationRecord.ragged_pages_live; 0 where the padded one ran). A
+    pair with attributes and not a longer tuple: benchmark/serve.py
+    unpacks two (ROADMAP D10)."""
 
-    def __new__(cls, sampled, chunk_logits, ragged: bool):
+    def __new__(cls, sampled, chunk_logits, ragged: bool,
+                pages_live: int = 0):
         self = super().__new__(cls, (sampled, chunk_logits))
         self.ragged = bool(ragged)
+        self.pages_live = int(pages_live)
         return self
 
 

@@ -7,6 +7,21 @@ from dataclasses import dataclass, field, replace
 from typing import Dict, Optional
 
 
+def mean_over_layers(c, on_global: int, on_sliding: int) -> int:
+    """The mean over a model's layers of a count that is `on_global` on a
+    layer with global attention and `on_sliding` on one under the sliding
+    window (layer l is global iff l % sw_period == sw_global_residue;
+    every layer is without a window). For the flight recorder's
+    `*_pages_live` counters; `c` is anything with ModelConfig's four
+    window fields."""
+    if not c.sliding_window:
+        return on_global
+    n_global = sum(l % c.sw_period == c.sw_global_residue
+                   for l in range(c.n_layers))
+    return round((on_global * n_global
+                  + on_sliding * (c.n_layers - n_global)) / c.n_layers)
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny"

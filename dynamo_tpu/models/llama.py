@@ -300,23 +300,43 @@ def forward(
 
         h = lax.with_sharding_constraint(h, NamedSharding(mesh, SPEC_SEQ_ACT))
 
-    # the Pallas decode kernel walks a list of its rows' live pages, which
-    # hangs on the lengths and the window alone: built here, once a step,
+    # the Pallas decode and ragged kernels walk a list of live pages (a
+    # decode row's; a ragged work unit's), which hangs on the lengths,
+    # the positions and the window alone: built here, once a step,
     # because XLA leaves it in the layer scan's body (three small fusions
     # a layer). A model whose layers alternate sliding and global gets
     # both lists and each layer picks one.
     walk_sliding = walk_global = None
-    if attn_impl == "pallas" and S == 1 and ragged is None and not c.is_mla:
-        from dynamo_tpu.ops.paged_attention import decode_work_list
+    if attn_impl == "pallas" and not c.is_mla and (S == 1 or ragged is not None):
+        PS = jax.tree.leaves(k_pool)[0].shape[2]
+        if ragged is not None:
+            from dynamo_tpu.ops.ragged_paged_attention import ragged_work_list
 
-        walk_shape = (jax.tree.leaves(k_pool)[0].shape[2], page_table.shape[1])
+            seg_pt, seg_kvl, rmeta = ragged
+
+            def build_walk(window):
+                return ragged_work_list(
+                    rmeta, seg_kvl, window, PS, seg_pt.shape[1], S)
+        else:
+            from dynamo_tpu.ops.paged_attention import decode_work_list
+
+            def build_walk(window):
+                return decode_work_list(
+                    kv_lens, window, PS, page_table.shape[1])
+
         if c.sliding_window > 0:
-            walk_sliding = decode_work_list(
-                kv_lens, jnp.int32(c.sliding_window), *walk_shape)
+            walk_sliding = build_walk(jnp.int32(c.sliding_window))
         if c.sliding_window <= 0 or any(
                 l % c.sw_period == c.sw_global_residue
                 for l in range(c.n_layers)):
-            walk_global = decode_work_list(kv_lens, None, *walk_shape)
+            walk_global = build_walk(None)
+
+    def layer_walk(win):
+        """This layer's list: the one there is, or by the layer's window."""
+        if walk_sliding is None or walk_global is None:
+            return walk_global if walk_sliding is None else walk_sliding
+        return jax.tree.map(lambda g, s: jnp.where(win > 0, s, g),
+                            walk_global, walk_sliding)
 
     lora_layers = (lora or {}).get("layers", {})
     if lora_layers and c.is_mla:
@@ -483,12 +503,13 @@ def forward(
                     if tp:
                         attn = ragged_paged_attention_sharded(
                             qg[0], k_pool, v_pool, seg_pt, seg_kvl, rmeta,
-                            mesh, window=win, layer=l_idx, **kwr,
+                            mesh, window=win, layer=l_idx,
+                            work=layer_walk(win), **kwr,
                         )[None]
                     else:
                         attn = ragged_paged_attention(
                             qg[0], k_pool, v_pool, seg_pt, seg_kvl, rmeta,
-                            win, l_idx, **kwr,
+                            win, l_idx, layer_walk(win), **kwr,
                         )[None]  # [1, T, Hk, G, hd]
                 else:
                     # per-token B=T, S=1 rows of the canonical jnp reference;
@@ -505,11 +526,7 @@ def forward(
                 )
 
                 kwg = dict(scale=g_scale, softcap=c.attn_logit_softcap)
-                walk = walk_global if walk_sliding is None else walk_sliding
-                if walk_sliding is not None and walk_global is not None:
-                    walk = jax.tree.map(
-                        lambda g, s: jnp.where(win > 0, s, g),
-                        walk_global, walk_sliding)
+                walk = layer_walk(win)
                 if tp:
                     attn = decode_paged_attention_sharded(
                         qg[:, 0], k_pool, v_pool, page_table, kv_lens,

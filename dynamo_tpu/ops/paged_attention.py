@@ -106,43 +106,58 @@ def decode_work_list(kv_lens, window, page_size: int, max_pages: int):
     """The decode kernel's grid as a list of live pages, built in XLA from
     the lengths (and the window) alone: (work[W] int32, n_work int32).
 
-    Row b's live pages run from `lo // PS` (the first page its window
-    shows; 0 without one) to `(kv_len - 1) // PS`; a pad row (kv_len 0)
-    has none. Entry w < n_work is `row * MP + page` of the w-th live page,
-    rows in order and each row's pages ascending; W = B * MP is the static
-    bound and the entries past n_work are never visited. One packed list:
-    scalar-prefetch operands live in SMEM, where the worker's default
-    shape (bucket 64, MP 256) already keeps a 64 KB page table."""
-    B = kv_lens.shape[0]
-    first, last = _live_pages(kv_lens, window, page_size, max_pages)
-    count = jnp.where(kv_lens > 0, last - first + 1, 0)
+    A decode row is the walk's one-query case (`live_pages`): its live
+    pages run from the first its window shows (0 without one) to
+    `(kv_len - 1) // PS`; a pad row (kv_len 0) has none. W = B * MP is
+    the static bound (`work_list`)."""
+    first, last = live_pages(kv_lens - 1, kv_lens - 1, kv_lens, window,
+                             page_size, max_pages)
+    return work_list(first, jnp.where(kv_lens > 0, last - first + 1, 0),
+                     max_pages)
+
+
+def work_list(first, count, max_pages: int):
+    """A walk as one packed list, (work[W] int32, n_work int32): unit u (a
+    decode row; a ragged work unit) brings `count[u]` pages from
+    `first[u]` up. Entry w < n_work is `unit * MP + page` of the w-th
+    live (unit, page) pair, units in order and each unit's pages
+    ascending; W = U * MP is the static bound and the entries past n_work
+    are never visited (they stay inside the table all the same: an index
+    map may read one step ahead). One packed list: scalar-prefetch
+    operands live in SMEM, where the worker's default shape (bucket 64,
+    MP 256) already keeps a 64 KB page table."""
+    U = first.shape[0]
     ends = jnp.cumsum(count)
-    # row of entry w = how many rows end at or before it (a row with no
+    # unit of entry w = how many units end at or before it (a unit with no
     # live page ends where it starts and is stepped over); its page =
-    # first[row] + (w - start[row]). Both as [W, B] compares-and-sums:
+    # first[unit] + (w - start[unit]). Both as [W, U] compares-and-sums:
     # one small fusion, no gather and no sort
-    w = lax.iota(jnp.int32, B * max_pages)
+    w = lax.iota(jnp.int32, U * max_pages)
     before = w[:, None] >= ends[None, :]
-    row = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), B - 1)
-    here = lax.iota(jnp.int32, B)[None, :] == row[:, None]
+    unit = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), U - 1)
+    here = lax.iota(jnp.int32, U)[None, :] == unit[:, None]
     shift = first - (ends - count)
     page = w + jnp.sum(jnp.where(here, shift[None, :], 0), axis=1)
     page = jnp.clip(page, 0, max_pages - 1)
-    return row * max_pages + page, ends[-1]
+    return unit * max_pages + page, ends[-1]
 
 
-def _live_pages(kv_len, window, page_size: int, max_pages: int):
-    """(first, last) live page of a row that holds kv_len tokens: the
-    decode query sits at position kv_len - 1, so with a sliding window
-    only positions >= lo = kv_len - window are visible and the pages
-    wholly below lo are dead. Shared by the work list (XLA, [B] arrays)
-    and the kernel (SMEM scalars) so the two cannot drift apart. Both
-    stay inside the page table whatever kv_len says: a length past
-    MP * PS walks the table's MP pages, as the old grid did, and never
-    makes the list longer than its W entries."""
-    last = jnp.minimum(
-        _div(jnp.maximum(kv_len - 1, 0), page_size), max_pages - 1)
-    return jnp.minimum(_div(_window_lo(kv_len, window), page_size), last), last
+def live_pages(q_first, q_last, kv_len, window, page_size: int,
+               max_pages: int):
+    """(first, last) live page of a run of queries at positions q_first ..
+    q_last over a sequence that holds kv_len tokens: causality and the
+    length end the run at the page of min(q_last, kv_len - 1), and under
+    a sliding window nothing below `q_first - window + 1` is visible to
+    any of them, so the pages wholly below it are dead. THE walk's rule:
+    a decode row is the run of one query at kv_len - 1, a ragged work
+    unit the rows of one q block that belong to one segment. Shared by
+    the work lists (XLA, arrays) and the kernels (SMEM scalars) so the
+    two cannot drift apart. Both stay inside the page table whatever the
+    lengths say: a length past MP * PS walks the table's MP pages, and
+    never makes a list longer than its W entries."""
+    end = jnp.maximum(jnp.minimum(q_last, kv_len - 1), 0)
+    last = jnp.minimum(_div(end, page_size), max_pages - 1)
+    return jnp.minimum(_div(_window_lo(q_first, window), page_size), last), last
 
 
 def _div(a, b: int):
@@ -157,10 +172,11 @@ def _rem(a, b: int):
     return lax.rem(a, np.int32(b))
 
 
-def _window_lo(kv_len, window):
+def _window_lo(q_pos, window):
+    """The lowest position a query at q_pos sees (0 without a window)."""
     if window is None:
-        return jnp.zeros_like(kv_len)
-    return jnp.where(window > 0, jnp.maximum(kv_len - window, 0), 0)
+        return jnp.zeros_like(q_pos)
+    return jnp.where(window > 0, jnp.maximum(q_pos - window + 1, 0), 0)
 
 
 def _decode_kernel_body(
@@ -192,7 +208,8 @@ def _decode_kernel_body(
     i = _rem(entry, max_pages)
     kv_len = kv_lens_ref[b]
     window = None if win_ref is None else win_ref[0]
-    first, last = _live_pages(kv_len, window, page_size, max_pages)
+    first, last = live_pages(kv_len - 1, kv_len - 1, kv_len, window,
+                             page_size, max_pages)
 
     @pl.when(i == first)
     def _init():
@@ -204,7 +221,7 @@ def _decode_kernel_body(
     # partly so: slots past kv_len, and slots below the window's lo
     n_valid = jnp.minimum(kv_len - i * page_size, page_size)
     lo_in_page = jnp.clip(
-        _window_lo(kv_len, window) - i * page_size, 0, page_size)
+        _window_lo(kv_len - 1, window) - i * page_size, 0, page_size)
     page = _page_by_rows if by_rows else _page_by_heads
     page(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
          n_valid, lo_in_page, scale=scale, softcap=softcap)

@@ -1,4 +1,4 @@
-"""Pallas TPU ragged paged attention: one grid for decode + packed prefills.
+"""Pallas TPU ragged paged attention: one walk for decode + packed prefills.
 
 The mixed iteration's hot op. PR 1's token-budget scheduler packs the decode
 batch plus several partial-prefill chunks into one fused dispatch, but the
@@ -11,7 +11,7 @@ buffer whose length T comes from a small set of token-budget buckets, so
 mixed-iteration cost is proportional to real tokens and the compile key is
 T alone.
 
-Work-unit grid. The flat token axis is cut into q_block-row blocks; a block
+Work units. The flat token axis is cut into q_block-row blocks; a block
 that spans a segment boundary would mix two segments' (page table, kv_len,
 positions), so the host emits one WORK UNIT per (block, segment) overlap:
 
@@ -24,15 +24,39 @@ positions), so the host emits one WORK UNIT per (block, segment) overlap:
 
 NW and the segment capacity are functions of the T bucket only
 (`ragged_work_cap` / `ragged_seg_cap`), so they never add compile keys.
-Grid is (NW, MP) with the page index innermost: consecutive units sharing a
-block keep the q and out blocks resident (same block index -> Pallas elides
-the DMA), and each unit read-modify-writes ONLY its rows of the out block
-under a row mask at finalize. Units are emitted in increasing-row order so
-a later unit never clobbers an earlier one's rows. K/V pages stream exactly
-as in the decode kernel (ops/paged_attention.py), from the layer-stacked
-pool [L, NP, PS, Hk, D] at a scalar-prefetched layer: the index_map clamps dead
-pages (causal top, kv_len, window low bound) to a repeated index so their
-copies are elided, and a `needed` guard skips their compute.
+
+Grid: a WORK LIST of live (work unit, page) pairs, one grid step each, its
+length a traced bound, as in the decode kernel (ops/paged_attention.py).
+A call costs what its units can see, not units x the page table's width
+(under the old grid (NW, MP) the serving cell's mixed step took 8,512
+steps a layer for 100-230 live pairs). Unit w sees pages first .. last:
+`last` the page of min(qpos0 + rows - 1, kv_len - 1), `first` the page of
+max(qpos0 - window + 1, 0) under a sliding window and 0 without one
+(`_unit_pages`, which is `live_pages`, the decode walk's rule, for a run
+of `rows` queries: a decode row is its one-query case). A padding unit
+(rows 0) and the dummy tail (kv_len 0) see none and are never visited.
+`ragged_work_list` builds the list in XLA from `meta`, the segments'
+lengths and the window: entry g is `unit * MP + page`, units in `meta`'s
+order and each unit's pages ascending, NW * MP entries of capacity (a
+function of the T bucket and MP alone; int32 in SMEM beside the segment
+page table: 140 KB + 99 KB at the worker's defaults, T 320 and MP 256;
+the chip's 1 MiB holds T 320 up to MP 1024 and T 2048 up to MP 512). In
+XLA and not on the host: nothing more to stage or send a dispatch, and a
+model whose layers alternate sliding and global windows needs two lists.
+A caller that runs many layers on one plan builds it once
+(models/llama.py, above its layer scan). The kernel body reads the same
+`_unit_pages` from SMEM scalars: the softmax state is initialised at a
+unit's first page and written out at its last. Consecutive entries of
+one q block keep the q and out blocks resident (same block index ->
+Pallas elides the DMA), and each unit read-modify-writes ONLY its rows of
+the out block under a row mask at finalize. Units are emitted in
+increasing-row order so a later unit never clobbers an earlier one's
+rows. A row that no visited unit covers is never written; the wrapper
+defines it as 0 from the list's `covered` mask. K/V pages stream exactly
+as in the decode kernel, from the layer-stacked pool [L, NP, PS, Hk, D]
+at a scalar-prefetched layer: the index maps read the list, then the
+unit's segment, then its page-table row; no page is clamped, because no
+dead page is in the list.
 
 Parity: GQA (G groups per kv head), sliding window (traced scalar, 0 =
 global at runtime), logit softcap, and int8-KV per-(token, head) scales all
@@ -59,7 +83,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.paged_attention import (
-    scalar_operands, split_scales, stacked_pools,
+    _div, _rem, live_pages, scalar_operands, split_scales, stacked_pools,
+    work_list,
 )
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
 
@@ -104,7 +129,8 @@ def build_ragged_metadata(
 
     Segments are laid out back to back in the flat [t_bucket] token axis in
     the given order; the tail [sum(q_lens), t_bucket) is covered by a dummy
-    segment with kv_len=0 (no compute, finalize writes zeros). Returns the
+    segment with kv_len=0 (no live page: the kernel never visits it and
+    its wrapper returns 0 there). Returns the
     kernel operands (seg_page_table, seg_kv_lens, meta) padded to the
     bucket's static caps, plus per-token arrays for the model's KV writes /
     RoPE / jnp fallback (tok_*) and the per-segment last-token gather
@@ -151,8 +177,9 @@ def build_ragged_metadata(
             meta[:, w] = (s, b, blo - b * q_block, bhi - blo, qp0)
             w += 1
         lo = hi
-    # padding units: rows=0 no-ops pointing at the last real block (its
-    # buffers stay resident, so the repeat elides every DMA)
+    # padding units: rows=0, no live page, never visited. They point at
+    # the last real block and a real segment row all the same: the walk's
+    # entries past its bound name them, and an index map may read those
     if w:
         pad_blk = meta[1, w - 1]
     else:
@@ -186,8 +213,63 @@ def build_ragged_metadata(
     }
 
 
+def ragged_work_list(meta, seg_kv_lens, window, page_size: int,
+                     max_pages: int, n_tokens: int,
+                     q_block: int = DEFAULT_Q_BLOCK):
+    """The ragged kernel's walk, built in XLA from the work units, the
+    segments' lengths and the window: (work[W] int32, n_work int32,
+    covered[T] bool).
+
+    Entry g < n_work is `unit * MP + page` of the g-th live (unit, page)
+    pair: units in `meta`'s order (increasing rows), each unit's pages
+    ascending from `_unit_pages`' first to its last. A padding unit
+    (rows 0) and the dummy tail (kv_len 0) bring none. W = NW * MP is the
+    static bound, a function of the T bucket and the page table's width
+    alone. `covered` marks the flat rows that some visited unit writes;
+    the kernel's wrapper zeroes the rest. A caller that runs many layers
+    on one plan builds this once (models/llama.py, above its layer
+    scan)."""
+    seg, qblk, rs, rows, qpos0 = meta
+    first, last, live = _unit_pages(
+        rows, qpos0, seg_kv_lens[seg], window, page_size, max_pages)
+    work, n_work = work_list(
+        first, jnp.where(live, last - first + 1, 0), max_pages)
+    lo = qblk * q_block + rs
+    t = lax.iota(jnp.int32, n_tokens)[:, None]
+    covered = jnp.any(live & (t >= lo) & (t < lo + rows), axis=1)
+    return work, n_work, covered
+
+
+def ragged_live_pairs(meta, seg_kv_lens, window: int, page_size: int,
+                      max_pages: int) -> int:
+    """`ragged_work_list`'s n_work on the host, in numpy, from the
+    metadata `build_ragged_metadata` returned (`window` 0: none): the
+    count behind `IterationRecord.ragged_pages_live`, with no device
+    work. The rule is `_unit_pages`'; tests hold the two together."""
+    seg, _, _, rows, qpos0 = np.asarray(meta)
+    kv = np.asarray(seg_kv_lens)[seg]
+    last = np.minimum(
+        np.maximum(np.minimum(qpos0 + rows - 1, kv - 1), 0) // page_size,
+        max_pages - 1)
+    lo = np.maximum(qpos0 - window + 1, 0) if window > 0 else 0
+    first = np.minimum(lo // page_size, last)
+    return int(np.sum(np.where((rows > 0) & (kv > 0), last - first + 1, 0)))
+
+
+def _unit_pages(rows, qpos0, kv_len, window, page_size: int, max_pages: int):
+    """(first, last, live) of a work unit: `rows` queries of one segment
+    from position qpos0 on see the pages `live_pages` gives their run;
+    a unit without rows, or of a segment without tokens, sees none.
+    The one rule that the list (arrays) and the kernel body (SMEM
+    scalars) both read."""
+    first, last = live_pages(
+        qpos0, qpos0 + rows - 1, kv_len, window, page_size, max_pages)
+    return first, last, (rows > 0) & (kv_len > 0)
+
+
 def _ragged_kernel_body(
     # scalar prefetch
+    work_ref,  # [NW * MP] int32: unit * MP + page of each live pair
     meta_ref,  # [5, NW] int32 (seg, qblk, rs, rows, qpos0)
     pt_ref,  # [SEG, MP] int32 per-segment page-table rows
     kvl_ref,  # [SEG] int32 per-segment context length
@@ -200,116 +282,113 @@ def _ragged_kernel_body(
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
     o_ref,  # [Hk, QB*G, D]
-    # scratch (persist across the page loop)
+    # scratch (persist across a unit's pages)
     m_ref,  # [Hk, QB*G, 1] f32
     l_ref,  # [Hk, QB*G, 1] f32
     acc_ref,  # [Hk, QB*G, D] f32
     *,
     page_size: int,
+    max_pages: int,
     n_groups: int,
     scale: float,
     softcap: float = 0.0,
 ):
-    w = pl.program_id(0)
-    i = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+    entry = work_ref[pl.program_id(0)]
+    w = _div(entry, max_pages)
+    i = _rem(entry, max_pages)
     seg = meta_ref[0, w]
     row_start = meta_ref[2, w]
     n_rows = meta_ref[3, w]
     qpos0 = meta_ref[4, w]
     kv_len = kvl_ref[seg]
-    # last absolute position any valid row of this work unit can see
-    blk_max_pos = qpos0 + n_rows - 1
+    wv = None if win_ref is None else win_ref[0]
+    first, last, _ = _unit_pages(
+        n_rows, qpos0, kv_len, wv, page_size, max_pages)
     page_first = i * page_size
-    needed = (n_rows > 0) & (page_first <= blk_max_pos) & (page_first < kv_len)
-    if win_ref is not None:
-        wv = win_ref[0]
-        blk_lo = jnp.where(wv > 0, jnp.maximum(qpos0 - wv + 1, 0), 0)
-        needed = needed & (page_first + page_size > blk_lo)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)  # [Hk, QB*G, D]
-        k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
-        s = lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        ) * scale  # [Hk, QB*G, PS]
-        if ks_ref is not None:
-            s = s * ks_ref[...].T[:, None, :]
-        if softcap:
-            # the TRUE score (post any int8 fold), matching the jnp path
-            s = softcap * jnp.tanh(s / softcap)
+    @pl.when(i == first)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        row = lax.broadcasted_iota(jnp.int32, s.shape, 1) // n_groups
-        col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        q_pos = qpos0 + row - row_start  # valid only inside the row band
-        kv_pos = page_first + col
-        mask = (
-            (row >= row_start)
-            & (row < row_start + n_rows)
-            & (kv_pos <= q_pos)
-            & (kv_pos < kv_len)
-        )
-        if win_ref is not None:
-            wv = win_ref[0]
-            mask = mask & ((wv <= 0) | (kv_pos > q_pos - wv))
-        s = jnp.where(mask, s, NEG_INF)
+    # every pair the walk visits is live: the page runs unguarded
+    q = q_ref[...].astype(jnp.float32)  # [Hk, QB*G, D]
+    k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
+    s = lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
+    ) * scale  # [Hk, QB*G, PS]
+    if ks_ref is not None:
+        s = s * ks_ref[...].T[:, None, :]
+    if softcap:
+        # the TRUE score (post any int8 fold), matching the jnp path
+        s = softcap * jnp.tanh(s / softcap)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
+    row = _div(lax.broadcasted_iota(jnp.int32, s.shape, 1), n_groups)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    q_pos = qpos0 + row - row_start  # valid only inside the row band
+    kv_pos = page_first + col
+    mask = (
+        (row >= row_start)
+        & (row < row_start + n_rows)
+        & (kv_pos <= q_pos)
+        & (kv_pos < kv_len)
+    )
+    if wv is not None:
+        mask = mask & ((wv <= 0) | (kv_pos > q_pos - wv))
+    s = jnp.where(mask, s, NEG_INF)
 
-        l_add = jnp.sum(p, axis=2, keepdims=True)  # raw-probability denom
-        if vs_ref is not None:
-            p = p * vs_ref[...].T[:, None, :]
-        v = v_ref[...].astype(jnp.float32)
-        pv = lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        )
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        l_ref[...] = l_ref[...] * alpha + l_add
-        m_ref[...] = m_new
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
 
-    @pl.when(i == n_pages - 1)
+    l_add = jnp.sum(p, axis=2, keepdims=True)  # raw-probability denom
+    if vs_ref is not None:
+        p = p * vs_ref[...].T[:, None, :]
+    v = v_ref[...].astype(jnp.float32)
+    pv = lax.dot_general(
+        p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
+    )
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    l_ref[...] = l_ref[...] * alpha + l_add
+    m_ref[...] = m_new
+
+    @pl.when(i == last)
     def _finalize():
         # read-modify-write ONLY this unit's row band: units sharing the
         # block run back to back on the same resident out buffer, each
-        # masking in its own rows (increasing-row emission order)
+        # masking in its own rows (increasing-row emission order). What
+        # the buffer held outside every band of the block is whatever
+        # was there: the wrapper's `covered` mask defines those rows
         denom = jnp.maximum(l_ref[...], 1e-30)
         res = acc_ref[...] / denom  # [Hk, QB*G, D]
-        row = lax.broadcasted_iota(jnp.int32, res.shape, 1) // n_groups
+        row = _div(lax.broadcasted_iota(jnp.int32, res.shape, 1), n_groups)
         keep = (row >= row_start) & (row < row_start + n_rows)
         prev = o_ref[...].astype(jnp.float32)
         o_ref[...] = jnp.where(keep, res, prev).astype(o_ref.dtype)
 
 
-def _ragged_kernel(meta, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
-    _ragged_kernel_body(meta, pt, kl, None, q, k, v, None, None,
+def _ragged_kernel(wk, meta, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
+    _ragged_kernel_body(wk, meta, pt, kl, None, q, k, v, None, None,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_win(meta, pt, kl, ly, win, q, k, v, o, m, l, acc, **kw):
-    _ragged_kernel_body(meta, pt, kl, win, q, k, v, None, None,
+def _ragged_kernel_win(wk, meta, pt, kl, ly, win, q, k, v, o, m, l, acc,
+                       **kw):
+    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, None, None,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_int8(meta, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc,
+def _ragged_kernel_int8(wk, meta, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc,
                         **kw):
-    _ragged_kernel_body(meta, pt, kl, None, q, k, v, ks, vs,
+    _ragged_kernel_body(wk, meta, pt, kl, None, q, k, v, ks, vs,
                         o, m, l, acc, **kw)
 
 
-def _ragged_kernel_int8_win(meta, pt, kl, ly, win, q, k, ks, v, vs, o, m, l,
-                            acc, **kw):
-    _ragged_kernel_body(meta, pt, kl, win, q, k, v, ks, vs,
+def _ragged_kernel_int8_win(wk, meta, pt, kl, ly, win, q, k, ks, v, vs, o, m,
+                            l, acc, **kw):
+    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, ks, vs,
                         o, m, l, acc, **kw)
 
 
@@ -355,6 +434,7 @@ def ragged_paged_attention_sharded(
     axis_name: str = AXIS_MODEL,
     window=None,
     layer=None,  # traced int32 scalar, replicated
+    work=None,  # ragged_work_list's triple, replicated (see below)
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -370,21 +450,26 @@ def ragged_paged_attention_sharded(
         pool = {"q": pool, "s": scales}
     k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
     scalars = scalar_operands(layer, window)
+    if work is None:  # the same on every shard: built once, outside
+        PS = jax.tree.leaves(k_pool)[0].shape[2]
+        work = ragged_work_list(meta, seg_kv_lens, window, PS,
+                                seg_page_table.shape[1], q.shape[0], q_block)
 
-    def part(q, k_pool, v_pool, seg_pt, seg_kvl, meta, layer, window=None):
+    def part(q, k_pool, v_pool, seg_pt, seg_kvl, meta, work, n_work, covered,
+             layer, window=None):
         return ragged_paged_attention(
             q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, layer,
-            q_block=q_block, scale=scale, softcap=softcap,
-            interpret=interpret,
+            (work, n_work, covered), q_block=q_block, scale=scale,
+            softcap=softcap, interpret=interpret,
         )
 
     fn = jax.shard_map(
         part, mesh=mesh,
-        in_specs=(heads, pool, pool, P(None, None), P(None), P(None, None))
-        + (P(),) * len(scalars),
+        in_specs=(heads, pool, pool, P(None, None), P(None), P(None, None),
+                  P(None), P(), P(None)) + (P(),) * len(scalars),
         out_specs=heads, check_vma=False,
     )
-    return fn(q, k_pool, v_pool, seg_page_table, seg_kv_lens, meta,
+    return fn(q, k_pool, v_pool, seg_page_table, seg_kv_lens, meta, *work,
               *scalars)
 
 
@@ -401,6 +486,9 @@ def ragged_paged_attention(
     meta: jax.Array,  # [5, NW] int32 work units (build_ragged_metadata)
     window=None,  # None = no-window compile; else traced int32 scalar
     layer=None,  # traced int32 scalar: the stacked pool's layer to read
+    work=None,  # ragged_work_list(meta, seg_kv_lens, window, PS, MP, T,
+    #   q_block), for a caller that runs many layers on one plan and
+    #   builds it once; None = built here
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -419,49 +507,36 @@ def ragged_paged_attention(
     MP = seg_page_table.shape[1]
     if T % q_block:
         raise ValueError(f"T {T} not a multiple of q_block {q_block}")
-    NW = meta.shape[1]
     if scale is None:
         scale = D**-0.5
     windowed = window is not None
+    if windowed:
+        window = jnp.asarray(window, jnp.int32).reshape(())
+    work, n_work, covered = work or ragged_work_list(
+        meta, seg_kv_lens, window, PS, MP, T, q_block)
 
     # group axis merged into the rows HERE, in XLA: a [.., G, D] block
     # pads G up to a full sublane tile in VMEM and Mosaic cannot
     # shape-cast every (QB, G) split (G == 1 fails to lower)
     qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
 
-    def _clamp(w, i, mt, pt, kl, ly, *rest):
-        # clamp dead pages (causal top, kv_len, window low bound) to a
-        # repeated index so Pallas elides their DMA — flash-prefill trick,
-        # per work unit instead of per (b, sb)
-        seg = mt[0, w]
-        rows = mt[3, w]
-        qpos0 = mt[4, w]
-        blk_max_pos = qpos0 + jnp.maximum(rows, 1) - 1
-        last = jnp.minimum(blk_max_pos, jnp.maximum(kl[seg] - 1, 0)) // PS
-        last = jnp.clip(last, 0, MP - 1)
-        i_eff = jnp.minimum(i, last)
-        if rest:
-            (win,) = rest
-            wv = win[0]
-            lo = jnp.where(wv > 0, jnp.maximum(qpos0 - wv + 1, 0), 0)
-            i_eff = jnp.maximum(i_eff, jnp.minimum(lo // PS, last))
-        return seg, i_eff
+    # the index maps read the list, then the unit, then the page table:
+    # entry g is `unit * MP + page` of a live pair, so no page is clamped
+    def kv_index(g, wk, mt, pt, kl, ly, *rest):
+        return (ly[0], pt[mt[0, _div(wk[g], MP)], _rem(wk[g], MP)], 0, 0, 0)
 
-    def kv_index(w, i, mt, pt, kl, ly, *rest):
-        seg, i_eff = _clamp(w, i, mt, pt, kl, ly, *rest)
-        return (ly[0], pt[seg, i_eff], 0, 0, 0)
+    def scale_index(g, wk, mt, pt, kl, ly, *rest):
+        return kv_index(g, wk, mt, pt, kl, ly, *rest)[1:4]
 
-    def scale_index(w, i, mt, pt, kl, ly, *rest):
-        return kv_index(w, i, mt, pt, kl, ly, *rest)[1:4]
-
-    def q_index(w, i, mt, pt, kl, ly, *rest):
-        return (0, mt[1, w], 0)
+    def q_index(g, wk, mt, *rest):
+        return (0, mt[1, _div(wk[g], MP)], 0)
 
     q_spec = pl.BlockSpec((Hk, q_block * G, D), q_index)
     # one token-major page of one layer = one contiguous PS*Hk*D slab
     # (single DMA)
     kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
-    kw = dict(page_size=PS, n_groups=G, scale=scale, softcap=softcap)
+    kw = dict(page_size=PS, max_pages=MP, n_groups=G, scale=scale,
+              softcap=softcap)
     if quantized:
         kernel = functools.partial(
             _ragged_kernel_int8_win if windowed else _ragged_kernel_int8,
@@ -477,12 +552,12 @@ def ragged_paged_attention(
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (qt, kq, vq)
 
-    prefetch = (meta, seg_page_table, seg_kv_lens) + scalar_operands(
+    prefetch = (work, meta, seg_page_table, seg_kv_lens) + scalar_operands(
         layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # meta, seg_pt, seg_kvl, layer
-        #   (+ window)
-        grid=(NW, MP),
+        num_scalar_prefetch=len(prefetch),  # work, meta, seg_pt, seg_kvl,
+        #   layer (+ window)
+        grid=(n_work,),  # a traced bound: the live pairs, not NW * MP
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
@@ -498,5 +573,8 @@ def ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct((Hk, T * G, D), q.dtype),
         interpret=interpret,
     )(*prefetch, *operands)
-    # [Hk, T*G, D] -> [T, Hk, G, D]
-    return out.reshape(Hk, T, G, D).transpose(1, 0, 2, 3)
+    # [Hk, T*G, D] -> [T, Hk, G, D]; a row that no visited unit covers
+    # (bucket padding, a segment without tokens) was never written:
+    # define it, as 0
+    out = out.reshape(Hk, T, G, D).transpose(1, 0, 2, 3)
+    return jnp.where(covered[:, None, None, None], out, 0)

@@ -82,7 +82,22 @@ class IterationRecord:
     whose layers alternate sliding and global counts the mean over
     layers). From the positions the engine holds on the host: no device
     work. Beside it `decode_seqs x decode_steps` gives rows, and the
-    kernel's device seconds over it the cost of a live page."""
+    kernel's device seconds over it the cost of a live page.
+
+    `ragged_pages_live` [pairs] is the ragged (mixed-step) kernel's unit
+    of work: the live (work unit, page) pairs one layer's call walked,
+    0 where no ragged program ran. A work unit is the rows of one 8-row
+    q block that belong to one segment (a decode row, a verify row with
+    its draft, a prefill chunk); it sees the pages from the first its
+    sliding window shows to the page of its last row's position (the
+    mean over layers where sliding and global alternate). Counted by the
+    runner from the dispatch's host metadata (MixedOut.pages_live): no
+    device work. The kernel's device seconds over it is the cost of a
+    live pair; over `NW x MP` (ops.ragged_paged_attention.ragged_work_cap
+    of the T bucket, times the page table's width) it is the share of
+    the old (NW, MP) grid that was live. A ragged iteration's decode rows
+    are walked here at step 0 and by the decode kernel from step 1 on, so
+    `decode_pages_live` leaves their step 0 out."""
 
     seq: int               # engine iteration number (monotonic)
     ts: float              # wall clock (time.time()) at iteration start
@@ -106,6 +121,7 @@ class IterationRecord:
     compile_calls: int     # cumulative jitted calls (calls - variants
     #   growth = compile-cache hits)
     decode_pages_live: int = 0  # live KV pages walked (see the docstring)
+    ragged_pages_live: int = 0  # live (unit, page) pairs of a ragged step
     anomaly: bool = False  # this iteration fired the EWMA trigger
     # speculative decoding: mean tokens emitted per speculating row this
     # iteration (accepted drafts + the verified/bonus token; 0.0 when no

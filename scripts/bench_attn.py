@@ -1,4 +1,5 @@
-"""Microbench: decode paged attention, what a call and a live page cost.
+"""Microbench: decode and ragged paged attention, what a call and a live
+page cost.
 
 The table (`python scripts/bench_attn.py`, on the chip): geometries phi-3
 (Hk 32, G 1, D 96, window 2047 live) and GQA (Hk 8, G 4, D 128), page size
@@ -10,8 +11,15 @@ DMA time at the chip's HBM peak (K + V as the pool holds them, a head dim
 padded to 128 lanes). `us_call` times the call with the lengths fixed
 across calls, as in a layer scan, where what depends on the lengths alone
 is built once; `us_call_relisted` (the 4-row, 7-page points) makes the
-lengths depend on the previous call's output, so every call rebuilds it. `--ragged` adds the
-ragged-against-padded mixed comparison; `--jnp` the jnp gather path.
+lengths depend on the previous call's output, so every call rebuilds it.
+`--jnp` adds the jnp gather path. `--ragged` puts the ragged (mixed-step)
+kernel's table first (`--ragged --ragged-only`: that table alone): the
+cell's plans under its T buckets (288 for a 256-token chunk beside three
+decode rows of six pages, at prior 0 and at prior 256; 64 for a 32-row
+decode batch beside a 32-token chunk), us a call with the walk built once
+above the calls, us a live (work unit, page) pair, and the live share of
+the (NW, MP) grid the kernel took until PR 31. The script runs unchanged
+on a checkout of an earlier commit, which is how two are compared.
 
 Timing rule: many iters fused in one jit via lax.scan with a data
 dependency (out feeds next q), then ONE device_get — one dispatch and one
@@ -157,9 +165,9 @@ def bench_decode_table(impls) -> None:
         del pools
 
 
-def check_decode(interpret: bool):
+def check_decode(interpret: bool) -> None:
     """Numeric agreement with the jnp gather, ragged lengths and a pad
-    row; returns (rng, k_pool, v_pool) for the ragged comparison."""
+    row."""
     rng = np.random.default_rng(0)
     k_pool = jnp.asarray(rng.standard_normal((NP, PS, Hk, D)), jnp.bfloat16)
     v_pool = jnp.asarray(rng.standard_normal((NP, PS, Hk, D)), jnp.bfloat16)
@@ -179,98 +187,138 @@ def check_decode(interpret: bool):
         np.float32,
     )
     print("max abs diff:", np.abs(o1 - o2)[kv > 0].max(), flush=True)
-    return rng, k_pool, v_pool
 
 
-def bench_ragged_mixed(rng, k_pool, v_pool) -> None:
-    """Ragged mixed dispatch: one flat-token grid vs the padded pair
-    (decode batch via decode_paged_attention + [N, S] bucket-padded
-    chunks via prefill_paged_attention). Same KV pools; disjoint pages
-    per segment. On CPU only numeric parity runs (interpret mode timing
-    is meaningless); on TPU the scan-with-dependency timing rule above
-    applies."""
-    from dynamo_tpu.ops.flash_prefill import prefill_paged_attention
+# the benchmark cell's mixed plans: (decode rows' contexts, (chunk tokens,
+# prior tokens)) under its ragged T buckets and a page table 64 wide
+RAGGED_T_BUCKETS = (32, 64, 128, 256, 288)
+RAGGED_PLANS = {
+    "chunk 256 @0 + 3 decode": ((350, 380, 330), (256, 0)),
+    "chunk 256 @256 + 3 decode": ((350, 380, 330), (256, 256)),
+    "chunk 32 @0 + 32 decode": (tuple(330 + 3 * i for i in range(32)),
+                                (32, 0)),
+}
+
+
+def ragged_plan(rng, decode_kv, chunk, mp):
+    """build_ragged_metadata's output for a plan, page tables drawn from
+    the pool as bench_point draws them."""
+    from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
+
+    n_tok, prior = chunk
+    q_lens = [1] * len(decode_kv) + [n_tok]
+    q_starts = [n - 1 for n in decode_kv] + [prior]
+    kv_lens = list(decode_kv) + [prior + n_tok]
+    rows = [((rng.integers(1, POOL_PAGES, -(-n // PS)).cumsum()
+              + rng.integers(POOL_PAGES)) % POOL_PAGES).tolist()
+            for n in kv_lens]
+    t = min(b for b in RAGGED_T_BUCKETS if b >= sum(q_lens))
+    return build_ragged_metadata(q_lens, q_starts, kv_lens, rows, t,
+                                 max_pages=mp)
+
+
+def ragged_live_pairs(md, window) -> int:
+    """Live (work unit, page) pairs of a plan, by the kernel docstring's
+    rule: written out here so the script counts the same on a checkout
+    whose kernel keeps the (NW, MP) grid."""
+    seg, _, _, rows, qpos0 = md["meta"]
+    kv = md["seg_kv_lens"][seg]
+    last = np.minimum(qpos0 + rows - 1, kv - 1) // PS
+    first = (np.maximum(qpos0 - window + 1, 0) if window else 0 * last) // PS
+    return int(np.sum(np.where((rows > 0) & (kv > 0), last - first + 1, 0)))
+
+
+@partial(jax.jit, static_argnames=("relist",),
+         donate_argnames=("k_pool", "v_pool"))
+def ragged_loop(q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, relist):
+    """ITERS chained ragged calls on one plan. The walk is built once,
+    above the scan, as llama.forward builds it above its layers, where
+    the kernel takes one (`relist`: inside, every call)."""
+    from dynamo_tpu.ops import ragged_paged_attention as rg
+
+    build = getattr(rg, "ragged_work_list", None)
+    page_size, mp = k_pool.shape[2], seg_pt.shape[1]
+
+    def walk(kvl):
+        return (build(meta, kvl, window, page_size, mp, q.shape[0]),)
+
+    hoisted = walk(seg_kvl) if build and not relist else ()
+
+    def body(q, i):
+        kvl, work = seg_kvl, hoisted
+        if relist:  # never true, and XLA cannot know (decode_loop)
+            kvl = kvl + (q[0, 0, 0, 0] > 3e38).astype(jnp.int32)
+            work = walk(kvl) if build else ()
+        o = rg.ragged_paged_attention(q, k_pool, v_pool, seg_pt, kvl, meta,
+                                      window, jnp.minimum(i, LAYER), *work)
+        return o.astype(q.dtype), None
+
+    q, _ = lax.scan(body, q, jnp.arange(ITERS) + LAYER)
+    return q, k_pool, v_pool
+
+
+def bench_ragged_table() -> None:
+    """The ragged (mixed-step) kernel on the cell's plans, one JSON line a
+    point: us a call and us a live (work unit, page) pair, with the
+    share of the (NW, MP) grid that is live."""
+    for gname, geom in GEOMETRIES.items():
+        hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
+        shape = (LAYERS, POOL_PAGES, PS, hk, d)
+        keys = jax.random.split(jax.random.key(0), 2)
+        pools = [jax.random.normal(k, shape, jnp.bfloat16) for k in keys]
+        win = None if window is None else jnp.int32(window)
+        for name, (decode_kv, chunk) in RAGGED_PLANS.items():
+            rng = np.random.default_rng(0)
+            md = ragged_plan(rng, decode_kv, chunk, 64)
+            t, nw = md["tok_positions"].shape[0], md["meta"].shape[1]
+            q = jnp.asarray(rng.standard_normal((t, hk, g, d)), jnp.bfloat16)
+            tail = tuple(jnp.asarray(md[k]) for k in
+                         ("seg_page_table", "seg_kv_lens", "meta")) + (win,)
+
+            def call(relist):
+                out, pools[0], pools[1] = ragged_loop(
+                    q, pools[0], pools[1], *tail, relist=relist)
+                return out
+
+            live = ragged_live_pairs(md, window)
+            us = _time(partial(call, False))
+            line = {"point": f"ragged {gname} {name}", "T": t,
+                    "page_table": 64, "units": int(md["n_work"]),
+                    "live_pairs": live,
+                    "live_share_of_grid": round(live / (nw * 64), 4),
+                    "us_call": round(us, 1),
+                    "us_live_pair": round(us / live, 3)}
+            if name.startswith("chunk 256 @0"):
+                line["us_call_relisted"] = round(_time(partial(call, True)), 1)
+            print(json.dumps(line), flush=True)
+        del pools
+
+
+def check_ragged(interpret: bool) -> None:
+    """The first plan against the per-token jnp reference, at the GQA
+    geometry."""
     from dynamo_tpu.ops.ragged_paged_attention import (
-        build_ragged_metadata,
-        ragged_attention_reference,
-        ragged_paged_attention,
+        ragged_attention_reference, ragged_paged_attention,
     )
 
-    DEC_B, DEC_KV = 8, 256
-    CHUNKS = (512, 32, 32, 32)
-    S_BUCKET = 512  # chunk bucket the padded path rounds every row up to
-    T_REAL = DEC_B + sum(CHUNKS)
-    T_B = (T_REAL + 7) // 8 * 8
-
-    q_lens = [1] * DEC_B + list(CHUNKS)
-    q_starts = [DEC_KV - 1] * DEC_B + [0] * len(CHUNKS)
-    kv_lens_r = [DEC_KV] * DEC_B + list(CHUNKS)
-    rows = [list(range(i * MP, (i + 1) * MP)) for i in range(len(q_lens))]
-    md = build_ragged_metadata(q_lens, q_starts, kv_lens_r, rows, T_B,
-                               max_pages=MP)
-    q_flat = jnp.asarray(rng.standard_normal((T_B, Hk, G, D)), jnp.bfloat16)
-    seg_pt = jnp.asarray(md["seg_page_table"])
-    seg_kvl = jnp.asarray(md["seg_kv_lens"])
-    meta = jnp.asarray(md["meta"])
-
-    cu = md["cu_q_lens"]
-    q_dec = q_flat[:DEC_B]
-    q_pad = jnp.zeros((len(CHUNKS), S_BUCKET, Hk, G, D), jnp.bfloat16)
-    for i, n in enumerate(CHUNKS):
-        q_pad = q_pad.at[i, :n].set(q_flat[cu[DEC_B + i] : cu[DEC_B + i] + n])
-    pt_dec = jnp.asarray(np.asarray(rows[:DEC_B], np.int32))
-    kvl_dec = jnp.full((DEC_B,), DEC_KV, jnp.int32)
-    pt_chunk = jnp.asarray(np.asarray(rows[DEC_B:], np.int32))
-    qs_chunk = jnp.zeros((len(CHUNKS),), jnp.int32)
-    ql_chunk = jnp.asarray(np.asarray(CHUNKS, np.int32))
-    kvl_chunk = ql_chunk
-
-    if jax.devices()[0].platform == "cpu":
-        out = ragged_paged_attention(q_flat, k_pool, v_pool, seg_pt, seg_kvl,
-                                     meta, interpret=True)
-        ref = ragged_attention_reference(
-            q_flat, k_pool, v_pool, jnp.asarray(md["tok_page_table"]),
-            jnp.asarray(md["tok_positions"]), jnp.asarray(md["tok_kv_lens"]),
-        )
-        d = np.abs(np.asarray(out[:T_REAL], np.float32)
-                   - np.asarray(ref[:T_REAL], np.float32)).max()
-        print(f"ragged mixed (cpu parity only): tokens ragged={T_REAL} "
-              f"padded={DEC_B + len(CHUNKS) * S_BUCKET}  max abs diff: {d}",
-              flush=True)
-        return
-
-    @partial(jax.jit, static_argnames=("impl",))
-    def mixed_loop(q_f, q_d, q_p, impl):
-        if impl == "ragged":
-            def body(q, _):
-                o = ragged_paged_attention(q, k_pool, v_pool, seg_pt,
-                                           seg_kvl, meta)
-                return o.astype(q.dtype), None
-
-            q, _ = lax.scan(body, q_f, None, length=ITERS)
-            return q
-        def body(carry, _):
-            qd, qp = carry
-            od = decode_paged_attention(qd, k_pool, v_pool, pt_dec, kvl_dec)
-            op = prefill_paged_attention(qp, k_pool, v_pool, pt_chunk,
-                                         qs_chunk, ql_chunk, kvl_chunk)
-            return (od.astype(qd.dtype), op.astype(qp.dtype)), None
-
-        (qd, _qp), _ = lax.scan(body, (q_d, q_p), None, length=ITERS)
-        return qd
-
-    for impl in ("padded", "ragged"):
-        out = mixed_loop(q_flat, q_dec, q_pad, impl)
-        np.asarray(jax.device_get(out))  # warmup + compile
-        times = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            out = mixed_loop(q_flat, q_dec, q_pad, impl)
-            np.asarray(jax.device_get(out))
-            times.append((time.perf_counter() - t0) / ITERS * 1e6)
-        toks = T_REAL if impl == "ragged" else DEC_B + len(CHUNKS) * S_BUCKET
-        print(f"mixed {impl:7s} tokens={toks:5d} per-iter: "
-              f"{min(times):8.1f} us", flush=True)
+    rng = np.random.default_rng(0)
+    hk, g, d = (GEOMETRIES["gqa"][k] for k in ("Hk", "G", "D"))
+    md = ragged_plan(rng, *RAGGED_PLANS["chunk 256 @0 + 3 decode"], 8)
+    t = md["tok_positions"].shape[0]
+    q = jnp.asarray(rng.standard_normal((t, hk, g, d)), jnp.bfloat16)
+    pools = [jnp.asarray(rng.standard_normal((POOL_PAGES, PS, hk, d)),
+                         jnp.bfloat16) for _ in range(2)]
+    out = ragged_paged_attention(
+        q, *pools, *(jnp.asarray(md[k]) for k in
+                     ("seg_page_table", "seg_kv_lens", "meta")),
+        interpret=interpret)
+    ref = ragged_attention_reference(
+        q, *pools, *(jnp.asarray(md[k]) for k in
+                     ("tok_page_table", "tok_positions", "tok_kv_lens")))
+    real = md["tok_positions"] >= 0
+    print("ragged max abs diff:", np.abs(
+        np.asarray(out, np.float32) - np.asarray(ref, np.float32))[real].max(),
+        flush=True)
 
 
 def main() -> None:
@@ -278,15 +326,18 @@ def main() -> None:
 
     dynamo_tpu.enable_compilation_cache()
     cpu = jax.devices()[0].platform == "cpu"  # pallas needs interpret on CPU
-    rng, k_pool, v_pool = check_decode(interpret=cpu)
-    if "--ragged" in sys.argv[1:] or cpu:
-        bench_ragged_mixed(rng, k_pool, v_pool)
+    check_decode(interpret=cpu)
+    check_ragged(interpret=cpu)
     if cpu:
         print("no accelerator: parity only (nothing timed on a CPU is a "
               "device number)", flush=True)
         return
-    impls = ("pallas", "jnp") if "--jnp" in sys.argv[1:] else ("pallas",)
-    bench_decode_table(impls)
+    args = sys.argv[1:]
+    if "--ragged" in args:
+        bench_ragged_table()
+    if "--ragged-only" not in args:
+        impls = ("pallas", "jnp") if "--jnp" in args else ("pallas",)
+        bench_decode_table(impls)
 
 
 if __name__ == "__main__":

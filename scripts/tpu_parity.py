@@ -16,7 +16,11 @@ GQA checks — the cross product, every one REQUIRED to pass compiled:
               128 lanes, and G = 1, are where Mosaic layouts differ)
 The ragged layout packs three decode segments and a chunk start into one
 q block (the finalize read-modify-write), chunks that cross block
-boundaries, a chunk continuing a long prior context, and a padded tail.
+boundaries, a chunk continuing a long prior context, and a padded tail;
+"ragged sparse-table" is the serving cell's mixed step (segments of 1 to
+8 pages under a page table 64 wide, T 288), and on a host with several
+chips it runs again through the tensor-parallel wrapper, kv heads over
+all of them.
 Each of these reads a layer-STACKED pool [L, NP, PS, Hk, D] at a nonzero
 traced layer, as the model's layer scan does; every layer holds other
 data, so a kernel that indexed the wrong layer misses the reference.
@@ -56,6 +60,7 @@ from dynamo_tpu.ops.paged_attention import decode_paged_attention
 from dynamo_tpu.ops.ragged_paged_attention import (
     build_ragged_metadata,
     ragged_paged_attention,
+    ragged_paged_attention_sharded,
 )
 
 TOL = 3e-2
@@ -197,16 +202,12 @@ def check_prefill(geom, variant) -> float:
     return max(_max_err(out[b, : ql[b]], ref[b, : ql[b]]) for b in range(B))
 
 
-def check_ragged(geom, variant) -> float:
+def _ragged_err(geom, variant, rng, segs, T, mesh=None) -> float:
+    """Ragged kernel against the f32 reference on `segs`, (q_len, q_start)
+    each, in a flat axis of T tokens; through the tensor-parallel wrapper
+    where a mesh is given."""
     quantized, window, softcap = _variant(geom, variant)
-    rng = np.random.default_rng(2)
-    T, Hk, G, D = 256, geom["Hk"], geom["G"], geom["D"]
-    long_ctx = geom["ctx"]
-    # (q_len, q_start): three decode rows and a chunk start share q block
-    # 0; chunks cross 8-row block boundaries; one chunk continues a long
-    # prior context; 256 - 195 rows of padded tail
-    segs = [(1, long_ctx - 1), (1, 70), (1, 0), (37, 0),
-            (100, long_ctx - 100), (9, 3), (1, 129), (45, 64)]
+    Hk, G, D = geom["Hk"], geom["G"], geom["D"]
     q_lens = [s[0] for s in segs]
     q_starts = [s[1] for s in segs]
     kv = np.asarray([a + b for a, b in segs], np.int32)
@@ -216,12 +217,19 @@ def check_ragged(geom, variant) -> float:
         q_lens, q_starts, kv, pt, T, max_pages=geom["MP"]
     )
     q = jnp.asarray(rng.standard_normal((T, Hk, G, D)), jnp.bfloat16)
-    out = ragged_paged_attention(
-        q, pool.k, pool.v, jnp.asarray(md["seg_page_table"]),
-        jnp.asarray(md["seg_kv_lens"]), jnp.asarray(md["meta"]),
-        None if window is None else jnp.int32(window), jnp.int32(LAYER),
-        softcap=softcap, interpret=INTERPRET,
-    )
+    seg = tuple(jnp.asarray(md[k]) for k in
+                ("seg_page_table", "seg_kv_lens", "meta"))
+    win = None if window is None else jnp.int32(window)
+    if mesh is None:
+        out = ragged_paged_attention(
+            q, pool.k, pool.v, *seg, win, jnp.int32(LAYER),
+            softcap=softcap, interpret=INTERPRET,
+        )
+    else:
+        out = ragged_paged_attention_sharded(
+            q, pool.k, pool.v, *seg, mesh, window=win,
+            layer=jnp.int32(LAYER), softcap=softcap, interpret=INTERPRET,
+        )
     # reference per segment (a B=1, S=q_len row of paged_attention_jnp —
     # what ragged_attention_reference computes per token, without
     # gathering the whole context once for every token)
@@ -234,6 +242,28 @@ def check_ragged(geom, variant) -> float:
         lo += n
     # rows covered by no real segment return 0
     return max(worst, _max_err(out[lo:], jnp.zeros_like(out[lo:])))
+
+
+def check_ragged(geom, variant) -> float:
+    long_ctx = geom["ctx"]
+    # (q_len, q_start): three decode rows and a chunk start share q block
+    # 0; chunks cross 8-row block boundaries; one chunk continues a long
+    # prior context; 256 - 195 rows of padded tail
+    segs = [(1, long_ctx - 1), (1, 70), (1, 0), (37, 0),
+            (100, long_ctx - 100), (9, 3), (1, 129), (45, 64)]
+    return _ragged_err(geom, variant, np.random.default_rng(2), segs, 256)
+
+
+# a sparse page table, as the serving cell's mixed step has it: segments
+# of 1 to 8 pages under a table 64 wide, so a few percent of the (work
+# unit, page) pairs are live. Decode rows of 1, 6 and 8 pages, a chunk
+# continuing a four-page prior, a fresh chunk, 288 - 259 rows of tail
+_SPARSE_SEGS = [(1, 0), (1, 380), (1, 511), (150, 256), (106, 0)]
+
+
+def check_ragged_sparse(geom, variant, mesh=None) -> float:
+    return _ragged_err(geom, variant, np.random.default_rng(3),
+                       _SPARSE_SEGS, 288, mesh)
 
 
 def check_mla() -> float:
@@ -387,10 +417,22 @@ def all_checks():
         for kname, fn in (("decode", check_decode),
                           ("decode ragged-batch", check_decode_ragged),
                           ("prefill", check_prefill),
-                          ("ragged", check_ragged)):
+                          ("ragged", check_ragged),
+                          ("ragged sparse-table", check_ragged_sparse)):
             for variant in VARIANTS:
                 checks.append((f"{kname} {variant} @{gname}",
                                functools.partial(fn, geom, variant)))
+    if len(jax.devices()) > 1:
+        # the tensor-parallel wrapper: kv heads over every device there is
+        from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+        mesh = make_mesh(MeshConfig(model=len(jax.devices())))
+        for gname, geom in GEOMETRIES.items():
+            for variant in VARIANTS:
+                checks.append((
+                    f"ragged sparse-table sharded {variant} @{gname}",
+                    functools.partial(check_ragged_sparse, geom, variant,
+                                      mesh)))
     checks += [
         ("gemma decode (softcap+window, G 2, PS 16)", check_gemma_decode),
         ("gemma prefill (softcap+window, G 2, PS 16)", check_gemma_prefill),

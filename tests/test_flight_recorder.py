@@ -177,6 +177,77 @@ async def test_record_counts_live_decode_pages(window, n_global):
     assert seen >= 2
 
 
+@pytest.mark.parametrize("model", ["tiny", "tiny-gemma3"])
+def test_record_counts_live_ragged_pairs(monkeypatch, model):
+    """ragged_pages_live: the live (work unit, page) pairs one layer's
+    ragged call walked, on a real runner's mixed iteration (one decode row
+    beside a 9-token chunk), against the rule written out from the
+    positions (the mean over layers where sliding and global alternate);
+    0 on an iteration no ragged program ran, and there decode_pages_live
+    keeps step 0, which a ragged iteration hands to the ragged kernel."""
+    from dynamo_tpu.engine.model_runner import ModelRunner
+    from dynamo_tpu.engine.scheduler import Sequence
+    from dynamo_tpu.models.config import get_config
+
+    monkeypatch.setenv("DYN_FUSED_MIXED", "1")
+    ps, qb = 4, 8
+    c = get_config(model)
+    runner = ModelRunner(c, num_pages=96, page_size=ps, max_pages_per_seq=16,
+                         decode_buckets=(1, 2, 4), prefill_buckets=(8, 16),
+                         seed=7)
+    engine = InferenceEngine(runner, max_batch=4, chunk_size=16,
+                             mixed_prefill_tokens=16)
+
+    def add(rid, prompt):
+        engine._inbox.put(("add", Sequence(
+            request_id=rid, prompt=prompt, sampling={"temperature": 0.0},
+            stop={"max_tokens": 32, "stop_ids": []},
+            arrival=time.monotonic())))
+
+    def pairs(q_lens, q_starts, kv_lens, window):
+        n, lo = 0, 0
+        for ln, start, kv in zip(q_lens, q_starts, kv_lens):
+            hi = lo + ln
+            for b in range(lo // qb, (hi - 1) // qb + 1):
+                blo, bhi = max(lo, b * qb), min(hi, (b + 1) * qb)
+                qpos0, rows = start + blo - lo, bhi - blo
+                last = min(qpos0 + rows - 1, kv - 1) // ps
+                first = max(qpos0 - window + 1, 0) // ps if window else 0
+                n += last - min(first, last) + 1
+            lo = hi
+        return n
+
+    a, b = [4, 2, 4, 2, 7, 5, 1, 3, 9, 8, 6], [9, 8, 7, 1, 3, 1, 4, 1, 5]
+    add("a", a)
+    engine._loop_once()  # a's prefill
+    engine._loop_once()  # a decodes alone
+    engine._flush_late_record()
+    alone = engine.recorder.snapshot()[-1]
+    assert alone.kind == "decode" and alone.ragged_pages_live == 0
+    assert alone.decode_pages_live > 0
+    pos = engine.scheduler.active[0].computed_len  # a's next position
+    add("b", b)
+    engine._loop_once()  # a's decode + b's only chunk, one ragged dispatch
+    engine._flush_late_record()
+    rec = engine.recorder.snapshot()[-1]
+    assert (rec.kind, rec.ragged, rec.n_chunks, rec.decode_seqs) == (
+        "mixed", True, 1, 1), rec
+    plan = ([1, len(b)], [pos, 0], [pos + 1, len(b)])
+    full = pairs(*plan, 0)
+    want = full
+    if c.sliding_window:
+        n_global = sum(l % c.sw_period == c.sw_global_residue
+                       for l in range(c.n_layers))
+        want = round((full * n_global + pairs(*plan, c.sliding_window)
+                      * (c.n_layers - n_global)) / c.n_layers)
+        assert want < full  # the window cuts a page of the decode row
+    assert rec.ragged_pages_live == want, rec
+    # the decode kernel ran steps 1 .. n-1 only
+    tail = sum((pos + t) // ps + 1 for t in range(1, rec.decode_steps))
+    if not c.sliding_window:
+        assert rec.decode_pages_live == tail, rec
+
+
 # -- latency spine ----------------------------------------------------------
 
 

@@ -1112,6 +1112,225 @@ def test_build_ragged_metadata_overflow():
                               max_segs=4)
 
 
+# -- the ragged kernel's walk (its grid is a work list of live pairs) ---------
+# name -> (q_lens, q_starts, kv_lens, T bucket): segments back to back in
+# the flat token axis, page size 4, a page table 32 wide
+_RAGGED_PS, _RAGGED_MP = 4, 32
+_RAGGED_PLANS = {
+    # two decode rows, a fresh chunk that starts mid-block, a second chunk
+    # with prior pages, 5 rows of tail
+    "mixed": ([1, 1, 9, 16], [11, 0, 0, 8], [12, 1, 9, 24], 32),
+    # a small live share: one long decode row among short chunks, under a
+    # page table much wider than any chunk
+    "sparse": ([1, 5, 7, 3], [99, 0, 4, 0], [100, 5, 11, 3], 24),
+    # verify_spec's shape: each row's draft is a segment of q_len 2-5 (a
+    # tree's branches are more segments), then a plain decode row and a chunk
+    "verify": ([3, 5, 2, 4, 1, 6], [20, 7, 33, 33, 9, 0],
+               [23, 12, 35, 37, 10, 6], 24),
+    # a chunk that fills its blocks exactly, and no tail segment at all
+    "full": ([8, 8], [0, 16], [8, 24], 16),
+    # no real segment: the bound is 0 and every row comes back 0
+    "empty": ([], [], [], 8),
+}
+
+
+def _ragged_plan(name, seed=23, forked=False):
+    from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
+
+    q_lens, q_starts, kv_lens, T = _RAGGED_PLANS[name]
+    rng = np.random.default_rng(seed)
+    NP = 2 + sum(-(-n // _RAGGED_PS) for n in kv_lens)
+    free = list(rng.permutation(NP - 2))
+    rows = [[int(free.pop()) for _ in range(-(-n // _RAGGED_PS))]
+            for n in kv_lens]
+    if forked:  # a tree's branch: the trunk's pages shared by reference
+        rows[3][: len(rows[2]) - 1] = rows[2][:-1]
+    md = build_ragged_metadata(q_lens, q_starts, kv_lens, rows, T,
+                               max_pages=_RAGGED_MP)
+    return md, NP
+
+
+def _oracle_pairs(name, window):
+    """The live (unit, page) pairs by the kernel docstring's rule, from
+    the plan itself: a unit is a (q block, segment) overlap of `rows`
+    rows from position qpos0; it sees pages first .. last."""
+    q_lens, q_starts, kv_lens, _ = _RAGGED_PLANS[name]
+    PS, MP, QB = _RAGGED_PS, _RAGGED_MP, 8
+    pairs, w, lo = [], 0, 0
+    for s, ln in enumerate(q_lens):
+        hi = lo + ln
+        for b in range(lo // QB, (hi - 1) // QB + 1):
+            blo, bhi = max(lo, b * QB), min(hi, (b + 1) * QB)
+            qpos0, rows = q_starts[s] + blo - lo, bhi - blo
+            last = min(min(qpos0 + rows - 1, kv_lens[s] - 1) // PS, MP - 1)
+            first = max(qpos0 - window + 1, 0) // PS if window else 0
+            pairs += [(w, p) for p in range(min(first, last), last + 1)]
+            w += 1
+        lo = hi
+    return pairs
+
+
+@pytest.mark.parametrize("window", [None, 0, 6, 16])
+@pytest.mark.parametrize("plan", list(_RAGGED_PLANS))
+def test_ragged_work_list_matches_oracle(plan, window):
+    """The list is the oracle's pairs, units in order and pages
+    ascending; pad units and the dummy tail bring none; its capacity is
+    a function of (T bucket, MP) alone; the host's count is its bound."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        ragged_live_pairs, ragged_work_cap, ragged_work_list,
+    )
+
+    md, _ = _ragged_plan(plan)
+    T = md["tok_positions"].shape[0]
+    win = None if window is None else jnp.int32(window)
+    work, n_work, covered = ragged_work_list(
+        jnp.asarray(md["meta"]), jnp.asarray(md["seg_kv_lens"]), win,
+        _RAGGED_PS, _RAGGED_MP, T)
+    want = _oracle_pairs(plan, window or 0)
+    got = [(int(e) // _RAGGED_MP, int(e) % _RAGGED_MP)
+           for e in np.asarray(work)[: int(n_work)]]
+    assert got == want
+    assert got == sorted(got)
+    assert work.shape == (ragged_work_cap(T) * _RAGGED_MP,)
+    # entries past the bound stay inside the tables (an index map may
+    # read one step ahead)
+    assert np.all(np.asarray(work) // _RAGGED_MP < md["meta"].shape[1])
+    assert ragged_live_pairs(md["meta"], md["seg_kv_lens"], window or 0,
+                             _RAGGED_PS, _RAGGED_MP) == len(want)
+    np.testing.assert_array_equal(np.asarray(covered),
+                                  md["tok_positions"] >= 0)
+    if plan == "empty":
+        assert int(n_work) == 0 and not np.asarray(covered).any()
+
+
+# name -> (window, softcap, int8 KV)
+_RAGGED_VARIANTS = {
+    "plain": (None, 0.0, False),
+    "window": (6, 0.0, False),
+    "softcap": (None, 30.0, False),
+    "int8": (None, 0.0, True),
+    "int8-window-softcap": (6, 30.0, True),
+}
+
+
+def _ragged_run(plan, variant, geom=(2, 3, 64), poison=False, forked=False,
+                sharded=False):
+    """(kernel output, reference, real-row mask) of a plan; `poison`: every
+    pool page outside the live pairs holds NaN and every page-table entry
+    outside them names an unowned NaN page."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        ragged_attention_reference, ragged_paged_attention,
+        ragged_paged_attention_sharded,
+    )
+
+    Hk, G, D = geom
+    window, softcap, quant = _RAGGED_VARIANTS[variant]
+    md, NP = _ragged_plan(plan, forked=forked)
+    T = md["tok_positions"].shape[0]
+    rng = np.random.default_rng(29)
+    q = jnp.asarray(rng.standard_normal((T, Hk, G, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((NP, _RAGGED_PS, Hk, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((NP, _RAGGED_PS, Hk, D)), jnp.bfloat16)
+    seg_pt = md["seg_page_table"]
+    if poison:
+        live = np.zeros(seg_pt.shape, bool)
+        for w, p in _oracle_pairs(plan, window or 0):
+            live[md["meta"][0, w], p] = True
+        dead = jnp.asarray(np.setdiff1d(np.arange(NP), seg_pt[live]))
+        kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+        seg_pt = np.where(live, seg_pt, NP - 1).astype(np.int32)
+    kq, vq = _q_pools(kp, vp) if quant else (kp, vp)
+    win = None if window is None else jnp.int32(window)
+    seg = (jnp.asarray(seg_pt), jnp.asarray(md["seg_kv_lens"]),
+           jnp.asarray(md["meta"]))
+    if sharded:
+        from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+        out = ragged_paged_attention_sharded(
+            q, kq, vq, *seg, make_mesh(MeshConfig(model=2)), window=win,
+            softcap=softcap, interpret=True)
+    else:
+        out = ragged_paged_attention(q, kq, vq, *seg, win, softcap=softcap,
+                                     interpret=True)
+    ref = ragged_attention_reference(
+        q, kq, vq, jnp.asarray(md["tok_page_table"]),
+        jnp.asarray(md["tok_positions"]), jnp.asarray(md["tok_kv_lens"]),
+        softcap=softcap, window=win)
+    return (np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            md["tok_positions"] >= 0)
+
+
+def _ragged_close(out, ref, real):
+    assert np.all(out[~real] == 0.0)  # no real segment's row: defined, zero
+    if real.any():
+        d = np.abs(out - ref)[real].max()
+        assert d < 3e-2, d
+
+
+@pytest.mark.parametrize("variant", list(_RAGGED_VARIANTS))
+@pytest.mark.parametrize("plan", list(_RAGGED_PLANS))
+def test_ragged_walk_matches_reference(plan, variant):
+    _ragged_close(*_ragged_run(plan, variant, forked=plan == "verify"))
+
+
+@pytest.mark.parametrize("geom", [(16, 1, 96), (4, 2, 128)])
+def test_ragged_walk_geometries(geom):
+    """phi-3's MHA (G 1, a head dim that is not 128 lanes) and a GQA
+    geometry, on the sparse plan under a window."""
+    _ragged_close(*_ragged_run("sparse", "window", geom=geom))
+
+
+@pytest.mark.parametrize("variant", ["plain", "window"])
+@pytest.mark.parametrize("plan", ["mixed", "sparse", "verify"])
+def test_ragged_walk_reads_only_live_pairs(plan, variant):
+    """With every dead page and every dead page-table entry poisoned the
+    result is the clean one, bit for bit: no dead pair was visited."""
+    clean, _, real = _ragged_run(plan, variant)
+    dirty, _, _ = _ragged_run(plan, variant, poison=True)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
+@pytest.mark.parametrize("variant", ["window", "int8"])
+def test_ragged_walk_sharded(variant):
+    _ragged_close(*_ragged_run("sparse", variant, geom=(2, 4, 64),
+                               sharded=True))
+
+
+def test_ragged_grid_is_one_traced_bound():
+    """One dynamic grid dimension, and the call's static shapes (the
+    list's capacity among them) hang on (T bucket, MP) only, not on the
+    plan: one program a T bucket."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        build_ragged_metadata, ragged_paged_attention, ragged_work_cap,
+    )
+
+    T = 24
+
+    def call_of(plan):
+        q_lens, q_starts, kv_lens, _ = _RAGGED_PLANS[plan]
+        rows = [[1] * -(-n // _RAGGED_PS) for n in kv_lens]
+        md = build_ragged_metadata(q_lens, q_starts, kv_lens, rows, T,
+                                   max_pages=_RAGGED_MP)
+        q = jnp.zeros((T, 2, 2, 64), jnp.bfloat16)
+        pool = jnp.zeros((4, _RAGGED_PS, 2, 64), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            ragged_paged_attention, interpret=True))(
+            q, pool, pool, jnp.asarray(md["seg_page_table"]),
+            jnp.asarray(md["seg_kv_lens"]), jnp.asarray(md["meta"]))
+        (eqn,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                  if e.primitive.name == "pallas_call"]
+        gm = eqn.params["grid_mapping"]
+        return (gm.grid, gm.num_dynamic_grid_bounds,
+                [v.aval.shape for v in eqn.invars])
+
+    assert call_of("sparse") == call_of("verify") == call_of("empty")
+    grid, n_dynamic, shapes = call_of("sparse")
+    assert n_dynamic == 1 and len(grid) == 1
+    assert (ragged_work_cap(T) * _RAGGED_MP,) in shapes  # the list
+
+
 # -- layer-stacked pools: the kernels read [L, NP, PS, Hk, D] at a layer ----
 _STACK_L = 4
 
