@@ -35,13 +35,14 @@ def expert_layer_params(model: dict, experts_hit: int) -> int:
     return e * model["n_experts"] + 3 * e * shared + experts_hit * 3 * e * f
 
 
-def weight_stream_bytes(model: dict, dtype: str = "bf16", experts_hit: int | None = None) -> int:
+def weight_stream_bytes(model: dict, dtype: str = "bf16", experts_hit: float | None = None) -> int | float:
     """Weights one step reads. `experts_hit` is how many routed experts of an
     expert layer the step's rows reach between them; the default is
     `n_experts_active`, the least a step can read (every row agrees), so a
     share reckoned without a counter is understated and never overstated. A
-    reader that has the measured number passes it; a path that computes
-    every expert streams `n_experts`."""
+    reader that has the measured number passes it (a mean over layers and
+    steps, so not a whole number); a path that computes every expert streams
+    `n_experts`."""
     l, n_exp = model["n_layers"], model.get("n_experts") or 0
     dense = (model.get("n_dense_layers") or 0) if n_exp else l
     total = l * attn_params(model) + dense * 3 * model["dim"] * model["ffn_dim"]

@@ -1,6 +1,10 @@
 """The bytes a median decode step has to read (every weight once + the live
 KV in the pool, from shapes: benchmark/costs.py) over the chip's peak HBM
-bandwidth (benchmark/peaks.json), as a share of the measured step (%)."""
+bandwidth (benchmark/peaks.json), as a share of the measured step (%). Of a
+routed model's experts a step has to read the ones its rows picked: the
+median `moe_experts_hit` of the window's decode iterations (the flight
+recorder's counter), and `n_experts_active`, the least a step can read, where
+the program records none."""
 import os
 
 import costs
@@ -16,5 +20,9 @@ def read(ctx):
     eng = ctx["ready"]["engine"]
     usage = ctx["percentile"]([i["kv_usage"] for i in dec], 50)
     live = usage * eng["num_pages"] * eng["page_size"]
-    need = costs.weight_stream_bytes(ctx["model"]) + live * costs.kv_bytes_per_token(ctx["model"])
+    hit = None
+    if ctx["model"].get("n_experts"):
+        hit = ctx["percentile"]([i.get("moe_experts_hit", 0.0) for i in dec], 50) or None
+    need = (costs.weight_stream_bytes(ctx["model"], experts_hit=hit)
+            + live * costs.kv_bytes_per_token(ctx["model"]))
     return 100.0 * (need / peaks["hbm_bytes_per_s"]) / (step / 1e3)

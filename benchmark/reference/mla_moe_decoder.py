@@ -42,6 +42,10 @@ serves (random weights make either convention a valid model):
     publication defines them with sigmoid; no configuration pairs them with
     softmax).
 
+Followed mode (`follow_at`): the expert layers are computed with the experts
+the SERVED program picked, each pick first held against this reference's own
+float32 selection scores (`need_of`); see `follow_at`.
+
 `model` is the configuration file's `model` group (the program's ModelConfig
 field names), `params` the served tree: embed [V, E], norm_f [E], lm_head
 [E, V], layers / layers_dense.{attn_norm, kv_norm, mlp_norm [L, .]; wq (or
@@ -148,10 +152,9 @@ def _swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ _f32(gate)) * (x @ _f32(up))) @ _f32(down)
 
 
-def route(logits, bias, model):
-    """(selected experts [S, k], their mixing weights [S, k], the biased
-    selection scores [S, n]) from float32 router logits."""
-    k = int(model["n_experts_active"])
+def selection(logits, bias, model):
+    """(the unbiased scores [S, n], the selection scores [S, n]: the bias
+    added, the experts of a banned group at -inf) from float32 router logits."""
     if model.get("moe_scoring", "softmax") == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     else:
@@ -165,19 +168,53 @@ def route(logits, bias, model):
         kept = jnp.argsort(-best2, axis=-1)[:, :keep]
         allowed = jnp.zeros(best2.shape, bool).at[jnp.arange(best2.shape[0])[:, None], kept].set(True)
         choose = jnp.where(jnp.repeat(allowed, per, axis=-1), choose, -jnp.inf)
-    sel = jnp.argsort(-choose, axis=-1)[:, :k]
+    return scores, choose
+
+
+def mixing_weights(scores, sel, model):
+    """The weights of experts `sel` [S, k]: their UNBIASED scores,
+    renormalised over the set when `moe_norm_topk`, times the routed scale."""
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if model.get("moe_norm_topk", True):
         w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
-    return sel, w * float(model.get("moe_routed_scale", 1.0)), choose
+    return w * float(model.get("moe_routed_scale", 1.0))
 
 
-def _experts(x, lp, model):
-    logits = x @ _f32(lp["w_router"])
-    sel, w, choose = route(logits, lp.get("router_bias"), model)
-    k = sel.shape[-1]
-    ranked = -jnp.sort(-choose, axis=-1)
-    margin = ranked[:, k - 1] - ranked[:, k] if choose.shape[-1] > k else jnp.full(x.shape[:1], jnp.inf)
+def route(logits, bias, model):
+    """(selected experts [S, k], their mixing weights [S, k], the biased
+    selection scores [S, n]) from float32 router logits."""
+    scores, choose = selection(logits, bias, model)
+    sel = jnp.argsort(-choose, axis=-1)[:, : int(model["n_experts_active"])]
+    return sel, mixing_weights(scores, sel, model), choose
+
+
+def need_of(choose, picks):
+    """How far the selection scores `choose` [S, n] would have to move for
+    the set `picks` [S, k] to be their top k: the best score among the experts
+    passed over minus the weakest among the picks, 0 where the picks are the
+    top k already, inf for a pick from a banned group, a repeated id or an id
+    that is no expert's."""
+    n = choose.shape[-1]
+    real = ((picks >= 0) & (picks < n)).all(-1)
+    p = jnp.clip(picks, 0, n - 1)
+    inside = jnp.zeros(choose.shape, bool).at[jnp.arange(p.shape[0])[:, None], p].set(True)
+    weakest = jnp.take_along_axis(choose, p, axis=-1).min(-1)
+    passed_over = jnp.where(inside, -jnp.inf, choose).max(-1)
+    sound = real & (inside.sum(-1) == p.shape[-1]) & ~jnp.isneginf(weakest)
+    return jnp.where(sound, jnp.maximum(passed_over - weakest, 0.0), jnp.inf)
+
+
+def _experts(x, lp, model, picks, follow):
+    """The expert layer's output for x [S, E], computed with the experts
+    `picks` [S, k] where `follow` (a traced flag: one program serves both
+    modes, so the reference's own picks handed back give the same bits) and
+    with the reference's own otherwise; (output, need [S], the experts used)."""
+    scores, choose = selection(x @ _f32(lp["w_router"]), lp.get("router_bias"), model)
+    own = jnp.argsort(-choose, axis=-1)[:, : picks.shape[-1]]
+    sel = jnp.where(follow, picks, own).astype(own.dtype)
+    need = need_of(choose, sel)
+    sel = jnp.clip(sel, 0, choose.shape[-1] - 1)
+    w = mixing_weights(scores, sel, model)
 
     def token(args):
         xt, st, wt = args  # [E], [k], [k]: only this token's experts are read
@@ -188,25 +225,30 @@ def _experts(x, lp, model):
     out = jax.lax.map(token, (x, sel, w), batch_size=TOKEN_BLOCK)
     if "ws_gate" in lp:
         out = out + _swiglu(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-    return out, margin
+    return out, need, sel
 
 
-def _layer(h, lp, pos, inv, sizes, m, soft):
+def _layer(h, lp, pos, inv, sizes, m, soft, picks=None, follow=False):
+    """(the residual stream after the layer, (need [S], experts used [S, k])
+    of an expert layer or None of a dense one)."""
     model = dict(sizes)
     h = _attention(h, lp, pos, model, inv, m, soft)
     x = _rms(h, _f32(lp["mlp_norm"]), float(model["norm_eps"]))
     if "w_router" in lp:
-        y, margin = _experts(x, lp, model)
-        return h + y, margin
-    return h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), jnp.full(h.shape[:1], jnp.inf)
+        if picks is None:  # called without: the reference's own (tests/test_moe_routing_trace.py)
+            picks = jnp.zeros((h.shape[0], int(model["n_experts_active"])), jnp.int32)
+        y, need, sel = _experts(x, lp, model, picks, follow)
+        return h + y, (need, sel)
+    return h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
 
 
 _step = jax.jit(_layer, static_argnums=(4, 5, 6))
 
 
-def hidden_states(model: dict, params, tokens: np.ndarray):
-    """(the residual stream [S, E] after the last layer, each token's
-    smallest routing margin over the expert layers [S])."""
+def hidden_states(model: dict, params, tokens: np.ndarray, picks=None):
+    """(the residual stream [S, E] after the last layer, need [S, L_moe], the
+    experts used [S, L_moe, k]). `picks` int [S, L_moe, k]: the experts to
+    compute every position's expert layers with; None: the reference's own."""
     inv, m, soft = rope_table(model)
     sizes = tuple(sorted((k, v) for k, v in model.items()
                          if isinstance(v, (bool, int, float, str))))
@@ -216,31 +258,68 @@ def hidden_states(model: dict, params, tokens: np.ndarray):
     inv = jax.device_put(jnp.asarray(inv, jnp.float32), dev)
     h = _f32(params["embed"][tok])
     n_dense = int(model.get("n_dense_layers") or 0) if "layers_dense" in params else 0
-    margin = jnp.full(tok.shape, jnp.inf)
+    routed = "w_router" in params["layers"]
+    n_moe = int(model["n_layers"]) - n_dense if routed else 0
+    k = int(model.get("n_experts_active") or 0)
+    follow = picks is not None
+    if follow:
+        picks = np.asarray(picks)
+        if picks.shape != (tok.shape[0], n_moe, k):
+            raise ValueError(f"picks {picks.shape}: want {(tok.shape[0], n_moe, k)}")
+    else:
+        picks = np.zeros((tok.shape[0], n_moe, k), np.int32)
+    picks = jax.device_put(jnp.asarray(picks, jnp.int32), dev)
+    flag = jax.device_put(jnp.asarray(follow), dev)
+    needs, used = [], []
     for l in range(int(model["n_layers"])):
         stack, i = (params["layers_dense"], l) if l < n_dense else (params["layers"], l - n_dense)
-        h, mg = _step(h, jax.tree.map(lambda a: a[i], stack), pos, inv, sizes, m, soft)
-        margin = jnp.minimum(margin, mg)
-    return h, margin
+        # one layer at a time, to where the embedding lives: a tree whose layer
+        # stacks are kept on the host (no room beside the program's own) works
+        lp = jax.device_put(jax.tree.map(lambda a: a[i], stack), dev)
+        if "w_router" not in lp:
+            h, _ = _step(h, lp, pos, inv, sizes, m, soft)
+            continue
+        h, (need, sel) = _step(h, lp, pos, inv, sizes, m, soft, picks[:, len(needs)], flag)
+        needs.append(need)
+        used.append(sel)
+    if not needs:
+        return h, jnp.zeros((tok.shape[0], 0)), picks
+    return h, jnp.stack(needs, axis=1), jnp.stack(used, axis=1)
 
 
-def check_at(model: dict, params, tokens: np.ndarray, at: list):
+def _logprobs(model, params, h, at):
+    h = _rms(h[jnp.asarray(at)], _f32(params["norm_f"]), float(model["norm_eps"]))
+    head = params["lm_head"] if "lm_head" in params else params["embed"].T
+    return np.asarray(jax.nn.log_softmax(h @ _f32(head), axis=-1))
+
+
+def follow_at(model: dict, params, tokens: np.ndarray, at: list, picks):
     """(log-softmax of the next-token distribution after each position in
-    `at`, for one sequence `tokens` [S]: float32 [len(at), V]; each of those
-    positions' routing margin: the smallest distance, over the expert layers,
-    between its k-th and (k+1)-th selection scores, float32 [len(at)], inf
-    where nothing is routed). A served bf16 program moves a selection score by
-    its own rounding, so under some margin the two sides may pick different
-    experts and the token's logits then differ by more than any tolerance.
-    Where that margin lies is a matter of the configuration's sizes, measured
-    and not known here: the caller holds it (`correct_routing_tie`)."""
+    `at`, for one sequence `tokens` [S]: float32 [len(at), V]; need, float32
+    [S, L_moe]), every expert layer of EVERY position computed with the served
+    experts `picks` int [S, L_moe, k] (ids over the router's full width) and
+    this reference's own unbiased float32 scores as their weights. `need` says
+    how far its own selection scores would have to move for the served set to
+    be its top k (`need_of`). A served bf16 program moves a selection score by
+    its own rounding and so picks, now and then, another expert than float32
+    arithmetic; what it then computes is right for ITS picks and far from what
+    this reference computes for its own, with no program at fault. So the
+    comparison follows the served picks, and holds each of them to be one that
+    the float32 scores nearly made: the caller holds `need` to a margin the
+    configuration states (`correct_routing_margin`). With this reference's own
+    picks (`own_picks`) handed back it returns `logprobs_at`'s rows bit for
+    bit and a need of 0 everywhere."""
     with jax.default_matmul_precision("highest"):
-        h, margin = hidden_states(model, params, tokens)
-        h = _rms(h[jnp.asarray(at)], _f32(params["norm_f"]), float(model["norm_eps"]))
-        head = params["lm_head"] if "lm_head" in params else params["embed"].T
-        return (np.asarray(jax.nn.log_softmax(h @ _f32(head), axis=-1)),
-                np.asarray(margin)[np.asarray(at)])
+        h, need, _ = hidden_states(model, params, tokens, picks)
+        return _logprobs(model, params, h, at), np.asarray(need)
+
+
+def own_picks(model: dict, params, tokens: np.ndarray) -> np.ndarray:
+    """The experts this reference routes every position to: int32 [S, L_moe, k]."""
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(hidden_states(model, params, tokens)[2])
 
 
 def logprobs_at(model: dict, params, tokens: np.ndarray, at: list) -> np.ndarray:
-    return check_at(model, params, tokens, at)[0]
+    with jax.default_matmul_precision("highest"):
+        return _logprobs(model, params, hidden_states(model, params, tokens)[0], at)

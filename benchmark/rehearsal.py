@@ -1,6 +1,6 @@
 """The sizes of a --rehearse run, for run.py and serve.py alike:
 rehearse.json, with the configuration's own optional `rehearse` group
-(`model`, `server_flags`, `correct_tolerance`, `correct_routing_tie`) laid
+(`model`, `server_flags`, `correct_tolerance`, `correct_routing_margin`) laid
 over it key by key, and the result laid over the configuration's `model` and
 `server_flags`. A configuration without the group rehearses at rehearse.json's
 sizes alone."""
@@ -13,7 +13,7 @@ import os
 
 def rehearsal_sizes(cfg: dict, here: str) -> dict:
     """{"model", "server_flags", "length_divisor", "correct_tolerance",
-    "correct_routing_tie"} of a rehearsal of configuration file `cfg`. The
+    "correct_routing_margin"} of a rehearsal of configuration file `cfg`. The
     ratio of query to KV heads is kept where the configuration has both and
     its group names no `n_kv_heads` of its own."""
     with open(os.path.join(here, "rehearse.json")) as f:
@@ -31,5 +31,6 @@ def rehearsal_sizes(cfg: dict, here: str) -> dict:
             "length_divisor": int(base["length_divisor"]),
             "correct_tolerance": float(own.get("correct_tolerance", base["correct_tolerance"])),
             # the margin is a reading at the rehearsal's own sizes: a
-            # configuration's full-size one says nothing about it
-            "correct_routing_tie": float(own.get("correct_routing_tie", 0.0))}
+            # configuration's full-size one says nothing about it (None: not
+            # stated, which serve.py refuses where the check follows picks)
+            "correct_routing_margin": own.get("correct_routing_margin")}

@@ -352,6 +352,9 @@ def run(args) -> dict:
         log("modules " + json.dumps({k: {"n": v["n"], "median_ms": v["median_ms"],
                                          "total_s": v["total_s"]}
                                      for k, v in tr["modules"].items()}))
+    # every number that decided `correct`, beside its limit: last in the line
+    result["compared"] = {**ready.get("compared", {}),
+                          "http_greedy_repeat_differs": [int(not identical), 0]}
     return result
 
 
@@ -372,6 +375,9 @@ def main() -> int:
             c.stop()
     if rc != 0 or result is None:
         return rc or 1
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     if args.rehearse:
         # a rehearsal measures nothing: names only, no value of any metric
         print(json.dumps({"rehearsal": True, "correct": result["correct"],

@@ -264,11 +264,18 @@ def check_prompts(config, sched, rng, rehearse: bool):
     return [(rng.integers(1, config.vocab_size, size=n).tolist(), k) for n, k in sizes]
 
 
-async def served(engine, sample, logprobs: bool):
+async def served(engine, sample, logprobs: bool, picks: bool = False):
     """Serve the whole sample through the engine at once, greedy:
-    [(tokens, logprobs)] per prompt. The lead starts alone; the rest are
-    submitted when its first token is out, so their prefills meet a live
-    decode row and all of them then decode side by side in one batch."""
+    [(tokens, logprobs, picks)] per prompt. The lead starts alone; the rest
+    are submitted when its first token is out, so their prefills meet a live
+    decode row and all of them then decode side by side in one batch.
+    `picks`: also ask for the experts the router chose (`routed_experts`,
+    docs/observability.md) and assemble them, int32 [n_prompt + n_out - 1,
+    L_moe, k], ids over the router's full width; None for a stream whose
+    items do not cover positions 0 .. n_prompt + n_out - 2 once each and in
+    order, and where nothing was asked."""
+    import numpy as np
+
     from dynamo_tpu.runtime.context import Context
 
     lead_out = asyncio.Event()
@@ -276,14 +283,19 @@ async def served(engine, sample, logprobs: bool):
     async def one(i: int, ids, n_out: int):
         if i > 0:
             await lead_out.wait()
-        toks, lps = [], []
-        payload = {"token_ids": list(ids),
-                   "sampling": {"temperature": 0.0, **({"logprobs": 0} if logprobs else {})},
+        toks, lps, routed, in_order = [], [], [], True
+        sampling = {"temperature": 0.0, **({"logprobs": 0} if logprobs else {}),
+                    **({"routed_experts": True} if picks else {})}
+        payload = {"token_ids": list(ids), "sampling": sampling,
                    "stop": {"max_tokens": n_out, "stop_ids": [], "ignore_eos": True}}
         try:
             async for item in engine.generate(payload, Context()):
                 toks += list(item.get("token_ids") or [])
                 lps += [e["logprob"] for e in item.get("logprobs") or []]
+                r = item.get("routed_experts")
+                if r:
+                    in_order = in_order and r["start"] == len(routed)
+                    routed += r["ids"]
                 if toks:
                     lead_out.set()
                 if item.get("finish_reason"):
@@ -292,66 +304,127 @@ async def served(engine, sample, logprobs: bool):
                     break
         finally:
             lead_out.set()  # a lead that fails must not hang the rest
-        return toks, lps
+        covered = picks and in_order and len(routed) == len(ids) + len(toks) - 1
+        return toks, lps, np.asarray(routed, np.int32) if covered else None
 
     return await asyncio.gather(*(one(i, ids, k) for i, (ids, k) in enumerate(sample)))
 
 
-MOST_LEFT_OUT = 0.5  # a check that leaves out more than half has checked too little
+# One number, here and in no configuration: the (position, expert layer)
+# pairs of a check whose served picks may lie beyond the configuration's
+# margin. Twelve seeds at each of two published sizes held it (PERF.md
+# section 6, PR 32), so a rule that forgives no pick stands.
+INADMISSIBLE_ALLOWED = 0
+UNBOUNDED_NEED = 1e9  # how a need that no margin admits (inf) reads in the check's line
+
+
+def follows(ref, model: dict) -> bool:
+    """Does the check of this model follow the served picks? Where the
+    reference offers the followed mode and the model routes at all."""
+    return bool(hasattr(ref, "follow_at") and model.get("n_experts"))
+
+
+def routing_margin(ref, model: dict, stated, where: str):
+    """The configuration's `correct_routing_margin` (None for a check that
+    does not follow). It has no default: a reading at the configuration's own
+    sizes, stated where the check follows and nowhere else."""
+    if follows(ref, model) != (stated is not None):
+        raise ValueError(
+            f"{where}: its reference " + (
+                "follows the served picks, so it has to state `correct_routing_margin`"
+                if stated is None else
+                "does not follow served picks, so `correct_routing_margin` means nothing"))
+    return None if stated is None else float(stated)
 
 
 def check_against_reference(ref, model: dict, params, sample, got, tol: float,
-                            routing_tie: float = 0.0) -> dict:
+                            margin=None) -> dict:
     """Teacher-force the plain float32 reference on prompt + served tokens.
     Where the engine gave logprobs, compare the logprob of each served token
     (`max`, `mean`); in every case measure how far the served token lies
     under the reference's best one (`gap`): a greedy token picked from logits
-    that are each within `tol` lies within 2 x tol of it. A reference of a
-    routed model offers `check_at` (logprobs and each position's routing
-    margin); a configuration that states a `correct_routing_tie` has the
-    tokens under it left out, counted in `left_out`, and they may be at most
-    half of all."""
+    that are each within `tol` lies within 2 x tol of it. A routed model
+    (`margin` stated): the reference computes every expert layer with the
+    experts the program served (`follow_at`), after holding each served set
+    against its own float32 selection scores: a set that those scores would
+    have to move by more than `margin` to choose is inadmissible, and none
+    may be. No token is left out, of a routed model's check or a dense one's."""
     import numpy as np
 
-    check_at = getattr(ref, "check_at", None) if routing_tie > 0 else None
-    worst, total, gap, n, short, per, left_out = 0.0, 0.0, 0.0, 0, False, [], 0
-    for (ids, n_out), (toks, lps) in zip(sample, got):
-        if len(toks) != n_out or len(lps) not in (0, n_out):
+    worst, total, gap, n, short, per = 0.0, 0.0, 0.0, 0, False, []
+    pairs, differ, inadmissible, need_max = 0, 0, 0, 0.0
+    for (ids, n_out), (toks, lps, picks) in zip(sample, got):
+        if len(toks) != n_out or len(lps) not in (0, n_out) or \
+                (margin is not None and picks is None):
             short = True
             continue
         seq = np.asarray(list(ids) + toks[:-1], np.int32)
         at = list(range(len(ids) - 1, len(seq)))
-        if check_at:
-            logp, margin = check_at(model, params, seq, at)
-            keep = np.asarray(margin) >= routing_tie
+        if margin is not None:
+            logp, need = ref.follow_at(model, params, seq, at, picks)
+            pairs, differ = pairs + need.size, differ + int((need > 0).sum())
+            inadmissible += int((need > margin).sum())
+            need_max = max(need_max, float(min(need.max(initial=0.0), UNBOUNDED_NEED)))
         else:
-            logp, keep = ref.logprobs_at(model, params, seq, at), np.ones(len(at), bool)
-        left_out += int((~keep).sum())
-        if not keep.any():
-            continue
+            logp = ref.logprobs_at(model, params, seq, at)
         want = logp[np.arange(len(toks)), np.asarray(toks)]  # logp [len(at), V] f32
-        under = float((logp.max(axis=-1) - want)[keep].max())
-        row = {"prompt": len(ids), "tokens": int(keep.sum()), "gap": round(under, 4)}
+        under = float((logp.max(axis=-1) - want).max())
+        row = {"prompt": len(ids), "tokens": len(toks), "gap": round(under, 4)}
         if lps:
-            err = np.abs(want - np.asarray(lps))[keep]
+            err = np.abs(want - np.asarray(lps))
             worst, total = max(worst, float(err.max())), total + float(err.sum())
             row.update(max=round(float(err.max()), 4), mean=round(float(err.mean()), 4))
-        gap, n = max(gap, under), n + int(keep.sum())
+        gap, n = max(gap, under), n + len(toks)
         per.append(row)
-    mean = total / max(n, 1)
-    # the bound on the worst token is `tol`; the mean over tokens is held to
-    # a third of it, which is the steadier of the two readings
-    out = {"max_abs_logprob_err": worst, "mean_abs_logprob_err": mean,
-           "max_gap_under_best": gap, "tokens": n, "tolerance": tol, "per_prompt": per,
-           "ok": bool(n > 0 and not short and worst <= tol and mean <= tol / 3
-                      and gap <= 2 * tol and left_out <= MOST_LEFT_OUT * (n + left_out))}
-    if check_at:
-        out.update(left_out=left_out, left_out_limit=MOST_LEFT_OUT * (n + left_out))
+    out = {"max_abs_logprob_err": worst, "mean_abs_logprob_err": total / max(n, 1),
+           "max_gap_under_best": gap, "tokens": n, "tolerance": tol, "per_prompt": per}
+    if margin is not None:
+        out.update(picks=pairs, picks_differ=differ, inadmissible=inadmissible,
+                   need_max=need_max, margin=margin)
+    out["ok"] = bool(n > 0 and not short
+                     and all(out[k] <= limit for k, limit in limits_of(out).items()))
     return out
 
 
-async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
-                          rehearse: bool, routing_tie: float = 0.0) -> dict:
+def limits_of(res: dict) -> dict:
+    """{number compared: its limit} of one pass's result. The bound on the
+    worst token is the tolerance; the mean over tokens is held to a third of
+    it, which is the steadier of the two readings; a greedy token picked from
+    logits that are each within the tolerance lies within twice that of the
+    reference's best; and of a routed model's picks none may be inadmissible."""
+    tol = res["tolerance"]
+    out = {"max_abs_logprob_err": tol, "mean_abs_logprob_err": tol / 3,
+           "max_gap_under_best": 2 * tol}
+    if "inadmissible" in res:
+        out["inadmissible"] = INADMISSIBLE_ALLOWED
+    return out
+
+
+def compared(checks: list) -> dict:
+    """{short name: [number, limit]} over the replicas' checks, for the
+    result line: every number that decided `correct` beside its limit (the
+    pass without logprobs compares no logprob)."""
+    out = {}
+    for i, check in enumerate(checks):
+        for name in ("logprobs", "ragged"):
+            for k, limit in limits_of(check[name]).items():
+                if name == "logprobs" or "logprob_err" not in k:
+                    out[f"r{i}.{name}.{k}"] = [check[name][k], limit]
+            if "need_max" in check[name]:  # what made a pick inadmissible, in the scores' unit
+                out[f"r{i}.{name}.need_max"] = [check[name]["need_max"], check[name]["margin"]]
+            # too few tokens or picks streamed, never two rows decoding at
+            # once, or the fused program not met: the pass's own verdict
+            out[f"r{i}.{name}.failed"] = [int(not check[name]["ok"]), 0]
+    return out
+
+
+def load_reference(cfg: dict):
+    return load_module(os.path.join(HERE, "reference", cfg["reference"] + ".py"),
+                       "bench_reference")
+
+
+async def reference_check(ref, model: dict, engine, seed: int, tol: float,
+                          rehearse: bool, margin=None, params=None) -> dict:
     """One replica's check, in two passes over samples of the same shape.
     With logprobs: each served token's logprob against the reference. A
     request that asks for logprobs never rides the fused mixed step (the
@@ -363,11 +436,12 @@ async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
     reference's best token (a model the ragged program shuts out rides the
     padded mixed program there). A pass that never decoded two rows at once,
     or a second pass that missed the fused program the engine runs, has
-    checked too little and fails."""
+    checked too little and fails. Both passes of a routed model (`margin`
+    stated) also ask for the router's picks, which changes no dispatch. The
+    reference reads the tree the runner serves, or `params` where the program
+    holds its weights in another form (a control under `quantize`)."""
     import numpy as np
 
-    ref = load_module(os.path.join(HERE, "reference", cfg["reference"] + ".py"),
-                      "bench_reference")
     r, s = engine.runner, engine.scheduler
     mixed_on = bool(s.mixed_prefill_tokens > 0 and r.ragged_mixed and engine.fused_mixed)
     padded_on = bool(s.mixed_prefill_tokens > 0 and engine.fused_mixed and not mixed_on
@@ -377,9 +451,13 @@ async def reference_check(cfg: dict, model: dict, engine, seed: int, tol: float,
         sample = check_prompts(engine.runner.config, s,
                                np.random.default_rng([seed, int(logprobs)]), rehearse)
         calls0 = {k: v.get("calls", 0) for k, v in r.compile_stats().items()}
-        t0 = time.time()
-        got = await served(engine, sample, logprobs)
-        res = check_against_reference(ref, model, r.params, sample, got, tol, routing_tie)
+        t0, m0 = time.time(), time.monotonic()
+        got = await served(engine, sample, logprobs, picks=margin is not None)
+        m1 = time.monotonic()
+        res = check_against_reference(ref, model, r.params if params is None else params,
+                                      sample, got, tol, margin)
+        log(f"check pass {name}: served in {m1 - m0:.1f}s, "
+            f"reference in {time.monotonic() - m1:.1f}s")
         res["calls"] = {k: v.get("calls", 0) - calls0.get(k, 0)
                         for k, v in r.compile_stats().items()}
         res["max_decode_rows"] = max(
@@ -429,13 +507,12 @@ async def amain(args) -> int:
         cfg = json.load(f)
     model = dict(cfg["model"])
     flags = dict(cfg["server_flags"])
-    tol = float(cfg["correct_tolerance"])
-    routing_tie = float(cfg.get("correct_routing_tie", 0.0))
+    tol, stated = float(cfg["correct_tolerance"]), cfg.get("correct_routing_margin")
     if args.rehearse:
         reh = load_module(os.path.join(HERE, "rehearsal.py"),
                           "bench_rehearsal").rehearsal_sizes(cfg, HERE)
         model, flags, tol = reh["model"], reh["server_flags"], reh["correct_tolerance"]
-        routing_tie = reh["correct_routing_tie"]
+        stated = reh["correct_routing_margin"]
 
     import jax
     import jax.numpy as jnp
@@ -448,6 +525,9 @@ async def amain(args) -> int:
     from dynamo_tpu.worker_common import serve_worker
 
     cache_dir = dynamo_tpu.enable_compilation_cache()
+    ref = load_reference(cfg)
+    margin = routing_margin(ref, model, stated, args.config + (
+        " (its `rehearse` group)" if args.rehearse else ""))
     devices = jax.devices()
     plat = devices[0].platform
     log(f"jax {jax.__version__} platform={plat} kind={devices[0].device_kind!r} "
@@ -508,7 +588,7 @@ async def amain(args) -> int:
     import numpy as np
 
     t = time.monotonic()
-    checks = [await reference_check(cfg, model, e, args.seed, tol, args.rehearse, routing_tie)
+    checks = [await reference_check(ref, model, e, args.seed, tol, args.rehearse, margin)
               for e in engines]
     log(f"reference check {time.monotonic() - t:.1f}s: {json.dumps(checks)}")
 
@@ -540,7 +620,8 @@ async def amain(args) -> int:
     sched = engines[0].scheduler
     write_json(os.path.join(args.run_dir, "ready.json"), {
         "device": {"platform": plat, "kind": devices[0].device_kind, "count": len(devices)},
-        "model": config.name, "correct": checks, "replicas": [b[2] for b in built],
+        "model": config.name, "correct": checks, "compared": compared(checks),
+        "replicas": [b[2] for b in built],
         "device_report": report, "memory": mem(), "ready_s": time.monotonic() - T0,
         "engine": {"max_batch": sched.max_batch, "decode_steps": sched.decode_steps,
                    "mixed_prefill_tokens": sched.mixed_prefill_tokens,

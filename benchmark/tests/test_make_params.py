@@ -125,7 +125,8 @@ def test_rehearsal_overlay():
     want["n_kv_heads"] = base["model"]["n_heads"]  # phi-3's ratio of 1 is kept
     assert r["model"] == want
     assert r["server_flags"] == {**phi["server_flags"], **base["server_flags"]}
-    assert r["correct_tolerance"] == base["correct_tolerance"] and r["correct_routing_tie"] == 0.0
+    assert r["correct_tolerance"] == base["correct_tolerance"]
+    assert r["correct_routing_margin"] is None  # a dense model states none
     assert r["length_divisor"] == base["length_divisor"]
     # its own group is laid over rehearse.json's, key by key
     with open(os.path.join(BENCH, "tests", "data", "fixture-mla-moe.json")) as f:
@@ -139,6 +140,13 @@ def test_rehearsal_overlay():
     assert r["server_flags"]["max-batch"] == own["server_flags"]["max-batch"]
     assert r["server_flags"]["page-size"] == base["server_flags"]["page-size"]
     assert r["correct_tolerance"] == own["correct_tolerance"]
+    # the margin is the group's own: the configuration's full-size one, had it
+    # one, would say nothing about the rehearsal's sizes
+    assert r["correct_routing_margin"] == own["correct_routing_margin"] == 2 ** -5
+    assert rehearsal.rehearsal_sizes(
+        {**fix, "correct_routing_margin": 0.5, "rehearse": {
+            k: v for k, v in own.items() if k != "correct_routing_margin"}},
+        BENCH)["correct_routing_margin"] is None
     ModelConfig(**r["model"])  # every key is a field of the program's
     # a group that names its own n_kv_heads keeps it; a model with no heads to
     # keep a ratio of needs none

@@ -49,12 +49,10 @@ def test_without_a_tpu_it_fails_and_prints_no_result():
     assert not r.stdout.strip().splitlines()[-1].startswith("{")
 
 
-@pytest.fixture(scope="module")
-def scratch_root(tmp_path_factory):
+def _scratch_root(root):
     """A root with the program and the benchmark linked in and a
     BENCHMARK.json of its own: the repo's, with the fixture configuration and
     one cell of it in place of the repo's configurations and cells."""
-    root = tmp_path_factory.mktemp("bench_root")
     for name in ("dynamo_tpu", "benchmark"):
         os.symlink(os.path.join(ROOT, name), root / name)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -69,6 +67,16 @@ def scratch_root(tmp_path_factory):
     return str(root)
 
 
+@pytest.fixture(scope="module")
+def scratch_root(tmp_path_factory):
+    return _scratch_root(tmp_path_factory.mktemp("bench_root"))
+
+
+def _fixture_check(r) -> dict:
+    line = next(ln for ln in r.stdout.splitlines() if "] reference check " in ln)
+    return json.loads(line.split("reference check ", 1)[1])[0]
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 def test_a_latent_attention_expert_cell_comes_in_as_files(scratch_root, trace):
     r = _run("--workload", "fixture-chat-steady", "--seed", str(2**31 + 17), "--seconds", "6",
@@ -77,13 +85,59 @@ def test_a_latent_attention_expert_cell_comes_in_as_files(scratch_root, trace):
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and last["correct"] is True
     assert last["attempted"] > 0 and last["failed"] == 0
-    # the check went through the latent-attention expert reference, left the
-    # routing near-ties out and counted them, and pass two rode the padded
-    # mixed program (the ragged one shuts latent attention out)
-    line = next(ln for ln in r.stdout.splitlines() if "] reference check " in ln)
-    check = json.loads(line.split("reference check ", 1)[1])[0]
+    # the check went through the latent-attention expert reference, which
+    # followed the served picks of every position (no token left out) and
+    # found each admissible, and pass two rode the padded mixed program (the
+    # ragged one shuts latent attention out)
+    check = _fixture_check(r)
     for name in ("logprobs", "ragged"):
-        assert 0 <= check[name]["left_out"] <= check[name]["left_out_limit"]
+        c = check[name]
+        assert len(c["per_prompt"]) == 5 and c["tokens"] == sum(p["tokens"] for p in c["per_prompt"])
+        assert c["inadmissible"] == 0 and c["need_max"] <= c["margin"]
+        assert c["picks"] == 2 * sum(p["prompt"] + p["tokens"] - 1 for p in c["per_prompt"])
     assert check["ragged"]["calls"]["mixed"] > 0 and check["ragged"]["calls"]["ragged"] == 0
     assert "runner.compiles_in_window" in last["metric_names"] if trace else \
         "setup_s" in last["metric_names"]
+    # every number compared stands beside its limit, last on standard error
+    compared = [ln.split() for ln in r.stderr.strip().splitlines() if ln.startswith("compared ")]
+    assert {"r0.logprobs.inadmissible", "r0.ragged.need_max", "r0.logprobs.max_abs_logprob_err",
+            "http_greedy_repeat_differs"} <= {c[1] for c in compared}
+    assert r.stderr.strip().splitlines()[-1].startswith("compared ")
+
+
+FAULT = """# the timed path broken underneath: the program's router hands back gate
+# weights 1.3 times what it computed (its picks are what they were)
+import dynamo_tpu.ops.moe_dispatch as _m
+
+_sound = _m.router_topk
+
+
+def _router_topk(*a, **kw):
+    weights, sel = _sound(*a, **kw)
+    return weights * 1.3, sel
+
+
+_m.router_topk = _router_topk
+"""
+
+
+def test_with_the_expert_layer_broken_underneath_it_is_not_correct(tmp_path):
+    """The rest of a run as it is (no look for a chip: --rehearse), the
+    program's expert layer broken through a sitecustomize.py in the scratch
+    root (run.py puts its root on the children's PYTHONPATH). The first expert
+    layer's picks are still the router's; its output is not what the
+    reference computes for them, so the logprobs fail, and the second expert
+    layer's router, fed that output, makes picks the reference's scores did
+    not nearly make."""
+    root = _scratch_root(tmp_path)
+    (tmp_path / "sitecustomize.py").write_text(FAULT)
+    r = _run("--workload", "fixture-chat-steady", "--seed", str(2**31 + 17), "--seconds", "6",
+             "--trace", "0", "--rehearse", root=root)
+    assert r.returncode != 0
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["rehearsal"] is True and last["correct"] is False
+    check = _fixture_check(r)
+    c = check["logprobs"]
+    assert not check["ok"] and not c["ok"]
+    assert c["max_abs_logprob_err"] > c["tolerance"] or c["inadmissible"] > 0
+    assert any(ln.startswith("compared ") for ln in r.stderr.splitlines())

@@ -47,7 +47,7 @@ def test_idle_shares_add_up_to_the_idle_time():
 
 
 @pytest.mark.parametrize("name", IDLE[:3])
-def test_idle_share_of_a_program_without_the_spans_is_left_out(name):
+def test_idle_share_of_a_program_without_the_spans_is_not_reported(name):
     old = trace_ctx([["engine.decode", 0.12], ["unattributed", 0.04], ["engine.mixed", 0.03]])
     assert reader(name)(old) is None
     assert reader(name)({"trace": None}) is None and reader(name)({}) is None
@@ -144,3 +144,59 @@ def test_decode_loop_of_the_dense_decoder_reads_as_before():
     assert reader("runner.decode_step_ms")(_loop_ctx(other, 32)) is None
     assert reader("runner.decode_step_ms")(_loop_ctx({}, 32)) is None
     assert reader("runner.decode_step_ms")({"trace": None, "model": {"n_layers": 32}}) is None
+
+
+# -- the weight stream of a decode step --------------------------------------
+
+
+def _stream_ctx(model, iterations, step_ms=20.0):
+    layers = model["n_layers"]
+    kernel = "decode_mla_attention" if model.get("attn_type") == "mla" else "decode_paged_attention"
+    return {"trace": {"modules": {f"jit_decode_loop[{kernel}]": {
+                "durations_ms": [4 * step_ms], "kernel_calls": [4 * layers],
+                "kernels": [{kernel: 4 * layers}]}}},
+            "model": model, "percentile": loadgen.percentile,
+            "here": os.path.dirname(LAYERS),
+            "ready": {"device": {"kind": "TPU v5 lite"},
+                      "engine": {"num_pages": 100, "page_size": 64}},
+            "counters": {"iterations": iterations}}
+
+
+PHI3 = {"vocab_size": 32064, "dim": 3072, "n_layers": 32, "n_heads": 32, "n_kv_heads": 32,
+        "ffn_dim": 8192}
+ROUTED = {"vocab_size": 1024, "dim": 2048, "n_layers": 9, "n_heads": 32, "n_kv_heads": 32,
+          "ffn_dim": 6144, "attn_type": "mla", "kv_lora_rank": 512, "q_lora_rank": 0,
+          "qk_rope_head_dim": 64, "qk_nope_head_dim": 128, "v_head_dim": 128, "n_experts": 128,
+          "n_experts_active": 6, "moe_ffn_dim": 768, "n_shared_experts": 2, "n_dense_layers": 1}
+
+
+def test_decode_stream_share_of_a_dense_model_is_the_expression_it_was():
+    import costs
+
+    its = [{"decode_seqs": 3, "kv_usage": 0.5, "moe_experts_hit": 0.0},
+           {"decode_seqs": 0, "kv_usage": 0.9, "moe_experts_hit": 0.0}]
+    got = reader("model.decode_stream_pct")(_stream_ctx(PHI3, its))
+    need = costs.weight_stream_bytes(PHI3) + 0.5 * 100 * 64 * costs.kv_bytes_per_token(PHI3)
+    assert got == 100.0 * (need / 819e9) / 20e-3
+    assert isinstance(costs.weight_stream_bytes(PHI3), int)  # no counter, no float
+    assert reader("model.decode_stream_pct")(_stream_ctx(PHI3, its[1:])) is None
+
+
+def test_decode_stream_share_of_a_routed_model_counts_the_experts_its_rows_hit():
+    import costs
+
+    read = reader("model.decode_stream_pct")
+    its = [{"decode_seqs": 4, "kv_usage": 0.25, "moe_experts_hit": h} for h in (21.7, 22.4, 23.0)]
+    live = 0.25 * 100 * 64 * costs.kv_bytes_per_token(ROUTED)
+    got = read(_stream_ctx(ROUTED, its))
+    assert got == pytest.approx(
+        100.0 * ((costs.weight_stream_bytes(ROUTED, experts_hit=22.4) + live) / 819e9) / 20e-3)
+    # 16.4 more experts than the floor, in each of 8 expert layers, 3 matrices each
+    floor = read(_stream_ctx(ROUTED, [dict(i, moe_experts_hit=0.0) for i in its]))
+    assert floor == pytest.approx(
+        100.0 * ((costs.weight_stream_bytes(ROUTED) + live) / 819e9) / 20e-3)
+    assert (got - floor) * 819e9 * 20e-3 / 100.0 == pytest.approx(
+        8 * 16.4 * 3 * 2048 * 768 * 2)
+    # a program that records no such counter reads the floor too
+    old = [{k: v for k, v in i.items() if k != "moe_experts_hit"} for i in its]
+    assert read(_stream_ctx(ROUTED, old)) == floor
