@@ -392,8 +392,8 @@ class InferenceEngine:
         self._routed_out: Dict[str, tuple] = {}  # rid -> (item field,
         #   whether it holds one position per emitted token: a decode row)
         self._rec_late: Optional[tuple] = None  # (IterationRecord, MoeLoad)
-        self.moe_totals = {"token_slots_total": 0, "experts_hit": 0.0,
-                           "load_max_share": 0.0}
+        self.moe_totals = {"token_slots_total": 0, "held_slots_total": 0.0,
+                           "experts_hit": 0.0, "load_max_share": 0.0}
         # sick peers for cross-worker pulls: instance -> retry-after time
         self._remote_fetch_backoff: Dict[int, float] = {}
         # disaggregation state
@@ -1287,14 +1287,16 @@ class InferenceEngine:
             self._rec_late = (record, load)
             return
         if load is not None:
-            slots, hit, share = load.result()
+            slots, hit, share, held = load.result()
             t = self.moe_totals
             t["token_slots_total"] += slots
+            t["held_slots_total"] += held
             t["experts_hit"], t["load_max_share"] = hit, share
             if record is not None:
                 record.moe_token_slots = slots
                 record.moe_experts_hit = hit
                 record.moe_load_max_share = share
+                record.moe_held_slots = held
         if record is not None:
             self.recorder.append(record)
 

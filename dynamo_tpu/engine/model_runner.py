@@ -74,7 +74,7 @@ def _chunk_load(config: ModelConfig, sel, valid):
     """routing_stats of one [N, S] prefill forward: sel [L_moe, N, S, k]."""
     L, N, S, k = sel.shape
     return routing_stats(sel.reshape(L, N * S, k), valid.reshape(N * S),
-                         config.n_experts)
+                         config)
 
 
 def _decode_loop(
@@ -206,7 +206,7 @@ def _decode_loop(
         if routed:
             picks = sel[0][:, :, 0]  # [L_moe, B, k]
             outs = outs + (picks, routing_stats(
-                picks, positions0 >= 0, config.n_experts))
+                picks, positions0 >= 0, config))
         if use_pen:
             r = jnp.arange(B, dtype=jnp.int32)
             cnt = cnt.at[r, s].add(1.0)
@@ -368,7 +368,7 @@ def _ragged_step(
     if routed:
         flat = sel[0][:, 0]  # [L_moe, T, k]
         out += ({"flat": flat, "load": routing_stats(
-            flat, positions[0] >= 0, config.n_experts)},)
+            flat, positions[0] >= 0, config)},)
     return out
 
 
@@ -617,15 +617,16 @@ class MoeLoad:
     def ready(self) -> bool:
         return all(isinstance(p.load, np.ndarray) for p in self._parts)
 
-    def result(self) -> Tuple[int, float, float]:
-        """(moe_token_slots, moe_experts_hit, moe_load_max_share): see
-        runtime/flight_recorder.IterationRecord."""
+    def result(self) -> Tuple[int, float, float, float]:
+        """(moe_token_slots, moe_experts_hit, moe_load_max_share,
+        moe_held_slots): see runtime/flight_recorder.IterationRecord."""
         _device_get_with_loads(self._parts)
         units = sum(p.forwards for p in self._parts) * self._n_layers
         if not units:
-            return 0, 0.0, 0.0
+            return 0, 0.0, 0.0, 0.0
         tot = np.sum([p.load for p in self._parts], axis=0, dtype=np.float64)
-        return int(round(tot[0])), float(tot[1] / units), float(tot[2] / units)
+        return (int(round(tot[0])), float(tot[1] / units),
+                float(tot[2] / units), float(tot[3] / self._n_layers))
 
 
 # Wire layout version for P→D / cross-worker KV payloads. v2 = token-major

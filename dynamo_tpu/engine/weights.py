@@ -190,7 +190,7 @@ def load_hf_checkpoint(
                                 f"model.layers.{i}.{moe_base}.experts.{e}.{p}.weight",
                                 transpose=True,
                             )
-                            for e in range(config.n_experts)
+                            for e in held_experts(config)
                         ]
                     )
                     for i in range(L)
@@ -219,6 +219,12 @@ def load_hf_checkpoint(
         params["lm_head"] = get("lm_head.weight", transpose=True)
     log.info("loaded HF checkpoint %s (%d files)", checkpoint_dir, len(files))
     return params
+
+
+def held_experts(c: ModelConfig) -> range:
+    """Ids of the routed experts whose tensors a load reads: the share
+    this chip holds (`n_experts_held` from `expert_first` on), or all."""
+    return range(c.expert_first, c.expert_first + c.experts_held)
 
 
 def _rope_deinterleave(d: int) -> np.ndarray:
@@ -285,15 +291,15 @@ def _load_mla(config: ModelConfig, tensors, get, get_f32,
             "w_router": get(pre + "gate.weight", True),
             "we_gate": np.stack([
                 get(f"{pre}experts.{e}.gate_proj.weight", True)
-                for e in range(c.n_experts)
+                for e in held_experts(c)
             ]),
             "we_up": np.stack([
                 get(f"{pre}experts.{e}.up_proj.weight", True)
-                for e in range(c.n_experts)
+                for e in held_experts(c)
             ]),
             "we_down": np.stack([
                 get(f"{pre}experts.{e}.down_proj.weight", True)
-                for e in range(c.n_experts)
+                for e in held_experts(c)
             ]),
         }
         if c.moe_router_bias:
@@ -357,9 +363,30 @@ def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConf
         cfg = {**defaults, **cfg["text_config"], "model_type": "gemma3_text"}
         mt = "gemma3_text"
     rope_kw = _rope_scaling_from_hf(cfg)
-    if mt.startswith("deepseek"):
+    if mt.startswith("deepseek") or mt == "mistral4":
+        # mistral4 (Mistral-Small-4) is the DeepSeek-V3 layer under
+        # Mistral's config keys: its rotary settings sit under
+        # `rope_parameters` (rope_theta among them), it has no
+        # `scoring_func` key (softmax over all experts, the family's
+        # published router) and it scales the query by position
+        # (`llama_4_scaling_beta`, over the yarn `original_max_position_
+        # embeddings`). Its checkpoints interleave the rotary pairs like
+        # DeepSeek's (`rope_interleave`), which _load_mla permutes.
+        rp = cfg.get("rope_parameters") or {}
+        if cfg.get("rope_interleave") is False:
+            raise ValueError(
+                "rope_interleave false: this loader permutes interleaved "
+                "rotary pairs on import and has no path that leaves them"
+            )
+        qscale_kw = {}
+        if rp.get("llama_4_scaling_beta"):
+            qscale_kw = dict(
+                attn_qscale_beta=float(rp["llama_4_scaling_beta"]),
+                attn_qscale_orig=int(rp["original_max_position_embeddings"]),
+            )
         return ModelConfig(
             **rope_kw,
+            **qscale_kw,
             n_expert_groups=int(cfg.get("n_group") or 0),
             topk_groups=int(cfg.get("topk_group") or 0),
             name=name or cfg.get("_name_or_path", "deepseek-hf"),
@@ -370,7 +397,8 @@ def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConf
             n_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
             ffn_dim=cfg["intermediate_size"],
             max_seq_len=cfg.get("max_position_embeddings", 8192),
-            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rope_theta=float(cfg.get("rope_theta") or rp.get("rope_theta")
+                             or 10000.0),
             norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
             tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
             attn_type="mla",
@@ -527,8 +555,10 @@ def _rope_scaling_from_hf(cfg: Dict[str, Any]) -> Dict[str, Any]:
     """HF rope_scaling dict → ModelConfig rope_* kwargs. Unknown scaling
     types raise — silently ignoring one produces numerically wrong
     long-context attention."""
-    rs = cfg.get("rope_scaling")
-    if not rs:
+    # newer configs (mistral4) keep the same keys under `rope_parameters`,
+    # beside rope_theta; read where `rope_scaling` is absent
+    rs = cfg.get("rope_scaling") or cfg.get("rope_parameters")
+    if not rs or not (rs.get("rope_type") or rs.get("type")):
         return {}
     kind = rs.get("rope_type") or rs.get("type") or ""
     if kind == "llama3":

@@ -123,6 +123,15 @@ class ModelConfig:
     # picks top-k experts within them
     n_expert_groups: int = 0
     topk_groups: int = 0
+    # This chip's share of the routed experts under expert parallelism
+    # (the model-configs guide's section 4): it holds `n_experts_held`
+    # of them from id `expert_first` on (0 held = all of them: every
+    # preset). `n_experts` stays the router's width: the router and its
+    # picks are over all experts, we_gate / we_up / we_down have the held
+    # count as their expert axis, and the block computes the held
+    # experts' part of the layer (models/moe.py).
+    n_experts_held: int = 0
+    expert_first: int = 0
     # RoPE long-context scaling (HF rope_scaling):
     #   "llama3" — Llama-3.1+ frequency smoothing (factor, low/high freq)
     #   "yarn"   — DeepSeek/Qwen yarn (factor, betas, mscale): also scales
@@ -147,6 +156,11 @@ class ModelConfig:
     qk_rope_head_dim: int = 0  # decoupled positional key dim (shared head)
     qk_nope_head_dim: int = 0  # per-head content key dim
     v_head_dim: int = 0
+    # position-dependent query scale of latent attention (Mistral-Small-4's
+    # `llama_4_scaling_beta`): the query of position p is multiplied by
+    # 1 + beta * ln(1 + floor(p / orig)); exactly 1 below `orig`. 0 = off
+    attn_qscale_beta: float = 0.0
+    attn_qscale_orig: int = 0
 
     def __post_init__(self):
         if not self.pre_norms and not self.post_norms:
@@ -155,6 +169,20 @@ class ModelConfig:
             raise ValueError(
                 "pre_norms=False requires post_norms=True (OLMo-2 style: "
                 "the branch outputs are normed instead of the inputs)"
+            )
+        if self.n_experts_held or self.expert_first:
+            if not (0 <= self.expert_first and 0 < self.n_experts_held
+                    and self.expert_first + self.n_experts_held <= self.n_experts):
+                raise ValueError(
+                    f"held experts [{self.expert_first}, {self.expert_first} + "
+                    f"{self.n_experts_held}) do not lie inside the router's "
+                    f"{self.n_experts}"
+                )
+        if self.attn_qscale_beta and (
+                self.attn_type != "mla" or self.attn_qscale_orig <= 0):
+            raise ValueError(
+                "attn_qscale_beta is latent attention's query scale and "
+                "needs attn_type='mla' and attn_qscale_orig > 0"
             )
 
     @property
@@ -172,6 +200,15 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this chip holds (all, unless told)."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def holds_share(self) -> bool:
+        return self.experts_held < self.n_experts
 
     @property
     def shared_ffn_dim(self) -> int:
@@ -415,6 +452,58 @@ PRESETS: Dict[str, ModelConfig] = {
         sliding_window=2047,
         sw_period=1,
         sw_global_residue=1,
+    ),
+    # latent attention with a compressed query and the position-dependent
+    # query scale (toy `orig`, so that it is not 1 in a short test), routed
+    # experts of which this chip holds the second quarter: Mistral-Small-4's
+    # structure at test size (CPU CI)
+    "tiny-mistral4": ModelConfig(
+        name="tiny-mistral4", n_layers=2, attn_type="mla", kv_lora_rank=32,
+        q_lora_rank=48, qk_rope_head_dim=16, qk_nope_head_dim=16,
+        v_head_dim=32, n_experts=16, n_experts_active=4, moe_ffn_dim=64,
+        n_shared_experts=1, n_experts_held=4, expert_first=4,
+        attn_qscale_beta=0.1, attn_qscale_orig=8, norm_eps=1e-6,
+        rope_theta=10000.0, rope_scaling="yarn", rope_factor=128.0,
+        rope_orig_max_seq=8, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+    ),
+    # Mistral-Small-4-119B-2603 (latent attention at rank 256 with a
+    # compressed query, 128 routed experts of width 2048, four a token,
+    # one shared; all 36 layers alike). 238 GB in bf16: a chip holds a
+    # share (n_experts_held / expert_first, benchmark/configs/
+    # mistral-small-4-119b.json)
+    "mistral-small-4-119b": ModelConfig(
+        name="mistral-small-4-119b",
+        vocab_size=131072,
+        dim=4096,
+        n_layers=36,
+        n_heads=32,
+        n_kv_heads=32,
+        ffn_dim=12288,  # unused (no dense layer)
+        max_seq_len=1048576,
+        rope_theta=10000.0,
+        norm_eps=1e-6,
+        attn_type="mla",
+        kv_lora_rank=256,
+        q_lora_rank=1024,
+        qk_rope_head_dim=64,
+        qk_nope_head_dim=64,
+        v_head_dim=128,
+        n_experts=128,
+        n_experts_active=4,
+        moe_ffn_dim=2048,
+        n_shared_experts=1,
+        moe_scoring="softmax",
+        moe_norm_topk=True,
+        moe_routed_scale=1.0,
+        rope_scaling="yarn",
+        rope_factor=128.0,
+        rope_orig_max_seq=8192,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_mscale=1.0,
+        rope_mscale_all_dim=1.0,
+        attn_qscale_beta=0.1,
+        attn_qscale_orig=8192,
     ),
     # Mistral 7B v0.1 (every-layer sliding window via the period-1
     # schedule: (l % 1) == 1 never holds, so no layer is global)

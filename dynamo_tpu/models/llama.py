@@ -172,12 +172,15 @@ def _init_layer_stack(config: ModelConfig, key: jax.Array, L: int,
             "post_mlp_norm": norm_init(L, c.dim),
         })
     if moe:
+        # the router (and its bias) at full width; the expert axis of the
+        # expert matrices is what this chip holds (all, unless told)
+        held = c.experts_held
         layers.update(
             {
                 "w_router": w(k[5], c.dim, L, c.dim, c.n_experts),
-                "we_gate": w(k[6], c.dim, L, c.n_experts, c.dim, c.moe_ffn_dim),
-                "we_up": w(k[7], c.dim, L, c.n_experts, c.dim, c.moe_ffn_dim),
-                "we_down": w(k[8], c.moe_ffn_dim, L, c.n_experts, c.moe_ffn_dim, c.dim),
+                "we_gate": w(k[6], c.dim, L, held, c.dim, c.moe_ffn_dim),
+                "we_up": w(k[7], c.dim, L, held, c.dim, c.moe_ffn_dim),
+                "we_down": w(k[8], c.moe_ffn_dim, L, held, c.moe_ffn_dim, c.dim),
             }
         )
         if c.moe_router_bias:  # DeepSeek-V3 e_score_correction_bias

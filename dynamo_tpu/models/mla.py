@@ -41,7 +41,9 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
     query and latent projections with their norms, RoPE and the cache
     write; `attn.absorb` W_UK folded into the query; `attn.kernel` the
     attention over the latent cache; `attn.lift` W_UV back to per-head
-    values. The output projection is the caller's `attn.proj`."""
+    values; `attn.qscale` (inside `attn.proj`) the position-dependent
+    query scale, where the model has one. The output projection is the
+    caller's `attn.proj`."""
     B, S = positions.shape
     H = c.n_heads
     dn, dr, dv, dc = (c.qk_nope_head_dim, c.qk_rope_head_dim,
@@ -55,6 +57,17 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
         else:
             q = mm(x, lp["wq"])
         q = q.reshape(B, S, H, dn + dr)
+        if c.attn_qscale_beta:
+            # the query of position p times 1 + beta ln(1 + floor(p / orig))
+            # (Mistral-Small-4's `llama_4_scaling_beta`): a scale of the
+            # scores that hangs on the query's position, so it goes onto
+            # the query, content and rotary part alike, where the static
+            # softmax scale goes into the kernel; in f32, then rounded
+            # once. Exactly 1 below `orig`.
+            with jax.named_scope("attn.qscale"):
+                qs = 1.0 + c.attn_qscale_beta * jnp.log1p(jnp.floor(
+                    safe_pos.astype(jnp.float32) / c.attn_qscale_orig))
+                q = (q.astype(jnp.float32) * qs[..., None, None]).astype(q.dtype)
         q_nope, q_r = q[..., :dn], q[..., dn:]
         q_r = rope(q_r, safe_pos, c.rope_theta, config=c)
 

@@ -7,6 +7,15 @@ every expert's weights read each step (128/6 = 21x at 128 experts, six a
 token), which is ROADMAP S4's open item. Shared experts (DeepSeek /
 Qwen2-MoE) stay out of the dispatch entirely.
 
+A chip that holds a share of the experts (`ModelConfig.n_experts_held`
+from `expert_first` on: one chip of an expert-parallel deployment, run
+without the others) routes over all `n_experts`, weighs the picks as the
+whole layer does, and computes the part of the layer its own experts
+give plus the shared experts. What the absent experts would add is left
+out and nothing stands in for them or their exchange; the picks stay ids
+over the router's full width. It computes every HELD expert for every
+token (S4 again, on a quarter of the weights).
+
 The block hands the router's picks out beside its output, and
 `routing_stats` reduces a forward's picks to the three expert-load
 counters of an engine iteration (docs/observability.md, "Routed
@@ -46,6 +55,12 @@ def _moe_block(c: ModelConfig, lp, x: jax.Array,
             if "ws_gatectl" in lp:  # qwen2-moe: sigmoid-gated shared expert
                 shared = shared * jax.nn.sigmoid(x @ lp["ws_gatectl"])
     ep = mesh is not None and mesh.shape.get("expert", 1) > 1
+    if ep and c.holds_share:
+        raise NotImplementedError(
+            "a held share of the experts (n_experts_held) is one chip of an "
+            "expert-parallel deployment; it does not combine with an expert "
+            "mesh axis"
+        )
     if ep and not is_quantized(lp["we_gate"]) and (B * S) % mesh.shape["expert"] == 0:
         from dynamo_tpu.ops.moe_dispatch import moe_ep
 
@@ -86,32 +101,48 @@ def _moe_block(c: ModelConfig, lp, x: jax.Array,
 
     with jax.named_scope("moe.experts"):
         expert_out = jax.vmap(one_expert)(lp["we_gate"], lp["we_up"], lp["we_down"])
-        # expert_out: [n_exp, B, S, E]; select & mix
+        # expert_out: [n_held, B, S, E]; select & mix
+        local = sel
+        if c.holds_share:
+            # a pick of an expert held elsewhere keeps its place among the
+            # k (the weights were renormalised over all of them) and adds
+            # nothing here
+            local = sel - c.expert_first
+            here = (local >= 0) & (local < c.experts_held)
+            weights = jnp.where(here, weights, 0)
+            local = jnp.clip(local, 0, c.experts_held - 1)
         sel_out = jnp.take_along_axis(
-            expert_out.transpose(1, 2, 0, 3),  # [B,S,n_exp,E]
-            sel[..., None],
+            expert_out.transpose(1, 2, 0, 3),  # [B,S,n_held,E]
+            local[..., None],
             axis=2,
         )  # [B,S,k,E]
         routed = jnp.sum(sel_out * weights[..., None], axis=2)
     return routed + shared, sel
 
 
-def routing_stats(sel: jax.Array, valid: jax.Array, n_experts: int) -> jax.Array:
+def routing_stats(sel: jax.Array, valid: jax.Array, c: ModelConfig) -> jax.Array:
     """One forward's expert load, reduced on the device. sel int32
-    [L_moe, T, k] (any token layout flattened to T), valid bool [T]
-    (False = padding, not counted). Returns f32 [3]:
+    [L_moe, T, k] (any token layout flattened to T; ids over the router's
+    full width), valid bool [T] (False = padding, not counted). The load
+    is counted over the experts this chip HOLDS (`c.expert_first`,
+    `c.experts_held`: all of them unless it holds a share), which are the
+    ones whose weights a step here can read. Returns f32 [4]:
       [0] routed token-slots: real tokens x k (one layer's; the same in all)
-      [1] experts selected at least once, summed over the expert layers
-      [2] the share of the real tokens that picked a layer's fullest
+      [1] held experts selected at least once, summed over the expert layers
+      [2] the share of the real tokens that picked a layer's fullest held
           expert (k / n_experts is even, 1.0 is one straggler), summed
           over the expert layers (0 in a forward with no real token)
-    The caller sums [1] and [2] over an iteration's forwards and divides by
-    forwards x L_moe (engine `_record_iteration`)."""
-    onehot = (sel[..., None] == jnp.arange(n_experts, dtype=sel.dtype)) \
-        & valid[None, :, None, None]
-    load = jnp.sum(onehot, axis=(1, 2), dtype=jnp.int32)  # [L_moe, n_experts]
+      [3] token-slots that fell to held experts, summed over the expert
+          layers ([0] x L_moe where every expert is held; a quarter of it
+          where a quarter is and the routing is even)
+    The caller sums them over an iteration's forwards and divides [1] and
+    [2] by forwards x L_moe and [3] by L_moe (model_runner.MoeLoad)."""
+    held = c.expert_first + jnp.arange(c.experts_held, dtype=sel.dtype)
+    onehot = (sel[..., None] == held) & valid[None, :, None, None]
+    load = jnp.sum(onehot, axis=(1, 2), dtype=jnp.int32)  # [L_moe, n_held]
     tokens = jnp.sum(valid, dtype=jnp.int32)
     hit = jnp.sum(load > 0, dtype=jnp.int32)
     share = jnp.sum(jnp.max(load, axis=-1) / jnp.maximum(tokens, 1))
     return jnp.stack([(tokens * sel.shape[-1]).astype(jnp.float32),
-                      hit.astype(jnp.float32), share.astype(jnp.float32)])
+                      hit.astype(jnp.float32), share.astype(jnp.float32),
+                      jnp.sum(load).astype(jnp.float32)])

@@ -266,8 +266,14 @@ def prefill_mla_attention(
     # VMEM budget: the f32 acc scratch is q_block*H x dc — at flagship MLA
     # dims (H=128, dc=512) a 128-row block would need ~34MiB of scratch
     # alone. Cap the block so acc stays ~<=4MiB; tiny test dims keep the
-    # requested block.
-    q_block = min(q_block, max(8, (4 << 20) // max(H * dc * 4, 1)))
+    # requested block. The query block, its f32 copy and the scores grow
+    # with the block's rows (q_block x H) whatever dc is: 2048 rows is what
+    # the two caps above come to at the geometries the chip has run (128
+    # heads at rank 512: 16 x 128; 16 heads at rank 512: 128 x 16), and at
+    # 32 heads and rank 256 (Mistral-Small-4) the acc cap alone allows 4096,
+    # which Mosaic refuses (19.7 MB of scoped VMEM against 16).
+    q_block = min(q_block, max(8, (4 << 20) // max(H * dc * 4, 1)),
+                  max(8, 2048 // H))
     q_block = min(q_block, S)
     while S % q_block:
         q_block -= 1

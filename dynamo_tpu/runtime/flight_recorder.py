@@ -61,17 +61,24 @@ class IterationRecord:
     record's own append), and it leaves out the inbox, the scheduler and
     any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`).
 
-    The three `moe_*` fields are a routed model's expert load, reduced on
+    The four `moe_*` fields are a routed model's expert load, reduced on
     the device from the router's picks over real rows (padding masked)
-    and read back with the sampled tokens; 0 / 0.0 for a dense model:
+    and read back with the sampled tokens; 0 / 0.0 for a dense model.
+    The load is over the experts this engine HOLDS: all `n_experts`, or
+    the `n_experts_held` from `expert_first` on of a chip that holds a
+    share (the picks themselves stay ids over the router's full width).
     `moe_token_slots` [token-slots] real tokens x experts a token, over
     the iteration's forwards (rows x steps x k + chunk tokens x k);
-    `moe_experts_hit` [experts, of n_experts] how many were picked at
+    `moe_held_slots` [token-slots] those of them that fell to held
+    experts, mean over expert layers (all of them where every expert is
+    held; a quarter where a quarter is and the routing is even);
+    `moe_experts_hit` [experts, of the held] how many were picked at
     least once in a forward, mean over expert layers and forwards (what
     a step reads of the expert weights where only the picked are read);
     `moe_load_max_share` [fraction] the share of a forward's real tokens
-    that picked the layer's fullest expert, the same mean (k / n_experts
-    is an even load, 1.0 is every token on one expert: a straggler).
+    that picked the layer's fullest held expert, the same mean (k /
+    n_experts is an even load, 1.0 is every token on one expert: a
+    straggler).
     A forward is one pass of the layers: a decode step, a prefill chunk
     set, a ragged step.
 
@@ -136,6 +143,7 @@ class IterationRecord:
     moe_token_slots: int = 0
     moe_experts_hit: float = 0.0
     moe_load_max_share: float = 0.0
+    moe_held_slots: float = 0.0
     # causal tracing: trace ids of the requests this iteration served
     # (bounded by the engine at append time) — joins the per-iteration
     # timeline to the distributed span rings and incident bundles

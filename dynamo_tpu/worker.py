@@ -33,6 +33,14 @@ def parse_args(argv=None):
     p.add_argument("--checkpoint", default=None,
                    help="HF safetensors checkpoint dir (config derived from its config.json)")
     p.add_argument("--model-name", default=None, help="served model name (default: config name)")
+    p.add_argument("--experts-held", type=int, default=0,
+                   help="routed experts this worker holds: one chip's share "
+                        "of an expert-parallel deployment (the router stays "
+                        "at full width, the worker computes its own experts' "
+                        "part of each layer and reads only their tensors "
+                        "from a checkpoint); 0 = all of them")
+    p.add_argument("--expert-first", type=int, default=0,
+                   help="id of the first routed expert held (with --experts-held)")
     p.add_argument("--shm-weights", default=None, metavar="NAME",
                    help="host shared-memory weight staging (gpu_memory_"
                         "service analog): attach the staged tree if a "
@@ -297,6 +305,9 @@ def build_runner(args, save_snapshot_ok: bool = True) -> tuple[ModelRunner, "obj
         config = config_from_hf(args.checkpoint, name=args.model_name or args.model)
     else:
         config = get_config(args.model)
+    if args.experts_held or args.expert_first:
+        config = config.with_(n_experts_held=args.experts_held,
+                              expert_first=args.expert_first)
 
     params = None
     # warm tier 1 — host-shm staging (gpu_memory_service analog,

@@ -458,11 +458,11 @@ async def serve_worker(
 
     # routed experts -> /metrics: the engine's expert-load counters
     # (IterationRecord.moe_*; docs/observability.md "Routed experts") as
-    # two gauges of the newest iteration and one running total. Only a
+    # two gauges of the newest iteration and two running totals. Only a
     # worker whose step programs hand out the picks has the series.
     if _runner is not None and _runner.routed:
         _mm = runtime.metrics.child(dynamo_namespace=namespace)
-        _moe_sent = {"slots": 0}
+        _moe_sent = {"slots": 0, "held": 0.0}
 
         def _update_moe_gauges(_m=None) -> None:
             t = engine.moe_totals
@@ -481,6 +481,13 @@ async def serve_worker(
                 "routed token-slots served (real tokens x experts a token)",
             ).inc(t["token_slots_total"] - _moe_sent["slots"])
             _moe_sent["slots"] = t["token_slots_total"]
+            _mm.counter(
+                "moe_held_slots_total",
+                "routed token-slots that fell to the experts this worker "
+                "holds, mean over expert layers (all of them unless it "
+                "holds a share)",
+            ).inc(t["held_slots_total"] - _moe_sent["held"])
+            _moe_sent["held"] = t["held_slots_total"]
 
         engine.on_fpm(_update_moe_gauges)
         _update_moe_gauges()

@@ -340,7 +340,9 @@ def test_dense_programs_return_what_they_returned(fused):
 
 def _count(picks, n_experts):
     """numpy twin of models/moe.routing_stats over forwards: picks
-    [forwards][L_moe, tokens, k] -> (slots, hit, share) as the record has them."""
+    [forwards][L_moe, tokens, k] -> (slots, hit, share, held slots) as the
+    record has them; every expert is held here, so every slot is a held one
+    (a held share: tests/test_mistral4.py)."""
     slots, hit, share, units = 0, 0.0, 0.0, 0
     for f in picks:
         L, T, k = f.shape
@@ -350,7 +352,7 @@ def _count(picks, n_experts):
             hit += (load > 0).sum()
             share += load.max() / T
             units += 1
-    return slots, hit / units, share / units
+    return slots, hit / units, share / units, float(slots)
 
 
 @pytest.mark.parametrize("name", ["mla", "gqa"])

@@ -92,3 +92,40 @@ def test_sharded_decode_kernel_compiles_for_v5e_2x2(topo, name):
     text = jax.jit(fn).lower(q, k, v, pt, kl, window, layer).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "all-reduce(" not in text and "all-gather(" not in text
+
+
+# -- latent attention at Mistral-Small-4's geometry (benchmark cell
+# mistral4-chat-steady): 32 heads, latent rank 256 + a rotary key of 64, so
+# the cached vector is 320 wide (2.5 lane tiles), 384 pages of 64 tokens under
+# a page table 64 wide. name -> (kernel, rows or chunk length)
+_MLA_SHAPES = {
+    "decode-b1": ("decode", 1), "decode-b16": ("decode", 16),
+    "prefill-s16": ("prefill", 16), "prefill-s256": ("prefill", 256),
+    # the acc cap alone allowed a 128-row query block here (4096 rows x 32
+    # heads), which Mosaic refuses: 19.7 MB of scoped VMEM against 16
+    "prefill-s512": ("prefill", 512),
+}
+
+
+@pytest.mark.parametrize("name", list(_MLA_SHAPES))
+def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
+    from dynamo_tpu.ops.mla_attention import decode_mla_attention, prefill_mla_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    kind, n = _MLA_SHAPES[name]
+    H, Dl, dc, NP, PS, MP = 32, 320, 256, 384, 64, 64
+    kw = dict(dc=dc, scale=128 ** -0.5 * (0.1 * np.log(128) + 1) ** 2)
+    pool = s((NP, PS, 1, Dl), jnp.bfloat16)
+    if kind == "decode":
+        fn = lambda q, l, pt, kl: decode_mla_attention(q, l, pt, kl, **kw)
+        args = (s((n, H, Dl), jnp.bfloat16), pool, s((n, MP), jnp.int32), s((n,), jnp.int32))
+    else:
+        fn = lambda q, l, pt, qs, ql, kl: prefill_mla_attention(q, l, pt, qs, ql, kl, **kw)
+        one = s((1,), jnp.int32)
+        args = (s((1, n, H, Dl), jnp.bfloat16), pool, s((1, MP), jnp.int32), one, one, one)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
