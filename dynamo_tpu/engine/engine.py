@@ -1287,7 +1287,7 @@ class InferenceEngine:
             self._rec_late = (record, load)
             return
         if load is not None:
-            slots, hit, share, held = load.result()
+            slots, hit, share, held, listed = load.result()
             t = self.moe_totals
             t["token_slots_total"] += slots
             t["held_slots_total"] += held
@@ -1297,6 +1297,7 @@ class InferenceEngine:
                 record.moe_experts_hit = hit
                 record.moe_load_max_share = share
                 record.moe_held_slots = held
+                record.moe_experts_listed = listed
         if record is not None:
             self.recorder.append(record)
 

@@ -48,7 +48,7 @@ log = logging.getLogger("dynamo_tpu.engine.runner")
 # than a dense model's, always and last: a dict of the router's picks as
 # the program laid them out (`decode` [n_steps, L_moe, B, k], `chunks`
 # [L_moe, N, S, k], `flat` [L_moe, T, k]; int32 expert ids) and `load`,
-# the f32 [3] expert-load counters of its forwards over real tokens
+# the f32 [5] expert-load counters of its forwards over real tokens
 # (models/moe.routing_stats, summed over the forwards). A dense model's
 # programs return what they always did. See ModelRunner._note_routed.
 
@@ -60,21 +60,23 @@ def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
     """The prefill step program of a routed model: llama.forward, with the
     picks of its chunk rows beside the pools. (A dense model's is
     llama.forward itself.)"""
-    logits, k_pool, v_pool, sel = llama.forward(
+    logits, k_pool, v_pool, sel, listed = llama.forward(
         config, params, tokens, positions, k_pool, v_pool, page_table,
         kv_lens, last_index, attn_impl=attn_impl, mesh=mesh,
         sp_has_prior=sp_has_prior, lora=lora, adapter_idx=adapter_idx,
         mm_embeds=mm_embeds, mm_mask=mm_mask, return_routed=True,
+        return_listed=True,
     )
     return logits, k_pool, v_pool, {
-        "chunks": sel, "load": _chunk_load(config, sel, positions >= 0)}
+        "chunks": sel,
+        "load": _chunk_load(config, sel, positions >= 0, listed)}
 
 
-def _chunk_load(config: ModelConfig, sel, valid):
+def _chunk_load(config: ModelConfig, sel, valid, listed):
     """routing_stats of one [N, S] prefill forward: sel [L_moe, N, S, k]."""
     L, N, S, k = sel.shape
     return routing_stats(sel.reshape(L, N * S, k), valid.reshape(N * S),
-                         config)
+                         config, listed)
 
 
 def _decode_loop(
@@ -167,7 +169,7 @@ def _decode_loop(
         logits, kp, vp, *sel = llama.forward(
             config, params, tok[:, None], pos[:, None], kp, vp, page_table, kvl,
             attn_impl=attn_impl, mesh=mesh, lora=lora, adapter_idx=adapter_idx,
-            return_routed=routed,
+            return_routed=routed, return_listed=routed,
         )
         raw = logits[:, 0, :]
         l = raw
@@ -206,7 +208,7 @@ def _decode_loop(
         if routed:
             picks = sel[0][:, :, 0]  # [L_moe, B, k]
             outs = outs + (picks, routing_stats(
-                picks, positions0 >= 0, config))
+                picks, positions0 >= 0, config, sel[1]))
         if use_pen:
             r = jnp.arange(B, dtype=jnp.int32)
             cnt = cnt.at[r, s].add(1.0)
@@ -272,7 +274,7 @@ def _mixed_loop(
     logits, k_pool, v_pool, *sel = llama.forward(
         config, params, ptok, ppos, k_pool, v_pool, ppt, pkvl, plast,
         attn_impl=attn_impl, mesh=mesh, lora=lora, adapter_idx=padapter,
-        return_routed=routed,
+        return_routed=routed, return_listed=routed,
     )
     toks, last, _, k_pool, v_pool, *dec = _decode_loop(
         config, attn_impl, mesh, n_steps, -1, params, tokens0, packed,
@@ -282,7 +284,7 @@ def _mixed_loop(
     out = (toks, last, chunk_logits, k_pool, v_pool)
     if routed:
         real = jnp.arange(ptok.shape[0], dtype=jnp.int32)[:, None] < prows
-        load = _chunk_load(config, sel[0], (ppos >= 0) & real)
+        load = _chunk_load(config, sel[0], (ppos >= 0) & real, sel[1])
         out += ({"chunks": sel[0], "decode": dec[0]["decode"],
                  "load": load + dec[0]["load"]},)
     return out
@@ -346,6 +348,7 @@ def _ragged_step(
         config, params, tokens, positions, k_pool, v_pool, tok_pt, tok_kvl,
         last_index=gather_idx, attn_impl=attn_impl, mesh=mesh,
         ragged=(seg_pt, seg_kvl, meta), return_routed=routed,
+        return_listed=routed,
     )
     seg_logits = logits[0]  # [SEG_CAP, V]
     # in-XLA sampling expansion: gather each row's base (per-seq) params,
@@ -368,7 +371,7 @@ def _ragged_step(
     if routed:
         flat = sel[0][:, 0]  # [L_moe, T, k]
         out += ({"flat": flat, "load": routing_stats(
-            flat, positions[0] >= 0, config)},)
+            flat, positions[0] >= 0, config, sel[1])},)
     return out
 
 
@@ -617,16 +620,18 @@ class MoeLoad:
     def ready(self) -> bool:
         return all(isinstance(p.load, np.ndarray) for p in self._parts)
 
-    def result(self) -> Tuple[int, float, float, float]:
+    def result(self) -> Tuple[int, float, float, float, int]:
         """(moe_token_slots, moe_experts_hit, moe_load_max_share,
-        moe_held_slots): see runtime/flight_recorder.IterationRecord."""
+        moe_held_slots, moe_experts_listed): see
+        runtime/flight_recorder.IterationRecord."""
         _device_get_with_loads(self._parts)
         units = sum(p.forwards for p in self._parts) * self._n_layers
         if not units:
-            return 0, 0.0, 0.0, 0.0
+            return 0, 0.0, 0.0, 0.0, 0
         tot = np.sum([p.load for p in self._parts], axis=0, dtype=np.float64)
         return (int(round(tot[0])), float(tot[1] / units),
-                float(tot[2] / units), float(tot[3] / self._n_layers))
+                float(tot[2] / units), float(tot[3] / self._n_layers),
+                int(round(tot[4])))
 
 
 # Wire layout version for P→D / cross-worker KV payloads. v2 = token-major

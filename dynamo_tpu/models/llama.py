@@ -37,7 +37,7 @@ from jax import lax
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.mla import _mla_attention
-from dynamo_tpu.models.moe import _moe_block
+from dynamo_tpu.models.moe import EXPERT_STACKS, _moe_block, experts_kernel_stack
 from dynamo_tpu.models.quant import embed_lookup, mm, tied_logits
 
 # The shared toolkit lives in models/toolkit.py (r5 split); these names
@@ -240,6 +240,8 @@ def forward(
     #   FLAT per-segment last-token indices.
     return_routed: bool = False,  # static: a routed model's step programs
     #   set it and get the router's picks as a fourth output
+    return_listed: bool = False,  # static, with return_routed: a fifth
+    #   output, what the expert kernels' work lists held (see below)
 ) -> Tuple[jax.Array, ...]:
     """One forward pass (covers prefill chunks S>1 and decode S=1).
 
@@ -254,12 +256,28 @@ def forward(
     pools: int32 [L_moe, B, S, k], the experts each token was routed to,
     expert layers in model order (the leading dense layers have none).
     They are the `ys` of the expert-layer scan, so they cost one small
-    output and no second pass.
+    output and no second pass. With `return_listed` a fifth follows:
+    int32 [L_moe], the entries of each expert layer's work list of hit
+    experts as its kernel was given it (models/moe.py; 0 in a forward
+    that took the dense path).
     """
     c = config
     B, S = tokens.shape
     if return_routed and not c.is_moe:
         raise ValueError("return_routed needs a model with routed experts")
+    if return_listed and not return_routed:
+        raise ValueError("return_listed rides on return_routed")
+    # a forward of few rows on the chip computes its routed experts from
+    # the layer-STACKED weights, read in place by a kernel (a slice of the
+    # stack in front of a custom call is a copy of it): the three stacks
+    # then stay out of what the layer scan slices
+    moe_stack = (experts_kernel_stack(c, params["layers"], B * S, mesh,
+                                      attn_impl) if c.is_moe else None)
+    scan_layers = params["layers"]
+    if moe_stack is not None:
+        scan_layers = {k: v for k, v in scan_layers.items()
+                       if k not in EXPERT_STACKS}
+    real_rows = positions >= 0
     if ragged is not None:
         if B != 1:
             raise ValueError("ragged forward takes a single flat [1, T] row")
@@ -354,6 +372,19 @@ def forward(
         rope_if_global = rope_inv_freq(c, hd, c.rope_theta)
         rope_if_local = rope_inv_freq(None, hd, c.rope_local_theta)
 
+    def moe_ffn(lp, x, l_idx):
+        stack = None
+        if moe_stack is not None:  # + this layer's index into the stacks
+            stack = moe_stack + (l_idx - c.n_dense_layers,)
+        return _moe_block(c, lp, x, mesh, real_rows, stack)
+
+    def routed_ys(routed):
+        """What an expert layer hands the scan: its picks (+ what its
+        work list held), if the caller asked."""
+        if not return_routed or routed is None:
+            return None
+        return tuple(routed) if return_listed else routed[0]
+
     def make_layer(use_moe):
         def layer(carry, xs):
             return _layer_body(carry, xs, use_moe)
@@ -376,7 +407,7 @@ def forward(
         # named scopes mark the parts of a layer in HLO metadata (an HLO
         # dump and xprof then say which part a fusion belongs to); they
         # change no computation
-        sel = None  # the router's picks, an expert layer's scan output
+        routed = None  # an expert layer's scan outputs (routed_ys)
         if c.is_mla:
             # _mla_attention names its own parts (attn.proj / absorb /
             # kernel / lift), matching the GQA path below
@@ -390,12 +421,12 @@ def forward(
             with jax.named_scope("ffn"):
                 x = rms_norm(h, lp["mlp_norm"], c.norm_eps)
                 if use_moe:
-                    ffw, sel = _moe_block(c, lp, x, mesh)
+                    ffw, *routed = moe_ffn(lp, x, l_idx)
                     h = h + ffw
                 else:
                     gate = jax.nn.silu(mm(x, lp["w_gate"]))
                     h = h + mm(gate * mm(x, lp["w_up"]), lp["w_down"])
-            return (h, k_pool, v_pool), (sel if return_routed else None)
+            return (h, k_pool, v_pool), routed_ys(routed)
 
         zc = c.norm_zero_centered
         with jax.named_scope("attn.proj"):
@@ -617,7 +648,7 @@ def forward(
                  if c.pre_norms else h)
             rm = c.residual_multiplier
             if use_moe:
-                ffw, sel = _moe_block(c, lp, x, mesh)
+                ffw, *routed = moe_ffn(lp, x, l_idx)
             else:
                 act = (
                     partial(jax.nn.gelu, approximate=True)
@@ -633,7 +664,7 @@ def forward(
             if rm != 1.0:  # Granite branch scaling
                 ffw = ffw * jnp.asarray(rm, ffw.dtype)
             h = h + ffw
-        return (h, k_pool, v_pool), (sel if return_routed else None)
+        return (h, k_pool, v_pool), routed_ys(routed)
 
     dense_stack = params.get("layers_dense")
     if dense_stack is not None:
@@ -652,14 +683,14 @@ def forward(
         (h, k_pool, v_pool), routed = lax.scan(
             make_layer(True),
             (h, k_pool, v_pool),
-            (params["layers"], {},
+            (scan_layers, {},
              jnp.arange(kD, c.n_layers, dtype=jnp.int32)),
         )
     else:
         (h, k_pool, v_pool), routed = lax.scan(
             make_layer(c.is_moe),
             (h, k_pool, v_pool),
-            (params["layers"], lora_layers,
+            (scan_layers, lora_layers,
              jnp.arange(c.n_layers, dtype=jnp.int32)),
         )
 
@@ -692,6 +723,8 @@ def forward(
         if c.final_logit_softcap:
             cap = c.final_logit_softcap
             logits = cap * jnp.tanh(logits / cap)
+    if return_listed:
+        return (logits, k_pool, v_pool) + routed  # + [L_moe]
     if return_routed:
         return logits, k_pool, v_pool, routed  # [L_moe, B, S, k]
     return logits, k_pool, v_pool

@@ -61,7 +61,7 @@ class IterationRecord:
     record's own append), and it leaves out the inbox, the scheduler and
     any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`).
 
-    The four `moe_*` fields are a routed model's expert load, reduced on
+    The `moe_*` fields are a routed model's expert load, reduced on
     the device from the router's picks over real rows (padding masked)
     and read back with the sampled tokens; 0 / 0.0 for a dense model.
     The load is over the experts this engine HOLDS: all `n_experts`, or
@@ -81,6 +81,14 @@ class IterationRecord:
     straggler).
     A forward is one pass of the layers: a decode step, a prefill chunk
     set, a ragged step.
+    `moe_experts_listed` [work-list entries] is the expert kernel's unit
+    of work (ops/moe_experts.py): the entries of the work lists of hit
+    experts the iteration's kernels walked, summed over forwards and
+    expert layers, taken from the live count each kernel was given (not
+    recomputed from the picks); 0 where the dense path ran (a prefill
+    chunk, a CPU, int8 experts, a mesh). Over an iteration whose forwards
+    all took the kernel it equals `moe_experts_hit` x forwards x expert
+    layers: padding rows list nothing.
 
     `decode_pages_live` [pages] is the decode kernel's unit of work: what
     one layer's calls walked this iteration, the sum over decode rows and
@@ -144,6 +152,7 @@ class IterationRecord:
     moe_experts_hit: float = 0.0
     moe_load_max_share: float = 0.0
     moe_held_slots: float = 0.0
+    moe_experts_listed: int = 0
     # causal tracing: trace ids of the requests this iteration served
     # (bounded by the engine at append time) — joins the per-iteration
     # timeline to the distributed span rings and incident bundles

@@ -164,7 +164,7 @@ def test_the_shares_add_up_to_the_whole_layer(ref, whole_ref, side):
             mine = {k: (v[c.expert_first: c.expert_first + 4] if k.startswith("we_") else v)
                     for k, v in lp.items()}
             if side == "program":
-                y, sel = _moe_block(c, mine, x)
+                y, sel, _ = _moe_block(c, mine, x)
                 y, sel = y[0], sel[0]
             else:
                 y, _, sel = ref._experts(x[0], mine, _model(c), picks, False)
@@ -211,7 +211,7 @@ def test_every_expert_held_is_bit_identical_to_the_parent(held, dtype):
     _, lp, x = _layer_inputs()
     lp = jax.tree.map(lambda a: a.astype(dtype) if a.dtype == jnp.float32 and a.ndim > 1 else a, lp)
     x = x.astype(dtype)
-    got, sel = jax.jit(lambda lp, x: _moe_block(c, lp, x))(lp, x)
+    got, sel, _ = jax.jit(lambda lp, x: _moe_block(c, lp, x))(lp, x)
     want, want_sel = jax.jit(lambda lp, x: _parent_block(c, lp, x))(lp, x)
     assert (np.asarray(sel) == np.asarray(want_sel)).all()
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
@@ -379,7 +379,7 @@ def test_routing_stats_count_the_held_experts():
     valid = np.array([True] * 7 + [False] * 2)
     got = np.asarray(routing_stats(jnp.asarray(sel, jnp.int32), jnp.asarray(valid), TOY))
     slots, hit, share, mine = _count([sel[:, :7]], 4, 4)
-    np.testing.assert_allclose(got, [slots, hit * 2, share * 2, mine * 2], rtol=1e-6)
+    np.testing.assert_allclose(got, [slots, hit * 2, share * 2, mine * 2, 0], rtol=1e-6)
     whole = np.asarray(routing_stats(jnp.asarray(sel, jnp.int32), jnp.asarray(valid), WHOLE))
     assert whole[3] == whole[0] * 2  # every expert held: every slot, in both layers
 

@@ -340,9 +340,11 @@ def test_dense_programs_return_what_they_returned(fused):
 
 def _count(picks, n_experts):
     """numpy twin of models/moe.routing_stats over forwards: picks
-    [forwards][L_moe, tokens, k] -> (slots, hit, share, held slots) as the
-    record has them; every expert is held here, so every slot is a held one
-    (a held share: tests/test_mistral4.py)."""
+    [forwards][L_moe, tokens, k] -> (slots, hit, share, held slots, listed)
+    as the record has them; every expert is held here, so every slot is a
+    held one (a held share: tests/test_mistral4.py), and on the CPU the
+    dense path runs, which lists nothing (the kernel's count:
+    tests/test_moe_experts_kernel.py)."""
     slots, hit, share, units = 0, 0.0, 0.0, 0
     for f in picks:
         L, T, k = f.shape
@@ -352,7 +354,7 @@ def _count(picks, n_experts):
             hit += (load > 0).sum()
             share += load.max() / T
             units += 1
-    return slots, hit / units, share / units, float(slots)
+    return slots, hit / units, share / units, float(slots), 0
 
 
 @pytest.mark.parametrize("name", ["mla", "gqa"])

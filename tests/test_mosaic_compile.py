@@ -129,3 +129,113 @@ def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
         args = (s((1, n, H, Dl), jnp.bfloat16), pool, s((1, MP), jnp.int32), one, one, one)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+# -- routed experts over a work list of hit experts (ops/moe_experts.py) at
+# the benchmark cell's geometry (mistral4-chat-steady: dim 4096, experts of
+# width 2048, 32 held of a router 128 wide, 4 a token, six layers stacked)
+# and at a whole layer of 8 wide experts, 2 a token (Mixtral's widths).
+# name -> (rows, E, F, held, first, k, L)
+_EXPERT_SHAPES = {
+    "cell-t8": (8, 4096, 2048, 32, 32, 4, 6),
+    "cell-t16": (16, 4096, 2048, 32, 32, 4, 6),
+    "cell-t32": (32, 4096, 2048, 32, 32, 4, 6),
+    "whole8-t8": (8, 4096, 14336, 8, 0, 2, 2),
+}
+
+
+def _slab_ops(text, held, E, F):
+    """HLO instructions whose result is one layer's slab of an expert stack
+    ([held, E, F] or [held, F, E], with or without a leading 1): a copy or
+    a slice of the stack in front of the kernel (ROADMAP S6a)."""
+    import re
+
+    slab = re.compile(
+        rf"= bf16\[(1,)?{held},({E},{F}|{F},{E})\]\S* (?!parameter)")
+    return [l.strip()[:160] for l in text.splitlines() if slab.search(l)]
+
+
+@pytest.mark.parametrize("name", list(_EXPERT_SHAPES))
+def test_routed_experts_kernel_compiles_for_v5e(topo, name):
+    """The list built in XLA from the picks, then one Mosaic call on the
+    stacked weights at a traced layer: no slab of a stack in front of it."""
+    from dynamo_tpu.ops.moe_experts import hit_work_list, routed_experts
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    T, E, F, held, first, k, L = _EXPERT_SHAPES[name]
+
+    def fn(x, sel, w, valid, wg, wu, wd, layer):
+        work, n_work, wcol = hit_work_list(sel, w, valid, first, held)
+        return routed_experts(x, work, n_work, wcol, wg, wu, wd, layer)
+
+    bf = jnp.bfloat16
+    text = jax.jit(fn).lower(
+        s((T, E), bf), s((T, k), jnp.int32), s((T, k), bf), s((T,), jnp.bool_),
+        s((L, held, E, F), bf), s((L, held, E, F), bf), s((L, held, F, E), bf),
+        s((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "custom-call(" in text and "%routed_experts" in text  # no `attention` in its name
+    assert _slab_ops(text, held, E, F) == []
+
+
+def test_decode_loop_reads_the_expert_stacks_in_place(topo):
+    """The compiled decode loop of the cell's configuration (two of its
+    six layers, four fused steps, 32 rows): the expert kernel beside the
+    latent attention kernel in the layer scan, and no copy or slice of an
+    expert stack anywhere in the program. Handed the scan's slice of a
+    stack instead, the same kernel gets a copy of the slab in front of it,
+    which is how the check can see one."""
+    import json
+    import os
+    from functools import partial
+
+    from dynamo_tpu.engine.model_runner import _decode_loop
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops.moe_experts import hit_work_list, routed_experts
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", "mistral-small-4-119b.json")) as f:
+        c = ModelConfig(**json.load(f)["model"]).with_(n_layers=2)
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(
+        lambda: llama.make_kv_pool(c, 768, 64, dtype=jnp.bfloat16)))
+    B, MP, f32, i32 = 32, 64, jnp.float32, jnp.int32
+    samp = SamplingParams(s((B,), f32), s((B,), i32), s((B,), f32), s((B, 2), jnp.uint32),
+                          s((B,), f32), s((B,), f32), s((B,), f32))
+    text = jax.jit(partial(_decode_loop, c, "pallas", None, 4, -1)).lower(
+        params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None,
+        *pools, samp).compile().as_text()
+    held, E, F = c.experts_held, c.dim, c.moe_ffn_dim
+    kernels = {l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for l in text.splitlines() if "tpu_custom_call" in l and " = " in l}
+    assert kernels == {"decode_mla_attention", "routed_experts"}
+    assert _slab_ops(text, held, E, F) == []
+
+    def sliced(x, sel, w, valid, wg, wu, wd):
+        def layer(x, lp):  # the kernel on what a layer scan slices
+            work, n_work, wcol = hit_work_list(sel, w, valid, c.expert_first, held)
+            y = routed_experts(x, work, n_work, wcol, *(a[None] for a in lp), 0)
+            return x + y.astype(x.dtype), None
+        return jax.lax.scan(layer, x, (wg, wu, wd))[0]
+
+    bf = jnp.bfloat16
+    text = jax.jit(sliced).lower(
+        s((B, E), bf), s((B, 4), i32), s((B, 4), bf), s((B,), jnp.bool_),
+        s((2, held, E, F), bf), s((2, held, E, F), bf), s((2, held, F, E), bf),
+    ).compile().as_text()
+    assert _slab_ops(text, held, E, F)
