@@ -25,8 +25,13 @@ import numpy as np
 
 from dynamo_tpu.engine.kv_pool import KvEvent, NoSpace, PagePool
 
-from dynamo_tpu.engine.runner_api import BucketOverflowError, Runner
+from dynamo_tpu.engine.runner_api import (
+    BucketOverflowError,
+    Runner,
+    state_refusal,
+)
 from dynamo_tpu.engine.scheduler import (
+    STATE_NO_PREFIX,
     DecodePlan,
     MixedPlan,
     PrefillPlan,
@@ -225,6 +230,22 @@ class InferenceEngine:
         # callable(hint) -> payload that pulls blocks from a peer's
         # kv_host_fetch endpoint (None = feature off)
         self.remote_kv_fetch = None
+        # a model with state-space layers (Runner.holds_state): every
+        # sequence owns a state slot beside its pages. What moves KV by
+        # pages alone, or rolls tokens back, is refused here in words and
+        # not met halfway at run time (_state_refusal)
+        self._state_on = bool(runner.holds_state)
+        if self._state_on:
+            if host_kv_blocks > 0 or disk_kv_blocks > 0 or obj_kv_root or prefetch:
+                raise ValueError(self._state_refusal(
+                    "tier demotion of KV blocks (G2-G4, prefetch)"))
+            if spec_ngram:
+                raise ValueError(self._state_refusal(
+                    "speculative decoding (n-gram drafts verified in the "
+                    "mixed step)"))
+            if enable_prefix_cache:
+                log.info("prefix cache off: %s", STATE_NO_PREFIX)
+                enable_prefix_cache = False
         self.pool = PagePool(runner.num_pages, runner.page_size)
         # fork-on-branch CoW: the pool copies a forked tail page's device
         # KV through the runner
@@ -303,6 +324,11 @@ class InferenceEngine:
             host_onboard=self._onboard_from_host if self.host_pool is not None else None,
             spec_max_tokens=spec_max_tokens,
             spec_seg_budget=runner.spec_seg_budget,
+            # one slot for every sequence that can be active (a chunk a
+            # step packs beside the batch is an active sequence's), and the
+            # scratch slot
+            state_slots=(runner.ensure_state_slots(max_batch + 1)
+                         if self._state_on else 0),
         )
         # n-gram speculative decoding (docs/spec_decode.md): drafts ride
         # the mixed dispatch as ragged verify rows, so both the runner
@@ -735,6 +761,15 @@ class InferenceEngine:
         # roles stream exactly one completion per worker — no fan-out.
         if seq.disagg is None:
             seq.n_branches = max(1, min(16, int(seq.sampling.get("n") or 1)))
+        if self._state_on and (seq.disagg is not None or seq.n_branches > 1
+                               or seq.kv_import is not None):
+            what = ("disaggregated serving (KV export and import by pages)"
+                    if seq.disagg is not None or seq.kv_import is not None
+                    else "n > 1 sampling (fork-on-branch over shared pages)")
+            yield {"finish_reason": "error", "token_ids": [],
+                   "error": self._state_refusal(what)}
+            self._streams.pop(rid, None)
+            return
         if seq.logit_bias and (
             self.runner.has_draft
             or self.runner.pp
@@ -879,6 +914,9 @@ class InferenceEngine:
             self._spec_sampling_warned.discard(rid)
             if not finished:
                 self._inbox.put(("abort", rid))
+
+    def _state_refusal(self, what: str) -> str:
+        return state_refusal(self.runner.config.name, what)
 
     def _routed_refusal(self) -> Optional[str]:
         """Why this worker cannot stream `routed_experts` (None: it can)."""
@@ -1257,6 +1295,16 @@ class InferenceEngine:
             forks=self.pool.forks,
             trace_ids=trace_ids,
         )
+        if self._state_on:
+            sched = self.scheduler
+            record.state_slots_used = sched.state_slots_used
+            record.state_slots_total = sched.state_slots - 1
+            # the scan's work: every prefill chunk (standalone or in the
+            # ragged step) and the ragged step's decode rows, segments of
+            # one token. The decode loop's steps run the one-token update.
+            rows = rinfo["decode_seqs"] if rinfo["ragged"] else 0
+            record.ssm_scan_segments = rinfo["n_chunks"] + rows
+            record.ssm_scan_tokens = rinfo["chunk_tokens"] + rows
         self._settle_record(record, load)
 
     def _decode_pages_live(self, seqs, n_steps: int) -> int:
@@ -1765,6 +1813,7 @@ class InferenceEngine:
             prior_len=plan.start_pos,
             adapter=seq.adapter_idx,
             mm=mm_chunk,
+            **({"slot": seq.state_slot} if self._state_on else {}),
         )
         if self.runner.has_draft and seq.disagg != "prefill":
             # keep the draft model's KV pools in lockstep so spec decode
@@ -2242,6 +2291,13 @@ class InferenceEngine:
             "ragged_pages_live": out.pages_live,
         }
 
+    def _slots_kw(self, seqs: List[Sequence]) -> Dict[str, Any]:
+        """The decode rows' state slots, for a runner that holds state;
+        no keyword (and no work) for any other."""
+        if not self._state_on:
+            return {}
+        return {"slots": [s.state_slot for s in seqs]}
+
     def _mixed_fusible(self, plan: MixedPlan) -> bool:
         """Whether this MixedPlan can run as ONE dispatch (runner
         decode_multi_with_prefills). What the runner's programs carry is
@@ -2381,11 +2437,13 @@ class InferenceEngine:
                                 "table": p.seq.pages,
                                 "prior": p.start_pos,
                                 "adapter": p.seq.adapter_idx,
+                                **({"slot": p.seq.state_slot}
+                                   if self._state_on else {}),
                             }
                             for p in prefills
                         ],
                         adapters=adapters,
-                        **mixkw,
+                        **mixkw, **self._slots_kw(seqs),
                     )
                     break
                 except BucketOverflowError as e:
@@ -2490,7 +2548,7 @@ class InferenceEngine:
             adapters = [s.adapter_idx for s in seqs]
         sampled = self.runner.decode_multi(
             T, tokens, positions, page_tables, sp, step0,
-            adapters=adapters, **mkw,
+            adapters=adapters, **mkw, **self._slots_kw(seqs),
         )
         lp = None
         if n_lp >= 0:

@@ -33,9 +33,10 @@ from dynamo_tpu.engine.runner_api import (
     BucketOverflowError,
     MixedOut,
     Runner,
+    state_refusal,
 )
 from dynamo_tpu.engine.sampling import SamplingParams, sample
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import jamba, llama
 from dynamo_tpu.models.config import ModelConfig, mean_over_layers
 from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
@@ -112,6 +113,10 @@ def _decode_loop(
     # Unguided/dead rows sit in the shared DEAD state (all-True mask).
     # gpend != 0 advances at t=0 too (the ragged tail: tok0 was sampled
     # on device by the ragged step and never folded into gstate).
+    state=None,  # a state-space model's state pool (models/jamba.py): carried
+    # through the steps like the KV pools and returned last; None, and no
+    # operand, for every other model
+    slots=None,  # int32 [B] with it: each row's state slot
 ):
     """n_steps decode iterations fused in one jit: forward → sample → feed
     the sampled token back, entirely on device (lax.scan). Amortizes the
@@ -156,8 +161,12 @@ def _decode_loop(
     if use_guided:
         gtrans, gmask, gstate0, gpend = guided
 
+    hybrid = state is not None
+
     def body(carry, t):
-        gs = None
+        gs = st = None
+        if hybrid:
+            carry, st = carry[:-1], carry[-1]
         if use_guided:
             carry, gs = carry[:-1], carry[-1]
         if use_pen:
@@ -166,11 +175,19 @@ def _decode_loop(
             (tok, kp, vp), cnt, cnt_out = carry, None, None
         pos = jnp.where(positions0 < 0, -1, positions0 + t)
         kvl = jnp.where(positions0 < 0, 0, positions0 + t + 1)
-        logits, kp, vp, *sel = llama.forward(
-            config, params, tok[:, None], pos[:, None], kp, vp, page_table, kvl,
-            attn_impl=attn_impl, mesh=mesh, lora=lora, adapter_idx=adapter_idx,
-            return_routed=routed, return_listed=routed,
-        )
+        if hybrid:
+            logits, kp, vp, st = jamba.forward(
+                config, params, tok[:, None], pos[:, None], kp, vp,
+                page_table, kvl, attn_impl=attn_impl, mesh=mesh, state=st,
+                slots=slots,
+            )
+        else:
+            logits, kp, vp, *sel = llama.forward(
+                config, params, tok[:, None], pos[:, None], kp, vp,
+                page_table, kvl, attn_impl=attn_impl, mesh=mesh, lora=lora,
+                adapter_idx=adapter_idx, return_routed=routed,
+                return_listed=routed,
+            )
         raw = logits[:, 0, :]
         l = raw
         if use_pen:
@@ -218,11 +235,15 @@ def _decode_loop(
             nxt = (s, kp, vp)
         if use_guided:
             nxt = nxt + (gs,)
+        if hybrid:
+            nxt = nxt + (st,)
         return nxt, outs
 
     carry0 = (tokens0, k_pool, v_pool) + ((counts0, out0) if use_pen else ())
     if use_guided:
         carry0 = carry0 + (gstate0,)
+    if hybrid:
+        carry0 = carry0 + (state,)
     carry, ys = lax.scan(body, carry0, jnp.arange(n_steps, dtype=jnp.int32))
     last, k_pool, v_pool = carry[0], carry[1], carry[2]
     toks = ys[0]
@@ -236,6 +257,8 @@ def _decode_loop(
     out = (toks.T, last, lp, k_pool, v_pool)  # [B, n_steps], [B]
     if routed:
         out += ({"decode": ys[-2], "load": ys[-1].sum(0)},)
+    if hybrid:
+        out += (carry[-1],)
     return out
 
 
@@ -325,6 +348,10 @@ def _ragged_step(
     # (all-zero when no row is biased — same constant-treedef rule; lets
     # logit_bias rows ride the verify/mixed dispatch instead of pausing
     # speculation batch-wide)
+    state=None,  # a state-space model's state pool, returned last (see
+    # _decode_loop); None for every other model
+    seg_slots=None,  # int32 [3, SEG_CAP] with it: each segment's state
+    # slot, first flat token and tokens (models/jamba.forward)
 ):
     """The ragged mixed step: ONE forward serves the whole decode batch
     (each sequence a q_len=1 segment) and every packed prefill chunk from
@@ -344,12 +371,19 @@ def _ragged_step(
     seg_logits, k_pool, v_pool); a routed model's adds {"flat", "load"}
     (see _forward above)."""
     routed = config.is_moe
-    logits, k_pool, v_pool, *sel = llama.forward(
-        config, params, tokens, positions, k_pool, v_pool, tok_pt, tok_kvl,
-        last_index=gather_idx, attn_impl=attn_impl, mesh=mesh,
-        ragged=(seg_pt, seg_kvl, meta), return_routed=routed,
-        return_listed=routed,
-    )
+    if state is not None:
+        logits, k_pool, v_pool, state = jamba.forward(
+            config, params, tokens, positions, k_pool, v_pool, tok_pt,
+            tok_kvl, last_index=gather_idx, attn_impl=attn_impl, mesh=mesh,
+            ragged=(seg_pt, seg_kvl, meta), state=state, slots=seg_slots,
+        )
+    else:
+        logits, k_pool, v_pool, *sel = llama.forward(
+            config, params, tokens, positions, k_pool, v_pool, tok_pt,
+            tok_kvl, last_index=gather_idx, attn_impl=attn_impl, mesh=mesh,
+            ragged=(seg_pt, seg_kvl, meta), return_routed=routed,
+            return_listed=routed,
+        )
     seg_logits = logits[0]  # [SEG_CAP, V]
     # in-XLA sampling expansion: gather each row's base (per-seq) params,
     # then fold the verify position into the seed for j>0 rows. Matches
@@ -372,6 +406,8 @@ def _ragged_step(
         flat = sel[0][:, 0]  # [L_moe, T, k]
         out += ({"flat": flat, "load": routing_stats(
             flat, positions[0] >= 0, config, sel[1])},)
+    if state is not None:
+        out += (state,)
     return out
 
 
@@ -866,6 +902,23 @@ class ModelRunner(Runner):
         self.multihost = any(
             d.process_index != jax.process_index() for d in self.mesh.devices.flat
         )
+        # a model with state-space layers (models/jamba.py): each sequence
+        # owns a state slot beside its pages; the pool is sized by
+        # ensure_state_slots (the engine knows how many sequences it runs)
+        self.holds_state = bool(config.is_hybrid)
+        self.state = None  # {"S", "conv"} once ensured
+        self.state_slots = 0
+        if self.holds_state:
+            mc = self.mesh_config
+            self.has_verify_spec = False  # no state rollback
+            if mc.n_devices > 1:
+                raise NotImplementedError(
+                    "a state-space model is not sharded yet: its state pool "
+                    f"and mixers run on one device (mesh {mc.shape})")
+            if draft_config is not None or lora_slots > 0:
+                raise NotImplementedError(
+                    self._state_refusal(
+                        "speculative decoding with a draft model (and LoRA)"))
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -1021,12 +1074,22 @@ class ModelRunner(Runner):
         # the dispatches since the engine last took them (_note_routed);
         # bounded for callers that never do (warm-up walks, benches)
         self._routed_parts: "deque[_RoutedPart]" = deque(maxlen=64)
-        self._jit_forward = _family(
-            "forward",
-            partial(_forward if self.routed else llama.forward, self.config),
-            donate_argnums=(3, 4),  # k_pool, v_pool
-            static_argnames=("attn_impl", "mesh", "sp_has_prior"),
-        )
+        # a state-holding model's programs also take (and donate) the
+        # state pool, by keyword; no other model's jit hears of it
+        skw = {"donate_argnames": ("state",)} if self.holds_state else {}
+        if self.holds_state:
+            self._jit_forward = _family(
+                "forward", partial(jamba.forward, self.config),
+                donate_argnums=(3, 4), static_argnames=("attn_impl", "mesh"),
+                **skw,
+            )
+        else:
+            self._jit_forward = _family(
+                "forward",
+                partial(_forward if self.routed else llama.forward, self.config),
+                donate_argnums=(3, 4),  # k_pool, v_pool
+                static_argnames=("attn_impl", "mesh", "sp_has_prior"),
+            )
         self._jit_sample = jax.jit(sample)
         self._jit_decode_loop = _family(
             "decode_loop",
@@ -1034,6 +1097,7 @@ class ModelRunner(Runner):
             static_argnums=(0, 1),  # n_steps, n_logprobs
             static_argnames=("mask_fn",),  # guided per-step mask callback
             donate_argnums=(8, 9),  # k_pool, v_pool
+            **skw,
         )
         # one trampoline per runner: static-arg identity keys the jit
         # cache, so the guided-callback program compiles once per bucket
@@ -1046,6 +1110,7 @@ class ModelRunner(Runner):
         # cached identity (row_seq, row_j) maps per row cap — the mixed
         # path's no-op for the ragged step's in-XLA sampling expansion
         self._row_map_cache: Dict[int, Tuple[jax.Array, jax.Array]] = {}
+        self._row_slices_met: set = set()  # SEG_CAPs (_meet_row_slices)
         # device-resident guided DFA staging (combined transition/mask
         # tables keyed by schema uids) + state scratch; see _stage_guided
         self._guided_dev_cache: "OrderedDict[Any, Tuple[jax.Array, jax.Array]]" = (
@@ -1084,6 +1149,7 @@ class ModelRunner(Runner):
                 partial(_ragged_step, self.config, self.attn_impl,
                         self._fwd_mesh),
                 donate_argnums=(9, 10),  # k_pool, v_pool
+                **skw,
             )
         # device n-gram draft ring (_draft_ring_step): registered
         # UNCONDITIONALLY so spec-on and spec-off runners expose the same
@@ -1175,6 +1241,8 @@ class ModelRunner(Runner):
                 "shard_shape": list(k_leaf.sharding.shard_shape(k_leaf.shape)),
             },
             "kv_pool_bytes": self.kv_pool_bytes(),
+            "state_slots": self.state_slots,
+            "state_pool_bytes": self.state_slots * self.state_slot_bytes,
             "memory": memory,
         }
 
@@ -1187,6 +1255,9 @@ class ModelRunner(Runner):
         prior_len: int,
         adapter: int = 0,
         mm: Optional[Dict[str, Any]] = None,  # {"embeds": [n,E], "offsets": [n]}
+        slot: int = 0,  # a state-holding model: the sequence's state slot
+        #   (0: scratch). A chunk at start_pos 0 starts from zeros, a later
+        #   one from what the slot holds.
     ) -> jax.Array:
         """Run one prefill chunk for a single sequence. `tokens` are the
         uncomputed prompt tokens starting at absolute position `start_pos`;
@@ -1207,6 +1278,17 @@ class ModelRunner(Runner):
                 mesh=self.mesh, axis="pipe",
             )
             return logits[0, n - 1]
+        if self.holds_state:
+            if mm is not None:
+                raise NotImplementedError(
+                    "multimodal prefill is not wired for a state-space model")
+            logits, self.k_pool, self.v_pool, self.state = self._jit_forward(
+                self.params, tok, pos, self.k_pool, self.v_pool, pt, kv_lens,
+                jnp.int32(n - 1), attn_impl=self.attn_impl,
+                mesh=self._fwd_mesh, state=self._state_pool(),
+                slots=jnp.asarray([slot], jnp.int32),
+            )
+            return logits[0, 0]
         impl = "ring" if self.sp_enabled else self.attn_impl
         logits, self.k_pool, self.v_pool, *routed = self._jit_forward(
             self.params, tok, pos, self.k_pool, self.v_pool, pt, kv_lens,
@@ -1219,6 +1301,77 @@ class ModelRunner(Runner):
         )
         self._note_routed(routed, 1, chunk_lens=[n])
         return logits[0, 0]
+
+    # -- state slots (a model with state-space layers) ----------------------
+    @property
+    def state_slot_bytes(self) -> int:
+        if not self.holds_state:
+            return 0
+        return jamba.state_slot_bytes(self.config, conv_dtype=self.dtype)
+
+    def ensure_state_slots(self, slots: int) -> int:
+        """Hold a state pool of at least `slots` slots (slot 0 is scratch)
+        and say how many it has: 0 where the model keeps no state. Growing
+        allocates a zeroed pool, so it is for construction, before any
+        sequence owns a slot. `S` is float32, as the published model keeps
+        it; the convolution's inputs are the activations' dtype."""
+        if not self.holds_state:
+            return 0
+        if self.state is None or slots > self.state_slots:
+            sh = self.policy.replicated()
+            self.state = jax.jit(
+                partial(jamba.make_state_pool, self.config, int(slots),
+                        conv_dtype=self.dtype),
+                out_shardings={"S": sh, "conv": sh})()
+            self.state_slots = int(slots)
+        return self.state_slots
+
+    def _state_pool(self):
+        """The pool a step hands its program: whoever takes the runner
+        sizes it first (the engine does: ensure_state_slots)."""
+        if self.state is None:
+            raise RuntimeError(
+                f"{self.config.name} keeps a recurrent state a sequence and "
+                "nobody sized its pool: call ensure_state_slots(slots) first")
+        return self.state
+
+    def _state_kw(self, slots, B: int) -> Dict[str, Any]:
+        """_decode_loop's keywords for the rows' state slots at bucket B
+        (padding rows name the scratch slot and, having no position,
+        change none); nothing for a model without state."""
+        if not self.holds_state:
+            return {}
+        rows = np.zeros(B, np.int32)
+        if slots is not None:
+            rows[: len(slots)] = slots
+        return {"state": self._state_pool(), "slots": jnp.asarray(rows)}
+
+    def _seg_state_kw(self, slots, n_dec: int, chunks, seg_cap: int):
+        """_ragged_step's keywords: per segment (decode rows first, then
+        the chunks, as _prep_ragged lays them) its slot, first flat token
+        and token count; dead entries hold no tokens."""
+        if not self.holds_state:
+            return {}
+        seg = np.zeros((3, seg_cap), np.int32)
+        lens = [1] * n_dec + [len(c["tokens"]) for c in chunks]
+        n = len(lens)
+        if slots is not None:
+            seg[0, :n_dec] = slots
+        seg[0, n_dec:n] = [c.get("slot") or 0 for c in chunks]
+        seg[1, :n] = np.cumsum([0] + lens[:-1])
+        seg[2, :n] = lens
+        return {"state": self._state_pool(), "seg_slots": jnp.asarray(seg)}
+
+    def _keep_state(self, extra: list) -> list:
+        """A state-holding model's step returns its pool last: keep it,
+        and hand back what else followed the KV pools."""
+        if self.holds_state:
+            self.state = extra[-1]
+            return extra[:-1]
+        return extra
+
+    def _state_refusal(self, what: str) -> str:
+        return state_refusal(self.config.name, what)
 
     # -- routed experts ------------------------------------------------------
     def _note_routed(self, routed, forwards: int, n_dec: int = 0,
@@ -1435,6 +1588,8 @@ class ModelRunner(Runner):
         n_logprobs: int = -1,
         histories: Optional[List[List[int]]] = None,
         prompt_lens: Optional[List[int]] = None,
+        slots: Optional[List[int]] = None,  # a state-holding model: each
+        # row's state slot (None: scratch, the warm-up's dummies)
     ):
         """n_steps fused decode iterations (one host sync total). Page
         tables must already cover positions[i] + n_steps slots. Returns
@@ -1490,8 +1645,9 @@ class ModelRunner(Runner):
             toks, _, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
                 n_steps, n_logprobs, self.params, tok, packed_dev, hist,
                 mask_dev, bias_dev, self.k_pool, self.v_pool,
-                samp, self.lora, **mkw,
+                samp, self.lora, **mkw, **self._state_kw(slots, B),
             )
+            routed = self._keep_state(routed)
             self._note_routed(routed, n_steps, n_dec=n)
         with self._allow("token_readback"), annotate("engine.readback"):
             if n_logprobs >= 0:
@@ -1571,6 +1727,8 @@ class ModelRunner(Runner):
         mask_fn=None,  # GuidedMaskContext for the fused tail steps 1..n-1
         biases: Optional[np.ndarray] = None,  # [n_dec, V] logit-bias rows
         guided_dev=None,  # device guided DFA plan for the fused tail
+        slots: Optional[List[int]] = None,  # a state-holding model: each
+        # decode row's state slot; a chunk's rides its dict as "slot"
     ) -> MixedOut:
         """Fused mixed iteration: the decode batch's n_steps AND the
         token-budgeted prefill chunk set, one chunk or many, in one
@@ -1592,10 +1750,11 @@ class ModelRunner(Runner):
                 return self._decode_multi_with_prefills_ragged(
                     n_steps, tokens, positions, page_tables, sampling, step,
                     chunks, masks=masks, mask_fn=mask_fn, biases=biases,
-                    guided_dev=guided_dev,
+                    guided_dev=guided_dev, slots=slots,
                 )
             except BucketOverflowError as e:
-                if constrained:
+                if constrained or self.holds_state:
+                    # (a state-holding model has no padded program either)
                     # the padded fallback has no mask/bias plane; the
                     # engine sheds chunks and retries rather than dropping
                     # a guided row's constraint or a bias ban
@@ -1609,6 +1768,11 @@ class ModelRunner(Runner):
                 "guided masks / logit bias require the ragged mixed path "
                 "(can_fuse gates on it)"
             )
+        if self.holds_state:
+            raise NotImplementedError(
+                "a state-space model fuses a mixed plan on the ragged program "
+                f"alone, and this plan ({len(positions)} rows + {len(chunks)} "
+                "chunks) has more segments than it takes")
         n_dec = len(positions)
         with annotate("engine.stage"):
             chunk_half = self._prep_prefill_packed(chunks)
@@ -1834,6 +1998,7 @@ class ModelRunner(Runner):
         guided_dev=None,  # device guided DFA plan (decode_multi): step 0
         # rides the ragged mask operand (`masks`), the fused tail rides
         # the in-XLA advance
+        slots: Optional[List[int]] = None,
     ) -> MixedOut:
         """Ragged mixed iteration, two dispatches with T-bucket-only and
         decode-bucket-only compile keys respectively:
@@ -1853,16 +2018,19 @@ class ModelRunner(Runner):
             step_dev = jnp.int32(step)
             seg_mask = self._seg_mask(masks, seg_cap)
             seg_bias = self._seg_bias(biases, seg_cap)
+            skw = self._seg_state_kw(slots, n_dec, chunks, seg_cap)
         sampled, seg_logits, self.k_pool, self.v_pool, *routed = self._jit_ragged(
             self.params, ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl,
             meta, gather, self.k_pool, self.v_pool,
-            samp, row_seq, row_j, step_dev, seg_mask, seg_bias,
+            samp, row_seq, row_j, step_dev, seg_mask, seg_bias, **skw,
         )
+        routed = self._keep_state(routed)
         self._note_routed(routed, 1, n_dec,
                           [len(c["tokens"]) for c in chunks])
         B = _next_bucket(self.decode_buckets, n_dec)
         # both slices are eager programs enqueued behind the ragged step,
         # before the host blocks: the device never waits for them
+        self._meet_row_slices(sampled)
         tok0 = sampled[:B]  # decode rows lead the segment order
         chunk_logits = seg_logits[n_dec : n_dec + len(chunks)]  # [N, V]
         if n_steps > 1:
@@ -1888,8 +2056,9 @@ class ModelRunner(Runner):
             rest, _, _, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(  # dynlint: disable=DYN-J004
                 n_steps - 1, -1, self.params, tok0, packed_dev,
                 None, None, bias_dev, self.k_pool, self.v_pool,
-                samp, None, **mkw,
+                samp, None, **mkw, **self._state_kw(slots, B),
             )
+            routed = self._keep_state(routed)
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
             with annotate("engine.readback"):
                 tok0_h, rest_h = self._readback((tok0, rest))
@@ -1902,6 +2071,21 @@ class ModelRunner(Runner):
                 toks = np.asarray(self._readback(tok0))[:, None]
                 rows = _chunk_rows(chunk_logits, len(chunks))
         return MixedOut(toks, rows, True, pages_live)
+
+    def _meet_row_slices(self, sampled: jax.Array) -> None:
+        """`sampled[:B]` is one eager program a (SEG_CAP, decode bucket)
+        pair. The first dispatch at a T bucket runs it for every decode
+        bucket that fits, so that none compiles later, under traffic:
+        benchmark/serve.py's walk meets each T bucket but not each pair (a
+        T bucket no larger than the decode bucket, 40 rows and a 20-token
+        chunk at buckets 64 / 64, it cannot form from whole dummies)."""
+        cap = sampled.shape[0]
+        if cap in self._row_slices_met:
+            return
+        self._row_slices_met.add(cap)
+        for b in self.decode_buckets:
+            if b < cap:
+                sampled[:b]
 
     def verify_spec(
         self,
@@ -1948,6 +2132,8 @@ class ModelRunner(Runner):
         Raises BucketOverflowError when the plan exceeds the T bucket or
         the gather capacity (defensive — the scheduler budgets drafted
         tokens against both)."""
+        if self.holds_state:
+            raise NotImplementedError(self._state_refusal("speculative verify (no state rollback)"))
         from dynamo_tpu.ops.ragged_paged_attention import (
             RAGGED_MAX_SEGS, build_ragged_metadata, ragged_seg_cap,
         )
@@ -2456,6 +2642,8 @@ class ModelRunner(Runner):
         """Gather whole KV pages into fresh device buffers (no host copy).
         The gather materializes a new array, so the source pool can keep
         being donated by its engine's step loop afterwards."""
+        if self.holds_state:
+            raise NotImplementedError(self._state_refusal("KV export by pages (export_pages_device)"))
         idx = jnp.asarray(np.asarray(pages, np.int32))
         return self._dense_pages(self.k_pool, idx), self._dense_pages(self.v_pool, idx)
 
@@ -2463,6 +2651,8 @@ class ModelRunner(Runner):
         """Scatter device-staged pages into this pool's slots (the TPU
         analog of the reference's NIXL device-to-device transfer; the
         host-staged path below is the DCN fallback)."""
+        if self.holds_state:
+            raise NotImplementedError(self._state_refusal("KV import by pages (import_pages_device)"))
         idx = jnp.asarray(np.asarray(target_pages, np.int32))
         n = len(target_pages)
         self.k_pool = self._store_pages(self.k_pool, idx, k[:, offset : offset + n])
@@ -2502,6 +2692,8 @@ class ModelRunner(Runner):
         multi-host mesh the gather runs jitted with a replicated output
         sharding (an all-gather over ICI) so every process holds the full
         pages and the host read is local."""
+        if self.holds_state:
+            raise NotImplementedError(self._state_refusal("KV export by pages (export_pages: disaggregation, tier demotion)"))
         idx = jnp.asarray(np.asarray(pages, np.int32))
         if self.multihost:
             if not hasattr(self, "_jit_export_repl"):
@@ -2576,6 +2768,8 @@ class ModelRunner(Runner):
         independently, so the scheduler can dispatch prefill as soon as
         the shallow layers land while deeper groups are still in flight.
         Final pool contents are identical to a whole-sequence import."""
+        if self.holds_state:
+            raise NotImplementedError(self._state_refusal("KV import by pages (import_pages: disaggregation, tier onboarding, remote pulls)"))
         if payload.get("quant") == "int8_ts":
             return self._import_pages_quant(
                 target_pages, offset, payload, layer_groups)
@@ -2650,7 +2844,8 @@ class ModelRunner(Runner):
         try:
             return any(
                 getattr(a, "is_deleted", lambda: False)()
-                for a in jax.tree.leaves((self.k_pool, self.v_pool))
+                for a in jax.tree.leaves(
+                    (self.k_pool, self.v_pool, self.state))
             )
         except Exception:
             return True
@@ -2664,6 +2859,9 @@ class ModelRunner(Runner):
             self.draft_k_pool, self.draft_v_pool = self._new_kv_pools(
                 self.draft_config
             )
+        if self.state is not None:  # a failed step consumed it too
+            n, self.state = self.state_slots, None
+            self.ensure_state_slots(n)
 
     def _new_kv_pools(self, config: ModelConfig):
         """Zeroed (k, v) pools for `config`, allocated DIRECTLY under their

@@ -47,6 +47,15 @@ class MixedOut(tuple):
         return self
 
 
+def state_refusal(model_name: str, what: str) -> str:
+    """Why a worker whose model has state-space layers (holds_state) does
+    not do `what`: the one sentence every such refusal raises or streams,
+    from the runner and the engine alike."""
+    return (f"{what} is not built for a model with state-space layers "
+            f"({model_name}): a sequence's recurrent state lives in its state "
+            "slot, which this path neither moves, copies nor rolls back")
+
+
 def device_step(fn):
     """Marks a Runner method that enqueues device work, or changes state
     that device work depends on (the pools, the weights, a compile
@@ -88,11 +97,17 @@ class Runner:
     has_verify_spec = False  # verify_spec
     has_draft_ring = False  # ensure_draft_ring / draft_ring_reset / draft_step
     has_prefill_packed = False  # prefill_packed
+    holds_state = False  # the model has state-space layers: a sequence owns
+    #   a state slot beside its pages (ensure_state_slots), its steps take
+    #   `slots=` / `slot=` / a chunk's "slot", and whatever moves KV by pages
+    #   alone or rolls tokens back is refused
+    state_slots = 0  # slots of the state pool, scratch slot 0 among them
+    state_slot_bytes = 0  # one sequence's recurrent state, all layers
 
     # -- steps ---------------------------------------------------------------
     @device_step
     def prefill(self, tokens, start_pos, page_table_row, prior_len,
-                adapter=0, mm=None):
+                adapter=0, mm=None, slot=0):
         """One prefill chunk of one sequence; its last-token logits."""
         raise NotImplementedError
 
@@ -122,7 +137,7 @@ class Runner:
     def decode_multi(self, n_steps, tokens, positions, page_tables, sampling,
                      step, adapters=None, masks=None, biases=None,
                      mask_fn=None, guided_dev=None, n_logprobs=-1,
-                     histories=None, prompt_lens=None):
+                     histories=None, prompt_lens=None, slots=None):
         """n_steps fused decode iterations: sampled [rows, n_steps] on the
         host; with n_logprobs >= 0, (sampled, lp | None)."""
         raise NotImplementedError
@@ -140,7 +155,8 @@ class Runner:
     def decode_multi_with_prefills(self, n_steps, tokens, positions,
                                    page_tables, sampling, step, chunks,
                                    adapters=None, masks=None, mask_fn=None,
-                                   biases=None, guided_dev=None) -> MixedOut:
+                                   biases=None, guided_dev=None,
+                                   slots=None) -> MixedOut:
         raise NotImplementedError
 
     @device_step
@@ -173,6 +189,13 @@ class Runner:
     @device_step
     def draft_step(self, updates, k: int):
         raise NotImplementedError
+
+    @device_step
+    def ensure_state_slots(self, slots: int) -> int:
+        """Hold a state pool of at least `slots` slots and say how many
+        there are (holds_state runners; 0 where no model state is kept).
+        The engine calls it once, when it takes the runner."""
+        return 0
 
     @device_step
     def ensure_ragged_bucket(self, t: int) -> None:

@@ -36,6 +36,12 @@ def _chain_seed(seq: "Sequence") -> Optional[int]:
 
 log = logging.getLogger("dynamo_tpu.engine.scheduler")
 
+STATE_NO_PREFIX = (
+    "a model with state-space layers matches no prefix: a cached block is "
+    "usable only with the recurrent state at its boundary, which nothing "
+    "snapshots, so its scheduler runs without the prefix cache and without "
+    "a host tier (and publishes no stored blocks)")
+
 
 class SeqState(Enum):
     WAITING = "waiting"
@@ -73,6 +79,8 @@ class Sequence:
     pages: List[int] = field(default_factory=list)
     computed_len: int = 0
     n_shared_pages: int = 0  # leading pages from prefix-cache hits
+    state_slot: int = 0  # a state-holding model: where the sequence's
+    #   recurrent state lives while it is active (0: none held)
     hash_chain: List[int] = field(default_factory=list)  # registered block hashes
     finish_reason: Optional[str] = None
     n_preemptions: int = 0
@@ -183,6 +191,9 @@ class Scheduler:
         max_seq_tokens: int = 0,  # model context length (0 = page cap only)
         spec_max_tokens: int = 0,  # per-iteration cap on speculative
         #   draft tokens (0 = bounded by the mixed pool leftover alone)
+        state_slots: int = 0,  # a state-holding model (Runner.holds_state):
+        #   slots of the runner's state pool, scratch slot 0 among them: one
+        #   for every sequence that can be active, so max_batch bounds both
         spec_seg_budget: int = 0,  # sampled-row slots one ragged dispatch
         #   offers (decode rows + chunks + verify tokens); 0 = unbounded
     ):
@@ -212,6 +223,15 @@ class Scheduler:
         self.spec_seg_budget = max(0, spec_seg_budget)
         self.host_tier = host_tier
         self.host_onboard = host_onboard
+        self.state_slots = int(state_slots)
+        if self.state_slots and (enable_prefix_cache or host_tier is not None):
+            raise ValueError(STATE_NO_PREFIX)
+        if self.state_slots and self.state_slots - 1 < max_batch:
+            raise ValueError(
+                f"{self.state_slots} state slots (one of them scratch) do not "
+                f"give each of max_batch {max_batch} active sequences its own")
+        # free slots, lowest first; 0 is scratch and never handed out
+        self._free_slots: List[int] = list(range(self.state_slots - 1, 0, -1))
         self.waiting: deque[Sequence] = deque()
         self.active: List[Sequence] = []
         self.stats = SchedulerStats()
@@ -381,11 +401,26 @@ class Scheduler:
             s.spec_tree = kept
 
     # -- admission ---------------------------------------------------------
+    @property
+    def state_slots_used(self) -> int:
+        return max(0, self.state_slots - 1 - len(self._free_slots))
+
+    def _release_slot(self, seq: Sequence) -> None:
+        if seq.state_slot:
+            self._free_slots.append(seq.state_slot)
+            seq.state_slot = 0
+
     def _admit(self) -> None:
         while self.waiting and len(self.active) < self.max_batch:
             seq = self.waiting[0]
             if not self._try_allocate(seq):
                 break
+            if self.state_slots:
+                # every slot holder is active and there are max_batch slots,
+                # so one is free here. A sequence's first token starts from
+                # zeros whatever the slot held (models/jamba.py), so a slot
+                # needs no clearing
+                seq.state_slot = self._free_slots.pop()
             self.waiting.popleft()
             self.active.append(seq)
             seq.state = SeqState.PREFILL
@@ -509,6 +544,7 @@ class Scheduler:
         decode worker's pull, out of the active set."""
         seq.state = SeqState.FINISHED
         seq.finish_reason = "prefill_complete"
+        self._release_slot(seq)
         if seq in self.active:
             self.active.remove(seq)
 
@@ -525,6 +561,10 @@ class Scheduler:
         *not* yet computed, so computed_len = len(prompt) - 1."""
         if len(self.active) >= self.max_batch:
             return False
+        if self.state_slots:
+            raise ValueError(
+                "admission with transferred KV is not built for a model "
+                "with state-space layers: pages carry no recurrent state")
         if not self._try_allocate(seq):
             return False
         seq.computed_len = len(seq.prompt) - 1
@@ -543,7 +583,8 @@ class Scheduler:
         tail copied — so the branch starts exactly where the parent is:
         same computed KV, same hash chain, one prefill-sampled token away
         from its first decode step. No prefill pass, no allocation."""
-        if len(self.active) >= self.max_batch:
+        if len(self.active) >= self.max_batch or self.state_slots:
+            # (a state slot cannot be forked: the engine asks for no branch)
             self.pool.release(pages)
             return False
         branch.tokens = list(parent.tokens)
@@ -601,6 +642,7 @@ class Scheduler:
     def _preempt(self, seq: Sequence) -> None:
         log.info("preempting %s (recompute)", seq.request_id)
         self.pool.release(seq.pages)
+        self._release_slot(seq)  # it starts over from a zeroed state
         seq.pages = []
         seq.hash_chain = []
         seq.n_shared_pages = 0
@@ -653,6 +695,7 @@ class Scheduler:
         seq.state = SeqState.FINISHED
         seq.finish_reason = reason
         self.pool.release(seq.pages)
+        self._release_slot(seq)
         seq.pages = []
         seq.spec_draft = []
         seq.spec_tree = []

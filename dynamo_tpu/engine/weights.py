@@ -108,6 +108,8 @@ def load_hf_checkpoint(
         return _raw(name).astype(np.float32)
 
     L = config.n_layers
+    if config.is_hybrid:
+        return _load_jamba(config, get, get_f32)
     if config.is_mla:
         return _load_mla(config, tensors, get, get_f32, checkpoint_dir)
     first_q = get("model.layers.0.self_attn.q_proj.weight", transpose=True)
@@ -362,6 +364,8 @@ def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConf
             }
         cfg = {**defaults, **cfg["text_config"], "model_type": "gemma3_text"}
         mt = "gemma3_text"
+    if mt == "jamba":
+        return _jamba_config(cfg, name)
     rope_kw = _rope_scaling_from_hf(cfg)
     if mt.startswith("deepseek") or mt == "mistral4":
         # mistral4 (Mistral-Small-4) is the DeepSeek-V3 layer under
@@ -549,6 +553,118 @@ def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConf
         # probabilities un-renormalized (HF semantics)
         moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
     )
+
+
+def _jamba_config(cfg: Dict[str, Any], name: Optional[str]) -> ModelConfig:
+    """`model_type: jamba` with every MLP dense (models/jamba.py)."""
+    if int(cfg.get("num_experts") or 1) > 1:
+        raise NotImplementedError(
+            f"jamba with num_experts = {cfg['num_experts']}: the routed "
+            "MLPs of the larger Jamba models are not built; only the dense "
+            "configurations (num_experts 1) load")
+    if cfg.get("mamba_proj_bias"):
+        raise NotImplementedError("jamba with mamba_proj_bias is not built")
+    if not cfg.get("mamba_conv_bias", True):
+        raise NotImplementedError(
+            "jamba without mamba_conv_bias: the loader expects conv1d.bias")
+    rank = cfg.get("mamba_dt_rank", "auto")
+    if rank == "auto":
+        rank = -(-cfg["hidden_size"] // 16)
+    return ModelConfig(
+        name=name or cfg.get("_name_or_path") or "jamba",
+        vocab_size=cfg["vocab_size"],
+        dim=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+        ffn_dim=cfg["intermediate_size"],
+        max_seq_len=cfg.get("max_position_embeddings", 262144),
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=True,  # the forward reads the embedding as the head
+        mamba_d_state=int(cfg.get("mamba_d_state", 16)),
+        mamba_d_conv=int(cfg.get("mamba_d_conv", 4)),
+        mamba_dt_rank=int(rank),
+        mamba_expand=int(cfg.get("mamba_expand", 2)),
+        attn_layer_period=int(cfg.get("attn_layer_period", 8)),
+        attn_layer_offset=int(cfg.get("attn_layer_offset", 4)),
+    )
+
+
+# models/jamba.py's tree <-> `JambaForCausalLM`'s state dict: (our leaf,
+# their name under model.layers.{i}., how the array turns). "T": a Linear's
+# [out, in] is our [in, out]; "conv": conv1d.weight [d, 1, K] is our [K, d];
+# "A": A_log [d, N] is our [N, d].
+_JAMBA_EVERY = (("attn_norm", "input_layernorm.weight", ""),
+                ("mlp_norm", "pre_ff_layernorm.weight", ""),
+                ("w_gate", "feed_forward.gate_proj.weight", "T"),
+                ("w_up", "feed_forward.up_proj.weight", "T"),
+                ("w_down", "feed_forward.down_proj.weight", "T"))
+_JAMBA_MAMBA = (("w_in", "mamba.in_proj.weight", "T"),
+                ("w_conv", "mamba.conv1d.weight", "conv"),
+                ("b_conv", "mamba.conv1d.bias", ""),
+                ("w_x", "mamba.x_proj.weight", "T"),
+                ("dt_norm", "mamba.dt_layernorm.weight", ""),
+                ("b_norm", "mamba.b_layernorm.weight", ""),
+                ("c_norm", "mamba.c_layernorm.weight", ""),
+                ("w_dt", "mamba.dt_proj.weight", "T"),
+                ("b_dt", "mamba.dt_proj.bias", ""),
+                ("A_log", "mamba.A_log", "A"),
+                ("D", "mamba.D", ""),
+                ("w_out", "mamba.out_proj.weight", "T"))
+_JAMBA_ATTN = (("wq", "self_attn.q_proj.weight", "T"),
+               ("wk", "self_attn.k_proj.weight", "T"),
+               ("wv", "self_attn.v_proj.weight", "T"),
+               ("wo", "self_attn.o_proj.weight", "T"))
+_JAMBA_F32 = {"attn_norm", "mlp_norm", "b_conv", "dt_norm", "b_norm",
+              "c_norm", "b_dt", "A_log", "D"}  # the program's f32 leaves
+
+
+def _jamba_groups(c: ModelConfig):
+    """(subtree, its leaf table, the model layers it stacks in order)."""
+    every = list(range(c.n_layers))
+    return (("layers", _JAMBA_EVERY, every),
+            ("mamba", _JAMBA_MAMBA, [l for l in every if not c.is_attn_layer(l)]),
+            ("attn", _JAMBA_ATTN, list(c.attn_layers)))
+
+
+def _load_jamba(c: ModelConfig, get, get_f32) -> Dict[str, Any]:
+    def leaf(ours, name, how):
+        if ours in _JAMBA_F32:
+            a = get_f32(name)
+            return a.T if how == "A" else a
+        if how == "conv":  # [d, 1, K] -> [K, d]
+            a = get(name)
+            return np.ascontiguousarray(a[:, 0, :].T)
+        return get(name, transpose=how == "T")
+
+    params: Dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight"),
+        "norm_f": get_f32("model.final_layernorm.weight"),
+    }
+    for sub, table, at in _jamba_groups(c):
+        params[sub] = {
+            ours: np.stack([leaf(ours, f"model.layers.{l}.{theirs}", how)
+                            for l in at])
+            for ours, theirs, how in table}
+    return params
+
+
+def jamba_to_hf_state(c: ModelConfig, params: Dict[str, Any]) -> Dict[str, Any]:
+    """models/jamba.py's tree as `JambaForCausalLM`'s state dict (numpy
+    float32): _load_jamba's inverse, for the tests that hold the program and
+    its reference to transformers."""
+    f = lambda a: np.asarray(a, np.float32)
+    back = {"": lambda a: a, "T": lambda a: a.T, "A": lambda a: a.T,
+            "conv": lambda a: a.T[:, None, :]}
+    out = {"model.embed_tokens.weight": f(params["embed"]),
+           "lm_head.weight": f(params["embed"]),
+           "model.final_layernorm.weight": f(params["norm_f"])}
+    for sub, table, at in _jamba_groups(c):
+        for ours, theirs, how in table:
+            for i, l in enumerate(at):
+                out[f"model.layers.{l}.{theirs}"] = np.ascontiguousarray(
+                    back[how](f(params[sub][ours][i])))
+    return out
 
 
 def _rope_scaling_from_hf(cfg: Dict[str, Any]) -> Dict[str, Any]:

@@ -161,6 +161,19 @@ class ModelConfig:
     # 1 + beta * ln(1 + floor(p / orig)); exactly 1 below `orig`. 0 = off
     attn_qscale_beta: float = 0.0
     attn_qscale_orig: int = 0
+    # Mamba-1 selective state-space layers (Jamba; models/jamba.py): layer
+    # l is attention iff l % attn_layer_period == attn_layer_offset, and a
+    # Mamba mixer of inner width mamba_expand x dim otherwise. 0 states = no
+    # such layer: every preset but the jamba ones. A sequence then carries,
+    # besides its KV pages, one constant-size recurrent state (a state slot).
+    # Such a model's attention has no position term (the state-space layers
+    # carry the order of the tokens): the rope_* fields are not read.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
+    mamba_expand: int = 2
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
 
     def __post_init__(self):
         if not self.pre_norms and not self.post_norms:
@@ -184,6 +197,50 @@ class ModelConfig:
                 "attn_qscale_beta is latent attention's query scale and "
                 "needs attn_type='mla' and attn_qscale_orig > 0"
             )
+        if self.is_hybrid:
+            if not (0 <= self.attn_layer_offset < self.attn_layer_period
+                    and self.mamba_dt_rank > 0):
+                raise ValueError(
+                    "state-space layers need attn_layer_period > "
+                    "attn_layer_offset >= 0 and mamba_dt_rank > 0"
+                )
+            if (self.is_mla or self.is_moe or self.sliding_window
+                    or not self.tie_embeddings
+                    or self.attn_bias or self.qk_norm or self.post_norms
+                    or not self.pre_norms or self.act != "silu"):
+                raise ValueError(
+                    "a hybrid state-space model (models/jamba.py) is Jamba's: "
+                    "dense SwiGLU MLPs, tied embedding, grouped-query "
+                    "attention with no position term, window, bias or "
+                    "extra norm"
+                )
+
+    @property
+    def is_hybrid(self) -> bool:
+        """State-space layers beside the attention layers (Jamba)."""
+        return self.mamba_d_state > 0
+
+    def is_attn_layer(self, l: int) -> bool:
+        return (not self.is_hybrid
+                or l % self.attn_layer_period == self.attn_layer_offset)
+
+    @property
+    def attn_layers(self) -> tuple:
+        """The layers that hold KV, in model order; an attention layer's
+        index into the KV pool is its rank here."""
+        return tuple(l for l in range(self.n_layers) if self.is_attn_layer(l))
+
+    @property
+    def kv_layers(self) -> int:
+        return len(self.attn_layers) if self.is_hybrid else self.n_layers
+
+    @property
+    def mamba_layers(self) -> int:
+        return self.n_layers - self.kv_layers
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.dim
 
     @property
     def head_dim(self) -> int:
@@ -504,6 +561,34 @@ PRESETS: Dict[str, ModelConfig] = {
         rope_mscale_all_dim=1.0,
         attn_qscale_beta=0.1,
         attn_qscale_orig=8192,
+    ),
+    # Jamba's structure at test size (CPU CI): period 4 with the attention
+    # layer at 2, one KV head
+    "tiny-jamba": ModelConfig(
+        name="tiny-jamba", n_layers=8, n_heads=4, n_kv_heads=1,
+        tie_embeddings=True, norm_eps=1e-6, mamba_d_state=4,
+        mamba_dt_rank=8, attn_layer_period=4, attn_layer_offset=2,
+    ),
+    # AI21-Jamba2-3B (also published as Jamba Reasoning 3B): 26 Mamba-1
+    # mixers and 2 attention layers (7 and 21) of 28, every MLP dense, MQA
+    # at 20 heads on 1 KV head of 128, no position term, tied embedding
+    "ai21-jamba2-3b": ModelConfig(
+        name="ai21-jamba2-3b",
+        vocab_size=65536,
+        dim=2560,
+        n_layers=28,
+        n_heads=20,
+        n_kv_heads=1,
+        ffn_dim=8192,
+        max_seq_len=262144,
+        norm_eps=1e-6,
+        tie_embeddings=True,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_dt_rank=160,
+        mamba_expand=2,
+        attn_layer_period=14,
+        attn_layer_offset=7,
     ),
     # Mistral 7B v0.1 (every-layer sliding window via the period-1
     # schedule: (l % 1) == 1 never holds, so no layer is global)

@@ -69,6 +69,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
     a SECOND stacked tree `layers_dense` for the leading dense-FFN layers
     — the forward runs two scans, one compiled body each."""
     c = config
+    if c.is_hybrid:  # Jamba: a tree and a forward of its own
+        from dynamo_tpu.models import jamba
+
+        return jamba.init_params(c, key, dtype)
     if c.is_moe and c.n_dense_layers:
         moe_part = _init_layer_stack(
             c, key, c.n_layers - c.n_dense_layers, moe=True, dtype=dtype
@@ -263,6 +267,10 @@ def forward(
     """
     c = config
     B, S = tokens.shape
+    if c.is_hybrid:
+        raise NotImplementedError(
+            "a model with state-space layers runs models/jamba.forward, which "
+            "takes and returns the state pool; this path has no state")
     if return_routed and not c.is_moe:
         raise ValueError("return_routed needs a model with routed experts")
     if return_listed and not return_routed:

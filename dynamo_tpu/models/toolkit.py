@@ -67,7 +67,8 @@ def make_kv_pool(
         if kv_quantize is not None:
             raise ValueError(f"unknown kv_quantize mode {kv_quantize!r}")
         return jnp.zeros(lat, dtype=dtype), jnp.zeros(stub, dtype=dtype)
-    shape = (config.n_layers, num_pages, page_size, config.n_kv_heads, config.head_dim)
+    # a hybrid model's pool holds its attention layers alone (kv_layers)
+    shape = (config.kv_layers, num_pages, page_size, config.n_kv_heads, config.head_dim)
     if kv_quantize == "int8":
         mk = lambda: {
             "q": jnp.zeros(shape, jnp.int8),

@@ -153,6 +153,16 @@ class IterationRecord:
     moe_load_max_share: float = 0.0
     moe_held_slots: float = 0.0
     moe_experts_listed: int = 0
+    # a model with state-space layers (0 for every other): sequences that
+    # hold a state slot after the step and the slots there are (scratch
+    # left out); what the selective-scan kernel was given this iteration
+    # (tokens and segments of its flat axis: a ragged step's decode rows,
+    # one token each, and chunks; a standalone prefill's chunk), 0 where
+    # only the decode loop's one-token update ran
+    state_slots_used: int = 0
+    state_slots_total: int = 0
+    ssm_scan_tokens: int = 0
+    ssm_scan_segments: int = 0
     # causal tracing: trace ids of the requests this iteration served
     # (bounded by the engine at append time) — joins the per-iteration
     # timeline to the distributed span rings and incident bundles

@@ -492,6 +492,26 @@ async def serve_worker(
         engine.on_fpm(_update_moe_gauges)
         _update_moe_gauges()
 
+    # state slots -> /metrics: a model with state-space layers keeps one
+    # slot of recurrent state a sequence (docs/observability.md "A
+    # sequence's state slot"). Only such a worker has the series.
+    if _runner is not None and _runner.holds_state:
+        _sm = runtime.metrics.child(dynamo_namespace=namespace)
+
+        def _update_slot_gauges(_m=None) -> None:
+            sched = engine.scheduler
+            _sm.gauge(
+                "state_slots_used",
+                "sequences that hold a state slot (scratch left out)",
+            ).set(sched.state_slots_used)
+            _sm.gauge(
+                "state_slots_total",
+                "state slots a sequence can be given (scratch left out)",
+            ).set(max(0, sched.state_slots - 1))
+
+        engine.on_fpm(_update_slot_gauges)
+        _update_slot_gauges()
+
     # latency spine -> /metrics: per-finished-request phase durations as
     # histograms labeled by phase (queue_wait/ttft/kv_onboard/...; ITL
     # samples fold into one phase="itl" histogram). Fired from the engine

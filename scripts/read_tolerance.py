@@ -1,0 +1,131 @@
+"""Read a configuration's `correct_tolerance` by benchmark/README.md's rule:
+through benchmark/serve.py's own `make_params` and `reference_check`, at the
+configuration's own sizes and server flags, for each seed the sound bf16
+program and its controls: the same weights under `quantize: int8`, and, for a
+model with state-space layers, the program with the recurrent state `S` kept
+in bfloat16 (a pool this script lays under the runner: the program has no
+such option). Chip only (like serve.py it refuses a CPU unless --rehearse).
+
+    python scripts/read_tolerance.py --config benchmark/configs/<name>.json \
+        --seeds 3600000300:3600000312 [--tolerance 0.1] [--out chiprun_out/tol.jsonl]
+
+One JSON line a (seed, variant): per pass the worst and the mean error and the
+gap under the reference's best, the larger of the two passes, and `ok` under
+--tolerance. A dense or hybrid model only: a routed one also needs its margin
+(PERF.md section 6, PR 32).
+"""
+
+import argparse
+import asyncio
+import importlib.util
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _engine(config, dev, wargs, mpps, params, variant: str):
+    """A runner and an engine as benchmark/serve.py builds them, for one
+    variant of the program."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu import worker
+    from dynamo_tpu.engine.model_runner import ModelRunner
+    from dynamo_tpu.models import jamba
+
+    runner = ModelRunner(
+        config, None, devices=[dev], num_pages=wargs.num_pages,
+        page_size=wargs.page_size, max_pages_per_seq=mpps, params=params,
+        quantize="int8" if variant == "int8" else None)
+    engine = worker.build_engine(wargs, runner=runner)[0]
+    if variant == "state-bf16":
+        # the program keeps S in float32 and has no option for anything
+        # else: the control lays a bfloat16 pool of the engine's own size
+        # under the runner before any sequence owns a slot
+        runner.state = jax.device_put(jamba.make_state_pool(
+            config, runner.state_slots, jnp.bfloat16, runner.dtype), dev)
+    return engine
+
+
+async def main(args) -> int:
+    import jax
+    import jax.numpy as jnp
+
+    import dynamo_tpu
+    from dynamo_tpu import worker
+    from dynamo_tpu.models.config import ModelConfig
+
+    serve = _load(os.path.join(ROOT, "benchmark", "serve.py"), "bench_serve")
+    with open(args.config) as f:
+        cfg = json.load(f)
+    model, flags = dict(cfg["model"]), dict(cfg["server_flags"])
+    if args.rehearse:
+        import rehearsal
+
+        reh = rehearsal.rehearsal_sizes(cfg, os.path.join(ROOT, "benchmark"))
+        model, flags = reh["model"], reh["server_flags"]
+    dynamo_tpu.enable_compilation_cache()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        print("no TPU: a tolerance is read on the chip (--rehearse debugs this script)")
+        return 3
+    ref = serve.load_reference(cfg)
+    config = ModelConfig(**model)
+    wargs = worker.parse_args(
+        [x for k, v in flags.items() for x in (f"--{k}", str(v))]
+        + ["--tokenizer", "byte", "--model-name", config.name])
+    mpps = -(-wargs.max_seq_len // wargs.page_size)
+    variants = ["sound", "int8"] + (["state-bf16"] if config.is_hybrid else [])
+    lo, hi = (int(x) for x in args.seeds.split(":"))
+    out = open(args.out, "a") if args.out else None
+    for seed in range(lo, hi):
+        params = serve.make_params(config, seed, dev, jnp.bfloat16)
+        for variant in [v for v in variants if not args.only or v in args.only]:
+            t0 = time.monotonic()
+            engine = await asyncio.to_thread(  # off the event loop
+                _engine, config, dev, wargs, mpps, params, variant)
+            res = await serve.reference_check(
+                ref, model, engine, seed, args.tolerance, args.rehearse, None,
+                params=params if variant == "int8" else None)
+            engine.stop()
+            row = {"seed": seed, "variant": variant, "ok": res["ok"],
+                   "tolerance": args.tolerance, "seconds": round(time.monotonic() - t0, 1)}
+            for name in ("logprobs", "ragged"):
+                row[name] = {k: res[name][k] for k in (
+                    "max_abs_logprob_err", "mean_abs_logprob_err", "max_gap_under_best",
+                    "tokens", "max_decode_rows", "ok")}
+            row["worst"] = res["logprobs"]["max_abs_logprob_err"]
+            row["mean"] = res["logprobs"]["mean_abs_logprob_err"]
+            row["gap"] = max(res[n]["max_gap_under_best"] for n in ("logprobs", "ragged"))
+            line = json.dumps(row)
+            print(line, flush=True)
+            if out:
+                out.write(line + "\n")
+                out.flush()
+            del engine
+    return 0
+
+
+if __name__ == "__main__":
+    p = argparse.ArgumentParser()
+    p.add_argument("--config", required=True)
+    p.add_argument("--seeds", required=True, help="lo:hi (hi excluded)")
+    p.add_argument("--tolerance", type=float, default=10.0)
+    p.add_argument("--only", nargs="*", default=None)
+    p.add_argument("--out", default=None)
+    p.add_argument("--rehearse", action="store_true")
+    rc = asyncio.run(main(p.parse_args()))
+    sys.stdout.flush()
+    os._exit(rc)

@@ -71,6 +71,11 @@ INTERPRET = False  # set by --interpret (rehearsal only)
 GEOMETRIES = {
     "llama-3.2-3b": dict(Hk=8, G=3, D=128, PS=64, MP=64, window=100, ctx=512),
     "phi-3-mini-4k": dict(Hk=32, G=1, D=96, PS=64, MP=64, window=2047, ctx=2560),
+    # MQA at 20 query heads on one KV head (no multiple of the kernels' row
+    # block of 8), as the two attention layers of ai21-jamba2-3b run it:
+    # bf16 pools, no window, no softcap
+    "ai21-jamba2-3b": dict(Hk=1, G=20, D=128, PS=64, MP=64, window=100, ctx=2560,
+                           variants=("bf16",)),
 }
 # name -> (int8 KV pools, sliding window + logit softcap)
 VARIANTS = {
@@ -410,6 +415,67 @@ def check_block_copy() -> float:
     return max(d1, d2, d3)
 
 
+def _ssm_operands(rng, T: int, state_dtype: str):
+    """A state pool [3, 9, N, d // 128, 128] and T tokens' operands at
+    ai21-jamba2-3b's widths (N 16, d 5120): steps of 0.001-0.5 and decays
+    of -(1..16), as its random tree has them."""
+    from dynamo_tpu.ops import ssm
+
+    N, d = 16, 5120
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    pool = f(3, 9, N, *ssm.state_shape(d)).astype(state_dtype)
+    dt = jax.nn.softplus(f(T, d) - 3.0)
+    A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], (N, d))
+    return pool, (f(T, d), dt, f(T, N), f(T, N), A)
+
+
+def check_ssm_update(state_dtype: str) -> float:
+    """The decode step's state update: 13 live rows of a bucket of 16, one
+    of them a sequence's first token; the padding rows move nothing."""
+    from dynamo_tpu.ops import ssm
+
+    rng = np.random.default_rng(7)
+    pool, ops = _ssm_operands(rng, 16, state_dtype)
+    # eight sequences' slots, then five rows on the scratch slot (warm-up's
+    # dummies share it, and race on it), then padding
+    slots = jnp.asarray([5, 1, 8, 2, 7, 3, 6, 4] + [0] * 8, jnp.int32)
+    live = jnp.arange(16) < 13
+    fresh = jnp.arange(16) == 2
+    y0, p0 = ssm.ssm_update_jnp(pool, LAYER, slots, live, fresh, *ops)
+    y1, p1 = ssm.ssm_update(pool, LAYER, slots, live, fresh, *ops,
+                            interpret=INTERPRET)
+    keep = [1, 2, 3, 4, 5, 6, 7, 8]
+    untouched = _max_err(np.asarray(p1)[:LAYER], np.asarray(pool)[:LAYER])
+    return max(_max_err(y1[:8], y0[:8]), _max_err(y1[13:], y0[13:]),
+               _max_err(np.asarray(p1)[LAYER, keep], np.asarray(p0)[LAYER, keep]),
+               untouched)
+
+
+def check_ssm_scan(state_dtype: str) -> float:
+    """The ragged step's flat axis: five decode rows (segments of one
+    token), a chunk that starts its sequence, one that goes on from its
+    slot, and a tail of padding, 64 tokens in all."""
+    from dynamo_tpu.ops import ssm
+
+    rng = np.random.default_rng(8)
+    T = 64
+    pool, ops = _ssm_operands(rng, T, state_dtype)
+    lens = [1, 1, 1, 1, 1, 23, 30]
+    slot = [5, 1, 8, 2, 7, 3, 6]
+    fresh = [0, 0, 0, 0, 0, 1, 0]
+    start = np.cumsum([0] + lens[:-1])
+    seg_of = np.repeat(np.arange(len(lens)), lens)
+    seg_of = np.concatenate([seg_of, np.full(T - len(seg_of), len(lens) - 1)])
+    off = np.arange(T) - start[seg_of]
+    flags = ssm.scan_flags(jnp.arange(T) < sum(lens), jnp.asarray(off == 0),
+                           jnp.asarray(off == np.asarray(lens)[seg_of] - 1),
+                           jnp.asarray(np.asarray(fresh)[seg_of] != 0))
+    tok_slot = jnp.asarray(np.asarray(slot)[seg_of], jnp.int32)
+    y0, p0 = ssm.ssm_scan_jnp(pool, LAYER, tok_slot, flags, *ops)
+    y1, p1 = ssm.ssm_scan(pool, LAYER, tok_slot, flags, *ops, interpret=INTERPRET)
+    return max(_max_err(y1, y0), _max_err(p1, p0))
+
+
 def all_checks():
     """(name, thunk) for every check, GQA cross product first."""
     checks = []
@@ -419,7 +485,7 @@ def all_checks():
                           ("prefill", check_prefill),
                           ("ragged", check_ragged),
                           ("ragged sparse-table", check_ragged_sparse)):
-            for variant in VARIANTS:
+            for variant in geom.get("variants", VARIANTS):
                 checks.append((f"{kname} {variant} @{gname}",
                                functools.partial(fn, geom, variant)))
     if len(jax.devices()) > 1:
@@ -428,6 +494,8 @@ def all_checks():
 
         mesh = make_mesh(MeshConfig(model=len(jax.devices())))
         for gname, geom in GEOMETRIES.items():
+            if geom["Hk"] % len(jax.devices()):
+                continue  # one KV head: nothing to shard
             for variant in VARIANTS:
                 checks.append((
                     f"ragged sparse-table sharded {variant} @{gname}",
@@ -441,6 +509,13 @@ def all_checks():
         ("mla decode int8-latent", check_mla_int8),
         ("block copy/permute/scatter", check_block_copy),
     ]
+    for dt in ("float32", "bfloat16"):
+        checks += [
+            (f"ssm_update {dt} state @ai21-jamba2-3b",
+             functools.partial(check_ssm_update, dt)),
+            (f"ssm_scan {dt} state @ai21-jamba2-3b",
+             functools.partial(check_ssm_scan, dt)),
+        ]
     return checks
 
 

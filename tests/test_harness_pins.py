@@ -14,6 +14,8 @@ import os
 import threading
 import time
 
+import pytest
+
 from dynamo_tpu import worker
 from dynamo_tpu.engine.model_runner import ModelRunner
 from dynamo_tpu.engine.scheduler import DecodePlan, MixedPlan, PrefillPlan, Sequence
@@ -39,15 +41,19 @@ def _seq(rid, prompt, max_tokens):
     )
 
 
-def test_warm_lattice_meets_every_program_traffic_reaches(monkeypatch):
+# tiny-jamba: a model with state-space layers, whose steps also take state
+# slots: the walk passes none (positionally, dummies), so slots must be data
+# under a trailing keyword and never a shape
+@pytest.mark.parametrize("preset", ["tiny", "tiny-jamba"])
+def test_warm_lattice_meets_every_program_traffic_reaches(monkeypatch, preset):
     monkeypatch.setenv("DYN_FUSED_MIXED", "1")
     args = worker.parse_args([
-        "--model", "tiny", "--max-batch", "4", "--chunk-size", "16",
+        "--model", preset, "--max-batch", "4", "--chunk-size", "16",
         "--mixed-prefill-tokens", "12", "--mixed-prefill-seqs", "2",
         "--mixed-min-chunk", "4",
     ])
     runner = ModelRunner(
-        get_config("tiny"), num_pages=96, page_size=4, max_pages_per_seq=16,
+        get_config(preset), num_pages=96, page_size=4, max_pages_per_seq=16,
         decode_buckets=(2, 4), prefill_buckets=(8, 16),
         ragged_buckets=(8, 16), seed=7,
     )

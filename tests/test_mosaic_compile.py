@@ -239,3 +239,90 @@ def test_decode_loop_reads_the_expert_stacks_in_place(topo):
         s((2, held, E, F), bf), s((2, held, E, F), bf), s((2, held, F, E), bf),
     ).compile().as_text()
     assert _slab_ops(text, held, E, F)
+
+
+# -- the state-space kernels and a hybrid model's step programs --------------
+
+
+@pytest.mark.parametrize("kernel", ["ssm_update", "ssm_scan"])
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_state_space_kernels_compile_for_v5e_at_jamba_widths(topo, kernel, state_dtype):
+    """ai21-jamba2-3b's recurrence: 26 layers x 65 slots of [16, 5120]
+    states, 64 decode rows / a flat step of 320 tokens."""
+    from dynamo_tpu.ops import ssm
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    N, d, f32, i32 = 16, 5120, jnp.float32, jnp.int32
+    pool = s((26, 65, N) + ssm.state_shape(d), state_dtype)
+    T = 64 if kernel == "ssm_update" else 320
+    ops = (s((T, d), f32), s((T, d), f32), s((T, N), f32), s((T, N), f32), s((N, d), f32))
+    if kernel == "ssm_update":
+        args = (pool, s((), i32), s((T,), i32), s((T,), jnp.bool_), s((T,), jnp.bool_)) + ops
+    else:
+        args = (pool, s((), i32), s((T,), i32), s((T,), i32)) + ops
+    text = jax.jit(getattr(ssm, kernel), donate_argnums=(0,)).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_a_hybrid_models_step_programs_compile_for_v5e(topo):
+    """ai21-jamba2-3b whole, as its cell runs it: the decode loop (64 rows, 4
+    fused steps) and the ragged step (320 tokens). The attention kernels at
+    its geometry (20 query heads on one KV head of 128: no multiple of their
+    row block), the state kernels beside them, and the state pool read in
+    place: no slice or copy of it in front of a kernel."""
+    import re
+    from functools import partial
+
+    from dynamo_tpu.engine.model_runner import _decode_loop, _ragged_step
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.models import jamba, llama
+    from dynamo_tpu.models.config import get_config
+    from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    def samp(B):
+        return SamplingParams(s((B,), f32), s((B,), i32), s((B,), f32), s((B, 2), jnp.uint32),
+                              s((B,), f32), s((B,), f32), s((B,), f32))
+
+    def kernels(text):
+        return {l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                for l in text.splitlines() if "tpu_custom_call" in l and " = " in l}
+
+    c = get_config("ai21-jamba2-3b")
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(lambda: llama.make_kv_pool(c, 2880, 64, dtype=jnp.bfloat16)))
+    state = on_chip(jax.eval_shape(lambda: jamba.make_state_pool(c, 65)))
+    assert pools[0].shape[0] == 2  # the attention layers alone
+    B, MP, f32, i32 = 64, 64, jnp.float32, jnp.int32
+    jit = partial(jax.jit, donate_argnames=("state",))
+    text = jit(partial(_decode_loop, c, "pallas", None, 4, -1), donate_argnums=(6, 7)).lower(
+        params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None, *pools, samp(B),
+        state=state, slots=s((B,), i32)).compile().as_text()
+    assert kernels(text) == {"decode_paged_attention", "ssm_update"}
+    # one layer's states of every slot, sliced or copied out of the pool
+    slab = re.compile(r"= f32\[(1,)?65,16,40,128\]\S* (dynamic-slice|slice|copy)\(")
+    assert not slab.search(text)
+    T = 320
+    md = build_ragged_metadata([1] * 8 + [100, 150], [5] * 8 + [0, 0], [6] * 8 + [100, 150],
+                               [[1]] * 8 + [[2, 3], [4, 5, 6]], T, q_block=8, max_pages=MP)
+    SEG, V = md["seg_page_table"].shape[0], c.vocab_size
+    text = jit(partial(_ragged_step, c, "pallas", None), donate_argnums=(9, 10)).lower(
+        params, s((1, T), i32), s((1, T), i32), s((T, MP), i32), s((T,), i32),
+        s(md["seg_page_table"].shape, i32), s((SEG,), i32), s(md["meta"].shape, i32),
+        s((SEG,), i32), *pools, samp(SEG), s((SEG,), i32), s((SEG,), i32), s((), i32),
+        s((SEG, V), jnp.bool_), s((SEG, V), f32), state=state,
+        seg_slots=s((3, SEG), i32)).compile().as_text()
+    assert kernels(text) == {"ragged_paged_attention", "ssm_scan"}
+    assert not slab.search(text)
