@@ -31,6 +31,7 @@ from dynamo_tpu.engine.runner_api import (
     state_refusal,
 )
 from dynamo_tpu.engine.scheduler import (
+    StepsInFlight,
     STATE_NO_PREFIX,
     DecodePlan,
     MixedPlan,
@@ -135,6 +136,23 @@ class GuidedMaskContext:
                     continue
             mask[idx] = self._row_mask(m, state)[: self.vocab]
         return mask
+
+
+class _InFlight:
+    """A plain decode dispatch the step loop has enqueued and not read
+    back: `rows` its sequences by place, `live` which of them it really
+    serves (the others are pad rows holding a place), `T` its fused steps,
+    `handle` the runner's (None: the runner read it back itself, into
+    `sampled`), and what the iteration's record needs from its enqueue:
+    `ts` when its staging began, `rinfo` its composition."""
+
+    __slots__ = ("rows", "live", "T", "n_lp", "ts", "rinfo", "handle",
+                 "sampled")
+
+    def __init__(self, rows, live, T, n_lp, ts, rinfo):
+        self.rows, self.live, self.T, self.n_lp = rows, live, T, n_lp
+        self.ts, self.rinfo = ts, rinfo
+        self.handle = self.sampled = None
 
 
 class InferenceEngine:
@@ -418,6 +436,13 @@ class InferenceEngine:
         self._routed_out: Dict[str, tuple] = {}  # rid -> (item field,
         #   whether it holds one position per emitted token: a decode row)
         self._rec_late: Optional[tuple] = None  # (IterationRecord, MoeLoad)
+        # the step loop's decode dispatch in flight (_loop_once), the
+        # moment the last iteration was committed (walls run from it), and
+        # iterations by how they were enqueued: "ahead" of the read-back
+        # of the one before, or the reason not (IterationRecord.drain)
+        self._inflight: Optional[_InFlight] = None
+        self._t_mark = time.monotonic()
+        self.run_ahead_totals: Dict[str, int] = {}
         self.moe_totals = {"token_slots_total": 0, "held_slots_total": 0.0,
                            "experts_hit": 0.0, "load_max_share": 0.0}
         # sick peers for cross-worker pulls: instance -> retry-after time
@@ -1001,6 +1026,7 @@ class InferenceEngine:
         # compiles on this thread that no step family sees count in this
         # runner's compile_stats()["other"]
         self.runner.name_step_thread()
+        self._t_mark = time.monotonic()
         if self._routed_ok:
             # what the runner holds of dispatches that were not this
             # engine's (a warm-up walk) is none of its first iteration's load
@@ -1029,9 +1055,26 @@ class InferenceEngine:
                         # its failure must not mask the fatal path itself
                         log.exception("fatal callback failed")
                 break
+        try:
+            # stopping with a dispatch enqueued: its tokens were computed,
+            # so they are committed like any other
+            self._commit_inflight()
+        except Exception:
+            log.exception("commit of the dispatch in flight failed")
         log.info("engine step loop stopped")
 
     def _loop_once(self) -> None:
+        """One iteration: plan it, enqueue it, and read back, emit and
+        publish what is due. A plain decode dispatch is left in flight
+        (`_inflight`) and read back only after the NEXT iteration is
+        planned, staged and enqueued on its device-resident tokens, so
+        the host's share of an iteration runs while the device works and
+        not between its programs. That holds while the next plan is the
+        rows in flight, in their places (a row that ended keeps its place
+        as a pad row), and needs nothing of their tokens on the host;
+        whenever it does not (`_why_not_ahead` names why) what is in
+        flight is committed first and the iteration at hand runs in the
+        serial order: plan, enqueue, read back, emit, publish."""
         from dynamo_tpu.parallel.multihost import GroupBroken
 
         sched = self.scheduler
@@ -1040,20 +1083,40 @@ class InferenceEngine:
             self._propose_drafts()
         with annotate("engine.schedule", waiting=len(sched.waiting),
                       running=len(sched.active)):
-            plan = sched.step_plan()
+            try:
+                plan = sched.step_plan()
+                why = self._why_not_ahead(plan)
+            except StepsInFlight:
+                plan, why = None, "preempt"
+        if self._inflight is not None and why is not None:
+            # the plan was made around steps in flight (rows they finish
+            # left out, positions past them): commit them, then plan on
+            # what they brought
+            self._commit_inflight()
+            with annotate("engine.schedule", waiting=len(sched.waiting),
+                          running=len(sched.active)):
+                plan = sched.step_plan()
+                if why == "idle":  # the commit left something to plan
+                    why = self._why_not_ahead(plan)
         if plan is None:
             self._flush_late_record()
             if not sched.has_work():
                 with annotate("engine.wait"):
                     time.sleep(self.idle_sleep_s)
+            self._t_mark = time.monotonic()
             return
-        t0 = time.monotonic()
-        t_start, ts_wall = t0, time.time()
+        if isinstance(plan, DecodePlan) and not (
+                self.runner.has_draft or any(s.spec_draft for s in plan.seqs)):
+            self._step_decode(plan, why)
+            return
+        # walls run from commit to commit (IterationRecord.wall_s)
+        t0, ts_wall = self._t_mark, time.time()
         # plan-composition fields for this iteration's flight record;
         # branches fill in what they actually served
         rinfo = {"decode_seqs": 0, "decode_steps": 0, "n_chunks": 0,
                  "chunk_tokens": 0, "fused": False, "ragged": False,
-                 "spec_rows": 0, "spec_drafted": 0, "spec_emitted": 0}
+                 "spec_rows": 0, "spec_drafted": 0, "spec_emitted": 0,
+                 "drain": why or "cold"}
         if isinstance(plan, MixedPlan):
             _dseqs = plan.decode.seqs
         elif isinstance(plan, DecodePlan):
@@ -1064,10 +1127,11 @@ class InferenceEngine:
             1 for s in _dseqs if s.guided_m is not None
         )
         if _dseqs and self.recorder.enabled:
+            at = [s.computed_len for s in _dseqs]
             rinfo["pages_live"] = self._decode_pages_live(
-                _dseqs, getattr(plan, "decode", plan).n_steps)
+                at, getattr(plan, "decode", plan).n_steps)
             # step 0 apart: a ragged program walks it, not the decode kernel
-            rinfo["pages_step0"] = self._decode_pages_live(_dseqs, 1)
+            rinfo["pages_step0"] = self._decode_pages_live(at, 1)
         decode_done = False
         try:
             if isinstance(plan, PrefillPlan):
@@ -1199,17 +1263,22 @@ class InferenceEngine:
                 )
             else:
                 seqs = plan.seqs
-            log.exception(
-                "engine step failed; erroring %d sequence(s)", len(seqs)
-            )
-            for seq in seqs:
-                try:
-                    self._emit(seq, [], "error")
-                    self.scheduler.abort(seq.request_id)
-                except Exception:
-                    log.exception("failed to fail sequence %s", seq.request_id)
-            self._recover_poisoned_pools()
+            self._fail_step(seqs)
             return
+        self._publish_step(
+            kind, n_tok, ts_wall, rinfo, t0=t0,
+            rec_kind="mixed" if isinstance(plan, MixedPlan) else kind)
+
+    def _publish_step(self, kind: str, n_tok: int, ts_wall: float, rinfo,
+                      t0: Optional[float] = None,
+                      rec_kind: Optional[str] = None) -> None:
+        """The end of an iteration's commit: FPM, KV events, the flight
+        record, and the mark the next iteration's wall runs from. Walls
+        run from that mark, commit to commit (IterationRecord.wall_s);
+        `t0`: where a two-dispatch iteration published its first half."""
+        t_start = self._t_mark
+        if t0 is None:
+            t0 = t_start
         with annotate("engine.publish"):
             if self.sanitizer is not None:
                 # arms the transfer guard + freezes the compiled-family
@@ -1218,15 +1287,223 @@ class InferenceEngine:
             self._publish_fpm(kind, time.monotonic() - t0, n_tok)
             self._publish_kv_events()
             self._record_iteration(
-                ts_wall, time.monotonic() - t_start,
-                "mixed" if isinstance(plan, MixedPlan) else kind, rinfo,
-            )
+                ts_wall, time.monotonic() - t_start, rec_kind or kind, rinfo)
+            self._t_mark = time.monotonic()
+
+    def _fail_step(self, seqs) -> None:
+        """One bad step fails ITS sequences and never the step thread."""
+        log.exception(
+            "engine step failed; erroring %d sequence(s)", len(seqs)
+        )
+        for seq in seqs:
+            try:
+                self._emit(seq, [], "error")
+                self.scheduler.abort(seq.request_id)
+            except Exception:
+                log.exception("failed to fail sequence %s", seq.request_id)
+        self._recover_poisoned_pools()
+
+    # -- the decode dispatch in flight ---------------------------------------
+    def _ahead_blocker(self, seqs: List[Sequence]) -> Optional[str]:
+        """Why a decode dispatch of these rows is read back before the
+        next one is planned, whatever that plan will be: the next plan
+        needs its tokens on the host (None: it may wait in flight)."""
+        if not self.runner.can_run_ahead:
+            return "runner"  # PP / SP programs, a multi-host group
+        if self._stop.is_set():
+            return "shutdown"
+        if self._spec_on or self.runner.has_draft:
+            return "spec"  # drafts are proposed from host tokens
+        if any(s.guided_m is not None for s in seqs):
+            return "guided"  # the DFA state advances at commit
+        if _batch_penalties(seqs):
+            return "penalties"  # the histories are host tokens
+        return None
+
+    def _why_not_ahead(self, plan) -> Optional[str]:
+        """None where `plan` can be enqueued before the dispatch in flight
+        is read back; else the reason it cannot, one of a closed set
+        (IterationRecord.drain). With nothing in flight the reason is the
+        one that left nothing there ("cold": the iteration before was no
+        plain decode, or the loop idled)."""
+        if plan is None:
+            return "idle"
+        if isinstance(plan, PrefillPlan):
+            return "prefill"
+        if isinstance(plan, MixedPlan):
+            return "mixed"
+        why = self._ahead_blocker(plan.seqs)
+        fl = self._inflight
+        if why is not None or fl is None:
+            return why or "cold"
+        # stable places: every row of the plan continues the row of the
+        # dispatch in flight that sits where it will sit; nobody joins
+        here = {id(s) for s, live in zip(fl.rows, fl.live) if live}
+        if any(id(s) not in here for s in plan.seqs):
+            return "rows"
+        if self.runner.decode_bucket(len(plan.seqs)) < self.runner.decode_bucket(
+                len(fl.rows)):
+            return "bucket"  # the rows left fit a smaller program
+        return None
+
+    def _commit_inflight(self) -> None:
+        """Commit the decode dispatch in flight, if there is one."""
+        fl, self._inflight = self._inflight, None
+        if fl is not None:
+            self._commit_decode(fl)
+
+    def _step_decode(self, plan: DecodePlan, why: Optional[str]) -> None:
+        """A plain decode iteration. `why` None: it is enqueued on the
+        tokens of the dispatch in flight, which is read back, emitted and
+        published after, under it. Then it stays in flight itself unless
+        its own rows rule that out."""
+        from dynamo_tpu.parallel.multihost import GroupBroken
+
+        prev = self._inflight if why is None else None
+        try:
+            nxt = self._dispatch_decode(plan, prev)
+        except GroupBroken:
+            raise
+        except Exception:
+            self._commit_inflight()
+            self._fail_step([s for s in plan.seqs
+                             if s.state == SeqState.RUNNING])
+            return
+        nxt.rinfo["ahead"] = prev is not None
+        nxt.rinfo["drain"] = "" if prev is not None else (why or "cold")
+        self._commit_inflight()
+        if self._ahead_blocker(plan.seqs) is None:
+            self._inflight = nxt
+        else:
+            self._commit_decode(nxt)
+
+    def _dispatch_decode(self, plan: DecodePlan,
+                         prev: Optional["_InFlight"]) -> "_InFlight":
+        """Prep, stage and enqueue a plain decode plan: plan.n_steps fused
+        iterations in one jit with on-device token feedback. On `prev`
+        (the dispatch in flight) the rows keep prev's places and take
+        their first tokens from its last ones on the device; a row of
+        prev the plan left out (ended, aborted, its budget spent by the
+        steps in flight) becomes a pad row: position -1, no pages, the
+        scratch state slot."""
+        with annotate("engine.decode", batch=len(plan.seqs),
+                      steps=plan.n_steps), self._san_scope("decode"):
+            with annotate("engine.prep"):
+                ts_wall = time.time()
+                T = plan.n_steps
+                if prev is None:
+                    rows, live = list(plan.seqs), [True] * len(plan.seqs)
+                else:
+                    rows = prev.rows
+                    planned = {id(s) for s in plan.seqs}
+                    live = [id(s) in planned for s in rows]
+                seqs = plan.seqs
+                rinfo = {"decode_seqs": len(seqs), "n_chunks": 0,
+                         "chunk_tokens": 0, "fused": False, "ragged": False,
+                         "guided_rows": sum(
+                             1 for s in seqs if s.guided_m is not None)}
+                # a row in flight sits `inflight` steps past what is
+                # committed; the scheduler's page look-ahead covers that
+                positions = [s.computed_len + s.inflight if ok else -1
+                             for s, ok in zip(rows, live)]
+                tables = [s.pages if ok else [] for s, ok in zip(rows, live)]
+                T, mkw = self._decode_extras(rows, T, False)
+                step0 = self._step_counter + 1
+                self._step_counter += T
+                n_lp = _batch_logprobs(seqs)
+                histories = (
+                    [list(s.tokens) for s in rows]
+                    if _batch_penalties(seqs) else None
+                )
+                if (n_lp >= 0 or histories is not None) and self.runner.pp:
+                    # the PP decode loop has no logprob/penalty wiring yet —
+                    # drop the extras with a warning (same contract as spec
+                    # decode) instead of letting a raise inside the shared
+                    # dispatch error EVERY sequence in the plan
+                    for s in seqs:
+                        if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
+                            self._warn_spec_once(
+                                s.request_id,
+                                "logprobs/penalties are unsupported on "
+                                "pipeline-parallel workers and were ignored",
+                            )
+                    n_lp, histories = -1, None
+                if n_lp >= 0 or histories is not None:
+                    mkw.update(n_logprobs=n_lp, histories=histories,
+                               prompt_lens=[s.n_prompt0 for s in rows])
+                if self._state_on:
+                    mkw["slots"] = [s.state_slot if ok else 0
+                                    for s, ok in zip(rows, live)]
+                if self.recorder.enabled:
+                    at = [p for p in positions if p >= 0]
+                    rinfo["pages_live"] = self._decode_pages_live(at, T)
+                    rinfo["pages_step0"] = self._decode_pages_live(at, 1)
+                rinfo["decode_steps"] = T
+                sp = _sampling_params(rows)
+                adapters = [s.adapter_idx for s in rows]
+                args = (T, [s.tokens[-1] for s in rows], positions, tables,
+                        sp, step0)
+            rinfo["step"] = self._step_counter
+            fl = _InFlight(rows, live, T, n_lp, ts_wall, rinfo)
+            if self.runner.can_run_ahead:
+                fl.handle = self.runner.decode_dispatch(
+                    *args, adapters=adapters, **mkw,
+                    prev=None if prev is None else prev.handle)
+            else:
+                # a runner of whole steps (a multi-host group replays
+                # decode_multi): the readback is part of the call
+                fl.sampled = self.runner.decode_multi(
+                    *args, adapters=adapters, **mkw)
+            for s, ok in zip(rows, live):
+                if ok:
+                    s.inflight += T
+        return fl
+
+    def _finish_decode(self, fl: "_InFlight") -> None:
+        """Read a decode dispatch back, commit its tokens and emit them.
+        A row whose sequence is finished or aborted by now (a stop token
+        found in the dispatch before this one, an abort at the inbox) is
+        skipped: its pages and state slot went back once, when it
+        finished, and what this dispatch wrote for it lies at positions
+        past its computed_len, in pages no one else read
+        (docs/concurrency.md)."""
+        with annotate("engine.decode", batch=sum(fl.live), steps=fl.T), \
+                self._san_scope("decode"):
+            sampled = fl.sampled
+            if fl.handle is not None:
+                sampled = self.runner.decode_collect(fl.handle)
+            lp = None
+            if fl.n_lp >= 0:
+                sampled, lp = sampled
+            self._collect_routed(fl.rows, fl.T, [], fl.live)
+            with annotate("engine.emit"):
+                self._commit_decoded(fl.rows, sampled, lp, fl)
+
+    def _commit_decode(self, fl: "_InFlight") -> None:
+        """_finish_decode and the iteration's publish: the half of a
+        decode iteration that runs under the next dispatch where one was
+        enqueued ahead."""
+        from dynamo_tpu.parallel.multihost import GroupBroken
+
+        try:
+            self._finish_decode(fl)
+        except GroupBroken:
+            raise
+        except Exception:
+            # (aborting them zeroes what they had in flight)
+            self._fail_step([s for s, ok in zip(fl.rows, fl.live)
+                             if ok and s.state == SeqState.RUNNING])
+            return
+        self._publish_step("decode", sum(fl.live), fl.ts, fl.rinfo)
 
     def _record_iteration(self, ts: float, wall: float, kind: str,
                           rinfo: Dict[str, Any]) -> None:
         """Assemble and append this iteration's flight record (step
         thread; cheap field reads only — see DYN-R004)."""
         rec = self.recorder
+        outcome = "ahead" if rinfo.get("ahead") else rinfo.get("drain", "")
+        self.run_ahead_totals[outcome] = self.run_ahead_totals.get(
+            outcome, 0) + 1
         self._flush_late_record()
         # a routed model's expert-load counters came back with the sampled
         # tokens; taken every iteration so the runner forgets the dispatch
@@ -1264,7 +1541,7 @@ class InferenceEngine:
                 if pctx is not None and pctx.trace_id not in trace_ids:
                     trace_ids.append(pctx.trace_id)
         record = IterationRecord(
-            seq=self._step_counter,
+            seq=rinfo.get("step", self._step_counter),
             ts=ts,
             wall_s=wall,
             kind=kind,
@@ -1291,6 +1568,8 @@ class InferenceEngine:
                 if rinfo.get("spec_rows") else 0.0
             ),
             guided_rows=rinfo.get("guided_rows", 0),
+            ahead=bool(rinfo.get("ahead")),
+            drain=rinfo.get("drain", ""),
             tree_hit_blocks=self.pool.match_hit_blocks,
             forks=self.pool.forks,
             trace_ids=trace_ids,
@@ -1307,17 +1586,17 @@ class InferenceEngine:
             record.ssm_scan_tokens = rinfo["chunk_tokens"] + rows
         self._settle_record(record, load)
 
-    def _decode_pages_live(self, seqs, n_steps: int) -> int:
-        """IterationRecord.decode_pages_live for these decode rows, from
-        their positions before the step: at fused step t a row's context
-        is computed_len + t + 1 tokens, and the device runs every row for
-        all n_steps (tokens past a stop are dropped on the host)."""
+    def _decode_pages_live(self, positions, n_steps: int) -> int:
+        """IterationRecord.decode_pages_live for decode rows at these
+        positions before the step: at fused step t a row's context is
+        position + t + 1 tokens, and the device runs every row for all
+        n_steps (tokens past a stop are dropped on the host)."""
         ps = self.pool.page_size
         c = self.runner.config
         window = c.sliding_window if c is not None else 0
         full = sliding = 0
-        for s in seqs:
-            for n in range(s.computed_len + 1, s.computed_len + n_steps + 1):
+        for p in positions:
+            for n in range(p + 1, p + n_steps + 1):
                 last = (n - 1) // ps
                 full += last + 1
                 sliding += last - max(n - window, 0) // ps + 1
@@ -1734,7 +2013,8 @@ class InferenceEngine:
         return {"embeds": np.ascontiguousarray(seq.mm_embeds[list(rows)]),
                 "offsets": list(offs)}
 
-    def _collect_routed(self, seqs, n_steps: int, prefills) -> None:
+    def _collect_routed(self, seqs, n_steps: int, prefills,
+                        live=None) -> None:
         """After a dispatch and before its emits: where a request of it
         asked (`sampling.routed_experts`), fetch the dispatch's picks and
         keep each asking request's share for the item _emit_item sends
@@ -1746,7 +2026,9 @@ class InferenceEngine:
         if not self._routed_ok:
             return
         want_d = [i for i, s in enumerate(seqs)
-                  if s.sampling.get("routed_experts")]
+                  if s.sampling.get("routed_experts")
+                  and (live is None or (
+                      live[i] and s.state == SeqState.RUNNING))]
         want_c = [i for i, p in enumerate(prefills)
                   if p.seq.sampling.get("routed_experts")]
         if not (want_d or want_c):
@@ -2366,15 +2648,24 @@ class InferenceEngine:
             kw["biases"] = biases
         return T, kw
 
-    def _commit_decoded(self, seqs: List[Sequence], rows, lp=None) -> None:
+    def _commit_decoded(self, seqs: List[Sequence], rows, lp=None,
+                        fl: Optional["_InFlight"] = None) -> None:
         """The one commit of a decode batch's tokens (plain, mixed, and
         both speculations), inside the caller's engine.emit span: rows[i]
         holds, in order, the tokens sequence i may take from this
         dispatch. Each is committed until one finishes the sequence
         (tokens sampled past a stop are dropped here), the guided DFA
         follows every token that did not, and the row leaves as one item.
-        `lp`: the decode loop's stacked logprob report, indexed like rows."""
+        `lp`: the decode loop's stacked logprob report, indexed like rows.
+        `fl`: the dispatch these came from where it may have waited in
+        flight: its pad rows, and rows whose sequence has finished or been
+        aborted since it was enqueued, take nothing and are never
+        complete_decode'd a second time."""
         for i, seq in enumerate(seqs):
+            if fl is not None:
+                if not fl.live[i] or seq.state != SeqState.RUNNING:
+                    continue
+                seq.inflight = max(0, seq.inflight - fl.T)
             emit: List[int] = []
             lp_entries: Optional[List[Dict[str, Any]]] = None
             if lp is not None and seq.sampling.get("logprobs") is not None:
@@ -2461,101 +2752,65 @@ class InferenceEngine:
         return prefills, out
 
     def _run_decode(self, plan: DecodePlan) -> None:
-        with annotate("engine.decode", batch=len(plan.seqs),
-                      steps=plan.n_steps):
-            with self._san_scope("decode"):
-                self._run_decode_inner(plan)
+        """A decode batch run and read back on the spot: the decode half
+        of a two-dispatch mixed iteration, and every decode of a worker
+        with a draft model."""
+        if self.runner.has_draft and self._run_draft_decode(plan):
+            return
+        self._finish_decode(self._dispatch_decode(plan, None))
 
-    def _run_decode_inner(self, plan: DecodePlan) -> None:
-        """Fused multi-step decode: plan.n_steps iterations in one jit with
-        on-device token feedback (one host sync per plan, not per token).
-        Tokens sampled past a stop are discarded host-side."""
-        with annotate("engine.prep"):
-            seqs = plan.seqs
-            T = plan.n_steps
-            tokens = [s.tokens[-1] for s in seqs]
-            positions = [s.computed_len for s in seqs]
-            page_tables = [s.pages for s in seqs]
-            step0 = self._step_counter + 1
-            gamma = self.runner.spec_gamma
-            use_draft_spec = self.runner.has_draft
-            if use_draft_spec and (
-                _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs)
-            ):
-                # the speculative verify distribution can't honor
-                # logprobs/penalties: warn once per offending request and
-                # fall back to the PLAIN decode path below, which does. The
-                # draft model's KV pools skip these positions — that costs
-                # draft acceptance on later iterations (verify still
-                # corrects every token), never correctness.
-                for s in seqs:
-                    if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
-                        self._warn_spec_once(
-                            s.request_id,
-                            "logprobs/penalties are incompatible with "
-                            "speculative verification — falling back to "
-                            "non-speculative decode",
-                        )
-                use_draft_spec = False
-        if use_draft_spec:
-            # (guided requests were rejected at admission on draft workers,
-            # so no mask handling is needed on this path)
-            # speculative path: R fused draft-propose + target-verify
-            # rounds; each round yields 1..gamma+1 tokens per sequence.
-            # Near a token budget (T < gamma+1) shrink gamma instead of
-            # falling back to plain decode — the plain path writes no draft
-            # KV, which would leave batch-wide draft-pool holes (gamma=0 is
-            # plain decoding plus the draft bookkeeping)
-            if T < gamma + 1:
-                gamma, R = T - 1, 1
-            else:
-                R = T // (gamma + 1)
-            self._step_counter += R
+    def _run_draft_decode(self, plan: DecodePlan) -> bool:
+        """Draft-model speculation: R fused draft-propose + target-verify
+        rounds; each round yields 1..gamma+1 tokens per sequence. False
+        where the batch cannot take it and nothing ran. Tokens sampled
+        past a stop are discarded host-side."""
+        seqs = plan.seqs
+        T = plan.n_steps
+        if _batch_logprobs(seqs) >= 0 or _batch_penalties(seqs):
+            # the speculative verify distribution can't honor
+            # logprobs/penalties: warn once per offending request and
+            # fall back to the PLAIN decode path, which does. The
+            # draft model's KV pools skip these positions — that costs
+            # draft acceptance on later iterations (verify still
+            # corrects every token), never correctness.
+            for s in seqs:
+                if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
+                    self._warn_spec_once(
+                        s.request_id,
+                        "logprobs/penalties are incompatible with "
+                        "speculative verification — falling back to "
+                        "non-speculative decode",
+                    )
+            return False
+        with annotate("engine.decode", batch=len(seqs), steps=T), \
+                self._san_scope("decode"):
+            with annotate("engine.prep"):
+                tokens = [s.tokens[-1] for s in seqs]
+                positions = [s.computed_len for s in seqs]
+                page_tables = [s.pages for s in seqs]
+                step0 = self._step_counter + 1
+                gamma = self.runner.spec_gamma
+                # (guided requests were rejected at admission on draft
+                # workers, so no mask handling is needed on this path)
+                # Near a token budget (T < gamma+1) shrink gamma instead of
+                # falling back to plain decode — the plain path writes no
+                # draft KV, which would leave batch-wide draft-pool holes
+                # (gamma=0 is plain decoding plus the draft bookkeeping)
+                if T < gamma + 1:
+                    gamma, R = T - 1, 1
+                else:
+                    R = T // (gamma + 1)
+                self._step_counter += R
             toks, counts = self.runner.spec_decode_multi(
-                R, tokens, positions, page_tables, _sampling_params(seqs), step0,
-                gamma=gamma, adapters=[s.adapter_idx for s in seqs],
+                R, tokens, positions, page_tables, _sampling_params(seqs),
+                step0, gamma=gamma, adapters=[s.adapter_idx for s in seqs],
             )
             with annotate("engine.emit"):
                 self._commit_decoded(seqs, [
                     [t for r in range(R) for t in toks[i, r, : counts[i, r]]]
                     for i in range(len(seqs))
                 ])
-            return
-        with annotate("engine.prep"):
-            T, mkw = self._decode_extras(seqs, T, False)
-            self._step_counter += T
-            n_lp = _batch_logprobs(seqs)
-            histories = (
-                [list(s.tokens) for s in seqs] if _batch_penalties(seqs) else None
-            )
-            if (n_lp >= 0 or histories is not None) and self.runner.pp:
-                # the PP decode loop has no logprob/penalty wiring yet — drop
-                # the extras with a warning (same contract as spec decode
-                # above) instead of letting a raise inside the shared dispatch
-                # error EVERY sequence in the plan
-                for s in seqs:
-                    if _batch_logprobs([s]) >= 0 or _batch_penalties([s]):
-                        self._warn_spec_once(
-                            s.request_id,
-                            "logprobs/penalties are unsupported on "
-                            "pipeline-parallel workers and were ignored",
-                        )
-                n_lp, histories = -1, None
-            if n_lp >= 0 or histories is not None:
-                mkw.update(n_logprobs=n_lp, histories=histories,
-                           prompt_lens=[s.n_prompt0 for s in seqs])
-            sp = _sampling_params(seqs)
-            adapters = [s.adapter_idx for s in seqs]
-        sampled = self.runner.decode_multi(
-            T, tokens, positions, page_tables, sp, step0,
-            adapters=adapters, **mkw, **self._slots_kw(seqs),
-        )
-        lp = None
-        if n_lp >= 0:
-            sampled, lp = sampled
-        self._collect_routed(seqs, T, [])
-        with annotate("engine.emit"):
-            self._commit_decoded(seqs, sampled, lp)
+        return True
 
     def _guided_advance(self, seq: Sequence, token: int) -> None:
         """Advance a sequence's constraint DFA past an accepted token. A

@@ -670,6 +670,21 @@ class MoeLoad:
                 int(round(tot[4])))
 
 
+class DecodeHandle:
+    """A decode dispatch that nobody has read back (ModelRunner.
+    decode_dispatch): its results on the device and what is its own of
+    the routed bookkeeping. `last` [rows] is what the next dispatch takes
+    as its rows' first tokens without a round trip through the host;
+    `rows` the decode bucket both must share for that."""
+
+    __slots__ = ("toks", "last", "lp", "parts", "rows")
+
+    def __init__(self, toks, last, lp, parts, rows: int):
+        self.toks, self.last, self.lp = toks, last, lp
+        self.parts = parts
+        self.rows = rows
+
+
 # Wire layout version for P→D / cross-worker KV payloads. v2 = token-major
 # [L, n, PS, Hk, D]; v1 (implicit, no field) was head-major. Mirrors the
 # disk tier's BLOCK_LAYOUT_VERSION: in a mixed-version cluster (rolling
@@ -1053,6 +1068,12 @@ class ModelRunner(Runner):
         # prefill uses the flash kernel on TPU (S>1), jnp elsewhere; with a
         # seq mesh axis, prefill goes sequence-parallel (ring attention)
         self.sp_enabled = self.mesh_config.seq > 1
+        # a decode dispatch can be left in flight and the next one chained
+        # on its tokens (decode_dispatch / decode_collect); the PP and SP
+        # programs were never run that way
+        self.can_run_ahead = not (self.pp or self.sp_enabled)
+        # where a dispatch's host tokens go (_stage_decode_rows)
+        self._tok_sharding = None if self.pp else self.policy.replicated()
         # per-family compile observability (variant counts + compile
         # seconds); see _CompiledFamily / compile_stats()
         self._families: Dict[str, _CompiledFamily] = {}
@@ -1523,8 +1544,13 @@ class ModelRunner(Runner):
         decode bucket B: (tokens [B], packed pos|pt|adapters|step) on the
         device, all per-dispatch dynamic ints in ONE transfer (see
         _decode_loop). `tokens` may be a device array [B] — the ragged
-        tail chains on the tokens the ragged step sampled — and passes
-        through untouched: no readback, no eager slice program."""
+        tail chains on the tokens the ragged step sampled, a dispatch run
+        ahead on the `last` of the one before it — and passes through
+        untouched: no readback, no eager slice program. Host tokens are
+        placed as a step program returns its own (replicated over the
+        mesh, committed): the placement is part of the jit's signature,
+        so the loop a warm-up compiled from host tokens is the one a
+        chained dispatch finds, and not a second program."""
         n = len(positions)
         pt = self._pad_page_table(page_tables, B)
         MP = pt.shape[1]
@@ -1542,7 +1568,8 @@ class ModelRunner(Runner):
             else:
                 tok_h = np.zeros(B, np.int32)
                 tok_h[:n] = tokens
-                tok = jnp.asarray(tok_h)
+                tok = (jnp.asarray(tok_h) if self._tok_sharding is None
+                       else jax.device_put(tok_h, self._tok_sharding))
             return tok, jnp.asarray(packed)
 
     def _pad_rows(self, rows: np.ndarray, B: int, fill, dtype) -> jax.Array:
@@ -1593,7 +1620,8 @@ class ModelRunner(Runner):
     ):
         """n_steps fused decode iterations (one host sync total). Page
         tables must already cover positions[i] + n_steps slots. Returns
-        sampled tokens [B_bucket, n_steps] (host).
+        sampled tokens [B_bucket, n_steps] (host): decode_dispatch and its
+        decode_collect, back to back.
 
         The sampling extras: `histories` (per-sequence prompt+generated
         token ids) switches on repetition/frequency/presence penalties —
@@ -1602,6 +1630,30 @@ class ModelRunner(Runner):
         history is prompt); with `n_logprobs` >= 0 the return is
         (sampled, (tok_lp [B, T], top_ids [B, T, K], top_lps [B, T, K]))
         host arrays."""
+        return self.decode_collect(self.decode_dispatch(
+            n_steps, tokens, positions, page_tables, sampling, step,
+            adapters=adapters, masks=masks, biases=biases, mask_fn=mask_fn,
+            guided_dev=guided_dev, n_logprobs=n_logprobs,
+            histories=histories, prompt_lens=prompt_lens, slots=slots))
+
+    def decode_bucket(self, n: int) -> int:
+        return _next_bucket(self.decode_buckets, n)
+
+    def decode_dispatch(self, n_steps, tokens, positions, page_tables,
+                        sampling, step, adapters=None, masks=None,
+                        biases=None, mask_fn=None, guided_dev=None,
+                        n_logprobs=-1, histories=None, prompt_lens=None,
+                        slots=None, prev: Optional[DecodeHandle] = None,
+                        ) -> DecodeHandle:
+        """decode_multi's stage and enqueue, without its readback: the
+        handle decode_collect reads. With `prev` (a handle of the same
+        decode bucket, read back or not) the rows take their first tokens
+        from its `last` on the device and `tokens` is not looked at: row i
+        continues row i. A row with position -1 is a pad row wherever it
+        sits (an empty page table, state slot 0): it writes nothing and its
+        tokens mean nothing, which is how a row that ended keeps its place
+        open. The pools are donated from one program to the next, so a
+        dispatch queued behind another runs after it on the device."""
         if self.pp and (
                 n_logprobs >= 0 or histories is not None or biases is not None
                 or mask_fn is not None or guided_dev is not None):
@@ -1612,6 +1664,12 @@ class ModelRunner(Runner):
         with annotate("engine.stage"):
             n = len(positions)
             B = _next_bucket(self.decode_buckets, n)
+            if prev is not None:
+                if prev.rows != B:
+                    raise ValueError(
+                        f"a dispatch of {n} rows (bucket {B}) cannot chain "
+                        f"on one of bucket {prev.rows}")
+                tokens = prev.last
             tok, packed_dev = self._stage_decode_rows(
                 tokens, positions, page_tables, step, adapters, B)
             hist = None
@@ -1636,24 +1694,51 @@ class ModelRunner(Runner):
             mkw = self._guided_kw(mask_fn, guided_dev, B)
             with self._allow("decode_staging"):
                 samp = self._device_sampling(sampling, B)
+        lp, parts = None, []
         if self.pp:
-            toks, _, self.k_pool, self.v_pool = self._jit_pp_decode(
+            toks, last, self.k_pool, self.v_pool = self._jit_pp_decode(
                 n_steps, self.params, tok, packed_dev, mask_dev,
                 self.k_pool, self.v_pool, samp,
             )
         else:
-            toks, _, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
+            toks, last, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
                 n_steps, n_logprobs, self.params, tok, packed_dev, hist,
                 mask_dev, bias_dev, self.k_pool, self.v_pool,
                 samp, self.lora, **mkw, **self._state_kw(slots, B),
             )
             routed = self._keep_state(routed)
-            self._note_routed(routed, n_steps, n_dec=n)
+            if routed:
+                # the handle's own until it is collected: a readback of
+                # another dispatch must not wait for these counters
+                parts = [_RoutedPart(routed[0], n_steps, n, ())]
+        handle = DecodeHandle(toks, last, lp if n_logprobs >= 0 else None,
+                              parts, B)
+        with self._allow("token_readback"):
+            # start the copy to the host behind this program, before
+            # another is enqueued: decode_collect then waits for this
+            # dispatch's results and not for a place in a transfer queue
+            for a in jax.tree_util.tree_leaves(
+                    (toks, handle.lp, [p.load for p in parts])):
+                a.copy_to_host_async()
+        return handle
+
+    def decode_collect(self, handle: DecodeHandle):
+        """Read a decode_dispatch back: sampled [B_bucket, n_steps] on the
+        host, with a logprob report as decode_multi pairs them. It waits
+        for this dispatch and for none queued behind it: the expert-load
+        counters it fetches beside the tokens are the handle's own (and
+        those of earlier dispatches that sampled nothing), and from here
+        on routed_picks() and take_moe_load() answer for this dispatch."""
+        if handle.parts:
+            for p in self._routed_parts:
+                p.picks = None
+            self._routed_parts.extend(handle.parts)
+            handle.parts = []
         with self._allow("token_readback"), annotate("engine.readback"):
-            if n_logprobs >= 0:
-                toks_h, lp_h = self._readback((toks, lp))
+            if handle.lp is not None:
+                toks_h, lp_h = self._readback((handle.toks, handle.lp))
                 return np.asarray(toks_h), tuple(np.asarray(a) for a in lp_h)
-            return np.asarray(self._readback(toks))
+            return np.asarray(self._readback(handle.toks))
 
     def can_fuse(self, n_decode: int, n_chunks: int, *,
                  constrained: bool) -> bool:

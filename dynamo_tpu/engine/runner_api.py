@@ -101,6 +101,9 @@ class Runner:
     #   a state slot beside its pages (ensure_state_slots), its steps take
     #   `slots=` / `slot=` / a chunk's "slot", and whatever moves KV by pages
     #   alone or rolls tokens back is refused
+    can_run_ahead = False  # decode_dispatch / decode_collect: a decode
+    #   dispatch may stay in flight while the next is chained on its tokens
+    #   (the engine's step loop keeps one ahead where this says so)
     state_slots = 0  # slots of the state pool, scratch slot 0 among them
     state_slot_bytes = 0  # one sequence's recurrent state, all layers
 
@@ -141,6 +144,32 @@ class Runner:
         """n_steps fused decode iterations: sampled [rows, n_steps] on the
         host; with n_logprobs >= 0, (sampled, lp | None)."""
         raise NotImplementedError
+
+    # The pair decode_multi is made of, for a runner that can_run_ahead.
+    # Not device steps of a multi-host group: a handle names arrays of one
+    # process, so a runner that replays its steps elsewhere says it cannot.
+    def decode_dispatch(self, n_steps, tokens, positions, page_tables,
+                        sampling, step, adapters=None, masks=None,
+                        biases=None, mask_fn=None, guided_dev=None,
+                        n_logprobs=-1, histories=None, prompt_lens=None,
+                        slots=None, prev=None):
+        """Stage and enqueue decode_multi's work and return a handle
+        without reading anything back. `prev`: a handle of the same
+        decode_bucket whose last sampled tokens, wherever they are, are
+        these rows' first tokens (row i continues row i; `tokens` is not
+        looked at). A row with position -1 is a pad row: it keeps a place
+        open and nothing is written for it."""
+        raise NotImplementedError
+
+    def decode_collect(self, handle):
+        """What decode_multi returns, for the dispatch behind `handle`;
+        waits for that dispatch alone, not for one queued behind it."""
+        raise NotImplementedError
+
+    def decode_bucket(self, n: int) -> int:
+        """The rows a decode dispatch of n rows is padded to (handles
+        chain only within one); n itself where nothing is padded."""
+        return n
 
     def can_fuse(self, n_decode: int, n_chunks: int, *,
                  constrained: bool) -> bool:

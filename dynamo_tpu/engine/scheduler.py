@@ -78,6 +78,9 @@ class Sequence:
     tokens: List[int] = field(default_factory=list)  # prompt + generated
     pages: List[int] = field(default_factory=list)
     computed_len: int = 0
+    inflight: int = 0  # decode steps dispatched and not yet committed (the
+    #   engine runs one dispatch ahead): its next position is computed_len +
+    #   inflight, and a budget those steps spend is spent
     n_shared_pages: int = 0  # leading pages from prefix-cache hits
     state_slot: int = 0  # a state-holding model: where the sequence's
     #   recurrent state lives while it is active (0: none held)
@@ -171,6 +174,13 @@ class SchedulerStats:
     n_running: int = 0
     scheduled_tokens: int = 0
     kv_usage: float = 0.0
+
+
+class StepsInFlight(Exception):
+    """The plan needs to preempt a sequence that has decode steps in
+    flight. A preemption rewrites a sequence's prompt from its committed
+    tokens, so the engine commits what is in flight first and plans again
+    (Scheduler._preempt)."""
 
 
 class Scheduler:
@@ -292,12 +302,22 @@ class Scheduler:
         if self.max_seq_tokens:
             cap = min(cap, self.max_seq_tokens)
         n_steps = self.decode_steps
+        live = []
         for s in running:
+            # steps in flight count as taken: step plans cap n_steps by
+            # every row's budget, so a row that ends by length ends exactly
+            # at a dispatch's last step, and one whose budget the steps in
+            # flight spend has no place in this plan (it finishes when they
+            # are committed)
             budget = min(
                 cap - s.computed_len,
                 int((s.stop or {}).get("max_tokens", 1 << 30)) - s.n_generated,
-            )
+            ) - s.inflight
+            if s.inflight and budget <= 0:
+                continue
+            live.append(s)
             n_steps = min(n_steps, max(1, budget))
+        running = live
         # prefill chunks claim the pool FIRST (planning is side-effect
         # free) so a speculation burst can never starve real prefills —
         # verify rows are charged from the pool's leftover only
@@ -602,8 +622,9 @@ class Scheduler:
         self, running: List[Sequence], lookahead: int = 1
     ) -> List[Sequence]:
         """Each running seq needs page slots for positions computed_len ..
-        computed_len+lookahead-1; on pool exhaustion preempt the youngest
-        sequences (recompute-style)."""
+        computed_len+lookahead-1, past the steps it has in flight; on pool
+        exhaustion preempt the youngest sequences (recompute-style), which
+        StepsInFlight puts off until nothing is in flight."""
         survivors: List[Sequence] = []
         for seq in running:
             if seq.state != SeqState.RUNNING:  # preempted by an earlier turn
@@ -611,7 +632,7 @@ class Scheduler:
             # a speculating row writes KV for its fed draft tokens at
             # computed_len+1 .. +K in the SAME dispatch, so its lookahead
             # is the draft length + 1, not the fused step count
-            last_pos = seq.computed_len + max(
+            last_pos = seq.computed_len + seq.inflight + max(
                 lookahead, len(seq.spec_draft) + 1
             ) - 1
             while True:
@@ -640,6 +661,10 @@ class Scheduler:
         return None
 
     def _preempt(self, seq: Sequence) -> None:
+        if seq.inflight:
+            # its prompt would be rewritten from tokens that lack the ones
+            # in flight: the engine commits them and plans again
+            raise StepsInFlight()
         log.info("preempting %s (recompute)", seq.request_id)
         self.pool.release(seq.pages)
         self._release_slot(seq)  # it starts over from a zeroed state
@@ -697,6 +722,7 @@ class Scheduler:
         self.pool.release(seq.pages)
         self._release_slot(seq)
         seq.pages = []
+        seq.inflight = 0  # what is still queued for it is dropped at commit
         seq.spec_draft = []
         seq.spec_tree = []
         if seq in self.active:

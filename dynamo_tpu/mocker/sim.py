@@ -281,8 +281,19 @@ def _sim_token(seed: int, position: int, vocab: int = 50000) -> int:
     return (seed * 1103515245 + position * 2654435761) % (vocab - 16) + 16
 
 
+class _SimHandle:
+    """A SimRunner decode dispatch: its tokens and when the simulated
+    device is done with it."""
+
+    def __init__(self, done_at: float):
+        self.out = None
+        self.done_at = done_at
+
+
 class SimRunner(Runner):
     """Drop-in for ModelRunner inside InferenceEngine (no JAX)."""
+
+    can_run_ahead = True
 
     # guided rows ride full multi-step loops: decode_multi honors the
     # engine's host-callback mask context between fused steps, so the
@@ -345,6 +356,8 @@ class SimRunner(Runner):
         # that consume onboarded pages block on it before returning.
         self._onboard_ready_t = 0.0
         self._onboard_rest_s = 0.0
+        # when the simulated device finishes its newest decode dispatch
+        self._device_free_at = 0.0
 
     def charged_tokens(self) -> int:
         return self.stats["packed_tokens_charged"]
@@ -408,12 +421,47 @@ class SimRunner(Runner):
         mask_fn=None, guided_dev=None, n_logprobs: int = -1,
         histories=None, prompt_lens=None,
     ):
-        t = self.timing
-        t.sleep(
-            t.dispatch_overhead_s
-            + n_steps * (t.decode_base_s + len(tokens) * t.decode_per_seq_s)
-        )
+        return self.decode_collect(self.decode_dispatch(
+            n_steps, tokens, positions, page_tables, sampling, step,
+            masks=masks, mask_fn=mask_fn, guided_dev=guided_dev,
+            n_logprobs=n_logprobs))
+
+    def decode_collect(self, handle):
+        """Wait until the simulated device has finished the dispatch."""
+        wait = handle.done_at - time.monotonic()
+        if wait > 0:
+            time.sleep(wait)
         self._drain_onboard()
+        return handle.out
+
+    def decode_dispatch(
+        self, n_steps: int, tokens, positions: List[int],
+        page_tables, sampling, step: int, adapters=None, masks=None,
+        biases=None, mask_fn=None, guided_dev=None, n_logprobs: int = -1,
+        histories=None, prompt_lens=None, slots=None, prev=None,
+    ):
+        """The tokens at once (they are a pure function of the inputs) and
+        the time the device model owes for them on the handle: the device
+        starts a dispatch when it has finished the one before, and the host
+        pays for it only where decode_collect has to wait. Host time spent
+        under a queued dispatch is therefore counted once."""
+        t = self.timing
+        if prev is not None:
+            toks = prev.out[0] if isinstance(prev.out, tuple) else prev.out
+            tokens = [int(x) for x in toks[:, -1]]
+        cost = t.speed * (
+            t.dispatch_overhead_s
+            + n_steps * (t.decode_base_s + len(positions) * t.decode_per_seq_s))
+        self._device_free_at = max(self._device_free_at,
+                                   time.monotonic()) + cost
+        handle = _SimHandle(self._device_free_at)
+        handle.out = self._decode_tokens(
+            n_steps, tokens, positions, masks, mask_fn, guided_dev,
+            n_logprobs)
+        return handle
+
+    def _decode_tokens(self, n_steps, tokens, positions, masks, mask_fn,
+                       guided_dev, n_logprobs):
         # device-resident guided plan: the numpy twin of the runner's
         # in-XLA DFA walk (_decode_loop's `guided` operand) — combined
         # transition/mask tables, per-row global states, advance-before-

@@ -241,6 +241,10 @@ _PREV_LOGITS = "__prev_logits__"
 
 
 _COLOCATED_ONLY = ("export_pages_device", "import_pages_device")
+# what this class answers itself and never reads through to the wrapped
+# runner: the colocated-only refusals and the run-ahead door (a handle
+# names one process's arrays; the group replays whole steps)
+_OWN = _COLOCATED_ONLY + ("can_run_ahead", "decode_dispatch", "decode_collect")
 
 
 class ReplicatingRunner(Runner):
@@ -254,6 +258,8 @@ class ReplicatingRunner(Runner):
     engine passes are logits into sample_one, replaced by the
     _PREV_LOGITS sentinel (the follower substitutes its own replica)."""
 
+    can_run_ahead = False  # followers replay decode_multi whole
+
     def __init__(self, runner, plane: StepPlaneLeader):
         self._runner = runner
         self._plane = plane
@@ -261,7 +267,7 @@ class ReplicatingRunner(Runner):
     def __getattribute__(self, name):
         # every public name belongs to the wrapped runner, the base's
         # defaults included: this class only stands in the door
-        if name.startswith("_") or name in _COLOCATED_ONLY:
+        if name.startswith("_") or name in _OWN:
             return object.__getattribute__(self, name)
         attr = getattr(object.__getattribute__(self, "_runner"), name)
         if name not in DEVICE_STEPS:
@@ -288,6 +294,12 @@ class ReplicatingRunner(Runner):
     def import_pages_device(self, *a, **kw):
         raise RuntimeError("device-handle KV import is colocated-only; "
                            "multihost groups use import_pages()")
+
+    def decode_dispatch(self, *a, **kw):
+        raise RuntimeError("a multihost group replays whole device steps: "
+                           "decode_multi, not its dispatch and collect halves")
+
+    decode_collect = decode_dispatch
 
 
 def follower_loop(runner, sock: socket.socket) -> None:

@@ -53,13 +53,28 @@ class IterationRecord:
     increasing process totals sampled at append time (deltas between
     consecutive records give per-iteration rates).
 
-    `wall_s` is the step, not its enqueue: the engine reads the clock
-    after `step_plan()` returns and again after `_publish_kv_events()`,
-    around a blocking `device_get` of the sampled tokens. So it is input
-    prep + staging + dispatch + the device's time + readback + emit +
-    publish (the `engine.<parent>` and `engine.publish` spans, less the
-    record's own append), and it leaves out the inbox, the scheduler and
-    any idle sleep (`engine.inbox`, `engine.schedule`, `engine.wait`).
+    `wall_s` runs from commit to commit: from the moment the iteration
+    before this one was published (or the loop last found nothing to do)
+    to the moment this one was. The step loop keeps one decode dispatch in
+    flight, so an iteration's own enqueue-to-commit span overlaps its
+    neighbours' and would count the device's time twice; commit to commit
+    it is what a token waits for an iteration, and the records' walls add
+    up to the loop's busy time: everything but its idle sleeps
+    (`engine.wait`), the inbox and the scheduler included. `ts` is when
+    the iteration's staging began, which for an iteration enqueued ahead
+    is before the one before it was committed.
+
+    `ahead`: the iteration was planned, staged and enqueued before the
+    iteration before it was read back (its rows took their first tokens
+    on the device). `drain` says why not, "" where it was: "cold" nothing
+    was in flight (the iteration before was no plain decode, or the loop
+    idled), "rows" the plan held a row the dispatch in flight did not
+    (a joiner), "bucket" the rows left fit a smaller decode bucket,
+    "prefill" / "mixed" the plan carried prompt chunks, "preempt" the
+    scheduler needed a preemption, "spec" / "guided" / "penalties" the
+    next plan needs this one's tokens on the host, "runner" the runner
+    cannot run ahead (pipeline or sequence parallel programs, a multi-host
+    group), "shutdown" the engine is stopping.
 
     The `moe_*` fields are a routed model's expert load, reduced on
     the device from the router's picks over real rows (padding masked)
@@ -116,7 +131,7 @@ class IterationRecord:
 
     seq: int               # engine iteration number (monotonic)
     ts: float              # wall clock (time.time()) at iteration start
-    wall_s: float          # the step's wall time, device included (above)
+    wall_s: float          # commit to commit (above)
     kind: str              # "prefill" | "decode" | "mixed"
     decode_seqs: int       # decode batch rows this iteration
     decode_steps: int      # fused decode steps (T)
@@ -138,6 +153,8 @@ class IterationRecord:
     decode_pages_live: int = 0  # live KV pages walked (see the docstring)
     ragged_pages_live: int = 0  # live (unit, page) pairs of a ragged step
     anomaly: bool = False  # this iteration fired the EWMA trigger
+    ahead: bool = False    # enqueued before the one before was read back
+    drain: str = ""        # why not (the closed set above); "" where ahead
     # speculative decoding: mean tokens emitted per speculating row this
     # iteration (accepted drafts + the verified/bonus token; 0.0 when no
     # row speculated) — the per-step multi-token factor the ITL spine

@@ -456,6 +456,25 @@ async def serve_worker(
         engine.on_fpm(_update_compile_gauges)
         _update_compile_gauges()
 
+    # the step loop's run-ahead -> /metrics: iterations by how they were
+    # enqueued, "ahead" of the read-back of the one before or the reason
+    # not (IterationRecord.drain; docs/observability.md "Run-ahead")
+    if hasattr(engine, "run_ahead_totals"):
+        _rm = runtime.metrics.child(dynamo_namespace=namespace)
+        _ahead_sent: dict = {}
+
+        def _update_run_ahead(_m=None) -> None:
+            for outcome, n in list(engine.run_ahead_totals.items()):
+                _rm.counter(
+                    "engine_run_ahead_total",
+                    "engine iterations by how they were enqueued: ahead of "
+                    "the read-back of the iteration before, or why not",
+                    outcome=outcome,
+                ).inc(n - _ahead_sent.get(outcome, 0))
+                _ahead_sent[outcome] = n
+
+        engine.on_fpm(_update_run_ahead)
+
     # routed experts -> /metrics: the engine's expert-load counters
     # (IterationRecord.moe_*; docs/observability.md "Routed experts") as
     # two gauges of the newest iteration and two running totals. Only a

@@ -78,7 +78,7 @@ async def test_donated_pool_poisoning_recovers(engine):
     subsequent requests."""
     import jax
 
-    orig = engine.runner.decode_multi
+    orig = engine.runner.decode_dispatch
 
     def consume_and_fail(*a, **kw):
         # mimic a jit failure after donation: buffers gone, call raised
@@ -86,11 +86,11 @@ async def test_donated_pool_poisoning_recovers(engine):
             arr.delete()
         raise RuntimeError("injected post-donation failure")
 
-    engine.runner.decode_multi = consume_and_fail
+    engine.runner.decode_dispatch = consume_and_fail
     try:
         items = await asyncio.wait_for(_generate(engine, [11, 12, 13]), timeout=30)
     finally:
-        engine.runner.decode_multi = orig
+        engine.runner.decode_dispatch = orig
     assert items[-1]["finish_reason"] == "error"
     # the error stream item is emitted before the step thread rebuilds the
     # pools — poll briefly rather than racing it

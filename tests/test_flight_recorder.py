@@ -138,6 +138,43 @@ async def test_record_fields_vs_sim_mixed_plan():
             assert r.decode_seqs == 0 and r.n_chunks == 1
 
 
+async def test_run_ahead_fields_and_commit_to_commit_walls():
+    """A run of decode iterations: the first starts cold, the rest are
+    enqueued ahead (`ahead`, `drain`); `wall_s` runs from commit to commit,
+    so the walls tile the loop's busy time: their sum is the run's wall,
+    less the idle sleeps around it, and in a run of iterations enqueued
+    ahead a decode's wall is the device's step time and not device plus
+    host."""
+    import time
+
+    step = 0.010
+    engine = _mk_engine(decode_base_s=step)
+    engine.start()
+    try:
+        t0 = time.monotonic()
+        toks, _ = await _gen(engine, [1, 2, 3, 4, 5], 41)
+        total = time.monotonic() - t0
+    finally:
+        engine.stop()
+    assert len(toks) == 41
+    recs = engine.recorder.snapshot()
+    dec = [r for r in recs if r.kind == "decode"]
+    assert [r.kind for r in recs] == ["prefill"] + ["decode"] * 10
+    assert [(r.ahead, r.drain) for r in dec] == [(False, "cold")] + [
+        (True, "")] * 9
+    assert recs[0].drain == "prefill" and not recs[0].ahead
+    walls = sum(r.wall_s for r in recs)
+    assert walls <= total and walls >= total - 0.05, (walls, total)
+    # 4 fused steps of `step` + per-seq + dispatch overhead a dispatch
+    device = 4 * (step + 0.0003) + 0.002
+    mid = sorted(r.wall_s for r in dec[2:])[len(dec[2:]) // 2]
+    assert device * 0.9 <= mid <= device * 1.5, (mid, device)
+    # records stay in commit order while `ts` (staging began) runs ahead
+    assert [r.seq for r in recs] == sorted(r.seq for r in recs)
+    assert engine.run_ahead_totals == {"prefill": 1, "cold": 1, "ahead": 9}
+
+
+
 @pytest.mark.parametrize("window,n_global", [(0, 0), (6, 0), (6, 1)])
 async def test_record_counts_live_decode_pages(window, n_global):
     """decode_pages_live: over an iteration's decode rows and fused steps,
@@ -221,6 +258,7 @@ def test_record_counts_live_ragged_pairs(monkeypatch, model):
     add("a", a)
     engine._loop_once()  # a's prefill
     engine._loop_once()  # a decodes alone
+    engine._commit_inflight()  # (it stays in flight until the next plan)
     engine._flush_late_record()
     alone = engine.recorder.snapshot()[-1]
     assert alone.kind == "decode" and alone.ragged_pages_live == 0

@@ -446,8 +446,11 @@ async def test_preempted_and_cancelled_sequences_leave_no_state_behind(monkeypat
             calls["n"] += 1
             run = [s for s in sched.active if s.state == SeqState.RUNNING]
             if run and run[0].n_generated >= 4 and not seen:
-                seen["slot"] = run[0].state_slot
+                slot = run[0].state_slot
+                # (StepsInFlight while the loop has a dispatch in flight:
+                # it commits that and plans again, and this runs again)
                 sched._preempt(run[0])
+                seen["slot"] = slot
             return plan()
 
         sched.step_plan = preempting
