@@ -49,7 +49,19 @@ from dynamo_tpu.engine.ngram_draft import (
 )
 from dynamo_tpu.frontend.protocols import engine_output
 from dynamo_tpu.models.config import mean_over_layers
-from dynamo_tpu.runtime.annotations import annotate
+from dynamo_tpu.runtime.annotations import (
+    EMIT,
+    INBOX,
+    PREP,
+    PUBLISH,
+    SCHEDULE,
+    WAIT,
+    StepClock,
+    annotate,
+    bind_clock,
+    phase,
+    unbind_clock,
+)
 from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.flight_recorder import FlightRecorder, IterationRecord
 from dynamo_tpu.runtime import tracing
@@ -443,6 +455,11 @@ class InferenceEngine:
         self._inflight: Optional[_InFlight] = None
         self._t_mark = time.monotonic()
         self.run_ahead_totals: Dict[str, int] = {}
+        # the step thread's host clock (runtime/annotations.py, the door):
+        # bound to the step thread while the loop runs, emptied into each
+        # iteration's record; on with the recorder and off with it
+        self.step_clock: Optional[StepClock] = (
+            StepClock() if self.recorder.enabled else None)
         self.moe_totals = {"token_slots_total": 0, "held_slots_total": 0.0,
                            "experts_hit": 0.0, "load_max_share": 0.0}
         # sick peers for cross-worker pulls: instance -> retry-after time
@@ -1026,6 +1043,8 @@ class InferenceEngine:
         # compiles on this thread that no step family sees count in this
         # runner's compile_stats()["other"]
         self.runner.name_step_thread()
+        if self.step_clock is not None:
+            bind_clock(self.step_clock)
         self._t_mark = time.monotonic()
         if self._routed_ok:
             # what the runner holds of dispatches that were not this
@@ -1061,6 +1080,7 @@ class InferenceEngine:
             self._commit_inflight()
         except Exception:
             log.exception("commit of the dispatch in flight failed")
+        unbind_clock()
         log.info("engine step loop stopped")
 
     def _loop_once(self) -> None:
@@ -1078,11 +1098,11 @@ class InferenceEngine:
         from dynamo_tpu.parallel.multihost import GroupBroken
 
         sched = self.scheduler
-        with annotate("engine.inbox"):
+        with phase(INBOX):
             self._drain_inbox()  # dynlint: disable=DYN-J006 — embed readback (.tolist in _run_embeds) is a request-boundary transfer; sanitizer allowlists it as "embed_readback"
             self._propose_drafts()
-        with annotate("engine.schedule", waiting=len(sched.waiting),
-                      running=len(sched.active)):
+        with phase(SCHEDULE, waiting=len(sched.waiting),
+                   running=len(sched.active)):
             try:
                 plan = sched.step_plan()
                 why = self._why_not_ahead(plan)
@@ -1091,19 +1111,30 @@ class InferenceEngine:
         if self._inflight is not None and why is not None:
             # the plan was made around steps in flight (rows they finish
             # left out, positions past them): commit them, then plan on
-            # what they brought
+            # what they brought. A prompt whose chunk that plan held was
+            # admitted already and waits out the commit inside its
+            # prefill_s: the spine's drain_wait_s, the first token's price
+            # for the dispatch in flight
+            t_drain = time.monotonic()
             self._commit_inflight()
-            with annotate("engine.schedule", waiting=len(sched.waiting),
-                          running=len(sched.active)):
+            t_drain = time.monotonic() - t_drain
+            for seq in _prefill_seqs(plan):
+                if "ttft_s" not in seq.phases:
+                    seq.phases["drain_wait_s"] = seq.phases.get(
+                        "drain_wait_s", 0.0) + t_drain
+            with phase(SCHEDULE, waiting=len(sched.waiting),
+                       running=len(sched.active)):
                 plan = sched.step_plan()
                 if why == "idle":  # the commit left something to plan
                     why = self._why_not_ahead(plan)
         if plan is None:
             self._flush_late_record()
             if not sched.has_work():
-                with annotate("engine.wait"):
+                with phase(WAIT):
                     time.sleep(self.idle_sleep_s)
             self._t_mark = time.monotonic()
+            if self.step_clock is not None:
+                self.step_clock.clear()  # as the wall: no iteration's
             return
         if isinstance(plan, DecodePlan) and not (
                 self.runner.has_draft or any(s.spec_draft for s in plan.seqs)):
@@ -1279,16 +1310,21 @@ class InferenceEngine:
         t_start = self._t_mark
         if t0 is None:
             t0 = t_start
-        with annotate("engine.publish"):
+        with phase(PUBLISH):
             if self.sanitizer is not None:
                 # arms the transfer guard + freezes the compiled-family
                 # baseline after warmup; a new variant past that is a leak
                 self.sanitizer.note_step(self.runner)
             self._publish_fpm(kind, time.monotonic() - t0, n_tok)
             self._publish_kv_events()
+            # the commit mark: this wall and the host clock's interval end
+            # on it and the next ones start on it, so the walls add up to
+            # the loop's busy time and a record's phases to at most its wall
+            now_ns = time.monotonic_ns()
             self._record_iteration(
-                ts_wall, time.monotonic() - t_start, rec_kind or kind, rinfo)
-            self._t_mark = time.monotonic()
+                ts_wall, now_ns * 1e-9 - t_start, rec_kind or kind, rinfo,
+                now_ns)
+            self._t_mark = now_ns * 1e-9
 
     def _fail_step(self, seqs) -> None:
         """One bad step fails ITS sequences and never the step thread."""
@@ -1371,7 +1407,13 @@ class InferenceEngine:
             return
         nxt.rinfo["ahead"] = prev is not None
         nxt.rinfo["drain"] = "" if prev is not None else (why or "cold")
-        self._commit_inflight()
+        try:
+            self._commit_inflight()
+        except BaseException:
+            # nxt is dropped uncollected: not in flight for the clock either
+            if nxt.handle is not None and self.step_clock is not None:
+                self.step_clock.handles -= 1
+            raise
         if self._ahead_blocker(plan.seqs) is None:
             self._inflight = nxt
         else:
@@ -1388,7 +1430,7 @@ class InferenceEngine:
         scratch state slot."""
         with annotate("engine.decode", batch=len(plan.seqs),
                       steps=plan.n_steps), self._san_scope("decode"):
-            with annotate("engine.prep"):
+            with phase(PREP):
                 ts_wall = time.time()
                 T = plan.n_steps
                 if prev is None:
@@ -1449,6 +1491,10 @@ class InferenceEngine:
                 fl.handle = self.runner.decode_dispatch(
                     *args, adapters=adapters, **mkw,
                     prev=None if prev is None else prev.handle)
+                if self.step_clock is not None:
+                    # enqueued and not collected: what the host does from
+                    # here to its decode_collect is hidden under it
+                    self.step_clock.handles += 1
             else:
                 # a runner of whole steps (a multi-host group replays
                 # decode_multi): the readback is part of the call
@@ -1471,12 +1517,16 @@ class InferenceEngine:
                 self._san_scope("decode"):
             sampled = fl.sampled
             if fl.handle is not None:
-                sampled = self.runner.decode_collect(fl.handle)
+                try:
+                    sampled = self.runner.decode_collect(fl.handle)
+                finally:
+                    if self.step_clock is not None:
+                        self.step_clock.handles -= 1
             lp = None
             if fl.n_lp >= 0:
                 sampled, lp = sampled
             self._collect_routed(fl.rows, fl.T, [], fl.live)
-            with annotate("engine.emit"):
+            with phase(EMIT):
                 self._commit_decoded(fl.rows, sampled, lp, fl)
 
     def _commit_decode(self, fl: "_InFlight") -> None:
@@ -1497,9 +1547,10 @@ class InferenceEngine:
         self._publish_step("decode", sum(fl.live), fl.ts, fl.rinfo)
 
     def _record_iteration(self, ts: float, wall: float, kind: str,
-                          rinfo: Dict[str, Any]) -> None:
+                          rinfo: Dict[str, Any], now_ns: int) -> None:
         """Assemble and append this iteration's flight record (step
-        thread; cheap field reads only — see DYN-R004)."""
+        thread; cheap field reads only — see DYN-R004). `now_ns`: the
+        commit mark `wall` ends on (time.monotonic_ns)."""
         rec = self.recorder
         outcome = "ahead" if rinfo.get("ahead") else rinfo.get("drain", "")
         self.run_ahead_totals[outcome] = self.run_ahead_totals.get(
@@ -1518,10 +1569,9 @@ class InferenceEngine:
             if self.host_pool.disk is not None:
                 g3 = len(self.host_pool.disk)
         hits = self.prefetch.stats["hits"] if self.prefetch is not None else 0
-        variants = calls = 0
+        variants = 0
         for fam in self.runner.compile_families().values():
             variants += fam.variants
-            calls += fam.calls
         charged = rinfo["chunk_tokens"]
         cum = self.runner.charged_tokens()
         if cum is not None:
@@ -1559,7 +1609,6 @@ class InferenceEngine:
             g3_blocks=g3,
             prefetch_hits=hits,
             compile_variants=variants,
-            compile_calls=calls,
             decode_pages_live=rinfo.get("pages_live", 0) - (
                 rinfo.get("pages_step0", 0) if rinfo["ragged"] else 0),
             ragged_pages_live=rinfo.get("ragged_pages_live", 0),
@@ -1570,8 +1619,6 @@ class InferenceEngine:
             guided_rows=rinfo.get("guided_rows", 0),
             ahead=bool(rinfo.get("ahead")),
             drain=rinfo.get("drain", ""),
-            tree_hit_blocks=self.pool.match_hit_blocks,
-            forks=self.pool.forks,
             trace_ids=trace_ids,
         )
         if self._state_on:
@@ -1584,6 +1631,9 @@ class InferenceEngine:
             rows = rinfo["decode_seqs"] if rinfo["ragged"] else 0
             record.ssm_scan_segments = rinfo["n_chunks"] + rows
             record.ssm_scan_tokens = rinfo["chunk_tokens"] + rows
+        clock = self.step_clock  # (there is one: rec.enabled)
+        clock.cut(now_ns)
+        rec.take_clock(record, clock)
         self._settle_record(record, load)
 
     def _decode_pages_live(self, positions, n_steps: int) -> int:
@@ -2079,14 +2129,14 @@ class InferenceEngine:
                 }
                 for p in plans
             ])
-            with annotate("engine.emit"):
+            with phase(EMIT):
                 for plan, lg in zip(plans, logits_rows):
                     self.scheduler.complete_prefill(plan)
                     self._finish_prefill(plan, lg)
 
     def _run_prefill_inner(self, plan: PrefillPlan) -> None:
         seq = plan.seq
-        with annotate("engine.prep"):
+        with phase(PREP):
             mm_chunk = self._mm_chunk(seq, plan.start_pos, len(plan.chunk))
         logits = self.runner.prefill(
             plan.chunk,
@@ -2107,7 +2157,7 @@ class InferenceEngine:
                 mm=mm_chunk,
             )
         self._collect_routed([], 0, [plan])
-        with annotate("engine.emit"):
+        with phase(EMIT):
             self.scheduler.complete_prefill(plan)
             self._finish_prefill(plan, logits)
 
@@ -2254,7 +2304,7 @@ class InferenceEngine:
         decode half)."""
         from dynamo_tpu.parallel.multihost import GroupBroken
 
-        with annotate("engine.emit"):
+        with phase(EMIT):
             for pplan, lg in zip(prefills, chunk_logits):
                 try:
                     self.scheduler.complete_prefill(pplan)
@@ -2433,7 +2483,7 @@ class InferenceEngine:
         seqs = dplan.seqs
         with annotate("engine.spec_verify", batch=len(seqs),
                       chunks=len(prefills)):
-            with annotate("engine.prep"):
+            with phase(PREP):
                 drafts = [list(s.spec_draft) for s in seqs]
                 trees = [list(s.spec_tree) for s in seqs]
                 for s in seqs:
@@ -2524,7 +2574,7 @@ class InferenceEngine:
                 )
                 return None
             rows, chunk_logits = out
-            with annotate("engine.emit"):
+            with phase(EMIT):
                 n_rows = sum(1 for d in drafts[: len(seqs)] if d)
                 accepted = emitted_spec = tree_sw = 0
                 taken: List[List[int]] = []  # per sequence, what it accepts
@@ -2698,7 +2748,7 @@ class InferenceEngine:
         with annotate("engine.mixed", batch=len(seqs),
                       steps=plan.decode.n_steps, chunks=len(prefills),
                       chunk=sum(len(p.chunk) for p in prefills)):
-            with annotate("engine.prep"):
+            with phase(PREP):
                 tokens = [s.tokens[-1] for s in seqs]
                 positions = [s.computed_len for s in seqs]
                 tables = [s.pages for s in seqs]
@@ -2747,7 +2797,7 @@ class InferenceEngine:
                         e, shed.seq.request_id,
                     )
             self._collect_routed(seqs, T, prefills)
-            with annotate("engine.emit"):
+            with phase(EMIT):
                 self._commit_decoded(seqs, out[0])
         return prefills, out
 
@@ -2784,7 +2834,7 @@ class InferenceEngine:
             return False
         with annotate("engine.decode", batch=len(seqs), steps=T), \
                 self._san_scope("decode"):
-            with annotate("engine.prep"):
+            with phase(PREP):
                 tokens = [s.tokens[-1] for s in seqs]
                 positions = [s.computed_len for s in seqs]
                 page_tables = [s.pages for s in seqs]
@@ -2805,7 +2855,7 @@ class InferenceEngine:
                 R, tokens, positions, page_tables, _sampling_params(seqs),
                 step0, gamma=gamma, adapters=[s.adapter_idx for s in seqs],
             )
-            with annotate("engine.emit"):
+            with phase(EMIT):
                 self._commit_decoded(seqs, [
                     [t for r in range(R) for t in toks[i, r, : counts[i, r]]]
                     for i in range(len(seqs))
@@ -2884,6 +2934,9 @@ class InferenceEngine:
             # (loadgen/goodput aggregate it; the frontend adds span events)
             phases = dict(seq.phases)
             phases["preemptions"] = seq.n_preemptions
+            # inside prefill_s: what its chunks waited for the commit of a
+            # dispatch in flight (_loop_once); 0.0 where they waited for none
+            phases.setdefault("drain_wait_s", 0.0)
             if seq.arrival:
                 phases["e2e_s"] = max(0.0, time.monotonic() - seq.arrival)
             if seq.itl:
@@ -3295,6 +3348,15 @@ def _batch_biases(seqs: List[Sequence], runner):
             s._bias_row = cached  # constant for the sequence's lifetime
         rows[i] = cached
     return rows
+
+
+def _prefill_seqs(plan) -> List[Sequence]:
+    """The sequences whose prompt chunks `plan` carries."""
+    if isinstance(plan, PrefillPlan):
+        return [plan.seq]
+    if isinstance(plan, MixedPlan):
+        return [p.seq for p in plan.prefills]
+    return []
 
 
 def _batch_penalties(seqs: List[Sequence]) -> bool:

@@ -40,7 +40,13 @@ from dynamo_tpu.models import jamba, llama
 from dynamo_tpu.models.config import ModelConfig, mean_over_layers
 from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
-from dynamo_tpu.runtime.annotations import annotate
+from dynamo_tpu.runtime.annotations import (
+    DISPATCH,
+    READBACK,
+    STAGE,
+    phase,
+    synced,
+)
 
 log = logging.getLogger("dynamo_tpu.engine.runner")
 
@@ -591,7 +597,7 @@ class _CompiledFamily:
         self._asked_xla = False
         t0 = time.monotonic()
         try:
-            with annotate("engine.dispatch", family=self.name):
+            with phase(DISPATCH, family=self.name):
                 out = self._fn(*args, **kwargs)
         finally:
             _compile_tls.family = outer
@@ -1285,7 +1291,7 @@ class ModelRunner(Runner):
         `prior_len` is the context length already in the pool (prefix-cache
         hits + earlier chunks). `mm` injects multimodal embeddings at
         chunk-local offsets. Returns last-token logits [V] (device)."""
-        with annotate("engine.stage"):
+        with phase(STAGE):
             tok, pos, pt, kv_lens, n = self._prep_prefill(tokens, start_pos, page_table_row, prior_len)
             if not self.pp:
                 mm_embeds, mm_mask = self._mm_arrays(mm, tok.shape[1])
@@ -1416,8 +1422,11 @@ class ModelRunner(Runner):
         of the expert-load counters no readback has brought yet (a dense
         model has none: the plain device_get it always was)."""
         if not self._routed_parts:
-            return jax.device_get(x)
-        return _device_get_with_loads(self._routed_parts, x)
+            out = jax.device_get(x)
+        else:
+            out = _device_get_with_loads(self._routed_parts, x)
+        synced()  # the step clock: nothing serial is enqueued any more
+        return out
 
     def routed_picks(self):
         """The experts every token of the newest dispatch (a ragged step
@@ -1429,7 +1438,7 @@ class ModelRunner(Runner):
         in an iteration where a request asked (`routed_experts`); the
         arrays otherwise never leave the device."""
         parts = [p for p in self._routed_parts if p.picks is not None]
-        with self._allow("token_readback"), annotate("engine.readback"):
+        with self._allow("token_readback"), phase(READBACK):
             host = self._readback([p.picks for p in parts])
         dec: List[np.ndarray] = []
         chunks: List[np.ndarray] = []
@@ -1661,7 +1670,7 @@ class ModelRunner(Runner):
                 "logprobs/penalties/logit_bias/multi-step guided masks "
                 "are not wired on the pipeline-parallel decode path yet"
             )
-        with annotate("engine.stage"):
+        with phase(STAGE):
             n = len(positions)
             B = _next_bucket(self.decode_buckets, n)
             if prev is not None:
@@ -1734,7 +1743,7 @@ class ModelRunner(Runner):
                 p.picks = None
             self._routed_parts.extend(handle.parts)
             handle.parts = []
-        with self._allow("token_readback"), annotate("engine.readback"):
+        with self._allow("token_readback"), phase(READBACK):
             if handle.lp is not None:
                 toks_h, lp_h = self._readback((handle.toks, handle.lp))
                 return np.asarray(toks_h), tuple(np.asarray(a) for a in lp_h)
@@ -1859,7 +1868,7 @@ class ModelRunner(Runner):
                 f"alone, and this plan ({len(positions)} rows + {len(chunks)} "
                 "chunks) has more segments than it takes")
         n_dec = len(positions)
-        with annotate("engine.stage"):
+        with phase(STAGE):
             chunk_half = self._prep_prefill_packed(chunks)
             B = _next_bucket(self.decode_buckets, n_dec)
             tok_dev, packed_dev = self._stage_decode_rows(
@@ -1874,7 +1883,7 @@ class ModelRunner(Runner):
         )
         self._note_routed(routed, 1 + n_steps, n_dec,
                           [len(c["tokens"]) for c in chunks])
-        with annotate("engine.readback"):
+        with phase(READBACK):
             sampled = np.asarray(self._readback(toks))
             rows = _chunk_rows(chunk_logits, len(chunks))
         return MixedOut(sampled, rows, False)
@@ -2094,7 +2103,7 @@ class ModelRunner(Runner):
            seeds and step indices match the legacy fused loop exactly).
         Returns decode_multi_with_prefills' MixedOut."""
         n_dec = len(positions)
-        with annotate("engine.stage"):
+        with phase(STAGE):
             (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
              seg_cap, pages_live) = self._prep_ragged(
                  tokens, positions, page_tables, chunks)
@@ -2119,7 +2128,7 @@ class ModelRunner(Runner):
         tok0 = sampled[:B]  # decode rows lead the segment order
         chunk_logits = seg_logits[n_dec : n_dec + len(chunks)]  # [N, V]
         if n_steps > 1:
-            with annotate("engine.stage"):
+            with phase(STAGE):
                 # guided rows continue through the fused tail: tok0 was
                 # sampled on the device and is not yet folded into the row
                 # states, so the host callback advances each DFA copy by it
@@ -2145,14 +2154,14 @@ class ModelRunner(Runner):
             )
             routed = self._keep_state(routed)
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
-            with annotate("engine.readback"):
+            with phase(READBACK):
                 tok0_h, rest_h = self._readback((tok0, rest))
                 rows = _chunk_rows(chunk_logits, len(chunks))
             toks = np.concatenate(
                 [np.asarray(tok0_h)[:, None], np.asarray(rest_h)], axis=1
             )
         else:
-            with annotate("engine.readback"):
+            with phase(READBACK):
                 toks = np.asarray(self._readback(tok0))[:, None]
                 rows = _chunk_rows(chunk_logits, len(chunks))
         return MixedOut(toks, rows, True, pages_live)
@@ -2223,7 +2232,7 @@ class ModelRunner(Runner):
             RAGGED_MAX_SEGS, build_ragged_metadata, ragged_seg_cap,
         )
 
-        with annotate("engine.stage"):
+        with phase(STAGE):
             chunks = list(chunks)
             n_rows = len(positions)
             row_lens = [len(d) + 1 for d in drafts]
@@ -2325,7 +2334,7 @@ class ModelRunner(Runner):
         # the counters hold for a verify dispatch too; its picks have no
         # reader (a worker that speculates refuses `routed_experts`)
         self._note_routed([{"load": r["load"]} for r in routed], 1)
-        with self._allow("token_readback"), annotate("engine.readback"):
+        with self._allow("token_readback"), phase(READBACK):
             sampled_h = np.asarray(self._readback(sampled))  # one bulk sync
         out: List[np.ndarray] = []
         w = 0
@@ -2577,7 +2586,7 @@ class ModelRunner(Runner):
                 pt_d, samp, step_d, self.lora, adapt_d,
             )
         )
-        with self._allow("token_readback"), annotate("engine.readback"):
+        with self._allow("token_readback"), phase(READBACK):
             toks_h, counts_h = jax.device_get((toks, counts))
         return np.asarray(toks_h), np.asarray(counts_h)
 

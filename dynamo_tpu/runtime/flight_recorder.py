@@ -26,10 +26,13 @@ Anomaly trigger: per-kind EWMA of iteration wall time; an iteration
 exceeding `ewma * anomaly_k` (after `anomaly_min_samples` warmup) fires
 ONCE per excursion — the trigger re-arms only after a sub-threshold
 iteration of the same kind, so a sustained stall produces one dump, not
-one per iteration. A fired trigger snapshots the last N records to the
-dump queue; the daemon thread writes them as JSON under
-`anomaly_dump_dir` and (optionally) opens a `jax.profiler` capture
-window so the NEXT stall of a recurring pathology lands in a real trace.
+one per iteration. A fired trigger hands the record to the daemon
+thread, which logs ONE line that says who held the iteration (`stall_line`:
+the record's phases, `exposed_s`, `gc_s`, the compiled variants' growth)
+and, where `anomaly_dump_dir` is set, writes the last N records (snapshot
+at fire time) as JSON under it and (optionally) opens a `jax.profiler`
+capture window so the NEXT stall of a recurring pathology lands in a real
+trace.
 """
 
 from __future__ import annotations
@@ -42,6 +45,9 @@ import threading
 import time
 from dataclasses import asdict, dataclass, field
 from typing import Any, Dict, List, Optional
+
+from dynamo_tpu.runtime.annotations import (
+    EMIT, PHASES, RECORD_PHASES, STAGE, StepClock)
 
 log = logging.getLogger("dynamo_tpu.flight_recorder")
 
@@ -75,6 +81,28 @@ class IterationRecord:
     next plan needs this one's tokens on the host, "runner" the runner
     cannot run ahead (pipeline or sequence parallel programs, a multi-host
     group), "shutdown" the engine is stopping.
+
+    The step loop's host clock (runtime/annotations.py, the door): what
+    the step thread did between the two commits `wall_s` runs between,
+    whichever iteration the work was for (under run-ahead the staging in
+    an iteration's interval is the NEXT iteration's). `host_<phase>_s` for
+    the eight phases the profiler's spans name (`engine.inbox`, `schedule`,
+    `prep`, `stage`, `dispatch`, `readback`, `emit`, `publish`): seconds
+    inside that span and no span inside it, so they add up to at most
+    `wall_s` and the rest is the loop's own glue. `host_readback_s` is
+    the time the step thread was blocked on the device: the device-bound
+    share of the loop. `exposed_s`: the part of the other seven that ran
+    while the step thread had NOTHING enqueued on the device and not
+    collected, so the device was provably idle for want of the host;
+    `exposed_stage_s`, `exposed_emit_s` its two largest owners. What is
+    not exposed is hidden under a queued program (where the host outlasts
+    the program the device idles all the same: the device trace judges
+    that). `gc_s`: seconds of garbage collection on the step thread
+    inside the interval (they lie inside whatever phase ran; usually
+    0.0). All 0.0 where the recorder is off (nothing is recorded then)
+    or the runner names no phase. (`engine.wait`, the idle sleeps, is in
+    no iteration's wall and on no record: /metrics carries it as one more
+    phase of `dynamo_engine_host_seconds_total`.)
 
     The `moe_*` fields are a routed model's expert load, reduced on
     the device from the router's picks over real rows (padding masked)
@@ -148,13 +176,24 @@ class IterationRecord:
     g3_blocks: int         # disk-tier resident blocks (0 = tier off)
     prefetch_hits: int     # cumulative prefetched-block claims
     compile_variants: int  # cumulative compiled jit variants (all families)
-    compile_calls: int     # cumulative jitted calls (calls - variants
-    #   growth = compile-cache hits)
     decode_pages_live: int = 0  # live KV pages walked (see the docstring)
     ragged_pages_live: int = 0  # live (unit, page) pairs of a ragged step
     anomaly: bool = False  # this iteration fired the EWMA trigger
     ahead: bool = False    # enqueued before the one before was read back
     drain: str = ""        # why not (the closed set above); "" where ahead
+    # the step thread's host clock over the wall's interval (docstring)
+    host_inbox_s: float = 0.0
+    host_schedule_s: float = 0.0
+    host_prep_s: float = 0.0
+    host_stage_s: float = 0.0
+    host_dispatch_s: float = 0.0
+    host_readback_s: float = 0.0
+    host_emit_s: float = 0.0
+    host_publish_s: float = 0.0
+    exposed_s: float = 0.0        # of those but readback, nothing enqueued
+    exposed_stage_s: float = 0.0
+    exposed_emit_s: float = 0.0
+    gc_s: float = 0.0             # collections on the step thread
     # speculative decoding: mean tokens emitted per speculating row this
     # iteration (accepted drafts + the verified/bonus token; 0.0 when no
     # row speculated) — the per-step multi-token factor the ITL spine
@@ -162,8 +201,6 @@ class IterationRecord:
     accepted_per_step: float = 0.0
     # agentic session-tree serving
     guided_rows: int = 0       # constraint-masked decode rows this iteration
-    tree_hit_blocks: int = 0   # cumulative blocks served warm by match_prefix
-    forks: int = 0             # cumulative fork-on-branch fan-outs
     # routed experts (see the docstring; engine `_record_iteration`)
     moe_token_slots: int = 0
     moe_experts_hit: float = 0.0
@@ -186,14 +223,21 @@ class IterationRecord:
     trace_ids: List[str] = field(default_factory=list)
 
 
+# the record's fields for the step clock's phases, in the clock's order
+_HOST_FIELDS = tuple(f"host_{p}_s" for p in RECORD_PHASES)
+
+
 @dataclass
 class _AnomalyDump:
-    """Snapshot handed to the writer thread when the trigger fires."""
+    """What the writer thread is handed when the trigger fires: the
+    record that fired, how far the compiled variants grew over the record
+    before it, and (where dumps are written) a snapshot of the ring."""
 
     fired_ts: float
     trigger: IterationRecord
     ewma_s: float
     k: float
+    variants_grew: int = 0
     records: List[IterationRecord] = field(default_factory=list)
 
 
@@ -236,6 +280,8 @@ class FlightRecorder:
         # metrics are bind-time optional (worker_common re-homes them onto
         # the status-port hierarchy); None until bound
         self._m_anomalies = None
+        self._metrics = None
+        self._m_host = None  # per PHASES index: (hidden, exposed) counters
         # anomaly-fire hooks (incident capture arming): called on the STEP
         # thread with the triggering record — handlers must be hand-off
         # cheap (put_nowait into their own queue), never blocking I/O
@@ -254,8 +300,43 @@ class FlightRecorder:
         self._m_anomalies = node.counter(
             "flight_recorder_anomalies_total",
             "iterations that exceeded the EWMA*k wall-time threshold")
+        self._metrics = metrics  # the step clock's seconds: take_clock
 
     # -- hot path (engine step thread; DYN-R004: no blocking I/O) ----------
+    def take_clock(self, rec: IterationRecord, clock: StepClock) -> None:
+        """Empty the step thread's host clock, whose interval the engine
+        has just closed at `rec`'s commit mark (`StepClock.cut`), into
+        `rec`'s host fields and, where /metrics is bound, into
+        `dynamo_engine_host_seconds_total{phase, exposed}` (there with the
+        idle sleeps since the record before, `phase="wait"`)."""
+        ns, ex = clock.ns, clock.exposed_ns
+        exposed = 0
+        for i, name in enumerate(_HOST_FIELDS):
+            setattr(rec, name, ns[i] * 1e-9)
+            exposed += ex[i]
+        rec.exposed_s = exposed * 1e-9
+        rec.exposed_stage_s = ex[STAGE] * 1e-9
+        rec.exposed_emit_s = ex[EMIT] * 1e-9
+        rec.gc_s = clock.gc_ns * 1e-9
+        if self._metrics is not None:
+            if self._m_host is None:
+                # made once, by the first record of an engine with a clock
+                # (a mocker's recorder never gets here and shows no series)
+                self._m_host = [tuple(self._metrics.counter(
+                    "engine_host_seconds_total",
+                    "step-thread seconds by phase of the iteration (wait: "
+                    "the idle sleeps between iterations), and by whether "
+                    "nothing was enqueued on the device meanwhile",
+                    phase=ph, exposed=label) for label in ("false", "true"))
+                    for ph in PHASES]
+            for i, (hidden, shown) in enumerate(self._m_host):
+                e = ex[i]
+                if ns[i] != e:
+                    hidden.inc((ns[i] - e) * 1e-9)
+                if e:
+                    shown.inc(e * 1e-9)
+        clock.clear(wait=True)
+
     def append(self, rec: IterationRecord) -> None:
         if not self.enabled:
             return
@@ -280,17 +361,21 @@ class FlightRecorder:
                 self.anomalies_fired += 1
                 if self._m_anomalies is not None:
                     self._m_anomalies.inc()
-                if self.anomaly_dump_dir:
-                    dump = _AnomalyDump(
-                        fired_ts=rec.ts, trigger=rec, ewma_s=ewma,
-                        k=self.anomaly_k,
-                        records=self.snapshot(self.anomaly_dump_last_n),
-                    )
-                    try:
-                        self._dump_q.put_nowait(dump)
-                    except queue.Full:
-                        self.dumps_dropped += 1
-                    self._ensure_dump_thread()
+                # the stall line and the dump are the writer thread's
+                prev = self._ring[(self._n - 1) % self.capacity]
+                dump = _AnomalyDump(
+                    fired_ts=rec.ts, trigger=rec, ewma_s=ewma,
+                    k=self.anomaly_k,
+                    variants_grew=0 if prev is None else (
+                        rec.compile_variants - prev.compile_variants),
+                    records=self.snapshot(self.anomaly_dump_last_n)
+                    if self.anomaly_dump_dir else [],
+                )
+                try:
+                    self._dump_q.put_nowait(dump)
+                except queue.Full:
+                    self.dumps_dropped += 1
+                self._ensure_dump_thread()
                 for hook in self._anomaly_hooks:
                     try:
                         hook(rec)
@@ -361,6 +446,9 @@ class FlightRecorder:
                 dump = self._dump_q.get(timeout=30.0)
             except queue.Empty:
                 return  # idle: let the thread die; refired on next anomaly
+            log.warning("%s", stall_line(dump.trigger, dump.variants_grew))
+            if not self.anomaly_dump_dir:
+                continue
             try:
                 self._write_dump(dump)
                 self.dumps_written += 1
@@ -407,6 +495,18 @@ class FlightRecorder:
             log.debug("anomaly profiler window unavailable", exc_info=True)
 
 
+def stall_line(rec: IterationRecord, variants_grew: int) -> str:
+    """One line that says who held a stalled iteration: a long `readback`
+    is the device or the machine under it, a long host phase is the host,
+    `gc_s` a collection inside one, and variants that grew a compile."""
+    phases = " ".join(f"{p}={getattr(rec, name):.4f}"
+                      for p, name in zip(RECORD_PHASES, _HOST_FIELDS))
+    return (f"stalled iteration seq={rec.seq} kind={rec.kind} "
+            f"drain={rec.drain or 'ahead'} wall_s={rec.wall_s:.4f} {phases} "
+            f"exposed_s={rec.exposed_s:.4f} gc_s={rec.gc_s:.4f} "
+            f"variants_grew={variants_grew}")
+
+
 # -- Perfetto / Chrome-trace export -----------------------------------------
 
 # track (tid) layout inside the engine process
@@ -447,10 +547,28 @@ def to_chrome_trace(records: List[IterationRecord],
                 "ragged": rec.ragged,
                 "fused": rec.fused,
                 "compile_variants": rec.compile_variants,
-                "compile_calls": rec.compile_calls,
+                "ahead": rec.ahead,
+                "drain": rec.drain,
+                "exposed_s": rec.exposed_s,
+                "gc_s": rec.gc_s,
                 "trace_ids": list(getattr(rec, "trace_ids", []) or []),
             },
         })
+        # the step thread's phases as child slices: true lengths, laid end
+        # to end in the loop's order from the slice's start (the record
+        # keeps seconds by phase, not when each began)
+        at = ts_us
+        for phase, name in zip(RECORD_PHASES, _HOST_FIELDS):
+            dur = getattr(rec, name) * 1e6
+            if dur <= 0.0:
+                continue
+            child = {"ph": "X", "ts": at, "dur": dur, "pid": pid,
+                     "tid": _TID_DISPATCH, "name": "engine." + phase}
+            if phase in ("stage", "emit"):
+                child["args"] = {
+                    "exposed_s": getattr(rec, f"exposed_{phase}_s")}
+            events.append(child)
+            at += dur
         events.append({
             "ph": "C", "ts": ts_us, "pid": pid, "tid": _TID_SCHED,
             "name": "queue",

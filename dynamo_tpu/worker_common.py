@@ -567,7 +567,9 @@ async def serve_worker(
 
         engine.on_phases(_observe_phases)
 
-    # flight recorder: fired-anomaly counter onto the shared registry, and
+    # flight recorder: fired-anomaly counter and the step clock's
+    # engine_host_seconds_total{phase, exposed} (docs/observability.md
+    # "Run-ahead") onto the shared registry, and
     # advertise the recorder via metadata so tooling knows /debug/timeline
     # is live on this worker's status port
     _rec = getattr(engine, "recorder", None)

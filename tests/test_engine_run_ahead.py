@@ -6,6 +6,7 @@ a runner whose fact says it cannot run ahead; on a recording runner the
 order of dispatches and read-backs is the one the drain reasons allow."""
 
 import asyncio
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -531,3 +532,218 @@ def test_scheduler_plans_around_steps_in_flight():
         sch.step_plan()
     assert b.state.value == "running"  # nobody was preempted
     pool.release(hog)
+
+
+# -- the step loop's host clock (runtime/annotations.py, the door) -----------
+# Every record carries what the step thread did between the two commits its
+# wall runs between, by the profiler's span names, and how much of it ran
+# with nothing enqueued on the device (exposed). On a runner whose blocking
+# read sleeps, so that the device-bound share is known.
+
+SLEEP = 0.02
+HOST = ("inbox", "schedule", "prep", "stage", "dispatch", "readback", "emit",
+        "publish")
+
+
+def _sleepy(runner):
+    """`runner` with a device that takes SLEEP to answer a blocking read
+    (inside the runner's engine.readback span, where the real wait is)."""
+    real = runner._readback
+
+    def slow(x):
+        time.sleep(SLEEP)
+        return real(x)
+
+    runner._readback = slow
+    return runner
+
+
+def _clocked(kind):
+    """A long decode alone, then a joiner against it: served by a prefill of
+    its own (`kind` "prefill") or inside a fused mixed step ("mixed")."""
+    reqs = [{"rid": "a", "prompt": _prompt(6, 71), "n": 40},
+            {"rid": "b", "prompt": _prompt(7, 72), "n": 12, "after": ("a", 12)}]
+    kw = {"mixed_prefill_tokens": 8} if kind == "mixed" else {}
+    eng = _engine(_sleepy(_runner()), True, **kw)
+    _, items, recs = _drive(eng, reqs)
+    assert any(r.kind == kind for r in recs), [r.kind for r in recs]
+    return eng, items, recs
+
+
+@pytest.fixture(scope="module", params=["prefill", "mixed"])
+def clocked(request):
+    import os
+
+    was = os.environ.get("DYN_FUSED_MIXED")
+    os.environ["DYN_FUSED_MIXED"] = "1"
+    try:
+        return (request.param,) + _clocked(request.param)
+    finally:
+        if was is None:
+            os.environ.pop("DYN_FUSED_MIXED", None)
+        else:
+            os.environ["DYN_FUSED_MIXED"] = was
+
+
+def _host_sum(r):
+    return sum(getattr(r, f"host_{p}_s") for p in HOST)
+
+
+def test_phases_are_parts_of_the_wall(clocked):
+    _, _, _, recs = clocked
+    assert len(recs) > 10
+    for r in recs:
+        parts = [getattr(r, f"host_{p}_s") for p in HOST]
+        assert all(p >= 0.0 for p in parts), r
+        assert _host_sum(r) <= r.wall_s + 1e-9, (r, _host_sum(r))
+        assert 0.0 <= r.exposed_s <= _host_sum(r) - r.host_readback_s + 1e-9, r
+        assert r.exposed_stage_s <= r.host_stage_s + 1e-12
+        assert r.exposed_emit_s <= r.host_emit_s + 1e-12
+        assert r.exposed_stage_s + r.exposed_emit_s <= r.exposed_s + 1e-12
+        assert r.gc_s >= 0.0
+    clock = clocked[1].step_clock
+    assert clock.handles == 0 and not clock.serial  # all collected
+
+
+def test_readback_holds_the_devices_time_and_is_never_exposed(clocked):
+    _, _, _, recs = clocked
+    dec = [r for r in recs if r.kind == "decode"]
+    assert all(r.host_readback_s >= SLEEP * 0.95 for r in dec), [
+        r.host_readback_s for r in dec]
+    # a loop that waits for its device is all readback, and none of that
+    # wait is the host's: exposed seconds stay small beside it
+    # (but where its interval held a compile: the first call of a bucket)
+    ahead = [r for r in dec if r.ahead and r.host_dispatch_s < SLEEP]
+    assert len(ahead) > 4
+    assert all(r.host_readback_s > 0.5 * r.wall_s for r in ahead), ahead
+    for r in recs:
+        assert r.exposed_s <= r.wall_s - r.host_readback_s + 1e-9, r
+
+
+def test_exposed_leaves_out_what_ran_under_a_queued_dispatch(clocked):
+    """An iteration enqueued ahead was staged and dispatched under the one
+    before it, and so was the one after it under it: nothing of its interval
+    is exposed but the commit of the last one before a drain. A cold or a
+    mixed iteration had nothing queued: its own staging is exposed."""
+    kind, _, _, recs = clocked
+    by_seq = {r.seq: i for i, r in enumerate(recs)}
+    for r in recs:
+        nxt = recs[by_seq[r.seq] + 1] if by_seq[r.seq] + 1 < len(recs) else None
+        if r.ahead and nxt is not None and nxt.ahead:
+            assert r.exposed_s == 0.0 and r.exposed_stage_s == 0.0, r
+            assert r.host_stage_s > 0.0 and r.host_dispatch_s > 0.0, r
+    cold = [r for r in recs if r.drain == "cold"]
+    assert cold
+    for r in cold:
+        assert r.exposed_stage_s > 0.0, r
+        # prep, stage and dispatch of its own enqueue, at the least
+        assert r.exposed_s > r.exposed_stage_s + r.exposed_emit_s, r
+    joined = [r for r in recs if r.kind == kind]
+    assert joined and all(r.drain == kind for r in joined)
+    for r in joined:
+        assert r.exposed_stage_s > 0.0 and r.exposed_s > r.exposed_stage_s, r
+
+
+def test_a_joiner_waits_out_the_drain_inside_its_prefill(clocked):
+    """`drain_wait_s`: the joiner's chunk was planned while a dispatch was
+    in flight, and waited for its commit (one sleepy readback at the least)
+    before its own could be enqueued; the request that found the loop idle
+    waited for none. The TTFT identity stays exact."""
+    _, _, items, _ = clocked
+    ph = {rid: _finals(v)[0]["phases"] for rid, v in items.items()}
+    assert ph["a"]["drain_wait_s"] == 0.0
+    assert ph["b"]["drain_wait_s"] >= SLEEP * 0.95
+    for p in ph.values():
+        assert p["drain_wait_s"] <= p["prefill_s"]
+        assert p["ttft_s"] == pytest.approx(
+            p["queue_wait_s"] + p.get("kv_onboard_s", 0.0) + p["prefill_s"],
+            abs=1e-12)
+
+
+def test_idle_sleeps_are_in_no_iteration():
+    eng = _engine(_runner(), True)
+    toks, _, _ = _drive(eng, [{"rid": "a", "prompt": _prompt(6, 81), "n": 6}])
+    assert len(toks["a"]) == 6
+    clock = eng.step_clock
+    # _drive idles 50 ms after the last token: the sleeps since the last
+    # record wait in the clock for the next one to take them to /metrics,
+    # and the phases of iterations that found nothing to do were dropped
+    from dynamo_tpu.runtime.annotations import WAIT
+
+    assert 0.03 < clock.ns[WAIT] * 1e-9 < 0.5
+    assert clock.exposed_ns[WAIT] == clock.ns[WAIT]  # nothing was enqueued
+    assert clock.ns[:WAIT] == [0] * WAIT and clock.gc_ns == 0
+
+
+def test_a_dispatch_dropped_uncollected_is_not_in_flight_for_the_clock():
+    """Where the commit of the dispatch in flight raises after the next one
+    was enqueued, that one is never collected: the clock must not go on
+    reading every later second as hidden under it."""
+    from dynamo_tpu.engine import engine as E
+
+    eng = _engine(_runner(), True)
+    clock = eng.step_clock
+    nxt = E._InFlight([], [], 1, -1, 0.0, {})
+    nxt.handle = object()
+
+    def dispatch(plan, prev):
+        clock.handles += 1
+        return nxt
+
+    def commit():
+        raise RuntimeError("publish failed")
+
+    eng._dispatch_decode, eng._commit_inflight = dispatch, commit
+    plan = E.DecodePlan([], 1)
+    with pytest.raises(RuntimeError):
+        eng._step_decode(plan, "cold")
+    assert clock.handles == 0 and eng._inflight is None
+
+
+def test_recorder_off_turns_the_account_off():
+    eng = _engine(_runner(), True, recorder_size=0)
+    assert eng.step_clock is None
+    toks, _, recs = _drive(eng, [{"rid": "a", "prompt": _prompt(6, 82), "n": 9}])
+    assert len(toks["a"]) == 9 and recs == []
+
+
+def test_a_stalled_iteration_logs_who_held_it(caplog):
+    """The EWMA trigger fires once an excursion; the recorder's writer
+    thread (never the step thread) logs one line with the record's phases,
+    so an untraced run's log tells a blocked readback from a host phase, a
+    collection or a compile."""
+    import logging
+
+    runner = _runner()
+    eng = _engine(runner, True, anomaly_k=3.0, decode_steps=1)
+    real, calls = runner._readback, [0]
+
+    def stall_once(x):
+        # late enough that the first call's compile has left the EWMA
+        calls[0] += 1
+        if calls[0] == 50:
+            time.sleep(0.5)
+        return real(x)
+
+    runner._readback = stall_once
+    def lines():
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("stalled iteration")]
+
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.flight_recorder"):
+        _, _, recs = _drive(eng, [{"rid": "a", "prompt": _prompt(6, 83), "n": 56}])
+        fired = [r for r in recs if r.anomaly]
+        deadline = time.monotonic() + 5.0
+        while len(lines()) < len(fired) and time.monotonic() < deadline:
+            time.sleep(0.01)
+    assert all(r.threadName == "flight-recorder-dump" for r in caplog.records
+               if r.getMessage().startswith("stalled iteration"))
+    lines = lines()
+    assert len(fired) == len(lines) >= 1, (len(fired), lines)
+    held = max(fired, key=lambda r: r.wall_s)
+    assert held.host_readback_s >= 0.45
+    line = next(m for m in lines if f"seq={held.seq} " in m)
+    for key in ("kind=decode", "drain=", "wall_s=", "readback=", "exposed_s=",
+                "gc_s=", "variants_grew=0") + tuple(p + "=" for p in HOST):
+        assert key in line, (key, line)
+    assert f"readback={held.host_readback_s:.4f}" in line

@@ -52,7 +52,9 @@ async def _serve(engine, prompts, max_tokens=6):
 
 
 class _Recorder:
-    """Stands in for annotate(): begin/end events of the step thread."""
+    """Stands in for TraceAnnotation, which annotate() and the door
+    (annotations.phase) both open with the gate on: begin/end events of
+    the step thread."""
 
     def __init__(self):
         self.events = []
@@ -100,15 +102,15 @@ def recorded():
     prompts arriving against it (fused mixed steps), then idle."""
     import os
 
-    from dynamo_tpu.engine import engine as engine_mod
-    from dynamo_tpu.engine import model_runner as runner_mod
     from dynamo_tpu.engine.engine import InferenceEngine
 
     rec = _Recorder()
-    saved = (engine_mod.annotate, runner_mod.annotate,
-             os.environ.get("DYN_FUSED_MIXED"))
-    engine_mod.annotate = runner_mod.annotate = rec
+    saved = (annotations._trace_annotation, os.environ.get("DYN_FUSED_MIXED"),
+             os.environ.get("DYN_ENABLE_JAX_TRACE"))
+    annotations._trace_annotation = rec
     os.environ["DYN_FUSED_MIXED"] = "1"
+    os.environ["DYN_ENABLE_JAX_TRACE"] = "1"
+    annotations._enabled.cache_clear()
     try:
         engine = InferenceEngine(_runner(), max_batch=4, chunk_size=8,
                                  mixed_prefill_tokens=8)
@@ -127,11 +129,14 @@ def recorded():
         finally:
             engine.stop()
     finally:
-        engine_mod.annotate, runner_mod.annotate = saved[:2]
-        if saved[2] is None:
-            os.environ.pop("DYN_FUSED_MIXED", None)
-        else:
-            os.environ["DYN_FUSED_MIXED"] = saved[2]
+        annotations._trace_annotation = saved[0]
+        for key, was in zip(("DYN_FUSED_MIXED", "DYN_ENABLE_JAX_TRACE"),
+                            saved[1:]):
+            if was is None:
+                os.environ.pop(key, None)
+            else:
+                os.environ[key] = was
+        annotations._enabled.cache_clear()
     return rec, finals
 
 
@@ -207,9 +212,10 @@ def test_idle_iteration_waits(recorded):
         "engine.inbox", "engine.schedule", "engine.wait"]
 
 
-def test_gate_off_every_call_site_gets_the_shared_nullcontext(monkeypatch):
-    """With DYN_ENABLE_JAX_TRACE unset a span costs one cached check: every
-    call site of a served request gets the same object back."""
+def test_gate_off_every_call_site_gets_a_shared_object(monkeypatch):
+    """With DYN_ENABLE_JAX_TRACE unset a span allocates nothing: a step
+    parent gets the one shared nullcontext from annotate(), a phase the
+    one context manager its clock made for it (the door)."""
     from dynamo_tpu.engine import engine as engine_mod
     from dynamo_tpu.engine import model_runner as runner_mod
     from dynamo_tpu.engine.engine import InferenceEngine
@@ -223,8 +229,14 @@ def test_gate_off_every_call_site_gets_the_shared_nullcontext(monkeypatch):
         got.append((name, cm))
         return cm
 
+    def door(idx, **kw):
+        cm = annotations.phase(idx, **kw)
+        got.append((annotations.SPAN_NAMES[idx], cm))
+        return cm
+
     monkeypatch.setattr(engine_mod, "annotate", spy)
-    monkeypatch.setattr(runner_mod, "annotate", spy)
+    monkeypatch.setattr(engine_mod, "phase", door)
+    monkeypatch.setattr(runner_mod, "phase", door)
     engine = InferenceEngine(_runner(), max_batch=4, chunk_size=8)
     engine.start()
     try:
@@ -236,7 +248,12 @@ def test_gate_off_every_call_site_gets_the_shared_nullcontext(monkeypatch):
             "engine.prep", "engine.stage", "engine.dispatch", "engine.readback",
             "engine.emit", "engine.publish"} <= names, names
     assert names <= TABLE_A, names - TABLE_A
-    assert all(cm is annotations._NULL for _, cm in got)
+    made = {p.name: p for p in engine.step_clock._phases}
+    for name, cm in got:
+        if name in PARENTS:
+            assert cm is annotations._NULL, name
+        else:
+            assert cm is made[name], name
 
 
 class _LowerSpy:

@@ -33,7 +33,7 @@ def _rec(seq, wall_s=0.004, kind="decode", **over):
         decode_seqs=2, decode_steps=4, n_chunks=0, chunk_tokens=0,
         charged_tokens=0, ragged=False, fused=False, n_waiting=0,
         n_running=2, kv_usage=0.25, g2_blocks=0, g3_blocks=0,
-        prefetch_hits=0, compile_variants=1, compile_calls=seq + 1,
+        prefetch_hits=0, compile_variants=1,
     )
     base.update(over)
     return IterationRecord(**base)
@@ -374,6 +374,83 @@ def test_chrome_trace_schema():
     assert len(instants) == 1 and instants[0]["name"] == "anomaly"
     # slices are ordered by wall-clock like the ring
     assert [s["ts"] for s in slices] == sorted(s["ts"] for s in slices)
+
+
+def test_chrome_trace_draws_the_host_phases_inside_their_iteration():
+    """The step thread's phases are child slices of the iteration's
+    `dispatch` slice: true lengths, laid end to end from its start, inside
+    it (they add up to at most the wall), the exposed seconds in `args`."""
+    rec = _rec(3, wall_s=0.050, host_inbox_s=0.001, host_schedule_s=0.002,
+               host_stage_s=0.006, host_dispatch_s=0.004,
+               host_readback_s=0.030, host_emit_s=0.003, host_publish_s=0.001,
+               exposed_s=0.005, exposed_stage_s=0.004, exposed_emit_s=0.0,
+               ahead=False, drain="rows", gc_s=0.0005)
+    events = to_chrome_trace([_rec(2), rec], pid=1)["traceEvents"]
+    slices = [e for e in events if e["ph"] == "X"]
+    parents = [e for e in slices if e["name"] == "decode"]
+    kids = [e for e in slices if e["name"].startswith("engine.")]
+    assert len(parents) == 2  # a record without the clock draws no children
+    assert [k["name"] for k in kids] == [
+        "engine.inbox", "engine.schedule", "engine.stage", "engine.dispatch",
+        "engine.readback", "engine.emit", "engine.publish"]  # prep was 0.0
+    parent = parents[1]
+    assert parent["args"]["exposed_s"] == 0.005 and parent["args"]["drain"] == "rows"
+    assert parent["args"]["gc_s"] == 0.0005 and parent["args"]["ahead"] is False
+    assert "compile_calls" not in parent["args"]
+    at = parent["ts"]
+    for k in kids:
+        assert k["tid"] == parent["tid"] and k["ts"] == pytest.approx(at)
+        at += k["dur"]
+    assert at <= parent["ts"] + parent["dur"] + 1e-6
+    by = {k["name"]: k for k in kids}
+    assert by["engine.readback"]["dur"] == pytest.approx(30000.0)
+    assert by["engine.stage"]["args"] == {"exposed_s": 0.004}
+    assert by["engine.emit"]["args"] == {"exposed_s": 0.0}
+    assert "args" not in by["engine.readback"]
+    json.dumps(events)
+
+
+async def test_host_seconds_reach_metrics_by_phase_and_exposure():
+    """/metrics: dynamo_engine_host_seconds_total{phase, exposed}, added to
+    by the recorder as it empties the step clock into each record (with the
+    idle sleeps since the record before as phase "wait"); and the spine's
+    drain_wait_s as one more phase of request_phase_seconds."""
+    from dynamo_tpu.frontend.protocols import ModelCard
+    from dynamo_tpu.runtime.discovery import MemDiscovery
+    from dynamo_tpu.runtime.distributed import DistributedRuntime
+    from dynamo_tpu.worker_common import serve_worker
+
+    rt = DistributedRuntime(discovery=MemDiscovery(realm="hostsec"),
+                            event_transport="inproc")
+    engine = _mk_engine(decode_base_s=0.002)
+    w = await serve_worker(rt, engine, ModelCard(name="m"))
+    try:
+        toks, _ = await _gen(engine, [1, 2, 3, 4, 5], 24)
+        assert len(toks) == 24
+    finally:
+        await w.stop()
+    text = rt.metrics.render().decode()
+    series = {}
+    for line in text.splitlines():
+        if line.startswith("dynamo_engine_host_seconds_total{"):
+            labels, value = line.rsplit(" ", 1)
+            ph = labels.split('phase="')[1].split('"')[0]
+            ex = labels.split('exposed="')[1].split('"')[0]
+            series[ph, ex] = float(value)
+    from dynamo_tpu.runtime.annotations import PHASES, RECORD_PHASES
+
+    assert set(series) == {(p, e) for p in PHASES for e in ("true", "false")}
+    recs = engine.recorder.snapshot()
+    for p in RECORD_PHASES:
+        assert series[p, "true"] + series[p, "false"] == pytest.approx(
+            sum(getattr(r, f"host_{p}_s") for r in recs), abs=1e-6)
+    assert sum(series[p, "true"] for p in RECORD_PHASES) == pytest.approx(
+        sum(r.exposed_s for r in recs), abs=1e-6)
+    assert series["emit", "false"] > 0.0  # emitted under a dispatch in flight
+    assert series["readback", "true"] == 0.0
+    # the loop slept until the request came, with nothing enqueued
+    assert series["wait", "true"] > 0.0 and series["wait", "false"] == 0.0
+    assert 'phase="drain_wait"' in text
 
 
 async def test_debug_timeline_route():

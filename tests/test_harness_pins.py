@@ -118,3 +118,25 @@ def test_warm_lattice_meets_every_program_traffic_reaches(monkeypatch, preset):
         assert after[fam]["variants"] == before[fam]["variants"], (
             fam, before[fam], after[fam], kinds)
     assert after["other"] == before["other"]
+
+
+def test_the_doors_phases_are_the_spans_the_reduction_owns_gaps_by():
+    """The step thread's phases (runtime/annotations.py, the door) open the
+    spans `benchmark/layers/_idle.CHILDREN` names, and the iteration record
+    carries the same names: a phase renamed, added or dropped on one side
+    would make the idle shares and the host clock read different things."""
+    import dataclasses
+
+    from dynamo_tpu.runtime import annotations
+    from dynamo_tpu.runtime.flight_recorder import IterationRecord
+
+    spec = importlib.util.spec_from_file_location(
+        "_bench_idle_pins", os.path.join(ROOT, "benchmark", "layers", "_idle.py"))
+    idle = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(idle)
+    assert sorted(annotations.SPAN_NAMES) == sorted(idle.CHILDREN)
+    assert annotations.SPAN_NAMES[annotations.WAIT] == "engine.wait"
+    fields = {f.name for f in dataclasses.fields(IterationRecord)}
+    assert {f"host_{p}_s" for p in annotations.RECORD_PHASES} == {
+        f for f in fields if f.startswith("host_")}
+    assert {"exposed_s", "exposed_stage_s", "exposed_emit_s", "gc_s"} <= fields

@@ -448,7 +448,10 @@ async def test_metrics_show_the_three_series(served):
         out = await _serve(engine, sv["reqs"][:2], False)
         assert all(_tokens(o) for o in out)
         for _ in range(100):  # the idle flush and the last FPM hook
-            if engine._rec_late is None and not engine.scheduler.has_work():
+            # (and the commit of a dispatch enqueued ahead for rows that
+            # have ended since: its forwards count too)
+            if (engine._rec_late is None and engine._inflight is None
+                    and not engine.scheduler.has_work()):
                 break
             await asyncio.sleep(0.01)
         engine._publish_fpm("decode", 0.0, 0)

@@ -134,7 +134,12 @@ async def test_second_turn_hits_warm_tree_and_stays_byte_identical():
     assert saved == warm_reused, (saved, warm_reused)
 
 
-async def test_tree_hit_blocks_in_flight_recorder():
+async def test_tree_hit_blocks_reach_the_worker_digest():
+    """Blocks served warm by match_prefix are a total of the pool; their
+    reader is the worker digest's `tree` block (the iteration record
+    carried a copy no one read, until PR 38)."""
+    from dynamo_tpu.runtime.fleet_observer import DigestBuilder
+
     turn1 = [2, 7, 1, 8] * 6
     _, e = _engine(True)
     e.start()
@@ -143,9 +148,10 @@ async def test_tree_hit_blocks_in_flight_recorder():
         await _collect(e, turn1 + out1 + [9, 9])
     finally:
         e.stop()
-    recs = e.recorder.snapshot()
-    assert recs and recs[-1].tree_hit_blocks > 0
-    assert e.pool.match_hit_blocks == recs[-1].tree_hit_blocks
+    assert e.pool.match_hit_blocks > 0
+    tree = DigestBuilder(instance_id=1).build(e)["tree"]
+    assert tree["hit_blocks"] == e.pool.match_hit_blocks
+    assert 0.0 < tree["hit_rate"] <= 1.0
 
 
 # -- fork-on-branch (n>1 sampling) ------------------------------------------
