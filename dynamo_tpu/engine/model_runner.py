@@ -1224,11 +1224,12 @@ class ModelRunner(Runner):
         log.info(
             "runner ready: %s params+pool placed in %.1fs (mesh %s, %d pages "
             "x %d tokens) on %s %r devices %s; attn_impl=%s (%s), "
-            "ragged_mixed=%s, kv_copy_kernel=%s (interpret=%s)",
+            "decode_page_routine=%s, ragged_mixed=%s, kv_copy_kernel=%s "
+            "(interpret=%s)",
             config.name, placed_s, self.mesh_config.shape, num_pages,
             page_size, rep["platform"], rep["device_kind"], rep["device_ids"],
-            self.attn_impl, self.attn_impl_reason, self.ragged_mixed,
-            self._kv_copy_kernel, self._kv_copy_interpret,
+            self.attn_impl, self.attn_impl_reason, rep["decode_page_routine"],
+            self.ragged_mixed, self._kv_copy_kernel, self._kv_copy_interpret,
         )
 
     def device_report(self) -> Dict[str, Any]:
@@ -1239,9 +1240,21 @@ class ModelRunner(Runner):
         gives the devices that hold K-pool shards and one shard's shape (a
         TP=4 mesh must show four devices and Hk/4 heads). Both are read
         off the sharding, not the buffers: the step thread donates those
-        while the status server calls this."""
+        while the status server calls this. `decode_page_routine` is what
+        the Pallas decode kernel does with a page at this worker's shapes
+        (ops/paged_attention.py `page_routine`: one shard's KV heads, the
+        query heads on each, the pool's dtype); None where that kernel is
+        not on the path (the jnp gather, latent attention)."""
+        from dynamo_tpu.ops.paged_attention import page_routine
+
         devs = list(self.mesh.devices.flat)
         k_leaf = jax.tree.leaves(self.k_pool)[0]
+        shard_shape = list(k_leaf.sharding.shard_shape(k_leaf.shape))
+        routine = None
+        if self.attn_impl == "pallas" and not self.config.is_mla:
+            routine = page_routine(
+                shard_shape[3], self.config.n_heads // self.config.n_kv_heads,
+                k_leaf.dtype, isinstance(self.k_pool, dict))
         memory = {}
         for d in devs:
             if d.process_index != jax.process_index():
@@ -1260,12 +1273,13 @@ class ModelRunner(Runner):
             "mesh": list(self.mesh_config.shape),
             "attn_impl": self.attn_impl,
             "attn_impl_reason": self.attn_impl_reason,
+            "decode_page_routine": routine,
             "ragged_mixed": self.ragged_mixed,
             "kv_copy_kernel": self._kv_copy_kernel,
             "kv_copy_interpret": self._kv_copy_interpret,
             "kv_shards": {
                 "devices": sorted(d.id for d in k_leaf.sharding.device_set),
-                "shard_shape": list(k_leaf.sharding.shard_shape(k_leaf.shape)),
+                "shard_shape": shard_shape,
             },
             "kv_pool_bytes": self.kv_pool_bytes(),
             "state_slots": self.state_slots,

@@ -327,9 +327,10 @@ def forward(
             seg_pt, seg_kvl, rmeta = ragged
             walk = ragged_work_list(rmeta, seg_kvl, None, PS, seg_pt.shape[1], S)
         else:
-            from dynamo_tpu.ops.paged_attention import decode_work_list
+            from dynamo_tpu.ops.paged_attention import decode_walk
 
-            walk = decode_work_list(kv_lens, None, PS, page_table.shape[1])
+            walk = decode_walk((c.n_kv_heads, G), k_pool, page_table,
+                               kv_lens, None)
 
     def mlp(h, lp):
         with jax.named_scope("ffn"):

@@ -347,11 +347,15 @@ def forward(
                 return ragged_work_list(
                     rmeta, seg_kvl, window, PS, seg_pt.shape[1], S)
         else:
-            from dynamo_tpu.ops.paged_attention import decode_work_list
+            from dynamo_tpu.ops.paged_attention import decode_walk
+
+            # the heads ONE call of the kernel sees: a model-axis shard's
+            shards = mesh.shape.get("model", 1) if mesh is not None else 1
 
             def build_walk(window):
-                return decode_work_list(
-                    kv_lens, window, PS, page_table.shape[1])
+                return decode_walk(
+                    (c.n_kv_heads // shards, c.n_heads // c.n_kv_heads),
+                    k_pool, page_table, kv_lens, window)
 
         if c.sliding_window > 0:
             walk_sliding = build_walk(jnp.int32(c.sliding_window))

@@ -32,9 +32,25 @@ All kv heads are processed per step. A token-major page [PS, Hk, D] is one
 CONTIGUOUS slab in the pool, so each grid step issues a single large DMA
 (the head-major layout needed Hk strided chunks per page). What a step
 does with its page is chosen from the static shapes the kernel sees, one
-walk either way: `_page_by_heads`, a batched MXU product a kv head (GQA:
-G query heads share the page), or at G = 1 `_page_by_rows`, every query
-row against the page read as one [PS * Hk, D] matrix.
+walk either way (`page_routine`): `_page_by_heads`, a batched MXU product
+a kv head (GQA: G query heads share the page); at G = 1 `_page_by_rows`,
+every query row against the page read as one [PS * Hk, D] matrix; at
+Hk = 1 (MQA, or a tensor-parallel shard left with one head)
+`_pages_one_head`, the row's [G, D] queries against several pages a step,
+each the contiguous [PS, D] tile it is in the pool.
+
+One KV head. A pool [L, NP, PS, 1, D] holds the bytes of a row-major
+[L, NP, PS, D] array, and that is how the step programs carry it. Mosaic
+takes an operand row-major with the minor pair tiled, so the 5-d operand
+is padded token by token ((1, D) tiles) and XLA converts the whole stack
+in front of every call; the 4-d view is the layout itself and costs
+nothing. A page is then 2 * PS * D bytes of K or V, too little for a grid
+step of its own (0.35 us against 0.04 us of DMA at PS 64), so a step of
+this routine brings `one_head_tiles` pages of the row, each a block of
+its own on the same operand, and the walk is the same walk at that
+granularity: `decode_walk` lists (row, step) pairs and, beside them, the
+page table with its dead entries filled in, so an index map is two SMEM
+reads and no clamp.
 
 The reference framework ships CUDA kernels for its block engine
 (lib/llm/src/kernels/block_copy.cu, lib/kvbm-kernels/cuda/
@@ -45,6 +61,7 @@ equivalent of that hot path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +131,67 @@ def decode_work_list(kv_lens, window, page_size: int, max_pages: int):
                              page_size, max_pages)
     return work_list(first, jnp.where(kv_lens > 0, last - first + 1, 0),
                      max_pages)
+
+
+def page_routine(Hk: int, G: int, dtype, quantized: bool) -> str:
+    """What a decode step does with its pages, from the static shapes one
+    call of the kernel sees (a tensor-parallel shard: its local heads) and
+    the pool's dtype alone: "by_rows" at G = 1 when Hk fills whole sublane
+    tiles of the pool's dtype, so the page reads as a matrix for free;
+    "one_head" at Hk = 1; "by_heads" for the rest, and for int8 pools,
+    whose scales ride per (token, head)."""
+    if quantized:
+        return "by_heads"
+    if G == 1 and Hk % (32 // jnp.dtype(dtype).itemsize) == 0:
+        return "by_rows"
+    return "one_head" if Hk == 1 else "by_heads"
+
+
+# pages a grid step of the one-head routine brings. At page size 64 on a
+# v5e (scripts/bench_attn.py, the cell's decode step of 627 live pages; my
+# chip run, PR 39): 1 -> 328 us a call, 2 -> 211, 4 -> 127, 8 -> 104,
+# 16 -> 99 with rows of one page twice as dear
+ONE_HEAD_TILES = 8
+
+
+def one_head_tiles(max_pages: int) -> int:
+    """Pages a step brings under a page table `max_pages` wide: the largest
+    power of two up to ONE_HEAD_TILES that divides it, so a row's steps
+    tile the table."""
+    return math.gcd(max_pages, ONE_HEAD_TILES)
+
+
+def decode_walk(heads, k_pool, page_table, kv_lens, window):
+    """The list a decode call walks, for a caller that runs many layers on
+    one set of lengths and builds it once: `decode_work_list`'s pair, or
+    where `heads` = (Hk, G) of ONE call (a shard's local heads) and the
+    pool pick the one-head routine, (work, n_work, pages): the same walk
+    over steps of `one_head_tiles` pages, entry w = `row * steps_a_row +
+    step`, so that `entry * tiles + t` is the place of the step's t-th
+    tile in `pages`, the page table flattened with every dead entry of a
+    row replaced by its nearest live one. A tile past either end of the
+    row's live pages thus repeats a live page (its slots are masked by
+    position), and the kernel reads no dead page-table entry and no dead
+    page. Built from compares and masked sums alone: a gather of B * MP
+    scalars cost more than the kernel's call (175 us against 104, my chip
+    run, PR 39)."""
+    kq = k_pool["q"] if isinstance(k_pool, dict) else k_pool
+    PS, MP = kq.shape[-3], page_table.shape[1]
+    if page_routine(*heads, kq.dtype, isinstance(k_pool, dict)) != "one_head":
+        return decode_work_list(kv_lens, window, PS, MP)
+    tiles = one_head_tiles(MP)
+    work, n_work = decode_work_list(kv_lens, window, PS * tiles, MP // tiles)
+    first, last = (a[:, None] for a in live_pages(
+        kv_lens - 1, kv_lens - 1, kv_lens, window, PS, MP))
+    col = lax.iota(jnp.int32, MP)[None, :]
+
+    def entry_at(i):  # page_table[b, i[b]] as [B, 1]
+        return jnp.sum(jnp.where(col == i, page_table, 0), axis=1,
+                       keepdims=True)
+
+    pages = jnp.where(col < first, entry_at(first),
+                      jnp.where(col > last, entry_at(last), page_table))
+    return work, n_work, pages.reshape(-1)
 
 
 def work_list(first, count, max_pages: int):
@@ -186,22 +264,26 @@ def _decode_kernel_body(
     win_ref,  # [1] int32 sliding window (0 = global) or None (no-window
     #   compile: Gemma-2 alternates sliding/global per layer with a
     #   TRACED scalar, so the window rides as a prefetch operand)
-    q_ref,  # [Hk, G, D] all query heads of the row ([Hk, D] by rows)
-    k_ref,  # [PS, Hk, D] one token-major page of keys (one contiguous DMA)
-    v_ref,  # [PS, Hk, D]
+    q_ref,  # [Hk, G, D] all query heads of the row ([Hk, D] by rows,
+    #   [G, D] at one head)
+    k_ref,  # [PS, Hk, D] one token-major page of keys (one contiguous DMA);
+    #   at one head the step's pages, a tuple of [PS, D] tiles
+    v_ref,  # like k_ref
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
     o_ref,  # like q_ref
     # scratch (persist across a row's pages)
-    m_ref,  # f32 running max: [Hk, G, 1] ([Hk, 1] by rows)
+    m_ref,  # f32 running max: [Hk, G, 1] ([Hk, 1] by rows, [G, 1] at one
+    #   head)
     l_ref,  # f32 running denom, like m_ref
     acc_ref,  # f32 running numerator, like q_ref
     *,
-    page_size: int,
+    page_size: int,  # tokens a grid step covers: a page (at one head: its
+    #   tiles' pages together, and max_pages the steps a row can take)
     max_pages: int,
     scale: float,
     softcap: float = 0.0,  # Gemma-2 attention-score soft capping (0 = off)
-    by_rows: bool = False,  # the per-page routine (see decode_paged_attention)
+    routine: str = "by_heads",  # the per-page routine (page_routine)
 ):
     entry = work_ref[pl.program_id(0)]
     b = _div(entry, max_pages)
@@ -222,9 +304,9 @@ def _decode_kernel_body(
     n_valid = jnp.minimum(kv_len - i * page_size, page_size)
     lo_in_page = jnp.clip(
         _window_lo(kv_len - 1, window) - i * page_size, 0, page_size)
-    page = _page_by_rows if by_rows else _page_by_heads
-    page(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
-         n_valid, lo_in_page, scale=scale, softcap=softcap)
+    _PAGE_ROUTINES[routine](
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+        n_valid, lo_in_page, scale=scale, softcap=softcap)
 
     @pl.when(i == last)
     def _finalize():
@@ -315,20 +397,63 @@ def _page_by_rows(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
     alpha = jnp.exp(m_prev - m_new)
     l_add = jnp.sum(p, axis=1, keepdims=True)
 
-    v = v_ref[...].reshape(N, D)
-    if v.dtype == jnp.float32:
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
-    else:
-        terms = []
-        for _ in range(3):
-            terms.append(p.astype(v.dtype))
-            p = p - terms[-1].astype(jnp.float32)
-        pv = jnp.dot(jnp.concatenate(terms, axis=0), v,
-                     preferred_element_type=jnp.float32)  # [3 * Hk, D]
-        pv = pv[:Hk] + pv[Hk:2 * Hk] + pv[2 * Hk:]
+    pv = _pv_exact(p, v_ref[...].reshape(N, D))
     acc_ref[...] = acc_ref[...] * alpha + pv
     l_ref[...] = l_ref[...] * alpha + l_add
     m_ref[...] = m_new
+
+
+def _pv_exact(p, v):
+    """[R, N] f32 probabilities x [N, D] values in the pool's dtype, V
+    never cast: below f32 the probabilities go as three terms of V's dtype
+    (bf16: 8 + 8 + 8 mantissa bits, exact), stacked on rows so the MXU
+    loads V once."""
+    if v.dtype == jnp.float32:
+        return jnp.dot(p, v, preferred_element_type=jnp.float32)
+    R = p.shape[0]
+    terms = []
+    for _ in range(3):
+        terms.append(p.astype(v.dtype))
+        p = p - terms[-1].astype(jnp.float32)
+    pv = jnp.dot(jnp.concatenate(terms, axis=0), v,
+                 preferred_element_type=jnp.float32)  # [3 * R, D]
+    return pv[:R] + pv[R:2 * R] + pv[2 * R:]
+
+
+def _pages_one_head(q_ref, k_refs, v_refs, ks_ref, vs_ref, m_ref, l_ref,
+                    acc_ref, n_valid, lo_in_page, *, scale, softcap):
+    """A step's pages into the running softmax at Hk = 1: two plain
+    products, the row's [G, D] queries against the tiles as they lie in
+    the pool, [N, D] stacked on rows (whole vregs: no relayout), then
+    [G, N] x [N, D]. K and V go to the MXU in the pool's dtype
+    (`_pv_exact`); scores, softmax state and accumulator are f32. A tile
+    that repeats a live page (`decode_walk`) sits past n_valid or below
+    lo_in_page like any dead slot."""
+    del ks_ref, vs_ref  # dense pools only (page_routine)
+    k = jnp.concatenate([r[...] for r in k_refs], axis=0)  # [N, D]
+    s = lax.dot_general(
+        q_ref[...], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32
+    ) * scale  # [G, N]
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    valid = (col < n_valid) & (col >= lo_in_page)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_ref[...]  # [G, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_add = jnp.sum(p, axis=1, keepdims=True)
+    pv = _pv_exact(p, jnp.concatenate([r[...] for r in v_refs], axis=0))
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    l_ref[...] = l_ref[...] * alpha + l_add
+    m_ref[...] = m_new
+
+
+_PAGE_ROUTINES = {"by_heads": _page_by_heads, "by_rows": _page_by_rows,
+                  "one_head": _pages_one_head}
 
 
 def _decode_kernel(wk, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
@@ -339,6 +464,15 @@ def _decode_kernel(wk, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
 def _decode_kernel_win(wk, pt, kl, ly, win, q, k, v, o, m, l, acc, **kw):
     _decode_kernel_body(wk, pt, kl, win, q, k, v, None, None, o, m, l, acc,
                         **kw)
+
+
+def _decode_kernel_tiles(wk, pg, kl, ly, *refs, tiles, windowed, **kw):
+    """The one-head call: `pg` the walk's filled-in page table where the
+    others take the page table; K and V `tiles` refs each."""
+    win, refs = (refs[0], refs[1:]) if windowed else (None, refs)
+    q, k, v, rest = (refs[0], refs[1:1 + tiles], refs[1 + tiles:1 + 2 * tiles],
+                     refs[1 + 2 * tiles:])
+    _decode_kernel_body(wk, pg, kl, win, q, k, v, None, None, *rest, **kw)
 
 
 def _decode_kernel_int8(wk, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc, **kw):
@@ -362,7 +496,7 @@ def decode_paged_attention_sharded(
     axis_name: str = AXIS_MODEL,
     window=None,  # traced int32 scalar (see decode_paged_attention)
     layer=None,  # traced int32 scalar, replicated
-    work=None,  # decode_work_list's pair, replicated (see below)
+    work=None,  # decode_walk's lists, replicated (see below)
     *,
     scale=None,
     softcap: float = 0.0,
@@ -380,22 +514,26 @@ def decode_paged_attention_sharded(
         pool = {"q": pool, "s": scales}
     k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
     scalars = scalar_operands(layer, window)
-    if work is None:  # the same on every shard: built once, outside
-        PS = jax.tree.leaves(k_pool)[0].shape[2]
-        work = decode_work_list(kv_lens, window, PS, page_table.shape[1])
+    if work is None:  # the same on every shard: built once, outside, for
+        # the heads one shard is left with
+        Hk, G = q.shape[1] // mesh.shape[axis_name], q.shape[2]
+        work = decode_walk((Hk, G), k_pool, page_table, kv_lens, window)
 
-    def part(q, k_pool, v_pool, page_table, kv_lens, work, n_work, layer,
-             window=None):
+    n_lists = len(work)
+
+    def part(q, k_pool, v_pool, page_table, kv_lens, *rest):
+        layer, *window = rest[n_lists:]
         return decode_paged_attention(
-            q, k_pool, v_pool, page_table, kv_lens, window, layer,
-            (work, n_work), scale=scale, softcap=softcap,
-            interpret=interpret,
+            q, k_pool, v_pool, page_table, kv_lens,
+            window[0] if window else None, layer, rest[:n_lists],
+            scale=scale, softcap=softcap, interpret=interpret,
         )
 
     fn = jax.shard_map(
         part,
         mesh=mesh,
-        in_specs=(heads, pool, pool, P(None, None), P(None), P(None), P())
+        in_specs=(heads, pool, pool, P(None, None), P(None))
+        + tuple(P(None) if w.ndim else P() for w in work)
         + (P(),) * len(scalars),
         out_specs=heads,
         check_vma=False,
@@ -417,9 +555,9 @@ def decode_paged_attention(
     #   (0 = global at runtime) — Gemma-2 alternates per layer in the scan
     layer=None,  # traced int32 scalar: the layer of the stacked pool to
     #   read; rides the scan as a prefetch operand like `window`
-    work=None,  # decode_work_list(kv_lens, window, PS, MP), for a caller
-    #   that runs many layers on one set of lengths and builds it once;
-    #   None = built here
+    work=None,  # decode_walk((Hk, G), k_pool, page_table, kv_lens, window),
+    #   for a caller that runs many layers on one set of lengths and builds
+    #   it once; None = built here
     *,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
     softcap: float = 0.0,  # Gemma-2 logit soft capping (static; 0 = off)
@@ -438,15 +576,16 @@ def decode_paged_attention(
     windowed = window is not None
     if windowed:
         window = jnp.asarray(window, jnp.int32).reshape(())
-    work, n_work = work or decode_work_list(kv_lens, window, PS, MP)
-    # the per-page routine, from what the shapes say: at G = 1 each kv
-    # head serves one query row, and when Hk fills whole sublane tiles of
-    # the pool's dtype the page reads as a matrix for free (_page_by_rows)
-    by_rows = (G == 1 and not quantized
-               and Hk % (32 // kq.dtype.itemsize) == 0)
+    # the per-page routine, from what the shapes say
+    routine = page_routine(Hk, G, kq.dtype, quantized)
+    work, n_work, *filled = work or decode_walk(
+        (Hk, G), k_pool, page_table, kv_lens, window)
+    # pages a grid step brings, and the steps a row can take
+    tiles = one_head_tiles(MP) if routine == "one_head" else 1
+    steps = MP // tiles
 
     def row_of(w, wk):
-        return _div(wk[w], MP)
+        return _div(wk[w], steps)
 
     def kv_index(w, wk, pt, kl, ly, *rest):
         return (ly[0], pt[row_of(w, wk), _rem(wk[w], MP)], 0, 0, 0)
@@ -454,9 +593,15 @@ def decode_paged_attention(
     def scale_index(w, wk, pt, kl, ly, *rest):
         return kv_index(w, wk, pt, kl, ly, *rest)[1:4]
 
-    if by_rows:
+    if routine == "by_rows":
         q = q.reshape(B, Hk, D)
         qo_block, state = (None, Hk, D), (Hk, 1)
+
+        def qo_index(w, wk, *rest):
+            return (row_of(w, wk), 0, 0)
+    elif routine == "one_head":
+        q = q.reshape(B, G, D)
+        qo_block, state = (None, G, D), (G, 1)
 
         def qo_index(w, wk, *rest):
             return (row_of(w, wk), 0, 0)
@@ -471,7 +616,7 @@ def decode_paged_attention(
     # single DMA, with a legal (PS, Hk, D) tile (minor dims (Hk, D))
     kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, max_pages=MP, scale=scale, softcap=softcap,
-              by_rows=by_rows)
+              routine=routine)
     if quantized:
         kernel = functools.partial(
             _decode_kernel_int8_win if windowed else _decode_kernel_int8, **kw
@@ -480,6 +625,23 @@ def decode_paged_attention(
         s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
         in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
         operands = (q, kq, ks, vq, vs)
+    elif routine == "one_head":
+        # the pool as the step program carries it: [L, NP, PS, D], a page
+        # one contiguous [PS, D] tile, `tiles` of them a step, each a block
+        # of its own that finds its page in the walk's filled-in table
+        kw.update(page_size=PS * tiles, max_pages=steps)
+        kernel = functools.partial(
+            _decode_kernel_tiles, tiles=tiles, windowed=windowed, **kw)
+
+        def tile_index(t, w, wk, pg, kl, ly, *rest):
+            return (ly[0], pg[wk[w] * tiles + t], 0, 0)
+
+        kv_specs = [pl.BlockSpec((None, None, PS, D),
+                                 functools.partial(tile_index, t))
+                    for t in range(tiles)]
+        in_specs = [q_spec] + kv_specs * 2
+        k4, v4 = (p.reshape(p.shape[:3] + (D,)) for p in (kq, vq))
+        operands = (q,) + (k4,) * tiles + (v4,) * tiles
     else:
         kernel = functools.partial(
             _decode_kernel_win if windowed else _decode_kernel, **kw
@@ -487,10 +649,11 @@ def decode_paged_attention(
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, kq, vq)
 
-    prefetch = (work, page_table, kv_lens) + scalar_operands(layer, window)
+    prefetch = (work, *(filled or [page_table]), kv_lens) + scalar_operands(
+        layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # work, page_table, kv_lens,
-        #   layer (+ window)
+        num_scalar_prefetch=len(prefetch),  # work, page_table (one head:
+        #   the walk's filled-in one), kv_lens, layer (+ window)
         grid=(n_work,),  # a traced bound: the live pages, not B * MP
         in_specs=in_specs,
         out_specs=pl.BlockSpec(qo_block, qo_index),

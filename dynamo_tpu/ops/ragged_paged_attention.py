@@ -275,7 +275,8 @@ def _ragged_kernel_body(
     kvl_ref,  # [SEG] int32 per-segment context length
     #   (the pool's layer [1] rides next; only the index maps read it)
     win_ref,  # [1] int32 sliding window (0 = global) or None
-    # blocks
+    # blocks (at one KV head, dense: the head axis is gone from all of
+    # them, the page the contiguous [PS, D] tile it is in the pool)
     q_ref,  # [Hk, QB*G, D] (row r is block token r // G, group r % G)
     k_ref,  # [PS, Hk, D] one token-major page
     v_ref,  # [PS, Hk, D]
@@ -315,8 +316,11 @@ def _ragged_kernel_body(
     # every pair the walk visits is live: the page runs unguarded
     q = q_ref[...].astype(jnp.float32)  # [Hk, QB*G, D]
     k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
+    one_head = k.ndim == 2  # two plain products, [QB*G, D] x [PS, D]
     s = lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())) if one_head
+        else (((2,), (2,)), ((0,), (1,))),
+        preferred_element_type=jnp.float32
     ) * scale  # [Hk, QB*G, PS]
     if ks_ref is not None:
         s = s * ks_ref[...].T[:, None, :]
@@ -324,8 +328,8 @@ def _ragged_kernel_body(
         # the TRUE score (post any int8 fold), matching the jnp path
         s = softcap * jnp.tanh(s / softcap)
 
-    row = _div(lax.broadcasted_iota(jnp.int32, s.shape, 1), n_groups)
-    col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    row = _div(lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2), n_groups)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
     q_pos = qpos0 + row - row_start  # valid only inside the row band
     kv_pos = page_first + col
     mask = (
@@ -339,16 +343,18 @@ def _ragged_kernel_body(
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
 
-    l_add = jnp.sum(p, axis=2, keepdims=True)  # raw-probability denom
+    l_add = jnp.sum(p, axis=-1, keepdims=True)  # raw-probability denom
     if vs_ref is not None:
         p = p * vs_ref[...].T[:, None, :]
     v = v_ref[...].astype(jnp.float32)
     pv = lax.dot_general(
-        p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
+        p, v, (((1,), (0,)), ((), ())) if one_head
+        else (((2,), (0,)), ((0,), (1,))),
+        preferred_element_type=jnp.float32
     )
     acc_ref[...] = acc_ref[...] * alpha + pv
     l_ref[...] = l_ref[...] * alpha + l_add
@@ -363,7 +369,8 @@ def _ragged_kernel_body(
         # was there: the wrapper's `covered` mask defines those rows
         denom = jnp.maximum(l_ref[...], 1e-30)
         res = acc_ref[...] / denom  # [Hk, QB*G, D]
-        row = _div(lax.broadcasted_iota(jnp.int32, res.shape, 1), n_groups)
+        row = _div(lax.broadcasted_iota(jnp.int32, res.shape, res.ndim - 2),
+                   n_groups)
         keep = (row >= row_start) & (row < row_start + n_rows)
         prev = o_ref[...].astype(jnp.float32)
         o_ref[...] = jnp.where(keep, res, prev).astype(o_ref.dtype)
@@ -519,22 +526,31 @@ def ragged_paged_attention(
     # pads G up to a full sublane tile in VMEM and Mosaic cannot
     # shape-cast every (QB, G) split (G == 1 fails to lower)
     qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
+    # one KV head, dense (ops/paged_attention.py "One KV head"): the pool
+    # as the step program carries it, [L, NP, PS, D], a page one contiguous
+    # [PS, D] tile, and the head axis dropped from every block
+    one_head = Hk == 1 and not quantized
+    heads = () if one_head else (Hk,)
+    if one_head:
+        qt = qt.reshape(T * G, D)
+        kq, vq = (p.reshape(p.shape[:3] + (D,)) for p in (kq, vq))
 
     # the index maps read the list, then the unit, then the page table:
     # entry g is `unit * MP + page` of a live pair, so no page is clamped
     def kv_index(g, wk, mt, pt, kl, ly, *rest):
-        return (ly[0], pt[mt[0, _div(wk[g], MP)], _rem(wk[g], MP)], 0, 0, 0)
+        return (ly[0], pt[mt[0, _div(wk[g], MP)], _rem(wk[g], MP)]
+                ) + (0,) * (kq.ndim - 2)
 
     def scale_index(g, wk, mt, pt, kl, ly, *rest):
         return kv_index(g, wk, mt, pt, kl, ly, *rest)[1:4]
 
     def q_index(g, wk, mt, *rest):
-        return (0, mt[1, _div(wk[g], MP)], 0)
+        return (0,) * len(heads) + (mt[1, _div(wk[g], MP)], 0)
 
-    q_spec = pl.BlockSpec((Hk, q_block * G, D), q_index)
+    q_spec = pl.BlockSpec(heads + (q_block * G, D), q_index)
     # one token-major page of one layer = one contiguous PS*Hk*D slab
     # (single DMA)
-    kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
+    kv_spec = pl.BlockSpec((None, None, PS) + heads + (D,), kv_index)
     kw = dict(page_size=PS, max_pages=MP, n_groups=G, scale=scale,
               softcap=softcap)
     if quantized:
@@ -561,16 +577,16 @@ def ragged_paged_attention(
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
-            pltpu.VMEM((Hk, q_block * G, 1), jnp.float32),
-            pltpu.VMEM((Hk, q_block * G, D), jnp.float32),
+            pltpu.VMEM(heads + (q_block * G, 1), jnp.float32),
+            pltpu.VMEM(heads + (q_block * G, 1), jnp.float32),
+            pltpu.VMEM(heads + (q_block * G, D), jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hk, T * G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
     )(*prefetch, *operands)
     # [Hk, T*G, D] -> [T, Hk, G, D]; a row that no visited unit covers

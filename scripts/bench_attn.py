@@ -2,10 +2,13 @@
 page cost.
 
 The table (`python scripts/bench_attn.py`, on the chip): geometries phi-3
-(Hk 32, G 1, D 96, window 2047 live) and GQA (Hk 8, G 4, D 128), page size
-64, page table 64 wide, x rows 4 and 32 x contexts of 64, 448 and 4096
-tokens a row, plus one mixed batch (1, 3, 7, 20 pages and four pad rows)
-and the 4-row, 7-page point again under a page table 8 wide. Each line is
+(Hk 32, G 1, D 96, window 2047 live), GQA (Hk 8, G 4, D 128) and MQA
+(Hk 1, G 20, D 128: ai21-jamba2-3b, over its cell's pool of 2880 pages),
+page size 64, page table 64 wide, x rows 4 and 32 x contexts of 64, 448
+and 4096 tokens a row, plus one mixed batch (1, 3, 7, 20 pages and four
+pad rows), the 4-row, 7-page point again under a page table 8 wide, and
+for MQA the cell's decode step (34 rows of 3-34 pages in a bucket of 64).
+Each line is
 one JSON object: us a call, us a live page, and beside them the page's
 DMA time at the chip's HBM peak (K + V as the pool holds them, a head dim
 padded to 128 lanes). `us_call` times the call with the lengths fixed
@@ -16,10 +19,12 @@ lengths depend on the previous call's output, so every call rebuilds it.
 kernel's table first (`--ragged --ragged-only`: that table alone): the
 cell's plans under its T buckets (288 for a 256-token chunk beside three
 decode rows of six pages, at prior 0 and at prior 256; 64 for a 32-row
-decode batch beside a 32-token chunk), us a call with the walk built once
+decode batch beside a 32-token chunk; 256 for a 128-token chunk beside
+the 34 decode rows of jamba2-reasoning-steady's step), us a call with the walk built once
 above the calls, us a live (work unit, page) pair, and the live share of
-the (NW, MP) grid the kernel took until PR 31. The script runs unchanged
-on a checkout of an earlier commit, which is how two are compared.
+the (NW, MP) grid the kernel took until PR 31. `--only NAME` keeps one
+geometry of both tables. The script runs unchanged on a checkout of an
+earlier commit, which is how two are compared.
 
 Timing rule: many iters fused in one jit via lax.scan with a data
 dependency (out feeds next q), then ONE device_get — one dispatch and one
@@ -64,9 +69,13 @@ POOL_PAGES = 176
 GEOMETRIES = {
     "phi-3": dict(Hk=32, G=1, D=96, window=2047),
     "gqa": dict(Hk=8, G=4, D=128, window=None),
+    "mqa": dict(Hk=1, G=20, D=128, window=None, pool_pages=2880),
 }
 ROWS, PAGES = (4, 32), (1, 7, 64)  # 64, 448 and 4096 tokens a row
 MIXED_PAGES = (1, 3, 7, 20, 0, 0, 0, 0)
+# jamba2-reasoning-steady's decode step: ~34 rows at contexts of 200-2200
+# tokens (627 live pages) in the bucket of 64
+CELL_PAGES = tuple(3 + (i * 29) % 32 for i in range(34)) + (0,) * 30
 
 
 @partial(jax.jit, static_argnames=("impl", "relist"),
@@ -115,15 +124,15 @@ def bench_point(name, geom, pages, mp, pools, impls, relisted=False) -> None:
     (0 = a pad row), under a page table `mp` wide. `pools` is the list
     [k_pool, v_pool], rebound to what each donating call hands back."""
     hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
-    rows = len(pages)
+    rows, pool_pages = len(pages), pools[0].shape[1]
     rng = np.random.default_rng(0)
     pt = np.zeros((rows, mp), np.int32)
     for b, n in enumerate(pages):
         # drawn from a pool smaller than the rows' contexts, so pages are
         # shared (read-only here); a step never repeats its predecessor's
         # page, so every live page is one DMA
-        pt[b, :n] = (rng.integers(1, POOL_PAGES, n).cumsum()
-                     + rng.integers(POOL_PAGES)) % POOL_PAGES
+        pt[b, :n] = (rng.integers(1, pool_pages, n).cumsum()
+                     + rng.integers(pool_pages)) % pool_pages
     kv_lens = np.asarray(pages, np.int32) * PS
     live = sum(live_pages(int(n), window, PS) for n in kv_lens)
     q = jnp.asarray(rng.standard_normal((rows, hk, g, d)), jnp.bfloat16)
@@ -153,7 +162,8 @@ def bench_point(name, geom, pages, mp, pools, impls, relisted=False) -> None:
 
 def bench_decode_table(impls) -> None:
     for gname, geom in GEOMETRIES.items():
-        shape = (LAYERS, POOL_PAGES, PS, geom["Hk"], geom["D"])
+        shape = (LAYERS, geom.get("pool_pages", POOL_PAGES), PS, geom["Hk"],
+                 geom["D"])
         keys = jax.random.split(jax.random.key(0), 2)
         pools = [jax.random.normal(k, shape, jnp.bfloat16) for k in keys]
         for rows in ROWS:
@@ -162,6 +172,9 @@ def bench_decode_table(impls) -> None:
                             64, pools, impls, relisted=(rows, pages) == (4, 7))
         bench_point(f"{gname} mixed", geom, MIXED_PAGES, 64, pools, impls)
         bench_point(f"{gname} 4x7 MP8", geom, (7,) * 4, 8, pools, impls)
+        if gname == "mqa":
+            bench_point(f"{gname} cell 34 of 64", geom, CELL_PAGES, 64, pools,
+                        impls, relisted=True)
         del pools
 
 
@@ -197,10 +210,14 @@ RAGGED_PLANS = {
     "chunk 256 @256 + 3 decode": ((350, 380, 330), (256, 256)),
     "chunk 32 @0 + 32 decode": (tuple(330 + 3 * i for i in range(32)),
                                 (32, 0)),
+    # jamba2-reasoning-steady's mixed step: a joiner's prompt beside the
+    # decode batch (CELL_PAGES' contexts)
+    "chunk 128 @0 + 34 decode": (tuple(n * PS - 7 for n in CELL_PAGES[:34]),
+                                 (128, 0)),
 }
 
 
-def ragged_plan(rng, decode_kv, chunk, mp):
+def ragged_plan(rng, decode_kv, chunk, mp, pool_pages=POOL_PAGES):
     """build_ragged_metadata's output for a plan, page tables drawn from
     the pool as bench_point draws them."""
     from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
@@ -209,8 +226,8 @@ def ragged_plan(rng, decode_kv, chunk, mp):
     q_lens = [1] * len(decode_kv) + [n_tok]
     q_starts = [n - 1 for n in decode_kv] + [prior]
     kv_lens = list(decode_kv) + [prior + n_tok]
-    rows = [((rng.integers(1, POOL_PAGES, -(-n // PS)).cumsum()
-              + rng.integers(POOL_PAGES)) % POOL_PAGES).tolist()
+    rows = [((rng.integers(1, pool_pages, -(-n // PS)).cumsum()
+              + rng.integers(pool_pages)) % pool_pages).tolist()
             for n in kv_lens]
     t = min(b for b in RAGGED_T_BUCKETS if b >= sum(q_lens))
     return build_ragged_metadata(q_lens, q_starts, kv_lens, rows, t,
@@ -263,13 +280,14 @@ def bench_ragged_table() -> None:
     share of the (NW, MP) grid that is live."""
     for gname, geom in GEOMETRIES.items():
         hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
-        shape = (LAYERS, POOL_PAGES, PS, hk, d)
+        pool_pages = geom.get("pool_pages", POOL_PAGES)
+        shape = (LAYERS, pool_pages, PS, hk, d)
         keys = jax.random.split(jax.random.key(0), 2)
         pools = [jax.random.normal(k, shape, jnp.bfloat16) for k in keys]
         win = None if window is None else jnp.int32(window)
         for name, (decode_kv, chunk) in RAGGED_PLANS.items():
             rng = np.random.default_rng(0)
-            md = ragged_plan(rng, decode_kv, chunk, 64)
+            md = ragged_plan(rng, decode_kv, chunk, 64, pool_pages)
             t, nw = md["tok_positions"].shape[0], md["meta"].shape[1]
             q = jnp.asarray(rng.standard_normal((t, hk, g, d)), jnp.bfloat16)
             tail = tuple(jnp.asarray(md[k]) for k in
@@ -333,6 +351,10 @@ def main() -> None:
               "device number)", flush=True)
         return
     args = sys.argv[1:]
+    if "--only" in args:
+        only = {args[args.index("--only") + 1]}
+        for name in GEOMETRIES.keys() - only:
+            del GEOMETRIES[name]
     if "--ragged" in args:
         bench_ragged_table()
     if "--ragged-only" not in args:

@@ -232,10 +232,26 @@ def test_a_prompt_in_any_chunks_gives_one_state(params, sizes):
         np.testing.assert_array_equal(np.asarray(a[:, 3]), np.asarray(b[:, 3]))
 
 
-def test_prefill_then_decode_through_slot_and_pages(params):
+def _interpreted_kernels(monkeypatch):
+    """attn_impl="pallas" on the CPU: the four kernels of a hybrid step in
+    interpret mode (the attention ones at ONE KV head, so the one-head
+    routine over the 4-d view of the pool, ops/paged_attention.py)."""
+    import functools
+
+    from dynamo_tpu.ops import paged_attention as pa_ops
+    from dynamo_tpu.ops import ragged_paged_attention as rg_ops
+
+    for mod, name in ((pa_ops, "decode_paged_attention"), (rg_ops, "ragged_paged_attention"),
+                      (ssm, "ssm_update"), (ssm, "ssm_scan")):
+        monkeypatch.setattr(mod, name, functools.partial(getattr(mod, name), interpret=True))
+
+
+@pytest.mark.parametrize("attn_impl", ["jnp", "pallas"])
+def test_prefill_then_decode_through_slot_and_pages(params, attn_impl, monkeypatch):
     """30 tokens prefilled, 7 decoded one at a time beside two padding rows:
     every logprob is the reference's full pass, and the padding rows (which
     name the scratch slot, and have no position) change no slot."""
+    _interpreted_kernels(monkeypatch)
     toks = _tokens(37, 3)
     want = ref.logprobs_at(MODEL, params, toks, list(range(37)))
     lg, (kp, vp, st) = _chunk(params, _pools(), toks, 0, 30, [1, 2, 3, 4], 3)
@@ -246,7 +262,8 @@ def test_prefill_then_decode_through_slot_and_pages(params):
         lg, kp, vp, st = jamba.forward(
             C, params, jnp.asarray([[toks[p]], [0], [0]], jnp.int32),
             jnp.asarray([[p], [-1], [-1]], jnp.int32), kp, vp, table,
-            jnp.asarray([p + 1, 0, 0]), state=st, slots=jnp.asarray([3, 0, 0]))
+            jnp.asarray([p + 1, 0, 0]), state=st, slots=jnp.asarray([3, 0, 0]),
+            attn_impl=attn_impl)
         assert np.abs(_logp(lg[0, 0]) - want[p]).max() < TOL
         for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(before)):
             others = [s for s in range(a.shape[1]) if s != 3]
@@ -254,10 +271,12 @@ def test_prefill_then_decode_through_slot_and_pages(params):
             assert not np.array_equal(np.asarray(a[:, 3]), np.asarray(b[:, 3]))
 
 
-def test_ragged_step_with_a_decode_row_and_two_chunks(params):
+@pytest.mark.parametrize("attn_impl", ["jnp", "pallas"])
+def test_ragged_step_with_a_decode_row_and_two_chunks(params, attn_impl, monkeypatch):
     """One flat step: sequence X decodes its 31st token from slot 3, Y's
     first ten tokens start slot 1 (junk in it), Z goes on from its ninth in
     slot 2. Each row's logprobs are the reference's for its own sequence."""
+    _interpreted_kernels(monkeypatch)
     x, y, z = _tokens(31, 3), _tokens(10, 5), _tokens(25, 6)
     _, pools = _chunk(params, _pools(), x, 0, 30, [1, 2, 3, 4], 3)
     _, (kp, vp, st) = _chunk(params, pools, z, 0, 9, [5, 6], 2)
@@ -276,7 +295,7 @@ def test_ragged_step_with_a_decode_row_and_two_chunks(params):
         kp, vp, jnp.asarray(md["tok_page_table"]), jnp.asarray(md["tok_kv_lens"]),
         last_index=jnp.asarray(gather),
         ragged=tuple(jnp.asarray(md[k]) for k in ("seg_page_table", "seg_kv_lens", "meta")),
-        state=st, slots=jnp.asarray(seg))
+        state=st, slots=jnp.asarray(seg), attn_impl=attn_impl)
     got = _logp(lg[0])
     for row, (toks, at) in enumerate(((x, 30), (y, 9), (z, 24))):
         want = ref.logprobs_at(MODEL, params, toks, [at])[0]
@@ -500,6 +519,21 @@ def test_every_active_sequence_owns_a_slot_and_gives_it_back():
     sched.step_plan()
     assert sorted(s.state_slot for s in sched.active) == [1, 2]
     assert not sched.waiting
+
+
+def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
+    """A static fact of a worker, in `runner ready` and /debug/device: one KV
+    head on the Pallas path is the one-head routine; the jnp gather has none."""
+    import logging
+
+    kw = dict(num_pages=8, page_size=4, params=params, dtype=jnp.float32)
+    with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.runner"):
+        on_kernel = ModelRunner(C, attn_impl="pallas", **kw)
+    assert on_kernel.device_report()["decode_page_routine"] == "one_head"
+    assert "decode_page_routine=one_head" in caplog.text
+    assert ModelRunner(C, **kw).device_report()["decode_page_routine"] is None
+    wide = ModelRunner(get_config("tiny"), num_pages=8, page_size=4, attn_impl="pallas")
+    assert wide.device_report()["decode_page_routine"] == "by_heads"
 
 
 def test_a_pool_with_fewer_slots_than_rows_is_refused():

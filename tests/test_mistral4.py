@@ -475,7 +475,8 @@ async def test_metrics_show_the_held_slots(served):
              "stop": {"max_tokens": 6, "stop_ids": [], "ignore_eos": True}}, Context())]
         assert items[-1]["finish_reason"] == "length"
         for _ in range(100):
-            if engine._rec_late is None and not engine.scheduler.has_work():
+            if (engine._rec_late is None and engine._inflight is None
+                    and not engine.scheduler.has_work()):
                 break
             await asyncio.sleep(0.01)
         engine._publish_fpm("decode", 0.0, 0)

@@ -47,6 +47,12 @@ _DECODE_SHAPES = {
     "mha-d128-ps16": (8, 32, 1, 128, 4, 256, 16, 128, False, False),
     "llama3.2-int8": (8, 8, 3, 128, 28, 256, 64, 64, True, True),
     "phi3-int8": (8, 32, 1, 96, 4, 176, 64, 64, True, True),
+    # one KV head (ai21-jamba2-3b's cell: 20 query heads, 2 attention
+    # layers, 2880 pages): the one-head routine on the 4-d view, 8 pages a
+    # step; under a window; and four KV heads, one a shard on a 2x2
+    "jamba2-mqa-b64": (64, 1, 20, 128, 2, 2880, 64, 64, False, False),
+    "mqa-window-ps16": (8, 1, 8, 128, 4, 512, 16, 256, True, False),
+    "mqa-a-shard-b64": (64, 4, 20, 128, 2, 2880, 64, 64, False, False),
 }
 
 
@@ -73,7 +79,8 @@ def test_decode_kernel_compiles_for_v5e(topo, name):
     assert text.count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("name", ["phi3-b4", "mistral-b32", "llama3.2-int8"])
+@pytest.mark.parametrize("name", ["phi3-b4", "mistral-b32", "llama3.2-int8",
+                                  "mqa-a-shard-b64"])
 def test_sharded_decode_kernel_compiles_for_v5e_2x2(topo, name):
     """Heads over four chips: each shard walks the same list on its own
     heads, and no collective appears."""
@@ -268,12 +275,16 @@ def test_state_space_kernels_compile_for_v5e_at_jamba_widths(topo, kernel, state
     assert text.count("tpu_custom_call") == 1
 
 
-def test_a_hybrid_models_step_programs_compile_for_v5e(topo):
+def test_a_hybrid_models_step_programs_compile_for_v5e(topo, monkeypatch):
     """ai21-jamba2-3b whole, as its cell runs it: the decode loop (64 rows, 4
     fused steps) and the ragged step (320 tokens). The attention kernels at
     its geometry (20 query heads on one KV head of 128: no multiple of their
     row block), the state kernels beside them, and the state pool read in
-    place: no slice or copy of it in front of a kernel."""
+    place: no slice or copy of it in front of a kernel. Nor of the KV pool:
+    at one KV head the kernels take the stack as the programs carry it.
+    Handed the 5-d operand instead (the by-heads routine, which every call
+    at one head took until PR 39), the decode loop converts both pools
+    whole in front of the kernel, which is how the check can see one."""
     import re
     from functools import partial
 
@@ -307,13 +318,27 @@ def test_a_hybrid_models_step_programs_compile_for_v5e(topo):
     assert pools[0].shape[0] == 2  # the attention layers alone
     B, MP, f32, i32 = 64, 64, jnp.float32, jnp.int32
     jit = partial(jax.jit, donate_argnames=("state",))
-    text = jit(partial(_decode_loop, c, "pallas", None, 4, -1), donate_argnums=(6, 7)).lower(
-        params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None, *pools, samp(B),
-        state=state, slots=s((B,), i32)).compile().as_text()
+
+    def decode_loop():
+        return jit(partial(_decode_loop, c, "pallas", None, 4, -1), donate_argnums=(6, 7)).lower(
+            params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None, *pools, samp(B),
+            state=state, slots=s((B,), i32)).compile().as_text()
+
+    text = decode_loop()
     assert kernels(text) == {"decode_paged_attention", "ssm_update"}
     # one layer's states of every slot, sliced or copied out of the pool
     slab = re.compile(r"= f32\[(1,)?65,16,40,128\]\S* (dynamic-slice|slice|copy)\(")
     assert not slab.search(text)
+    # the whole KV stack, or its 4-d view, copied (converted between layouts)
+    kv_copy = re.compile(r"= bf16\[2,2880,64,(1,)?128\]\S* copy\(")
+    assert not kv_copy.search(text)
+    from dynamo_tpu.ops import paged_attention as pa_ops
+
+    with monkeypatch.context() as m:
+        m.setattr(pa_ops, "page_routine", lambda *a: "by_heads")
+        jax.clear_caches()  # the kernel's wrapper is traced again
+        assert len(kv_copy.findall(decode_loop())) == 2  # K and V
+    jax.clear_caches()
     T = 320
     md = build_ragged_metadata([1] * 8 + [100, 150], [5] * 8 + [0, 0], [6] * 8 + [100, 150],
                                [[1]] * 8 + [[2, 3], [4, 5, 6]], T, q_block=8, max_pages=MP)
@@ -326,3 +351,4 @@ def test_a_hybrid_models_step_programs_compile_for_v5e(topo):
         seg_slots=s((3, SEG), i32)).compile().as_text()
     assert kernels(text) == {"ragged_paged_attention", "ssm_scan"}
     assert not slab.search(text)
+    assert not kv_copy.search(text)

@@ -218,12 +218,17 @@ def test_decode_paged_attention_sharded_int8_kv():
 
 # -- the decode kernel's page walk (its grid is a work list of live pages) ---
 # name -> (Hk, G, D, PS, MP): G 1 with Hk filling bf16's 16-row tiles is
-# the by-rows routine (phi-3's MHA at D 96), the rest the batched one
+# the by-rows routine (phi-3's MHA at D 96); Hk 1 the one-head routine
+# (ai21-jamba2-3b's 20 query heads on one KV head; a step brings 2 pages
+# of a table 6 wide, all 8 of one 8 wide); the rest the batched one
 _WALK_GEOMS = {
     "mha-d96": (16, 1, 96, 16, 6),
     "gqa-g3": (2, 3, 128, 8, 6),
     "gqa-g4": (2, 4, 128, 8, 6),
     "gemma-ps16": (4, 2, 128, 16, 6),
+    "mqa-g20": (1, 20, 128, 8, 6),
+    "mqa-g8-d64": (1, 8, 64, 4, 8),
+    "mqa-g1": (1, 1, 128, 8, 6),
 }
 # name -> (window or None, softcap, int8 KV)
 _WALK_VARIANTS = {
@@ -291,7 +296,7 @@ def test_decode_walk_matches_reference(geom, variant):
 
 
 @pytest.mark.parametrize("layer", [0, 2])
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
 def test_decode_walk_reads_stacked_pool_layer(geom, layer):
     q, kp, vp, pt, kv, window, softcap = _walk_case(
         geom, "window-mid-page", layers=3)
@@ -304,22 +309,92 @@ def test_decode_walk_reads_stacked_pool_layer(geom, layer):
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
+@pytest.mark.parametrize("variant", ["window-mid-page", "int8"])
 @pytest.mark.parametrize("geom", ["gqa-g4", "gemma-ps16"])
-def test_decode_walk_sharded(geom):
+def test_decode_walk_sharded(geom, variant):
+    """Heads over two shards; gqa-g4's two KV heads leave each shard ONE, so
+    it takes the one-head routine on its local 4-d view (int8: by heads)."""
     from dynamo_tpu.ops.paged_attention import decode_paged_attention_sharded
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
-    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, "window-mid-page")
+    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
     out = decode_paged_attention_sharded(
         q, kp, vp, jnp.asarray(pt), jnp.asarray(kv),
-        make_mesh(MeshConfig(model=2)), window=jnp.int32(window),
+        make_mesh(MeshConfig(model=2)),
+        window=None if window is None else jnp.int32(window),
         interpret=True,
     )
     _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, softcap), kv)
 
 
+def _pallas_calls(jaxpr, found=None):
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, found)
+    return found
+
+
+def test_page_routine_follows_heads_and_dtype():
+    """The routine is a fact of (Hk, G, pool dtype): nothing else picks it."""
+    from dynamo_tpu.ops.paged_attention import one_head_tiles, page_routine
+
+    bf, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+    assert page_routine(32, 1, bf, False) == "by_rows"
+    assert page_routine(8, 1, f32, False) == "by_rows"
+    assert page_routine(8, 1, bf, False) == "by_heads"  # half a bf16 tile
+    assert page_routine(8, 4, bf, False) == "by_heads"
+    assert page_routine(1, 20, bf, False) == "one_head"
+    assert page_routine(1, 1, f32, False) == "one_head"
+    assert page_routine(1, 20, i8, True) == "by_heads"  # scales a (token, head)
+    assert page_routine(32, 1, i8, True) == "by_heads"
+    # a step's pages: a power of two up to 8 that tiles the page table
+    assert [one_head_tiles(mp) for mp in (64, 256, 12, 6, 7)] == [8, 8, 4, 2, 1]
+
+
+@pytest.mark.parametrize("kernel", ["decode", "ragged"])
+def test_one_kv_head_operand_is_the_4d_view(kernel):
+    """At Hk 1 a dense pool reaches the kernel as [L, NP, PS, D], the bytes
+    the step program carries; an int8 pool at Hk 1 and a dense one at Hk 2
+    keep the 5-d operand they have always had."""
+    from dynamo_tpu.ops.ragged_paged_attention import ragged_paged_attention
+
+    L, NP, PS, G, D = 2, 8, 8, 20, 128
+
+    def pool_operands(Hk, quant):
+        pool = jnp.zeros((L, NP, PS, Hk, D), jnp.bfloat16)
+        if quant:
+            pool = _q_pools(pool, pool)[0]
+        if kernel == "decode":
+            fn = lambda: decode_paged_attention(  # noqa: E731
+                jnp.zeros((4, Hk, G, D), jnp.bfloat16), pool, pool,
+                jnp.zeros((4, 4), jnp.int32), jnp.ones((4,), jnp.int32), None,
+                jnp.int32(1), interpret=True)
+        else:
+            from dynamo_tpu.ops.ragged_paged_attention import (
+                build_ragged_metadata,
+            )
+
+            md = build_ragged_metadata([1, 5], [3, 0], [4, 5], [[1], [2]], 8,
+                                       max_pages=4)
+            fn = lambda: ragged_paged_attention(  # noqa: E731
+                jnp.zeros((8, Hk, G, D), jnp.bfloat16), pool, pool,
+                jnp.asarray(md["seg_page_table"]),
+                jnp.asarray(md["seg_kv_lens"]), jnp.asarray(md["meta"]), None,
+                jnp.int32(1), interpret=True)
+        (call,) = _pallas_calls(jax.make_jaxpr(fn)().jaxpr)
+        return {v.aval.shape for v in call.invars
+                if v.aval.shape[:3] == (L, NP, PS) and v.aval.ndim >= 4}
+
+    assert pool_operands(1, False) == {(L, NP, PS, D)}
+    assert pool_operands(1, True) == {(L, NP, PS, 1, D)}
+    assert pool_operands(2, False) == {(L, NP, PS, 2, D)}
+
+
 @pytest.mark.parametrize("variant", ["plain", "window-mid-page"])
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
 def test_decode_walk_reads_only_live_pages(geom, variant):
     """Every pool page outside the rows' live ranges holds NaN, and every
     page-table entry outside a row's live range names an unowned (NaN)
@@ -384,7 +459,7 @@ def test_decode_walk_steps_follow_live_pages_not_table_width(window):
     assert grid_of(8)[1] == 1 and len(grid_of(8)[0]) == 1
 
 
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
 def test_decode_walk_stays_inside_the_page_table(geom):
     """A length past MP * PS (nothing the engine sends) walks the table's
     MP pages like a full row: the list never outgrows its W entries and
@@ -1273,20 +1348,32 @@ def test_ragged_walk_matches_reference(plan, variant):
     _ragged_close(*_ragged_run(plan, variant, forked=plan == "verify"))
 
 
-@pytest.mark.parametrize("geom", [(16, 1, 96), (4, 2, 128)])
+@pytest.mark.parametrize("geom", [(16, 1, 96), (4, 2, 128), (1, 8, 64),
+                                  (1, 1, 128)])
 def test_ragged_walk_geometries(geom):
-    """phi-3's MHA (G 1, a head dim that is not 128 lanes) and a GQA
-    geometry, on the sparse plan under a window."""
+    """phi-3's MHA (G 1, a head dim that is not 128 lanes), a GQA geometry
+    and two of one KV head, on the sparse plan under a window."""
     _ragged_close(*_ragged_run("sparse", "window", geom=geom))
 
 
+_MQA = (1, 20, 128)  # ai21-jamba2-3b: the page a [PS, D] tile of a 4-d pool
+
+
+@pytest.mark.parametrize("variant", list(_RAGGED_VARIANTS))
+@pytest.mark.parametrize("plan", list(_RAGGED_PLANS))
+def test_ragged_walk_one_kv_head_matches_reference(plan, variant):
+    _ragged_close(*_ragged_run(plan, variant, geom=_MQA,
+                               forked=plan == "verify"))
+
+
+@pytest.mark.parametrize("geom", [(2, 3, 64), _MQA], ids=["gqa", "mqa"])
 @pytest.mark.parametrize("variant", ["plain", "window"])
 @pytest.mark.parametrize("plan", ["mixed", "sparse", "verify"])
-def test_ragged_walk_reads_only_live_pairs(plan, variant):
+def test_ragged_walk_reads_only_live_pairs(plan, variant, geom):
     """With every dead page and every dead page-table entry poisoned the
     result is the clean one, bit for bit: no dead pair was visited."""
-    clean, _, real = _ragged_run(plan, variant)
-    dirty, _, _ = _ragged_run(plan, variant, poison=True)
+    clean, _, real = _ragged_run(plan, variant, geom=geom)
+    dirty, _, _ = _ragged_run(plan, variant, geom=geom, poison=True)
     assert np.isfinite(dirty).all()
     np.testing.assert_array_equal(dirty, clean)
 
@@ -1336,7 +1423,7 @@ _STACK_L = 4
 
 
 def _stacked_case(kernel, *, quant=False, window=None, softcap=0.0,
-                  sharded=False, seed=31):
+                  sharded=False, seed=31, heads=(2, 3)):
     """One kernel over a stacked pool with different data in every layer.
     Returns (run, ref): run(k_pool, v_pool, layer) calls the kernel
     (`layer` None for a per-layer pool), ref(layer) is the jnp path on
@@ -1347,7 +1434,7 @@ def _stacked_case(kernel, *, quant=False, window=None, softcap=0.0,
     )
 
     rng = np.random.default_rng(seed)
-    L, Hk, G, D, NP, PS, MP = _STACK_L, 2, 3, 64, 48, 8, 8
+    L, (Hk, G), D, NP, PS, MP = _STACK_L, heads, 64, 48, 8, 8
     kp = jnp.asarray(rng.standard_normal((L, NP, PS, Hk, D)), jnp.bfloat16)
     vp = jnp.asarray(rng.standard_normal((L, NP, PS, Hk, D)), jnp.bfloat16)
     if quant:
@@ -1409,8 +1496,8 @@ def _stacked_case(kernel, *, quant=False, window=None, softcap=0.0,
                 window=win)
 
     else:
-        q, _kp, _vp, md, (q_lens, *_rest) = _ragged_case(seed, NP=NP, PS=PS,
-                                                         MP=MP)
+        q, _kp, _vp, md, (q_lens, *_rest) = _ragged_case(
+            seed, Hk=Hk, G=G, NP=NP, PS=PS, MP=MP)
         T = int(sum(q_lens))
         seg = [jnp.asarray(md[k]) for k in
                ("seg_page_table", "seg_kv_lens", "meta")]
@@ -1444,6 +1531,12 @@ _STACKED_CASES = {
     "decode-sharded": dict(kernel="decode", sharded=True),
     "prefill-sharded-window": dict(kernel="prefill", sharded=True, window=5),
     "ragged-sharded-int8": dict(kernel="ragged", sharded=True, quant=True),
+    # one KV head: the 4-d view of the stack, at the same traced layer
+    "decode-mqa-window": dict(kernel="decode", heads=(1, 20), window=11,
+                              softcap=20.0),
+    "ragged-mqa": dict(kernel="ragged", heads=(1, 20)),
+    "decode-mqa-int8": dict(kernel="decode", heads=(1, 20), quant=True),
+    "ragged-sharded-window": dict(kernel="ragged", sharded=True, window=16),
 }
 
 
@@ -1471,7 +1564,7 @@ def test_kernels_read_stacked_pool_at_layer(case, layer):
 
 @pytest.mark.parametrize(
     "case", ["decode", "prefill", "ragged", "decode-int8", "ragged-window",
-             "decode-sharded"],
+             "decode-sharded", "decode-mqa-window", "ragged-mqa"],
 )
 def test_per_layer_pool_is_the_one_layer_stack(case):
     """A pool of rank 4 is viewed as `pool[None]` at layer 0: the same
