@@ -1290,9 +1290,12 @@ class ModelRunner(Runner):
         off the sharding, not the buffers: the step thread donates those
         while the status server calls this. `decode_page_routine` is what
         the Pallas decode kernel does with a page at this worker's shapes
-        (ops/paged_attention.py `page_routine`: one shard's KV heads, the
-        query heads on each, the pool's dtype); None where that kernel is
-        not on the path (the jnp gather, latent attention)."""
+        (ops/paged_attention.py `page_routine`, the decision the kernel's
+        wrapper and its walk make: one shard's KV heads, the query heads on
+        each, the pool's dtype, a sink, values narrower than keys); a name
+        a kind, {"global", "window"}, where window layers keep a pool of
+        their own; None where that kernel is not on the path (the jnp
+        gather, latent attention)."""
         from dynamo_tpu.ops.paged_attention import page_routine
 
         devs = list(self.mesh.devices.flat)
@@ -1300,9 +1303,20 @@ class ModelRunner(Runner):
         shard_shape = list(k_leaf.sharding.shard_shape(k_leaf.shape))
         routine = None
         if self.attn_impl == "pallas" and not self.config.is_mla:
-            routine = page_routine(
-                shard_shape[3], self.config.n_heads // self.config.n_kv_heads,
-                k_leaf.dtype, isinstance(self.k_pool, dict))
+            c = self.config
+            shards = k_leaf.shape[3] // shard_shape[3]
+            v_width = jax.tree.leaves(self.v_pool)[0].shape[-1]
+            kinds = {"global": (c.n_kv_heads, c.sink_global)}
+            if self.holds_window_pool:
+                kinds["window"] = (c.n_kv_heads_window, c.sink_window)
+            routine = {
+                kind: page_routine(
+                    Hk // shards, c.n_heads // Hk, k_leaf.dtype,
+                    isinstance(self.k_pool, dict), sinked,
+                    k_leaf.shape[-1] == v_width)
+                for kind, (Hk, sinked) in kinds.items()}
+            if len(routine) == 1:
+                routine = routine["global"]
         memory = {}
         for d in devs:
             if d.process_index != jax.process_index():

@@ -329,8 +329,8 @@ def forward(
         else:
             from dynamo_tpu.ops.paged_attention import decode_walk
 
-            walk = decode_walk((c.n_kv_heads, G), k_pool, page_table,
-                               kv_lens, None)
+            walk = decode_walk((c.n_kv_heads, G), k_pool, v_pool, page_table,
+                               kv_lens, None, False)
 
     def mlp(h, lp):
         with jax.named_scope("ffn"):

@@ -363,7 +363,7 @@ def forward(
             def build_walk(window):
                 return decode_walk(
                     (c.n_kv_heads // shards, c.n_heads // c.n_kv_heads),
-                    k_pool, page_table, kv_lens, window)
+                    k_pool, v_pool, page_table, kv_lens, window, False)
 
         if c.sliding_window > 0:
             walk_sliding = build_walk(jnp.int32(c.sliding_window))

@@ -234,11 +234,12 @@ def forward(
                 walks[kind] = ragged_work_list(
                     rmeta, seg_kvl, window_of[kind], PS, seg_pt.shape[1], S)
             else:
-                from dynamo_tpu.ops.paged_attention import decode_work_list
+                from dynamo_tpu.ops.paged_attention import decode_walk
 
-                # (two head sizes: the by_heads routine, whatever the heads)
-                walks[kind] = decode_work_list(
-                    kv_lens, window_of[kind], PS, page_table.shape[1])
+                Hk = kv_heads(c, kind)
+                walks[kind] = decode_walk(
+                    (Hk, H // Hk), *pools[kind], tables[kind][0], kv_lens,
+                    window_of[kind], has_sink(c, kind))
 
     def partial_rope(x, kind):
         """The rotary on the first `rope_partial_dims` dims of each head."""

@@ -29,28 +29,53 @@ layers on one set of lengths builds the list once and hands it in (`work`;
 models/llama.py does, above its layer scan).
 
 All kv heads are processed per step. A token-major page [PS, Hk, D] is one
-CONTIGUOUS slab in the pool, so each grid step issues a single large DMA
-(the head-major layout needed Hk strided chunks per page). What a step
-does with its page is chosen from the static shapes the kernel sees, one
-walk either way (`page_routine`): `_page_by_heads`, a batched MXU product
-a kv head (GQA: G query heads share the page); at G = 1 `_page_by_rows`,
-every query row against the page read as one [PS * Hk, D] matrix; at
-Hk = 1 (MQA, or a tensor-parallel shard left with one head)
-`_pages_one_head`, the row's [G, D] queries against several pages a step,
-each the contiguous [PS, D] tile it is in the pool.
+CONTIGUOUS slab in the pool, so each grid step issues a single large DMA a
+page (the head-major layout needed Hk strided chunks per page). What a
+step does with its pages is decided in one place from the static shapes
+the kernel sees (`page_routine`; the wrapper, `decode_walk` and the
+runner's report all ask there), one walk whatever the answer:
 
-One KV head. A pool [L, NP, PS, 1, D] holds the bytes of a row-major
-[L, NP, PS, D] array, and that is how the step programs carry it. Mosaic
-takes an operand row-major with the minor pair tiled, so the 5-d operand
-is padded token by token ((1, D) tiles) and XLA converts the whole stack
-in front of every call; the 4-d view is the layout itself and costs
-nothing. A page is then 2 * PS * D bytes of K or V, too little for a grid
-step of its own (0.35 us against 0.04 us of DMA at PS 64), so a step of
-this routine brings `one_head_tiles` pages of the row, each a block of
-its own on the same operand, and the walk is the same walk at that
-granularity: `decode_walk` lists (row, step) pairs and, beside them, the
-page table with its dead entries filled in, so an index map is two SMEM
-reads and no clamp.
+- an int8 pool: `_page_by_heads`, a batched float32 product a kv head, the
+  scales folded in per (token, head). Nothing else takes it.
+- G = 1 where Hk fills whole sublane tiles of the pool's dtype, no sink,
+  values as wide as keys (phi-3's MHA): `_page_by_rows`, every query row
+  against the page read as one [PS * Hk, D] matrix.
+- every other dense pool (GQA at any Hk and G, one KV head, G = 1 at half
+  a tile, a sink, values narrower than keys): `_pages_by_tiles`, the same
+  form for G query heads a KV head and several pages a step: all Hk * G
+  query rows against the step's pages read as one [N * Hk, D] matrix in
+  the pool's dtype, the columns of other KV heads masked, the values
+  through `_pv_exact` at their own width; scores, softmax state and
+  accumulator float32.
+
+Why no head is ever brought together. The pools lie in HBM as the step
+programs carry them, `T(4,128)(2,1)` / `T(8,128)(2,1)` over the minor pair
+(Hk, D): heads on sublanes (two bf16 heads share a 32-bit one), a head's
+vector on lanes. A view [L, NP, PS, Hk * D], a head a lane-aligned slice
+of a row, is another byte order, and XLA lays the whole stack out again
+for it (a `reshape` that is no bitcast: tests/test_mosaic_compile.py shows
+one). A head read out of the 5-d block in VMEM (`ref[:, h, :]`, or a
+slice of the loaded block, or a batched product) is a strided, half-word
+gather: 2.6 us a page at mimo-v2-flash's global geometry where the
+page's DMA is 0.24 (float32 `by_heads`: 5.7). Read as the matrix
+[PS * Hk, D] the block moves nowhere: the MXU multiplies every query row
+by every (token, head) row, Hk times the useful products, which it has
+to spare, and the mask keeps a head's own: 0.5 us a page (my chip runs,
+PR 41). One KV head is the case with nothing to mask, and the one whose
+view is free: a pool [L, NP, PS, 1, D] holds the bytes of a row-major
+[L, NP, PS, D] array, the 5-d operand would be padded token by token
+((1, D) tiles) and converted whole in front of every call, and the 4-d
+view is the layout itself, a page one contiguous [PS, D] tile.
+
+Pages a grid step. A step costs ~0.35 us whatever it brings, and a page is
+2 * PS * D bytes of K or V a head (0.04 us of DMA at one head of 128, 0.24
+and 0.48 us for mimo-v2-flash's two kinds of page), so a step of the tile
+routine brings `step_tiles` pages of the row, from the page's bytes: each a
+block of its own on the same operand, and the walk is the same walk at
+that granularity: `decode_walk` lists (row, step) pairs and, beside them,
+the page table with its dead entries filled in, so an index map is two
+SMEM reads and no clamp. A tile past a row's live pages repeats a live
+page and is fetched all the same, which is why large pages ride few.
 
 The reference framework ships CUDA kernels for its block engine
 (lib/llm/src/kernels/block_copy.cu, lib/kvbm-kernels/cuda/
@@ -133,53 +158,77 @@ def decode_work_list(kv_lens, window, page_size: int, max_pages: int):
                      max_pages)
 
 
-def page_routine(Hk: int, G: int, dtype, quantized: bool) -> str:
-    """What a decode step does with its pages, from the static shapes one
-    call of the kernel sees (a tensor-parallel shard: its local heads) and
-    the pool's dtype alone: "by_rows" at G = 1 when Hk fills whole sublane
-    tiles of the pool's dtype, so the page reads as a matrix for free;
-    "one_head" at Hk = 1; "by_heads" for the rest, and for int8 pools,
-    whose scales ride per (token, head)."""
+def page_routine(Hk: int, G: int, dtype, quantized: bool, sinked: bool,
+                 one_width: bool) -> str:
+    """What a decode step does with its pages, THE decision, from the static
+    shapes one call of the kernel sees (a tensor-parallel shard: its local
+    heads), the pool's dtype, whether a sink rides along and whether the
+    values are as wide as the keys, in this order: "by_heads" for an int8
+    pool, whose scales ride per (token, head), and for nothing else;
+    "by_rows" at G = 1 when Hk fills whole sublane tiles of the pool's
+    dtype, so the page reads as a matrix for free, with no sink and one
+    width; "by_tiles" for every other dense call (one KV head is its
+    one-head case). `decode_paged_attention`, `decode_walk` and
+    `ModelRunner.device_report` all ask here."""
     if quantized:
         return "by_heads"
-    if G == 1 and Hk % (32 // jnp.dtype(dtype).itemsize) == 0:
+    if (G == 1 and not sinked and one_width
+            and Hk % (32 // jnp.dtype(dtype).itemsize) == 0):
         return "by_rows"
-    return "one_head" if Hk == 1 else "by_heads"
+    return "by_tiles"
 
 
-# pages a grid step of the one-head routine brings. At page size 64 on a
-# v5e (scripts/bench_attn.py, the cell's decode step of 627 live pages; my
-# chip run, PR 39): 1 -> 328 us a call, 2 -> 211, 4 -> 127, 8 -> 104,
-# 16 -> 99 with rows of one page twice as dear
-ONE_HEAD_TILES = 8
+# bytes a grid step of the tile routine brings at most (K + V of its pages
+# as the pool holds them). A step costs ~0.35 us whatever it brings (PR 39),
+# so small pages ride several a step; a tile past a row's live pages repeats
+# a live page and is fetched all the same, so large ones ride few.
+# scripts/bench_attn.py's sweep on a v5e (my chip runs, PR 41) set it:
+# see `step_tiles`
+STEP_BYTES = 1 << 20
 
 
-def one_head_tiles(max_pages: int) -> int:
-    """Pages a step brings under a page table `max_pages` wide: the largest
-    power of two up to ONE_HEAD_TILES that divides it, so a row's steps
-    tile the table."""
-    return math.gcd(max_pages, ONE_HEAD_TILES)
+def page_bytes(k_pool, v_pool) -> int:
+    """K + V of one page of one layer as a dense pool holds them: a head's
+    vector padded to whole lane rows."""
+    def lanes(a):
+        return -(-a.shape[-1] // 128) * 128
+    PS, Hk = k_pool.shape[-3:-1]
+    return PS * Hk * (lanes(k_pool) + lanes(v_pool)) * k_pool.dtype.itemsize
 
 
-def decode_walk(heads, k_pool, page_table, kv_lens, window):
+def step_tiles(nbytes: int, max_pages: int) -> int:
+    """Pages a step of the tile routine brings, from a page's `nbytes`
+    (`page_bytes`) under a page table `max_pages` wide: the largest power
+    of two, at most 8, that keeps a step within STEP_BYTES and divides the
+    table, so a row's steps tile it."""
+    tiles = 8
+    while tiles > 1 and tiles * nbytes > STEP_BYTES:
+        tiles //= 2
+    return math.gcd(max_pages, tiles)
+
+
+def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window, sinked):
     """The list a decode call walks, for a caller that runs many layers on
-    one set of lengths and builds it once: `decode_work_list`'s pair, or
-    where `heads` = (Hk, G) of ONE call (a shard's local heads) and the
-    pool pick the one-head routine, (work, n_work, pages): the same walk
-    over steps of `one_head_tiles` pages, entry w = `row * steps_a_row +
-    step`, so that `entry * tiles + t` is the place of the step's t-th
-    tile in `pages`, the page table flattened with every dead entry of a
-    row replaced by its nearest live one. A tile past either end of the
-    row's live pages thus repeats a live page (its slots are masked by
-    position), and the kernel reads no dead page-table entry and no dead
-    page. Built from compares and masked sums alone: a gather of B * MP
-    scalars cost more than the kernel's call (175 us against 104, my chip
-    run, PR 39)."""
-    kq = k_pool["q"] if isinstance(k_pool, dict) else k_pool
+    one set of lengths and builds it once. `heads` = (Hk, G) of ONE call (a
+    shard's local heads); with the pools and `sinked` they decide the
+    routine as the call itself will (`page_routine`). `decode_work_list`'s
+    pair for "by_rows" and "by_heads"; for "by_tiles" (work, n_work,
+    pages): the same walk over steps of `step_tiles` pages, entry w = `row
+    * steps_a_row + step`, so that `entry * tiles + t` is the place of the
+    step's t-th tile in `pages`, the page table flattened with every dead
+    entry of a row replaced by its nearest live one. A tile past either
+    end of the row's live pages thus repeats a live page (its slots are
+    masked by position), and the kernel reads no dead page-table entry and
+    no dead page. Built from compares and masked sums alone: a gather of
+    B * MP scalars cost more than the kernel's call (175 us against 104, my
+    chip run, PR 39)."""
+    quantized = isinstance(k_pool, dict)
+    kq, vq = (p["q"] if quantized else p for p in (k_pool, v_pool))
     PS, MP = kq.shape[-3], page_table.shape[1]
-    if page_routine(*heads, kq.dtype, isinstance(k_pool, dict)) != "one_head":
+    if page_routine(*heads, kq.dtype, quantized, sinked,
+                    kq.shape[-1] == vq.shape[-1]) != "by_tiles":
         return decode_work_list(kv_lens, window, PS, MP)
-    tiles = one_head_tiles(MP)
+    tiles = step_tiles(page_bytes(kq, vq), MP)
     work, n_work = decode_work_list(kv_lens, window, PS * tiles, MP // tiles)
     first, last = (a[:, None] for a in live_pages(
         kv_lens - 1, kv_lens - 1, kv_lens, window, PS, MP))
@@ -265,20 +314,21 @@ def _decode_kernel_body(
     #   compile: Gemma-2 alternates sliding/global per layer with a
     #   TRACED scalar, so the window rides as a prefetch operand)
     q_ref,  # [Hk, G, D] all query heads of the row ([Hk, D] by rows,
-    #   [G, D] at one head)
+    #   [Hk * G, D] by tiles)
     k_ref,  # [PS, Hk, D] one token-major page of keys (one contiguous DMA);
-    #   at one head the step's pages, a tuple of [PS, D] tiles
-    v_ref,  # like k_ref
+    #   by tiles: the step's pages, a tuple of such blocks ([PS, D] tiles
+    #   of the 4-d view at one head)
+    v_ref,  # like k_ref, at the values' width Dv
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
-    o_ref,  # like q_ref
+    o_ref,  # like q_ref, Dv wide
     # scratch (persist across a row's pages)
-    m_ref,  # f32 running max: [Hk, G, 1] ([Hk, 1] by rows, [G, 1] at one
-    #   head)
+    m_ref,  # f32 running max: [Hk, G, 1] ([Hk, 1] by rows, [Hk * G, 1] by
+    #   tiles)
     l_ref,  # f32 running denom, like m_ref
-    acc_ref,  # f32 running numerator, like q_ref
+    acc_ref,  # f32 running numerator, like o_ref
     *,
-    page_size: int,  # tokens a grid step covers: a page (at one head: its
+    page_size: int,  # tokens a grid step covers: a page (by tiles: its
     #   tiles' pages together, and max_pages the steps a row can take)
     max_pages: int,
     scale: float,
@@ -426,40 +476,63 @@ def _pv_exact(p, v):
     return pv[:R] + pv[R:2 * R] + pv[2 * R:]
 
 
-def _pages_one_head(q_ref, k_refs, v_refs, ks_ref, vs_ref, m_ref, l_ref,
+def _pages_by_tiles(q_ref, k_refs, v_refs, ks_ref, vs_ref, m_ref, l_ref,
                     acc_ref, n_valid, lo_in_page, *, scale, softcap):
-    """A step's pages into the running softmax at Hk = 1: two plain
-    products, the row's [G, D] queries against the tiles as they lie in
-    the pool, [N, D] stacked on rows (whole vregs: no relayout), then
-    [G, N] x [N, D]. K and V go to the MXU in the pool's dtype
-    (`_pv_exact`); scores, softmax state and accumulator are f32. A tile
-    that repeats a live page (`decode_walk`) sits past n_valid or below
-    lo_in_page like any dead slot."""
+    """A step's pages into the running softmax, `_page_by_rows` carried to G
+    query heads a KV head and to several pages a step: every query row,
+    [Hk * G, D], against the step's tiles as they lie in the pool, each
+    token-major [PS, Hk, D] block read as the matrix [PS * Hk, D] and the
+    tiles stacked on rows, in ONE product: s[r, (t, p, h)] = q[r] .
+    k[t, p, h], of which the entries with h == r's KV head are the scores
+    and the rest are masked like dead slots. The masked probabilities ARE
+    the block-diagonal left operand of the PV product, at the values' own
+    width. Nothing is moved to bring a head's tokens together: the pools
+    hold heads on sublanes, and a head read out of the block as
+    `ref[:, h, :]`, a product a head, cost 2.6 us a page where this costs
+    0.5 (PERF.md section 6, PR 41). K, V and q go to the MXU in the pool's
+    dtype (`_pv_exact`); scores, softmax state and accumulator are f32. The
+    MXU does Hk times the useful products, and has them to spare. At one
+    KV head a tile is the [PS, D] block of the 4-d view and no column is
+    another head's. A tile that repeats a live page (`decode_walk`) sits
+    past n_valid or below lo_in_page like any dead slot."""
     del ks_ref, vs_ref  # dense pools only (page_routine)
-    k = jnp.concatenate([r[...] for r in k_refs], axis=0)  # [N, D]
+
+    def rows(refs):  # [N * Hk, width], rows (tile, token, head)
+        return jnp.concatenate(
+            [r[...].reshape(-1, r.shape[-1]) for r in refs], axis=0)
+
+    Hk = 1 if len(k_refs[0].shape) == 2 else k_refs[0].shape[1]
+    R = q_ref.shape[0]
+    k = rows(k_refs)
     s = lax.dot_general(
         q_ref[...], k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32
-    ) * scale  # [G, N]
+    ) * scale  # [R, N * Hk]
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)  # (t * PS + p) * Hk + h
+    if Hk > 1:  # (one head: the kernel body PR 39 measured, to the equation)
+        n_valid, lo_in_page = n_valid * Hk, lo_in_page * Hk
     valid = (col < n_valid) & (col >= lo_in_page)
+    if Hk > 1:
+        head = _div(lax.broadcasted_iota(jnp.int32, (R, 1), 0), R // Hk)
+        valid &= (col & (Hk - 1) if Hk & (Hk - 1) == 0
+                  else _rem(col, Hk)) == head
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]  # [G, 1]
+    m_prev = m_ref[...]  # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_add = jnp.sum(p, axis=1, keepdims=True)
-    pv = _pv_exact(p, jnp.concatenate([r[...] for r in v_refs], axis=0))
+    pv = _pv_exact(p, rows(v_refs))
     acc_ref[...] = acc_ref[...] * alpha + pv
     l_ref[...] = l_ref[...] * alpha + l_add
     m_ref[...] = m_new
 
 
 _PAGE_ROUTINES = {"by_heads": _page_by_heads, "by_rows": _page_by_rows,
-                  "one_head": _pages_one_head}
+                  "by_tiles": _pages_by_tiles}
 
 
 def _decode_kernel(wk, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
@@ -472,21 +545,17 @@ def _decode_kernel_win(wk, pt, kl, ly, win, q, k, v, o, m, l, acc, **kw):
                         **kw)
 
 
-def _decode_kernel_sink(wk, pt, kl, ly, *refs, windowed, **kw):
-    """The call with a sink operand behind V (by_heads, dense pools)."""
-    win, refs = (refs[0], refs[1:]) if windowed else (None, refs)
-    q, k, v, sink, o, m, l, acc = refs
-    _decode_kernel_body(wk, pt, kl, win, q, k, v, None, None, o, m, l, acc,
-                        sink_ref=sink, **kw)
-
-
-def _decode_kernel_tiles(wk, pg, kl, ly, *refs, tiles, windowed, **kw):
-    """The one-head call: `pg` the walk's filled-in page table where the
-    others take the page table; K and V `tiles` refs each."""
+def _decode_kernel_tiles(wk, pg, kl, ly, *refs, tiles, windowed, sinked,
+                         **kw):
+    """The tile routine's call: `pg` the walk's filled-in page table where
+    the others take the page table; K and V `tiles` refs each, a sink
+    operand behind them where there is one."""
     win, refs = (refs[0], refs[1:]) if windowed else (None, refs)
     q, k, v, rest = (refs[0], refs[1:1 + tiles], refs[1 + tiles:1 + 2 * tiles],
                      refs[1 + 2 * tiles:])
-    _decode_kernel_body(wk, pg, kl, win, q, k, v, None, None, *rest, **kw)
+    sink, rest = (rest[0], rest[1:]) if sinked else (None, rest)
+    _decode_kernel_body(wk, pg, kl, win, q, k, v, None, None, *rest,
+                        sink_ref=sink, **kw)
 
 
 def _decode_kernel_int8(wk, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc, **kw):
@@ -515,6 +584,7 @@ def decode_paged_attention_sharded(
     scale=None,
     softcap: float = 0.0,
     interpret: bool = False,
+    sink=None,  # f32 [Hk, G], sharded with the heads
 ) -> jax.Array:
     """Tensor-parallel wrapper: attention is independent per kv-head, and
     the KV pool shards kv-heads over the model axis (ShardingPolicy), so
@@ -528,19 +598,22 @@ def decode_paged_attention_sharded(
         pool = {"q": pool, "s": scales}
     k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
     scalars = scalar_operands(layer, window)
+    sinks = () if sink is None else (sink,)
     if work is None:  # the same on every shard: built once, outside, for
         # the heads one shard is left with
         Hk, G = q.shape[1] // mesh.shape[axis_name], q.shape[2]
-        work = decode_walk((Hk, G), k_pool, page_table, kv_lens, window)
+        work = decode_walk((Hk, G), k_pool, v_pool, page_table, kv_lens,
+                           window, sink is not None)
 
     n_lists = len(work)
 
     def part(q, k_pool, v_pool, page_table, kv_lens, *rest):
-        layer, *window = rest[n_lists:]
+        layer, *window = rest[n_lists:n_lists + len(scalars)]
         return decode_paged_attention(
             q, k_pool, v_pool, page_table, kv_lens,
             window[0] if window else None, layer, rest[:n_lists],
             scale=scale, softcap=softcap, interpret=interpret,
+            sink=rest[-1] if sinks else None,
         )
 
     fn = jax.shard_map(
@@ -548,11 +621,11 @@ def decode_paged_attention_sharded(
         mesh=mesh,
         in_specs=(heads, pool, pool, P(None, None), P(None))
         + tuple(P(None) if w.ndim else P() for w in work)
-        + (P(),) * len(scalars),
+        + (P(),) * len(scalars) + (P(axis_name, None),) * len(sinks),
         out_specs=heads,
         check_vma=False,
     )
-    return fn(q, k_pool, v_pool, page_table, kv_lens, *work, *scalars)
+    return fn(q, k_pool, v_pool, page_table, kv_lens, *work, *scalars, *sinks)
 
 
 @functools.partial(
@@ -569,9 +642,9 @@ def decode_paged_attention(
     #   (0 = global at runtime) — Gemma-2 alternates per layer in the scan
     layer=None,  # traced int32 scalar: the layer of the stacked pool to
     #   read; rides the scan as a prefetch operand like `window`
-    work=None,  # decode_walk((Hk, G), k_pool, page_table, kv_lens, window),
-    #   for a caller that runs many layers on one set of lengths and builds
-    #   it once; None = built here
+    work=None,  # decode_walk((Hk, G), k_pool, v_pool, page_table, kv_lens,
+    #   window, sinked), for a caller that runs many layers on one set of
+    #   lengths and builds it once; None = built here
     *,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
     softcap: float = 0.0,  # Gemma-2 logit soft capping (static; 0 = off)
@@ -597,17 +670,15 @@ def decode_paged_attention(
     sinked = sink is not None
     if windowed:
         window = jnp.asarray(window, jnp.int32).reshape(())
+    if quantized and sinked:
+        raise NotImplementedError("a sink over an int8 KV pool")
     # the per-page routine, from what the shapes say
-    routine = page_routine(Hk, G, kq.dtype, quantized)
-    if sinked or Dv != D:
-        # a sink, or values narrower than keys: the by_heads routine alone
-        # has them (its walk is `decode_work_list`'s, whatever the heads)
-        routine = "by_heads"
-        work = work or decode_work_list(kv_lens, window, PS, MP)
+    routine = page_routine(Hk, G, kq.dtype, quantized, sinked, Dv == D)
     work, n_work, *filled = work or decode_walk(
-        (Hk, G), k_pool, page_table, kv_lens, window)
+        (Hk, G), k_pool, v_pool, page_table, kv_lens, window, sinked)
     # pages a grid step brings, and the steps a row can take
-    tiles = one_head_tiles(MP) if routine == "one_head" else 1
+    tiles = (step_tiles(page_bytes(kq, vq), MP) if routine == "by_tiles"
+             else 1)
     steps = MP // tiles
 
     def row_of(w, wk):
@@ -619,15 +690,9 @@ def decode_paged_attention(
     def scale_index(w, wk, pt, kl, ly, *rest):
         return kv_index(w, wk, pt, kl, ly, *rest)[1:4]
 
-    if routine == "by_rows":
-        q = q.reshape(B, Hk, D)
-        qo_block, state = (None, Hk, D), (Hk, 1)
-
-        def qo_index(w, wk, *rest):
-            return (row_of(w, wk), 0, 0)
-    elif routine == "one_head":
-        q = q.reshape(B, G, D)
-        qo_block, state = (None, G, D), (G, 1)
+    if routine in ("by_rows", "by_tiles"):  # the query heads as one matrix
+        q = q.reshape(B, Hk * G, D)
+        qo_block, state = (None, Hk * G, D), (Hk * G, 1)
 
         def qo_index(w, wk, *rest):
             return (row_of(w, wk), 0, 0)
@@ -642,8 +707,6 @@ def decode_paged_attention(
     # one token-major page of one layer = one contiguous PS*Hk*D slab: a
     # single DMA, with a legal (PS, Hk, D) tile (minor dims (Hk, D))
     kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
-    v_spec = (kv_spec if Dv == D
-              else pl.BlockSpec((None, None, PS, Hk, Dv), kv_index))
     kw = dict(page_size=PS, max_pages=MP, scale=scale, softcap=softcap,
               routine=routine)
     if quantized:
@@ -652,42 +715,48 @@ def decode_paged_attention(
         )
         # (None, PS, Hk): minor dims are full array dims — legal tile
         s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
-        in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
+        v_spec = pl.BlockSpec((None, None, PS, Hk, Dv), kv_index)
+        in_specs = [q_spec, kv_spec, s_spec, v_spec, s_spec]
         operands = (q, kq, ks, vq, vs)
-    elif routine == "one_head":
-        # the pool as the step program carries it: [L, NP, PS, D], a page
-        # one contiguous [PS, D] tile, `tiles` of them a step, each a block
-        # of its own that finds its page in the walk's filled-in table
+    elif routine == "by_tiles":
+        # `tiles` pages a step, each a block of its own on the same operand
+        # that finds its page in the walk's filled-in table: the token-major
+        # page as the pool holds it, or at one KV head the pool as the step
+        # program carries it, [L, NP, PS, D], a page one contiguous [PS, D]
+        # tile
         kw.update(page_size=PS * tiles, max_pages=steps)
         kernel = functools.partial(
-            _decode_kernel_tiles, tiles=tiles, windowed=windowed, **kw)
+            _decode_kernel_tiles, tiles=tiles, windowed=windowed,
+            sinked=sinked, **kw)
+        if Hk == 1:
+            kq, vq = (p.reshape(p.shape[:3] + p.shape[4:]) for p in (kq, vq))
+        zeros = (0,) * (kq.ndim - 2)
 
         def tile_index(t, w, wk, pg, kl, ly, *rest):
-            return (ly[0], pg[wk[w] * tiles + t], 0, 0)
+            return (ly[0], pg[wk[w] * tiles + t]) + zeros
 
-        kv_specs = [pl.BlockSpec((None, None, PS, D),
+        def tile_specs(pool):
+            return [pl.BlockSpec((None, None) + pool.shape[2:],
                                  functools.partial(tile_index, t))
                     for t in range(tiles)]
-        in_specs = [q_spec] + kv_specs * 2
-        k4, v4 = (p.reshape(p.shape[:3] + (D,)) for p in (kq, vq))
-        operands = (q,) + (k4,) * tiles + (v4,) * tiles
-    elif sinked:
-        kernel = functools.partial(_decode_kernel_sink, windowed=windowed, **kw)
-        in_specs = [q_spec, kv_spec, v_spec,
-                    pl.BlockSpec(state, lambda w, *rest: (0, 0, 0))]
-        operands = (q, kq, vq, sink.astype(jnp.float32).reshape(state))
+
+        in_specs = [q_spec] + tile_specs(kq) + tile_specs(vq)
+        operands = (q,) + (kq,) * tiles + (vq,) * tiles
+        if sinked:
+            in_specs.append(pl.BlockSpec(state, lambda w, *rest: (0, 0)))
+            operands += (sink.astype(jnp.float32).reshape(state),)
     else:
         kernel = functools.partial(
             _decode_kernel_win if windowed else _decode_kernel, **kw
         )
-        in_specs = [q_spec, kv_spec, v_spec]
+        in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, kq, vq)
 
     prefetch = (work, *(filled or [page_table]), kv_lens) + scalar_operands(
         layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # work, page_table (one head:
-        #   the walk's filled-in one), kv_lens, layer (+ window)
+        num_scalar_prefetch=len(prefetch),  # work, page_table (the tile
+        #   routine: the walk's filled-in one), kv_lens, layer (+ window)
         grid=(n_work,),  # a traced bound: the live pages, not B * MP
         in_specs=in_specs,
         out_specs=pl.BlockSpec(o_block, qo_index),

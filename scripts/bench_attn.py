@@ -8,6 +8,14 @@ page size 64, page table 64 wide, x rows 4 and 32 x contexts of 64, 448
 and 4096 tokens a row, plus one mixed batch (1, 3, 7, 20 pages and four
 pad rows), the 4-row, 7-page point again under a page table 8 wide, and
 for MQA the cell's decode step (34 rows of 3-34 pages in a bucket of 64).
+`mimo-global` and `mimo-window` are mimo-v2-flash's two kinds of layer
+(Hk 4, G 16 and Hk 8, G 8 with a sink under a window of 128; keys 256 wide
+beside values of 128, over the cell's pools of 4096 and 165 pages under a
+page table 96 wide), each with mimo2-agent-steady's decode step: 14 rows
+of 30 pages in a bucket of 16, of which the window shows 3.
+`--tiles-sweep` (with `--only NAME`) times that step at 1, 2, 4 and 8
+pages a grid step where the checkout's kernel has the rule to set
+(`step_tiles`), which is how the rule's constant was chosen.
 Each line is
 one JSON object: us a call, us a live page, and beside them the page's
 DMA time at the chip's HBM peak (K + V as the pool holds them, a head dim
@@ -66,23 +74,33 @@ LAYERS, LAYER = 2, 1  # the stacked pools, and the layer read
 # PR 29: 10 ms a dispatch)
 POOL_PAGES = 176
 
-GEOMETRIES = {
-    "phi-3": dict(Hk=32, G=1, D=96, window=2047),
-    "gqa": dict(Hk=8, G=4, D=128, window=None),
-    "mqa": dict(Hk=1, G=20, D=128, window=None, pool_pages=2880),
-}
-ROWS, PAGES = (4, 32), (1, 7, 64)  # 64, 448 and 4096 tokens a row
-MIXED_PAGES = (1, 3, 7, 20, 0, 0, 0, 0)
 # jamba2-reasoning-steady's decode step: ~34 rows at contexts of 200-2200
 # tokens (627 live pages) in the bucket of 64
 CELL_PAGES = tuple(3 + (i * 29) % 32 for i in range(34)) + (0,) * 30
+# mimo2-agent-steady's decode step: 14 rows at ~1.9 k tokens (30 pages, the
+# last one part full so a window of 128 shows 3) in the bucket of 16
+MIMO_CELL_TOKENS = tuple(30 * 64 - 5 - 3 * i for i in range(14)) + (0, 0)
+GEOMETRIES = {
+    "phi-3": dict(Hk=32, G=1, D=96, window=2047),
+    "gqa": dict(Hk=8, G=4, D=128, window=None),
+    "mqa": dict(Hk=1, G=20, D=128, window=None, pool_pages=2880,
+                cell=tuple(n * PS for n in CELL_PAGES)),
+    "mimo-global": dict(Hk=4, G=16, D=256, Dv=128, window=None,
+                        pool_pages=4096, mp=96, cell=MIMO_CELL_TOKENS),
+    "mimo-window": dict(Hk=8, G=8, D=256, Dv=128, window=128, sink=True,
+                        pool_pages=165, mp=96, cell=MIMO_CELL_TOKENS),
+}
+ROWS, PAGES = (4, 32), (1, 7, 64)  # 64, 448 and 4096 tokens a row
+MIXED_PAGES = (1, 3, 7, 20, 0, 0, 0, 0)
 
 
 @partial(jax.jit, static_argnames=("impl", "relist"),
          donate_argnames=("k_pool", "v_pool"))
-def decode_loop(q, k_pool, v_pool, pt, kv_lens, window, impl, relist):
+def decode_loop(q, k_pool, v_pool, pt, kv_lens, window, sink, impl, relist):
     """ITERS chained calls; the pools are donated and handed back, as
     the step programs carry them."""
+    kw = {} if sink is None else {"sink": sink}
+
     def body(q, i):
         kv = kv_lens
         if relist:  # never true, and XLA cannot know: the lengths now
@@ -90,12 +108,14 @@ def decode_loop(q, k_pool, v_pool, pt, kv_lens, window, impl, relist):
             kv = kv + (q[0, 0, 0, 0] > 3e38).astype(jnp.int32)
         if impl == "pallas":
             o = decode_paged_attention(q, k_pool, v_pool, pt, kv, window,
-                                       jnp.minimum(i, LAYER))
+                                       jnp.minimum(i, LAYER), **kw)
         else:
             o = paged_attention_jnp(
                 q[:, None], k_pool[LAYER], v_pool[LAYER], pt,
-                jnp.maximum(kv - 1, 0)[:, None], kv, window=window,
+                jnp.maximum(kv - 1, 0)[:, None], kv, window=window, **kw,
             )[:, 0]
+        # (values narrower than keys: the output repeated to a query's width)
+        o = jnp.tile(o, q.shape[-1] // o.shape[-1])
         return o.astype(q.dtype), None
 
     q, _ = lax.scan(body, q, jnp.arange(ITERS) + LAYER)
@@ -119,9 +139,11 @@ def live_pages(kv_len: int, window, ps: int) -> int:
     return (kv_len - 1) // ps - lo // ps + 1
 
 
-def bench_point(name, geom, pages, mp, pools, impls, relisted=False) -> None:
+def bench_point(name, geom, pages, mp, pools, impls, relisted=False,
+                tokens=None) -> None:
     """One line of the table: rows holding `pages[b]` whole pages each
-    (0 = a pad row), under a page table `mp` wide. `pools` is the list
+    (0 = a pad row; `tokens`: the rows' lengths, where pages are part
+    full), under a page table `mp` wide. `pools` is the list
     [k_pool, v_pool], rebound to what each donating call hands back."""
     hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
     rows, pool_pages = len(pages), pools[0].shape[1]
@@ -134,11 +156,16 @@ def bench_point(name, geom, pages, mp, pools, impls, relisted=False) -> None:
         pt[b, :n] = (rng.integers(1, pool_pages, n).cumsum()
                      + rng.integers(pool_pages)) % pool_pages
     kv_lens = np.asarray(pages, np.int32) * PS
+    if tokens is not None:
+        kv_lens = np.asarray(tokens, np.int32)
     live = sum(live_pages(int(n), window, PS) for n in kv_lens)
     q = jnp.asarray(rng.standard_normal((rows, hk, g, d)), jnp.bfloat16)
     win = None if window is None else jnp.int32(window)
-    tail = (jnp.asarray(pt), jnp.asarray(kv_lens), win)
-    page_bytes = 2 * PS * hk * (-(-d // 128) * 128) * pools[0].dtype.itemsize
+    sink = (jnp.asarray(rng.standard_normal((hk, g)), jnp.float32)
+            if geom.get("sink") else None)
+    tail = (jnp.asarray(pt), jnp.asarray(kv_lens), win, sink)
+    page_bytes = PS * hk * sum(-(-p.shape[-1] // 128) * 128
+                               for p in pools) * pools[0].dtype.itemsize
 
     def call(impl, relist):
         out, pools[0], pools[1] = decode_loop(
@@ -160,21 +187,58 @@ def bench_point(name, geom, pages, mp, pools, impls, relisted=False) -> None:
     print(json.dumps(line), flush=True)
 
 
+def make_pools(geom):
+    """[k_pool, v_pool] of a geometry, layer-stacked."""
+    shape = (LAYERS, geom.get("pool_pages", POOL_PAGES), PS, geom["Hk"])
+    keys = jax.random.split(jax.random.key(0), 2)
+    return [jax.random.normal(k, shape + (w,), jnp.bfloat16)
+            for k, w in zip(keys, (geom["D"], geom.get("Dv", geom["D"])))]
+
+
+def bench_cell_point(gname, geom, pools, impls, label="") -> None:
+    """The geometry's cell's decode step, where it has one."""
+    tokens = geom.get("cell")
+    if tokens:
+        live = sum(n > 0 for n in tokens)
+        bench_point(f"{gname} cell {live} of {len(tokens)}{label}", geom,
+                    tuple(-(-n // PS) for n in tokens), geom.get("mp", 64),
+                    pools, impls, relisted=True, tokens=tokens)
+
+
 def bench_decode_table(impls) -> None:
     for gname, geom in GEOMETRIES.items():
-        shape = (LAYERS, geom.get("pool_pages", POOL_PAGES), PS, geom["Hk"],
-                 geom["D"])
-        keys = jax.random.split(jax.random.key(0), 2)
-        pools = [jax.random.normal(k, shape, jnp.bfloat16) for k in keys]
+        pools, mp = make_pools(geom), geom.get("mp", 64)
         for rows in ROWS:
             for pages in PAGES:
                 bench_point(f"{gname} {rows}x{pages}", geom, (pages,) * rows,
-                            64, pools, impls, relisted=(rows, pages) == (4, 7))
-        bench_point(f"{gname} mixed", geom, MIXED_PAGES, 64, pools, impls)
+                            mp, pools, impls, relisted=(rows, pages) == (4, 7))
+        bench_point(f"{gname} mixed", geom, MIXED_PAGES, mp, pools, impls)
         bench_point(f"{gname} 4x7 MP8", geom, (7,) * 4, 8, pools, impls)
-        if gname == "mqa":
-            bench_point(f"{gname} cell 34 of 64", geom, CELL_PAGES, 64, pools,
-                        impls, relisted=True)
+        bench_cell_point(gname, geom, pools, impls)
+        del pools
+
+
+def bench_tiles_sweep(impls) -> None:
+    """The cell's decode step at 1, 2, 4 and 8 pages a grid step: the
+    kernel's rule (`step_tiles`) replaced for the sweep, the programs
+    traced again at each count."""
+    import math
+
+    from dynamo_tpu.ops import paged_attention as pa
+
+    if not hasattr(pa, "step_tiles"):
+        print("this checkout's kernel has no pages-a-step rule to sweep",
+              flush=True)
+        return
+    rule = pa.step_tiles
+    for gname, geom in GEOMETRIES.items():
+        pools = make_pools(geom)
+        for tiles in (1, 2, 4, 8):
+            pa.step_tiles = lambda nbytes, mp, t=tiles: math.gcd(mp, t)
+            jax.clear_caches()
+            bench_cell_point(gname, geom, pools, impls, f" tiles {tiles}")
+        pa.step_tiles = rule
+        jax.clear_caches()
         del pools
 
 
@@ -279,11 +343,11 @@ def bench_ragged_table() -> None:
     point: us a call and us a live (work unit, page) pair, with the
     share of the (NW, MP) grid that is live."""
     for gname, geom in GEOMETRIES.items():
+        if "Dv" in geom:  # the decode table's geometries
+            continue
         hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
         pool_pages = geom.get("pool_pages", POOL_PAGES)
-        shape = (LAYERS, pool_pages, PS, hk, d)
-        keys = jax.random.split(jax.random.key(0), 2)
-        pools = [jax.random.normal(k, shape, jnp.bfloat16) for k in keys]
+        pools = make_pools(geom)
         win = None if window is None else jnp.int32(window)
         for name, (decode_kv, chunk) in RAGGED_PLANS.items():
             rng = np.random.default_rng(0)
@@ -357,8 +421,10 @@ def main() -> None:
             del GEOMETRIES[name]
     if "--ragged" in args:
         bench_ragged_table()
-    if "--ragged-only" not in args:
-        impls = ("pallas", "jnp") if "--jnp" in args else ("pallas",)
+    impls = ("pallas", "jnp") if "--jnp" in args else ("pallas",)
+    if "--tiles-sweep" in args:
+        bench_tiles_sweep(impls)
+    elif "--ragged-only" not in args:
         bench_decode_table(impls)
 
 

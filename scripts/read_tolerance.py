@@ -121,7 +121,9 @@ async def main(args) -> int:
                 + (["no-sink"] if config.sink_window else []) + ["int8"])
     lo, hi = (int(x) for x in args.seeds.split(":"))
     out = open(args.out, "a") if args.out else None
+    params = None
     for seed in range(lo, hi):
+        params = None  # (frees the last seed's tree where --only left int8 out)
         params = serve.make_params(config, seed, dev, jnp.bfloat16)
         for variant in [v for v in variants if not args.only or v in args.only]:
             t0 = time.monotonic()

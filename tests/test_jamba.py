@@ -522,18 +522,35 @@ def test_every_active_sequence_owns_a_slot_and_gives_it_back():
 
 
 def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
-    """A static fact of a worker, in `runner ready` and /debug/device: one KV
-    head on the Pallas path is the one-head routine; the jnp gather has none."""
+    """A static fact of a worker, in `runner ready` and /debug/device, from the
+    one decision the kernel's wrapper makes: every dense pool that is not
+    `by_rows` is the tile routine (one KV head its one-head case); the jnp
+    gather has none; a model whose window layers keep a pool of their own
+    names a routine a kind (its window layers carry a sink)."""
     import logging
 
     kw = dict(num_pages=8, page_size=4, params=params, dtype=jnp.float32)
     with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.runner"):
         on_kernel = ModelRunner(C, attn_impl="pallas", **kw)
-    assert on_kernel.device_report()["decode_page_routine"] == "one_head"
-    assert "decode_page_routine=one_head" in caplog.text
+    assert on_kernel.device_report()["decode_page_routine"] == "by_tiles"
+    assert "decode_page_routine=by_tiles" in caplog.text
     assert ModelRunner(C, **kw).device_report()["decode_page_routine"] is None
     wide = ModelRunner(get_config("tiny"), num_pages=8, page_size=4, attn_impl="pallas")
-    assert wide.device_report()["decode_page_routine"] == "by_heads"
+    assert wide.device_report()["decode_page_routine"] == "by_tiles"
+    mha = get_config("tiny").with_(n_heads=8, n_kv_heads=8)
+    by_rows = ModelRunner(mha, num_pages=8, page_size=4, attn_impl="pallas",
+                          dtype=jnp.float32)
+    assert by_rows.device_report()["decode_page_routine"] == "by_rows"
+    int8 = ModelRunner(get_config("tiny"), num_pages=8, page_size=4,
+                       attn_impl="pallas", kv_quantize="int8")
+    assert int8.device_report()["decode_page_routine"] == "by_heads"
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.runner"):
+        two_kinds = ModelRunner(get_config("tiny-mimo"), num_pages=8, page_size=4,
+                                attn_impl="pallas")
+    assert two_kinds.device_report()["decode_page_routine"] == {
+        "global": "by_tiles", "window": "by_tiles"}
+    assert "decode_page_routine={'global': 'by_tiles', 'window': 'by_tiles'}" in caplog.text
 
 
 def test_a_pool_with_fewer_slots_than_rows_is_refused():

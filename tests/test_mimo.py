@@ -408,10 +408,16 @@ def _program_stream(c, tree, toks):
 
 
 @pytest.mark.parametrize("Hk,G,window,sinked", [
-    (4, 2, None, False), (8, 1, 16, True), (4, 2, 16, True), (8, 2, None, True)])
+    (4, 2, None, False), (8, 1, 16, True), (4, 2, 16, True), (8, 2, None, True),
+    # the cell's two kinds of layer (Hk 4, G 16 global; Hk 8, G 8 under the
+    # window with the sink), G 3, one KV head, and a traced window of 0:
+    # global at run time
+    (4, 16, None, False), (8, 8, 16, True), (2, 3, 16, True), (1, 4, 16, True),
+    (2, 16, 0, True)])
 def test_attention_kernels_with_two_head_sizes_and_a_sink(Hk, G, window, sinked):
     """decode, ragged and flash-prefill at dk 24 != dv 16, with and without
-    the sink, Hk 4 and 8, windowed and global, against paged_attention_jnp."""
+    the sink, Hk 1 to 8, windowed and global, against paged_attention_jnp.
+    The decode kernel takes the tile routine at every one of them."""
     rs = np.random.RandomState(Hk * 10 + G)
     dk, dv, NP, L, B = 24, 16, 40, 2, 3
     kp = jnp.asarray(rs.randn(L, NP, PS, Hk, dk), jnp.float32)
@@ -420,10 +426,9 @@ def test_attention_kernels_with_two_head_sizes_and_a_sink(Hk, G, window, sinked)
     w = None if window is None else jnp.int32(window)
     pt = jnp.asarray(rs.permutation(np.arange(1, NP))[:B * 6].reshape(B, 6), jnp.int32)
     kvl = jnp.asarray([37, 5, 0], jnp.int32)
-    # (the decode kernel takes the by_heads routine whatever the heads)
     q = jnp.asarray(rs.randn(B, Hk, G, dk), jnp.float32)
     got = decode_paged_attention(q, kp, vp, pt, kvl, w, jnp.int32(1), sink=sink,
-                                 name="window_attention_decode" if window else None,
+                                 name=None if window is None else "window_attention_decode",
                                  interpret=True)
     want = paged_attention_jnp(q[:, None], kp[1], vp[1], pt,
                                jnp.maximum(kvl - 1, 0)[:, None], kvl, window=w, sink=sink)[:, 0]

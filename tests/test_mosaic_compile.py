@@ -34,7 +34,7 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-# name -> B, Hk, G, D, L, NP, PS, MP, windowed, int8 KV
+# name -> B, Hk, G, D, L, NP, PS, MP, windowed, int8 KV[, Dv, a sink]
 _DECODE_SHAPES = {
     # the benchmark cell: MHA at D 96 (the by-rows routine), window live
     "phi3-b4": (4, 32, 1, 96, 32, 176, 64, 64, True, False),
@@ -53,50 +53,69 @@ _DECODE_SHAPES = {
     "jamba2-mqa-b64": (64, 1, 20, 128, 2, 2880, 64, 64, False, False),
     "mqa-window-ps16": (8, 1, 8, 128, 4, 512, 16, 256, True, False),
     "mqa-a-shard-b64": (64, 4, 20, 128, 2, 2880, 64, 64, False, False),
+    # mimo-v2-flash's two kinds of layer at its cell's pools under a page
+    # table 96 wide: keys 256 wide beside values of 128, a head at a time
+    # out of the token-major page; the window layers with their sink
+    "mimo-global-b16": (16, 4, 16, 256, 2, 4096, 64, 96, False, False, 128, False),
+    "mimo-window-b16": (16, 8, 8, 256, 9, 165, 64, 96, True, False, 128, True),
+    "mimo-window-b32": (32, 8, 8, 256, 9, 165, 64, 96, True, False, 128, True),
+    # head sizes that are no whole lane row, query heads that fill no
+    # sublane tile
+    "gqa-d64-g3": (8, 4, 3, 64, 4, 256, 16, 64, True, False),
+    "gqa-d96-g2": (8, 4, 2, 96, 4, 256, 64, 64, False, False),
 }
 
 
 def _operands(shape, spec_of):
-    B, Hk, G, D, L, NP, PS, MP, windowed, int8 = shape
+    """(q, k_pool, v_pool, page_table, kv_lens, window, layer, sink)."""
+    B, Hk, G, D, L, NP, PS, MP, windowed, int8, *more = shape
+    Dv, sinked = more or (D, False)
 
     def s(dims, dtype, kind):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=spec_of(kind))
 
-    pool = s((L, NP, PS, Hk, D), jnp.int8 if int8 else jnp.bfloat16, "pool")
-    if int8:
-        pool = {"q": pool, "s": s((L, NP, PS, Hk), jnp.float32, "scales")}
+    def pool(width):
+        data = s((L, NP, PS, Hk, width), jnp.int8 if int8 else jnp.bfloat16, "pool")
+        if int8:
+            return {"q": data, "s": s((L, NP, PS, Hk), jnp.float32, "scales")}
+        return data
+
     scalar = s((), jnp.int32, "rep")
-    return (s((B, Hk, G, D), jnp.bfloat16, "heads"), pool, pool,
+    return (s((B, Hk, G, D), jnp.bfloat16, "heads"), pool(D), pool(Dv),
             s((B, MP), jnp.int32, "rep"), s((B,), jnp.int32, "rep"),
-            scalar if windowed else None, scalar)
+            scalar if windowed else None, scalar,
+            s((Hk, G), jnp.float32, "sink") if sinked else None)
 
 
 @pytest.mark.parametrize("name", list(_DECODE_SHAPES))
 def test_decode_kernel_compiles_for_v5e(topo, name):
     one_chip = SingleDeviceSharding(topo.devices[0])
-    args = _operands(_DECODE_SHAPES[name], lambda kind: one_chip)
-    text = jax.jit(decode_paged_attention).lower(*args).compile().as_text()
+    *args, sink = _operands(_DECODE_SHAPES[name], lambda kind: one_chip)
+    text = jax.jit(decode_paged_attention).lower(*args, sink=sink).compile().as_text()
     assert text.count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("name", ["phi3-b4", "mistral-b32", "llama3.2-int8",
-                                  "mqa-a-shard-b64"])
+                                  "mqa-a-shard-b64", "mimo-global-b16",
+                                  "mimo-window-b16"])
 def test_sharded_decode_kernel_compiles_for_v5e_2x2(topo, name):
     """Heads over four chips: each shard walks the same list on its own
-    heads, and no collective appears."""
+    heads (mimo-v2-flash's global layers leave a shard one KV head, its
+    window layers two), and no collective appears."""
     from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
 
     mesh = Mesh(np.array(topo.devices).reshape(4), (AXIS_MODEL,))
     heads, pool, scales = attention_specs(AXIS_MODEL)
-    specs = {"heads": heads, "pool": pool, "scales": scales, "rep": P()}
-    q, k, v, pt, kl, window, layer = _operands(
+    specs = {"heads": heads, "pool": pool, "scales": scales, "rep": P(),
+             "sink": P(AXIS_MODEL, None)}
+    q, k, v, pt, kl, window, layer, sink = _operands(
         _DECODE_SHAPES[name], lambda kind: NamedSharding(mesh, specs[kind]))
 
-    def fn(q, k, v, pt, kl, window, layer):
+    def fn(q, k, v, pt, kl, window, layer, sink):
         return decode_paged_attention_sharded(
-            q, k, v, pt, kl, mesh, window=window, layer=layer)
+            q, k, v, pt, kl, mesh, window=window, layer=layer, sink=sink)
 
-    text = jax.jit(fn).lower(q, k, v, pt, kl, window, layer).compile().as_text()
+    text = jax.jit(fn).lower(q, k, v, pt, kl, window, layer, sink).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "all-reduce(" not in text and "all-gather(" not in text
 
@@ -352,3 +371,82 @@ def test_a_hybrid_models_step_programs_compile_for_v5e(topo, monkeypatch):
     assert kernels(text) == {"ragged_paged_attention", "ssm_scan"}
     assert not slab.search(text)
     assert not kv_copy.search(text)
+
+
+def test_a_window_pool_models_decode_loop_reads_both_pools_in_place(topo):
+    """mimo-v2-flash as its cell runs it: the decode loop (16 rows, 4 fused
+    steps, a page table 96 wide) over the cell's pools, 4096 global pages
+    and the engine's 165 window pages. A kernel a kind of layer beside the
+    expert kernel, each reading a KV head out of the token-major page as
+    the step program carries it: no copy and no slice of either pool's
+    shape, nor of a 4-d view of one, anywhere in the program. Handed the 4-d
+    view `[L, NP, PS, Hk * D]` instead (a head a lane-aligned slice of a
+    row), XLA lays the whole stack out again in front of the kernel (a
+    `reshape` that is no bitcast): the pools lie `T(4,128)(2,1)` /
+    `T(8,128)(2,1)`, heads on sublanes, which is how the check can see one."""
+    import json
+    import os
+    import re
+    from functools import partial
+
+    from dynamo_tpu.engine.model_runner import _decode_loop
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.models import llama, mimo
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", "mimo-v2-flash.json")) as f:
+        cfg = json.load(f)
+    c = ModelConfig(**cfg["model"])
+    NP, NPW, PS = cfg["server_flags"]["num-pages"], 165, cfg["server_flags"]["page-size"]
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(lambda: llama.make_kv_pool(c, NP, PS, dtype=jnp.bfloat16)))
+    state = on_chip(jax.eval_shape(lambda: mimo.make_window_pool(c, NPW, PS)))
+    assert pools[0].shape == (2, NP, PS, 4, 256) and pools[1].shape == (2, NP, PS, 4, 128)
+    assert state["k"].shape == (9, NPW, PS, 8, 256) and state["v"].shape == (9, NPW, PS, 8, 128)
+    B, MP, f32, i32 = 16, 96, jnp.float32, jnp.int32
+    samp = SamplingParams(s((B,), f32), s((B,), i32), s((B,), f32), s((B, 2), jnp.uint32),
+                          s((B,), f32), s((B,), f32), s((B,), f32))
+    text = jax.jit(partial(_decode_loop, c, "pallas", None, 4, -1), donate_argnums=(6, 7),
+                   donate_argnames=("state",)).lower(
+        params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None, *pools, samp,
+        state=state, slots=s((B, MP), i32)).compile().as_text()
+    kernels = {l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for l in text.splitlines() if "tpu_custom_call" in l and " = " in l}
+    assert kernels == {"decode_paged_attention", "window_attention_decode", "routed_experts"}
+    # a pool, one layer of it or a page run of it, 5-d or as a 4-d view
+    moved = re.compile(
+        rf"= bf16\[(\d+,)?(\d+,)?{PS},(4,(256|128)|8,(256|128)|1024|512|2048)\]\S* "
+        r"(copy|reshape|dynamic-slice|slice)\(")
+    assert [l.strip()[:160] for l in text.splitlines() if moved.search(l)] == []
+
+    def viewed(k, at):  # a kernel on the 4-d view: head 0 of one page
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        def head0(at_ref, k_ref, o_ref):
+            o_ref[...] = k_ref[:, :256]
+
+        out = pl.pallas_call(
+            head0,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec((None, None, PS, 4 * 256),
+                                       lambda i, at: (at[0], at[1], 0, 0))],
+                out_specs=pl.BlockSpec((PS, 256), lambda i, at: (0, 0))),
+            out_shape=jax.ShapeDtypeStruct((PS, 256), k.dtype),
+        )(at, k.reshape(k.shape[:3] + (-1,)))
+        return out, k
+
+    text = jax.jit(viewed, donate_argnums=(0,)).lower(
+        pools[0], s((2,), i32)).compile().as_text()
+    assert [l for l in text.splitlines() if moved.search(l)]

@@ -217,10 +217,12 @@ def test_decode_paged_attention_sharded_int8_kv():
 
 
 # -- the decode kernel's page walk (its grid is a work list of live pages) ---
-# name -> (Hk, G, D, PS, MP): G 1 with Hk filling bf16's 16-row tiles is
-# the by-rows routine (phi-3's MHA at D 96); Hk 1 the one-head routine
-# (ai21-jamba2-3b's 20 query heads on one KV head; a step brings 2 pages
-# of a table 6 wide, all 8 of one 8 wide); the rest the batched one
+# name -> (Hk, G, D, PS, MP[, Dv]): G 1 with Hk filling bf16's 16-row tiles
+# is the by-rows routine (phi-3's MHA at D 96); every other dense pool the
+# tile routine, a KV head at a time: ai21-jamba2-3b's 20 query heads on one
+# KV head (a step brings 2 pages of a table 6 wide, all 8 of one 8 wide),
+# GQA at 2 / 4 / 8 KV heads x 2 / 3 / 16 query heads on each, G 1 at half a
+# bf16 tile, values narrower than keys, a table 12 wide (4 pages a step)
 _WALK_GEOMS = {
     "mha-d96": (16, 1, 96, 16, 6),
     "gqa-g3": (2, 3, 128, 8, 6),
@@ -229,16 +231,36 @@ _WALK_GEOMS = {
     "mqa-g20": (1, 20, 128, 8, 6),
     "mqa-g8-d64": (1, 8, 64, 4, 8),
     "mqa-g1": (1, 1, 128, 8, 6),
+    "gqa-h4-g16": (4, 16, 128, 8, 6),
+    "gqa-h8-g2-mp12": (8, 2, 64, 8, 12),
+    "half-tile-g1": (8, 1, 128, 8, 6),
+    "two-widths-h2-g3": (2, 3, 128, 8, 6, 64),
+    "two-widths-h4-g16": (4, 16, 64, 8, 7, 32),
+    "two-widths-mha": (16, 1, 64, 8, 6, 32),
 }
-# name -> (window or None, softcap, int8 KV)
+# name -> (window or None, softcap, int8 KV, sink, pool dtype)
+_BF, _F32 = jnp.bfloat16, jnp.float32
 _WALK_VARIANTS = {
-    "plain": (None, 0.0, False),
-    "window-cuts-pages": ("pages", 0.0, False),
-    "window-mid-page": ("mid", 0.0, False),
-    "int8": (None, 0.0, True),
-    "softcap": (None, 30.0, False),
-    "int8-window-softcap": ("mid", 30.0, True),
+    "plain": (None, 0.0, False, False, _BF),
+    "window-cuts-pages": ("pages", 0.0, False, False, _BF),
+    "window-mid-page": ("mid", 0.0, False, False, _BF),
+    "int8": (None, 0.0, True, False, _BF),
+    "softcap": (None, 30.0, False, False, _BF),
+    "int8-window-softcap": ("mid", 30.0, True, False, _BF),
+    "sink": (None, 0.0, False, True, _BF),
+    "window-sink-f32": ("mid", 0.0, False, True, _F32),
+    "window-zero-is-global": ("zero", 0.0, False, False, _BF),
+    "softcap-sink-f32": (None, 30.0, False, True, _F32),
 }
+# the variants PR 41 added run on the geometries it added too, and on one of
+# each older routine
+_NEW_GEOMS = ("gqa-h4-g16", "gqa-h8-g2-mp12", "half-tile-g1",
+              "two-widths-h2-g3", "two-widths-h4-g16", "two-widths-mha")
+_NEW_VARIANTS = ("sink", "window-sink-f32", "window-zero-is-global",
+                 "softcap-sink-f32")
+_WALK_CASES = [(g, v) for g in _WALK_GEOMS for v in _WALK_VARIANTS
+               if v not in _NEW_VARIANTS
+               or g in _NEW_GEOMS + ("mha-d96", "gqa-g3", "mqa-g20")]
 
 
 def _walk_lens(PS, MP):
@@ -249,82 +271,93 @@ def _walk_lens(PS, MP):
 
 
 def _walk_case(geom, variant, seed=21, layers=None):
-    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
-    win, softcap, quant = _WALK_VARIANTS[variant]
+    """(q, kp, vp, pt, kv, window, kw): kw the static extras and the sink."""
+    Hk, G, D, PS, MP, *Dv = _WALK_GEOMS[geom]
+    Dv = Dv[0] if Dv else D
+    win, softcap, quant, sinked, dtype = _WALK_VARIANTS[variant]
     # "pages": lo lands on a page boundary for the longest row (leading
-    # pages dead, the first live page whole); "mid": inside a page
-    window = {None: None, "pages": PS * 2, "mid": PS * 2 + 3}[win]
+    # pages dead, the first live page whole); "mid": inside a page;
+    # "zero": a traced window of 0, global at run time
+    window = {None: None, "pages": PS * 2, "mid": PS * 2 + 3, "zero": 0}[win]
     rng = np.random.default_rng(seed)
     kv = _walk_lens(PS, MP)
     B = len(kv)
     NP = B * MP + 2
-    shape = (NP, PS, Hk, D) if layers is None else (layers, NP, PS, Hk, D)
-    q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
-    kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    shape = (NP, PS, Hk) if layers is None else (layers, NP, PS, Hk)
+    q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), dtype)
+    kp = jnp.asarray(rng.standard_normal(shape + (D,)), dtype)
+    vp = jnp.asarray(rng.standard_normal(shape + (Dv,)), dtype)
     pt = rng.permutation(NP - 2)[: B * MP].reshape(B, MP).astype(np.int32)
     if quant:
         kp, vp = _q_pools(kp, vp)
-    return q, kp, vp, pt, kv, window, softcap
+    kw = {"softcap": softcap}
+    if sinked:
+        kw["sink"] = jnp.asarray(rng.standard_normal((Hk, G)), jnp.float32)
+    return q, kp, vp, pt, kv, window, kw
 
 
-def _walk_ref(q, kp, vp, pt, kv, window, softcap):
+def _walk_ref(q, kp, vp, pt, kv, window, kw):
     return paged_attention_jnp(
         q[:, None], kp, vp, jnp.asarray(pt),
         jnp.asarray(np.maximum(kv - 1, 0))[:, None], jnp.asarray(kv),
-        softcap=softcap, window=None if window is None else jnp.int32(window),
+        window=None if window is None else jnp.int32(window), **kw,
     )[:, 0]
 
 
 def _walk_close(out, ref, kv):
     out = np.asarray(out, np.float32)
+    assert out.shape == ref.shape
     assert np.all(out[kv == 0] == 0.0)  # a pad row: defined, and zero
     d = np.abs(out - np.asarray(ref, np.float32))[kv > 0].max()
     assert d < 3e-2, d
 
 
-@pytest.mark.parametrize("variant", list(_WALK_VARIANTS))
-@pytest.mark.parametrize("geom", list(_WALK_GEOMS))
+@pytest.mark.parametrize("geom,variant", _WALK_CASES)
 def test_decode_walk_matches_reference(geom, variant):
-    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
+    q, kp, vp, pt, kv, window, kw = _walk_case(geom, variant)
     out = decode_paged_attention(
         q, kp, vp, jnp.asarray(pt), jnp.asarray(kv),
-        None if window is None else jnp.int32(window),
-        softcap=softcap, interpret=True,
+        None if window is None else jnp.int32(window), interpret=True, **kw,
     )
-    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, softcap), kv)
+    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, kw), kv)
 
 
 @pytest.mark.parametrize("layer", [0, 2])
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64",
+                                  "gqa-h4-g16", "two-widths-h2-g3"])
 def test_decode_walk_reads_stacked_pool_layer(geom, layer):
-    q, kp, vp, pt, kv, window, softcap = _walk_case(
+    q, kp, vp, pt, kv, window, kw = _walk_case(
         geom, "window-mid-page", layers=3)
     out = decode_paged_attention(
         q, kp, vp, jnp.asarray(pt), jnp.asarray(kv), jnp.int32(window),
         jnp.int32(layer), interpret=True,
     )
-    _walk_close(out, _walk_ref(q, kp[layer], vp[layer], pt, kv, window,
-                               softcap), kv)
+    _walk_close(out, _walk_ref(q, kp[layer], vp[layer], pt, kv, window, kw),
+                kv)
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
-@pytest.mark.parametrize("variant", ["window-mid-page", "int8"])
-@pytest.mark.parametrize("geom", ["gqa-g4", "gemma-ps16"])
+@pytest.mark.parametrize("geom,variant", [
+    ("gqa-g4", "window-mid-page"), ("gqa-g4", "int8"),
+    ("gemma-ps16", "window-mid-page"), ("gemma-ps16", "int8"),
+    ("gqa-h4-g16", "window-zero-is-global"), ("gqa-h8-g2-mp12", "softcap"),
+    ("two-widths-h4-g16", "window-mid-page"),
+    ("two-widths-h2-g3", "window-sink-f32"), ("gqa-h4-g16", "sink")])
 def test_decode_walk_sharded(geom, variant):
-    """Heads over two shards; gqa-g4's two KV heads leave each shard ONE, so
-    it takes the one-head routine on its local 4-d view (int8: by heads)."""
+    """Heads over two shards, each the tile routine on its local heads:
+    gqa-g4's two KV heads leave each shard ONE, on its local 4-d view (int8:
+    by heads)."""
     from dynamo_tpu.ops.paged_attention import decode_paged_attention_sharded
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
-    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
+    q, kp, vp, pt, kv, window, kw = _walk_case(geom, variant)
     out = decode_paged_attention_sharded(
         q, kp, vp, jnp.asarray(pt), jnp.asarray(kv),
         make_mesh(MeshConfig(model=2)),
         window=None if window is None else jnp.int32(window),
-        interpret=True,
+        interpret=True, **kw,
     )
-    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, softcap), kv)
+    _walk_close(out, _walk_ref(q, kp, vp, pt, kv, window, kw), kv)
 
 
 def _pallas_calls(jaxpr, found=None):
@@ -338,20 +371,33 @@ def _pallas_calls(jaxpr, found=None):
 
 
 def test_page_routine_follows_heads_and_dtype():
-    """The routine is a fact of (Hk, G, pool dtype): nothing else picks it."""
-    from dynamo_tpu.ops.paged_attention import one_head_tiles, page_routine
+    """The routine is a fact of (Hk, G, pool dtype, a sink, one width or
+    two): nothing else picks it, and one place decides."""
+    from dynamo_tpu.ops.paged_attention import page_routine, step_tiles
 
     bf, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
-    assert page_routine(32, 1, bf, False) == "by_rows"
-    assert page_routine(8, 1, f32, False) == "by_rows"
-    assert page_routine(8, 1, bf, False) == "by_heads"  # half a bf16 tile
-    assert page_routine(8, 4, bf, False) == "by_heads"
-    assert page_routine(1, 20, bf, False) == "one_head"
-    assert page_routine(1, 1, f32, False) == "one_head"
-    assert page_routine(1, 20, i8, True) == "by_heads"  # scales a (token, head)
-    assert page_routine(32, 1, i8, True) == "by_heads"
-    # a step's pages: a power of two up to 8 that tiles the page table
-    assert [one_head_tiles(mp) for mp in (64, 256, 12, 6, 7)] == [8, 8, 4, 2, 1]
+    assert page_routine(32, 1, bf, False, False, True) == "by_rows"
+    assert page_routine(8, 1, f32, False, False, True) == "by_rows"
+    assert page_routine(8, 1, bf, False, False, True) == "by_tiles"  # half a bf16 tile
+    assert page_routine(8, 4, bf, False, False, True) == "by_tiles"
+    assert page_routine(1, 20, bf, False, False, True) == "by_tiles"
+    assert page_routine(1, 1, f32, False, False, True) == "by_tiles"
+    assert page_routine(1, 20, i8, True, False, True) == "by_heads"  # scales a (token, head)
+    assert page_routine(32, 1, i8, True, False, True) == "by_heads"
+    # a sink, or values narrower than keys: by_rows has neither
+    assert page_routine(32, 1, bf, False, True, True) == "by_tiles"
+    assert page_routine(32, 1, bf, False, False, False) == "by_tiles"
+    assert page_routine(4, 16, bf, False, False, False) == "by_tiles"
+    assert page_routine(8, 8, bf, False, True, False) == "by_tiles"
+    assert page_routine(8, 8, i8, True, False, False) == "by_heads"
+    # a step's pages: a power of two up to 8 that tiles the page table and
+    # keeps the step's bytes in bounds. ai21-jamba2-3b's page (32 KB):
+    jamba = 2 * 64 * 128 * 2
+    assert [step_tiles(jamba, mp) for mp in (64, 256, 12, 6, 7)] == [8, 8, 4, 2, 1]
+    # mimo-v2-flash's global and window pages (196,608 and 393,216 B)
+    assert step_tiles(64 * 4 * (256 + 128) * 2, 96) == 4
+    assert step_tiles(64 * 8 * (256 + 128) * 2, 96) == 2
+    assert step_tiles(64 * 8 * (256 + 128) * 2, 7) == 1
 
 
 @pytest.mark.parametrize("kernel", ["decode", "ragged"])
@@ -393,19 +439,22 @@ def test_one_kv_head_operand_is_the_4d_view(kernel):
     assert pool_operands(2, False) == {(L, NP, PS, 2, D)}
 
 
-@pytest.mark.parametrize("variant", ["plain", "window-mid-page"])
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
+@pytest.mark.parametrize("geom,variant", [
+    (g, v) for g in ("mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64")
+    for v in ("plain", "window-mid-page")] + [
+    ("gqa-h8-g2-mp12", "window-mid-page"), ("two-widths-h4-g16", "sink"),
+    ("two-widths-mha", "window-sink-f32")])
 def test_decode_walk_reads_only_live_pages(geom, variant):
     """Every pool page outside the rows' live ranges holds NaN, and every
     page-table entry outside a row's live range names an unowned (NaN)
     page: the result does not change, so no such page was read."""
     from dynamo_tpu.ops.paged_attention import decode_work_list
 
-    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
-    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, variant)
+    Hk, G, D, PS, MP, *_ = _WALK_GEOMS[geom]
+    q, kp, vp, pt, kv, window, kw = _walk_case(geom, variant)
     win = None if window is None else jnp.int32(window)
     clean = decode_paged_attention(q, kp, vp, jnp.asarray(pt),
-                                   jnp.asarray(kv), win, interpret=True)
+                                   jnp.asarray(kv), win, interpret=True, **kw)
     work, n_work = decode_work_list(jnp.asarray(kv), win, PS, MP)
     live = np.zeros(pt.shape, bool)
     live.reshape(-1)[np.asarray(work)[: int(n_work)]] = True
@@ -417,7 +466,7 @@ def test_decode_walk_reads_only_live_pages(geom, variant):
     vp_n = vp.at[poison].set(jnp.nan)
     pt_n = np.where(live, pt, NP - 1).astype(np.int32)
     out = decode_paged_attention(q, kp_n, vp_n, jnp.asarray(pt_n),
-                                 jnp.asarray(kv), win, interpret=True)
+                                 jnp.asarray(kv), win, interpret=True, **kw)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(clean, np.float32))
 
@@ -459,15 +508,16 @@ def test_decode_walk_steps_follow_live_pages_not_table_width(window):
     assert grid_of(8)[1] == 1 and len(grid_of(8)[0]) == 1
 
 
-@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64"])
+@pytest.mark.parametrize("geom", ["mha-d96", "gqa-g3", "mqa-g20", "mqa-g8-d64",
+                                  "gqa-h8-g2-mp12", "two-widths-h4-g16"])
 def test_decode_walk_stays_inside_the_page_table(geom):
     """A length past MP * PS (nothing the engine sends) walks the table's
     MP pages like a full row: the list never outgrows its W entries and
     the row is still finalized."""
     from dynamo_tpu.ops.paged_attention import decode_work_list
 
-    Hk, G, D, PS, MP = _WALK_GEOMS[geom]
-    q, kp, vp, pt, kv, window, softcap = _walk_case(geom, "plain")
+    Hk, G, D, PS, MP, *_ = _WALK_GEOMS[geom]
+    q, kp, vp, pt, kv, window, kw = _walk_case(geom, "plain")
     over = np.where(kv == PS * MP, PS * MP + 5, kv).astype(np.int32)
     _, n_full = decode_work_list(jnp.asarray(kv), None, PS, MP)
     _, n_over = decode_work_list(jnp.asarray(over), None, PS, MP)
