@@ -1,6 +1,10 @@
 """The percentile, due-time and window arithmetic on hand-made samples, and
 the generators' promise that every seed replays the same trace."""
 
+import json
+import math
+import os
+
 import pytest
 
 import loadgen
@@ -8,6 +12,9 @@ import numpy as np
 
 from generators import open_loop
 from generators.common import drawn_gaps, drawn_lengths
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
 
 
 def test_percentile_interpolates_between_closest_ranks():
@@ -121,6 +128,78 @@ def test_a_traffic_mix_can_extend_another(tmp_path, monkeypatch):
     monkeypatch.setattr(run, "HERE", str(tmp_path))
     assert run.read_traffic("faster") == {"kind": "open_loop", "rate_rps": 2.5, "lead_in_s": 10}
     assert run.read_traffic("base")["rate_rps"] == 1.0
+
+
+def _mixes():
+    d = os.path.join(BENCH, "traffic")
+    return sorted(f[:-5] for f in os.listdir(d) if f.endswith(".json"))
+
+
+def _run_seconds():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)["run_seconds"]
+
+
+@pytest.mark.parametrize("mix", _mixes())
+def test_every_traffic_file_has_what_its_generator_reads_and_names_its_knee(mix):
+    """With `extends` laid over its base: the mix's own generator draws from the
+    file alone (a key it reads and does not find is a KeyError here), the rate is
+    positive, and the `_why` names the knee that rate is a share of."""
+    import run
+
+    t = run.read_traffic(mix)
+    assert float(t["rate_rps"]) > 0
+    assert run.load_generator(t["kind"]).generate(t, 7, _run_seconds(), 1000)["chains"]
+    assert "knee" in t["_why"], f"{mix}: the _why names no knee"
+
+
+def _clipped_mean(spec):
+    """Mean of a log-normal length clamped to [min, max]."""
+    mu, s = math.log(spec["median"]), float(spec["sigma"])
+    lo, hi = float(spec["min"]), float(spec["max"])
+    cdf = lambda z: 0.5 * (1.0 + math.erf(z / math.sqrt(2.0)))
+    za, zb = (math.log(lo) - mu) / s, (math.log(hi) - mu) / s
+    return lo * cdf(za) + math.exp(mu + s * s / 2) * (cdf(zb - s) - cdf(za - s)) + hi * (1.0 - cdf(zb))
+
+
+def window_stats(traffic, seconds, shape_seed):
+    """The window the mix's generator draws at `shape_seed` (any `--seed`: a seed
+    only swaps neighbours), and whether it is typical by chat-steady.json's rule:
+    arrivals per 5 s with variance / mean in 0.8-1.2 (the variance over the counts
+    as they are, in whole numbers so that 0.8 itself is inside), mean prompt within
+    5 % and mean output within 3 % of the clipped distribution's."""
+    import run
+
+    t = dict(traffic, shape_seed=shape_seed)
+    lead = float(t["lead_in_s"])
+    window = [c for c in run.load_generator(t["kind"]).generate(t, 0, seconds, 2)["chains"]
+              if c["due_s"] >= lead]
+    counts = np.bincount([int((c["due_s"] - lead) // 5.0) for c in window],
+                         minlength=math.ceil(seconds / 5.0)).tolist()
+    n, total = len(counts), sum(counts)
+    num, den = n * sum(c * c for c in counts) - total * total, n * total  # variance / mean = num / den
+    plens = [len(c["prefix_ids"]) + len(c["turns"][0]["user_ids"]) for c in window]
+    olens = [c["turns"][0]["max_tokens"] for c in window]
+    p_off = float(np.mean(plens)) / _clipped_mean(t["prompt_tokens"]) - 1.0
+    o_off = float(np.mean(olens)) / _clipped_mean(t["output_tokens"]) - 1.0
+    return {"requests": len(window), "counts_per_5s": counts, "dispersion": num / den,
+            "prompt_off": p_off, "output_off": o_off,
+            "prompt_tokens": sum(plens), "output_tokens": sum(olens),
+            "typical": 4 * den <= 5 * num <= 6 * den and abs(p_off) <= 0.05 and abs(o_off) <= 0.03}
+
+
+# the steady mixes that say they replay the FIRST typical window of shape_seed 1, 2, 3, ...
+# A mix that wants another realisation (a burst, a saturated cell) is not listed.
+FIRST_TYPICAL = ["chat-steady", "chat-steady-mistral4", "reasoning-steady-jamba2", "agent-steady-mimo2"]
+
+
+@pytest.mark.parametrize("mix", FIRST_TYPICAL)
+def test_a_steady_mix_replays_the_first_typical_window(mix):
+    import run
+
+    t = run.read_traffic(mix)
+    stats = [window_stats(t, _run_seconds(), s) for s in range(1, t["shape_seed"] + 1)]
+    assert [s["typical"] for s in stats] == [False] * (t["shape_seed"] - 1) + [True], (mix, stats[-1])
 
 
 # -- bytes a step moves, by architecture (costs.py) --------------------------

@@ -119,9 +119,11 @@ def test_the_benchmark_lists_the_cell_and_its_metrics():
         assert sorted(json.load(f)["reduced"]) == sorted(cfg["reduced"])
     assert os.path.exists(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
     per = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW:
-        assert per[name]["workloads"] == [CELL] and per[name]["moves"] == "tpot_p95_ms"
+    for name in NEW:  # by name: later cells append themselves to a list, later PRs to the table
+        assert CELL in per[name]["workloads"] and per[name]["moves"] == "tpot_p95_ms"
         assert os.path.exists(os.path.join(BENCH, "layers", name + ".py"))
-    assert [m["name"] for m in bench["per_layer"]][-4:] == NEW  # appended, nothing moved
-    older = [m for m in bench["per_layer"] if m["name"] not in NEW]
-    assert len(older) == 21 and not any("workloads" in m for m in older)  # on every cell's line
+    assert per["kernels.mla_decode_roofline_pct"]["workloads"] == [CELL]  # the one latent cell
+    # the metrics every cell's line carries are not narrowed to some cells
+    for name in ("client.ttft_mean_ms", "engine.decode_batch_mean", "kernels.attn_busy_pct",
+                 "device.idle_pct", "runner.compiles_in_window", "sched.preempted_pct"):
+        assert "workloads" not in per[name]

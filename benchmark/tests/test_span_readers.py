@@ -146,6 +146,26 @@ def test_decode_loop_of_the_dense_decoder_reads_as_before():
     assert reader("runner.decode_step_ms")({"trace": None, "model": {"n_layers": 32}}) is None
 
 
+def test_attention_busy_share_counts_the_attention_kernels_alone():
+    """A routed cell's trace: two attention kernels beside an expert kernel
+    that takes most of the Mosaic time. The share is the attention kernels'
+    over busy time, not `kernel_s` (every Mosaic call) over it."""
+    k = lambda calls, total_s: {"calls": calls, "total_s": total_s, "median_us": 1e6 * total_s / calls}
+    trace = {"busy_s": 2.0, "kernel_s": 1.0, "kernels": {
+        "decode_paged_attention": k(1000, 0.15), "ragged_paged_attention": k(10, 0.05),
+        "routed_experts": k(2000, 0.7), "ssm_update": k(100, 0.1)}}
+    read = reader("kernels.attn_busy_pct")
+    assert read({"trace": trace}) == pytest.approx(10.0)  # (0.15 + 0.05) / 2.0, not 1.0 / 2.0
+    latent = {"busy_s": 1.0, "kernels": {"decode_mla_attention": k(10, 0.25),
+                                         "routed_experts": k(10, 0.5)}}
+    assert read({"trace": latent}) == pytest.approx(25.0)
+    # nothing to read: no trace, no busy time, or a window with no attention call in it
+    assert read({"trace": None}) is None
+    assert read({"trace": dict(trace, busy_s=0.0)}) is None
+    assert read({"trace": {"busy_s": 1.0, "kernel_s": 0.5,
+                           "kernels": {"routed_experts": k(10, 0.5)}}}) is None
+
+
 # -- the weight stream of a decode step --------------------------------------
 
 

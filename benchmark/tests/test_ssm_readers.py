@@ -264,22 +264,20 @@ def test_the_configuration_states_the_published_keys_uncut():
 def test_the_benchmark_lists_the_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
-    assert cell["name"] == CELL and cell["chips"] == 1 and len(cell["why"]) <= 200
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert cell["chips"] == 1 and len(cell["why"]) <= 200
     assert cell["config"] == "ai21-jamba2-3b" and cell["traffic"] == "reasoning-steady-jamba2"
-    cfg = bench["configs"][-1]
-    assert cfg["name"] == cell["config"] and cfg["reduced"] == [] and len(cfg["why"]) <= 200
+    cfg = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert cfg["reduced"] == [] and len(cfg["why"]) <= 200
     assert os.path.exists(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
-    per = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-5:] == NEW  # appended, nothing moved
+    per = {m["name"]: m for m in bench["per_layer"]}  # by name: later PRs append
     for name in NEW:
-        assert per[name]["workloads"] == [CELL]
+        assert CELL in per[name]["workloads"]
         assert os.path.exists(os.path.join(BENCH, "layers", name + ".py"))
     # the two readers that count a step over every layer find none in this cell
-    older = ["phi3-chat-steady", "mistral4-chat-steady"]
-    assert per["runner.decode_step_ms"]["workloads"] == older
-    assert per["model.decode_stream_pct"]["workloads"] == older
-    # every other accepted metric without a list is read in this cell too
+    assert CELL not in per["runner.decode_step_ms"]["workloads"]
+    assert CELL not in per["model.decode_stream_pct"]["workloads"]
+    # every accepted metric without a list is read in this cell too
     unlisted = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
-    assert len(unlisted) == 19 and all(os.path.exists(
+    assert len(unlisted) >= 19 and all(os.path.exists(
         os.path.join(BENCH, "layers", n + ".py")) for n in unlisted)
