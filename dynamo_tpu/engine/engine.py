@@ -258,6 +258,8 @@ class InferenceEngine:
             self.fused_mixed = False
         else:
             self.fused_mixed = runner.platform != "cpu"
+        # a runner with no one-dispatch program for a mixed plan says so
+        self.fused_mixed = self.fused_mixed and runner.fuses_mixed
         # cross-worker KVBM onboarding: worker_common injects an async
         # callable(hint) -> payload that pulls blocks from a peer's
         # kv_host_fetch endpoint (None = feature off)
@@ -1649,6 +1651,8 @@ class InferenceEngine:
             decode_pages_live=rinfo.get("pages_live", 0) - (
                 rinfo.get("pages_step0", 0) if rinfo["ragged"] else 0),
             ragged_pages_live=rinfo.get("ragged_pages_live", 0),
+            dsa_ctx_tokens=rinfo.get("dsa_ctx", 0),
+            dsa_sel_tokens=rinfo.get("dsa_sel", 0),
             accepted_per_step=(
                 rinfo.get("spec_emitted", 0) / rinfo["spec_rows"]
                 if rinfo.get("spec_rows") else 0.0
@@ -1705,6 +1709,13 @@ class InferenceEngine:
         rinfo["pages_live"] = self._layer_mean(kinds)
         rinfo["pages_step0"] = self._layer_mean(step0)
         rinfo["pages_live_kinds"], rinfo["pages_step0_kinds"] = kinds, step0
+        # (a cost model's config may be no ModelConfig: no indexer then)
+        topk = getattr(self.runner.config, "index_topk", 0)
+        if topk:
+            # what one layer's selection saw and kept over the steps
+            ctx = [n for p in positions for n in range(p + 1, p + n_steps + 1)]
+            rinfo["dsa_ctx"] = sum(ctx)
+            rinfo["dsa_sel"] = sum(min(n, topk) for n in ctx)
 
     def _decode_pages_live_kinds(self, positions, n_steps: int):
         """IterationRecord.decode_pages_live by kind of layer, (on one of

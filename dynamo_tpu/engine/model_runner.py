@@ -33,6 +33,7 @@ from dynamo_tpu.engine.runner_api import (
     BucketOverflowError,
     MixedOut,
     Runner,
+    indexer_refusal,
     state_refusal,
     window_refusal,
 )
@@ -57,8 +58,11 @@ log = logging.getLogger("dynamo_tpu.engine.runner")
 # the program laid them out (`decode` [n_steps, L_moe, B, k], `chunks`
 # [L_moe, N, S, k], `flat` [L_moe, T, k]; int32 expert ids) and `load`,
 # the f32 [5] expert-load counters of its forwards over real tokens
-# (models/moe.routing_stats, summed over the forwards). A dense model's
-# programs return what they always did. See ModelRunner._note_routed.
+# (models/moe.routing_stats, summed over the forwards). Where the model has
+# an indexer, `chosen_decode` [n_steps, L, B, W] and `chosen_chunks`
+# [L, N, S, W] beside them: the tokens each query attended to, as bit words
+# (models/mla.pack_chosen). A dense model's programs return what they
+# always did. See ModelRunner._note_routed.
 
 
 def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
@@ -68,7 +72,7 @@ def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
     """The prefill step program of a routed model: llama.forward, with the
     picks of its chunk rows beside the pools. (A dense model's is
     llama.forward itself.)"""
-    logits, k_pool, v_pool, sel, listed = llama.forward(
+    logits, k_pool, v_pool, sel, listed, *chosen = llama.forward(
         config, params, tokens, positions, k_pool, v_pool, page_table,
         kv_lens, last_index, attn_impl=attn_impl, mesh=mesh,
         sp_has_prior=sp_has_prior, lora=lora, adapter_idx=adapter_idx,
@@ -77,7 +81,8 @@ def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
     )
     return logits, k_pool, v_pool, {
         "chunks": sel,
-        "load": _chunk_load(config, sel, positions >= 0, listed)}
+        "load": _chunk_load(config, sel, positions >= 0, listed),
+        **({"chosen_chunks": chosen[0]} if chosen else {})}
 
 
 def _forward_window(config: ModelConfig, params, tokens, positions, k_pool,
@@ -257,8 +262,8 @@ def _decode_loop(
             outs = (s,) + top_logprobs(raw, s, n_logprobs)
         if routed:
             picks = sel[0][:, :, 0]  # [L_moe, B, k]
-            outs = outs + (picks, routing_stats(
-                picks, positions0 >= 0, config, sel[1]))
+            outs = outs + tuple(c[:, :, 0] for c in sel[2:]) + (  # [L, B, W]
+                picks, routing_stats(picks, positions0 >= 0, config, sel[1]))
         if use_pen:
             r = jnp.arange(B, dtype=jnp.int32)
             cnt = cnt.at[r, s].add(1.0)
@@ -289,7 +294,8 @@ def _decode_loop(
     # token matrix caller-side would be an extra eager device program
     out = (toks.T, last, lp, k_pool, v_pool)  # [B, n_steps], [B]
     if routed:
-        out += ({"decode": ys[-2], "load": ys[-1].sum(0)},)
+        out += ({"decode": ys[-2], "load": ys[-1].sum(0),
+                 **({"chosen_decode": ys[-3]} if config.has_indexer else {})},)
     if hybrid:
         out += (carry[-1],)
     return out
@@ -663,6 +669,25 @@ class _RoutedPart:
         self.chunk_lens = tuple(chunk_lens)
 
 
+def _beside_picks(picks, chosen, cells: int) -> np.ndarray:
+    """What `routed_picks()` hands out for a model with an indexer: the
+    picks int32 [L_moe, ..., k] and, below them along the first axis, the
+    tokens each query of each of the L layers attended to (`chosen` int32
+    [L, ..., W], as models/mla.pack_chosen laid them out on the device):
+    [L_moe + L x R, ..., k], a layer's set as R rows of k int32 words in
+    token order (token s is bit s % 32 of word s // 32, little-endian;
+    R x k x 32 >= cells, zeros behind)."""
+    from dynamo_tpu.models.mla import unpack_chosen
+
+    k = picks.shape[-1]
+    by = np.packbits(unpack_chosen(chosen, cells), axis=-1, bitorder="little")
+    by = np.pad(by, [(0, 0)] * (by.ndim - 1) + [(0, -by.shape[-1] % (4 * k))])
+    rows = np.ascontiguousarray(by).view("<i4")  # [L, ..., R * k]
+    rows = np.moveaxis(rows.reshape(rows.shape[:-1] + (-1, k)), -2, 1)
+    return np.concatenate(
+        [np.asarray(picks), rows.reshape((-1,) + rows.shape[2:])], axis=0)
+
+
 def _device_get_with_loads(parts, x=None):
     """jax.device_get(x) and, in the same call, of the expert-load
     counters of `parts` that no readback has brought yet."""
@@ -986,6 +1011,25 @@ class ModelRunner(Runner):
                     "quantized KV cache"))
         # either: the step programs take and return a second pool (`state`)
         self._side_pool = self.holds_state or self.holds_window_pool
+        # a model with an indexer (models/mla.py): the pool's second array
+        # holds its index keys under the latent pages' own page table, so
+        # pages are copied, exported, imported and offloaded as the pair
+        # with nothing added here. Refused: what would read or write the
+        # pair in a form the selection was never run on. It has no fused
+        # mixed program: chunks ride beside the decoding rows as two
+        # dispatches (Runner.fuses_mixed)
+        self.fuses_mixed = not config.has_indexer
+        if config.has_indexer:
+            if kv_quantize:
+                raise NotImplementedError(indexer_refusal(
+                    config.name, "a quantized KV cache (--kv-quantize)"))
+            if self.mesh_config.n_devices > 1:
+                raise NotImplementedError(indexer_refusal(
+                    config.name,
+                    f"a mesh of several devices ({self.mesh_config.shape})"))
+            if draft_config is not None:
+                raise NotImplementedError(indexer_refusal(
+                    config.name, "speculative decoding with a draft model"))
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -1580,8 +1624,11 @@ class ModelRunner(Runner):
             host = self._readback([p.picks for p in parts])
         dec: List[np.ndarray] = []
         chunks: List[np.ndarray] = []
+        cells = self.max_pages_per_seq * self.page_size
         for p, h in zip(parts, host):
             p.picks = None
+            # an indexer's choices ride below the picks (_beside_picks)
+            chosen_c, chosen_d = h.get("chosen_chunks"), h.get("chosen_decode")
             if "flat" in h:  # [L_moe, T, k]: decode rows, then the chunks
                 if p.n_dec:
                     dec.append(np.asarray(h["flat"][None, :, : p.n_dec]))
@@ -1591,9 +1638,17 @@ class ModelRunner(Runner):
                     off += n
             if "chunks" in h:  # [L_moe, N, S, k]: one padded row a chunk
                 for i, n in enumerate(p.chunk_lens):
-                    chunks.append(np.asarray(h["chunks"][:, i, :n]))
+                    picks = np.asarray(h["chunks"][:, i, :n])
+                    if chosen_c is not None:
+                        picks = _beside_picks(picks, chosen_c[:, i, :n], cells)
+                    chunks.append(picks)
             if "decode" in h:  # [n_steps, L_moe, B, k]
-                dec.append(np.asarray(h["decode"][:, :, : p.n_dec]))
+                picks = np.asarray(h["decode"][:, :, : p.n_dec])
+                if chosen_d is not None:  # the layers lead in _beside_picks
+                    picks = np.moveaxis(_beside_picks(
+                        np.moveaxis(picks, 1, 0),
+                        np.moveaxis(chosen_d[:, :, : p.n_dec], 1, 0), cells), 0, 1)
+                dec.append(picks)
         return (np.concatenate(dec) if dec else None), chunks
 
     def take_moe_load(self) -> MoeLoad:
@@ -1889,7 +1944,8 @@ class ModelRunner(Runner):
 
     def can_fuse(self, n_decode: int, n_chunks: int, *,
                  constrained: bool) -> bool:
-        if self.pp or self.sp_enabled or self.has_draft:
+        if (self.pp or self.sp_enabled or self.has_draft
+                or not self.fuses_mixed):
             # SP runners prefill with ring attention on the full mesh —
             # the fused program's plain attn_impl would miscompute the
             # chunk's KV there

@@ -67,6 +67,19 @@ def window_refusal(model_name: str, what: str) -> str:
             "moved, forked or rolled back by pages")
 
 
+def indexer_refusal(model_name: str, what: str) -> str:
+    """Why a worker whose model attends to its indexer's choice of tokens
+    (DeepSeek-V3.2: `ModelConfig.has_indexer`) does not do `what`: the one
+    sentence every such refusal raises. Its pool is two arrays under one
+    page table, the latent pages and the index keys; what moves, copies,
+    exports or offloads pages by their ids carries both and is not refused."""
+    return (f"{what} is not built for a model with an indexer "
+            f"({model_name}): its pool holds the latent pages and the index "
+            "keys side by side under one page table, the selection reads "
+            "both as they were written, and this path was never run on the "
+            "pair")
+
+
 def device_step(fn):
     """Marks a Runner method that enqueues device work, or changes state
     that device work depends on (the pools, the weights, a compile
@@ -97,6 +110,10 @@ class Runner:
     routed = False  # steps hand out expert picks and load counters
     kv_quantize: Optional[str] = None  # "int8": quantized device KV pools
     ragged_mixed = False  # mixed plans ride the flat-token program
+    fuses_mixed = True  # the runner has a one-dispatch program for a mixed
+    #   plan at all (ragged or padded); False: the engine co-schedules
+    #   chunks with the decoding rows as two dispatches on every platform,
+    #   and nobody walks or compiles a fused lattice for it
     static_shapes = False  # step shapes are compiled per bucket: a live
     #   retune may not grow past what construction registered
     spec_seg_budget = 0  # rows one verify dispatch can sample (0 = no cap)

@@ -252,6 +252,17 @@ def _load_mla(config: ModelConfig, tensors, get, get_f32,
     dn, dr, dv, dc = (c.qk_nope_head_dim, c.qk_rope_head_dim,
                       c.v_head_dim, c.kv_lora_rank)
     rp = _rope_deinterleave(dr)
+    read: set = set()  # names this load asked for (a v3.2 load accounts
+    #   for every tensor of the checkpoint: _account_for_every_tensor)
+    _get, _get_f32 = get, get_f32
+
+    def get(name: str, transpose: bool = False) -> np.ndarray:
+        read.add(name)
+        return _get(name, transpose)
+
+    def get_f32(name: str) -> np.ndarray:
+        read.add(name)
+        return _get_f32(name)
 
     def attn_rows(i: int) -> Dict[str, Any]:
         pre = f"model.layers.{i}."
@@ -279,6 +290,16 @@ def _load_mla(config: ModelConfig, tensors, get, get_f32,
             row["wq_up"] = fix_q(get(pre + "self_attn.q_b_proj.weight", True))
         else:
             row["wq"] = fix_q(get(pre + "self_attn.q_proj.weight", True))
+        if c.has_indexer:
+            # deepseek_v32's lightning indexer. Its rotary is published
+            # non-interleaved on a head's first dims, which is this
+            # program's half-rotation layout: the columns stay as they are
+            ix = pre + "self_attn.indexer."
+            row["wi_q"] = get(ix + "wq_b.weight", True)
+            row["wi_k"] = get(ix + "wk.weight", True)
+            row["wi_w"] = get(ix + "weights_proj.weight", True)
+            row["ik_norm"] = get_f32(ix + "k_norm.weight")
+            row["ik_norm_b"] = get_f32(ix + "k_norm.bias")
         return row
 
     def dense_rows(i: int) -> Dict[str, Any]:
@@ -332,8 +353,52 @@ def _load_mla(config: ModelConfig, tensors, get, get_f32,
         )
     if "lm_head.weight" in tensors and not c.tie_embeddings:
         params["lm_head"] = get("lm_head.weight", True)
+    if c.has_indexer:
+        _account_for_every_tensor(c, tensors, read)
     log.info("loaded DeepSeek MLA checkpoint %s", checkpoint_dir)
     return params
+
+
+def _account_for_every_tensor(c: ModelConfig, tensors, read: set) -> None:
+    """A deepseek_v32 load leaves no tensor of the checkpoint unexplained:
+    what it did not read is either a layer past the model's own (layer 61 of
+    the published checkpoint, the multi-token-prediction module, which is a
+    drafter and changes no logit: skipped, in words), a routed expert this
+    chip does not hold, or a tensor this loader has no name for, which it
+    refuses (an FP8 checkpoint's scales, a renamed indexer matrix: loading
+    around them would serve another model in silence)."""
+    import re
+
+    held = set(held_experts(c))
+    skipped, unknown = set(), []
+    for name in sorted(set(tensors) - read):
+        if name.startswith("language_model.") and name[15:] in read:
+            continue  # a wrapper's prefixed name, read under its alias
+        m = re.match(r"(?:language_model\.)?model\.layers\.(\d+)\.(.*)", name)
+        if m and int(m.group(1)) >= c.n_layers:
+            skipped.add(int(m.group(1)))
+            continue
+        e = re.match(r"mlp\.experts\.(\d+)\.", m.group(2)) if m else None
+        if e and int(e.group(1)) not in held:
+            continue
+        if name == "lm_head.weight" and c.tie_embeddings:
+            continue
+        unknown.append(name)
+    if unknown:
+        raise ValueError(
+            f"the checkpoint holds {len(unknown)} tensors this loader has no "
+            f"name for, the first {unknown[0]!r}: a deepseek_v32 layer is "
+            "input_layernorm, post_attention_layernorm, self_attn.{q_a_proj, "
+            "q_a_layernorm, q_b_proj, kv_a_proj_with_mqa, kv_a_layernorm, "
+            "kv_b_proj, o_proj}, self_attn.indexer.{wq_b, wk, k_norm, "
+            "weights_proj} and its mlp; nothing is loaded around a tensor "
+            "that is not one of them (FP8 scales need a dequantized "
+            "checkpoint)")
+    for i in sorted(skipped):
+        log.info(
+            "layer %d of the checkpoint is past the model's %d layers and is "
+            "skipped: the multi-token-prediction module is a drafter, it "
+            "changes no logit of the model and is not built", i, c.n_layers)
 
 
 def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConfig:
@@ -392,9 +457,15 @@ def config_from_hf(checkpoint_dir: str, name: Optional[str] = None) -> ModelConf
                 attn_qscale_beta=float(rp["llama_4_scaling_beta"]),
                 attn_qscale_orig=int(rp["original_max_position_embeddings"]),
             )
+        index_kw = {}
+        if cfg.get("index_topk"):  # deepseek_v32: the lightning indexer
+            index_kw = dict(index_topk=int(cfg["index_topk"]),
+                            index_n_heads=int(cfg["index_n_heads"]),
+                            index_head_dim=int(cfg["index_head_dim"]))
         return ModelConfig(
             **rope_kw,
             **qscale_kw,
+            **index_kw,
             n_expert_groups=int(cfg.get("n_group") or 0),
             topk_groups=int(cfg.get("topk_group") or 0),
             name=name or cfg.get("_name_or_path", "deepseek-hf"),

@@ -165,6 +165,16 @@ class ModelConfig:
     # 1 + beta * ln(1 + floor(p / orig)); exactly 1 below `orig`. 0 = off
     attn_qscale_beta: float = 0.0
     attn_qscale_orig: int = 0
+    # DeepSeek-V3.2's lightning indexer (models/mla.py): every layer scores
+    # the cached tokens for each query with `index_n_heads` small heads of
+    # `index_head_dim` against ONE cached index key a token, and latent
+    # attention's softmax runs over the `index_topk` best of them alone. The
+    # index keys live in the pool's second array, under the latent pages'
+    # own page table. 0 = no indexer: every preset but the dsa ones. A
+    # context of at most `index_topk` tokens is attended to whole.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
     # Mamba-1 selective state-space layers (Jamba; models/jamba.py): layer
     # l is attention iff l % attn_layer_period == attn_layer_offset, and a
     # Mamba mixer of inner width mamba_expand x dim otherwise. 0 states = no
@@ -246,6 +256,18 @@ class ModelConfig:
                 "attn_qscale_beta is latent attention's query scale and "
                 "needs attn_type='mla' and attn_qscale_orig > 0"
             )
+        if self.index_topk or self.index_n_heads or self.index_head_dim:
+            if not (self.is_mla and self.q_lora_rank > 0
+                    and self.index_topk > 0 and self.index_n_heads > 0
+                    and self.index_head_dim >= self.qk_rope_head_dim > 0
+                    and self.index_head_dim % 2 == 0):
+                raise ValueError(
+                    "index_topk, index_n_heads and index_head_dim are latent "
+                    "attention's indexer and come together: they need "
+                    "attn_type='mla', q_lora_rank > 0 (the index queries are "
+                    "projected from the compressed query) and an even "
+                    "index_head_dim of at least qk_rope_head_dim (the rotary "
+                    "turns a head's first qk_rope_head_dim dims)")
         if self.is_hybrid:
             if not (0 <= self.attn_layer_offset < self.attn_layer_period
                     and self.mamba_dt_rank > 0):
@@ -341,6 +363,28 @@ class ModelConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def mla_pool_dim(self) -> int:
+        """The width a token takes in the latent pool. A model with an
+        indexer states it in whole 128-lane rows where it spans more than
+        one (576 -> 640, zeros behind the rotary key), as `key_pool_dim`
+        does and for its reason: the device pads a row to that anyway, and
+        a pool of 576-wide rows and thousands of pages got the PAGE axis
+        minor, converted at the entry and the exit of every step program
+        (0.4 s a dispatch at 4096 pages: PERF.md section 6, PR 44). Every
+        other model's pool stays `mla_cache_dim` wide, as it was: the cause
+        is the width and the page count, not the indexer, so `deepseek-v3`
+        at thousands of pages is knowingly left on the slow layout until a
+        PR of its own may move `mistral-small-4-119b`'s step programs (the
+        rule then: `mla_cache_dim > 128` and not a whole number of rows)."""
+        d = self.mla_cache_dim
+        return -(-d // 128) * 128 if self.has_indexer and d > 128 else d
+
+    @property
+    def has_indexer(self) -> bool:
+        """Latent attention over the indexer's top `index_topk` tokens."""
+        return self.index_topk > 0
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
@@ -412,6 +456,21 @@ PRESETS: Dict[str, ModelConfig] = {
         n_experts=4, n_experts_active=2, moe_ffn_dim=96,
         n_shared_experts=1, moe_scoring="sigmoid",
         moe_router_bias=True, moe_routed_scale=2.5, n_dense_layers=1,
+    ),
+    # DeepSeek-V3.2's structure at test size (CPU CI): the tiny-mla-moe
+    # block with a compressed query, the lightning indexer over it (4 heads
+    # of 16, the 8 best tokens attended to), one leading dense layer, 16
+    # experts in 4 groups of which 2 stay, the second quarter of them held
+    "tiny-dsa": ModelConfig(
+        name="tiny-dsa", n_layers=3, attn_type="mla", kv_lora_rank=32,
+        q_lora_rank=48, qk_rope_head_dim=16, qk_nope_head_dim=32,
+        v_head_dim=32, index_topk=8, index_n_heads=4, index_head_dim=16,
+        n_experts=16, n_experts_active=4, moe_ffn_dim=64,
+        n_shared_experts=1, moe_scoring="sigmoid", moe_router_bias=True,
+        moe_routed_scale=2.5, n_dense_layers=1, n_expert_groups=4,
+        topk_groups=2, n_experts_held=4, expert_first=4, norm_eps=1e-6,
+        rope_theta=10000.0, rope_scaling="yarn", rope_factor=40.0,
+        rope_orig_max_seq=8, rope_mscale=1.0, rope_mscale_all_dim=1.0,
     ),
     # Llama 3.2 1B (fits one v5e chip in bf16 with room for KV)
     "llama-3.2-1b": ModelConfig(
@@ -540,6 +599,25 @@ PRESETS: Dict[str, ModelConfig] = {
         rope_beta_fast=32.0,
         rope_beta_slow=1.0,
         rope_mscale=1.0,
+        rope_mscale_all_dim=1.0,
+    ),
+    # DeepSeek-V3.2 (685B-A37B): the V3 block with the lightning indexer in
+    # every layer (64 heads of 128 on the compressed query, one 128-wide
+    # index key a token) and latent attention over its 2048 best tokens.
+    # The multi-token-prediction module (layer 61) is left out
+    # (benchmark/configs/deepseek-v3.2.json holds one chip's share)
+    "deepseek-v3.2": ModelConfig(
+        name="deepseek-v3.2", vocab_size=129280, dim=7168, n_layers=61,
+        n_heads=128, n_kv_heads=128, ffn_dim=18432, max_seq_len=163840,
+        rope_theta=10000.0, norm_eps=1e-6, attn_type="mla",
+        kv_lora_rank=512, q_lora_rank=1536, qk_rope_head_dim=64,
+        qk_nope_head_dim=128, v_head_dim=128, index_topk=2048,
+        index_n_heads=64, index_head_dim=128, n_experts=256,
+        n_experts_active=8, moe_ffn_dim=2048, n_shared_experts=1,
+        moe_scoring="sigmoid", moe_router_bias=True, moe_routed_scale=2.5,
+        n_dense_layers=3, n_expert_groups=8, topk_groups=4,
+        rope_scaling="yarn", rope_factor=40.0, rope_orig_max_seq=4096,
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
         rope_mscale_all_dim=1.0,
     ),
     # Granite 3.1 8B (Llama layout + the four Granite scalar

@@ -149,6 +149,17 @@ def _init_layer_stack(config: ModelConfig, key: jax.Array, L: int,
                                 c.n_heads * (dn + dr))
         else:
             attn_p["wq"] = w(k[1], c.dim, L, c.dim, c.n_heads * (dn + dr))
+        if c.has_indexer:
+            # the lightning indexer (models/mla.py): index queries from
+            # the compressed query, one index key a token and a weight a
+            # head from the normed hidden state, a LayerNorm on the key
+            ki = jax.random.split(jax.random.fold_in(key, 44), 3)
+            hi, di = c.index_n_heads, c.index_head_dim
+            attn_p["wi_q"] = w(ki[0], c.q_lora_rank, L, c.q_lora_rank, hi * di)
+            attn_p["wi_k"] = w(ki[1], c.dim, L, c.dim, di)
+            attn_p["wi_w"] = w(ki[2], c.dim, L, c.dim, hi)
+            attn_p["ik_norm"] = jnp.ones((L, di), jnp.float32)
+            attn_p["ik_norm_b"] = jnp.zeros((L, di), jnp.float32)
     else:
         attn_p = {
             "wq": w(k[1], c.dim, L, c.dim, c.n_heads * hd),
@@ -267,7 +278,9 @@ def forward(
     output and no second pass. With `return_listed` a fifth follows:
     int32 [L_moe], the entries of each expert layer's work list of hit
     experts as its kernel was given it (models/moe.py; 0 in a forward
-    that took the dense path).
+    that took the dense path). A model with an indexer (`c.has_indexer`)
+    adds one more, last: int32 [L, B, S, W], the tokens each query of each
+    layer attended to as bit words (models/mla.py `pack_chosen`).
     """
     c = config
     B, S = tokens.shape
@@ -398,12 +411,16 @@ def forward(
             stack = moe_stack + (l_idx - c.n_dense_layers,)
         return _moe_block(c, lp, x, mesh, real_rows, stack)
 
-    def routed_ys(routed):
-        """What an expert layer hands the scan: its picks (+ what its
-        work list held), if the caller asked."""
-        if not return_routed or routed is None:
+    def routed_ys(routed, chosen=None):
+        """What a layer hands the scan, if the caller asked: an expert
+        layer its picks (+ what its work list held); a layer with an
+        indexer (models/mla.py) also the tokens it attended to."""
+        if not return_routed:
             return None
-        return tuple(routed) if return_listed else routed[0]
+        ys = None
+        if routed is not None:
+            ys = tuple(routed) if return_listed else routed[0]
+        return ys if chosen is None else (ys, chosen)
 
     def make_layer(use_moe):
         def layer(carry, xs):
@@ -431,10 +448,10 @@ def forward(
         if c.is_mla:
             # _mla_attention names its own parts (attn.proj / absorb /
             # kernel / lift), matching the GQA path below
-            attn, k_pool = _mla_attention(
+            attn, k_pool, v_pool, chosen = _mla_attention(
                 c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
                 kv_lens, attn_impl=attn_impl, mesh=mesh,
-                q_start=q_start, q_len=q_len,
+                q_start=q_start, q_len=q_len, ik_pool=v_pool,
             )
             with jax.named_scope("attn.proj"):
                 h = h + mm(attn, lp["wo"])
@@ -446,7 +463,7 @@ def forward(
                 else:
                     gate = jax.nn.silu(mm(x, lp["w_gate"]))
                     h = h + mm(gate * mm(x, lp["w_up"]), lp["w_down"])
-            return (h, k_pool, v_pool), routed_ys(routed)
+            return (h, k_pool, v_pool), routed_ys(routed, chosen)
 
         zc = c.norm_zero_centered
         with jax.named_scope("attn.proj"):
@@ -695,7 +712,7 @@ def forward(
                 "LoRA is not supported with n_dense_layers models"
             )
         kD = c.n_dense_layers
-        (h, k_pool, v_pool), _ = lax.scan(
+        (h, k_pool, v_pool), first = lax.scan(
             make_layer(False),
             (h, k_pool, v_pool),
             (dense_stack, {}, jnp.arange(kD, dtype=jnp.int32)),
@@ -706,6 +723,8 @@ def forward(
             (scan_layers, {},
              jnp.arange(kD, c.n_layers, dtype=jnp.int32)),
         )
+        if return_routed and c.has_indexer:  # (picks, chosen) a layer
+            routed, chosen = routed[0], jnp.concatenate([first[1], routed[1]])
     else:
         (h, k_pool, v_pool), routed = lax.scan(
             make_layer(c.is_moe),
@@ -743,6 +762,11 @@ def forward(
         if c.final_logit_softcap:
             cap = c.final_logit_softcap
             logits = cap * jnp.tanh(logits / cap)
+    if return_routed and c.has_indexer:  # after the picks: [L, B, S, W]
+        if dense_stack is None:
+            routed, chosen = routed
+        routed = (tuple(routed) if return_listed else (routed,)) + (chosen,)
+        return (logits, k_pool, v_pool) + routed
     if return_listed:
         return (logits, k_pool, v_pool) + routed  # + [L_moe]
     if return_routed:

@@ -49,10 +49,22 @@ def make_kv_pool(
     its full-head equivalent). The "k" pool holds the latent; the "v"
     pool shrinks to a 1-wide placeholder so every page-indexed code path
     (transfer, tiering, disagg export) keeps its uniform k/v shape
-    contract without meaningful memory."""
+    contract without meaningful memory. A model with an indexer
+    (DeepSeek-V3.2) caches its index keys there instead of the stub."""
     if config.is_mla:
-        lat = (config.n_layers, num_pages, page_size, 1, config.mla_cache_dim)
+        lat = (config.n_layers, num_pages, page_size, 1, config.mla_pool_dim)
         stub = (config.n_layers, num_pages, page_size, 1, 1)
+        if config.has_indexer:
+            # the indexer's keys take the second array: one index_head_dim
+            # vector a token beside its latent, under the same page table
+            # and the same page ids, so whatever shares, copies, exports or
+            # offloads a page by its id carries both (models/mla.py)
+            if kv_quantize is not None:
+                raise ValueError(
+                    "a model with an indexer keeps its latent pages and its "
+                    "index keys unquantized (engine/runner_api.py "
+                    "indexer_refusal)")
+            stub = stub[:-1] + (config.index_head_dim,)
         if kv_quantize == "int8":
             # int8 latent cache: one f32 scale per (token) latent vector —
             # halves V3's already-57x-smaller cache again. The Pallas MLA

@@ -272,8 +272,15 @@ def prefill_mla_attention(
     # heads at rank 512: 16 x 128; 16 heads at rank 512: 128 x 16), and at
     # 32 heads and rank 256 (Mistral-Small-4) the acc cap alone allows 4096,
     # which Mosaic refuses (19.7 MB of scoped VMEM against 16).
+    # At 128 heads and rank 512 with a rotary key of 64 (DeepSeek-V3.2, the
+    # cached vector 576 wide in 640 lanes) 2048 rows are 16.6 MB against 16:
+    # the block's rows times the f32 query and acc they each cost stay
+    # under 6 MiB (1365 rows there, 8 x 128 after the divisor walk below;
+    # 2457 at rank 256, so the 2048 above still binds there).
+    lanes = -(-Dl // 128) * 128
     q_block = min(q_block, max(8, (4 << 20) // max(H * dc * 4, 1)),
-                  max(8, 2048 // H))
+                  max(8, 2048 // H),
+                  max(8, (6 << 20) // ((lanes + dc) * 4 * H)))
     q_block = min(q_block, S)
     while S % q_block:
         q_block -= 1

@@ -142,6 +142,15 @@ class IterationRecord:
     work. Beside it `decode_seqs x decode_steps` gives rows, and the
     kernel's device seconds over it the cost of a live page.
 
+    `dsa_ctx_tokens` / `dsa_sel_tokens` [tokens] are a model with an
+    indexer's (ModelConfig.has_indexer; 0 for every other): over the
+    iteration's decode rows and fused steps, the cached tokens ONE layer's
+    indexer scored (a row's context, position + t + 1 at step t) and the
+    tokens its attention then read, min(context, index_topk). Their ratio
+    is the share of the cached context a decode step attends to; times the
+    layers they are the index keys read and the latent rows gathered. From
+    the positions the engine holds on the host, like `decode_pages_live`.
+
     `ragged_pages_live` [pairs] is the ragged (mixed-step) kernel's unit
     of work: the live (work unit, page) pairs one layer's call walked,
     0 where no ragged program ran. A work unit is the rows of one 8-row
@@ -178,6 +187,8 @@ class IterationRecord:
     compile_variants: int  # cumulative compiled jit variants (all families)
     decode_pages_live: int = 0  # live KV pages walked (see the docstring)
     ragged_pages_live: int = 0  # live (unit, page) pairs of a ragged step
+    dsa_ctx_tokens: int = 0  # an indexer's model: tokens one layer's
+    dsa_sel_tokens: int = 0  # selection scored, and kept (docstring)
     anomaly: bool = False  # this iteration fired the EWMA trigger
     ahead: bool = False    # enqueued before the one before was read back
     drain: str = ""        # why not (the closed set above); "" where ahead

@@ -6,7 +6,9 @@ model with state-space layers, the program with the recurrent state `S` kept
 in bfloat16 (a pool this script lays under the runner: the program has no
 such option), for a model with sinks on its window layers the program with
 the sink left out (`no-sink`: the same tree served under a configuration
-whose `sink_window` is off). Chip only (like serve.py it refuses a CPU unless --rehearse).
+whose `sink_window` is off); for a model with an indexer the mechanism's own
+two (`recent`: the selection replaced by the most recent `index_topk` tokens;
+`no-selection`: every cached token attended to). Chip only (like serve.py it refuses a CPU unless --rehearse).
 
     python scripts/read_tolerance.py --config benchmark/configs/<name>.json \
         --seeds 3600000300:3600000312 [--tolerance 0.1] [--out chiprun_out/tol.jsonl]
@@ -20,6 +22,7 @@ the line then carries `need_max` and `inadmissible`, the larger of the passes.
 
 import argparse
 import asyncio
+import contextlib
 import importlib.util
 import json
 import os
@@ -50,6 +53,8 @@ def _engine(config, dev, wargs, mpps, params, variant: str):
 
     if variant == "no-sink":
         config = config.with_(sink_window=False)
+    if variant == "no-selection":  # every cached token attended to
+        config = config.with_(index_topk=config.max_seq_len)
     runner = ModelRunner(
         config, None, devices=[dev], num_pages=wargs.num_pages,
         page_size=wargs.page_size, max_pages_per_seq=mpps, params=params,
@@ -62,6 +67,52 @@ def _engine(config, dev, wargs, mpps, params, variant: str):
         runner.state = jax.device_put(jamba.make_state_pool(
             config, runner.state_slots, jnp.bfloat16, runner.dtype), dev)
     return engine
+
+
+@contextlib.contextmanager
+def _recent_tokens_selected(on: bool):
+    """The `recent` control of a model with an indexer: while its programs
+    are traced (the engine's first steps), the index score of a cached token
+    is its position, so the selection keeps the most recent index_topk
+    tokens, a sliding window: what a careless change could leave behind."""
+    from dynamo_tpu.models import mla
+
+    if not on:
+        yield
+        return
+    import jax.numpy as jnp
+
+    sound = mla.index_scores
+    mla.index_scores = lambda qi, wi, keys: jnp.broadcast_to(
+        jnp.arange(keys.shape[1], dtype=jnp.float32),
+        qi.shape[:2] + (keys.shape[1],))
+    try:
+        yield
+    finally:
+        mla.index_scores = sound
+
+
+class _NeedsByKind:
+    """The reference, noting on its way the largest need of each kind that
+    `follow_at` hands the check in one array: the expert layers' picks first
+    (`n_moe` columns), then, where the reference follows a served selection
+    too, the layers' selections."""
+
+    def __init__(self, ref, n_moe: int):
+        self._ref, self._n_moe = ref, n_moe
+        self.largest = {}
+
+    def __getattr__(self, name):
+        return getattr(self._ref, name)
+
+    def follow_at(self, model, params, seq, at, picks):
+        logp, need = self._ref.follow_at(model, params, seq, at, picks)
+        for kind, part in (("picks", need[:, : self._n_moe]),
+                           ("selection", need[:, self._n_moe:])):
+            if part.size:
+                self.largest[kind] = max(self.largest.get(kind, 0.0),
+                                         float(min(part.max(), 1e9)))
+        return logp, need
 
 
 def _is_quantized(params) -> bool:
@@ -110,23 +161,29 @@ async def main(args) -> int:
     if dev.platform != "tpu" and not args.rehearse:
         print("no TPU: a tolerance is read on the chip (--rehearse debugs this script)")
         return 3
-    ref = serve.load_reference(cfg)
     config = ModelConfig(**model)
+    ref = _NeedsByKind(serve.load_reference(cfg), config.n_layers - config.n_dense_layers)
     wargs = worker.parse_args(
         [x for k, v in flags.items() for x in (f"--{k}", str(v))]
         + ["--tokenizer", "byte", "--model-name", config.name])
     mpps = -(-wargs.max_seq_len // wargs.page_size)
     # (int8 last: where the tree is large it consumes the seed's params)
     variants = (["sound"] + (["state-bf16"] if config.is_hybrid else [])
-                + (["no-sink"] if config.sink_window else []) + ["int8"])
+                + (["no-sink"] if config.sink_window else [])
+                + (["recent", "no-selection"] if config.has_indexer else [])
+                + ["int8"])
     lo, hi = (int(x) for x in args.seeds.split(":"))
+    upto = {v: int(n) for v, n in (x.split("=") for x in args.upto)}
     out = open(args.out, "a") if args.out else None
     params = None
     for seed in range(lo, hi):
         params = None  # (frees the last seed's tree where --only left int8 out)
         params = serve.make_params(config, seed, dev, jnp.bfloat16)
         for variant in [v for v in variants if not args.only or v in args.only]:
+            if seed >= upto.get(variant, hi):
+                continue
             t0 = time.monotonic()
+            ref.largest = {}
             served, ref_tree = params, params if variant == "int8" else None
             if variant == "int8" and args.offload:
                 served, ref_tree = _int8_beside_a_host_copy(params)
@@ -134,9 +191,10 @@ async def main(args) -> int:
             engine = await asyncio.to_thread(  # off the event loop
                 _engine, config, dev, wargs, mpps, served, variant)
             del served
-            res = await serve.reference_check(
-                ref, model, engine, seed, args.tolerance, args.rehearse, args.margin,
-                params=ref_tree)
+            with _recent_tokens_selected(variant == "recent"):
+                res = await serve.reference_check(
+                    ref, model, engine, seed, args.tolerance, args.rehearse, args.margin,
+                    params=ref_tree)
             del ref_tree
             engine.stop()
             row = {"seed": seed, "variant": variant, "ok": res["ok"],
@@ -152,6 +210,8 @@ async def main(args) -> int:
                 row["need_max"] = max(res[n]["need_max"] for n in ("logprobs", "ragged"))
                 row["inadmissible"] = max(res[n]["inadmissible"] for n in ("logprobs", "ragged"))
                 row["picks_differ"] = max(res[n].get("picks_differ", 0) for n in ("logprobs", "ragged"))
+                row["need_by_kind"] = dict(ref.largest)
+                row["margin"] = args.margin
             line = json.dumps(row)
             print(line, flush=True)
             if out:
@@ -171,6 +231,8 @@ if __name__ == "__main__":
                    help="int8: keep the reference's layer stack on the host "
                         "(a tree that does not fit the chip beside its int8 copy)")
     p.add_argument("--only", nargs="*", default=None)
+    p.add_argument("--upto", nargs="*", default=[], metavar="VARIANT=SEED",
+                   help="a variant only for the seeds below this one")
     p.add_argument("--out", default=None)
     p.add_argument("--rehearse", action="store_true")
     rc = asyncio.run(main(p.parse_args()))

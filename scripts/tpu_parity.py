@@ -25,6 +25,13 @@ Each of these reads a layer-STACKED pool [L, NP, PS, Hk, D] at a nonzero
 traced layer, as the model's layer scan does; every layer holds other
 data, so a kernel that indexed the wrong layer misses the reference.
 
+DeepSeek-V3.2 (`dsa`): one layer of models/mla.py at published widths with its
+indexer: a decode step of 1 / 8 / 32 rows on 1 k / 4 k / 32 k cached tokens
+against the jnp path of the same layer, the compiled indexer and top-k against
+numpy's stable argsort of the chip's own scores (ties planted), a prefill chunk
+on a prior context against float32 attention under the same selection as a
+mask. `--time-dsa` prints the device time of the selecting arm's parts instead.
+
 Also run: Gemma geometry (G 2, PS 16) decode/prefill softcap+window on one
 layer's pool [NP, PS, Hk, D] (the kernels' rank-4 view), MLA
 decode/prefill, MLA int8-latent decode (gates DYN_MLA_INT8_KERNEL), and
@@ -498,6 +505,180 @@ def check_mimo_prefill(kind: str) -> float:
     return _max_err(out, ref)
 
 
+# DeepSeek-V3.2's layer at published widths (the cell dsv32-docqa-steady): one
+# layer of models/mla.py with its indexer, the selection and the attention over
+# the selected rows, on pools of 64-token pages at a nonzero layer.
+def _dsa_layer(rows: int, S: int, ctx: int, seed: int = 44):
+    """(config, layer weights, hidden states, pools, page table, positions,
+    kv_lens): `rows` sequences of `ctx` cached tokens whose last S are this
+    step's, the pools drawn (every layer other data), index keys of unit size."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import get_config
+
+    c = get_config("deepseek-v3.2").with_(n_layers=2, n_dense_layers=0, n_experts=8,
+                                          n_expert_groups=0, topk_groups=0)
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    # the attention's leaves alone, drawn as init_params draws them (norms 1,
+    # the LayerNorm's bias 0): the layer's experts are no part of the check
+    shapes = jax.eval_shape(lambda: llama.init_params(c, k[0]))["layers"]
+    lp = {}
+    for i, (n, sd) in enumerate(sorted(shapes.items())):
+        if n.startswith(("we_", "ws_", "w_router", "router_bias")):
+            continue
+        if sd.ndim == 2:  # [L, d]: a norm's weight, or the LayerNorm's bias
+            lp[n] = jnp.full(sd.shape[1:], 0.0 if n == "ik_norm_b" else 1.0, sd.dtype)
+        else:
+            lp[n] = (jax.random.normal(jax.random.fold_in(k[0], i), sd.shape[1:], jnp.float32)
+                     * sd.shape[-2] ** -0.5).astype(sd.dtype)
+    PS, MP = 64, -(-ctx // 64)
+    NP = rows * MP + 1
+    kp = jax.random.normal(k[1], (2, NP, PS, 1, c.mla_pool_dim), jnp.bfloat16)
+    ip = jax.random.normal(k[2], (2, NP, PS, 1, c.index_head_dim), jnp.bfloat16)
+    h = jax.random.normal(k[3], (rows, S, c.dim), jnp.bfloat16)
+    pt = jnp.asarray(1 + np.random.default_rng(seed).permutation(rows * MP)
+                     .reshape(rows, MP).astype(np.int32))
+    pos = jnp.broadcast_to(jnp.arange(ctx - S, ctx, dtype=jnp.int32), (rows, S))
+    return c, lp, h, kp, ip, pt, pos, jnp.full((rows,), ctx, jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _dsa_layer_fn(impl, c):
+    from dynamo_tpu.models.mla import _mla_attention
+
+    return jax.jit(lambda lp, h, kp, ip, pt, pos, kv: _mla_attention(
+        c, lp, h, kp, jnp.int32(1), pt, pos, pos, kv, attn_impl=impl,
+        q_start=pos[:, 0], q_len=jnp.full(pos.shape[:1], pos.shape[1], jnp.int32),
+        ik_pool=ip)[0])
+
+
+def _dsa_run(impl, c, lp, h, kp, ip, pt, pos, kv):
+    return _dsa_layer_fn(impl, c)(lp, h, kp, ip, pt, pos, kv)
+
+
+def check_dsa_decode(rows: int, ctx: int) -> float:
+    """A decode step of `rows` rows on `ctx` cached tokens: the chip's path
+    (the latent kernel on the pool at or below index_topk, on the gathered
+    buffer above it) against the jnp path of the same layer."""
+    args = _dsa_layer(rows, 1, ctx)
+    return _max_err(_dsa_run("jnp" if INTERPRET else "pallas", *args),
+                    _dsa_run("jnp", *args))
+
+
+def check_dsa_selection(rows: int, ctx: int) -> float:
+    """The compiled indexer and top-k on the chip: the selected SET of every
+    row is numpy's stable argsort of the chip's own scores (ties towards the
+    lower position), and the scores are the float32 ones to bf16's rounding.
+    Returns the share of selected positions that differ plus the scores'
+    largest relative error."""
+    from dynamo_tpu.models import mla
+
+    c, lp, h, kp, ip, pt, pos, kv = _dsa_layer(rows, 1, ctx)
+    k = jax.random.split(jax.random.PRNGKey(3), 2)
+    hi, di = c.index_n_heads, c.index_head_dim
+    qi = jax.random.normal(k[0], (rows, 1, hi, di), jnp.bfloat16)
+    wi = jax.random.normal(k[1], (rows, 1, hi), jnp.float32) * (hi * di) ** -0.5
+    keys = ip[1, pt].reshape(rows, -1, di)
+    keys = keys.at[:, 5::7].set(keys[:, 4::7][:, : keys[:, 5::7].shape[1]])  # ties
+    scores = jax.jit(mla.index_scores)(qi, wi, keys)[:, 0]
+    K = min(c.index_topk, scores.shape[-1])
+    got = np.sort(np.asarray(jax.jit(lambda x: mla.select_topk(x, K))(scores)), -1)
+    host = np.asarray(scores)
+    want = np.sort(np.argsort(-host, axis=-1, kind="stable")[:, :K], -1)
+    ref = np.einsum("bhc,bh->bc", np.maximum(np.einsum(
+        "bhd,bcd->bhc", np.asarray(qi[:, 0], np.float32), np.asarray(keys, np.float32)), 0),
+        np.asarray(wi[:, 0]))
+    rel = float(np.abs(host - ref).max() / np.abs(ref).max())
+    return float((got != want).mean()) + rel
+
+
+def check_dsa_prefill(S: int, ctx: int) -> float:
+    """A chunk of S tokens ending at `ctx`: the layer's selecting arm (XLA
+    ops: the selection as a mask by a radix select, attention a block of
+    queries by a block of pages at a time with a running softmax) against
+    float32 attention in one piece under the sorted selection as a mask."""
+    from dynamo_tpu.models import mla
+    from dynamo_tpu.models.toolkit import attn_score_scale
+
+    c, lp, h, kp, ip, pt, pos, kv = _dsa_layer(1, S, ctx)
+    k = jax.random.split(jax.random.PRNGKey(5), 4)
+    H, dc, Dl = c.n_heads, c.kv_lora_rank, c.mla_cache_dim
+    hi, di = c.index_n_heads, c.index_head_dim
+    q_abs = jax.random.normal(k[0], (1, S, H, dc), jnp.bfloat16) * dc ** -0.5
+    q_r = jax.random.normal(k[1], (1, S, H, Dl - dc), jnp.bfloat16) * dc ** -0.5
+    qi = jax.random.normal(k[2], (1, S, hi, di), jnp.bfloat16)
+    wi = jax.random.normal(k[3], (1, S, hi), jnp.float32)
+    scale = attn_score_scale(c, c.qk_nope_head_dim + c.qk_rope_head_dim)
+    out = jax.jit(lambda *a: mla._selected_attention(
+        c, *a, attn_impl="pallas", dc=dc, scale=scale))(
+        kp, ip, jnp.int32(1), q_abs, q_r, qi, wi, pt, pos, kv)
+
+    def masked(kp, ip, q_abs, q_r, qi, wi, pt, pos):
+        keys = ip[1, pt].reshape(1, -1, di)
+        lat = kp[1, pt].reshape(-1, c.mla_pool_dim)[:, :Dl].astype(jnp.float32)
+        sc = mla.index_scores(qi, wi, keys)[0]
+        live = jnp.arange(sc.shape[-1])[None, :] <= pos[0][:, None]
+        idx = mla.select_topk(jnp.where(live, sc, -jnp.inf), c.index_topk)
+        mask = jnp.zeros(sc.shape, bool).at[jnp.arange(S)[:, None], idx].set(True) & live
+        q = jnp.concatenate([q_abs, q_r], -1)[0].astype(jnp.float32)
+        outs = []
+        for h0 in range(0, H, 16):  # heads in blocks: [S, 16, C] f32 at a time
+            s_ = jnp.einsum("shd,cd->shc", q[:, h0:h0 + 16], lat) * scale
+            p = jax.nn.softmax(jnp.where(mask[:, None], s_, -jnp.inf), axis=-1)
+            outs.append(jnp.einsum("shc,cd->shd", p, lat[:, :dc]))
+        return jnp.concatenate(outs, axis=1)[None]
+
+    return _max_err(out, jax.jit(masked)(kp, ip, q_abs, q_r, qi, wi, pt, pos))
+
+
+def time_dsa() -> None:
+    """`--time-dsa` (chip only): device time of the selecting arm's parts at
+    the cell's sizes, one JSON line a point, by the host clock around
+    block_until_ready, the median of five calls."""
+    from dynamo_tpu.models import mla
+    from dynamo_tpu.ops.mla_attention import decode_mla_attention
+
+    def med(fn, *a):
+        jax.block_until_ready(fn(*a))
+        ts = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*a))
+            ts.append((time.perf_counter() - t0) * 1e3)
+        return round(sorted(ts)[2], 3)
+
+    for rows, S, ctx in [(8, 1, 32768), (16, 1, 24576), (32, 1, 32768),
+                         (1, 256, 24576), (1, 1024, 4096), (1, 1024, 16384), (1, 1024, 32768)]:
+        c, lp, h, kp, ip, pt, pos, kv = _dsa_layer(rows, S, ctx)
+        hi, di, Dl, dc = c.index_n_heads, c.index_head_dim, c.mla_pool_dim, c.kv_lora_rank
+        k = jax.random.split(jax.random.PRNGKey(1), 3)
+        qi = jax.random.normal(k[0], (rows, S, hi, di), jnp.bfloat16)
+        wi = jax.random.normal(k[1], (rows, S, hi), jnp.float32)
+        gather_keys = jax.jit(lambda ip, pt: ip[1, pt].reshape(rows, -1, di))
+        keys = gather_keys(ip, pt)
+        scores = jax.jit(mla.index_scores)(qi, wi, keys)
+        K = c.index_topk
+        idx = jax.jit(lambda x: mla.select_topk(x, K))(scores)
+        row = {"rows": rows, "S": S, "ctx": ctx,
+               "key_gather_ms": med(gather_keys, ip, pt),
+               "index_scores_ms": med(jax.jit(mla.index_scores), qi, wi, keys),
+               "top_k_ms": med(jax.jit(lambda x: mla.select_topk(x, K)), scores),
+               "topk_mask_ms": med(jax.jit(lambda x: mla.topk_mask(x, K)), scores)}
+        if S == 1:
+            lat_flat = kp.reshape(2, -1, Dl)
+            gather = jax.jit(lambda lf, i: lf[1, i[:, 0]].reshape(rows * K // 64, 64, 1, Dl))
+            sel = gather(lat_flat, idx)
+            q = jax.random.normal(k[2], (rows, c.n_heads, Dl), jnp.bfloat16)
+            own = jnp.arange(rows * K // 64, dtype=jnp.int32).reshape(rows, -1)
+            n = jnp.full((rows,), K, jnp.int32)
+            row["latent_gather_ms"] = med(gather, lat_flat, idx)
+            row["kernel_on_selected_ms"] = med(
+                lambda *a: decode_mla_attention(*a, dc=dc, scale=0.1352), q, sel, own, n)
+            row["kernel_on_whole_context_ms"] = med(
+                lambda *a: decode_mla_attention(*a, dc=dc, scale=0.1352), q, kp[1], pt, kv)
+        row["layer_ms"] = med(lambda *a: _dsa_run("pallas", c, *a), lp, h, kp, ip, pt, pos, kv)
+        print(json.dumps(row), flush=True)
+
+
 def check_block_copy() -> float:
     from dynamo_tpu.ops.block_copy import gather_pages, scatter_pages
 
@@ -619,6 +800,19 @@ def all_checks():
             (f"mimo prefill {kind} @mimo-v2-flash",
              functools.partial(check_mimo_prefill, kind)),
         ]
+    for rows, ctx in ((1, 1024), (8, 4096), (32, 4096), (1, 32768), (8, 32768), (32, 32768)):
+        checks.append((f"dsa decode {rows} rows ctx {ctx} @deepseek-v3.2",
+                       functools.partial(check_dsa_decode, rows, ctx)))
+    checks += [
+        ("dsa selection 8 rows ctx 4096 @deepseek-v3.2",
+         functools.partial(check_dsa_selection, 8, 4096)),
+        ("dsa selection 32 rows ctx 32768 @deepseek-v3.2",
+         functools.partial(check_dsa_selection, 32, 32768)),
+        ("dsa prefill 256 on ctx 4096 @deepseek-v3.2",
+         functools.partial(check_dsa_prefill, 256, 4096)),
+        ("dsa prefill 256 on ctx 32768 @deepseek-v3.2",
+         functools.partial(check_dsa_prefill, 256, 32768)),
+    ]
     for dt in ("float32", "bfloat16"):
         checks += [
             (f"ssm_update {dt} state @ai21-jamba2-3b",
@@ -648,6 +842,9 @@ def main(argv=None) -> int:
         print("FAIL: no accelerator backend (this gate checks compiled "
               "Mosaic; a CPU run proves nothing)", flush=True)
         return 2
+    if "--time-dsa" in argv:
+        time_dsa()
+        return 0
     only = argv[argv.index("--only") + 1] if "--only" in argv else ""
     results = []
     for name, fn in [c for c in all_checks() if only in c[0]]:

@@ -122,6 +122,78 @@ def test_warm_lattice_meets_every_program_traffic_reaches(monkeypatch, preset):
     assert after["other"] == before["other"]
 
 
+def test_warm_lattice_of_a_model_without_a_fused_mixed_program(monkeypatch):
+    """tiny-dsa (latent attention over an indexer's selection): the runner
+    says it has no one-dispatch program for a mixed plan, so with mixed-prefill
+    tokens stated, and fusing asked for, the walk compiles decode and prefill
+    buckets alone, and every plan the scheduler forms (chunks co-scheduled with
+    decoding rows as two dispatches, contexts on both sides of index_topk)
+    finds its programs compiled."""
+    monkeypatch.setenv("DYN_FUSED_MIXED", "1")
+    args = worker.parse_args([
+        "--model", "tiny-dsa", "--max-batch", "4", "--chunk-size", "16",
+        "--mixed-prefill-tokens", "12", "--mixed-prefill-seqs", "1",
+        "--mixed-min-chunk", "4",
+    ])
+    runner = ModelRunner(
+        get_config("tiny-dsa"), num_pages=96, page_size=4, max_pages_per_seq=16,
+        decode_buckets=(2, 4), prefill_buckets=(8, 16), seed=7)
+    engine, _ = worker.build_engine(args, runner=runner)
+    engine.scheduler.decode_steps = 2
+    assert not runner.fuses_mixed and not engine.fused_mixed
+
+    warm = _serve_module().warm_lattice(engine)
+    assert warm["compile"]["mixed"]["variants"] == 0, warm
+    assert warm["compile"]["ragged"]["variants"] == 0, warm
+    assert warm["compile"]["decode_loop"]["variants"] == 4, warm  # 2 buckets x 2 steps
+
+    arrivals = {
+        0: [_seq("a", range(1, 21), 64)],  # 20 tokens: past index_topk 8
+        4: [_seq("b", range(3, 8), 2)],    # 5 tokens: at most index_topk
+        5: [_seq("c", range(5, 19), 64)],
+        6: [_seq("d", range(2, 8), 64), _seq("e", range(4, 9), 64)],
+    }
+    kinds = []
+    step_plan = engine.scheduler.step_plan
+
+    def recording():
+        plan = step_plan()
+        if isinstance(plan, PrefillPlan):
+            kinds.append(("prefill", plan.start_pos > 0))
+        elif isinstance(plan, MixedPlan):
+            kinds.append(("mixed", len(plan.prefills), plan.decode.n_steps))
+        elif isinstance(plan, DecodePlan):
+            kinds.append(("decode", plan.n_steps))
+        return plan
+
+    engine.scheduler.step_plan = recording
+    out = {}
+
+    def drive():
+        runner.name_step_thread()
+        out["before"] = runner.compile_stats()
+        for it in range(12):
+            for seq in arrivals.get(it, ()):
+                engine._inbox.put(("add", seq))
+            engine._loop_once()
+        out["after"] = runner.compile_stats()
+
+    t = threading.Thread(target=drive)
+    t.start()
+    t.join(timeout=180)
+    assert not t.is_alive()
+
+    assert ("prefill", False) in kinds and ("prefill", True) in kinds, kinds
+    assert ("decode", 2) in kinds and any(k[0] == "mixed" for k in kinds), kinds
+    before, after = out["before"], out["after"]
+    assert after["mixed"]["calls"] == after["ragged"]["calls"] == 0
+    assert after["forward"]["calls"] > before["forward"]["calls"]
+    for fam in after:
+        assert after[fam]["variants"] == before[fam]["variants"], (
+            fam, before[fam], after[fam], kinds)
+    assert after["other"] == before["other"]
+
+
 def test_the_doors_phases_are_the_spans_the_reduction_owns_gaps_by():
     """The step thread's phases (runtime/annotations.py, the door) open the
     spans `benchmark/layers/_idle.CHILDREN` names, and the iteration record

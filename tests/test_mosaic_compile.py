@@ -157,6 +157,82 @@ def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
     assert text.count("tpu_custom_call") == 1
 
 
+# -- latent attention at DeepSeek-V3.2's geometry (benchmark cell
+# dsv32-docqa-steady): 128 heads, latent rank 512 + a rotary key of 64 (576
+# in 640 lanes), 4096 pages of 64 tokens under a page table 576 wide. The
+# dense kernels (a step whose rows all hold at most index_topk tokens), and
+# the layer itself with its indexer, selection and gather on a prior context
+# of 36 k: a decode step of 32 rows and a prefill chunk of 1024.
+_DSA = dict(H=128, Dl=640, dc=512, NP=4096, PS=64, MP=576)
+
+
+@pytest.mark.parametrize("kind, n", [("decode", 32), ("prefill", 256), ("prefill", 1024)])
+def test_latent_attention_kernels_compile_for_v5e_at_rank_512(topo, kind, n):
+    from dynamo_tpu.ops.mla_attention import decode_mla_attention, prefill_mla_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    H, Dl, dc, NP, PS, MP = (_DSA[k] for k in ("H", "Dl", "dc", "NP", "PS", "MP"))
+    kw = dict(dc=dc, scale=0.1352)
+    pool = s((NP, PS, 1, Dl), jnp.bfloat16)
+    if kind == "decode":
+        fn = lambda q, l, pt, kl: decode_mla_attention(q, l, pt, kl, **kw)
+        args = (s((n, H, Dl), jnp.bfloat16), pool, s((n, MP), jnp.int32), s((n,), jnp.int32))
+    else:
+        fn = lambda q, l, pt, qs, ql, kl: prefill_mla_attention(q, l, pt, qs, ql, kl, **kw)
+        one = s((1,), jnp.int32)
+        args = (s((1, n, H, Dl), jnp.bfloat16), pool, s((1, MP), jnp.int32), one, one, one)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("B, S", [(32, 1), (1, 1024)])
+def test_the_indexers_layer_compiles_for_v5e_at_published_widths(topo, B, S):
+    """One layer of the cell's configuration through models/mla.py on the
+    chip's path: both arms of the step (dense at or below index_topk, the
+    selection above it) in one program. A decode step makes one Mosaic call an
+    arm, the latent attention kernel on the pool or on the gathered buffer; a
+    prefill chunk's selecting arm is XLA ops (a mask, blocks of queries by
+    blocks of pages under a running softmax) and makes none."""
+    import json
+    import os
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.mla import _mla_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", "deepseek-v3.2.json")) as f:
+        c = ModelConfig(**json.load(f)["model"]).with_(n_layers=2)
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    lp = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a[0], llama.init_params(c, jax.random.PRNGKey(0))["layers"])))
+    kp, ip = on_chip(jax.eval_shape(lambda: llama.make_kv_pool(c, _DSA["NP"], _DSA["PS"])))
+
+    def layer(lp, h, kp, ip, pt, pos, kv):
+        safe = jnp.maximum(pos, 0)
+        return _mla_attention(c, lp, h, kp, jnp.int32(1), pt, pos, safe, kv,
+                              attn_impl="pallas", q_start=safe[:, 0],
+                              q_len=jnp.sum(pos >= 0, axis=1), ik_pool=ip)
+
+    comp = jax.jit(layer, donate_argnums=(2, 3)).lower(
+        lp, s((B, S, c.dim), jnp.bfloat16), kp, ip, s((B, _DSA["MP"]), jnp.int32),
+        s((B, S), jnp.int32), s((B,), jnp.int32)).compile()
+    kernels = [l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for l in comp.as_text().splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert kernels and set(kernels) == {"decode_mla_attention" if S == 1 else "prefill_mla_attention"}
+    assert len(kernels) == (2 if S == 1 else 1)
+    assert comp.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 # -- routed experts over a work list of hit experts (ops/moe_experts.py) at
 # the benchmark cell's geometry (mistral4-chat-steady: dim 4096, experts of
 # width 2048, 32 held of a router 128 wide, 4 a token, six layers stacked)
