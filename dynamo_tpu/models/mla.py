@@ -54,8 +54,9 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
     attention over the latent cache; `attn.lift` W_UV back to per-head
     values; `attn.qscale` (inside `attn.proj`) the position-dependent
     query scale, where the model has one; with an indexer `attn.index`
-    (its projections, the key write, the scores), `attn.select` (the top-k)
-    and `attn.gather` (the selected latent rows). The output projection is
+    (its projections, the key write, the scores), `attn.select` (the best
+    `index_topk`: a decode step's select kernel, a chunk's mask) and
+    `attn.gather` (the selected latent rows). The output projection is
     the caller's `attn.proj`."""
     B, S = positions.shape
     H = c.n_heads
@@ -205,7 +206,10 @@ def select_topk(scores, k, with_mask=False):
     and so come out last. `with_mask`: also the same set as bool [..., C],
     from the sort's own k-th value and the last position it took at that
     value (a row with under k live positions has dead ones in it: the
-    caller masks them)."""
+    caller masks them). No serving path sorts any more: this is the
+    reference the decode step's select kernel (ops/dsa_select.py) and
+    `topk_mask` are held to (tests/test_dsa.py, tests/test_dsa_select.py,
+    scripts/tpu_parity.py)."""
     vals, idx = lax.top_k(scores, k)
     idx = idx.astype(jnp.int32)
     if not with_mask:
@@ -287,11 +291,13 @@ def _selected_attention(c, k_pool, ik_pool, l_idx, q_abs, q_r, qi, wi,
     each query token t the min(index_topk, t + 1) cached tokens s <= t with
     the largest index score I[t, s].
 
-    A decode step on the chip: the chosen latent rows are gathered into a
-    buffer laid out as pages of their own, `index_topk` slots a row, live
-    ones first, and the decode kernel the dense path runs walks that buffer
-    under an identity page table (one call a layer: it reads the selected
-    rows and nothing else of the context). Everything else (a prefill
+    A decode step on the chip: the select kernel (ops/dsa_select.py: a
+    threshold select and a compaction, no sort) hands over the pool's cells
+    of the chosen tokens, live ones first, and the chosen set as words; the
+    chosen latent rows are gathered into a buffer laid out as pages of their
+    own, `index_topk` slots a row, and the decode kernel the dense path runs
+    walks that buffer under an identity page table (one call a layer: it
+    reads the selected rows and nothing else of the context). Everything else (a prefill
     chunk; the jnp path): the selection as a mask, and attention over the
     context a block of queries by a block of pages at a time with a running
     softmax, as far as the block's last query sees; a chunk's queries would
@@ -314,16 +320,17 @@ def _selected_attention(c, k_pool, ik_pool, l_idx, q_abs, q_r, qi, wi,
         scores = jnp.where(live, scores, -jnp.inf)
     qf = _to_pool_width(jnp.concatenate([q_abs, q_r], axis=-1), k_pool)
     if S == 1 and attn_impl == "pallas":
+        from dynamo_tpu.ops.dsa_select import dsa_select
         from dynamo_tpu.ops.mla_attention import decode_mla_attention
 
         with jax.named_scope("attn.select"):
-            idx, chosen = select_topk(scores[:, 0], K, with_mask=True)  # idx [B, K], live first
-            chosen = pack_chosen((chosen & live[:, 0])[:, None])
-            n_sel = jnp.clip(jnp.minimum(positions[:, 0] + 1, kv_lens), 0, K)
+            # a select, not a sort: the K best as the pool's cells, live first
+            n_live = jnp.clip(jnp.minimum(positions[:, 0] + 1, kv_lens), 0, C)
+            cells, words = dsa_select(scores[:, 0], page_table, n_live, k=K)
+            chosen = words[:, None]
+            n_sel = jnp.minimum(n_live, K)
         kp = -(-K // PS)  # pages a row's buffer takes
         with jax.named_scope("attn.gather"):
-            cells = (jnp.take_along_axis(page_table, idx // PS, axis=1) * PS
-                     + idx % PS)  # [B, K] flat token cells of the pool
             sel = k_pool.reshape(L, NP * PS, Dl)[l_idx, cells]  # [B, K, Dl]
             sel = jnp.pad(sel, ((0, 0), (0, kp * PS - K), (0, 0)))
             sel = sel.reshape(B * kp, PS, 1, Dl)

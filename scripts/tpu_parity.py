@@ -27,8 +27,9 @@ data, so a kernel that indexed the wrong layer misses the reference.
 
 DeepSeek-V3.2 (`dsa`): one layer of models/mla.py at published widths with its
 indexer: a decode step of 1 / 8 / 32 rows on 1 k / 4 k / 32 k cached tokens
-against the jnp path of the same layer, the compiled indexer and top-k against
-numpy's stable argsort of the chip's own scores (ties planted), a prefill chunk
+against the jnp path of the same layer, the compiled indexer and select kernel
+(ops/dsa_select.py; `select_topk` beside it) against numpy's stable argsort of
+the chip's own scores (ties planted, rows short of k live), a prefill chunk
 on a prior context against float32 attention under the same selection as a
 mask. `--time-dsa` prints the device time of the selecting arm's parts instead.
 
@@ -565,12 +566,16 @@ def check_dsa_decode(rows: int, ctx: int) -> float:
 
 
 def check_dsa_selection(rows: int, ctx: int) -> float:
-    """The compiled indexer and top-k on the chip: the selected SET of every
-    row is numpy's stable argsort of the chip's own scores (ties towards the
-    lower position), and the scores are the float32 ones to bf16's rounding.
-    Returns the share of selected positions that differ plus the scores'
-    largest relative error."""
+    """The compiled indexer and select kernel on the chip: the selected SET of
+    every row is numpy's stable argsort of the chip's own scores (ties towards
+    the lower position), handed over as the pool's cells in position order
+    (live first: a third of the rows have fewer live positions than k) with
+    the same set as bit words, and the scores are the float32 ones to bf16's
+    rounding. `select_topk` is held to the same set beside it. Returns the
+    share of selected positions that differ plus the scores' largest relative
+    error."""
     from dynamo_tpu.models import mla
+    from dynamo_tpu.ops.dsa_select import dsa_select
 
     c, lp, h, kp, ip, pt, pos, kv = _dsa_layer(rows, 1, ctx)
     k = jax.random.split(jax.random.PRNGKey(3), 2)
@@ -579,16 +584,29 @@ def check_dsa_selection(rows: int, ctx: int) -> float:
     wi = jax.random.normal(k[1], (rows, 1, hi), jnp.float32) * (hi * di) ** -0.5
     keys = ip[1, pt].reshape(rows, -1, di)
     keys = keys.at[:, 5::7].set(keys[:, 4::7][:, : keys[:, 5::7].shape[1]])  # ties
-    scores = jax.jit(mla.index_scores)(qi, wi, keys)[:, 0]
-    K = min(c.index_topk, scores.shape[-1])
-    got = np.sort(np.asarray(jax.jit(lambda x: mla.select_topk(x, K))(scores)), -1)
+    C = keys.shape[1]
+    K = min(c.index_topk, C)
+    n_live = np.full((rows,), C, np.int32)
+    n_live[1::3] = K - 5 - np.arange(len(n_live[1::3]))
+    live = np.arange(C)[None, :] < n_live[:, None]
+    scores = jnp.where(live, jax.jit(mla.index_scores)(qi, wi, keys)[:, 0], -jnp.inf)
     host = np.asarray(scores)
     want = np.sort(np.argsort(-host, axis=-1, kind="stable")[:, :K], -1)
+    sorted_ = np.sort(np.asarray(jax.jit(lambda x: mla.select_topk(x, K))(scores)), -1)
+    cells, words = jax.jit(lambda x, t, n: dsa_select(x, t, n, k=K, interpret=INTERPRET))(
+        scores, pt, jnp.asarray(n_live))
+    tables, PS = np.asarray(pt), C // pt.shape[1]
+    want_cells = np.take_along_axis(tables, want // PS, axis=1) * PS + want % PS
+    as_mask = np.zeros((rows, C), bool)
+    np.put_along_axis(as_mask, want, True, axis=-1)
+    bad_words = (mla.unpack_chosen(np.asarray(words), C) != (as_mask & live)).mean()
     ref = np.einsum("bhc,bh->bc", np.maximum(np.einsum(
         "bhd,bcd->bhc", np.asarray(qi[:, 0], np.float32), np.asarray(keys, np.float32)), 0),
         np.asarray(wi[:, 0]))
-    rel = float(np.abs(host - ref).max() / np.abs(ref).max())
-    return float((got != want).mean()) + rel
+    rel = float(np.abs(np.where(live, host - ref, 0)).max() / np.abs(ref).max())
+    wrong = ((np.asarray(cells) != want_cells).any() or bad_words > 0
+             or (sorted_ != want).any())  # a set: one position off fails
+    return float(wrong) + rel
 
 
 def check_dsa_prefill(S: int, ctx: int) -> float:
@@ -608,7 +626,7 @@ def check_dsa_prefill(S: int, ctx: int) -> float:
     qi = jax.random.normal(k[2], (1, S, hi, di), jnp.bfloat16)
     wi = jax.random.normal(k[3], (1, S, hi), jnp.float32)
     scale = attn_score_scale(c, c.qk_nope_head_dim + c.qk_rope_head_dim)
-    out = jax.jit(lambda *a: mla._selected_attention(
+    out, _ = jax.jit(lambda *a: mla._selected_attention(
         c, *a, attn_impl="pallas", dc=dc, scale=scale))(
         kp, ip, jnp.int32(1), q_abs, q_r, qi, wi, pt, pos, kv)
 
@@ -646,7 +664,21 @@ def time_dsa() -> None:
             ts.append((time.perf_counter() - t0) * 1e3)
         return round(sorted(ts)[2], 3)
 
-    for rows, S, ctx in [(8, 1, 32768), (16, 1, 24576), (32, 1, 32768),
+    from dynamo_tpu.ops.dsa_select import dsa_select
+
+    def chained_us(fn, x, reps=20):
+        """Device time of one fn(x) in us: `reps` calls in one program, each
+        fed by the one before, less the same loop around nothing."""
+        def loop(f):
+            def body(_, carry):
+                outs = f(x + carry)
+                return carry + sum(o.ravel()[0].astype(jnp.float32) for o in outs) * 0.0
+            return jax.jit(lambda: jax.lax.fori_loop(0, reps, body, jnp.float32(0.0)))
+
+        full, bare = med(loop(fn)), med(loop(lambda y: (y[:, :1],)))
+        return round((full - bare) * 1e3 / reps, 1)
+
+    for rows, S, ctx in [(4, 1, 36864), (8, 1, 32768), (16, 1, 24576), (32, 1, 32768),
                          (1, 256, 24576), (1, 1024, 4096), (1, 1024, 16384), (1, 1024, 32768)]:
         c, lp, h, kp, ip, pt, pos, kv = _dsa_layer(rows, S, ctx)
         hi, di, Dl, dc = c.index_n_heads, c.index_head_dim, c.mla_pool_dim, c.kv_lora_rank
@@ -664,6 +696,14 @@ def time_dsa() -> None:
                "top_k_ms": med(jax.jit(lambda x: mla.select_topk(x, K)), scores),
                "topk_mask_ms": med(jax.jit(lambda x: mla.topk_mask(x, K)), scores)}
         if S == 1:
+            row["dsa_select_ms"] = med(
+                jax.jit(lambda x, t, n: dsa_select(x[:, 0], t, n, k=K)), scores, pt, kv)
+            # the host's clock holds ~0.9 ms of dispatch a call: the device's
+            # own time a call, from 20 calls chained in one program
+            row["top_k_dev_us"] = chained_us(
+                lambda x: mla.select_topk(x, K, with_mask=True), scores[:, 0])
+            row["dsa_select_dev_us"] = chained_us(
+                lambda x: dsa_select(x, pt, kv, k=K), scores[:, 0])
             lat_flat = kp.reshape(2, -1, Dl)
             gather = jax.jit(lambda lf, i: lf[1, i[:, 0]].reshape(rows * K // 64, 64, 1, Dl))
             sel = gather(lat_flat, idx)
@@ -804,6 +844,8 @@ def all_checks():
         checks.append((f"dsa decode {rows} rows ctx {ctx} @deepseek-v3.2",
                        functools.partial(check_dsa_decode, rows, ctx)))
     checks += [
+        ("dsa selection 4 rows ctx 36864 @deepseek-v3.2",
+         functools.partial(check_dsa_selection, 4, 36864)),
         ("dsa selection 8 rows ctx 4096 @deepseek-v3.2",
          functools.partial(check_dsa_selection, 8, 4096)),
         ("dsa selection 32 rows ctx 32768 @deepseek-v3.2",

@@ -367,15 +367,18 @@ def test_a_chosen_set_goes_out_as_bit_words_and_comes_back(C):
 
 def test_a_decode_steps_two_arms_choose_and_attend_alike(monkeypatch):
     """A decode step of three rows past index_topk and one under it: the arm
-    the chip runs (a sort, the chosen rows gathered, the decode kernel over
-    them; interpreted here) against the masked arm, in what they attend to
-    and in what they say they chose."""
+    the chip runs (the select kernel, the chosen rows gathered, the decode
+    kernel over them; both interpreted here) against the masked arm, in what
+    they attend to and in what they say they chose."""
     import functools
 
+    from dynamo_tpu.ops import dsa_select as sel_ops
     from dynamo_tpu.ops import mla_attention as ops
 
     monkeypatch.setattr(ops, "decode_mla_attention",
                         functools.partial(ops.decode_mla_attention, interpret=True))
+    monkeypatch.setattr(sel_ops, "dsa_select",
+                        functools.partial(sel_ops.dsa_select, interpret=True))
     c = TOY
     rng = np.random.default_rng(5)
     B, H, dc, dr, NP, MP = 4, c.n_heads, c.kv_lora_rank, c.qk_rope_head_dim, 40, 8

@@ -170,7 +170,9 @@ def test_request_arriving_mid_stream_drains_then_resumes():
 
 def test_mixed_iterations_drain_and_streams_match(monkeypatch):
     monkeypatch.setenv("DYN_FUSED_MIXED", "1")
-    reqs = [{"rid": "a", "prompt": _prompt(6, 41), "n": 36},
+    # `a` long enough to be decoding still when `b`, sent at its 14th token,
+    # is planned: at 36 tokens a warm host often had it done by then
+    reqs = [{"rid": "a", "prompt": _prompt(6, 41), "n": 56},
             {"rid": "b", "prompt": _prompt(13, 42), "n": 10, "after": ("a", 14)}]
     (ta, _, ra), (tb, _, _) = _ab(_runner, reqs, mixed_prefill_tokens=8)
     assert ta == tb
