@@ -1316,12 +1316,13 @@ class ModelRunner(Runner):
         log.info(
             "runner ready: %s params+pool placed in %.1fs (mesh %s, %d pages "
             "x %d tokens) on %s %r devices %s; attn_impl=%s (%s), "
-            "decode_page_routine=%s, ragged_mixed=%s, kv_copy_kernel=%s "
-            "(interpret=%s)",
+            "decode_page_routine=%s, ragged_page_routine=%s, ragged_mixed=%s, "
+            "kv_copy_kernel=%s (interpret=%s)",
             config.name, placed_s, self.mesh_config.shape, num_pages,
             page_size, rep["platform"], rep["device_kind"], rep["device_ids"],
             self.attn_impl, self.attn_impl_reason, rep["decode_page_routine"],
-            self.ragged_mixed, self._kv_copy_kernel, self._kv_copy_interpret,
+            rep["ragged_page_routine"], self.ragged_mixed,
+            self._kv_copy_kernel, self._kv_copy_interpret,
         )
 
     def device_report(self) -> Dict[str, Any]:
@@ -1339,13 +1340,17 @@ class ModelRunner(Runner):
         each, the pool's dtype, a sink, values narrower than keys); a name
         a kind, {"global", "window"}, where window layers keep a pool of
         their own; None where that kernel is not on the path (the jnp
-        gather, latent attention)."""
+        gather, latent attention). `ragged_page_routine` is the same of the
+        ragged (mixed-step) kernel, from its own decision
+        (ops/ragged_paged_attention.py `ragged_page_routine`); None also
+        where the mixed step is not the ragged program."""
         from dynamo_tpu.ops.paged_attention import page_routine
+        from dynamo_tpu.ops.ragged_paged_attention import ragged_page_routine
 
         devs = list(self.mesh.devices.flat)
         k_leaf = jax.tree.leaves(self.k_pool)[0]
         shard_shape = list(k_leaf.sharding.shard_shape(k_leaf.shape))
-        routine = None
+        decode_routine = ragged_routine = None
         if self.attn_impl == "pallas" and not self.config.is_mla:
             c = self.config
             shards = k_leaf.shape[3] // shard_shape[3]
@@ -1353,14 +1358,21 @@ class ModelRunner(Runner):
             kinds = {"global": (c.n_kv_heads, c.sink_global)}
             if self.holds_window_pool:
                 kinds["window"] = (c.n_kv_heads_window, c.sink_window)
-            routine = {
-                kind: page_routine(
-                    Hk // shards, c.n_heads // Hk, k_leaf.dtype,
-                    isinstance(self.k_pool, dict), sinked,
-                    k_leaf.shape[-1] == v_width)
-                for kind, (Hk, sinked) in kinds.items()}
-            if len(routine) == 1:
-                routine = routine["global"]
+
+            quantized = isinstance(self.k_pool, dict)
+
+            def routine_of(ask):
+                """`ask(local Hk, G, a sink)` a kind; one name where one."""
+                routine = {kind: ask(Hk // shards, c.n_heads // Hk, sinked)
+                           for kind, (Hk, sinked) in kinds.items()}
+                return routine["global"] if len(routine) == 1 else routine
+
+            decode_routine = routine_of(lambda Hk, G, sinked: page_routine(
+                Hk, G, k_leaf.dtype, quantized, sinked,
+                k_leaf.shape[-1] == v_width))
+            if self.ragged_mixed:
+                ragged_routine = routine_of(
+                    lambda Hk, G, _: ragged_page_routine(Hk, G, quantized))
         memory = {}
         for d in devs:
             if d.process_index != jax.process_index():
@@ -1379,7 +1391,8 @@ class ModelRunner(Runner):
             "mesh": list(self.mesh_config.shape),
             "attn_impl": self.attn_impl,
             "attn_impl_reason": self.attn_impl_reason,
-            "decode_page_routine": routine,
+            "decode_page_routine": decode_routine,
+            "ragged_page_routine": ragged_routine,
             "ragged_mixed": self.ragged_mixed,
             "kv_copy_kernel": self._kv_copy_kernel,
             "kv_copy_interpret": self._kv_copy_interpret,

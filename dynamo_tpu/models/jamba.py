@@ -320,12 +320,12 @@ def forward(
 
     walk = None
     if attn_impl == "pallas" and (S == 1 or ragged is not None):
-        PS = jax.tree.leaves(k_pool)[0].shape[2]
         if ragged is not None:
-            from dynamo_tpu.ops.ragged_paged_attention import ragged_work_list
+            from dynamo_tpu.ops.ragged_paged_attention import ragged_walk
 
             seg_pt, seg_kvl, rmeta = ragged
-            walk = ragged_work_list(rmeta, seg_kvl, None, PS, seg_pt.shape[1], S)
+            walk = ragged_walk((c.n_kv_heads, G), k_pool, v_pool, seg_pt,
+                               seg_kvl, rmeta, None, S)
         else:
             from dynamo_tpu.ops.paged_attention import decode_walk
 

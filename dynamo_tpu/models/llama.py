@@ -358,25 +358,23 @@ def forward(
     # both lists and each layer picks one.
     walk_sliding = walk_global = None
     if attn_impl == "pallas" and not c.is_mla and (S == 1 or ragged is not None):
-        PS = jax.tree.leaves(k_pool)[0].shape[2]
+        # the heads ONE call of the kernel sees: a model-axis shard's
+        shards = mesh.shape.get("model", 1) if mesh is not None else 1
+        heads = (c.n_kv_heads // shards, c.n_heads // c.n_kv_heads)
         if ragged is not None:
-            from dynamo_tpu.ops.ragged_paged_attention import ragged_work_list
+            from dynamo_tpu.ops.ragged_paged_attention import ragged_walk
 
             seg_pt, seg_kvl, rmeta = ragged
 
             def build_walk(window):
-                return ragged_work_list(
-                    rmeta, seg_kvl, window, PS, seg_pt.shape[1], S)
+                return ragged_walk(heads, k_pool, v_pool, seg_pt, seg_kvl,
+                                   rmeta, window, S)
         else:
             from dynamo_tpu.ops.paged_attention import decode_walk
 
-            # the heads ONE call of the kernel sees: a model-axis shard's
-            shards = mesh.shape.get("model", 1) if mesh is not None else 1
-
             def build_walk(window):
-                return decode_walk(
-                    (c.n_kv_heads // shards, c.n_heads // c.n_kv_heads),
-                    k_pool, v_pool, page_table, kv_lens, window, False)
+                return decode_walk(heads, k_pool, v_pool, page_table, kv_lens,
+                                   window, False)
 
         if c.sliding_window > 0:
             walk_sliding = build_walk(jnp.int32(c.sliding_window))

@@ -226,17 +226,17 @@ def forward(
     # so a freed page's table entry is never read
     walks = {GLOBAL: None, WINDOW: None}
     if pallas and (S == 1 or ragged is not None):
-        PS = k_pool.shape[2]
         for kind in (GLOBAL, WINDOW):
+            Hk = kv_heads(c, kind)
             if ragged is not None:
-                from dynamo_tpu.ops.ragged_paged_attention import ragged_work_list
+                from dynamo_tpu.ops.ragged_paged_attention import ragged_walk
 
-                walks[kind] = ragged_work_list(
-                    rmeta, seg_kvl, window_of[kind], PS, seg_pt.shape[1], S)
+                walks[kind] = ragged_walk(
+                    (Hk, H // Hk), *pools[kind], tables[kind][1], seg_kvl,
+                    rmeta, window_of[kind], S)
             else:
                 from dynamo_tpu.ops.paged_attention import decode_walk
 
-                Hk = kv_heads(c, kind)
                 walks[kind] = decode_walk(
                     (Hk, H // Hk), *pools[kind], tables[kind][0], kv_lens,
                     window_of[kind], has_sink(c, kind))

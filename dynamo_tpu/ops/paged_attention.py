@@ -32,8 +32,9 @@ All kv heads are processed per step. A token-major page [PS, Hk, D] is one
 CONTIGUOUS slab in the pool, so each grid step issues a single large DMA a
 page (the head-major layout needed Hk strided chunks per page). What a
 step does with its pages is decided in one place from the static shapes
-the kernel sees (`page_routine`; the wrapper, `decode_walk` and the
-runner's report all ask there), one walk whatever the answer:
+the kernel sees (`page_routine`; `decode_walk` asks there and hands the
+answer to the wrapper with its lists, a `Walk`; the runner's report asks
+there too), one walk whatever the answer:
 
 - an int8 pool: `_page_by_heads`, a batched float32 product a kv head, the
   scales folded in per (token, head). Nothing else takes it.
@@ -85,8 +86,10 @@ equivalent of that hot path.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -168,8 +171,8 @@ def page_routine(Hk: int, G: int, dtype, quantized: bool, sinked: bool,
     "by_rows" at G = 1 when Hk fills whole sublane tiles of the pool's
     dtype, so the page reads as a matrix for free, with no sink and one
     width; "by_tiles" for every other dense call (one KV head is its
-    one-head case). `decode_paged_attention`, `decode_walk` and
-    `ModelRunner.device_report` all ask here."""
+    one-head case). `decode_walk` asks here, and the call reads the answer
+    off the walk; `ModelRunner.device_report` asks here too."""
     if quantized:
         return "by_heads"
     if (G == 1 and not sinked and one_width
@@ -187,12 +190,14 @@ def page_routine(Hk: int, G: int, dtype, quantized: bool, sinked: bool,
 STEP_BYTES = 1 << 20
 
 
-def page_bytes(k_pool, v_pool) -> int:
-    """K + V of one page of one layer as a dense pool holds them: a head's
-    vector padded to whole lane rows."""
+def page_bytes(Hk: int, k_pool, v_pool) -> int:
+    """K + V of one page of one layer as ONE call of the kernel holds them:
+    `Hk` KV heads (a tensor-parallel shard's local heads, NOT the pool's
+    head axis: a walk is built outside `shard_map`, where the pool still
+    has every head), a head's vector padded to whole lane rows."""
     def lanes(a):
         return -(-a.shape[-1] // 128) * 128
-    PS, Hk = k_pool.shape[-3:-1]
+    PS = k_pool.shape[-3]
     return PS * Hk * (lanes(k_pool) + lanes(v_pool)) * k_pool.dtype.itemsize
 
 
@@ -207,16 +212,41 @@ def step_tiles(nbytes: int, max_pages: int) -> int:
     return math.gcd(max_pages, tiles)
 
 
-def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window, sinked):
-    """The list a decode call walks, for a caller that runs many layers on
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=("work", "n_work", "covered", "pages"),
+    meta_fields=("routine", "tiles"))
+@dataclasses.dataclass(frozen=True)
+class Walk:
+    """The lists one call of a walking kernel takes, WITH the decision they
+    were built for: the walk's maker (`decode_walk`; the ragged kernel's
+    `ragged_walk`) decides the routine and the pages a step from the heads
+    one call sees, and the kernel's wrapper reads both from here and never
+    decides again, so a list cannot be decoded at another granularity than
+    it was written at (under tensor parallelism the maker runs outside
+    `shard_map`, on pools that still have every head). A pytree: the lists
+    are its leaves, `routine` and `tiles` static beside them, so it rides
+    `jit`, `shard_map` and a layer scan's closure like the tuple it was."""
+    work: jax.Array  # [W] int32: unit * steps_a_unit + step of a live pair
+    n_work: jax.Array  # int32: the live pairs, the grid's traced bound
+    covered: Optional[jax.Array]  # ragged: [T] bool, rows some unit writes
+    pages: Optional[jax.Array]  # by_tiles: `filled_page_table`, else None
+    routine: str  # page_routine / ragged_page_routine
+    tiles: int  # pages a grid step (1 unless by_tiles)
+
+
+def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window,
+                sinked) -> Walk:
+    """The `Walk` of a decode call, for a caller that runs many layers on
     one set of lengths and builds it once. `heads` = (Hk, G) of ONE call (a
-    shard's local heads); with the pools and `sinked` they decide the
-    routine as the call itself will (`page_routine`). `decode_work_list`'s
-    pair for "by_rows" and "by_heads"; for "by_tiles" (work, n_work,
-    pages): the same walk over steps of `step_tiles` pages, entry w = `row
-    * steps_a_row + step`, so that `entry * tiles + t` is the place of the
-    step's t-th tile in `pages`, the page table flattened with every dead
-    entry of a row replaced by its nearest live one. A tile past either
+    shard's local heads); with the pools' dtype and widths and `sinked`
+    they decide the routine (`page_routine`) and the pages a step
+    (`step_tiles` of `page_bytes` at THOSE heads), and the call takes both
+    from the walk. `decode_work_list`'s pair for "by_rows" and "by_heads";
+    for "by_tiles" the same walk over steps of `tiles` pages, entry w =
+    `row * steps_a_row + step`, so that `entry * tiles + t` is the place of
+    the step's t-th tile in `pages`, the page table flattened with every
+    dead entry of a row replaced by its nearest live one. A tile past either
     end of the row's live pages thus repeats a live page (its slots are
     masked by position), and the kernel reads no dead page-table entry and
     no dead page. Built from compares and masked sums alone: a gather of
@@ -225,22 +255,36 @@ def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window, sinked):
     quantized = isinstance(k_pool, dict)
     kq, vq = (p["q"] if quantized else p for p in (k_pool, v_pool))
     PS, MP = kq.shape[-3], page_table.shape[1]
-    if page_routine(*heads, kq.dtype, quantized, sinked,
-                    kq.shape[-1] == vq.shape[-1]) != "by_tiles":
-        return decode_work_list(kv_lens, window, PS, MP)
-    tiles = step_tiles(page_bytes(kq, vq), MP)
+    routine = page_routine(*heads, kq.dtype, quantized, sinked,
+                           kq.shape[-1] == vq.shape[-1])
+    if routine != "by_tiles":
+        work, n_work = decode_work_list(kv_lens, window, PS, MP)
+        return Walk(work, n_work, None, None, routine, 1)
+    tiles = step_tiles(page_bytes(heads[0], kq, vq), MP)
     work, n_work = decode_work_list(kv_lens, window, PS * tiles, MP // tiles)
-    first, last = (a[:, None] for a in live_pages(
-        kv_lens - 1, kv_lens - 1, kv_lens, window, PS, MP))
-    col = lax.iota(jnp.int32, MP)[None, :]
+    first, last = live_pages(
+        kv_lens - 1, kv_lens - 1, kv_lens, window, PS, MP)
+    return Walk(work, n_work, None,
+                filled_page_table(page_table, first, last), routine, tiles)
 
-    def entry_at(i):  # page_table[b, i[b]] as [B, 1]
+
+def filled_page_table(page_table, first, last):
+    """A page table [R, MP], flattened, with every entry of row r outside
+    its live run first[r] .. last[r] replaced by the run's nearest end: what
+    a walk of several pages a step indexes (`decode_walk`; the ragged
+    kernel's `ragged_walk`, a row a segment). Compares and masked sums,
+    no gather (`decode_walk`). A row without a live run (first > last)
+    names page 0 throughout; no walk visits it."""
+    first, last = first[:, None], last[:, None]
+    col = lax.iota(jnp.int32, page_table.shape[1])[None, :]
+
+    def entry_at(i):  # page_table[r, i[r]] as [R, 1]
         return jnp.sum(jnp.where(col == i, page_table, 0), axis=1,
                        keepdims=True)
 
     pages = jnp.where(col < first, entry_at(first),
                       jnp.where(col > last, entry_at(last), page_table))
-    return work, n_work, pages.reshape(-1)
+    return pages.reshape(-1)
 
 
 def work_list(first, count, max_pages: int):
@@ -579,7 +623,7 @@ def decode_paged_attention_sharded(
     axis_name: str = AXIS_MODEL,
     window=None,  # traced int32 scalar (see decode_paged_attention)
     layer=None,  # traced int32 scalar, replicated
-    work=None,  # decode_walk's lists, replicated (see below)
+    work=None,  # decode_walk's `Walk`, replicated (see below)
     *,
     scale=None,
     softcap: float = 0.0,
@@ -605,13 +649,11 @@ def decode_paged_attention_sharded(
         work = decode_walk((Hk, G), k_pool, v_pool, page_table, kv_lens,
                            window, sink is not None)
 
-    n_lists = len(work)
-
-    def part(q, k_pool, v_pool, page_table, kv_lens, *rest):
-        layer, *window = rest[n_lists:n_lists + len(scalars)]
+    def part(q, k_pool, v_pool, page_table, kv_lens, work, *rest):
+        layer, *window = rest[:len(scalars)]
         return decode_paged_attention(
             q, k_pool, v_pool, page_table, kv_lens,
-            window[0] if window else None, layer, rest[:n_lists],
+            window[0] if window else None, layer, work,
             scale=scale, softcap=softcap, interpret=interpret,
             sink=rest[-1] if sinks else None,
         )
@@ -619,13 +661,12 @@ def decode_paged_attention_sharded(
     fn = jax.shard_map(
         part,
         mesh=mesh,
-        in_specs=(heads, pool, pool, P(None, None), P(None))
-        + tuple(P(None) if w.ndim else P() for w in work)
+        in_specs=(heads, pool, pool, P(None, None), P(None), P())
         + (P(),) * len(scalars) + (P(axis_name, None),) * len(sinks),
         out_specs=heads,
         check_vma=False,
     )
-    return fn(q, k_pool, v_pool, page_table, kv_lens, *work, *scalars, *sinks)
+    return fn(q, k_pool, v_pool, page_table, kv_lens, work, *scalars, *sinks)
 
 
 @functools.partial(
@@ -644,7 +685,8 @@ def decode_paged_attention(
     #   read; rides the scan as a prefetch operand like `window`
     work=None,  # decode_walk((Hk, G), k_pool, v_pool, page_table, kv_lens,
     #   window, sinked), for a caller that runs many layers on one set of
-    #   lengths and builds it once; None = built here
+    #   lengths and builds it once; None = built here. Its routine and
+    #   pages a step are the call's
     *,
     scale=None,  # static score-scale override (query_pre_attn_scalar)
     softcap: float = 0.0,  # Gemma-2 logit soft capping (static; 0 = off)
@@ -672,14 +714,13 @@ def decode_paged_attention(
         window = jnp.asarray(window, jnp.int32).reshape(())
     if quantized and sinked:
         raise NotImplementedError("a sink over an int8 KV pool")
-    # the per-page routine, from what the shapes say
-    routine = page_routine(Hk, G, kq.dtype, quantized, sinked, Dv == D)
-    work, n_work, *filled = work or decode_walk(
-        (Hk, G), k_pool, v_pool, page_table, kv_lens, window, sinked)
-    # pages a grid step brings, and the steps a row can take
-    tiles = (step_tiles(page_bytes(kq, vq), MP) if routine == "by_tiles"
-             else 1)
-    steps = MP // tiles
+    # the per-page routine and the pages a grid step brings are the walk's,
+    # decided where its lists were written (`decode_walk`)
+    if work is None:  # dynlint: disable=DYN-J001 (the argument's absence)
+        work = decode_walk((Hk, G), k_pool, v_pool, page_table, kv_lens,
+                           window, sinked)
+    routine, tiles = work.routine, work.tiles
+    steps = MP // tiles  # the steps a row can take
 
     def row_of(w, wk):
         return _div(wk[w], steps)
@@ -752,12 +793,12 @@ def decode_paged_attention(
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, kq, vq)
 
-    prefetch = (work, *(filled or [page_table]), kv_lens) + scalar_operands(
-        layer, window)
+    prefetch = (work.work, page_table if work.pages is None else work.pages,
+                kv_lens) + scalar_operands(layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),  # work, page_table (the tile
         #   routine: the walk's filled-in one), kv_lens, layer (+ window)
-        grid=(n_work,),  # a traced bound: the live pages, not B * MP
+        grid=(work.n_work,),  # a traced bound: the live pages, not B * MP
         in_specs=in_specs,
         out_specs=pl.BlockSpec(o_block, qo_index),
         scratch_shapes=[

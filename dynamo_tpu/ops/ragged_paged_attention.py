@@ -58,11 +58,41 @@ at a scalar-prefetched layer: the index maps read the list, then the
 unit's segment, then its page-table row; no page is clamped, because no
 dead page is in the list.
 
+What a step does with its pages is the decode kernel's choice of routines
+(ops/paged_attention.py `_PAGE_ROUTINES`, its module docstring says why no
+head is ever brought together), asked in one place from the static shapes
+a call sees (`ragged_page_routine`, a rule of this kernel's own shapes),
+under one body. An int8 pool takes `by_heads`: one page a step, a batched
+float32 product a KV head, the scales folded in per (token, head); so
+does G = 1 at 32 KV heads or more (phi-3), the one dense geometry it
+measured faster at. Every other dense pool takes `by_tiles`: the q block
+is ONE matrix [Hk * G * q_block, D], rows (head, group, token),
+against the step's pages as they lie in the pool, in the pool's dtype,
+columns of other KV heads masked; where a decode row has one pair of
+bounds for its page, here each row has its own (its position inside the
+unit's band, the segment's length, the window), and the routine takes
+them as a column. A step brings `ragged_step_tiles` pages of the unit
+(`step_tiles`' count by the page's bytes, halved while the score block,
+q_block * Hk * G rows by tiles * PS * Hk columns of float32, passes
+SCORE_BYTES), each a block of its own on the same operand, and the walk is
+the same walk at that granularity: `ragged_walk` lists (unit, step) pairs
+and hands the kernel the segments' page table with every entry outside a
+segment's live run replaced by the run's nearest end, so a tile past the
+unit's pages names a live page of its segment and is masked by position.
+The walk is a `Walk` (ops/paged_attention.py): it carries the routine and
+the pages a step beside its lists, decided from the heads ONE call sees (a
+tensor-parallel shard's, though the walk is built outside `shard_map` on
+pools that still have every head), and the call reads them off it.
+One KV head is the case with nothing to mask, on the 4-d view [L, NP, PS,
+D] of the stack. On a v5e at mimo-v2-flash's two kinds of layer the
+float32 product a head cost 6.2 and 6.7 us a live pair where this costs
+1.6 and 3.3 (PERF.md section 6, PR 46).
+
 Parity: GQA (G groups per kv head), sliding window (traced scalar, 0 =
-global at runtime), logit softcap, and int8-KV per-(token, head) scales all
-follow the exact op order of the two kernels this subsumes — scales fold
-into scores BEFORE softcap, V scales fold into p AFTER the raw-probability
-denominator.
+global at runtime), logit softcap, a sink, and int8-KV per-(token, head)
+scales all follow the exact op order of the two kernels this subsumes —
+scales fold into scores BEFORE softcap, V scales fold into p AFTER the
+raw-probability denominator.
 
 The flat layout itself is the "Ragged Paged Attention" TPU kernel design
 (PAPERS.md); the reference framework reaches the same shape through
@@ -83,8 +113,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.paged_attention import (
-    _div, _rem, live_pages, scalar_operands, split_scales, stacked_pools,
-    work_list,
+    _PAGE_ROUTINES, Walk, _div, _rem, _window_lo, filled_page_table,
+    live_pages, page_bytes, scalar_operands, split_scales, stacked_pools,
+    step_tiles, work_list,
 )
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
 
@@ -267,33 +298,145 @@ def _unit_pages(rows, qpos0, kv_len, window, page_size: int, max_pages: int):
     return first, last, (rows > 0) & (kv_len > 0)
 
 
+def ragged_page_routine(Hk: int, G: int, quantized: bool) -> str:
+    """What a grid step of the ragged kernel does with its pages, THE
+    decision, from the static shapes one call sees (a tensor-parallel
+    shard: its local heads), stated on the ragged kernel's own shapes and
+    measurements, in this order. "by_heads", one page a step and a float32
+    product a KV head: an int8 pool, whose scales ride per (token, head);
+    and G = 1 at RAGGED_MHA_HEADS KV heads or more, where the tile routine
+    loses: it multiplies every query row by every (token, head) column, Hk
+    times the useful products on the MXU and Hk times the score block on
+    the VPU, quadratic in Hk where a product a head is linear, and at G = 1
+    the rows are q_block * Hk with nothing of a group to fill them.
+    "by_tiles" for every other dense pool: GQA at any Hk and G, one KV
+    head (nothing to mask), G = 1 at fewer heads, with or without a sink
+    or values narrower than keys, whatever the pool's dtype (the decode
+    kernel's `page_routine` hangs on those; nothing of this rule does):
+    the q block's rows against the step's pages as they lie in the pool
+    (`_pages_by_tiles`). `ragged_walk` asks
+    here and the call reads the answer off the walk;
+    `ModelRunner.device_report` asks here too."""
+    if quantized or (G == 1 and Hk >= RAGGED_MHA_HEADS):
+        return "by_heads"
+    return "by_tiles"
+
+
+# KV heads from which a G = 1 (MHA) call keeps the float32 product a head.
+# us a live pair on the cell's mixed step, by heads / by tiles, at D 128
+# (my chip run, PR 46, `scripts/bench_attn.py --ragged --routines`): Hk 4
+# 2.89 / 0.39, Hk 8 2.92 / 0.50, Hk 16 3.47 / 1.26, Hk 32 4.18 / 4.30; phi-3
+# (Hk 32, D 96) 3.35 / 4.27
+RAGGED_MHA_HEADS = 32
+
+
+# float32 bytes of the score block a step of the tile routine may hold:
+# `rows` x the step's (token, head) columns. The decode kernel's rows are
+# one token's heads and its step is bounded by the pages' bytes alone
+# (STEP_BYTES); a q block brings q_block times the rows, and the scores,
+# the probabilities and their three bf16 terms are each a block this size
+# in VMEM and on the VPU
+SCORE_BYTES = 1 << 20
+
+
+def ragged_step_tiles(rows: int, page_cols: int, nbytes: int,
+                      max_pages: int) -> int:
+    """Pages a step of the ragged tile routine brings: `step_tiles`' count
+    from the page's bytes, halved while the step's score block, `rows`
+    (q_block * Hk * G) x `page_cols` (PS * Hk) a page of float32, passes
+    SCORE_BYTES. One page at least, whatever the rows."""
+    tiles = step_tiles(nbytes, max_pages)
+    while tiles > 1 and rows * page_cols * tiles * 4 > SCORE_BYTES:
+        tiles //= 2
+    return tiles
+
+
+def _routine_and_tiles(heads, kq, vq, quantized: bool, max_pages: int,
+                       q_block: int):
+    """(routine, pages a step) of one ragged call, from the heads IT sees
+    (`heads`; the pools give the page's tokens, the dtype and the widths
+    alone: their head axis is every shard's heads where a walk is built)."""
+    Hk, G = heads
+    PS = kq.shape[-3]
+    routine = ragged_page_routine(Hk, G, quantized)
+    if routine != "by_tiles":
+        return routine, 1
+    return routine, ragged_step_tiles(
+        q_block * Hk * G, PS * Hk, page_bytes(Hk, kq, vq), max_pages)
+
+
+def ragged_walk(heads, k_pool, v_pool, seg_page_table, seg_kv_lens, meta,
+                window, n_tokens: int,
+                q_block: int = DEFAULT_Q_BLOCK) -> Walk:
+    """The `Walk` of a ragged call, for a caller that runs many layers on
+    one plan and builds it once (`decode_walk`'s twin). `heads` = (Hk, G)
+    of ONE call (a shard's local heads); with the pools' page size, dtype
+    and widths they decide the routine and the pages a step
+    HERE (`_routine_and_tiles`), and the call takes both from the walk: it
+    never decides again. "by_heads": `ragged_work_list`'s triple.
+    "by_tiles": the same walk over steps of `tiles` pages, entry g = `unit
+    * steps_a_unit + step`, and `pages` the segments' page table,
+    flattened, with every entry outside a segment's live run (the pages
+    its units see, first to last) replaced by the run's nearest end
+    (`filled_page_table`): the t-th tile of a step is `pages[seg * MP +
+    step * tiles + t]`. A tile past either end of the UNIT's pages is thus
+    another live page of its segment, or a repeat of one, its slots masked
+    by position: the kernel reads no dead page-table entry and no dead
+    page."""
+    quantized = isinstance(k_pool, dict)
+    kq, vq = (p["q"] if quantized else p for p in (k_pool, v_pool))
+    PS, MP = kq.shape[-3], seg_page_table.shape[1]
+    routine, tiles = _routine_and_tiles(heads, kq, vq, quantized, MP, q_block)
+    work, n_work, covered = ragged_work_list(
+        meta, seg_kv_lens, window, PS * tiles, MP // tiles, n_tokens, q_block)
+    if routine != "by_tiles":
+        return Walk(work, n_work, covered, None, routine, tiles)
+    seg, _, _, rows, qpos0 = meta
+    first, last, live = _unit_pages(
+        rows, qpos0, seg_kv_lens[seg], window, PS, MP)
+    mine = live[None, :] & (
+        seg[None, :] == lax.iota(jnp.int32, seg_page_table.shape[0])[:, None])
+    pages = filled_page_table(
+        seg_page_table,
+        jnp.min(jnp.where(mine, first[None, :], MP), axis=1),
+        jnp.max(jnp.where(mine, last[None, :], -1), axis=1))
+    return Walk(work, n_work, covered, pages, routine, tiles)
+
+
 def _ragged_kernel_body(
     # scalar prefetch
-    work_ref,  # [NW * MP] int32: unit * MP + page of each live pair
+    work_ref,  # [NW * steps] int32: unit * steps + step of each live pair
     meta_ref,  # [5, NW] int32 (seg, qblk, rs, rows, qpos0)
-    pt_ref,  # [SEG, MP] int32 per-segment page-table rows
+    pt_ref,  # [SEG, MP] int32 per-segment page-table rows (by tiles: the
+    #   walk's filled-in table, flat; only the index maps read either)
     kvl_ref,  # [SEG] int32 per-segment context length
     #   (the pool's layer [1] rides next; only the index maps read it)
     win_ref,  # [1] int32 sliding window (0 = global) or None
-    # blocks (at one KV head, dense: the head axis is gone from all of
-    # them, the page the contiguous [PS, D] tile it is in the pool)
-    q_ref,  # [Hk, QB*G, D] (row r is block token r // G, group r % G)
-    k_ref,  # [PS, Hk, D] one token-major page
-    v_ref,  # [PS, Hk, D]
+    # blocks, by heads / by tiles (ops/paged_attention.py `_PAGE_ROUTINES`)
+    q_ref,  # [Hk, QB*G, D] rows (token, group) / [Hk*G*QB, D] rows (head,
+    #   group, token) ([QB*G, D] rows (token, group) at one KV head)
+    k_ref,  # [PS, Hk, D] one token-major page / the step's pages, a tuple
+    #   of such blocks ([PS, D] tiles of the 4-d view at one head)
+    v_ref,  # like k_ref, at the values' width Dv
     ks_ref,  # [PS, Hk] f32 per-vector K scales (int8 KV) or None
     vs_ref,  # [PS, Hk] f32 per-vector V scales or None
-    o_ref,  # [Hk, QB*G, D]
+    o_ref,  # like q_ref, Dv wide
     # scratch (persist across a unit's pages)
-    m_ref,  # [Hk, QB*G, 1] f32
-    l_ref,  # [Hk, QB*G, 1] f32
-    acc_ref,  # [Hk, QB*G, D] f32
+    m_ref,  # f32 [Hk, QB*G, 1] / [Hk*G*QB, 1]
+    l_ref,  # like m_ref
+    acc_ref,  # f32 like o_ref
     *,
-    page_size: int,
+    page_size: int,  # tokens a grid step covers: a page (by tiles: its
+    #   tiles' pages together, and max_pages the steps a unit can take)
     max_pages: int,
     n_groups: int,
+    q_block: int,
     scale: float,
     softcap: float = 0.0,
-    sink_ref=None,  # [Hk, QB*G, 1] f32 sink logit of each row's query head
+    routine: str = "by_heads",  # ragged_page_routine
+    token_minor: bool = False,  # the rows' order: (head, group, token), or
+    #   (token, group) of one head
+    sink_ref=None,  # f32 like m_ref: the sink logit of each row's query head
     #   (ops/paged_attention.py; None: no sink)
 ):
     entry = work_ref[pl.program_id(0)]
@@ -307,7 +450,6 @@ def _ragged_kernel_body(
     wv = None if win_ref is None else win_ref[0]
     first, last, _ = _unit_pages(
         n_rows, qpos0, kv_len, wv, page_size, max_pages)
-    page_first = i * page_size
 
     @pl.when(i == first)
     def _init():
@@ -319,52 +461,25 @@ def _ragged_kernel_body(
             l_ref[...] = jnp.ones_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # every pair the walk visits is live: the page runs unguarded
-    q = q_ref[...].astype(jnp.float32)  # [Hk, QB*G, D]
-    k = k_ref[...].astype(jnp.float32)  # [PS, Hk, D]
-    one_head = k.ndim == 2  # two plain products, [QB*G, D] x [PS, D]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())) if one_head
-        else (((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32
-    ) * scale  # [Hk, QB*G, PS]
-    if ks_ref is not None:
-        s = s * ks_ref[...].T[:, None, :]
-    if softcap:
-        # the TRUE score (post any int8 fold), matching the jnp path
-        s = softcap * jnp.tanh(s / softcap)
-
-    row = _div(lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2), n_groups)
-    col = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
-    q_pos = qpos0 + row - row_start  # valid only inside the row band
-    kv_pos = page_first + col
-    mask = (
-        (row >= row_start)
-        & (row < row_start + n_rows)
-        & (kv_pos <= q_pos)
-        & (kv_pos < kv_len)
-    )
-    if wv is not None:
-        mask = mask & ((wv <= 0) | (kv_pos > q_pos - wv))
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-
-    l_add = jnp.sum(p, axis=-1, keepdims=True)  # raw-probability denom
-    if vs_ref is not None:
-        p = p * vs_ref[...].T[:, None, :]
-    v = v_ref[...].astype(jnp.float32)
-    pv = lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())) if one_head
-        else (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32
-    )
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    l_ref[...] = l_ref[...] * alpha + l_add
-    m_ref[...] = m_new
+    # every pair the walk visits is live, so the step runs unguarded. What
+    # a row may see of it is the row's own: the block's token of each row,
+    # its position inside the unit's band, and from them the slots of the
+    # step below min(q_pos + 1, kv_len) and from the window's edge on; a
+    # row outside the band sees none
+    rows = lax.broadcasted_iota(jnp.int32, m_ref.shape, m_ref.ndim - 2)
+    if token_minor:
+        tok = (rows & (q_block - 1) if q_block & (q_block - 1) == 0
+               else _rem(rows, q_block))
+    else:
+        tok = _div(rows, n_groups)
+    in_band = (tok >= row_start) & (tok < row_start + n_rows)
+    q_pos = qpos0 + tok - row_start
+    n_valid = jnp.where(
+        in_band, jnp.minimum(q_pos + 1, kv_len) - i * page_size, 0)
+    lo_in_step = _window_lo(q_pos, wv) - i * page_size
+    _PAGE_ROUTINES[routine](
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
+        n_valid, lo_in_step, scale=scale, softcap=softcap)
 
     @pl.when(i == last)
     def _finalize():
@@ -374,43 +489,28 @@ def _ragged_kernel_body(
         # the buffer held outside every band of the block is whatever
         # was there: the wrapper's `covered` mask defines those rows
         denom = jnp.maximum(l_ref[...], 1e-30)
-        res = acc_ref[...] / denom  # [Hk, QB*G, D]
-        row = _div(lax.broadcasted_iota(jnp.int32, res.shape, res.ndim - 2),
-                   n_groups)
-        keep = (row >= row_start) & (row < row_start + n_rows)
+        res = acc_ref[...] / denom
         prev = o_ref[...].astype(jnp.float32)
-        o_ref[...] = jnp.where(keep, res, prev).astype(o_ref.dtype)
+        o_ref[...] = jnp.where(in_band, res, prev).astype(o_ref.dtype)
 
 
-def _ragged_kernel(wk, meta, pt, kl, ly, q, k, v, o, m, l, acc, **kw):
-    _ragged_kernel_body(wk, meta, pt, kl, None, q, k, v, None, None,
-                        o, m, l, acc, **kw)
-
-
-def _ragged_kernel_win(wk, meta, pt, kl, ly, win, q, k, v, o, m, l, acc,
-                       **kw):
-    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, None, None,
-                        o, m, l, acc, **kw)
-
-
-def _ragged_kernel_sink(wk, meta, pt, kl, ly, *refs, windowed, **kw):
-    """The call with a sink operand behind V (dense pools)."""
+def _ragged_kernel(wk, meta, pt, kl, ly, *refs, tiles, windowed, sinked,
+                   quantized, **kw):
+    """One signature for every call: a window operand first where there is
+    one, q, `tiles` refs each of K and V (an int8 pool: the page and its
+    scales), a sink operand behind them where there is one."""
     win, refs = (refs[0], refs[1:]) if windowed else (None, refs)
-    q, k, v, sink, o, m, l, acc = refs
-    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, None, None,
-                        o, m, l, acc, sink_ref=sink, **kw)
-
-
-def _ragged_kernel_int8(wk, meta, pt, kl, ly, q, k, ks, v, vs, o, m, l, acc,
-                        **kw):
-    _ragged_kernel_body(wk, meta, pt, kl, None, q, k, v, ks, vs,
-                        o, m, l, acc, **kw)
-
-
-def _ragged_kernel_int8_win(wk, meta, pt, kl, ly, win, q, k, ks, v, vs, o, m,
-                            l, acc, **kw):
-    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, ks, vs,
-                        o, m, l, acc, **kw)
+    q, refs = refs[0], refs[1:]
+    if quantized:
+        k, ks, v, vs, *rest = refs
+    else:
+        ks = vs = None
+        k, v, rest = refs[:tiles], refs[tiles:2 * tiles], refs[2 * tiles:]
+        if kw["routine"] == "by_heads":
+            (k,), (v,) = k, v
+    sink, rest = (rest[0], rest[1:]) if sinked else (None, rest)
+    _ragged_kernel_body(wk, meta, pt, kl, win, q, k, v, ks, vs, *rest,
+                        sink_ref=sink, **kw)
 
 
 def ragged_attention_reference(
@@ -424,6 +524,7 @@ def ragged_attention_reference(
     scale=None,
     softcap: float = 0.0,
     window=None,
+    sink=None,
 ) -> jax.Array:
     """jnp reference (and CPU fallback): each flat token is a B=T, S=1 row
     of the canonical paged_attention_jnp — per-token page table / kv_len /
@@ -440,6 +541,7 @@ def ragged_attention_reference(
         scale=scale,
         softcap=softcap,
         window=window,
+        sink=sink,
     )
     return out[:, 0]
 
@@ -455,7 +557,7 @@ def ragged_paged_attention_sharded(
     axis_name: str = AXIS_MODEL,
     window=None,
     layer=None,  # traced int32 scalar, replicated
-    work=None,  # ragged_work_list's triple, replicated (see below)
+    work=None,  # ragged_walk's `Walk`, replicated (see below)
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -471,26 +573,27 @@ def ragged_paged_attention_sharded(
         pool = {"q": pool, "s": scales}
     k_pool, v_pool, layer = stacked_pools(k_pool, v_pool, layer)
     scalars = scalar_operands(layer, window)
-    if work is None:  # the same on every shard: built once, outside
-        PS = jax.tree.leaves(k_pool)[0].shape[2]
-        work = ragged_work_list(meta, seg_kv_lens, window, PS,
-                                seg_page_table.shape[1], q.shape[0], q_block)
+    if work is None:  # the same on every shard: built once, outside, for
+        # the heads one shard is left with
+        Hk, G = q.shape[1] // mesh.shape[axis_name], q.shape[2]
+        work = ragged_walk((Hk, G), k_pool, v_pool, seg_page_table,
+                           seg_kv_lens, meta, window, q.shape[0], q_block)
 
-    def part(q, k_pool, v_pool, seg_pt, seg_kvl, meta, work, n_work, covered,
-             layer, window=None):
+    def part(q, k_pool, v_pool, seg_pt, seg_kvl, meta, work, layer, *window):
         return ragged_paged_attention(
-            q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, layer,
-            (work, n_work, covered), q_block=q_block, scale=scale,
-            softcap=softcap, interpret=interpret,
+            q, k_pool, v_pool, seg_pt, seg_kvl, meta,
+            window[0] if window else None, layer, work,
+            q_block=q_block, scale=scale, softcap=softcap,
+            interpret=interpret,
         )
 
     fn = jax.shard_map(
         part, mesh=mesh,
         in_specs=(heads, pool, pool, P(None, None), P(None), P(None, None),
-                  P(None), P(), P(None)) + (P(),) * len(scalars),
+                  P()) + (P(),) * len(scalars),
         out_specs=heads, check_vma=False,
     )
-    return fn(q, k_pool, v_pool, seg_page_table, seg_kv_lens, meta, *work,
+    return fn(q, k_pool, v_pool, seg_page_table, seg_kv_lens, meta, work,
               *scalars)
 
 
@@ -508,9 +611,10 @@ def ragged_paged_attention(
     meta: jax.Array,  # [5, NW] int32 work units (build_ragged_metadata)
     window=None,  # None = no-window compile; else traced int32 scalar
     layer=None,  # traced int32 scalar: the stacked pool's layer to read
-    work=None,  # ragged_work_list(meta, seg_kv_lens, window, PS, MP, T,
-    #   q_block), for a caller that runs many layers on one plan and
-    #   builds it once; None = built here
+    work=None,  # ragged_walk((Hk, G), k_pool, v_pool, seg_page_table,
+    #   seg_kv_lens, meta, window, T, q_block), for a caller that
+    #   runs many layers on one plan and builds it once; None = built here.
+    #   Its routine and pages a step are the call's
     *,
     q_block: int = DEFAULT_Q_BLOCK,
     scale=None,
@@ -539,79 +643,116 @@ def ragged_paged_attention(
     sinked = sink is not None
     if windowed:
         window = jnp.asarray(window, jnp.int32).reshape(())
-    work, n_work, covered = work or ragged_work_list(
-        meta, seg_kv_lens, window, PS, MP, T, q_block)
+    if quantized and sinked:
+        raise NotImplementedError("a sink over an int8 KV pool")
+    # the step's routine and its pages are the walk's, decided where its
+    # lists were written (`ragged_walk`)
+    if work is None:  # dynlint: disable=DYN-J001 (the argument's absence)
+        work = ragged_walk((Hk, G), k_pool, v_pool, seg_page_table,
+                           seg_kv_lens, meta, window, T, q_block)
+    routine, tiles = work.routine, work.tiles
+    steps = MP // tiles
 
-    # group axis merged into the rows HERE, in XLA: a [.., G, D] block
-    # pads G up to a full sublane tile in VMEM and Mosaic cannot
-    # shape-cast every (QB, G) split (G == 1 fails to lower)
-    qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
-    # one KV head, dense (ops/paged_attention.py "One KV head"): the pool
-    # as the step program carries it, [L, NP, PS, D], a page one contiguous
-    # [PS, D] tile, and the head axis dropped from every block
-    one_head = Hk == 1 and not quantized and not sinked and Dv == D
-    heads = () if one_head else (Hk,)
-    if one_head:
-        qt = qt.reshape(T * G, D)
-        kq, vq = (p.reshape(p.shape[:3] + (D,)) for p in (kq, vq))
+    # entry g of the list is `unit * steps + step` of a live pair; the
+    # unit names its q block and its segment (`meta`)
+    def unit_of(g, wk):
+        return _div(wk[g], steps)
 
-    # the index maps read the list, then the unit, then the page table:
-    # entry g is `unit * MP + page` of a live pair, so no page is clamped
-    def kv_index(g, wk, mt, pt, kl, ly, *rest):
-        return (ly[0], pt[mt[0, _div(wk[g], MP)], _rem(wk[g], MP)]
-                ) + (0,) * (kq.ndim - 2)
+    NB, QG = T // q_block, q_block * G
+    token_minor = routine == "by_tiles" and Hk > 1
+    if routine == "by_tiles":
+        # a q block as ONE matrix. Rows (head, group, token): a head's rows
+        # together for the columns' head mask, the token innermost so a
+        # row's token is `row & (q_block - 1)`, laid out HERE, in XLA: one
+        # transpose of q a call, as before. One KV head: q as it lies,
+        # rows (token, group)
+        qt = q.reshape(NB, q_block, Hk * G, D)
+        if token_minor:
+            qt = qt.transpose(0, 2, 1, 3)
+        qt = qt.reshape(NB, Hk * QG, D)
+        qo_block, state = (None, Hk * QG), (Hk * QG, 1)
 
-    def scale_index(g, wk, mt, pt, kl, ly, *rest):
-        return kv_index(g, wk, mt, pt, kl, ly, *rest)[1:4]
-
-    def q_index(g, wk, mt, *rest):
-        return (0,) * len(heads) + (mt[1, _div(wk[g], MP)], 0)
-
-    q_spec = pl.BlockSpec(heads + (q_block * G, D), q_index)
-    o_spec = (q_spec if Dv == D
-              else pl.BlockSpec(heads + (q_block * G, Dv), q_index))
-    # one token-major page of one layer = one contiguous PS*Hk*D slab
-    # (single DMA)
-    kv_spec = pl.BlockSpec((None, None, PS) + heads + (D,), kv_index)
-    v_spec = (kv_spec if Dv == D
-              else pl.BlockSpec((None, None, PS) + heads + (Dv,), kv_index))
-    kw = dict(page_size=PS, max_pages=MP, n_groups=G, scale=scale,
-              softcap=softcap)
-    if quantized:
-        kernel = functools.partial(
-            _ragged_kernel_int8_win if windowed else _ragged_kernel_int8,
-            **kw,
-        )
-        s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
-        in_specs = [q_spec, kv_spec, s_spec, kv_spec, s_spec]
-        operands = (qt, kq, ks, vq, vs)
-    elif sinked:
-        kernel = functools.partial(_ragged_kernel_sink, windowed=windowed, **kw)
-        # each row's head's sink: row r of a block is token r // G, group
-        # r % G, the same in every block
-        rows = jnp.tile(sink.astype(jnp.float32), (1, q_block))[..., None]
-        in_specs = [q_spec, kv_spec, v_spec,
-                    pl.BlockSpec((Hk, q_block * G, 1), lambda g, *rest: (0, 0, 0))]
-        operands = (qt, kq, vq, rows)
+        def qo_index(g, wk, mt, *rest):
+            return (mt[1, unit_of(g, wk)], 0, 0)
     else:
-        kernel = functools.partial(
-            _ragged_kernel_win if windowed else _ragged_kernel, **kw
-        )
-        in_specs = [q_spec, kv_spec, v_spec]
-        operands = (qt, kq, vq)
+        # group axis merged into the rows HERE, in XLA: a [.., G, D] block
+        # pads G up to a full sublane tile in VMEM and Mosaic cannot
+        # shape-cast every (QB, G) split (G == 1 fails to lower)
+        qt = q.transpose(1, 0, 2, 3).reshape(Hk, T * G, D)
+        qo_block, state = (Hk, QG), (Hk, QG, 1)
 
-    prefetch = (work, meta, seg_page_table, seg_kv_lens) + scalar_operands(
-        layer, window)
+        def qo_index(g, wk, mt, *rest):
+            return (0, mt[1, unit_of(g, wk)], 0)
+
+    q_spec = pl.BlockSpec(qo_block + (D,), qo_index)
+    kw = dict(page_size=PS * tiles, max_pages=steps, n_groups=G,
+              q_block=q_block, scale=scale, softcap=softcap, routine=routine,
+              token_minor=token_minor)
+    if routine == "by_tiles":
+        # `tiles` pages a step, each a block of its own on the same operand
+        # that finds its page in the walk's filled-in table: the
+        # token-major page as the pool holds it, one contiguous slab (a
+        # single DMA), or at one KV head the pool as the step program
+        # carries it, [L, NP, PS, D], a page one contiguous [PS, D] tile
+        # (ops/paged_attention.py "One KV head")
+        if Hk == 1:
+            kq, vq = (p.reshape(p.shape[:3] + p.shape[4:]) for p in (kq, vq))
+        zeros = (0,) * (kq.ndim - 2)
+
+        def tile_index(t, g, wk, mt, pg, kl, ly, *rest):
+            return (ly[0], pg[mt[0, unit_of(g, wk)] * MP
+                              + _rem(wk[g], steps) * tiles + t]) + zeros
+
+        def tile_specs(pool):
+            return [pl.BlockSpec((None, None) + pool.shape[2:],
+                                 functools.partial(tile_index, t))
+                    for t in range(tiles)]
+
+        in_specs = [q_spec] + tile_specs(kq) + tile_specs(vq)
+        operands = (qt,) + (kq,) * tiles + (vq,) * tiles
+    else:
+        # the index maps read the list, then the unit's segment, then its
+        # page-table row: no page is clamped, because no dead page is in
+        # the list
+        def kv_index(g, wk, mt, pt, kl, ly, *rest):
+            return (ly[0], pt[mt[0, unit_of(g, wk)], _rem(wk[g], MP)], 0, 0, 0)
+
+        def scale_index(g, wk, mt, pt, kl, ly, *rest):
+            return kv_index(g, wk, mt, pt, kl, ly, *rest)[1:4]
+
+        kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
+        v_spec = pl.BlockSpec((None, None, PS, Hk, Dv), kv_index)
+        if quantized:
+            s_spec = pl.BlockSpec((None, PS, Hk), scale_index)
+            in_specs = [q_spec, kv_spec, s_spec, v_spec, s_spec]
+            operands = (qt, kq, ks, vq, vs)
+        else:
+            in_specs = [q_spec, kv_spec, v_spec]
+            operands = (qt, kq, vq)
+    if sinked:
+        # each row's head's sink, the same in every block, in the rows' order
+        sink = sink.astype(jnp.float32)
+        rows = (jnp.repeat(sink.reshape(-1), q_block) if token_minor
+                else jnp.tile(sink, (1, q_block)))
+        in_specs.append(pl.BlockSpec(state, lambda g, *rest: (0,) * len(state)))
+        operands += (rows.reshape(state),)
+    kernel = functools.partial(
+        _ragged_kernel, tiles=tiles, windowed=windowed, sinked=sinked,
+        quantized=quantized, **kw)
+
+    prefetch = (work.work, meta,
+                seg_page_table if work.pages is None else work.pages,
+                seg_kv_lens) + scalar_operands(layer, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # work, meta, seg_pt, seg_kvl,
-        #   layer (+ window)
-        grid=(n_work,),  # a traced bound: the live pairs, not NW * MP
+        num_scalar_prefetch=len(prefetch),  # work, meta, seg_pt (the tile
+        #   routine: the walk's filled-in one), seg_kvl, layer (+ window)
+        grid=(work.n_work,),  # a traced bound: the live pairs, not NW * MP
         in_specs=in_specs,
-        out_specs=o_spec,
+        out_specs=pl.BlockSpec(qo_block + (Dv,), qo_index),
         scratch_shapes=[
-            pltpu.VMEM(heads + (q_block * G, 1), jnp.float32),
-            pltpu.VMEM(heads + (q_block * G, 1), jnp.float32),
-            pltpu.VMEM(heads + (q_block * G, Dv), jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(state[:-1] + (Dv,), jnp.float32),
         ],
     )
 
@@ -622,8 +763,11 @@ def ragged_paged_attention(
         interpret=interpret,
         **({"name": name} if name else {}),
     )(*prefetch, *operands)
-    # [Hk, T*G, D] -> [T, Hk, G, D]; a row that no visited unit covers
-    # (bucket padding, a segment without tokens) was never written:
-    # define it, as 0
-    out = out.reshape(Hk, T, G, Dv).transpose(1, 0, 2, 3)
-    return jnp.where(covered[:, None, None, None], out, 0)
+    # back to [T, Hk, G, Dv]; a row that no visited unit covers (bucket
+    # padding, a segment without tokens) was never written: define it, as 0
+    if token_minor:
+        out = out.reshape(NB, Hk * G, q_block, Dv).transpose(0, 2, 1, 3)
+    elif routine == "by_heads":
+        out = out.reshape(Hk, T, G, Dv).transpose(1, 0, 2, 3)
+    out = out.reshape(T, Hk, G, Dv)
+    return jnp.where(work.covered[:, None, None, None], out, 0)

@@ -28,10 +28,20 @@ kernel's table first (`--ragged --ragged-only`: that table alone): the
 cell's plans under its T buckets (288 for a 256-token chunk beside three
 decode rows of six pages, at prior 0 and at prior 256; 64 for a 32-row
 decode batch beside a 32-token chunk; 256 for a 128-token chunk beside
-the 34 decode rows of jamba2-reasoning-steady's step), us a call with the walk built once
+the 34 decode rows of jamba2-reasoning-steady's step; 288 again for
+mimo2-agent-steady's mixed step: 21 decode rows of 30 pages beside a
+256-token chunk on 768 tokens of context), us a call with the walk built once
 above the calls, us a live (work unit, page) pair, and the live share of
-the (NW, MP) grid the kernel took until PR 31. `--only NAME` keeps one
-geometry of both tables. The script runs unchanged on a checkout of an
+the (NW, MP) grid the kernel took until PR 31; the two `mimo-*` geometries
+under their page table 96 wide. `--q-block N` runs the ragged table at
+another q block (host and kernel alike); `--ragged --tiles-sweep` times
+the cell's mixed step at 1, 2, 4 and 8 pages a grid step where the
+checkout's kernel has the rule to set (`ragged_step_tiles`);
+`--ragged --routines` times the G = 1 geometries (`MHA_GEOMETRIES`: 4, 8,
+16 and 32 KV heads of one query head each) on the cell's mixed step and on
+the chunk-heavy plan with the routine forced either way, which is what
+`ragged_page_routine`'s G = 1 rule was read from. `--only NAME`
+keeps one geometry of both tables. The script runs unchanged on a checkout of an
 earlier commit, which is how two are compared.
 
 Timing rule: many iters fused in one jit via lax.scan with a data
@@ -90,6 +100,10 @@ GEOMETRIES = {
     "mimo-window": dict(Hk=8, G=8, D=256, Dv=128, window=128, sink=True,
                         pool_pages=165, mp=96, cell=MIMO_CELL_TOKENS),
 }
+# one query head a KV head (`--ragged --routines`): the tile routine does Hk
+# times the useful products there with no group to fill the rows
+MHA_GEOMETRIES = {
+    f"mha-{hk}": dict(Hk=hk, G=1, D=128, window=None) for hk in (4, 8, 16, 32)}
 ROWS, PAGES = (4, 32), (1, 7, 64)  # 64, 448 and 4096 tokens a row
 MIXED_PAGES = (1, 3, 7, 20, 0, 0, 0, 0)
 
@@ -269,6 +283,7 @@ def check_decode(interpret: bool) -> None:
 # the benchmark cell's mixed plans: (decode rows' contexts, (chunk tokens,
 # prior tokens)) under its ragged T buckets and a page table 64 wide
 RAGGED_T_BUCKETS = (32, 64, 128, 256, 288)
+CELL_MIXED = "chunk 256 @768 + 21 decode"
 RAGGED_PLANS = {
     "chunk 256 @0 + 3 decode": ((350, 380, 330), (256, 0)),
     "chunk 256 @256 + 3 decode": ((350, 380, 330), (256, 256)),
@@ -278,7 +293,11 @@ RAGGED_PLANS = {
     # decode batch (CELL_PAGES' contexts)
     "chunk 128 @0 + 34 decode": (tuple(n * PS - 7 for n in CELL_PAGES[:34]),
                                  (128, 0)),
+    # mimo2-agent-steady's mixed step at 1.44 requests/s: 21 decode rows at
+    # ~1.9 k tokens beside a 256-token chunk on 768 tokens of context
+    CELL_MIXED: (tuple(30 * PS - 5 - 3 * i for i in range(21)), (256, 768)),
 }
+Q_BLOCK = 8  # `--q-block`
 
 
 def ragged_plan(rng, decode_kv, chunk, mp, pool_pages=POOL_PAGES):
@@ -295,7 +314,7 @@ def ragged_plan(rng, decode_kv, chunk, mp, pool_pages=POOL_PAGES):
             for n in kv_lens]
     t = min(b for b in RAGGED_T_BUCKETS if b >= sum(q_lens))
     return build_ragged_metadata(q_lens, q_starts, kv_lens, rows, t,
-                                 max_pages=mp)
+                                 q_block=Q_BLOCK, max_pages=mp)
 
 
 def ragged_live_pairs(md, window) -> int:
@@ -309,9 +328,10 @@ def ragged_live_pairs(md, window) -> int:
     return int(np.sum(np.where((rows > 0) & (kv > 0), last - first + 1, 0)))
 
 
-@partial(jax.jit, static_argnames=("relist",),
+@partial(jax.jit, static_argnames=("relist", "q_block"),
          donate_argnames=("k_pool", "v_pool"))
-def ragged_loop(q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, relist):
+def ragged_loop(q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, sink,
+                relist, q_block):
     """ITERS chained ragged calls on one plan. The walk is built once,
     above the scan, as llama.forward builds it above its layers, where
     the kernel takes one (`relist`: inside, every call)."""
@@ -319,9 +339,13 @@ def ragged_loop(q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, relist):
 
     build = getattr(rg, "ragged_work_list", None)
     page_size, mp = k_pool.shape[2], seg_pt.shape[1]
+    kw = {"q_block": q_block, **({} if sink is None else {"sink": sink})}
 
     def walk(kvl):
-        return (build(meta, kvl, window, page_size, mp, q.shape[0]),)
+        if hasattr(rg, "ragged_walk"):  # the lists of the call's routine
+            return (rg.ragged_walk(q.shape[1:3], k_pool, v_pool, seg_pt, kvl,
+                                   meta, window, q.shape[0], q_block),)
+        return (build(meta, kvl, window, page_size, mp, q.shape[0], q_block),)
 
     hoisted = walk(seg_kvl) if build and not relist else ()
 
@@ -331,49 +355,100 @@ def ragged_loop(q, k_pool, v_pool, seg_pt, seg_kvl, meta, window, relist):
             kvl = kvl + (q[0, 0, 0, 0] > 3e38).astype(jnp.int32)
             work = walk(kvl) if build else ()
         o = rg.ragged_paged_attention(q, k_pool, v_pool, seg_pt, kvl, meta,
-                                      window, jnp.minimum(i, LAYER), *work)
+                                      window, jnp.minimum(i, LAYER), *work,
+                                      **kw)
+        # (values narrower than keys: the output repeated to a query's width)
+        o = jnp.tile(o, q.shape[-1] // o.shape[-1])
         return o.astype(q.dtype), None
 
     q, _ = lax.scan(body, q, jnp.arange(ITERS) + LAYER)
     return q, k_pool, v_pool
 
 
-def bench_ragged_table() -> None:
-    """The ragged (mixed-step) kernel on the cell's plans, one JSON line a
-    point: us a call and us a live (work unit, page) pair, with the
-    share of the (NW, MP) grid that is live."""
+def bench_ragged_table(plans=None, label="") -> None:
+    """The ragged (mixed-step) kernel on the cell's plans (`plans`: those
+    named), one JSON line a point: us a call and us a live (work unit,
+    page) pair, with the share of the (NW, MP) grid that is live."""
     for gname, geom in GEOMETRIES.items():
-        if "Dv" in geom:  # the decode table's geometries
-            continue
         hk, g, d, window = geom["Hk"], geom["G"], geom["D"], geom["window"]
-        pool_pages = geom.get("pool_pages", POOL_PAGES)
+        pool_pages, mp = geom.get("pool_pages", POOL_PAGES), geom.get("mp", 64)
         pools = make_pools(geom)
         win = None if window is None else jnp.int32(window)
         for name, (decode_kv, chunk) in RAGGED_PLANS.items():
+            if plans and name not in plans:
+                continue
             rng = np.random.default_rng(0)
-            md = ragged_plan(rng, decode_kv, chunk, 64, pool_pages)
+            md = ragged_plan(rng, decode_kv, chunk, mp, pool_pages)
             t, nw = md["tok_positions"].shape[0], md["meta"].shape[1]
             q = jnp.asarray(rng.standard_normal((t, hk, g, d)), jnp.bfloat16)
+            sink = (jnp.asarray(rng.standard_normal((hk, g)), jnp.float32)
+                    if geom.get("sink") else None)
             tail = tuple(jnp.asarray(md[k]) for k in
-                         ("seg_page_table", "seg_kv_lens", "meta")) + (win,)
+                         ("seg_page_table", "seg_kv_lens", "meta")) + (win, sink)
 
             def call(relist):
                 out, pools[0], pools[1] = ragged_loop(
-                    q, pools[0], pools[1], *tail, relist=relist)
+                    q, pools[0], pools[1], *tail, relist=relist,
+                    q_block=Q_BLOCK)
                 return out
 
             live = ragged_live_pairs(md, window)
             us = _time(partial(call, False))
-            line = {"point": f"ragged {gname} {name}", "T": t,
-                    "page_table": 64, "units": int(md["n_work"]),
+            line = {"point": f"ragged {gname} {name}{label}", "T": t,
+                    "page_table": mp, "units": int(md["n_work"]),
                     "live_pairs": live,
-                    "live_share_of_grid": round(live / (nw * 64), 4),
+                    "live_share_of_grid": round(live / (nw * mp), 4),
                     "us_call": round(us, 1),
                     "us_live_pair": round(us / live, 3)}
             if name.startswith("chunk 256 @0"):
                 line["us_call_relisted"] = round(_time(partial(call, True)), 1)
             print(json.dumps(line), flush=True)
         del pools
+
+
+def bench_ragged_tiles_sweep() -> None:
+    """The cell's mixed step at 1, 2, 4 and 8 pages a grid step: the
+    kernel's rule (`ragged_step_tiles`) replaced for the sweep."""
+    import math
+
+    from dynamo_tpu.ops import ragged_paged_attention as rg
+
+    if not hasattr(rg, "ragged_step_tiles"):
+        print("this checkout's ragged kernel has no pages-a-step rule to "
+              "sweep", flush=True)
+        return
+    rule = rg.ragged_step_tiles
+    for tiles in (1, 2, 4, 8):
+        rg.ragged_step_tiles = lambda *a, t=tiles: math.gcd(a[-1], t)
+        jax.clear_caches()
+        try:
+            bench_ragged_table((CELL_MIXED,), f" tiles {tiles}")
+        except Exception as e:  # a step Mosaic refuses (VMEM)
+            print(json.dumps({"tiles": tiles, "refused": str(e)[-300:]}),
+                  flush=True)
+    rg.ragged_step_tiles = rule
+    jax.clear_caches()
+
+
+def bench_ragged_routines() -> None:
+    """The G = 1 geometries with the ragged routine forced by heads and by
+    tiles (`ragged_page_routine` replaced for the run), on the cell's mixed
+    step and on the plan that is mostly a chunk."""
+    from dynamo_tpu.ops import ragged_paged_attention as rg
+
+    if not hasattr(rg, "ragged_page_routine"):
+        print("this checkout's ragged kernel has one routine", flush=True)
+        return
+    rule = rg.ragged_page_routine
+    GEOMETRIES.clear()
+    GEOMETRIES.update(MHA_GEOMETRIES)
+    for routine in ("by_heads", "by_tiles"):
+        rg.ragged_page_routine = lambda *a, r=routine: r
+        jax.clear_caches()
+        bench_ragged_table((CELL_MIXED, "chunk 256 @256 + 3 decode"),
+                           f" {routine}")
+    rg.ragged_page_routine = rule
+    jax.clear_caches()
 
 
 def check_ragged(interpret: bool) -> None:
@@ -419,6 +494,15 @@ def main() -> None:
         only = {args[args.index("--only") + 1]}
         for name in GEOMETRIES.keys() - only:
             del GEOMETRIES[name]
+    if "--q-block" in args:
+        global Q_BLOCK
+        Q_BLOCK = int(args[args.index("--q-block") + 1])
+    if "--ragged" in args and "--tiles-sweep" in args:
+        bench_ragged_tiles_sweep()
+        return
+    if "--ragged" in args and "--routines" in args:
+        bench_ragged_routines()
+        return
     if "--ragged" in args:
         bench_ragged_table()
     impls = ("pallas", "jnp") if "--jnp" in args else ("pallas",)

@@ -15,7 +15,7 @@ f32, i32 = jnp.float32, jnp.int32
 def s(d, t): return jax.ShapeDtypeStruct(d, t)
 def samp(B): return SamplingParams(s((B,), f32), s((B,), i32), s((B,), f32), s((B, 2), jnp.uint32), s((B,), f32), s((B,), f32), s((B,), f32))
 out = {}
-for name in ("phi-3-mini-4k", "mistral-small-4-119b"):
+for name in ("phi-3-mini-4k", "mistral-small-4-119b", "deepseek-v3.2"):
     cfg = json.load(open(os.path.join(root, "benchmark", "configs", name + ".json")))
     c = ModelConfig(**cfg["model"])
     flags = cfg["server_flags"]
@@ -30,7 +30,8 @@ for name in ("phi-3-mini-4k", "mistral-small-4-119b"):
         out[f"{name}/{impl}/forward"] = dig(partial(fwd, c, attn_impl=impl), params, s((1, 128), i32), s((1, 128), i32), *pools, s((1, MP), i32), s((1,), i32), s((), i32))
         N, S = 2, 128
         kw = {"prows": s((), i32)} if c.is_moe else {}
-        out[f"{name}/{impl}/mixed"] = dig(partial(mr._mixed_loop, c, impl, None, 4), params, s((N, S), i32), s((N, S), i32), s((N, MP), i32), s((N,), i32), s((N,), i32), None, s((B,), i32), s((B + B * MP + 1,), i32), *pools, samp(B), **kw)
+        if not c.has_indexer:  # (no fused mixed program: Runner.fuses_mixed)
+            out[f"{name}/{impl}/mixed"] = dig(partial(mr._mixed_loop, c, impl, None, 4), params, s((N, S), i32), s((N, S), i32), s((N, MP), i32), s((N,), i32), s((N,), i32), None, s((B,), i32), s((B + B * MP + 1,), i32), *pools, samp(B), **kw)
         if not c.is_mla:
             T = 288
             md = build_ragged_metadata([1] * 8 + [100], [5] * 8 + [0], [6] * 8 + [100], [[1]] * 8 + [[2, 3]], T, q_block=8, max_pages=MP)

@@ -64,7 +64,10 @@ import numpy as np
 from dynamo_tpu.models.quant import kv_pool_quantize
 from dynamo_tpu.models.toolkit import paged_attention_jnp
 from dynamo_tpu.ops.flash_prefill import prefill_paged_attention
-from dynamo_tpu.ops.paged_attention import decode_paged_attention
+from dynamo_tpu.ops.paged_attention import (
+    decode_paged_attention,
+    decode_paged_attention_sharded,
+)
 from dynamo_tpu.ops.ragged_paged_attention import (
     build_ragged_metadata,
     ragged_paged_attention,
@@ -153,19 +156,27 @@ def _variant(geom, variant):
             SOFTCAP if windowed else 0.0)
 
 
-def _decode_err(geom, variant, rng, kv) -> float:
+def _decode_err(geom, variant, rng, kv, mesh=None) -> float:
     """Decode kernel against the f32 reference on rows of `kv` tokens; a
-    pad row (kv_len 0) reads no page and must come back finite."""
+    pad row (kv_len 0) reads no page and must come back finite; through
+    the tensor-parallel wrapper where a mesh is given."""
     quantized, window, softcap = _variant(geom, variant)
     B, Hk, G, D = len(kv), geom["Hk"], geom["G"], geom["D"]
     pool = _Pool(rng, geom, int(np.sum(-(-kv // geom["PS"]))) + 4, quantized)
     pt = pool.table(kv)
     q = jnp.asarray(rng.standard_normal((B, Hk, G, D)), jnp.bfloat16)
-    out = decode_paged_attention(
-        q, pool.k, pool.v, jnp.asarray(pt), jnp.asarray(kv),
-        None if window is None else jnp.int32(window), jnp.int32(LAYER),
-        softcap=softcap, interpret=INTERPRET,
-    )
+    win = None if window is None else jnp.int32(window)
+    if mesh is None:
+        out = decode_paged_attention(
+            q, pool.k, pool.v, jnp.asarray(pt), jnp.asarray(kv), win,
+            jnp.int32(LAYER), softcap=softcap, interpret=INTERPRET,
+        )
+    else:
+        out = decode_paged_attention_sharded(
+            q, pool.k, pool.v, jnp.asarray(pt), jnp.asarray(kv), mesh,
+            window=win, layer=jnp.int32(LAYER), softcap=softcap,
+            interpret=INTERPRET,
+        )
     ref = _ref(q.astype(jnp.float32)[:, None], pool, pt,
                np.maximum(kv - 1, 0)[:, None], kv, window, softcap)[:, 0]
     out = np.asarray(out, np.float32)
@@ -181,7 +192,7 @@ def check_decode(geom, variant) -> float:
     return _decode_err(geom, variant, rng, kv)
 
 
-def check_decode_ragged(geom, variant) -> float:
+def check_decode_ragged(geom, variant, mesh=None) -> float:
     """The page walk's edges in one batch: a pad row, 1 token, whole
     pages, one token into a page, the whole page table, and (windowed
     variants) a window that cuts leading pages and one that starts
@@ -189,7 +200,7 @@ def check_decode_ragged(geom, variant) -> float:
     PS, MP = geom["PS"], geom["MP"]
     kv = np.asarray([0, 1, PS * 3, PS * 3 + 1, PS * MP, geom["ctx"] + 17,
                      PS, 0], np.int32)
-    return _decode_err(geom, variant, np.random.default_rng(7), kv)
+    return _decode_err(geom, variant, np.random.default_rng(7), kv, mesh)
 
 
 def check_prefill(geom, variant) -> float:
@@ -818,10 +829,17 @@ def all_checks():
         for gname, geom in GEOMETRIES.items():
             if geom["Hk"] % len(jax.devices()):
                 continue  # one KV head: nothing to shard
+            # (a shard's page is 1 / devices of the pool's: the walks are
+            # built outside shard_map and must count a step's pages as the
+            # shard's kernel does)
             for variant in VARIANTS:
                 checks.append((
                     f"ragged sparse-table sharded {variant} @{gname}",
                     functools.partial(check_ragged_sparse, geom, variant,
+                                      mesh)))
+                checks.append((
+                    f"decode ragged-batch sharded {variant} @{gname}",
+                    functools.partial(check_decode_ragged, geom, variant,
                                       mesh)))
     checks += [
         ("gemma decode (softcap+window, G 2, PS 16)", check_gemma_decode),

@@ -526,7 +526,10 @@ def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
     one decision the kernel's wrapper makes: every dense pool that is not
     `by_rows` is the tile routine (one KV head its one-head case); the jnp
     gather has none; a model whose window layers keep a pool of their own
-    names a routine a kind (its window layers carry a sink)."""
+    names a routine a kind (its window layers carry a sink). The ragged
+    (mixed-step) kernel's routine beside it, from that kernel's own decision:
+    the tile routine for every dense pool but G = 1 at 32 KV heads or more,
+    the float32 product a head there and for an int8 pool."""
     import logging
 
     kw = dict(num_pages=8, page_size=4, params=params, dtype=jnp.float32)
@@ -534,16 +537,26 @@ def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
         on_kernel = ModelRunner(C, attn_impl="pallas", **kw)
     assert on_kernel.device_report()["decode_page_routine"] == "by_tiles"
     assert "decode_page_routine=by_tiles" in caplog.text
-    assert ModelRunner(C, **kw).device_report()["decode_page_routine"] is None
+    assert on_kernel.device_report()["ragged_page_routine"] == "by_tiles"
+    assert "ragged_page_routine=by_tiles" in caplog.text
+    on_gather = ModelRunner(C, **kw).device_report()
+    assert on_gather["decode_page_routine"] is on_gather["ragged_page_routine"] is None
     wide = ModelRunner(get_config("tiny"), num_pages=8, page_size=4, attn_impl="pallas")
     assert wide.device_report()["decode_page_routine"] == "by_tiles"
     mha = get_config("tiny").with_(n_heads=8, n_kv_heads=8)
     by_rows = ModelRunner(mha, num_pages=8, page_size=4, attn_impl="pallas",
                           dtype=jnp.float32)
     assert by_rows.device_report()["decode_page_routine"] == "by_rows"
+    # the ragged rule is that kernel's own: G = 1 keeps the product a head
+    # from 32 KV heads on, whatever the pool's dtype
+    assert by_rows.device_report()["ragged_page_routine"] == "by_tiles"
+    mha32 = ModelRunner(get_config("tiny").with_(n_heads=32, n_kv_heads=32),
+                        num_pages=8, page_size=4, attn_impl="pallas")
+    assert mha32.device_report()["ragged_page_routine"] == "by_heads"
     int8 = ModelRunner(get_config("tiny"), num_pages=8, page_size=4,
                        attn_impl="pallas", kv_quantize="int8")
     assert int8.device_report()["decode_page_routine"] == "by_heads"
+    assert int8.device_report()["ragged_page_routine"] == "by_heads"
     caplog.clear()
     with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.runner"):
         two_kinds = ModelRunner(get_config("tiny-mimo"), num_pages=8, page_size=4,
@@ -551,6 +564,9 @@ def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
     assert two_kinds.device_report()["decode_page_routine"] == {
         "global": "by_tiles", "window": "by_tiles"}
     assert "decode_page_routine={'global': 'by_tiles', 'window': 'by_tiles'}" in caplog.text
+    assert two_kinds.device_report()["ragged_page_routine"] == {
+        "global": "by_tiles", "window": "by_tiles"}
+    assert "ragged_page_routine={'global': 'by_tiles', 'window': 'by_tiles'}" in caplog.text
 
 
 def test_a_pool_with_fewer_slots_than_rows_is_refused():

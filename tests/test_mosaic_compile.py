@@ -120,6 +120,124 @@ def test_sharded_decode_kernel_compiles_for_v5e_2x2(topo, name):
     assert "all-reduce(" not in text and "all-gather(" not in text
 
 
+# -- the ragged (mixed-step) kernel at the geometries the benchmark's cells
+# run it at. name -> T, Hk, G, D, L, NP, PS, MP, windowed, int8 KV, Dv, a sink
+_RAGGED_SHAPES = {
+    "phi3-t288": (288, 32, 1, 96, 32, 176, 64, 64, True, False, 96, False),
+    "jamba2-t320": (320, 1, 20, 128, 2, 2880, 64, 64, False, False, 128, False),
+    "mimo-global-t288": (288, 4, 16, 256, 2, 4096, 64, 96, False, False, 128, False),
+    "mimo-window-t288": (288, 8, 8, 256, 9, 165, 64, 96, True, False, 128, True),
+    # a two-chip shard of phi-3: G 1 under 32 KV heads takes the tile routine
+    "phi3-a-shard-t288": (288, 16, 1, 96, 32, 176, 64, 64, True, False, 96, False),
+    # the float32 body a head, which an int8 pool (and phi-3 whole) keeps
+    "llama3.2-int8-t288": (288, 8, 3, 128, 28, 256, 64, 64, True, True, 128, False),
+}
+# what the kernel's body may hold, about 1.5 x what it does (139 equations at
+# its longest, the window layers' call with a sink): every ragged T bucket's
+# program of mimo2-agent-steady lowers it eleven times, and a first form of
+# PR 45's select kernel at 17 k equations took that cell's set-up from 134 s
+# to 356
+_RAGGED_KERNEL_EQUATIONS = 210
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _equations(inner)
+    return n
+
+
+@pytest.mark.parametrize("name", list(_RAGGED_SHAPES))
+def test_ragged_kernel_compiles_for_v5e_and_reads_the_pools_in_place(topo, name):
+    """One Mosaic call, its name the one a trace's readers look for, the
+    pools read as the step programs carry them (no copy, slice or reshape
+    that is no bitcast of a pool's shape, whole or a 4-d view), and the
+    kernel's text within its bound."""
+    import re
+
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention, ragged_seg_cap, ragged_work_cap,
+    )
+
+    T, Hk, G, D, L, NP, PS, MP, windowed, int8, Dv, sinked = _RAGGED_SHAPES[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def pool(width):
+        data = s((L, NP, PS, Hk, width), jnp.int8 if int8 else jnp.bfloat16)
+        return {"q": data, "s": s((L, NP, PS, Hk), jnp.float32)} if int8 else data
+
+    i32, SEG = jnp.int32, ragged_seg_cap(T)
+    args = (s((T, Hk, G, D), jnp.bfloat16), pool(D), pool(Dv), s((SEG, MP), i32),
+            s((SEG,), i32), s((5, ragged_work_cap(T)), i32),
+            s((), i32) if windowed else None, s((), i32))
+    kw = {"sink": s((Hk, G), jnp.float32)} if sinked else {}
+    text = jax.jit(ragged_paged_attention).lower(*args, **kw).compile().as_text()
+    calls = [l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for l in text.splitlines() if "tpu_custom_call" in l and " = " in l]
+    assert calls == ["ragged_paged_attention"]
+    moved = re.compile(
+        rf"= (bf16|s8)\[({L},)?{NP},{PS},({Hk},)?({D}|{Dv}|{Hk * D}|{Hk * Dv})\]\S* "
+        r"(copy|reshape|dynamic-slice|slice)\(")
+    assert [l.strip()[:160] for l in text.splitlines() if moved.search(l)] == []
+    jaxpr = jax.make_jaxpr(lambda *a: ragged_paged_attention(*a, **kw))(*args)
+    (call,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    assert _equations(call.params["jaxpr"]) <= _RAGGED_KERNEL_EQUATIONS
+
+
+@pytest.mark.parametrize("name, tiles", [("llama3.2-t288", 8),
+                                         ("mimo-window-t288", 8),
+                                         ("phi3-t288", 4)])
+def test_sharded_ragged_kernel_compiles_for_v5e_2x2(topo, name, tiles):
+    """Heads over four chips. The walk is built outside `shard_map`, on
+    pools that still have every head, FOR a shard's heads: a step brings the
+    pages a shard's page of a quarter the bytes allows (Llama-3.2-3B's 8 KV
+    heads: 8 where the whole pool's page would say 2; the widths of
+    mimo-v2-flash's window layers, keys 256 beside values 128: 8 where 1;
+    phi-3's 32 heads of one query each leave a shard 8, under the 32 from
+    which G = 1 keeps the product a head: the tile routine, 4 pages a step),
+    the list's length says so, and Mosaic takes the shard's kernel at that
+    count; no collective appears."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention_sharded, ragged_seg_cap, ragged_work_cap,
+    )
+    from dynamo_tpu.parallel.mesh import AXIS_MODEL, attention_specs
+
+    shapes = dict(_RAGGED_SHAPES, **{
+        "llama3.2-t288": (288, 8, 3, 128, 28, 256, 64, 64, True, False, 128, False)})
+    T, Hk, G, D, L, NP, PS, MP, windowed, _, Dv, _ = shapes[name]
+    mesh = Mesh(np.array(topo.devices).reshape(4), (AXIS_MODEL,))
+    heads, pool, _ = attention_specs(AXIS_MODEL)
+
+    def s(dims, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    i32, SEG = jnp.int32, ragged_seg_cap(T)
+    args = (s((T, Hk, G, D), jnp.bfloat16, heads),
+            s((L, NP, PS, Hk, D), jnp.bfloat16, pool),
+            s((L, NP, PS, Hk, Dv), jnp.bfloat16, pool), s((SEG, MP), i32),
+            s((SEG,), i32), s((5, ragged_work_cap(T)), i32), s((), i32), s((), i32))
+
+    def fn(q, k, v, pt, kl, meta, window, layer):
+        return ragged_paged_attention_sharded(
+            q, k, v, pt, kl, meta, mesh, window=window, layer=layer)
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-reduce(" not in text and "all-gather(" not in text
+    # the list the call walks: (work units) x (steps a unit can take)
+    assert f"s32[{ragged_work_cap(T) * MP // tiles}]" in text
+
+
 # -- latent attention at Mistral-Small-4's geometry (benchmark cell
 # mistral4-chat-steady): 32 heads, latent rank 256 + a rotary key of 64, so
 # the cached vector is 320 wide (2.5 lane tiles), 384 pages of 64 tokens under
@@ -539,3 +657,58 @@ def test_a_window_pool_models_decode_loop_reads_both_pools_in_place(topo):
     text = jax.jit(viewed, donate_argnums=(0,)).lower(
         pools[0], s((2,), i32)).compile().as_text()
     assert [l for l in text.splitlines() if moved.search(l)]
+
+
+def test_a_window_pool_models_ragged_step_reads_both_pools_in_place(topo):
+    """mimo-v2-flash's mixed step as its cell runs it (T bucket 288, a page
+    table 96 wide, the cell's pools): the ragged kernel under its two names,
+    two global calls and the window layers' in their scan, each taking its
+    step's pages as the pool holds them; no copy, slice or reshape of either
+    pool's shape, a layer's or a 4-d view's, anywhere in the program (the q
+    blocks' own transposes are `[36, 64, 8, 256]`: no pool's shape)."""
+    import json
+    import os
+    import re
+    from functools import partial
+
+    from dynamo_tpu.engine.model_runner import _ragged_step
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.models import llama, mimo
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops.ragged_paged_attention import ragged_seg_cap, ragged_work_cap
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", "mimo-v2-flash.json")) as f:
+        cfg = json.load(f)
+    c = ModelConfig(**cfg["model"])
+    NP, NPW, PS = cfg["server_flags"]["num-pages"], 165, cfg["server_flags"]["page-size"]
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(lambda: llama.make_kv_pool(c, NP, PS, dtype=jnp.bfloat16)))
+    state = on_chip(jax.eval_shape(lambda: mimo.make_window_pool(c, NPW, PS)))
+    T, MP, f32, i32 = 288, 96, jnp.float32, jnp.int32
+    SEG, V = ragged_seg_cap(T), c.vocab_size
+    samp = SamplingParams(s((SEG,), f32), s((SEG,), i32), s((SEG,), f32), s((SEG, 2), jnp.uint32),
+                          s((SEG,), f32), s((SEG,), f32), s((SEG,), f32))
+    text = jax.jit(partial(_ragged_step, c, "pallas", None), donate_argnums=(9, 10),
+                   donate_argnames=("state",)).lower(
+        params, s((1, T), i32), s((1, T), i32), s((T, MP), i32), s((T,), i32),
+        s((SEG, MP), i32), s((SEG,), i32), s((5, ragged_work_cap(T)), i32), s((SEG,), i32),
+        *pools, samp, s((SEG,), i32), s((SEG,), i32), s((), i32), s((SEG, V), jnp.bool_),
+        s((SEG, V), f32), state=state,
+        seg_slots=(s((T, MP), i32), s((SEG, MP), i32))).compile().as_text()
+    kernels = {l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for l in text.splitlines() if "tpu_custom_call" in l and " = " in l}
+    assert kernels == {"ragged_paged_attention", "window_attention_ragged"}
+    moved = re.compile(
+        rf"= bf16\[((2|9),)?({NP}|{NPW}),{PS},(4,(256|128)|8,(256|128)|1024|512|2048)\]\S* "
+        r"(copy|reshape|dynamic-slice|slice)\(")
+    assert [l.strip()[:160] for l in text.splitlines() if moved.search(l)] == []

@@ -237,6 +237,8 @@ _WALK_GEOMS = {
     "two-widths-h2-g3": (2, 3, 128, 8, 6, 64),
     "two-widths-h4-g16": (4, 16, 64, 8, 7, 32),
     "two-widths-mha": (16, 1, 64, 8, 6, 32),
+    # Llama-3.2-3B at pages of 64: 4 pages a step whole, 8 a shard of two
+    "llama-ps64": (8, 3, 128, 64, 8),
 }
 # name -> (window or None, softcap, int8 KV, sink, pool dtype)
 _BF, _F32 = jnp.bfloat16, jnp.float32
@@ -342,11 +344,14 @@ def test_decode_walk_reads_stacked_pool_layer(geom, layer):
     ("gemma-ps16", "window-mid-page"), ("gemma-ps16", "int8"),
     ("gqa-h4-g16", "window-zero-is-global"), ("gqa-h8-g2-mp12", "softcap"),
     ("two-widths-h4-g16", "window-mid-page"),
-    ("two-widths-h2-g3", "window-sink-f32"), ("gqa-h4-g16", "sink")])
+    ("two-widths-h2-g3", "window-sink-f32"), ("gqa-h4-g16", "sink"),
+    ("llama-ps64", "plain"), ("llama-ps64", "window-mid-page")])
 def test_decode_walk_sharded(geom, variant):
     """Heads over two shards, each the tile routine on its local heads:
     gqa-g4's two KV heads leave each shard ONE, on its local 4-d view (int8:
-    by heads)."""
+    by heads). llama-ps64: the walk is built outside `shard_map`, where the
+    pool's page is 256 KB (4 pages a step), for a shard that sees 128 KB
+    (8): the count is the shard's, and the kernel reads it off the walk."""
     from dynamo_tpu.ops.paged_attention import decode_paged_attention_sharded
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
@@ -1259,14 +1264,21 @@ _RAGGED_PLANS = {
 }
 
 
-def _ragged_plan(name, seed=23, forked=False):
+def _plan_of(name, shift=0):
+    """A plan, every segment `shift` tokens further into its context."""
+    q_lens, q_starts, kv_lens, T = _RAGGED_PLANS[name]
+    return (q_lens, [n + shift for n in q_starts],
+            [n + shift for n in kv_lens], T)
+
+
+def _ragged_plan(name, seed=23, forked=False, ps=_RAGGED_PS, shift=0):
     from dynamo_tpu.ops.ragged_paged_attention import build_ragged_metadata
 
-    q_lens, q_starts, kv_lens, T = _RAGGED_PLANS[name]
+    q_lens, q_starts, kv_lens, T = _plan_of(name, shift)
     rng = np.random.default_rng(seed)
-    NP = 2 + sum(-(-n // _RAGGED_PS) for n in kv_lens)
+    NP = 2 + sum(-(-n // ps) for n in kv_lens)
     free = list(rng.permutation(NP - 2))
-    rows = [[int(free.pop()) for _ in range(-(-n // _RAGGED_PS))]
+    rows = [[int(free.pop()) for _ in range(-(-n // ps))]
             for n in kv_lens]
     if forked:  # a tree's branch: the trunk's pages shared by reference
         rows[3][: len(rows[2]) - 1] = rows[2][:-1]
@@ -1275,12 +1287,12 @@ def _ragged_plan(name, seed=23, forked=False):
     return md, NP
 
 
-def _oracle_pairs(name, window):
+def _oracle_pairs(name, window, ps=_RAGGED_PS, shift=0):
     """The live (unit, page) pairs by the kernel docstring's rule, from
     the plan itself: a unit is a (q block, segment) overlap of `rows`
     rows from position qpos0; it sees pages first .. last."""
-    q_lens, q_starts, kv_lens, _ = _RAGGED_PLANS[name]
-    PS, MP, QB = _RAGGED_PS, _RAGGED_MP, 8
+    q_lens, q_starts, kv_lens, _ = _plan_of(name, shift)
+    PS, MP, QB = ps, _RAGGED_MP, 8
     pairs, w, lo = [], 0, 0
     for s, ln in enumerate(q_lens):
         hi = lo + ln
@@ -1333,16 +1345,19 @@ _RAGGED_VARIANTS = {
     "plain": (None, 0.0, False),
     "window": (6, 0.0, False),
     "softcap": (None, 30.0, False),
+    "window-softcap": (6, 30.0, False),
     "int8": (None, 0.0, True),
     "int8-window-softcap": (6, 30.0, True),
 }
 
 
 def _ragged_run(plan, variant, geom=(2, 3, 64), poison=False, forked=False,
-                sharded=False):
+                sharded=False, dv=None, sinked=False, ps=_RAGGED_PS, shift=0):
     """(kernel output, reference, real-row mask) of a plan; `poison`: every
     pool page outside the live pairs holds NaN and every page-table entry
-    outside them names an unowned NaN page."""
+    outside them names an unowned NaN page; `dv`: values narrower than
+    keys; `sinked`: a sink logit a query head; `ps`, `shift`: pages of `ps`
+    tokens, every segment `shift` tokens further into its context."""
     from dynamo_tpu.ops.ragged_paged_attention import (
         ragged_attention_reference, ragged_paged_attention,
         ragged_paged_attention_sharded,
@@ -1350,16 +1365,19 @@ def _ragged_run(plan, variant, geom=(2, 3, 64), poison=False, forked=False,
 
     Hk, G, D = geom
     window, softcap, quant = _RAGGED_VARIANTS[variant]
-    md, NP = _ragged_plan(plan, forked=forked)
+    md, NP = _ragged_plan(plan, forked=forked, ps=ps, shift=shift)
     T = md["tok_positions"].shape[0]
     rng = np.random.default_rng(29)
     q = jnp.asarray(rng.standard_normal((T, Hk, G, D)), jnp.bfloat16)
-    kp = jnp.asarray(rng.standard_normal((NP, _RAGGED_PS, Hk, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((NP, _RAGGED_PS, Hk, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((NP, ps, Hk, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((NP, ps, Hk, dv or D)),
+                     jnp.bfloat16)
+    sink = ({"sink": jnp.asarray(rng.standard_normal((Hk, G)), jnp.float32)}
+            if sinked else {})
     seg_pt = md["seg_page_table"]
     if poison:
         live = np.zeros(seg_pt.shape, bool)
-        for w, p in _oracle_pairs(plan, window or 0):
+        for w, p in _oracle_pairs(plan, window or 0, ps, shift):
             live[md["meta"][0, w], p] = True
         dead = jnp.asarray(np.setdiff1d(np.arange(NP), seg_pt[live]))
         kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
@@ -1376,11 +1394,11 @@ def _ragged_run(plan, variant, geom=(2, 3, 64), poison=False, forked=False,
             softcap=softcap, interpret=True)
     else:
         out = ragged_paged_attention(q, kq, vq, *seg, win, softcap=softcap,
-                                     interpret=True)
+                                     interpret=True, **sink)
     ref = ragged_attention_reference(
         q, kq, vq, jnp.asarray(md["tok_page_table"]),
         jnp.asarray(md["tok_positions"]), jnp.asarray(md["tok_kv_lens"]),
-        softcap=softcap, window=win)
+        softcap=softcap, window=win, **sink)
     return (np.asarray(out, np.float32), np.asarray(ref, np.float32),
             md["tok_positions"] >= 0)
 
@@ -1404,6 +1422,172 @@ def test_ragged_walk_geometries(geom):
     """phi-3's MHA (G 1, a head dim that is not 128 lanes), a GQA geometry
     and two of one KV head, on the sparse plan under a window."""
     _ragged_close(*_ragged_run("sparse", "window", geom=geom))
+
+
+# the benchmark's ragged cells: name -> ((Hk, G, D), Dv, a sink, variant).
+# Pages of 4 tokens ride 8 a step at every one, so each plan has units whose
+# live pages are fewer than the step's tiles; "mixed" has a block that spans
+# a segment boundary and a padding tail, "sparse" a window that kills a
+# unit's first pages
+_CELL_GEOMETRIES = {
+    "phi-3": ((32, 1, 96), None, False, "window-softcap"),
+    "jamba": ((1, 20, 128), None, False, "softcap"),
+    "mimo-global": ((4, 16, 256), 128, False, "softcap"),
+    "mimo-window": ((8, 8, 256), 128, True, "window-softcap"),
+}
+
+
+@pytest.mark.parametrize("plan", ["mixed", "sparse", "verify"])
+@pytest.mark.parametrize("cell", list(_CELL_GEOMETRIES))
+def test_ragged_tile_routine_at_the_cells_geometries(cell, plan):
+    """The ragged kernel at the widths the benchmark's cells run it. Three
+    take the tile routine, the q block's rows against the step's pages as one
+    matrix: columns of other KV heads masked, two head sizes, the sink
+    seeding the softmax, softcap before the masks, one KV head with its rows
+    as q holds them. phi-3's keeps the float32 product a head."""
+    geom, dv, sinked, variant = _CELL_GEOMETRIES[cell]
+    _ragged_close(*_ragged_run(plan, variant, geom=geom, dv=dv, sinked=sinked,
+                               forked=plan == "verify"))
+
+
+@pytest.mark.parametrize("cell", ["mimo-global", "mimo-window"])
+def test_ragged_tile_routine_reads_no_dead_page_at_two_widths(cell):
+    geom, dv, sinked, variant = _CELL_GEOMETRIES[cell]
+    clean, _, _ = _ragged_run("sparse", variant, geom=geom, dv=dv, sinked=sinked)
+    dirty, _, _ = _ragged_run("sparse", variant, geom=geom, dv=dv,
+                              sinked=sinked, poison=True)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("window", [None, 6, 16])
+@pytest.mark.parametrize("plan", list(_RAGGED_PLANS))
+def test_ragged_walk_steps_and_filled_table(plan, window):
+    """`ragged_walk` for a dense pool: the list is the oracle's pairs at the
+    step's granularity (units in order, steps ascending, each once), every
+    tile a step names is a page some unit of its segment sees (never a dead
+    entry, here poisoned to an unowned page), a tile inside the unit's own
+    run is the run's own page, and the host's count of live pairs still
+    equals the page-granular list's bound."""
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        _routine_and_tiles, ragged_live_pairs, ragged_walk, ragged_work_cap,
+        ragged_work_list,
+    )
+
+    md, NP = _ragged_plan(plan)
+    T, MP, PS = md["tok_positions"].shape[0], _RAGGED_MP, _RAGGED_PS
+    pairs = _oracle_pairs(plan, window or 0)
+    live = np.zeros(md["seg_page_table"].shape, bool)
+    for w, p in pairs:
+        live[md["meta"][0, w], p] = True
+    seg_pt = np.where(live, md["seg_page_table"], NP - 1).astype(np.int32)
+    pool = jnp.zeros((NP, PS, 2, 64), jnp.bfloat16)
+    routine, tiles = _routine_and_tiles((2, 3), pool, pool, False, MP, 8)
+    assert (routine, tiles) == ("by_tiles", 8)
+    win = None if window is None else jnp.int32(window)
+    meta, kvl = jnp.asarray(md["meta"]), jnp.asarray(md["seg_kv_lens"])
+    walk = ragged_walk(
+        (2, 3), pool, pool, jnp.asarray(seg_pt), kvl, meta, win, T)
+    # the walk carries the decision its lists were written for
+    assert (walk.routine, walk.tiles) == (routine, tiles)
+    work, n_work, covered, pages = (walk.work, walk.n_work, walk.covered,
+                                    walk.pages)
+    steps = MP // tiles
+    assert work.shape == (ragged_work_cap(T) * steps,)
+    assert pages.shape == (seg_pt.size,)
+    got = [(int(e) // steps, int(e) % steps)
+           for e in np.asarray(work)[: int(n_work)]]
+    assert got == sorted({(w, p // tiles) for w, p in pairs})
+    pages = np.asarray(pages).reshape(seg_pt.shape)
+    for w, step in got:
+        seg = md["meta"][0, w]
+        named = pages[seg, step * tiles:(step + 1) * tiles]
+        assert set(named) <= set(seg_pt[seg][live[seg]])
+        for p in range(step * tiles, (step + 1) * tiles):
+            if (w, p) in pairs:
+                assert pages[seg, p] == seg_pt[seg, p]
+    np.testing.assert_array_equal(np.asarray(covered),
+                                  md["tok_positions"] >= 0)
+    # an int8 pool keeps the float32 body and its page-granular list
+    q8 = {"q": jnp.zeros((NP, PS, 2, 64), jnp.int8),
+          "s": jnp.zeros((NP, PS, 2), jnp.float32)}
+    assert _routine_and_tiles((2, 3), q8["q"], q8["q"], True, MP, 8) == (
+        "by_heads", 1)
+    by_heads = ragged_walk((2, 3), q8, q8, jnp.asarray(seg_pt), kvl, meta,
+                           win, T)
+    by_pages = ragged_work_list(meta, kvl, win, PS, MP, T)
+    assert by_heads.pages is None
+    assert (by_heads.routine, by_heads.tiles) == ("by_heads", 1)
+    for a, b in zip((by_heads.work, by_heads.n_work, by_heads.covered),
+                    by_pages):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert ragged_live_pairs(md["meta"], md["seg_kv_lens"], window or 0,
+                             PS, MP) == int(by_pages[1]) == len(pairs)
+
+
+def test_ragged_page_routine_is_a_rule_of_its_own_shapes():
+    """Quantized, or G = 1 at 32 KV heads or more (Hk times the useful
+    work with no group to fill the rows): the product a head. It takes
+    nothing of what the decode kernel's `by_rows` rule hangs on (the pool's
+    dtype, a sink, one width or two)."""
+    from dynamo_tpu.ops.ragged_paged_attention import ragged_page_routine
+
+    assert [ragged_page_routine(Hk, 1, False)
+            for Hk in (1, 4, 8, 16, 24, 32, 40)] == [
+        "by_tiles"] * 5 + ["by_heads"] * 2
+    assert {ragged_page_routine(Hk, G, False) for Hk in (1, 2, 8, 32)
+            for G in (2, 3, 8, 20)} == {"by_tiles"}
+    assert ragged_page_routine(1, 20, True) == "by_heads"
+    assert ragged_page_routine(8, 4, True) == "by_heads"
+
+
+def test_walks_take_the_heads_of_one_call_not_the_pools():
+    """A walk is built outside `shard_map`, on pools that still have every
+    head: its pages a step come from `heads`, and ride the walk."""
+    from dynamo_tpu.ops.paged_attention import decode_walk, page_bytes
+    from dynamo_tpu.ops.ragged_paged_attention import (
+        build_ragged_metadata, ragged_walk,
+    )
+
+    whole = jnp.zeros((2, 4, 64, 8, 128), jnp.bfloat16)
+    shard = jnp.zeros((2, 4, 64, 4, 128), jnp.bfloat16)
+    assert page_bytes(4, whole, whole) == page_bytes(4, shard, shard) == 1 << 17
+    assert page_bytes(8, whole, whole) == 1 << 18
+    pt, kv = jnp.zeros((2, 8), jnp.int32), jnp.asarray([70, 300], jnp.int32)
+    for pool in (whole, shard):
+        walk = decode_walk((4, 3), pool, pool, pt, kv, None, False)
+        assert (walk.routine, walk.tiles) == ("by_tiles", 8)
+    assert decode_walk((8, 3), whole, whole, pt, kv, None, False).tiles == 4
+    md = build_ragged_metadata([1, 7], [69, 0], [70, 7], [[1, 2], [3]], 8,
+                               max_pages=8)
+    seg = tuple(jnp.asarray(md[k]) for k in
+                ("seg_page_table", "seg_kv_lens", "meta"))
+    for pool in (whole, shard):
+        walk = ragged_walk((4, 3), pool, pool, *seg, None, 8)
+        assert (walk.routine, walk.tiles) == ("by_tiles", 8)
+    assert ragged_walk((8, 3), whole, whole, *seg, None, 8).tiles == 2
+
+
+def test_ragged_step_tiles_sees_the_rows():
+    """Pages a step: `step_tiles`' count from the page's bytes, halved while
+    the q block's score block passes SCORE_BYTES; the cells' geometries."""
+    from dynamo_tpu.ops.ragged_paged_attention import _routine_and_tiles
+
+    def step(Hk, G, D, Dv, MP, q_block=8, PS=64):
+        kq = jax.ShapeDtypeStruct((2, 8, PS, Hk, D), jnp.bfloat16)
+        vq = jax.ShapeDtypeStruct((2, 8, PS, Hk, Dv), jnp.bfloat16)
+        return _routine_and_tiles((Hk, G), kq, vq, False, MP, q_block)
+
+    assert step(4, 16, 256, 128, 96) == ("by_tiles", 2)  # mimo-global
+    assert step(8, 8, 256, 128, 96) == ("by_tiles", 1)  # mimo-window
+    assert step(1, 20, 128, 128, 64) == ("by_tiles", 8)  # ai21-jamba2-3b
+    # phi-3: G 1 at 32 KV heads or more keeps the product a head
+    assert step(32, 1, 96, 96, 64) == ("by_heads", 1)
+    assert step(8, 1, 128, 128, 64) == ("by_tiles", 4)  # G 1 at fewer
+    assert step(8, 4, 128, 128, 64) == ("by_tiles", 2)
+    assert step(8, 4, 128, 128, 64, PS=16) == ("by_tiles", 8)
+    assert step(4, 16, 256, 128, 96, q_block=4) == ("by_tiles", 4)
+    assert step(8, 3, 128, 128, 7) == ("by_tiles", 1)  # a table no count divides
 
 
 _MQA = (1, 20, 128)  # ai21-jamba2-3b: the page a [PS, D] tile of a 4-d pool
@@ -1433,6 +1617,34 @@ def test_ragged_walk_reads_only_live_pairs(plan, variant, geom):
 def test_ragged_walk_sharded(variant):
     _ragged_close(*_ragged_run("sparse", variant, geom=(2, 4, 64),
                                sharded=True))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
+@pytest.mark.parametrize("plan,variant", [("mixed", "plain"),
+                                          ("sparse", "window")])
+def test_ragged_walk_sharded_takes_a_shards_pages_a_step(plan, variant):
+    """Llama-3.2-3B's heads (Hk 8, G 3, D 128) at pages of 64 over two
+    shards. The walk is built outside `shard_map`, where the pool still has
+    all 8 KV heads (a 256 KB page: 2 pages a step under the score block's
+    bound); a shard's kernel sees 4 (128 KB: 8). The lists are written for
+    the shard's heads and the kernel reads the count off the walk, so every
+    segment of ~10 pages is read at the granularity it was listed at;
+    poisoned: no dead page, no dead entry."""
+    from dynamo_tpu.ops.ragged_paged_attention import _routine_and_tiles
+
+    pool = jax.ShapeDtypeStruct((4, 64, 8, 128), jnp.bfloat16)
+    local = jax.ShapeDtypeStruct((4, 64, 4, 128), jnp.bfloat16)
+    assert _routine_and_tiles((8, 3), pool, pool, False, _RAGGED_MP,
+                              8) == ("by_tiles", 2)
+    assert _routine_and_tiles((4, 3), pool, pool, False, _RAGGED_MP,
+                              8) == _routine_and_tiles(
+        (4, 3), local, local, False, _RAGGED_MP, 8) == ("by_tiles", 8)
+    kw = dict(geom=(8, 3, 128), sharded=True, ps=64, shift=64 * 9 + 5)
+    clean, ref, real = _ragged_run(plan, variant, **kw)
+    _ragged_close(clean, ref, real)
+    dirty, _, _ = _ragged_run(plan, variant, poison=True, **kw)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
 
 
 def test_ragged_grid_is_one_traced_bound():
@@ -1465,7 +1677,8 @@ def test_ragged_grid_is_one_traced_bound():
     assert call_of("sparse") == call_of("verify") == call_of("empty")
     grid, n_dynamic, shapes = call_of("sparse")
     assert n_dynamic == 1 and len(grid) == 1
-    assert (ragged_work_cap(T) * _RAGGED_MP,) in shapes  # the list
+    # the list, at the tile routine's 8 of these 4 KB pages a step
+    assert (ragged_work_cap(T) * _RAGGED_MP // 8,) in shapes
 
 
 # -- layer-stacked pools: the kernels read [L, NP, PS, Hk, D] at a layer ----
