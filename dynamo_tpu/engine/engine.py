@@ -25,16 +25,10 @@ import numpy as np
 
 from dynamo_tpu.engine.kv_pool import KvEvent, NoSpace, PagePool
 
-from dynamo_tpu.engine.runner_api import (
-    BucketOverflowError,
-    Runner,
-    state_refusal,
-    window_refusal,
-)
+from dynamo_tpu.engine import side_cache
+from dynamo_tpu.engine.runner_api import BucketOverflowError, Runner
 from dynamo_tpu.engine.scheduler import (
     StepsInFlight,
-    STATE_NO_PREFIX,
-    WINDOW_NO_PREFIX,
     DecodePlan,
     MixedPlan,
     PrefillPlan,
@@ -264,50 +258,28 @@ class InferenceEngine:
         # callable(hint) -> payload that pulls blocks from a peer's
         # kv_host_fetch endpoint (None = feature off)
         self.remote_kv_fetch = None
-        # a model with state-space layers (Runner.holds_state): every
-        # sequence owns a state slot beside its pages. What moves KV by
-        # pages alone, or rolls tokens back, is refused here in words and
-        # not met halfway at run time (_state_refusal)
-        self._state_on = bool(runner.holds_state)
-        # a model with a window pool (Runner.holds_window_pool): every
-        # sequence has a second page table, into the window pool, whose
-        # pages the scheduler frees as they leave the window. The same is
-        # refused, in that fact's words (window_refusal)
-        self._window_on = bool(runner.holds_window_pool)
-        # either: a step hands the runner what the rows keep in its second
-        # pool (`_side_of`: a state slot, or a window page table)
-        self._side_on = self._state_on or self._window_on
-        if self._side_on:
+        # what a sequence keeps beside its KV pages (Runner.side_kind), its
+        # pool sized on the runner for this scheduler's limits. What moves
+        # KV by pages alone, or rolls tokens back, is refused here in the
+        # side cache's words and not met halfway at run time
+        self.side = side_cache.for_runner(
+            runner, max_batch=max_batch, chunk_size=chunk_size,
+            decode_steps=decode_steps,
+            mixed_prefill_tokens=mixed_prefill_tokens,
+            mixed_prefill_seqs=mixed_prefill_seqs)
+        if self.side is not None:
+            name = runner.config.name
             if host_kv_blocks > 0 or disk_kv_blocks > 0 or obj_kv_root or prefetch:
-                raise ValueError(self._state_refusal(
-                    "tier demotion of KV blocks (G2-G4, prefetch)"))
+                raise ValueError(self.side.refusal(
+                    name, "tier demotion of KV blocks (G2-G4, prefetch)"))
             if spec_ngram:
-                raise ValueError(self._state_refusal(
-                    "speculative decoding (n-gram drafts verified in the "
-                    "mixed step)"))
+                raise ValueError(self.side.refusal(
+                    name, "speculative decoding (n-gram drafts verified in "
+                    "the mixed step)"))
             if enable_prefix_cache:
-                log.info("prefix cache off: %s", WINDOW_NO_PREFIX
-                         if self._window_on else STATE_NO_PREFIX)
+                log.info("prefix cache off: %s", self.side.no_prefix)
                 enable_prefix_cache = False
         self.pool = PagePool(runner.num_pages, runner.page_size)
-        self.window_pool = None
-        if self._window_on:
-            # sized here, from what the scheduler can have in flight at
-            # once: every active sequence the pages its fused decode steps
-            # see and write, and on top the chunks of one iteration (one
-            # standalone chunk of chunk_size, or mixed_prefill_seqs chunks
-            # of mixed_prefill_tokens together), and scratch page 0
-            from dynamo_tpu.models.mimo import window_pages_needed
-
-            ps, w = runner.page_size, runner.config.sliding_window
-            a_row = window_pages_needed(w, ps, max(1, decode_steps))
-            mixed = (mixed_prefill_seqs * window_pages_needed(w, ps, 1)
-                     + -(-mixed_prefill_tokens // ps) + mixed_prefill_seqs)
-            pages = runner.ensure_window_pages(
-                1 + max_batch * a_row
-                + max(window_pages_needed(w, ps, chunk_size), mixed))
-            self.window_pool = PagePool(pages, ps)
-            self.window_pool.alloc(1)  # page 0: scratch, never handed out
         # fork-on-branch CoW: the pool copies a forked tail page's device
         # KV through the runner
         self.pool.copy_hook = runner.copy_pages
@@ -385,13 +357,7 @@ class InferenceEngine:
             host_onboard=self._onboard_from_host if self.host_pool is not None else None,
             spec_max_tokens=spec_max_tokens,
             spec_seg_budget=runner.spec_seg_budget,
-            # one slot for every sequence that can be active (a chunk a
-            # step packs beside the batch is an active sequence's), and the
-            # scratch slot
-            state_slots=(runner.ensure_state_slots(max_batch + 1)
-                         if self._state_on else 0),
-            window_pool=self.window_pool,
-            window=runner.config.sliding_window if self._window_on else 0,
+            side=self.side,
         )
         # n-gram speculative decoding (docs/spec_decode.md): drafts ride
         # the mixed dispatch as ragged verify rows, so both the runner
@@ -471,7 +437,6 @@ class InferenceEngine:
             anomaly_profile_ms=anomaly_profile_ms,
         )
         self._rec_prev_charged = 0  # runner packed_tokens_charged watermark
-        self._rec_window_freed = 0  # scheduler.window_pages_freed watermark
         # routed experts (docs/observability.md): whether the runner's
         # step programs hand out the router's picks; the picks fetched for
         # the asking requests of this iteration, rid -> the item's
@@ -837,13 +802,14 @@ class InferenceEngine:
         # roles stream exactly one completion per worker — no fan-out.
         if seq.disagg is None:
             seq.n_branches = max(1, min(16, int(seq.sampling.get("n") or 1)))
-        if self._side_on and (seq.disagg is not None or seq.n_branches > 1
-                              or seq.kv_import is not None):
+        if self.side is not None and (
+                seq.disagg is not None or seq.n_branches > 1
+                or seq.kv_import is not None):
             what = ("disaggregated serving (KV export and import by pages)"
                     if seq.disagg is not None or seq.kv_import is not None
                     else "n > 1 sampling (fork-on-branch over shared pages)")
             yield {"finish_reason": "error", "token_ids": [],
-                   "error": self._state_refusal(what)}
+                   "error": self.side.refusal(self.runner.config.name, what)}
             self._streams.pop(rid, None)
             return
         if seq.logit_bias and (
@@ -990,16 +956,6 @@ class InferenceEngine:
             self._spec_sampling_warned.discard(rid)
             if not finished:
                 self._inbox.put(("abort", rid))
-
-    def _state_refusal(self, what: str) -> str:
-        """The sentence that refuses `what` on this runner's second pool."""
-        refusal = window_refusal if self._window_on else state_refusal
-        return refusal(self.runner.config.name, what)
-
-    def _side_of(self, seq: Sequence):
-        """What `seq` keeps in the runner's second pool, as the runner's
-        steps take it: its state slot, or its window page table."""
-        return seq.wpages if self._window_on else seq.state_slot
 
     def _routed_refusal(self) -> Optional[str]:
         """Why this worker cannot stream `routed_experts` (None: it can)."""
@@ -1512,10 +1468,10 @@ class InferenceEngine:
                 if n_lp >= 0 or histories is not None:
                     mkw.update(n_logprobs=n_lp, histories=histories,
                                prompt_lens=[s.n_prompt0 for s in rows])
-                if self._side_on:
-                    mkw["slots"] = [self._side_of(s) if ok
-                                    else ([] if self._window_on else 0)
-                                    for s, ok in zip(rows, live)]
+                if self.side is not None:
+                    op = self.side.operand
+                    mkw["side"] = [op(s) if ok else None
+                                   for s, ok in zip(rows, live)]
                 if self.recorder.enabled:
                     at = [p for p in positions if p >= 0]
                     self._note_pages_live(rinfo, at, T)
@@ -1662,31 +1618,8 @@ class InferenceEngine:
             drain=rinfo.get("drain", ""),
             trace_ids=trace_ids,
         )
-        if self._state_on:
-            sched = self.scheduler
-            record.state_slots_used = sched.state_slots_used
-            record.state_slots_total = sched.state_slots - 1
-            # the scan's work: every prefill chunk (standalone or in the
-            # ragged step) and the ragged step's decode rows, segments of
-            # one token. The decode loop's steps run the one-token update.
-            rows = rinfo["decode_seqs"] if rinfo["ragged"] else 0
-            record.ssm_scan_segments = rinfo["n_chunks"] + rows
-            record.ssm_scan_tokens = rinfo["chunk_tokens"] + rows
-        if self._window_on:
-            sched, wp = self.scheduler, self.window_pool
-            record.window_pages_total = wp.num_pages - 1
-            record.window_pages_used = wp.num_pages - 1 - wp.n_free
-            record.window_tokens_resident = sched.window_tokens_resident
-            record.context_tokens_live = sum(
-                s.computed_len for s in sched.active)
-            record.window_pages_freed = (
-                sched.window_pages_freed - self._rec_window_freed)
-            self._rec_window_freed = sched.window_pages_freed
-            kinds = rinfo.get("pages_live_kinds")
-            if kinds:
-                step0 = rinfo["pages_step0_kinds"] if rinfo["ragged"] else (0, 0)
-                record.decode_pages_live_global = kinds[0] - step0[0]
-                record.decode_pages_live_window = kinds[1] - step0[1]
+        if self.side is not None:
+            self.side.record(record, rinfo, self.scheduler.active)
         clock = self.step_clock  # (there is one: rec.enabled)
         clock.cut(now_ns)
         rec.take_clock(record, clock)
@@ -2227,7 +2160,8 @@ class InferenceEngine:
             prior_len=plan.start_pos,
             adapter=seq.adapter_idx,
             mm=mm_chunk,
-            **({"slot": self._side_of(seq)} if self._side_on else {}),
+            **({"side": self.side.operand(seq)}
+               if self.side is not None else {}),
         )
         if self.runner.has_draft and seq.disagg != "prefill":
             # keep the draft model's KV pools in lockstep so spec decode
@@ -2705,13 +2639,6 @@ class InferenceEngine:
             "ragged_pages_live": out.pages_live,
         }
 
-    def _slots_kw(self, seqs: List[Sequence]) -> Dict[str, Any]:
-        """The decode rows' state slots, for a runner that holds state, or
-        their window page tables; no keyword (and no work) for any other."""
-        if not self._side_on:
-            return {}
-        return {"slots": [self._side_of(s) for s in seqs]}
-
     def _mixed_fusible(self, plan: MixedPlan) -> bool:
         """Whether this MixedPlan can run as ONE dispatch (runner
         decode_multi_with_prefills). What the runner's programs carry is
@@ -2860,13 +2787,15 @@ class InferenceEngine:
                                 "table": p.seq.pages,
                                 "prior": p.start_pos,
                                 "adapter": p.seq.adapter_idx,
-                                **({"slot": self._side_of(p.seq)}
-                                   if self._side_on else {}),
+                                **({"side": self.side.operand(p.seq)}
+                                   if self.side is not None else {}),
                             }
                             for p in prefills
                         ],
                         adapters=adapters,
-                        **mixkw, **self._slots_kw(seqs),
+                        **mixkw,
+                        **({"side": [self.side.operand(s) for s in seqs]}
+                           if self.side is not None else {}),
                     )
                     break
                 except BucketOverflowError as e:

@@ -33,9 +33,7 @@ from dynamo_tpu.engine.runner_api import (
     BucketOverflowError,
     MixedOut,
     Runner,
-    indexer_refusal,
-    state_refusal,
-    window_refusal,
+    refusal,
 )
 from dynamo_tpu.engine.sampling import SamplingParams, sample
 from dynamo_tpu.models import jamba, llama, mimo
@@ -85,28 +83,30 @@ def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
         **({"chosen_chunks": chosen[0]} if chosen else {})}
 
 
-def _forward_window(config: ModelConfig, params, tokens, positions, k_pool,
-                    v_pool, page_table, kv_lens, last_index=None,
-                    attn_impl: str = "jnp", mesh=None, state=None, slots=None):
-    """The prefill step program of a window-pool model: mimo.forward with
-    the picks of its chunk rows beside the pools, the window pool last."""
-    logits, k_pool, v_pool, sel, listed, state = mimo.forward(
-        config, params, tokens, positions, k_pool, v_pool, page_table,
-        kv_lens, last_index, attn_impl=attn_impl, mesh=mesh, state=state,
-        slots=slots, return_routed=True, return_listed=True)
-    return logits, k_pool, v_pool, {
-        "chunks": sel,
-        "load": _chunk_load(config, sel, positions >= 0, listed)}, state
-
-
-def _side_forward(config: ModelConfig, *args, **kw):
-    """The forward of a model whose step programs carry a second pool as
-    `state`: jamba's (the state pool) or mimo's (the window pool, with the
-    router's picks between the KV pools and it)."""
+def _side_ops(config: ModelConfig):
+    """The record (models/toolkit.SideCacheOps) of the module whose models
+    keep a cache beside their KV pages; None for every other model."""
     if config.is_hybrid:
-        return jamba.forward(config, *args, **kw)
-    return mimo.forward(config, *args, return_routed=True, return_listed=True,
-                        **kw)
+        return jamba.SIDE
+    if config.has_window_pool:
+        return mimo.SIDE
+    return None
+
+
+def _side_forward(config: ModelConfig, params, tokens, positions, *args,
+                  chunk_picks: bool = False, **kw):
+    """The forward of a model whose step programs carry a second pool as
+    `state` (`_side_ops`): (logits, k_pool, v_pool, *picks, pool). With
+    `chunk_picks` (the prefill program) a routed model's picks of its chunk
+    rows come as `_forward` hands them out."""
+    out = _side_ops(config).forward(config, params, tokens, positions, *args,
+                                    **kw)
+    if chunk_picks and len(out) > 4:
+        logits, k_pool, v_pool, sel, listed, state = out
+        return logits, k_pool, v_pool, {
+            "chunks": sel,
+            "load": _chunk_load(config, sel, positions >= 0, listed)}, state
+    return out
 
 
 def _chunk_load(config: ModelConfig, sel, valid, listed):
@@ -977,40 +977,37 @@ class ModelRunner(Runner):
         self.multihost = any(
             d.process_index != jax.process_index() for d in self.mesh.devices.flat
         )
-        # a model with state-space layers (models/jamba.py): each sequence
-        # owns a state slot beside its pages; the pool is sized by
-        # ensure_state_slots (the engine knows how many sequences it runs)
-        self.holds_state = bool(config.is_hybrid)
-        self.state = None  # {"S", "conv"} once ensured
-        self.state_slots = 0
-        if self.holds_state:
-            mc = self.mesh_config
-            self.has_verify_spec = False  # no state rollback
+        # a model that keeps a cache beside its KV pages (models/jamba.py:
+        # a state slot a sequence; models/mimo.py: the window layers' pool,
+        # the KV pool holds the global layers alone): its module's record.
+        # The step programs take and return that pool (`self.state`), sized
+        # by ensure_side_cache (the engine knows how many sequences it runs)
+        self._side_mod = _side_ops(config)
+        self.side_kind = self._side_mod.kind if self._side_mod else None
+        self.state = None  # the pool, once ensured
+        self.side_units = 0
+        mc = self.mesh_config
+        if self._side_mod is not None:
+            self.has_verify_spec = False  # no rollback of it
+            self.side_unit_bytes = self._side_mod.unit_bytes(
+                config, page_size, dtype)
+        if self.side_kind == "state":
             if mc.n_devices > 1:
                 raise NotImplementedError(
                     "a state-space model is not sharded yet: its state pool "
                     f"and mixers run on one device (mesh {mc.shape})")
             if draft_config is not None or lora_slots > 0:
-                raise NotImplementedError(
-                    self._state_refusal(
-                        "speculative decoding with a draft model (and LoRA)"))
-        # a model whose window layers keep a cache of their own
-        # (models/mimo.py): the KV pool holds the global layers, the window
-        # pool (`self.state`, sized by ensure_window_pages) the others
-        self.holds_window_pool = bool(config.has_window_pool)
-        self.window_pages = 0
-        if self.holds_window_pool:
-            self.has_verify_spec = False  # no rollback of freed pages
-            if self.mesh_config.n_devices > 1:
+                self._no_side(
+                    "speculative decoding with a draft model (and LoRA)")
+        if self.side_kind == "window":
+            if mc.n_devices > 1:
                 raise NotImplementedError(
                     "a window-pool model is not sharded yet: both its caches "
-                    f"live on one device (mesh {self.mesh_config.shape})")
+                    f"live on one device (mesh {mc.shape})")
             if draft_config is not None or lora_slots > 0 or kv_quantize:
-                raise NotImplementedError(self._state_refusal(
+                self._no_side(
                     "speculative decoding with a draft model, LoRA and a "
-                    "quantized KV cache"))
-        # either: the step programs take and return a second pool (`state`)
-        self._side_pool = self.holds_state or self.holds_window_pool
+                    "quantized KV cache")
         # a model with an indexer (models/mla.py): the pool's second array
         # holds its index keys under the latent pages' own page table, so
         # pages are copied, exported, imported and offloaded as the pair
@@ -1021,15 +1018,17 @@ class ModelRunner(Runner):
         self.fuses_mixed = not config.has_indexer
         if config.has_indexer:
             if kv_quantize:
-                raise NotImplementedError(indexer_refusal(
-                    config.name, "a quantized KV cache (--kv-quantize)"))
+                raise NotImplementedError(refusal(
+                    "indexer", config.name,
+                    "a quantized KV cache (--kv-quantize)"))
             if self.mesh_config.n_devices > 1:
-                raise NotImplementedError(indexer_refusal(
-                    config.name,
+                raise NotImplementedError(refusal(
+                    "indexer", config.name,
                     f"a mesh of several devices ({self.mesh_config.shape})"))
             if draft_config is not None:
-                raise NotImplementedError(indexer_refusal(
-                    config.name, "speculative decoding with a draft model"))
+                raise NotImplementedError(refusal(
+                    "indexer", config.name,
+                    "speculative decoding with a draft model"))
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -1085,7 +1084,6 @@ class ModelRunner(Runner):
         # pattern). Other mesh axes keep the XLA path (GSPMD partitions it).
         import os
 
-        mc = self.mesh_config
         tp_only_mesh = (
             mc.model > 1 and mc.data == mc.expert == mc.seq == mc.pipe == 1
         )
@@ -1193,12 +1191,12 @@ class ModelRunner(Runner):
         self._routed_parts: "deque[_RoutedPart]" = deque(maxlen=64)
         # a state-holding model's programs also take (and donate) the
         # state pool, by keyword; no other model's jit hears of it
-        skw = {"donate_argnames": ("state",)} if self._side_pool else {}
-        if self._side_pool:
+        skw = ({"donate_argnames": ("state",)}
+               if self._side_mod is not None else {})
+        if self._side_mod is not None:
             self._jit_forward = _family(
                 "forward",
-                partial(jamba.forward if self.holds_state else _forward_window,
-                        self.config),
+                partial(_side_forward, self.config, chunk_picks=True),
                 donate_argnums=(3, 4), static_argnames=("attn_impl", "mesh"),
                 **skw,
             )
@@ -1356,7 +1354,7 @@ class ModelRunner(Runner):
             shards = k_leaf.shape[3] // shard_shape[3]
             v_width = jax.tree.leaves(self.v_pool)[0].shape[-1]
             kinds = {"global": (c.n_kv_heads, c.sink_global)}
-            if self.holds_window_pool:
+            if c.has_window_pool:
                 kinds["window"] = (c.n_kv_heads_window, c.sink_window)
 
             quantized = isinstance(self.k_pool, dict)
@@ -1384,6 +1382,8 @@ class ModelRunner(Runner):
                     for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
                     if k in st
                 }
+        # (the side pool under its kind's keys; 0 under the other's)
+        units = {"state": 0, "window": 0, self.side_kind: self.side_units}
         return {
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
@@ -1401,10 +1401,10 @@ class ModelRunner(Runner):
                 "shard_shape": shard_shape,
             },
             "kv_pool_bytes": self.kv_pool_bytes(),
-            "state_slots": self.state_slots,
-            "state_pool_bytes": self.state_slots * self.state_slot_bytes,
-            "window_pages": self.window_pages,
-            "window_pool_bytes": self.window_pages * self.window_page_bytes,
+            "state_slots": units["state"],
+            "state_pool_bytes": units["state"] * self.side_unit_bytes,
+            "window_pages": units["window"],
+            "window_pool_bytes": units["window"] * self.side_unit_bytes,
             "memory": memory,
         }
 
@@ -1417,10 +1417,10 @@ class ModelRunner(Runner):
         prior_len: int,
         adapter: int = 0,
         mm: Optional[Dict[str, Any]] = None,  # {"embeds": [n,E], "offsets": [n]}
-        slot=0,  # a state-holding model: the sequence's state slot
-        #   (0: scratch). A chunk at start_pos 0 starts from zeros, a later
-        #   one from what the slot holds. A window-pool model: the
-        #   sequence's window page table (a list; 0: every entry scratch).
+        side=None,  # a model with a side cache: what the sequence holds
+        #   there (None: scratch). A state slot: a chunk at start_pos 0
+        #   starts from zeros, a later one from what the slot holds. A
+        #   window page table: a list.
     ) -> jax.Array:
         """Run one prefill chunk for a single sequence. `tokens` are the
         uncomputed prompt tokens starting at absolute position `start_pos`;
@@ -1441,7 +1441,7 @@ class ModelRunner(Runner):
                 mesh=self.mesh, axis="pipe",
             )
             return logits[0, n - 1]
-        if self._side_pool:
+        if self._side_mod is not None:
             if mm is not None:
                 raise NotImplementedError(
                     "multimodal prefill is not wired for a model with a "
@@ -1449,10 +1449,8 @@ class ModelRunner(Runner):
             logits, self.k_pool, self.v_pool, *routed = self._jit_forward(
                 self.params, tok, pos, self.k_pool, self.v_pool, pt, kv_lens,
                 jnp.int32(n - 1), attn_impl=self.attn_impl,
-                mesh=self._fwd_mesh, state=self._state_pool(),
-                slots=(self._window_tables([slot or []], 1)
-                       if self.holds_window_pool
-                       else jnp.asarray([slot], jnp.int32)),
+                mesh=self._fwd_mesh,
+                **self._state_kw([side], 1),
             )
             self._note_routed(self._keep_state(routed), 1, chunk_lens=[n])
             return logits[0, 0]
@@ -1469,131 +1467,68 @@ class ModelRunner(Runner):
         self._note_routed(routed, 1, chunk_lens=[n])
         return logits[0, 0]
 
-    # -- state slots (a model with state-space layers) ----------------------
-    @property
-    def state_slot_bytes(self) -> int:
-        if not self.holds_state:
-            return 0
-        return jamba.state_slot_bytes(self.config, conv_dtype=self.dtype)
-
-    def ensure_state_slots(self, slots: int) -> int:
-        """Hold a state pool of at least `slots` slots (slot 0 is scratch)
-        and say how many it has: 0 where the model keeps no state. Growing
+    # -- the side cache (a model with a cache beside its KV pages) ----------
+    def ensure_side_cache(self, units: int) -> int:
+        """Hold a side pool of at least `units` units (unit 0 is scratch)
+        and say how many it has: 0 where the model keeps none. Growing
         allocates a zeroed pool, so it is for construction, before any
-        sequence owns a slot. `S` is float32, as the published model keeps
-        it; the convolution's inputs are the activations' dtype."""
-        if not self.holds_state:
+        sequence holds a unit."""
+        if self._side_mod is None:
             return 0
-        if self.state is None or slots > self.state_slots:
-            sh = self.policy.replicated()
+        if self.state is None or units > self.side_units:
             self.state = jax.jit(
-                partial(jamba.make_state_pool, self.config, int(slots),
-                        conv_dtype=self.dtype),
-                out_shardings={"S": sh, "conv": sh})()
-            self.state_slots = int(slots)
-        return self.state_slots
-
-    # -- the window pool (window and global layers in caches of their own) --
-    @property
-    def window_page_bytes(self) -> int:
-        if not self.holds_window_pool:
-            return 0
-        return mimo.window_page_bytes(self.config, self.page_size,
-                                      jnp.dtype(self.dtype).itemsize)
-
-    def ensure_window_pages(self, pages: int) -> int:
-        """Hold a window pool of at least `pages` pages (page 0 is scratch)
-        and say how many it has: 0 where the model has no window pool.
-        Growing allocates a zeroed pool, so it is for construction."""
-        if not self.holds_window_pool:
-            return 0
-        if self.state is None or pages > self.window_pages:
-            sh = self.policy.replicated()
-            self.state = jax.jit(
-                partial(mimo.make_window_pool, self.config, int(pages),
+                partial(self._side_mod.make_pool, self.config, int(units),
                         self.page_size, self.dtype),
-                out_shardings={"k": sh, "v": sh})()
-            self.window_pages = int(pages)
-        return self.window_pages
-
-    def _window_tables(self, rows, B: int):
-        """int32 [B, MP] on the device: the rows' window page tables, pad
-        rows and missing entries at scratch page 0."""
-        out = np.zeros((B, self.max_pages_per_seq), np.int32)
-        for i, row in enumerate(rows or ()):
-            out[i, : len(row)] = row
-        return jnp.asarray(out)
+                out_shardings=self.policy.replicated())()
+            self.side_units = int(units)
+        return self.side_units
 
     def _state_pool(self):
         """The pool a step hands its program: whoever takes the runner
-        sizes it first (the engine does: ensure_state_slots /
-        ensure_window_pages)."""
+        sizes it first (the engine does: ensure_side_cache)."""
         if self.state is None:
             raise RuntimeError(
                 f"{self.config.name} keeps a second pool beside its KV pages "
                 "(a recurrent state a sequence, or its window layers' cache) "
-                "and nobody sized it: call ensure_state_slots(slots) or "
-                "ensure_window_pages(pages) first")
+                "and nobody sized it: call ensure_side_cache(units) first")
         return self.state
 
-    def _state_kw(self, slots, B: int) -> Dict[str, Any]:
-        """_decode_loop's keywords for the rows' state slots at bucket B
-        (padding rows name the scratch slot and, having no position,
-        change none), or for the rows' window page tables; nothing for a
-        model with one pool."""
-        if self.holds_window_pool:
-            return {"state": self._state_pool(),
-                    "slots": self._window_tables(slots, B)}
-        if not self.holds_state:
+    def _state_kw(self, side, B: int) -> Dict[str, Any]:
+        """_decode_loop's and the prefill program's keywords for the rows'
+        `side` operands at bucket B; nothing for a model with one pool."""
+        if self._side_mod is None:
             return {}
-        rows = np.zeros(B, np.int32)
-        if slots is not None:
-            rows[: len(slots)] = slots
-        return {"state": self._state_pool(), "slots": jnp.asarray(rows)}
+        return {"state": self._state_pool(),
+                "slots": self._side_mod.rows(side or (), B,
+                                             self.max_pages_per_seq)}
 
-    def _seg_state_kw(self, slots, n_dec: int, chunks, seg_cap: int):
-        """_ragged_step's keywords: per segment (decode rows first, then
-        the chunks, as _prep_ragged lays them) its slot, first flat token
-        and token count; dead entries hold no tokens. A window-pool
-        model's: the window page tables by token and by segment, laid out
-        as build_ragged_metadata lays tok_pt and seg_pt."""
-        if self.holds_window_pool:
-            rows = list(slots or [[]] * n_dec) + [
-                c.get("slot") or [] for c in chunks]
-            seg_wpt = np.zeros((seg_cap, self.max_pages_per_seq), np.int32)
-            for i, row in enumerate(rows):
-                seg_wpt[i, : len(row)] = row
-            lens = [1] * n_dec + [len(c["tokens"]) for c in chunks]
-            t_bucket = _next_bucket(self.ragged_buckets, sum(lens))
-            tok_wpt = np.zeros((t_bucket, self.max_pages_per_seq), np.int32)
-            tok_wpt[: sum(lens)] = np.repeat(seg_wpt[: len(lens)], lens, axis=0)
-            return {"state": self._state_pool(),
-                    "seg_slots": (jnp.asarray(tok_wpt), jnp.asarray(seg_wpt))}
-        if not self.holds_state:
+    def _seg_state_kw(self, side, n_dec: int, chunks, seg_cap: int,
+                      t_bucket: int) -> Dict[str, Any]:
+        """_ragged_step's keywords: the segments' `side` operands (decode
+        rows first, then the chunks, as _prep_ragged lays them)."""
+        if self._side_mod is None:
             return {}
-        seg = np.zeros((3, seg_cap), np.int32)
+        sides = list(side or [None] * n_dec) + [c.get("side") for c in chunks]
         lens = [1] * n_dec + [len(c["tokens"]) for c in chunks]
-        n = len(lens)
-        if slots is not None:
-            seg[0, :n_dec] = slots
-        seg[0, n_dec:n] = [c.get("slot") or 0 for c in chunks]
-        seg[1, :n] = np.cumsum([0] + lens[:-1])
-        seg[2, :n] = lens
-        return {"state": self._state_pool(), "seg_slots": jnp.asarray(seg)}
+        return {"state": self._state_pool(),
+                "seg_slots": self._side_mod.segs(
+                    sides, lens, seg_cap, t_bucket, self.max_pages_per_seq)}
 
     def _keep_state(self, extra: list) -> list:
         """A state-holding model's step returns its pool last: keep it,
         and hand back what else followed the KV pools."""
-        if self._side_pool:
+        if self._side_mod is not None:
             self.state = extra[-1]
             return extra[:-1]
         return extra
 
-    def _state_refusal(self, what: str) -> str:
-        """The sentence that refuses `what` on this runner's second pool."""
-        if self.holds_window_pool:
-            return window_refusal(self.config.name, what)
-        return state_refusal(self.config.name, what)
+    def _no_side(self, what: str) -> None:
+        """Refuse `what`, in its kind's sentence, where the model keeps a
+        side cache: its second pool is neither moved, copied nor rolled
+        back with the pages."""
+        if self._side_mod is not None:
+            raise NotImplementedError(
+                refusal(self.side_kind, self.config.name, what))
 
     # -- routed experts ------------------------------------------------------
     def _note_routed(self, routed, forwards: int, n_dec: int = 0,
@@ -1830,8 +1765,8 @@ class ModelRunner(Runner):
         n_logprobs: int = -1,
         histories: Optional[List[List[int]]] = None,
         prompt_lens: Optional[List[int]] = None,
-        slots: Optional[List[int]] = None,  # a state-holding model: each
-        # row's state slot (None: scratch, the warm-up's dummies)
+        side: Optional[list] = None,  # a model with a side cache: what each
+        # row's sequence holds there (None: scratch, the warm-up's dummies)
     ):
         """n_steps fused decode iterations (one host sync total). Page
         tables must already cover positions[i] + n_steps slots. Returns
@@ -1849,7 +1784,7 @@ class ModelRunner(Runner):
             n_steps, tokens, positions, page_tables, sampling, step,
             adapters=adapters, masks=masks, biases=biases, mask_fn=mask_fn,
             guided_dev=guided_dev, n_logprobs=n_logprobs,
-            histories=histories, prompt_lens=prompt_lens, slots=slots))
+            histories=histories, prompt_lens=prompt_lens, side=side))
 
     def decode_bucket(self, n: int) -> int:
         return _next_bucket(self.decode_buckets, n)
@@ -1858,7 +1793,7 @@ class ModelRunner(Runner):
                         sampling, step, adapters=None, masks=None,
                         biases=None, mask_fn=None, guided_dev=None,
                         n_logprobs=-1, histories=None, prompt_lens=None,
-                        slots=None, prev: Optional[DecodeHandle] = None,
+                        side=None, prev: Optional[DecodeHandle] = None,
                         ) -> DecodeHandle:
         """decode_multi's stage and enqueue, without its readback: the
         handle decode_collect reads. With `prev` (a handle of the same
@@ -1919,7 +1854,7 @@ class ModelRunner(Runner):
             toks, last, lp, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(
                 n_steps, n_logprobs, self.params, tok, packed_dev, hist,
                 mask_dev, bias_dev, self.k_pool, self.v_pool,
-                samp, self.lora, **mkw, **self._state_kw(slots, B),
+                samp, self.lora, **mkw, **self._state_kw(side, B),
             )
             routed = self._keep_state(routed)
             if routed:
@@ -2028,8 +1963,8 @@ class ModelRunner(Runner):
         mask_fn=None,  # GuidedMaskContext for the fused tail steps 1..n-1
         biases: Optional[np.ndarray] = None,  # [n_dec, V] logit-bias rows
         guided_dev=None,  # device guided DFA plan for the fused tail
-        slots: Optional[List[int]] = None,  # a state-holding model: each
-        # decode row's state slot; a chunk's rides its dict as "slot"
+        side: Optional[list] = None,  # a model with a side cache: what each
+        # decode row's sequence holds there; a chunk's rides its dict as "side"
     ) -> MixedOut:
         """Fused mixed iteration: the decode batch's n_steps AND the
         token-budgeted prefill chunk set, one chunk or many, in one
@@ -2051,10 +1986,10 @@ class ModelRunner(Runner):
                 return self._decode_multi_with_prefills_ragged(
                     n_steps, tokens, positions, page_tables, sampling, step,
                     chunks, masks=masks, mask_fn=mask_fn, biases=biases,
-                    guided_dev=guided_dev, slots=slots,
+                    guided_dev=guided_dev, side=side,
                 )
             except BucketOverflowError as e:
-                if constrained or self._side_pool:
+                if constrained or self._side_mod is not None:
                     # (a model with a second pool has no padded program either)
                     # the padded fallback has no mask/bias plane; the
                     # engine sheds chunks and retries rather than dropping
@@ -2069,7 +2004,7 @@ class ModelRunner(Runner):
                 "guided masks / logit bias require the ragged mixed path "
                 "(can_fuse gates on it)"
             )
-        if self._side_pool:
+        if self._side_mod is not None:
             raise NotImplementedError(
                 "a model with a state pool or a window pool fuses a mixed "
                 "plan on the ragged program "
@@ -2300,7 +2235,7 @@ class ModelRunner(Runner):
         guided_dev=None,  # device guided DFA plan (decode_multi): step 0
         # rides the ragged mask operand (`masks`), the fused tail rides
         # the in-XLA advance
-        slots: Optional[List[int]] = None,
+        side: Optional[list] = None,
     ) -> MixedOut:
         """Ragged mixed iteration, two dispatches with T-bucket-only and
         decode-bucket-only compile keys respectively:
@@ -2320,7 +2255,8 @@ class ModelRunner(Runner):
             step_dev = jnp.int32(step)
             seg_mask = self._seg_mask(masks, seg_cap)
             seg_bias = self._seg_bias(biases, seg_cap)
-            skw = self._seg_state_kw(slots, n_dec, chunks, seg_cap)
+            skw = self._seg_state_kw(side, n_dec, chunks, seg_cap,
+                                     ftok.shape[1])
         sampled, seg_logits, self.k_pool, self.v_pool, *routed = self._jit_ragged(
             self.params, ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl,
             meta, gather, self.k_pool, self.v_pool,
@@ -2358,7 +2294,7 @@ class ModelRunner(Runner):
             rest, _, _, self.k_pool, self.v_pool, *routed = self._jit_decode_loop(  # dynlint: disable=DYN-J004
                 n_steps - 1, -1, self.params, tok0, packed_dev,
                 None, None, bias_dev, self.k_pool, self.v_pool,
-                samp, None, **mkw, **self._state_kw(slots, B),
+                samp, None, **mkw, **self._state_kw(side, B),
             )
             routed = self._keep_state(routed)
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
@@ -2434,8 +2370,7 @@ class ModelRunner(Runner):
         Raises BucketOverflowError when the plan exceeds the T bucket or
         the gather capacity (defensive — the scheduler budgets drafted
         tokens against both)."""
-        if self._side_pool:
-            raise NotImplementedError(self._state_refusal("speculative verify (no rollback)"))
+        self._no_side("speculative verify (no rollback)")
         from dynamo_tpu.ops.ragged_paged_attention import (
             RAGGED_MAX_SEGS, build_ragged_metadata, ragged_seg_cap,
         )
@@ -2944,8 +2879,7 @@ class ModelRunner(Runner):
         """Gather whole KV pages into fresh device buffers (no host copy).
         The gather materializes a new array, so the source pool can keep
         being donated by its engine's step loop afterwards."""
-        if self._side_pool:
-            raise NotImplementedError(self._state_refusal("KV export by pages (export_pages_device)"))
+        self._no_side("KV export by pages (export_pages_device)")
         idx = jnp.asarray(np.asarray(pages, np.int32))
         return self._dense_pages(self.k_pool, idx), self._dense_pages(self.v_pool, idx)
 
@@ -2953,8 +2887,7 @@ class ModelRunner(Runner):
         """Scatter device-staged pages into this pool's slots (the TPU
         analog of the reference's NIXL device-to-device transfer; the
         host-staged path below is the DCN fallback)."""
-        if self._side_pool:
-            raise NotImplementedError(self._state_refusal("KV import by pages (import_pages_device)"))
+        self._no_side("KV import by pages (import_pages_device)")
         idx = jnp.asarray(np.asarray(target_pages, np.int32))
         n = len(target_pages)
         self.k_pool = self._store_pages(self.k_pool, idx, k[:, offset : offset + n])
@@ -2994,8 +2927,7 @@ class ModelRunner(Runner):
         multi-host mesh the gather runs jitted with a replicated output
         sharding (an all-gather over ICI) so every process holds the full
         pages and the host read is local."""
-        if self._side_pool:
-            raise NotImplementedError(self._state_refusal("KV export by pages (export_pages: disaggregation, tier demotion)"))
+        self._no_side("KV export by pages (export_pages: disaggregation, tier demotion)")
         idx = jnp.asarray(np.asarray(pages, np.int32))
         if self.multihost:
             if not hasattr(self, "_jit_export_repl"):
@@ -3070,8 +3002,7 @@ class ModelRunner(Runner):
         independently, so the scheduler can dispatch prefill as soon as
         the shallow layers land while deeper groups are still in flight.
         Final pool contents are identical to a whole-sequence import."""
-        if self._side_pool:
-            raise NotImplementedError(self._state_refusal("KV import by pages (import_pages: disaggregation, tier onboarding, remote pulls)"))
+        self._no_side("KV import by pages (import_pages: disaggregation, tier onboarding, remote pulls)")
         if payload.get("quant") == "int8_ts":
             return self._import_pages_quant(
                 target_pages, offset, payload, layer_groups)
@@ -3163,8 +3094,7 @@ class ModelRunner(Runner):
             )
         if self.state is not None:  # a failed step consumed it too
             self.state = None
-            self.ensure_state_slots(self.state_slots)
-            self.ensure_window_pages(self.window_pages)
+            self.ensure_side_cache(self.side_units)
 
     def _new_kv_pools(self, config: ModelConfig):
         """Zeroed (k, v) pools for `config`, allocated DIRECTLY under their

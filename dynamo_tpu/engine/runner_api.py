@@ -7,7 +7,10 @@ behind it: engine/model_runner.ModelRunner (the compiled programs),
 mocker/sim.SimRunner (a cost model, no jax) and
 parallel/multihost.ReplicatingRunner (a ModelRunner whose device steps a
 multi-host group replays). The engine reads these names plainly; it never
-asks a runner what it has by `getattr`/`hasattr`.
+asks a runner what it has by `getattr`/`hasattr`. What a sequence keeps
+beside its KV pages is four of them (`side_kind`, `side_units`,
+`side_unit_bytes`, `ensure_side_cache`) and one keyword of the steps
+(`side=`); engine/side_cache.py is the host side of it.
 
 This module imports nothing heavy: mocker processes stay jax-free.
 """
@@ -47,37 +50,36 @@ class MixedOut(tuple):
         return self
 
 
-def state_refusal(model_name: str, what: str) -> str:
-    """Why a worker whose model has state-space layers (holds_state) does
-    not do `what`: the one sentence every such refusal raises or streams,
-    from the runner and the engine alike."""
-    return (f"{what} is not built for a model with state-space layers "
-            f"({model_name}): a sequence's recurrent state lives in its state "
-            "slot, which this path neither moves, copies nor rolls back")
+_REFUSALS = {
+    # a model with state-space layers (models/jamba.py)
+    "state": ("{what} is not built for a model with state-space layers "
+              "({model}): a sequence's recurrent state lives in its state "
+              "slot, which this path neither moves, copies nor rolls back"),
+    # a model whose window and global attention layers keep caches of their
+    # own (models/mimo.py)
+    "window": ("{what} is not built for a model with a window pool "
+               "({model}): a block's window layers keep the last "
+               "sliding_window tokens alone, their older pages are freed, and "
+               "nothing snapshots them, so a sequence's KV cannot be matched, "
+               "moved, forked or rolled back by pages"),
+    # a model that attends to its indexer's choice of tokens (DeepSeek-V3.2:
+    # `ModelConfig.has_indexer`). Its pool is two arrays under one page
+    # table, the latent pages and the index keys; what moves, copies,
+    # exports or offloads pages by their ids carries both and is not refused
+    "indexer": ("{what} is not built for a model with an indexer "
+                "({model}): its pool holds the latent pages and the index "
+                "keys side by side under one page table, the selection reads "
+                "both as they were written, and this path was never run on the "
+                "pair"),
+}
 
 
-def window_refusal(model_name: str, what: str) -> str:
-    """Why a worker whose model keeps window and global attention layers in
-    caches of their own (holds_window_pool) does not do `what`: the one
-    sentence every such refusal raises or streams."""
-    return (f"{what} is not built for a model with a window pool "
-            f"({model_name}): a block's window layers keep the last "
-            "sliding_window tokens alone, their older pages are freed, and "
-            "nothing snapshots them, so a sequence's KV cannot be matched, "
-            "moved, forked or rolled back by pages")
-
-
-def indexer_refusal(model_name: str, what: str) -> str:
-    """Why a worker whose model attends to its indexer's choice of tokens
-    (DeepSeek-V3.2: `ModelConfig.has_indexer`) does not do `what`: the one
-    sentence every such refusal raises. Its pool is two arrays under one
-    page table, the latent pages and the index keys; what moves, copies,
-    exports or offloads pages by their ids carries both and is not refused."""
-    return (f"{what} is not built for a model with an indexer "
-            f"({model_name}): its pool holds the latent pages and the index "
-            "keys side by side under one page table, the selection reads "
-            "both as they were written, and this path was never run on the "
-            "pair")
+def refusal(kind: str, model_name: str, what: str) -> str:
+    """Why a worker whose model keeps a cache of `kind` (a side cache's
+    `Runner.side_kind`, or "indexer") does not do `what`: the one sentence
+    every such refusal raises or streams, from the runner and the engine
+    alike."""
+    return _REFUSALS[kind].format(what=what, model=model_name)
 
 
 def device_step(fn):
@@ -125,30 +127,25 @@ class Runner:
     has_verify_spec = False  # verify_spec
     has_draft_ring = False  # ensure_draft_ring / draft_ring_reset / draft_step
     has_prefill_packed = False  # prefill_packed
-    holds_state = False  # the model has state-space layers: a sequence owns
-    #   a state slot beside its pages (ensure_state_slots), its steps take
-    #   `slots=` / `slot=` / a chunk's "slot", and whatever moves KV by pages
-    #   alone or rolls tokens back is refused
     can_run_ahead = False  # decode_dispatch / decode_collect: a decode
     #   dispatch may stay in flight while the next is chained on its tokens
     #   (the engine's step loop keeps one ahead where this says so)
-    state_slots = 0  # slots of the state pool, scratch slot 0 among them
-    state_slot_bytes = 0  # one sequence's recurrent state, all layers
-    holds_window_pool = False  # the model's window layers keep a cache of
-    #   their own (models/mimo.py): a sequence has a second page table into
-    #   the window pool (ensure_window_pages), indexed by the same logical
-    #   page, whose entries the scheduler frees (scratch page 0) as they
-    #   leave the window. Its steps take the rows' window tables where a
-    #   state-holding model's take slots (`slots=` a list of tables, `slot=`
-    #   one table, a chunk's "slot"; None: every entry scratch), and what
-    #   matches, moves, forks or rolls back KV by pages is refused
-    window_pages = 0  # pages of the window pool, scratch page 0 among them
-    window_page_bytes = 0  # one window page, all window layers
+    side_kind: Optional[str] = None  # what a sequence keeps beside its KV
+    #   pages (engine/side_cache.py): "state", a slot of recurrent state
+    #   (models/jamba.py); "window", a second page table into the window
+    #   layers' pool, indexed by the same logical page (models/mimo.py);
+    #   None: nothing. With one, the steps take the rows' operands as
+    #   `side=` (a row a sequence, a chunk's in its dict as "side"; None: the
+    #   scratch unit), and whatever matches, moves, forks or rolls back KV
+    #   by pages alone is refused (`refusal`)
+    side_units = 0  # units of that pool (slots, pages), scratch unit 0
+    #   among them (ensure_side_cache)
+    side_unit_bytes = 0  # one unit, all the layers that keep it
 
     # -- steps ---------------------------------------------------------------
     @device_step
     def prefill(self, tokens, start_pos, page_table_row, prior_len,
-                adapter=0, mm=None, slot=0):
+                adapter=0, mm=None, side=None):
         """One prefill chunk of one sequence; its last-token logits."""
         raise NotImplementedError
 
@@ -178,7 +175,7 @@ class Runner:
     def decode_multi(self, n_steps, tokens, positions, page_tables, sampling,
                      step, adapters=None, masks=None, biases=None,
                      mask_fn=None, guided_dev=None, n_logprobs=-1,
-                     histories=None, prompt_lens=None, slots=None):
+                     histories=None, prompt_lens=None, side=None):
         """n_steps fused decode iterations: sampled [rows, n_steps] on the
         host; with n_logprobs >= 0, (sampled, lp | None)."""
         raise NotImplementedError
@@ -190,7 +187,7 @@ class Runner:
                         sampling, step, adapters=None, masks=None,
                         biases=None, mask_fn=None, guided_dev=None,
                         n_logprobs=-1, histories=None, prompt_lens=None,
-                        slots=None, prev=None):
+                        side=None, prev=None):
         """Stage and enqueue decode_multi's work and return a handle
         without reading anything back. `prev`: a handle of the same
         decode_bucket whose last sampled tokens, wherever they are, are
@@ -223,7 +220,7 @@ class Runner:
                                    page_tables, sampling, step, chunks,
                                    adapters=None, masks=None, mask_fn=None,
                                    biases=None, guided_dev=None,
-                                   slots=None) -> MixedOut:
+                                   side=None) -> MixedOut:
         raise NotImplementedError
 
     @device_step
@@ -258,18 +255,11 @@ class Runner:
         raise NotImplementedError
 
     @device_step
-    def ensure_state_slots(self, slots: int) -> int:
-        """Hold a state pool of at least `slots` slots and say how many
-        there are (holds_state runners; 0 where no model state is kept).
-        The engine calls it once, when it takes the runner."""
-        return 0
-
-    @device_step
-    def ensure_window_pages(self, pages: int) -> int:
-        """Hold a window pool of at least `pages` pages and say how many
-        there are (holds_window_pool runners; 0 for every other). The
-        engine calls it once, when it takes the runner, with what its
-        batch, its chunks and the window need."""
+    def ensure_side_cache(self, units: int) -> int:
+        """Hold a side pool of at least `units` units and say how many
+        there are (0 where side_kind is None). The engine's side cache
+        calls it once, when the engine takes the runner, with what its
+        scheduler's limits need."""
         return 0
 
     @device_step

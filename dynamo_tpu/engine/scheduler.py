@@ -36,20 +36,6 @@ def _chain_seed(seq: "Sequence") -> Optional[int]:
 
 log = logging.getLogger("dynamo_tpu.engine.scheduler")
 
-STATE_NO_PREFIX = (
-    "a model with state-space layers matches no prefix: a cached block is "
-    "usable only with the recurrent state at its boundary, which nothing "
-    "snapshots, so its scheduler runs without the prefix cache and without "
-    "a host tier (and publishes no stored blocks)")
-
-
-WINDOW_NO_PREFIX = (
-    "a model with a window pool matches no prefix: a cached block's window "
-    "layers kept the last sliding_window tokens alone and their older pages "
-    "are freed, which nothing snapshots, so its scheduler runs without the "
-    "prefix cache and without a host tier (and publishes no stored blocks)")
-
-
 class SeqState(Enum):
     WAITING = "waiting"
     PREFILL = "prefill"
@@ -89,13 +75,8 @@ class Sequence:
     #   engine runs one dispatch ahead): its next position is computed_len +
     #   inflight, and a budget those steps spend is spent
     n_shared_pages: int = 0  # leading pages from prefix-cache hits
-    state_slot: int = 0  # a state-holding model: where the sequence's
-    #   recurrent state lives while it is active (0: none held)
-    wpages: List[int] = field(default_factory=list)  # a window-pool model:
-    #   the sequence's second page table, into the window pool, indexed by
-    #   the same logical page as `pages`; 0 (scratch) where the page is
-    #   freed or not yet needed
-    w_lo: int = 0  # every entry of wpages below this logical page is freed
+    side: Any = None  # what the sequence holds in the scheduler's side
+    #   cache (engine/side_cache.py), which alone sets it; None: nothing
     hash_chain: List[int] = field(default_factory=list)  # registered block hashes
     finish_reason: Optional[str] = None
     n_preemptions: int = 0
@@ -213,30 +194,17 @@ class Scheduler:
         max_seq_tokens: int = 0,  # model context length (0 = page cap only)
         spec_max_tokens: int = 0,  # per-iteration cap on speculative
         #   draft tokens (0 = bounded by the mixed pool leftover alone)
-        state_slots: int = 0,  # a state-holding model (Runner.holds_state):
-        #   slots of the runner's state pool, scratch slot 0 among them: one
-        #   for every sequence that can be active, so max_batch bounds both
         spec_seg_budget: int = 0,  # sampled-row slots one ragged dispatch
         #   offers (decode rows + chunks + verify tokens); 0 = unbounded
-        window_pool: Optional[PagePool] = None,  # a window-pool model
-        #   (Runner.holds_window_pool): the allocator of the runner's window
-        #   pool, page 0 (scratch) taken out of it by the caller
-        window: int = 0,  # with it: the model's sliding window, in tokens
+        side=None,  # engine/side_cache.SideCache: what a sequence of this
+        #   model keeps beside its KV pages (Runner.side_kind); None: nothing
     ):
         self.pool = pool
-        self.wpool = window_pool
-        self.window = int(window)
-        if window_pool is not None:
+        self.side = side
+        if side is not None:
             if enable_prefix_cache or host_tier is not None:
-                raise ValueError(WINDOW_NO_PREFIX)
-            if self.window <= 0 or window_pool.page_size != pool.page_size:
-                raise ValueError(
-                    "a window pool needs the model's sliding window and the "
-                    "KV pool's page size")
-        # admissions put off for want of a window page where the global
-        # pool had room, and chunks a plan dropped for the same reason
-        self.window_waits = 0
-        self.window_pages_freed = 0  # pages given back as they left the window
+                raise ValueError(side.no_prefix)
+            side.check_limits(pool.page_size, max_batch)
         self.max_batch = max_batch
         self.chunk_size = chunk_size
         self.max_seq_pages = max_seq_pages
@@ -262,15 +230,6 @@ class Scheduler:
         self.spec_seg_budget = max(0, spec_seg_budget)
         self.host_tier = host_tier
         self.host_onboard = host_onboard
-        self.state_slots = int(state_slots)
-        if self.state_slots and (enable_prefix_cache or host_tier is not None):
-            raise ValueError(STATE_NO_PREFIX)
-        if self.state_slots and self.state_slots - 1 < max_batch:
-            raise ValueError(
-                f"{self.state_slots} state slots (one of them scratch) do not "
-                f"give each of max_batch {max_batch} active sequences its own")
-        # free slots, lowest first; 0 is scratch and never handed out
-        self._free_slots: List[int] = list(range(self.state_slots - 1, 0, -1))
         self.waiting: deque[Sequence] = deque()
         self.active: List[Sequence] = []
         self.stats = SchedulerStats()
@@ -302,12 +261,12 @@ class Scheduler:
         return bool(self.waiting or self.active)
 
     def step_plan(self) -> Optional[PrefillPlan | DecodePlan | MixedPlan]:
-        """`_step_plan`, then (a window-pool model) the window pages the
-        plan's chunks need, taken now that the plan is final."""
+        """`_step_plan`, then (a model with a side cache) what the plan's
+        chunks need of it, taken now that the plan is final."""
         plan = self._step_plan()
-        if self.wpool is None or plan is None:
+        if self.side is None or plan is None or isinstance(plan, DecodePlan):
             return plan
-        return self._window_pages_for_chunks(plan)
+        return self._side_cover_for_chunks(plan)
 
     def _step_plan(self) -> Optional[PrefillPlan | DecodePlan | MixedPlan]:
         """Admit what fits, then plan this iteration's work.
@@ -458,26 +417,11 @@ class Scheduler:
             s.spec_tree = kept
 
     # -- admission ---------------------------------------------------------
-    @property
-    def state_slots_used(self) -> int:
-        return max(0, self.state_slots - 1 - len(self._free_slots))
-
-    def _release_slot(self, seq: Sequence) -> None:
-        if seq.state_slot:
-            self._free_slots.append(seq.state_slot)
-            seq.state_slot = 0
-
     def _admit(self) -> None:
         while self.waiting and len(self.active) < self.max_batch:
             seq = self.waiting[0]
             if not self._try_allocate(seq):
                 break
-            if self.state_slots:
-                # every slot holder is active and there are max_batch slots,
-                # so one is free here. A sequence's first token starts from
-                # zeros whatever the slot held (models/jamba.py), so a slot
-                # needs no clearing
-                seq.state_slot = self._free_slots.pop()
             self.waiting.popleft()
             self.active.append(seq)
             seq.state = SeqState.PREFILL
@@ -520,17 +464,18 @@ class Scheduler:
         except NoSpace:
             self.pool.release(matched_pages)
             return False
-        if self.wpool is not None:
-            # and the window pages of the least first chunk it can be given
-            # (the rest come with each chunk, `_window_pages_for_chunks`):
-            # without them it waits, whatever the global pool has
+        if self.side is not None:
+            # and what the side cache keeps for it, as far as the least
+            # first chunk it can be given (the rest comes with each chunk,
+            # `_side_cover_for_chunks`): without it the sequence waits,
+            # whatever the KV pool has
             least = (self.mixed_min_chunk if self.mixed_prefill_tokens > 0
                      else self.chunk_size)
             try:
-                self._window_cover(seq, 0, min(len(prompt), least) - 1)
+                self.side.admit(seq, min(len(prompt), least))
             except NoSpace:
                 self.pool.release(fresh)
-                self.window_waits += 1
+                self.side.waits += 1
                 return False
 
         if host_n:
@@ -560,81 +505,37 @@ class Scheduler:
             self.prompt_tokens_total += len(prompt)
         return True
 
-    # -- window pages (a window-pool model) ---------------------------------
-    def _window_cover(self, seq: Sequence, first_query: int,
-                      last_query: int) -> None:
-        """Give `seq` the window pages that queries at first_query ..
-        last_query read and write, and take back the ones wholly below
-        what any query from first_query on can see (`live_pages`' rule:
-        below page (first_query - window + 1) // PS): their entries point
-        at scratch page 0, which no kernel's walk visits. Raises NoSpace
-        with nothing taken."""
-        from dynamo_tpu.models.mimo import window_first_live_page
+    # -- the side cache ------------------------------------------------------
+    def _release_side(self, seq: Sequence) -> None:
+        if self.side is not None:
+            self.side.release(seq)
 
-        PS = self.pool.page_size
-        lo = window_first_live_page(first_query, self.window, PS)
-        hi = last_query // PS
-        if len(seq.wpages) <= hi:
-            seq.wpages.extend([0] * (hi + 1 - len(seq.wpages)))
-        dead = [p for p in seq.wpages[seq.w_lo:lo] if p]
-        missing = [j for j in range(max(lo, seq.w_lo), hi + 1)
-                   if not seq.wpages[j]]
-        # what leaves the window comes back first: a row at its steady
-        # state gives one page and takes one
-        if self.wpool.n_free + len(dead) < len(missing):
-            raise NoSpace(f"need {len(missing)} window pages")
-        if dead:
-            self.wpool.release(dead)
-            self.window_pages_freed += len(dead)
-            for j in range(seq.w_lo, lo):
-                seq.wpages[j] = 0
-        seq.w_lo = max(seq.w_lo, lo)
-        for j, page in zip(missing, self.wpool.alloc(len(missing))):
-            seq.wpages[j] = page
-
-    def _release_window(self, seq: Sequence) -> None:
-        if self.wpool is not None and seq.wpages:
-            self.wpool.release([p for p in seq.wpages if p])
-        seq.wpages = []
-        seq.w_lo = 0
-
-    def _window_pages_for_chunks(self, plan):
-        """The window pages of a final plan's chunks (its decode rows got
-        theirs with their global pages, `_ensure_decode_capacity`). A chunk
-        of a mixed plan that finds none is left out of this iteration."""
-        if isinstance(plan, DecodePlan):
-            return plan
+    def _side_cover_for_chunks(self, plan):
+        """What the side cache keeps for a final plan's chunks (its decode
+        rows got theirs with their KV pages, `_ensure_decode_capacity`). A
+        chunk of a mixed plan that finds no unit is left out of this
+        iteration; a lone chunk that finds none cannot be served at all."""
         chunks = [plan] if isinstance(plan, PrefillPlan) else plan.prefills
         kept = []
         for p in chunks:
             try:
-                self._window_cover(p.seq, p.start_pos,
-                                   p.start_pos + len(p.chunk) - 1)
+                self.side.cover(p.seq, p.start_pos,
+                                p.start_pos + len(p.chunk) - 1)
                 kept.append(p)
             except NoSpace:
-                self.window_waits += 1
+                self.side.waits += 1
         if isinstance(plan, PrefillPlan):
             if kept:
                 return plan
             raise RuntimeError(
-                f"no window page for a prefill chunk of {len(plan.chunk)} "
-                f"tokens with nothing else to run: {self.wpool.num_pages} "
-                "window pages are too few for this batch and chunk size")
+                f"no unit of the {self.side.kind} cache for a prefill chunk "
+                f"of {len(plan.chunk)} tokens with nothing else to run: "
+                f"{self.side.units} units are too few for this batch and "
+                "chunk size")
         if not kept:
             return plan.decode
         plan.prefills[:] = kept
         return plan
-
-    @property
-    def window_tokens_resident(self) -> int:
-        """Tokens of context the window pool holds for the active sequences
-        (a page in use counts up to the sequence's computed length)."""
-        PS = self.pool.page_size
-        return sum(
-            min((j + 1) * PS, s.computed_len) - j * PS
-            for s in self.active
-            for j in range(s.w_lo, len(s.wpages))  # (below w_lo: all freed)
-            if s.wpages[j] and j * PS < s.computed_len)
 
     # -- prefill -----------------------------------------------------------
     def _plan_prefill(
@@ -689,8 +590,7 @@ class Scheduler:
         decode worker's pull, out of the active set."""
         seq.state = SeqState.FINISHED
         seq.finish_reason = "prefill_complete"
-        self._release_slot(seq)
-        self._release_window(seq)
+        self._release_side(seq)
         if seq in self.active:
             self.active.remove(seq)
 
@@ -707,11 +607,11 @@ class Scheduler:
         *not* yet computed, so computed_len = len(prompt) - 1."""
         if len(self.active) >= self.max_batch:
             return False
-        if self.state_slots or self.wpool is not None:
+        if self.side is not None:
             raise ValueError(
                 "admission with transferred KV is not built for a model "
-                "with state-space layers or a window pool: pages carry no "
-                "recurrent state and no window layers' cache")
+                f"with a {self.side.kind} cache beside its pages: pages do "
+                "not carry it")
         if not self._try_allocate(seq):
             return False
         seq.computed_len = len(seq.prompt) - 1
@@ -730,10 +630,9 @@ class Scheduler:
         tail copied — so the branch starts exactly where the parent is:
         same computed KV, same hash chain, one prefill-sampled token away
         from its first decode step. No prefill pass, no allocation."""
-        if (len(self.active) >= self.max_batch or self.state_slots
-                or self.wpool is not None):
-            # (a state slot or a window table cannot be forked: the engine
-            # asks for no branch)
+        if len(self.active) >= self.max_batch or self.side is not None:
+            # (what a side cache holds for the parent cannot be forked: the
+            # engine asks for no branch)
             self.pool.release(pages)
             return False
         branch.tokens = list(parent.tokens)
@@ -769,11 +668,11 @@ class Scheduler:
                 try:
                     if need > 0:
                         seq.pages.extend(self.pool.alloc(need))
-                    if self.wpool is not None:
+                    if self.side is not None:
                         # (the steps in flight read what they were given:
-                        # a page freed here is written by nothing before
+                        # a unit freed here is written by nothing before
                         # they are done, the device runs dispatches in order)
-                        self._window_cover(
+                        self.side.cover(
                             seq, seq.computed_len + seq.inflight, last_pos)
                     survivors.append(seq)
                     break
@@ -800,8 +699,7 @@ class Scheduler:
             raise StepsInFlight()
         log.info("preempting %s (recompute)", seq.request_id)
         self.pool.release(seq.pages)
-        self._release_slot(seq)  # it starts over from a zeroed state
-        self._release_window(seq)  # and prefills again from position 0
+        self._release_side(seq)  # it prefills again from position 0
         seq.pages = []
         seq.hash_chain = []
         seq.n_shared_pages = 0
@@ -854,8 +752,7 @@ class Scheduler:
         seq.state = SeqState.FINISHED
         seq.finish_reason = reason
         self.pool.release(seq.pages)
-        self._release_slot(seq)
-        self._release_window(seq)
+        self._release_side(seq)
         seq.pages = []
         seq.inflight = 0  # what is still queued for it is dropped at commit
         seq.spec_draft = []

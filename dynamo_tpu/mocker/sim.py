@@ -438,7 +438,7 @@ class SimRunner(Runner):
         self, n_steps: int, tokens, positions: List[int],
         page_tables, sampling, step: int, adapters=None, masks=None,
         biases=None, mask_fn=None, guided_dev=None, n_logprobs: int = -1,
-        histories=None, prompt_lens=None, slots=None, prev=None,
+        histories=None, prompt_lens=None, side=None, prev=None,
     ):
         """The tokens at once (they are a pure function of the inputs) and
         the time the device model owes for them on the handle: the device

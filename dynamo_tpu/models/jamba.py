@@ -43,6 +43,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.quant import embed_lookup, mm, tied_logits
 from dynamo_tpu.models.toolkit import (
     Params,
+    SideCacheOps,
     _write_kv,
     paged_attention_jnp,
     rms_norm,
@@ -455,3 +456,41 @@ def forward(
                 h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
         logits = tied_logits(h, params["embed"]).astype(jnp.float32)
     return logits, k_pool, v_pool, state
+
+
+# --------------------------------------------------------------------------
+# what the runner asks of a model with a cache beside its pages
+# --------------------------------------------------------------------------
+
+def _side_make_pool(config: ModelConfig, units: int, page_size: int, dtype):
+    # `S` is float32, as the published model keeps it; the convolution's
+    # inputs are the activations' dtype
+    return make_state_pool(config, units, conv_dtype=dtype)
+
+
+def _side_unit_bytes(config: ModelConfig, page_size: int, dtype) -> int:
+    return state_slot_bytes(config, conv_dtype=dtype)
+
+
+def _side_rows(sides, B: int, max_pages: int) -> jax.Array:
+    """int32 [B]: each row's state slot. Pad rows (None, and behind the
+    last) name the scratch slot and, having no position, change none."""
+    rows = np.zeros(B, np.int32)
+    rows[: len(sides)] = [s or 0 for s in sides]
+    return jnp.asarray(rows)
+
+
+def _side_segs(sides, lens, seg_cap: int, t_bucket: int,
+               max_pages: int) -> jax.Array:
+    """int32 [3, seg_cap]: each segment's slot, first flat token and token
+    count; dead entries hold no tokens."""
+    seg = np.zeros((3, seg_cap), np.int32)
+    n = len(lens)
+    seg[0, :n] = [s or 0 for s in sides]
+    seg[1, :n] = np.cumsum([0] + lens[:-1])
+    seg[2, :n] = lens
+    return jnp.asarray(seg)
+
+
+SIDE = SideCacheOps("state", _side_make_pool, _side_unit_bytes, _side_rows,
+                    _side_segs, forward)

@@ -43,10 +43,12 @@ and `sink_bias` are fills (`sink_bias` a ramp over the heads, see
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from dynamo_tpu.models.config import ModelConfig
@@ -54,6 +56,7 @@ from dynamo_tpu.models.moe import EXPERT_STACKS, _moe_block, experts_kernel_stac
 from dynamo_tpu.models.quant import embed_lookup, mm
 from dynamo_tpu.models.toolkit import (
     Params,
+    SideCacheOps,
     _write_kv,
     paged_attention_jnp,
     rms_norm,
@@ -398,3 +401,40 @@ def forward(
     if return_listed:
         out += (jnp.concatenate(listed, axis=0),)  # [L_moe]
     return out + ({"k": pools[WINDOW][0], "v": pools[WINDOW][1]},)
+
+
+# --------------------------------------------------------------------------
+# what the runner asks of a model with a cache beside its pages
+# --------------------------------------------------------------------------
+
+def _side_unit_bytes(config: ModelConfig, page_size: int, dtype) -> int:
+    return window_page_bytes(config, page_size, jnp.dtype(dtype).itemsize)
+
+
+def _window_tables(sides, n: int, max_pages: int) -> np.ndarray:
+    """int32 [n, max_pages]: the window page tables of `sides`, pad rows
+    (None, and behind the last) and missing entries at scratch page 0."""
+    out = np.zeros((n, max_pages), np.int32)
+    for i, row in enumerate(sides):
+        if row:
+            out[i, : len(row)] = row
+    return out
+
+
+def _side_rows(sides, B: int, max_pages: int) -> jax.Array:
+    return jnp.asarray(_window_tables(sides, B, max_pages))
+
+
+def _side_segs(sides, lens, seg_cap: int, t_bucket: int, max_pages: int):
+    """(tok_wpt int32 [t_bucket, max_pages], seg_wpt [seg_cap, max_pages]):
+    the window page tables by token and by segment, laid out as
+    build_ragged_metadata lays tok_pt and seg_pt."""
+    seg_wpt = _window_tables(sides, seg_cap, max_pages)
+    tok_wpt = np.zeros((t_bucket, max_pages), np.int32)
+    tok_wpt[: sum(lens)] = np.repeat(seg_wpt[: len(lens)], lens, axis=0)
+    return jnp.asarray(tok_wpt), jnp.asarray(seg_wpt)
+
+
+SIDE = SideCacheOps(
+    "window", make_window_pool, _side_unit_bytes, _side_rows, _side_segs,
+    partial(forward, return_routed=True, return_listed=True))

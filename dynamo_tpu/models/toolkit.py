@@ -11,7 +11,7 @@ _write_kv).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +21,29 @@ from jax import lax
 from dynamo_tpu.models.config import ModelConfig
 
 Params = Dict[str, Any]
+
+
+class SideCacheOps(NamedTuple):
+    """What the module of a model with a cache beside its KV pages answers
+    the runner, as its `SIDE` (engine/model_runner.py `_side_ops`;
+    docs/FAMILIES.md). The step programs take the pool as `state=` and
+    return it last, donated; `rows` and `segs` build what they take as
+    `slots=` and `seg_slots=`, in the format the model's forward reads, from
+    the operands the engine's side cache hands a step (`Runner` steps'
+    `side=`; None: the scratch unit)."""
+
+    kind: str  # Runner.side_kind (engine/side_cache.KINDS)
+    make_pool: Callable  # (config, units, page_size, dtype) -> the pool:
+    #   zeros, unit 0 scratch
+    unit_bytes: Callable  # (config, page_size, dtype) -> bytes of one unit
+    rows: Callable  # (sides, B, max_pages) -> `slots` at bucket B: a row a
+    #   sequence (None: a pad row), pad rows behind them
+    segs: Callable  # (sides, lens, seg_cap, t_bucket, max_pages) ->
+    #   `seg_slots` of the ragged step: a segment a sequence (decode rows
+    #   first, then the chunks, as _prep_ragged lays them) of lens[i] tokens
+    forward: Callable  # models/llama.forward's operands and `state=`,
+    #   `slots=` -> (logits, k_pool, v_pool, *picks, pool); picks (the chosen
+    #   experts, the listed ones) where the model routes
 
 
 def make_kv_pool(
@@ -63,7 +86,7 @@ def make_kv_pool(
                 raise ValueError(
                     "a model with an indexer keeps its latent pages and its "
                     "index keys unquantized (engine/runner_api.py "
-                    "indexer_refusal)")
+                    "refusal)")
             stub = stub[:-1] + (config.index_head_dim,)
         if kv_quantize == "int8":
             # int8 latent cache: one f32 scale per (token) latent vector —

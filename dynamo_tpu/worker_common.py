@@ -511,54 +511,20 @@ async def serve_worker(
         engine.on_fpm(_update_moe_gauges)
         _update_moe_gauges()
 
-    # state slots -> /metrics: a model with state-space layers keeps one
-    # slot of recurrent state a sequence (docs/observability.md "A
-    # sequence's state slot"). Only such a worker has the series.
-    if _runner is not None and _runner.holds_state:
+    # the side cache -> /metrics: what a sequence keeps beside its KV pages
+    # (engine/side_cache.py `gauges`; docs/observability.md "A sequence's
+    # state slot", "A sequence's two page tables"). Only a worker whose
+    # model has one has the series.
+    _side = getattr(engine, "side", None)
+    if _side is not None:
         _sm = runtime.metrics.child(dynamo_namespace=namespace)
 
-        def _update_slot_gauges(_m=None) -> None:
-            sched = engine.scheduler
-            _sm.gauge(
-                "state_slots_used",
-                "sequences that hold a state slot (scratch left out)",
-            ).set(sched.state_slots_used)
-            _sm.gauge(
-                "state_slots_total",
-                "state slots a sequence can be given (scratch left out)",
-            ).set(max(0, sched.state_slots - 1))
+        def _update_side_gauges(_m=None) -> None:
+            for name, doc, value in _side.gauges():
+                _sm.gauge(name, doc).set(value)
 
-        engine.on_fpm(_update_slot_gauges)
-        _update_slot_gauges()
-
-    # the window pool -> /metrics: a model whose window layers keep a cache
-    # of their own (docs/observability.md "A sequence's two page tables").
-    # Only such a worker has the series.
-    if _runner is not None and _runner.holds_window_pool:
-        _wm = runtime.metrics.child(dynamo_namespace=namespace)
-
-        def _update_window_gauges(_m=None) -> None:
-            sched, wp = engine.scheduler, engine.window_pool
-            _wm.gauge(
-                "window_pages_used",
-                "window-pool pages in use (scratch left out)",
-            ).set(wp.num_pages - 1 - wp.n_free)
-            _wm.gauge(
-                "window_pages_total",
-                "window-pool pages a sequence can be given (scratch left out)",
-            ).set(wp.num_pages - 1)
-            _wm.gauge(
-                "window_pages_freed_total",
-                "window pages given back as they left the window",
-            ).set(sched.window_pages_freed)
-            _wm.gauge(
-                "window_admission_waits_total",
-                "admissions and chunks put off for want of a window page "
-                "where the global pool had room",
-            ).set(sched.window_waits)
-
-        engine.on_fpm(_update_window_gauges)
-        _update_window_gauges()
+        engine.on_fpm(_update_side_gauges)
+        _update_side_gauges()
 
     # latency spine -> /metrics: per-finished-request phase durations as
     # histograms labeled by phase (queue_wait/ttft/kv_onboard/...; ITL

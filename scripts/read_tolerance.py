@@ -65,7 +65,7 @@ def _engine(config, dev, wargs, mpps, params, variant: str):
         # else: the control lays a bfloat16 pool of the engine's own size
         # under the runner before any sequence owns a slot
         runner.state = jax.device_put(jamba.make_state_pool(
-            config, runner.state_slots, jnp.bfloat16, runner.dtype), dev)
+            config, runner.side_units, jnp.bfloat16, runner.dtype), dev)
     return engine
 
 

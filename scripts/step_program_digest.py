@@ -53,4 +53,20 @@ for impl in ("jnp", "pallas"):
     md = build_ragged_metadata([1] * 4 + [40], [5] * 4 + [0], [6] * 4 + [40], [[1]] * 4 + [[2]], T, q_block=8, max_pages=MP)
     SEG, V = md["seg_page_table"].shape[0], c.vocab_size
     out[f"ai21-jamba2-3b/{impl}/ragged"] = dig(partial(mr._ragged_step, c, impl, None), params, s((1, T), i32), s((1, T), i32), s((T, MP), i32), s((T,), i32), s(md["seg_page_table"].shape, i32), s((SEG,), i32), s(md["meta"].shape, i32), s((SEG,), i32), *pools, samp(SEG), s((SEG,), i32), s((SEG,), i32), s((), i32), s((SEG, V), jnp.bool_), s((SEG, V), f32), state=state, seg_slots=s((3, SEG), i32))
+# the window-pool configuration: its decode loop and ragged step, which take
+# the window pool and the rows' window page tables under the same keywords
+from dynamo_tpu.models import mimo
+cfg = json.load(open(os.path.join(root, "benchmark", "configs", "mimo-v2-flash.json")))
+c = ModelConfig(**cfg["model"])
+flags = cfg["server_flags"]
+PS = flags["page-size"]
+params = jax.eval_shape(lambda: llama.init_params(c, jax.random.PRNGKey(0), jnp.bfloat16))
+pools = jax.eval_shape(lambda: llama.make_kv_pool(c, flags["num-pages"], PS, dtype=jnp.bfloat16))
+state = jax.eval_shape(lambda: mimo.make_window_pool(c, 165, PS))
+B, MP, T = 16, 96, 288
+for impl in ("jnp", "pallas"):
+    out[f"mimo-v2-flash/{impl}/decode_loop"] = dig(partial(mr._decode_loop, c, impl, None, 4, -1), params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None, *pools, samp(B), state=state, slots=s((B, MP), i32))
+    md = build_ragged_metadata([1] * 8 + [100], [5] * 8 + [0], [6] * 8 + [100], [[1]] * 8 + [[2, 3]], T, q_block=8, max_pages=MP)
+    SEG, V = md["seg_page_table"].shape[0], c.vocab_size
+    out[f"mimo-v2-flash/{impl}/ragged"] = dig(partial(mr._ragged_step, c, impl, None), params, s((1, T), i32), s((1, T), i32), s((T, MP), i32), s((T,), i32), s(md["seg_page_table"].shape, i32), s((SEG,), i32), s(md["meta"].shape, i32), s((SEG,), i32), *pools, samp(SEG), s((SEG,), i32), s((SEG,), i32), s((), i32), s((SEG, V), jnp.bool_), s((SEG, V), f32), state=state, seg_slots=(s((T, MP), i32), s((SEG, MP), i32)))
 print(json.dumps(out, indent=1))

@@ -152,7 +152,7 @@ def test_stop_releases_pages_and_slot_once():
     assert toks["x"] == free["x"][:k] and any(r.ahead for r in recs)
     sch = eng.scheduler
     assert not sch.active and eng.pool.n_free == 64
-    assert sorted(sch._free_slots) == list(range(1, sch.state_slots))
+    assert sorted(sch.side._free) == list(range(1, sch.side.units))
 
 
 def test_request_arriving_mid_stream_drains_then_resumes():

@@ -19,7 +19,8 @@ import pytest
 
 from dynamo_tpu import worker
 from dynamo_tpu.engine.model_runner import ModelRunner
-from dynamo_tpu.engine.scheduler import STATE_NO_PREFIX, Scheduler, SeqState
+from dynamo_tpu.engine.scheduler import Scheduler, SeqState
+from dynamo_tpu.engine.side_cache import StateSlots
 from dynamo_tpu.engine.kv_pool import PagePool
 from dynamo_tpu.engine.weights import (
     config_from_hf,
@@ -406,9 +407,9 @@ async def test_engine_sizes_the_pool_and_serves_through_every_program(monkeypatc
     engine, runner = _engine(monkeypatch, params)
     try:
         sched = engine.scheduler
-        assert runner.holds_state and runner.ragged_mixed
-        assert runner.state_slots == sched.state_slots == 4 + 1
-        assert runner.state_slot_bytes == jamba.state_slot_bytes(C, conv_dtype=jnp.float32)
+        assert runner.side_kind == "state" and runner.ragged_mixed
+        assert runner.side_units == sched.side.units == 4 + 1
+        assert runner.side_unit_bytes == jamba.state_slot_bytes(C, conv_dtype=jnp.float32)
         assert runner.k_pool.shape[0] == 2  # the attention layers alone
         assert not sched.enable_prefix_cache
         # junk in every slot: nothing a sequence reads before it wrote it
@@ -427,7 +428,7 @@ async def test_engine_sizes_the_pool_and_serves_through_every_program(monkeypatc
         assert max(r.state_slots_used for r in recs) >= 2
         assert all(r.state_slots_total == 4 for r in recs)
         assert sum(r.ssm_scan_tokens for r in recs) >= sum(len(r) for r in rest)
-        assert sched.state_slots_used == 0 and len(sched._free_slots) == 4
+        assert sched.side.used == 0 and len(sched.side._free) == 4
         # without logprobs the same drive rides the ragged program
         calls0 = runner.compile_stats()["ragged"]["calls"]
 
@@ -465,7 +466,7 @@ async def test_preempted_and_cancelled_sequences_leave_no_state_behind(monkeypat
             calls["n"] += 1
             run = [s for s in sched.active if s.state == SeqState.RUNNING]
             if run and run[0].n_generated >= 4 and not seen:
-                slot = run[0].state_slot
+                slot = run[0].side
                 # (StepsInFlight while the loop has a dispatch in flight:
                 # it commits that and plans again, and this runs again)
                 sched._preempt(run[0])
@@ -483,14 +484,14 @@ async def test_preempted_and_cancelled_sequences_leave_no_state_behind(monkeypat
             if not sched.active:
                 break
             await asyncio.sleep(0.01)
-        assert sched.state_slots_used == 0
-        freed = sched._free_slots[-1]
+        assert sched.side.used == 0
+        freed = sched.side._free[-1]
         assert float(jnp.abs(runner.state["S"][:, freed]).max()) > 0  # a's, stale
 
         async def watch():
             while not sched.active:
                 await asyncio.sleep(0.001)
-            return sched.active[0].state_slot
+            return sched.active[0].side
 
         slot, (toks, lps) = await asyncio.gather(watch(), _serve(engine, b, 8))
         assert slot == freed
@@ -503,7 +504,7 @@ def test_every_active_sequence_owns_a_slot_and_gives_it_back():
     from dynamo_tpu.engine.scheduler import Sequence
 
     sched = Scheduler(PagePool(64, 4), max_batch=2, enable_prefix_cache=False,
-                      state_slots=3)  # scratch + one a row
+                      side=StateSlots(3))  # scratch + one a row
 
     def seq(i):
         return Sequence(request_id=f"r{i}", prompt=[1, 2, 3], sampling={},
@@ -512,12 +513,12 @@ def test_every_active_sequence_owns_a_slot_and_gives_it_back():
     for i in range(3):
         sched.add(seq(i))
     sched.step_plan()
-    assert [s.state_slot for s in sched.active] == [1, 2]
-    assert len(sched.waiting) == 1 and sched.waiting[0].state_slot == 0
-    assert sched.state_slots_used == 2
+    assert [s.side for s in sched.active] == [1, 2]
+    assert len(sched.waiting) == 1 and sched.waiting[0].side is None
+    assert sched.side.used == 2
     sched.abort("r0")
     sched.step_plan()
-    assert sorted(s.state_slot for s in sched.active) == [1, 2]
+    assert sorted(s.side for s in sched.active) == [1, 2]
     assert not sched.waiting
 
 
@@ -572,18 +573,18 @@ def test_the_runner_names_the_decode_kernels_page_routine(params, caplog):
 def test_a_pool_with_fewer_slots_than_rows_is_refused():
     with pytest.raises(ValueError, match="3 state slots.*max_batch 8"):
         Scheduler(PagePool(64, 4), max_batch=8, enable_prefix_cache=False,
-                  state_slots=3)
+                  side=StateSlots(3))
 
 
 def test_a_step_on_a_pool_nobody_sized_raises(params):
     runner = ModelRunner(
         C, num_pages=16, page_size=4, max_pages_per_seq=8, decode_buckets=(2,),
         prefill_buckets=(8,), ragged_buckets=(8,), params=params, dtype=jnp.float32)
-    with pytest.raises(RuntimeError, match="ensure_state_slots"):
+    with pytest.raises(RuntimeError, match="ensure_side_cache"):
         runner.prefill([1, 2, 3], 0, [1], 0)
-    assert runner.ensure_state_slots(3) == 3 and runner.state_slots == 3
+    assert runner.ensure_side_cache(3) == 3 and runner.side_units == 3
     assert runner.state["S"].dtype == jnp.float32
-    runner.prefill([1, 2, 3], 0, [1], 0, slot=1)
+    runner.prefill([1, 2, 3], 0, [1], 0, side=1)
 
 
 # -- what a state-holding model refuses, in words ----------------------------
@@ -592,8 +593,8 @@ def test_a_step_on_a_pool_nobody_sized_raises(params):
 def test_every_path_that_cannot_carry_state_refuses_in_words(monkeypatch, params):
     words = "state-space layers"
     with pytest.raises(ValueError, match="matches no prefix"):
-        Scheduler(PagePool(8, 4), max_batch=2, enable_prefix_cache=True, state_slots=3)
-    assert words in STATE_NO_PREFIX
+        Scheduler(PagePool(8, 4), max_batch=2, enable_prefix_cache=True, side=StateSlots(3))
+    assert words in StateSlots.no_prefix
     with pytest.raises(ValueError, match="tier demotion.*" + words):
         _engine(monkeypatch, params, host_kv_blocks=8)
     with pytest.raises(ValueError, match="speculative decoding.*" + words):

@@ -23,10 +23,11 @@ import pytest
 from dynamo_tpu import worker
 from dynamo_tpu.engine.kv_pool import NoSpace, PagePool
 from dynamo_tpu.engine.model_runner import ModelRunner
-from dynamo_tpu.engine.runner_api import Runner, window_refusal
+from dynamo_tpu.engine.runner_api import Runner, refusal
 from dynamo_tpu.engine.scheduler import (
-    WINDOW_NO_PREFIX, PrefillPlan, Scheduler, SeqState, Sequence,
+    PrefillPlan, Scheduler, SeqState, Sequence,
 )
+from dynamo_tpu.engine.side_cache import WindowPages
 from dynamo_tpu.engine.weights import config_from_hf
 from dynamo_tpu.models import llama, mimo
 from dynamo_tpu.models.config import ModelConfig, get_config, mean_over_layers
@@ -463,11 +464,10 @@ def test_attention_kernels_with_two_head_sizes_and_a_sink(Hk, G, window, sinked)
 
 
 def _sched(window_pages=12, **kw):
-    wp = PagePool(window_pages, PS)
-    wp.alloc(1)
+    side = WindowPages(window_pages, PS, W)
     kw = {"max_batch": 3, "chunk_size": 16, "decode_steps": 2, "mixed_prefill_tokens": 8,
           "mixed_prefill_seqs": 2, "mixed_min_chunk": 4, "enable_prefix_cache": False, **kw}
-    return Scheduler(PagePool(64, PS), window_pool=wp, window=W, **kw), wp
+    return Scheduler(PagePool(64, PS), side=side, **kw), side.pool
 
 
 def _seq(rid, n, max_tokens=30):
@@ -486,8 +486,8 @@ def test_the_scheduler_accounts_for_both_kinds_of_pages():
         lo = mimo.window_first_live_page(start, W, PS)
         hi = (start + len(plan.chunk) - 1) // PS
         # every page the chunk's queries see is there, nothing below is kept
-        assert all(a.wpages[j] for j in range(lo, hi + 1))
-        assert not any(a.wpages[:lo]) and a.w_lo == lo
+        assert all(a.side[j] for j in range(lo, hi + 1))
+        assert not any(a.side[:lo]) and a.side.lo == lo
         sched.complete_prefill(plan)
         start += len(plan.chunk)
     assert len(a.pages) == 6  # the global table keeps all
@@ -498,17 +498,17 @@ def test_the_scheduler_accounts_for_both_kinds_of_pages():
         for _ in range(plan.n_steps):
             sched.complete_decode(a, 7)
         used.append(wp.num_pages - 1 - wp.n_free)
-        assert sum(1 for w in a.wpages if w) == used[-1]
+        assert sum(1 for w in a.side if w) == used[-1]
     # a decode row in its steady state: one page back for each one taken
-    assert max(used) <= mimo.window_pages_needed(W, PS, 2) and sched.window_pages_freed >= 5
-    assert sched.window_tokens_resident <= used[-1] * PS < a.computed_len
+    assert max(used) <= mimo.window_pages_needed(W, PS, 2) and sched.side.freed >= 5
+    assert sched.side.tokens_resident(sched.active) <= used[-1] * PS < a.computed_len
     b = _seq("b", 9)
     sched.add(b)
     sched.step_plan()
-    assert b.wpages and any(b.wpages)
-    held = [w for w in a.wpages if w]
+    assert b.side and any(b.side)
+    held = [w for w in a.side if w]
     sched._preempt(a)
-    assert a.wpages == [] and a.w_lo == 0 and a.computed_len == 0
+    assert a.side is None and a.computed_len == 0
     assert all(p in wp.free for p in held)
     sched.abort("b")
     sched.abort("a")
@@ -520,16 +520,16 @@ def test_an_admission_waits_for_a_window_page_and_says_so():
     a, b, c = _seq("a", 12), _seq("b", 12), _seq("c", 12)
     sched.add(a), sched.add(b), sched.add(c)
     plan = sched.step_plan()
-    assert plan.seq is a and b.state == SeqState.PREFILL and any(b.wpages)
-    assert c.state == SeqState.WAITING and not c.pages and sched.window_waits == 1
+    assert plan.seq is a and b.state == SeqState.PREFILL and any(b.side)
+    assert c.state == SeqState.WAITING and not c.pages and sched.side.waits == 1
     assert wp.n_free == 0 and sched.pool.n_free == 64 - 4
     with pytest.raises(NoSpace):
-        sched._window_cover(b, 0, 11)
-    assert sum(1 for w in b.wpages if w) == 1  # nothing more taken
+        sched.side.cover(b, 0, 11)
+    assert sum(1 for w in b.side if w) == 1  # nothing more taken
     with pytest.raises(ValueError, match="matches no prefix"):
-        Scheduler(PagePool(8, PS), window_pool=wp, window=W, enable_prefix_cache=True)
+        Scheduler(PagePool(8, PS), side=sched.side, enable_prefix_cache=True)
     with pytest.raises(ValueError, match="sliding window"):
-        Scheduler(PagePool(8, PS), window_pool=wp, window=0, enable_prefix_cache=False)
+        Scheduler(PagePool(8, PS), side=WindowPages(3, PS, 0), enable_prefix_cache=False)
 
 
 # -- the engine --------------------------------------------------------------------
@@ -579,13 +579,13 @@ def _held_to_reference(params, ids, toks, lps):
 async def test_engine_sizes_the_window_pool_and_serves_through_every_program(monkeypatch, params):
     engine, runner = _engine(monkeypatch, params)
     try:
-        sched, wp = engine.scheduler, engine.window_pool
-        assert runner.holds_window_pool and runner.ragged_mixed and not runner.holds_state
-        assert isinstance(runner, Runner) and Runner.holds_window_pool is False
+        sched, wp = engine.scheduler, engine.side.pool
+        assert runner.side_kind == "window" and runner.ragged_mixed
+        assert isinstance(runner, Runner) and Runner.side_kind is None
         # 4 rows x the pages 2 fused steps see, one iteration's chunks, scratch
         a_row = mimo.window_pages_needed(W, PS, 4)  # (sized at the built decode_steps)
-        assert runner.window_pages == wp.num_pages >= 1 + 4 * a_row
-        assert runner.window_page_bytes == mimo.window_page_bytes(C, PS, 4)
+        assert runner.side_units == wp.num_pages >= 1 + 4 * a_row
+        assert runner.side_unit_bytes == mimo.window_page_bytes(C, PS, 4)
         assert runner.k_pool.shape == (2, 96, PS, 1, 24) and runner.v_pool.shape[-1] == 16
         assert runner.state["k"].shape == (5, wp.num_pages, PS, 2, 24)
         assert not sched.enable_prefix_cache and wp.n_free == wp.num_pages - 1
@@ -604,7 +604,7 @@ async def test_engine_sizes_the_window_pool_and_serves_through_every_program(mon
         recs = engine.recorder.snapshot()
         assert all(r.window_pages_total == wp.num_pages - 1 for r in recs)
         assert max(r.window_pages_used for r in recs) >= 4
-        assert sum(r.window_pages_freed for r in recs) == sched.window_pages_freed > 0
+        assert sum(r.window_pages_freed for r in recs) == sched.side.freed > 0
         assert all(r.window_tokens_resident <= r.context_tokens_live for r in recs)
         assert any(0 < r.window_tokens_resident < r.context_tokens_live for r in recs)
         assert any(0 < r.decode_pages_live_window < r.decode_pages_live_global for r in recs)
@@ -628,14 +628,14 @@ async def test_preempted_and_cancelled_sequences_leave_no_window_page_behind(mon
     (stale keys in them). Both end with the logprobs of a fresh run."""
     engine, runner = _engine(monkeypatch, params)
     try:
-        sched, wp = engine.scheduler, engine.window_pool
+        sched, wp = engine.scheduler, engine.side.pool
         a, b = _tokens(30, 20), _tokens(21, 21)
         plan, seen = sched.step_plan, {}
 
         def preempting():
             run = [s for s in sched.active if s.state == SeqState.RUNNING]
             if run and run[0].n_generated >= 4 and not seen:
-                held = [w for w in run[0].wpages if w]
+                held = [w for w in run[0].side if w]
                 # (StepsInFlight while the loop has a dispatch in flight:
                 # it commits that and plans again, and this runs again)
                 sched._preempt(run[0])
@@ -657,9 +657,9 @@ async def test_preempted_and_cancelled_sequences_leave_no_window_page_behind(mon
         assert float(jnp.abs(runner.state["k"][:, jnp.asarray(sorted(freed))]).max()) > 0
 
         async def watch():
-            while not (sched.active and any(sched.active[0].wpages)):
+            while not (sched.active and any(sched.active[0].side or ())):
                 await asyncio.sleep(0.001)
-            return set(w for w in sched.active[0].wpages if w)
+            return set(w for w in sched.active[0].side if w)
 
         took, (toks, lps) = await asyncio.gather(watch(), _serve(engine, b, 8))
         assert took & freed
@@ -671,10 +671,10 @@ async def test_preempted_and_cancelled_sequences_leave_no_window_page_behind(mon
 def test_a_step_on_a_window_pool_nobody_sized_raises(params):
     runner = ModelRunner(C, num_pages=16, page_size=PS, max_pages_per_seq=8, params=params,
                          dtype=jnp.float32)
-    with pytest.raises(RuntimeError, match="ensure_window_pages"):
+    with pytest.raises(RuntimeError, match="ensure_side_cache"):
         runner.prefill([1, 2, 3], 0, [1], prior_len=0)
-    assert runner.ensure_window_pages(9) == 9 == runner.window_pages
-    assert runner.ensure_state_slots(4) == 0
+    assert runner.ensure_side_cache(9) == 9 == runner.side_units
+    assert runner.ensure_side_cache(4) == 9  # (it never shrinks)
     # the warm-up's dummies: one table, no window table (every entry scratch)
     runner.prefill([1, 2, 3], 0, [1], prior_len=0)
     assert runner.device_report()["window_pages"] == 9
@@ -682,7 +682,7 @@ def test_a_step_on_a_window_pool_nobody_sized_raises(params):
 
 def test_every_path_that_cannot_carry_a_window_pool_refuses_in_words(monkeypatch, params):
     words = "window pool"
-    assert words in WINDOW_NO_PREFIX and words in window_refusal("m", "x")
+    assert words in WindowPages.no_prefix and words in refusal("window", "m", "x")
     with pytest.raises(ValueError, match="tier demotion.*" + words):
         _engine(monkeypatch, params, host_kv_blocks=8)
     with pytest.raises(ValueError, match="speculative decoding.*" + words):
