@@ -36,7 +36,7 @@ from dynamo_tpu.engine.runner_api import (
     refusal,
 )
 from dynamo_tpu.engine.sampling import SamplingParams, sample
-from dynamo_tpu.models import jamba, llama, mimo
+from dynamo_tpu.models import jamba, ling, llama, mimo
 from dynamo_tpu.models.config import ModelConfig, mean_over_layers
 from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
@@ -90,6 +90,8 @@ def _side_ops(config: ModelConfig):
         return jamba.SIDE
     if config.has_window_pool:
         return mimo.SIDE
+    if config.is_kda:
+        return ling.SIDE
     return None
 
 
@@ -999,6 +1001,8 @@ class ModelRunner(Runner):
             if draft_config is not None or lora_slots > 0:
                 self._no_side(
                     "speculative decoding with a draft model (and LoRA)")
+            if config.is_kda and kv_quantize:
+                self._no_side("a quantized KV cache (--kv-quantize)")
         if self.side_kind == "window":
             if mc.n_devices > 1:
                 raise NotImplementedError(
@@ -1015,7 +1019,7 @@ class ModelRunner(Runner):
         # pair in a form the selection was never run on. It has no fused
         # mixed program: chunks ride beside the decoding rows as two
         # dispatches (Runner.fuses_mixed)
-        self.fuses_mixed = not config.has_indexer
+        self.fuses_mixed = not (config.has_indexer or config.is_kda)
         if config.has_indexer:
             if kv_quantize:
                 raise NotImplementedError(refusal(

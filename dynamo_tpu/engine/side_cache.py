@@ -7,7 +7,8 @@ what a step hands its program for a row, what an iteration records, the
 pages alone. The scheduler calls `admit` / `cover` / `release` where it
 takes and gives back KV pages, the engine `operand` / `record`, the worker
 `gauges`; none of them knows which kind a model has. Two kinds exist:
-`StateSlots` (models/jamba.py: one slot of recurrent state a sequence) and
+`StateSlots` (models/jamba.py and models/ling.py: one slot of recurrent
+state a sequence, Mamba's or KDA's) and
 `WindowPages` (models/mimo.py: a second page table into the window layers'
 pool). A pool that rides the KV page table (models/mla.py's index keys) is
 no side cache: pages carry it.
@@ -68,9 +69,10 @@ class SideCache:
 
 class StateSlots(SideCache):
     """One slot of recurrent state a sequence (a model with state-space
-    layers): a free list over the runner's state pool, slot 0 scratch. A
-    sequence's first token starts from zeros whatever the slot held
-    (models/jamba.py), so a slot needs no clearing."""
+    layers, models/jamba.py, or with KDA layers, models/ling.py): a free
+    list over the runner's state pool, slot 0 scratch. A sequence's first
+    token starts from zeros whatever the slot held, so a slot needs no
+    clearing."""
 
     kind = "state"
     no_prefix = (
@@ -79,8 +81,9 @@ class StateSlots(SideCache):
         "snapshots, so its scheduler runs without the prefix cache and without "
         "a host tier (and publishes no stored blocks)")
 
-    def __init__(self, units: int):
+    def __init__(self, units: int, kda: bool = False):
         self.units = int(units)
+        self.kda = kda  # the slots hold KDA states (models/ling.py)
         # free slots, lowest first; 0 is scratch and never handed out
         self._free: List[int] = list(range(self.units - 1, 0, -1))
 
@@ -88,7 +91,9 @@ class StateSlots(SideCache):
     def for_runner(cls, runner: Runner, *, max_batch: int, **_limits):
         # one slot for every sequence that can be active (a chunk a step
         # packs beside the batch is an active sequence's), and scratch
-        return cls(runner.ensure_side_cache(max_batch + 1))
+        # (a cost model's runner may carry no ModelConfig)
+        return cls(runner.ensure_side_cache(max_batch + 1),
+                   kda=getattr(runner.config, "is_kda", False))
 
     def check_limits(self, page_size: int, max_batch: int) -> None:
         if self.units - 1 < max_batch:
@@ -113,6 +118,13 @@ class StateSlots(SideCache):
     def record(self, record, rinfo: dict, active: List[Any]) -> None:
         record.state_slots_used = self.used
         record.state_slots_total = self.units - 1
+        if self.kda:
+            # no ragged step: every chunk is `kda_chunk`'s, every decode
+            # step `kda_update`'s
+            record.kda_update_rows = rinfo["decode_seqs"] * rinfo["decode_steps"]
+            record.kda_chunk_segments = rinfo["n_chunks"]
+            record.kda_chunk_tokens = rinfo["chunk_tokens"]
+            return
         # the scan's work: every prefill chunk (standalone or in the
         # ragged step) and the ragged step's decode rows, segments of
         # one token. The decode loop's steps run the one-token update.

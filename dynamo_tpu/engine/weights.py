@@ -48,6 +48,13 @@ def load_hf_checkpoint(
     import ml_dtypes
     from safetensors import safe_open
 
+    if config.is_kda:
+        raise NotImplementedError(
+            f"{config.name}: no checkpoint loader for a model with KDA layers "
+            "(models/ling.py) yet: the published config gives the family's "
+            "keys and no tensor names, so the tree's leaves cannot be mapped "
+            "until a checkpoint's index is in the repository; it serves drawn "
+            "weights (--model tiny-ling, benchmark/configs/ling-3.0-flash-vl.json)")
     np_dtype = np.dtype(ml_dtypes.bfloat16) if dtype == "bfloat16" else np.dtype(dtype)
     d = Path(checkpoint_dir)
     files = sorted(d.glob("*.safetensors"))

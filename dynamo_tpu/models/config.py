@@ -208,6 +208,20 @@ class ModelConfig:
     attn_value_scale: float = 0.0
     sink_window: bool = False
     sink_global: bool = False
+    # Kimi-delta-attention layers beside gated latent attention (Ling-3.0;
+    # models/ling.py): layer l is latent attention iff (l + 1) %
+    # kda_layer_period == 0 and a KDA mixer otherwise: `n_heads` heads whose
+    # keys and values are `kda_head_dim` wide, a matrix state [d_k, d_v] a
+    # head updated by a per-channel gated delta rule (ops/kda.py), causal
+    # convolutions of `kda_conv` taps on q, k and v, the log-decay a channel
+    # in (`kda_gate_lower`, 0). 0 = no such layer: every preset but the ling
+    # ones. A sequence then carries, besides the latent pages of its MLA
+    # layers, one state slot (engine/side_cache.StateSlots). Both kinds of
+    # mixer end in a head-wise sigmoid output gate.
+    kda_layer_period: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_lower: float = -5.0
 
     def __post_init__(self):
         if self.layer_pattern:
@@ -268,6 +282,27 @@ class ModelConfig:
                     "projected from the compressed query) and an even "
                     "index_head_dim of at least qk_rope_head_dim (the rotary "
                     "turns a head's first qk_rope_head_dim dims)")
+        if self.is_kda:
+            if not (self.is_mla and self.kda_layer_period > 1
+                    and self.kda_head_dim > 0 and self.kda_conv > 1
+                    and self.kda_gate_lower < 0):
+                raise ValueError(
+                    "KDA layers (models/ling.py) need attn_type='mla' for "
+                    "the layer that closes each kda_layer_period > 1, "
+                    "kda_head_dim > 0, kda_conv > 1 and kda_gate_lower < 0")
+            if (self.is_hybrid or self.has_indexer or self.layer_pattern
+                    or self.sliding_window or self.tie_embeddings
+                    or self.attn_qscale_beta or self.attn_bias
+                    or self.post_norms or not self.pre_norms
+                    or self.act != "silu"):
+                raise ValueError(
+                    "a model with KDA layers is Ling-3.0's: plain pre-norm "
+                    "residuals, SwiGLU, an untied head, latent attention "
+                    "without indexer, window or query scale")
+        elif self.kda_head_dim:
+            raise ValueError(
+                "kda_head_dim is read by the walk over KDA layers alone "
+                "(models/ling.py): state kda_layer_period")
         if self.is_hybrid:
             if not (0 <= self.attn_layer_offset < self.attn_layer_period
                     and self.mamba_dt_rank > 0):
@@ -291,7 +326,14 @@ class ModelConfig:
         """State-space layers beside the attention layers (Jamba)."""
         return self.mamba_d_state > 0
 
+    @property
+    def is_kda(self) -> bool:
+        """Kimi-delta-attention layers beside the latent attention (Ling)."""
+        return self.kda_layer_period > 0
+
     def is_attn_layer(self, l: int) -> bool:
+        if self.is_kda:
+            return (l + 1) % self.kda_layer_period == 0
         return (not self.is_hybrid
                 or l % self.attn_layer_period == self.attn_layer_offset)
 
@@ -307,7 +349,13 @@ class ModelConfig:
         window-pool model's global layers, else all."""
         if self.has_window_pool:
             return len(self.global_layers)
-        return len(self.attn_layers) if self.is_hybrid else self.n_layers
+        if self.is_hybrid or self.is_kda:
+            return len(self.attn_layers)
+        return self.n_layers
+
+    @property
+    def kda_layers(self) -> int:
+        return self.n_layers - self.kv_layers if self.is_kda else 0
 
     @property
     def has_window_pool(self) -> bool:
@@ -375,9 +423,12 @@ class ModelConfig:
         is the width and the page count, not the indexer, so `deepseek-v3`
         at thousands of pages is knowingly left on the slow layout until a
         PR of its own may move `mistral-small-4-119b`'s step programs (the
-        rule then: `mla_cache_dim > 128` and not a whole number of rows)."""
+        rule then: `mla_cache_dim > 128` and not a whole number of rows). A
+        model with KDA layers (PR 49) is new to the tree and takes the whole
+        rows from the start."""
         d = self.mla_cache_dim
-        return -(-d // 128) * 128 if self.has_indexer and d > 128 else d
+        wide = self.has_indexer or self.is_kda
+        return -(-d // 128) * 128 if wide and d > 128 else d
 
     @property
     def has_indexer(self) -> bool:
@@ -732,6 +783,19 @@ PRESETS: Dict[str, ModelConfig] = {
         name="tiny-jamba", n_layers=8, n_heads=4, n_kv_heads=1,
         tie_embeddings=True, norm_eps=1e-6, mamba_d_state=4,
         mamba_dt_rank=8, attn_layer_period=4, attn_layer_offset=2,
+    ),
+    # Ling-3.0's structure at test size (CPU CI; models/ling.py): two periods
+    # of three (KDA, KDA, MLA), the first layer dense, 4 heads of 16 in the
+    # KDA layers, latent rank 32 without query compression, 16 sigmoid
+    # experts in 4 groups of which 2 stay, the second quarter held
+    "tiny-ling": ModelConfig(
+        name="tiny-ling", n_layers=6, n_heads=4, n_kv_heads=4, attn_type="mla",
+        kv_lora_rank=32, qk_rope_head_dim=16, qk_nope_head_dim=32,
+        v_head_dim=32, kda_layer_period=3, kda_head_dim=16, norm_eps=1e-6,
+        rope_theta=6e6, n_experts=16, n_experts_active=4, moe_ffn_dim=64,
+        n_shared_experts=1, moe_scoring="sigmoid", moe_router_bias=True,
+        moe_routed_scale=2.5, n_dense_layers=1, n_expert_groups=4,
+        topk_groups=2, n_experts_held=4, expert_first=4,
     ),
     # test-size MiMo-V2-Flash structure (models/mimo.py): a dense global
     # layer, four window layers, a global one, a window one; window 16 (two

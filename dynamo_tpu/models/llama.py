@@ -77,6 +77,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
         from dynamo_tpu.models import mimo
 
         return mimo.init_params(c, key, dtype)
+    if c.is_kda:  # Ling-3.0: KDA layers beside latent attention (models/ling.py)
+        from dynamo_tpu.models import ling
+
+        return ling.init_params(c, key, dtype)
     if c.is_moe and c.n_dense_layers:
         moe_part = _init_layer_stack(
             c, key, c.n_layers - c.n_dense_layers, moe=True, dtype=dtype
@@ -292,6 +296,10 @@ def forward(
         raise NotImplementedError(
             "a model with window and global layers runs models/mimo.forward, "
             "which takes and returns the window pool; this path has one pool")
+    if c.is_kda:
+        raise NotImplementedError(
+            "a model with KDA layers runs models/ling.forward, which takes "
+            "and returns the state pool; this path has no state")
     if return_routed and not c.is_moe:
         raise ValueError("return_routed needs a model with routed experts")
     if return_listed and not return_routed:

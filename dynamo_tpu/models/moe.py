@@ -49,7 +49,8 @@ def experts_kernel_stack(c: ModelConfig, layers, rows: int, mesh,
     what the forward can see, all of it static: the Pallas kernels are in
     use (`attn_impl`, the runner's rule: Pallas on the chip, jnp
     elsewhere), no `expert` or `model` mesh axis above 1, unquantized
-    experts, at most `moe_experts.MAX_ROWS` rows (where every listed
+    experts, at most `moe_experts.row_bound` rows (32, or 64 under a wide
+    router whose picks leave experts unread there: where every listed
     expert taking all rows is still bound by the weights it streams: a
     decode step and its chained steps, not a prefill chunk), and an ffn
     tile that fits VMEM. The caller takes the three stacks out of what
@@ -58,7 +59,7 @@ def experts_kernel_stack(c: ModelConfig, layers, rows: int, mesh,
 
     we_gate = layers.get("we_gate")
     if (attn_impl != "pallas" or we_gate is None or is_quantized(we_gate)
-            or rows > moe_experts.MAX_ROWS):
+            or rows > moe_experts.row_bound(c.n_experts, c.n_experts_active)):
         return None
     if mesh is not None and any(
             mesh.shape.get(a, 1) > 1 for a in ("expert", "model")):

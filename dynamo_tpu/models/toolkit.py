@@ -75,8 +75,9 @@ def make_kv_pool(
     contract without meaningful memory. A model with an indexer
     (DeepSeek-V3.2) caches its index keys there instead of the stub."""
     if config.is_mla:
-        lat = (config.n_layers, num_pages, page_size, 1, config.mla_pool_dim)
-        stub = (config.n_layers, num_pages, page_size, 1, 1)
+        # (a model with KDA layers keeps latents of its MLA layers alone)
+        lat = (config.kv_layers, num_pages, page_size, 1, config.mla_pool_dim)
+        stub = (config.kv_layers, num_pages, page_size, 1, 1)
         if config.has_indexer:
             # the indexer's keys take the second array: one index_head_dim
             # vector a token beside its latent, under the same page table

@@ -35,9 +35,15 @@ flops a byte (197 TFLOP/s over 819 GB/s); the MXU holds a 128 x 128 tile
 of WEIGHTS stationary and loading one costs about what 128 rows through
 it do, so below 128 rows the product costs as if T were 128, still about
 half of what the stream allows. `MAX_ROWS` = 32 keeps a factor of four
-under that, and is where the list stops paying anyway: at 32 rows of 4
-picks a share of 32 held experts of 128 is hit 20 times in 32, at 64
-rows 28 times.
+under that, and is where the list stops paying for a router of few
+experts: at 32 rows of 4 picks a share of 32 held experts of 128 is hit
+20 times in 32, at 64 rows 28 times. A WIDE router still leaves experts
+unread there (8 picks of 512: 64 rows reach 63 % of the experts if they
+pick alone, and the MXU's cost is the same at 64 rows as at 32), so
+`row_bound` takes up to `WIDE_ROWS` = 64 rows where the rows' picks,
+drawn evenly, would leave at least a quarter of the experts unread
+(PERF.md section 6, PR 49: a 64-row decode bucket of ling-3.0-flash-vl on
+the every-expert path read 32 held experts a layer where its rows hit 14).
 """
 
 from __future__ import annotations
@@ -53,8 +59,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.paged_attention import _div, _rem
 
-# the row bound of the all-rows-per-hit-expert form (module docstring)
+# the row bounds of the all-rows-per-hit-expert form (module docstring)
 MAX_ROWS = 32
+WIDE_ROWS = 64
+UNREAD_SHARE = 0.25
+
+
+def row_bound(n_experts: int, k: int) -> int:
+    """The most rows a forward may have to take the work-list kernel: from
+    the router's width and its picks a token alone, both static."""
+    unread = (1.0 - k / max(n_experts, 1)) ** WIDE_ROWS
+    return WIDE_ROWS if unread >= UNREAD_SHARE else MAX_ROWS
 # two buffers of a step's three weight tiles may take this much VMEM; the
 # call asks for VMEM_LIMIT_BYTES of scoped VMEM (Mosaic's default is
 # 16 MiB, a compiler default: a v5e core has 128 MiB)

@@ -228,6 +228,13 @@ class IterationRecord:
     state_slots_total: int = 0
     ssm_scan_tokens: int = 0
     ssm_scan_segments: int = 0
+    # a model with KDA layers (models/ling.py; it keeps state slots too):
+    # what its kernels were given this iteration, a KDA layer: rows of the
+    # decode loop's one-token `kda_update` summed over its steps, and the
+    # tokens and segments (chunks) of `kda_chunk`
+    kda_update_rows: int = 0
+    kda_chunk_tokens: int = 0
+    kda_chunk_segments: int = 0
     # a model with a window pool (0 for every other): the window pool's
     # pages in use after the step and the pages there are (scratch left
     # out); the tokens of context those pages hold and the tokens of

@@ -8,7 +8,10 @@ such option), for a model with sinks on its window layers the program with
 the sink left out (`no-sink`: the same tree served under a configuration
 whose `sink_window` is off); for a model with an indexer the mechanism's own
 two (`recent`: the selection replaced by the most recent `index_topk` tokens;
-`no-selection`: every cached token attended to). Chip only (like serve.py it refuses a CPU unless --rehearse).
+`no-selection`: every cached token attended to); for a model with KDA layers
+`state-bf16` likewise, `no-decay` (the decay held at 1: the same tree served
+under a `kda_gate_lower` of -1e-9) and `chunk-end` (every prefill chunk leaves
+zeros in its slot: a state lost at a chunk boundary). Chip only (like serve.py it refuses a CPU unless --rehearse).
 
     python scripts/read_tolerance.py --config benchmark/configs/<name>.json \
         --seeds 3600000300:3600000312 [--tolerance 0.1] [--out chiprun_out/tol.jsonl]
@@ -49,8 +52,10 @@ def _engine(config, dev, wargs, mpps, params, variant: str):
 
     from dynamo_tpu import worker
     from dynamo_tpu.engine.model_runner import ModelRunner
-    from dynamo_tpu.models import jamba
+    from dynamo_tpu.models import jamba, ling
 
+    if variant == "no-decay":  # alpha held at 1: the delta rule without its gate
+        config = config.with_(kda_gate_lower=-1e-9)
     if variant == "no-sink":
         config = config.with_(sink_window=False)
     if variant == "no-selection":  # every cached token attended to
@@ -64,9 +69,38 @@ def _engine(config, dev, wargs, mpps, params, variant: str):
         # the program keeps S in float32 and has no option for anything
         # else: the control lays a bfloat16 pool of the engine's own size
         # under the runner before any sequence owns a slot
-        runner.state = jax.device_put(jamba.make_state_pool(
+        mod = ling if config.is_kda else jamba
+        runner.state = jax.device_put(mod.make_state_pool(
             config, runner.side_units, jnp.bfloat16, runner.dtype), dev)
     return engine
+
+
+@contextlib.contextmanager
+def _state_lost_at_chunk_ends(on: bool):
+    """The `chunk-end` control of a model with KDA layers: while its programs
+    are traced, a prefill chunk hands back zeros for the state it leaves, so
+    every chunk and the first decode step start from an empty slot: what a
+    slot lost, or never stored, at a chunk boundary would give."""
+    from dynamo_tpu.ops import kda
+
+    if not on:
+        yield
+        return
+    sound = {n: getattr(kda, n) for n in ("kda_chunk", "kda_chunk_jnp")}
+
+    def lossy(form):
+        def chunk(*a, **kw):
+            o, S = form(*a, **kw)
+            return o, S * 0
+        return chunk
+
+    for n, form in sound.items():  # the chip's form and the CPU's
+        setattr(kda, n, lossy(form))
+    try:
+        yield
+    finally:
+        for n, form in sound.items():
+            setattr(kda, n, form)
 
 
 @contextlib.contextmanager
@@ -169,6 +203,7 @@ async def main(args) -> int:
     mpps = -(-wargs.max_seq_len // wargs.page_size)
     # (int8 last: where the tree is large it consumes the seed's params)
     variants = (["sound"] + (["state-bf16"] if config.is_hybrid else [])
+                + (["state-bf16", "no-decay", "chunk-end"] if config.is_kda else [])
                 + (["no-sink"] if config.sink_window else [])
                 + (["recent", "no-selection"] if config.has_indexer else [])
                 + ["int8"])
@@ -191,7 +226,8 @@ async def main(args) -> int:
             engine = await asyncio.to_thread(  # off the event loop
                 _engine, config, dev, wargs, mpps, served, variant)
             del served
-            with _recent_tokens_selected(variant == "recent"):
+            with _recent_tokens_selected(variant == "recent"), \
+                    _state_lost_at_chunk_ends(variant == "chunk-end"):
                 res = await serve.reference_check(
                     ref, model, engine, seed, args.tolerance, args.rehearse, args.margin,
                     params=ref_tree)

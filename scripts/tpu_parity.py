@@ -809,6 +809,56 @@ def check_ssm_scan(state_dtype: str) -> float:
     return max(_max_err(y1, y0), _max_err(p1, p0))
 
 
+def _kda_operands(rng, T: int, bound: bool = False):
+    """T tokens' operands at ling-3.0-flash-vl's widths (32 heads of 128):
+    q and k L2-normed a head, log-decays in (-5, 0) (`bound`: every channel at
+    the lower bound), beta in (0, 1)."""
+    H, d = 32, 128
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    g = jnp.full((T, H, d), -5.0 * (1 - 1e-6)) if bound else (
+        -5.0 * jax.nn.sigmoid(2 * f(T, H, d) - 4))
+    return (unit(f(T, H, d)) * d ** -0.5, unit(f(T, H, d)), f(T, H, d), g,
+            jax.nn.sigmoid(f(T, H)))
+
+
+def check_kda_update() -> float:
+    """The decode step's delta-rule update: 13 live rows of a bucket of 16,
+    one of them a sequence's first token; the padding rows move nothing."""
+    from dynamo_tpu.ops import kda
+
+    rng = np.random.default_rng(17)
+    pool = jnp.asarray(rng.standard_normal((3, 9, 32, 128, 128)), jnp.float32)
+    ops = _kda_operands(rng, 16)
+    slots = jnp.asarray([5, 1, 8, 2, 7, 3, 6, 4] + [0] * 8, jnp.int32)
+    live = jnp.arange(16) < 13
+    fresh = jnp.arange(16) == 2
+    with jax.default_matmul_precision("highest"):
+        y0, p0 = kda.kda_update_jnp(pool, LAYER, slots, live, fresh, *ops)
+    y1, p1 = kda.kda_update(pool, LAYER, slots, live, fresh, *ops,
+                            interpret=INTERPRET)
+    keep = [1, 2, 3, 4, 5, 6, 7, 8]
+    untouched = _max_err(np.asarray(p1)[:LAYER], np.asarray(pool)[:LAYER])
+    return max(_max_err(y1[:8], y0[:8]), _max_err(y1[13:], y0[13:]),
+               _max_err(np.asarray(p1)[LAYER, keep], np.asarray(p0)[LAYER, keep]),
+               untouched)
+
+
+def check_kda_chunk(T: int, bound: bool) -> float:
+    """A prefill chunk from a carried-in state against the token-by-token
+    recurrence in float32 (x 100: the gate's tolerance is bf16 attention's,
+    this kernel's is float32's, 5e-4 on outputs of ~0.3)."""
+    from dynamo_tpu.ops import kda
+
+    rng = np.random.default_rng(18 + T)
+    S0 = jnp.asarray(rng.standard_normal((32, 128, 128)), jnp.float32)
+    ops = _kda_operands(rng, T, bound)
+    with jax.default_matmul_precision("highest"):
+        o0, s0 = jax.jit(kda.kda_recurrence)(S0, *ops)
+    o1, s1 = kda.kda_chunk(S0, *ops, interpret=INTERPRET)
+    return 100 * max(_max_err(o1, o0), _max_err(s1, s0))
+
+
 def all_checks():
     """(name, thunk) for every check, GQA cross product first."""
     checks = []
@@ -880,6 +930,13 @@ def all_checks():
             (f"ssm_scan {dt} state @ai21-jamba2-3b",
              functools.partial(check_ssm_scan, dt)),
         ]
+    checks += [
+        ("kda_update @ling-3.0-flash-vl", check_kda_update),
+        ("kda_chunk 512 tokens @ling-3.0-flash-vl",
+         functools.partial(check_kda_chunk, 512, False)),
+        ("kda_chunk 200 tokens at the decay bound @ling-3.0-flash-vl",
+         functools.partial(check_kda_chunk, 200, True)),
+    ]
     return checks
 
 

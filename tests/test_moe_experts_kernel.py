@@ -130,6 +130,21 @@ def test_kernel_is_the_dense_path_over_the_hit_experts(interpreted, layout, T,
     assert not got[~valid].any()  # a padding row weighs nothing
 
 
+def test_the_row_bound_widens_only_under_a_router_that_leaves_experts_unread():
+    """32 rows for every router the benchmark had before PR 49 (64 rows of 8
+    picks reach 87 % of 256 experts, of 4 picks 87 % of 128), 64 for 8 picks
+    of 512 (63 %); and `experts_kernel_stack` asks it."""
+    assert [moe_experts.row_bound(n, k) for n, k in
+            ((128, 4), (256, 8), (128, 6), (4, 2), (16, 4))] == [moe_experts.MAX_ROWS] * 5
+    assert moe_experts.row_bound(512, 8) == moe_experts.WIDE_ROWS == 64
+    wide = WHOLE.with_(n_experts=512, n_experts_active=8)
+    layers = {k: jnp.zeros((1, 512, 128, 128), jnp.bfloat16)
+              for k in ("we_gate", "we_up", "we_down")}
+    assert experts_kernel_stack(wide, layers, 64, None, "pallas") is not None
+    assert experts_kernel_stack(wide, layers, 65, None, "pallas") is None
+    assert experts_kernel_stack(WHOLE, layers, 64, None, "pallas") is None
+
+
 @pytest.mark.parametrize("layers", [
     {"we_gate": {"q": jnp.zeros((2, 8, E, F), jnp.int8), "s": jnp.ones((2, 8, 1, F))}},
     {"we_gate": jnp.zeros((2, 8, E, F), jnp.bfloat16)},

@@ -374,6 +374,9 @@ _EXPERT_SHAPES = {
     "cell-t16": (16, 4096, 2048, 32, 32, 4, 6),
     "cell-t32": (32, 4096, 2048, 32, 32, 4, 6),
     "whole8-t8": (8, 4096, 14336, 8, 0, 2, 2),
+    # ling-3.0-flash-vl's decode bucket of 64 rows (moe_experts.row_bound: a
+    # router of 512 with 8 picks still leaves experts unread at 64 rows)
+    "ling3-t64": (64, 2560, 768, 32, 32, 8, 16),
 }
 
 
@@ -712,3 +715,34 @@ def test_a_window_pool_models_ragged_step_reads_both_pools_in_place(topo):
         rf"= bf16\[((2|9),)?({NP}|{NPW}),{PS},(4,(256|128)|8,(256|128)|1024|512|2048)\]\S* "
         r"(copy|reshape|dynamic-slice|slice)\(")
     assert [l.strip()[:160] for l in text.splitlines() if moved.search(l)] == []
+
+
+# -- the delta-rule kernels (ops/kda.py) ---------------------------------------
+
+
+@pytest.mark.parametrize("kernel", ["kda_update", "kda_chunk"])
+def test_delta_rule_kernels_compile_for_v5e_at_ling_widths(topo, kernel):
+    """ling-3.0-flash-vl's KDA layers: 32 heads of 128 x 128 float32, 64
+    decode rows on a pool of 15 layers x 65 slots (read and written in place:
+    one custom call, no copy of the pool); a chunk of 512 tokens, whose walk
+    over 8 blocks of 64 is the one custom call among XLA matmuls."""
+    from dynamo_tpu.ops import kda
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    B, H, d, T = 64, 32, 128, 512
+    if kernel == "kda_update":
+        vec = s((B, H, d))
+        text = jax.jit(kda.kda_update, donate_argnums=(0,)).lower(
+            s((15, 65, H, d, d)), s((), jnp.int32), s((B,), jnp.int32),
+            s((B,), jnp.bool_), s((B,), jnp.bool_), vec, vec, vec, vec,
+            s((B, H))).compile().as_text()
+        assert "f32[15,65,32,128,128]{4,3,2,1,0} copy(" not in text
+    else:
+        tok = s((T, H, d))
+        text = jax.jit(kda.kda_chunk).lower(
+            s((H, d, d)), tok, tok, tok, tok, s((T, H))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1

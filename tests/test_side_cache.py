@@ -191,6 +191,46 @@ def test_every_refusal_is_its_kinds_sentence_and_names_model_and_feature(
     assert side.no_prefix.startswith(f"{words} matches no prefix: ")
 
 
+@pytest.mark.parametrize("is_kda", [False, True])
+def test_state_slots_serve_two_families_and_record_each_ones_kernels(is_kda):
+    """StateSlots under a Mamba model and under a model with KDA layers
+    (models/ling.py): the same slots, sized the same way, the same sentence
+    at the door; an iteration records the family's own counters of what its
+    kernels were given, and the other family's stay 0."""
+    import dataclasses
+    import types
+
+    from dynamo_tpu.runtime.flight_recorder import IterationRecord
+
+    class R(Runner):
+        side_kind = "state"
+        config = types.SimpleNamespace(is_kda=is_kda)
+
+        def ensure_side_cache(self, units):
+            self.side_units = units
+            return units
+
+    r = R()
+    side = side_cache.for_runner(r, max_batch=4, chunk_size=8, decode_steps=2,
+                                 mixed_prefill_tokens=8, mixed_prefill_seqs=1)
+    assert type(side) is StateSlots and side.units == r.side_units == 5
+    assert side.kda is is_kda
+    assert side.refusal("m", "X") == refusal("state", "m", "X")
+    names = {f.name for f in dataclasses.fields(IterationRecord)}
+    kda = ("kda_update_rows", "kda_chunk_tokens", "kda_chunk_segments")
+    ssm = ("ssm_scan_tokens", "ssm_scan_segments")
+    assert set(kda + ssm) <= names
+    rec = types.SimpleNamespace(**{n: 0 for n in kda + ssm})
+    side.record(rec, {"decode_seqs": 3, "decode_steps": 2, "n_chunks": 1,
+                      "chunk_tokens": 9, "ragged": False}, [])
+    assert (rec.state_slots_used, rec.state_slots_total) == (0, 4)
+    assert tuple(getattr(rec, n) for n in kda) == ((6, 9, 1) if is_kda else (0, 0, 0))
+    assert tuple(getattr(rec, n) for n in ssm) == ((0, 0) if is_kda else (9, 1))
+    # a runner with no ModelConfig at all (a cost model's) serves Mamba's
+    R.config = None
+    assert side_cache.for_runner(R(), max_batch=2).kda is False
+
+
 def test_a_runner_that_says_nothing_has_no_side_cache():
     r = Runner()
     assert (r.side_kind, r.side_units, r.side_unit_bytes) == (None, 0, 0)
