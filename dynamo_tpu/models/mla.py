@@ -400,7 +400,8 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
     def query():  # [B, S, H, the pool's width], built where a path wants it
         return _to_pool_width(jnp.concatenate([q_abs, q_r], axis=-1), k_pool)
 
-    lat_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
+    # the Pallas paths read the stacked pool in place, at l_idx; the jnp
+    # gathers take the layer's slab
     if quantized:
         # int8 latent pages. Decode can ride the Pallas kernel (scales
         # fold into scores/values per token) — opt-in via
@@ -422,9 +423,10 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
 
             qd = query()[:, 0]
             attn_lat = decode_mla_attention(
-                qd, lat_pool_l, page_table, kv_lens, dc=dc, scale=scale,
+                qd, k_pool, page_table, kv_lens, l_idx, dc=dc, scale=scale,
             )[:, None]
         else:
+            lat_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
             qg = query()[:, :, None, :, :]
             v_view = {"q": lat_pool_l["q"][..., :dc], "s": lat_pool_l["s"]}
             attn_lat = paged_attention_jnp(
@@ -443,12 +445,12 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
         qp = query()  # [B, S, H, Dl]
         if tp:
             attn_lat = prefill_mla_attention_sharded(
-                qp, lat_pool_l, page_table, q_start, q_len, kv_lens,
-                mesh, dc=dc, scale=scale,
+                qp, k_pool, page_table, q_start, q_len, kv_lens,
+                mesh, layer=l_idx, dc=dc, scale=scale,
             )
         else:
             attn_lat = prefill_mla_attention(
-                qp, lat_pool_l, page_table, q_start, q_len, kv_lens,
+                qp, k_pool, page_table, q_start, q_len, kv_lens, l_idx,
                 dc=dc, scale=scale,
             )
     elif attn_impl == "pallas" and S == 1:
@@ -462,13 +464,15 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
         qd = query()[:, 0]  # [B, H, Dl]
         if tp:
             attn_lat = decode_mla_attention_sharded(
-                qd, lat_pool_l, page_table, kv_lens, mesh, dc=dc, scale=scale,
+                qd, k_pool, page_table, kv_lens, mesh, layer=l_idx,
+                dc=dc, scale=scale,
             )[:, None]
         else:
             attn_lat = decode_mla_attention(
-                qd, lat_pool_l, page_table, kv_lens, dc=dc, scale=scale,
+                qd, k_pool, page_table, kv_lens, l_idx, dc=dc, scale=scale,
             )[:, None]  # [B, 1, H, d_c]
     else:
+        lat_pool_l = k_pool[l_idx]
         qg = query()[:, :, None, :, :]
         attn_lat = paged_attention_jnp(
             qg, lat_pool_l, lat_pool_l[..., :dc], page_table, safe_pos,

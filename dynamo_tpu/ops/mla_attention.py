@@ -13,6 +13,24 @@ index innermost, scalar-prefetched page table driving BlockSpec index
 maps with past-the-end pages clamped (repeat block index → Pallas elides
 the copy), online-softmax state in VMEM scratch.
 
+The pool operand is the latent pool as the layer scan carries it,
+[L, NP, PS, 1, Dl] (int8: the dict of "q" [L, NP, PS, 1, Dl] and "s"
+[L, NP, PS, 1]), read in place at `layer`, a traced int32 scalar that
+rides in scalar prefetch behind the page table and the lengths: the page
+block's index map returns (layer, page, 0, 0) over the free reshape
+[L, NP, PS, Dl], so no layer's slab is copied out in front of the call
+(ops/paged_attention.py `stacked_pools`, whose convention this is: one
+layer's [NP, PS, 1, Dl] is the one-layer stack read at layer 0 and takes
+no `layer`). The int8 pool's scales alone are sliced per layer outside
+the call (`split_scales`: 1/Dl of the data; handed the whole scale stack,
+the program compiled for a v5e lays it out anew inside the layer loop).
+A slab of a few tens of MB was not a plain cost: XLA wrote it to on-chip
+memory and the one-page-a-step DMAs came back faster from there than
+they do from HBM (mistral-small-4-119b at 768 pages, 83 -> 117 us a
+call, which is what its copy cost; at ling-3.0-flash-vl's 4096 pages the
+slab was 335 MB in HBM, 3 ms of a 10 ms decode step: PERF.md section 6,
+PR 51). Several pages a grid step is the cure for the first, not a slab.
+
 Tiling note: the latent dim for DeepSeek-V3 is 576 = 4.5 x 128 lanes;
 Pallas pads the last tile. Splitting the score matmul into an aligned
 512-wide latent part and a 64-wide rope part would avoid the padding —
@@ -29,6 +47,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.paged_attention import split_scales, stacked_pools
 from dynamo_tpu.parallel.mesh import AXIS_MODEL, SPEC_MLA_LATENT_POOL
 
 NEG_INF = -1e30
@@ -37,6 +56,7 @@ NEG_INF = -1e30
 def _mla_kernel_body(
     page_table_ref,  # [B, MP] int32 (SMEM, scalar-prefetched)
     kv_lens_ref,  # [B] int32 (SMEM)
+    layer_ref,  # [1] int32 (SMEM): the index maps' alone
     q_ref,  # [H, Dl] absorbed+rope query for seq b
     lat_ref,  # [PS, Dl] one latent page (single contiguous DMA)
     ls_ref,  # [1, PS] f32 per-token latent scales (int8 pool) or None
@@ -100,20 +120,36 @@ def _mla_kernel_body(
         o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _mla_kernel(pt, kl, q, lat, o, m, l, acc, **kw):
-    _mla_kernel_body(pt, kl, q, lat, None, o, m, l, acc, **kw)
+def _mla_kernel(pt, kl, ly, q, lat, o, m, l, acc, **kw):
+    _mla_kernel_body(pt, kl, ly, q, lat, None, o, m, l, acc, **kw)
 
 
-def _mla_kernel_int8(pt, kl, q, lat, ls, o, m, l, acc, **kw):
-    _mla_kernel_body(pt, kl, q, lat, ls, o, m, l, acc, **kw)
+def _mla_kernel_int8(pt, kl, ly, q, lat, ls, o, m, l, acc, **kw):
+    _mla_kernel_body(pt, kl, ly, q, lat, ls, o, m, l, acc, **kw)
+
+
+def _latent_operands(lat_pool, layer):
+    """(latents [L, NP, PS, Dl], this layer's scales [NP, 1, PS] or None,
+    layer[1]) as the pallas_calls take them, by `stacked_pools`' rule."""
+    lat_pool, _, layer = stacked_pools(lat_pool, None, layer)
+    lq, _, ls, _ = split_scales(lat_pool, lat_pool, layer)
+    L, NP, PS, _, Dl = lq.shape
+    if ls is not None:
+        # [NP, 1, PS]: a rank-1 (PS,) block is not a legal TPU tile; a
+        # (1, PS) block whose dims equal the array's is
+        ls = ls.reshape(NP, 1, PS)
+    return lq.reshape(L, NP, PS, Dl), ls, layer
 
 
 @functools.partial(jax.jit, static_argnames=("dc", "scale", "interpret"))
 def decode_mla_attention(
     q: jax.Array,  # [B, H, Dl] absorbed+rope queries
-    lat_pool_l: jax.Array,  # [NP, PS, 1, Dl] one layer's latent pool
+    lat_pool: jax.Array,  # [L, NP, PS, 1, Dl] the stacked latent pool (or
+    #   one layer's [NP, PS, 1, Dl]: see stacked_pools)
     page_table: jax.Array,  # [B, MP] int32
     kv_lens: jax.Array,  # [B] int32 (context incl. current token)
+    layer=None,  # traced int32 scalar: the layer of the stacked pool to
+    #   read; rides the scan as a prefetch operand
     *,
     dc: int,  # latent (value) width = kv_lora_rank
     scale: float,  # score scale ((d_nope + d_rh)^-0.5 [* yarn mscale^2])
@@ -121,39 +157,36 @@ def decode_mla_attention(
 ) -> jax.Array:
     """Returns the attended latents [B, H, dc] (the caller lifts them
     through W_UV). The current token's latent must already be written.
-    `lat_pool_l` may be the int8 dict ({"q": [NP,PS,1,Dl] int8, "s":
-    [NP,PS,1] f32}) — scales fold into scores/values per token."""
-    quantized = isinstance(lat_pool_l, dict)
-    lq = lat_pool_l["q"] if quantized else lat_pool_l
+    `lat_pool` may be the int8 dict ({"q": [L,NP,PS,1,Dl] int8, "s":
+    [L,NP,PS,1] f32}) — scales fold into scores/values per token."""
     B, H, Dl = q.shape
-    NP, PS, _, _ = lq.shape
+    lat, ls, layer = _latent_operands(lat_pool, layer)
+    PS = lat.shape[2]
     MP = page_table.shape[1]
-    lat = lq.reshape(NP, PS, Dl)
 
-    def lat_index(b, i, pt, kl):
+    def page_index(b, i, pt, kl, ly):  # the layer's scales: [NP, 1, PS]
         last = jnp.maximum(kl[b] - 1, 0) // PS
         return (pt[b, jnp.minimum(i, last)], 0, 0)
 
-    def scale_index(b, i, pt, kl):
-        return lat_index(b, i, pt, kl)
+    def lat_index(b, i, pt, kl, ly):
+        return (ly[0],) + page_index(b, i, pt, kl, ly)
 
     in_specs = [
-        pl.BlockSpec((None, H, Dl), lambda b, i, pt, kl: (b, 0, 0)),
-        pl.BlockSpec((None, PS, Dl), lat_index),
+        pl.BlockSpec((None, H, Dl), lambda b, i, *_: (b, 0, 0)),
+        pl.BlockSpec((None, None, PS, Dl), lat_index),
     ]
     operands = (q, lat)
     kernel = _mla_kernel
-    if quantized:
-        # [NP, 1, PS]: a rank-1 (PS,) block is not a legal TPU tile; a
-        # (1, PS) block whose dims equal the array's is
-        in_specs.append(pl.BlockSpec((None, 1, PS), scale_index))
-        operands = operands + (lat_pool_l["s"].reshape(NP, 1, PS),)
+    if ls is not None:
+        in_specs.append(pl.BlockSpec((None, 1, PS), page_index))
+        operands = operands + (ls,)
         kernel = _mla_kernel_int8
+    prefetch = (page_table, kv_lens, layer)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, MP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, H, dc), lambda b, i, pt, kl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, H, dc), lambda b, i, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -165,7 +198,7 @@ def decode_mla_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dc), q.dtype),
         interpret=interpret,
-    )(page_table, kv_lens, *operands)
+    )(*prefetch, *operands)
 
 
 def _mla_prefill_kernel(
@@ -173,6 +206,7 @@ def _mla_prefill_kernel(
     q_start_ref,  # [B] int32
     q_len_ref,  # [B] int32
     kv_lens_ref,  # [B] int32
+    layer_ref,  # [1] int32: the index maps' alone
     q_ref,  # [Sq, H, Dl] one query block
     lat_ref,  # [PS, Dl] one latent page
     o_ref,  # [Sq, H, dc]
@@ -243,11 +277,13 @@ def _mla_prefill_kernel(
 @functools.partial(jax.jit, static_argnames=("dc", "scale", "q_block", "interpret"))
 def prefill_mla_attention(
     q: jax.Array,  # [B, S, H, Dl] absorbed+rope queries (chunk)
-    lat_pool_l: jax.Array,  # [NP, PS, 1, Dl]
+    lat_pool: jax.Array,  # [L, NP, PS, 1, Dl] the stacked latent pool (or
+    #   one layer's [NP, PS, 1, Dl]: see stacked_pools)
     page_table: jax.Array,  # [B, MP]
     q_start: jax.Array,  # [B] absolute position of query token 0
     q_len: jax.Array,  # [B] valid query tokens
     kv_lens: jax.Array,  # [B] context incl. this chunk
+    layer=None,  # traced int32 scalar: the layer of the stacked pool to read
     *,
     dc: int,
     scale: float,
@@ -260,9 +296,11 @@ def prefill_mla_attention(
     latents [B, S, H, dc]; padding rows return 0. Same positions
     contract as ops/flash_prefill.py."""
     B, S, H, Dl = q.shape
-    NP, PS, _, _ = lat_pool_l.shape
+    if isinstance(lat_pool, dict):
+        raise NotImplementedError("the flash MLA prefill over an int8 pool")
+    lat, _, layer = _latent_operands(lat_pool, layer)
+    PS = lat.shape[2]
     MP = page_table.shape[1]
-    lat = lat_pool_l.reshape(NP, PS, Dl)
     # VMEM budget: the f32 acc scratch is q_block*H x dc — at flagship MLA
     # dims (H=128, dc=512) a 128-row block would need ~34MiB of scratch
     # alone. Cap the block so acc stays ~<=4MiB; tiny test dims keep the
@@ -286,24 +324,24 @@ def prefill_mla_attention(
         q_block -= 1
     n_sblk = S // q_block
 
-    def lat_index(b, sb, i, pt, qs, ql, kl):
+    def lat_index(b, sb, i, pt, qs, ql, kl, ly):
         rows = jnp.minimum(ql[b] - sb * q_block, q_block)
         blk_max_pos = qs[b] + sb * q_block + jnp.maximum(rows, 1) - 1
         last = jnp.minimum(blk_max_pos, jnp.maximum(kl[b] - 1, 0)) // PS
         last = jnp.clip(last, 0, MP - 1)
-        return (pt[b, jnp.minimum(i, last)], 0, 0)
+        return (ly[0], pt[b, jnp.minimum(i, last)], 0, 0)
 
+    prefetch = (page_table, q_start, q_len, kv_lens, layer)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, n_sblk, MP),
         in_specs=[
             pl.BlockSpec((None, q_block, H, Dl),
-                         lambda b, sb, i, pt, qs, ql, kl: (b, sb, 0, 0)),
-            pl.BlockSpec((None, PS, Dl), lat_index),
+                         lambda b, sb, i, *_: (b, sb, 0, 0)),
+            pl.BlockSpec((None, None, PS, Dl), lat_index),
         ],
         out_specs=pl.BlockSpec(
-            (None, q_block, H, dc),
-            lambda b, sb, i, pt, qs, ql, kl: (b, sb, 0, 0),
+            (None, q_block, H, dc), lambda b, sb, i, *_: (b, sb, 0, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((q_block * H, 1), jnp.float32),
@@ -319,18 +357,19 @@ def prefill_mla_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, dc), q.dtype),
         interpret=interpret,
-    )(page_table, q_start, q_len, kv_lens, q, lat)
+    )(*prefetch, q, lat)
 
 
 def prefill_mla_attention_sharded(
     q: jax.Array,  # [B, S, H, Dl] heads sharded over `axis_name`
-    lat_pool_l: jax.Array,  # [NP, PS, 1, Dl] REPLICATED (Hk=1)
+    lat_pool: jax.Array,  # [L, NP, PS, 1, Dl] REPLICATED (Hk=1)
     page_table: jax.Array,
     q_start: jax.Array,
     q_len: jax.Array,
     kv_lens: jax.Array,
     mesh,
     axis_name: str = AXIS_MODEL,
+    layer=None,  # traced int32 scalar, replicated
     *,
     dc: int,
     scale: float,
@@ -344,27 +383,29 @@ def prefill_mla_attention_sharded(
     TP meshes no longer fall back to the jnp gather)."""
     from jax.sharding import PartitionSpec as P
 
+    lat_pool, _, layer = stacked_pools(lat_pool, None, layer)
     fn = jax.shard_map(
         functools.partial(
             prefill_mla_attention, dc=dc, scale=scale, interpret=interpret
         ),
         mesh=mesh,
         in_specs=(P(None, None, axis_name, None), SPEC_MLA_LATENT_POOL,
-                  P(None, None), P(None), P(None), P(None)),
+                  P(None, None), P(None), P(None), P(None), P()),
         out_specs=P(None, None, axis_name, None),
         check_vma=False,
     )
-    return fn(q, lat_pool_l, page_table, q_start, q_len, kv_lens)
+    return fn(q, lat_pool, page_table, q_start, q_len, kv_lens, layer)
 
 
 def decode_mla_attention_sharded(
     q: jax.Array,  # [B, H, Dl] heads sharded over `axis_name`
-    lat_pool_l: jax.Array,  # [NP, PS, 1, Dl] REPLICATED (Hk=1 — no head
+    lat_pool: jax.Array,  # [L, NP, PS, 1, Dl] REPLICATED (Hk=1 — no head
     #   axis to shard; the latent pool is small by design)
     page_table: jax.Array,
     kv_lens: jax.Array,
     mesh,
     axis_name: str = AXIS_MODEL,
+    layer=None,  # traced int32 scalar, replicated
     *,
     dc: int,
     scale: float,
@@ -376,14 +417,15 @@ def decode_mla_attention_sharded(
     out-projection as usual)."""
     from jax.sharding import PartitionSpec as P
 
+    lat_pool, _, layer = stacked_pools(lat_pool, None, layer)
     fn = jax.shard_map(
         functools.partial(
             decode_mla_attention, dc=dc, scale=scale, interpret=interpret
         ),
         mesh=mesh,
         in_specs=(P(None, axis_name, None), SPEC_MLA_LATENT_POOL,
-                  P(None, None), P(None)),
+                  P(None, None), P(None), P()),
         out_specs=P(None, axis_name, None),
         check_vma=False,
     )
-    return fn(q, lat_pool_l, page_table, kv_lens)
+    return fn(q, lat_pool, page_table, kv_lens, layer)

@@ -58,10 +58,10 @@ SPEC_KV_PAGES = P(None, None, AXIS_MODEL, None)
 # (the attention wrappers' operands; ops/block_copy.py exports)
 SPEC_KV_POOL = P(None, None, None, AXIS_MODEL, None)
 SPEC_KV_POOL_SCALES = P(None, None, None, AXIS_MODEL)
-# MLA latent pool [NP, PS, 1, Dl]: Hk == 1 by construction (the cache is
+# MLA latent pool [L, NP, PS, 1, Dl]: Hk == 1 by construction (the cache is
 # per-token latent, not per-head), so it CANNOT shard kv-heads and is
 # small enough to replicate — deliberately, hence a named declaration
-SPEC_MLA_LATENT_POOL = P(None, None, None, None)
+SPEC_MLA_LATENT_POOL = P(None, None, None, None, None)
 
 # MoE dispatch (ops/moe_dispatch.py): tokens [T, E] over `expert`,
 # expert weights [n_exp, E, F] EP-sharded (+F on `model` for EP x TP)

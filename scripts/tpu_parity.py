@@ -35,7 +35,9 @@ mask. `--time-dsa` prints the device time of the selecting arm's parts instead.
 
 Also run: Gemma geometry (G 2, PS 16) decode/prefill softcap+window on one
 layer's pool [NP, PS, Hk, D] (the kernels' rank-4 view), MLA
-decode/prefill, MLA int8-latent decode (gates DYN_MLA_INT8_KERNEL), and
+decode/prefill, MLA int8-latent decode (gates DYN_MLA_INT8_KERNEL; all
+three since PR 51 on the latent pool stacked [3, NP, PS, 1, Dl] at layer 2,
+and at each layer held bit for bit to the same call on that layer's slab), and
 the batched page copy/permute/scatter roundtrip (gates DYN_KV_COPY_KERNEL).
 
 Every check runs even after a failure; an exception (a compiler refusal)
@@ -290,6 +292,19 @@ def check_ragged_sparse(geom, variant, mesh=None) -> float:
                        _SPARSE_SEGS, 288, mesh)
 
 
+def _stacked_err(kernel, pool, ref) -> float:
+    """A latent kernel on the pool as the layer scan carries it, read at
+    layer LAYER, against the float32 reference on that layer's slab; inf
+    unless, at every layer of the stack, the per-layer operand
+    (DeepSeek-V3.2's gathered buffer has that form) gives the same bits."""
+    for layer in range(LAYER + 1):
+        out = kernel(pool, jnp.int32(layer))
+        slab = kernel(jax.tree.map(lambda a: a[layer], pool), None)
+        if not np.array_equal(np.asarray(out, np.float32), np.asarray(slab, np.float32)):
+            return float("inf")
+    return _max_err(out, ref)
+
+
 def check_mla() -> float:
     from dynamo_tpu.ops.mla_attention import decode_mla_attention
 
@@ -301,15 +316,16 @@ def check_mla() -> float:
     pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP).astype(np.int32))
     kv = jnp.asarray(rng.integers(1, MP * PS, B).astype(np.int32))
     scale = (128 + dr) ** -0.5
-    out = decode_mla_attention(q, lat, pt, kv, dc=dc, scale=scale,
-                               interpret=INTERPRET)
     qg = q[:, None, None, :, :].transpose(0, 2, 1, 3, 4)
     ref = paged_attention_jnp(
         qg.astype(jnp.float32), lat.astype(jnp.float32),
         lat[..., :dc].astype(jnp.float32), pt, (kv - 1)[:, None], kv,
         scale=scale,
     )[:, 0, 0]
-    return _max_err(out, ref)
+    return _stacked_err(
+        lambda pool, layer: decode_mla_attention(
+            q, pool, pt, kv, layer, dc=dc, scale=scale, interpret=INTERPRET),
+        _Pool._stack(lat), ref)
 
 
 def check_mla_prefill() -> float:
@@ -325,10 +341,6 @@ def check_mla_prefill() -> float:
     ql = np.asarray([128, 128], np.int32)
     kv = jnp.asarray(qs + ql)
     scale = (128 + dr) ** -0.5
-    out = prefill_mla_attention(
-        q, lat, pt, jnp.asarray(qs), jnp.asarray(ql), kv, dc=dc, scale=scale,
-        interpret=INTERPRET,
-    )
     pos = np.zeros((B, S), np.int32)
     for b in range(B):
         pos[b] = np.arange(qs[b], qs[b] + S)
@@ -337,7 +349,11 @@ def check_mla_prefill() -> float:
         lat[..., :dc].astype(jnp.float32), pt, jnp.asarray(pos), kv,
         scale=scale,
     )[:, :, 0]
-    return _max_err(out, ref)
+    return _stacked_err(
+        lambda pool, layer: prefill_mla_attention(
+            q, pool, pt, jnp.asarray(qs), jnp.asarray(ql), kv, layer, dc=dc,
+            scale=scale, interpret=INTERPRET),
+        _Pool._stack(lat), ref)
 
 
 def check_mla_int8() -> float:
@@ -351,18 +367,20 @@ def check_mla_int8() -> float:
     Dl = dc + dr
     q = jnp.asarray(rng.standard_normal((B, H, Dl)), jnp.bfloat16)
     lat_dense = jnp.asarray(rng.standard_normal((NP, PS, 1, Dl)), jnp.bfloat16)
-    lat_q = kv_pool_quantize(lat_dense)
+    stack = kv_pool_quantize(_Pool._stack(lat_dense))
+    lat_q = jax.tree.map(lambda a: a[LAYER], stack)
     pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP).astype(np.int32))
     kv = jnp.asarray(rng.integers(1, MP * PS, B).astype(np.int32))
     scale = (128 + dr) ** -0.5
-    out = decode_mla_attention(q, lat_q, pt, kv, dc=dc, scale=scale,
-                               interpret=INTERPRET)
     v_view = {"q": lat_q["q"][..., :dc], "s": lat_q["s"]}
     ref = paged_attention_jnp(
         q.astype(jnp.float32)[:, None, None], lat_q, v_view, pt,
         (kv - 1)[:, None], kv, scale=scale,
     )[:, 0, 0]
-    return _max_err(out, ref)
+    return _stacked_err(
+        lambda pool, layer: decode_mla_attention(
+            q, pool, pt, kv, layer, dc=dc, scale=scale, interpret=INTERPRET),
+        stack, ref)
 
 
 def check_gemma_decode() -> float:
@@ -725,7 +743,8 @@ def time_dsa() -> None:
             row["kernel_on_selected_ms"] = med(
                 lambda *a: decode_mla_attention(*a, dc=dc, scale=0.1352), q, sel, own, n)
             row["kernel_on_whole_context_ms"] = med(
-                lambda *a: decode_mla_attention(*a, dc=dc, scale=0.1352), q, kp[1], pt, kv)
+                lambda *a: decode_mla_attention(*a, dc=dc, scale=0.1352),
+                q, kp, pt, kv, jnp.int32(1))
         row["layer_ms"] = med(lambda *a: _dsa_run("pallas", c, *a), lp, h, kp, ip, pt, pos, kv)
         print(json.dumps(row), flush=True)
 

@@ -326,33 +326,136 @@ async def _generate_async(runner, prompt, n=5):
         engine.stop()
 
 
-def test_decode_mla_attention_int8_matches_jnp():
-    """int8 MLA decode kernel (per-token scale folds into scores AND
-    values) vs the jnp dict-pool path on the same quantized pool."""
-    import jax.numpy as jnp
+# -- the latent kernels on the layer-stacked pool (ops/mla_attention.py) ------
+# The pool operand is the pool as the layer scan carries it,
+# [L, NP, PS, 1, Dl], read at a traced `layer`; one layer's [NP, PS, 1, Dl]
+# (DeepSeek-V3.2's gathered decode buffer has that form) is the one-layer
+# stack and takes no layer. `layer` None below is that per-layer case.
+_LAYERS = [None, 0, 1, 2]
 
-    from dynamo_tpu.models.quant import kv_pool_quantize
+
+def _latent_case(kernel, pool, dtype=jnp.bfloat16, L=3):
+    """(call(pool, layer=None, mesh=None) -> float32 numpy, ref(pool_l), the
+    stacked pool): one kernel of ops/mla_attention.py, interpreted, on fixed
+    queries and page tables, and `paged_attention_jnp` in float32 on one
+    layer's pool. `pool`: "dense" or "int8" (the dict of "q" and "s")."""
     from dynamo_tpu.models.toolkit import paged_attention_jnp
-    from dynamo_tpu.ops.mla_attention import decode_mla_attention
+    from dynamo_tpu.ops import mla_attention as ops
 
     rng = np.random.default_rng(9)
-    B, H, dc, dr, NP, PS, MP = 3, 4, 32, 16, 16, 4, 4
+    H, dc, dr, NP, PS, MP, scale = 4, 32, 16, 24, 4, 5, 0.13
     Dl = dc + dr
-    q = jnp.asarray(rng.standard_normal((B, H, Dl)), jnp.float32)
-    lat_dense = jnp.asarray(rng.standard_normal((NP, PS, 1, Dl)), jnp.float32)
-    lat_q = kv_pool_quantize(lat_dense)
+    stack = jnp.asarray(rng.standard_normal((L, NP, PS, 1, Dl)), dtype)
+    if pool == "int8":
+        from dynamo_tpu.models.quant import kv_pool_quantize
+
+        stack = kv_pool_quantize(stack)
+    if kernel == "decode":
+        B = 3
+        q = jnp.asarray(rng.standard_normal((B, H, Dl)), dtype)
+        kv = jnp.asarray([3, 9, 20], jnp.int32)
+        rows, pos, qg = (kv,), (kv - 1)[:, None], q[:, None, None]
+    else:
+        B, S = 2, 8
+        q = jnp.asarray(rng.standard_normal((B, S, H, Dl)), dtype)
+        qs, ql = jnp.asarray([4, 0], jnp.int32), jnp.asarray([8, 5], jnp.int32)
+        kv = qs + ql
+        rows, qg = (qs, ql, kv), q[:, :, None]
+        pos = jnp.where(jnp.arange(S)[None] < ql[:, None],
+                        qs[:, None] + jnp.arange(S)[None], -1)
     pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP).astype(np.int32))
-    kv = jnp.asarray([3, 9, 14], jnp.int32)
-    out = decode_mla_attention(
-        q, lat_q, pt, kv, dc=dc, scale=0.13, interpret=True
-    )
-    v_view = {"q": lat_q["q"][..., :dc], "s": lat_q["s"]}
-    ref = paged_attention_jnp(
-        q[:, None, None], lat_q, v_view, pt, (kv - 1)[:, None], kv,
-        scale=0.13,
-    )[:, 0, 0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+
+    def call(pool, layer=None, mesh=None):
+        kw = dict(layer=layer, dc=dc, scale=scale, interpret=True)
+        if mesh is not None:
+            fn = getattr(ops, f"{kernel}_mla_attention_sharded")
+            return np.asarray(fn(q, pool, pt, *rows, mesh, **kw), np.float32)
+        fn = getattr(ops, f"{kernel}_mla_attention")
+        return np.asarray(fn(q, pool, pt, *rows, **kw), np.float32)
+
+    def ref(pool_l):
+        if isinstance(pool_l, dict):
+            v_view = {"q": pool_l["q"][..., :dc], "s": pool_l["s"]}
+        else:
+            pool_l = pool_l.astype(jnp.float32)
+            v_view = pool_l[..., :dc]
+        out = paged_attention_jnp(qg.astype(jnp.float32), pool_l, v_view, pt,
+                                  pos, kv, scale=scale)
+        out = np.asarray(out[:, 0, 0] if kernel == "decode" else out[:, :, 0])
+        if kernel == "prefill":  # padding rows return 0
+            out = np.where(np.asarray(pos)[:, :, None, None] >= 0, out, 0.0)
+        return out
+
+    return call, ref, stack
+
+
+def _layer_of(stack, layer):
+    """(the operand, the layer to pass, that layer's pool)."""
+    pool_l = jax.tree.map(lambda a: a[layer or 0], stack)
+    if layer is None:
+        return pool_l, None, pool_l
+    return stack, jnp.int32(layer), pool_l
+
+
+@pytest.mark.parametrize("layer", _LAYERS)
+def test_decode_mla_attention_int8_matches_jnp(layer):
+    """int8 MLA decode kernel (per-token scale folds into scores AND
+    values) vs the jnp dict-pool path on the same quantized pool: the
+    layer's pool itself, and the stacked dict read at each of its layers."""
+    call, ref, stack = _latent_case("decode", "int8", jnp.float32)
+    operand, at, pool_l = _layer_of(stack, layer)
+    np.testing.assert_allclose(call(operand, at), ref(pool_l),
                                rtol=2e-5, atol=2e-5)
+
+
+_LATENT_KERNELS = [("decode", "dense"), ("decode", "int8"), ("prefill", "dense")]
+
+
+@pytest.mark.parametrize("layer", _LAYERS)
+@pytest.mark.parametrize("kernel, pool", _LATENT_KERNELS)
+def test_latent_kernels_read_the_stacked_pool_at_a_layer(kernel, pool, layer):
+    """bf16 queries and pool (and the int8 dict): the stacked pool read at
+    layer 0, a middle layer and L - 1 against `paged_attention_jnp` on
+    `pool[l]`, and bit for bit against the per-layer call on `pool[l]`,
+    since the operand alone differs; another layer's pool gives another
+    answer, so the layer is what was read."""
+    call, ref, stack = _latent_case(kernel, pool)
+    operand, at, pool_l = _layer_of(stack, layer)
+    got = call(operand, at)
+    assert np.abs(got).max() > 0
+    np.testing.assert_allclose(got, ref(pool_l), rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(got, call(pool_l))
+    other = jax.tree.map(lambda a: a[((layer or 0) + 1) % 3], stack)
+    assert not np.array_equal(got, call(other))
+
+
+@pytest.mark.parametrize("kernel, pool", _LATENT_KERNELS)
+def test_latent_kernels_refuse_a_pool_whose_rank_and_layer_disagree(kernel, pool):
+    call, _, stack = _latent_case(kernel, pool)
+    with pytest.raises(ValueError, match="takes no layer"):
+        call(jax.tree.map(lambda a: a[0], stack), jnp.int32(0))
+    with pytest.raises(ValueError, match="needs its layer"):
+        call(stack)
+
+
+def test_the_flash_latent_prefill_refuses_an_int8_pool():
+    call, _, stack = _latent_case("prefill", "int8")
+    with pytest.raises(NotImplementedError, match="int8"):
+        call(stack, jnp.int32(1))
+
+
+@pytest.mark.parametrize("layer", _LAYERS)
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_sharded_latent_kernels_read_the_stacked_pool_at_a_layer(kernel, layer):
+    """The tensor-parallel wrappers: the pool replicated with its layer
+    axis, `layer` through shard_map behind the lengths, a shard's heads
+    against it."""
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    call, _, stack = _latent_case(kernel, "dense")
+    operand, at, pool_l = _layer_of(stack, layer)
+    mesh = make_mesh(MeshConfig(model=2))
+    np.testing.assert_array_equal(call(operand, at, mesh), call(pool_l))
 
 
 def test_mla_int8_kernel_full_layer_matches_jnp(monkeypatch):

@@ -717,6 +717,132 @@ def test_a_window_pool_models_ragged_step_reads_both_pools_in_place(topo):
     assert [l.strip()[:160] for l in text.splitlines() if moved.search(l)] == []
 
 
+# -- the latent pool read in place (ops/mla_attention.py) ---------------------
+# name -> (benchmark configuration, layers kept (None: all), program)
+_LATENT_PROGRAMS = {
+    # ling-3.0-flash-vl whole: 3 pool layers, rank 512 + 64 in 640 lanes, the
+    # cell's 4096 pages; 16 decode rows, and its 512-token prefill chunk
+    "ling3-decode": ("ling-3.0-flash-vl", None, "decode"),
+    "ling3-chunk512": ("ling-3.0-flash-vl", None, "chunk"),
+    # mistral-small-4-119b, two of its layers: rank 256 + 64, 768 pages
+    "mistral4-decode": ("mistral-small-4-119b", 2, "decode"),
+    "mistral4-chunk512": ("mistral-small-4-119b", 2, "chunk"),
+}
+
+
+@pytest.mark.parametrize("name", list(_LATENT_PROGRAMS))
+def test_a_latent_models_step_programs_read_the_latent_pool_in_place(topo, name, monkeypatch):
+    """The decode loop (4 fused steps) and the 512-token prefill chunk of a
+    model with latent attention, compiled for the described v5e: the latent
+    kernel takes the pool as the layer scan carries it, at a scalar-prefetched
+    layer, so no instruction's result is one layer's slab `bf16[NP,PS,(1,)Dl]`
+    (a `dynamic-slice_bitcast_fusion` of 335 MB in front of every latent layer
+    of ling-3.0-flash-vl's step until PR 51), and none inside the loops is the
+    whole pool. (At 320 lanes the pool is laid out anew at the entry and the
+    exit of a step program, before and after this PR: ModelConfig.mla_pool_dim
+    says why; a pool of whole 128-lane rows is not.) The same decode loop with
+    `k_pool[l_idx]` handed to the kernel does have the slab, which is how the
+    check can see one."""
+    import json
+    import os
+    import re
+    from functools import partial
+
+    from dynamo_tpu.engine.model_runner import _decode_loop, _forward, _side_forward
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.models import ling, llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops import mla_attention as mla_ops
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    config, layers, program = _LATENT_PROGRAMS[name]
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    c = ModelConfig(**cfg["model"])
+    if layers:
+        c = c.with_(n_layers=layers)
+    flags = cfg["server_flags"]
+    NP, PS = flags["num-pages"], flags["page-size"]
+    MP, f32, i32 = flags["max-seq-len"] // PS, jnp.float32, jnp.int32
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(lambda: llama.make_kv_pool(c, NP, PS, dtype=jnp.bfloat16)))
+    L, Dl = c.kv_layers, c.mla_pool_dim
+    assert pools[0].shape == (L, NP, PS, 1, Dl)
+    side = {}
+    if c.is_kda:
+        side = {"state": on_chip(jax.eval_shape(lambda: ling.make_state_pool(c, 65)))}
+    skw = {"donate_argnames": ("state",)} if side else {}
+
+    def compiled():
+        if program == "decode":
+            B = 16
+            samp = SamplingParams(s((B,), f32), s((B,), i32), s((B,), f32),
+                                  s((B, 2), jnp.uint32), s((B,), f32), s((B,), f32), s((B,), f32))
+            slots = {"slots": s((B,), i32)} if side else {}
+            return jax.jit(partial(_decode_loop, c, "pallas", None, 4, -1),
+                           donate_argnums=(6, 7), **skw).lower(
+                params, s((B,), i32), s((B + B * MP + 1,), i32), None, None, None,
+                *pools, samp, **side, **slots).compile().as_text()
+        fwd = (partial(_side_forward, c, chunk_picks=True) if side else partial(_forward, c))
+        slots = {"slots": s((1,), i32)} if side else {}
+        return jax.jit(fwd, donate_argnums=(3, 4), static_argnames=("attn_impl", "mesh"),
+                       **skw).lower(
+            params, s((1, 512), i32), s((1, 512), i32), *pools, s((1, MP), i32),
+            s((1,), i32), s((), i32), attn_impl="pallas", mesh=None,
+            **side, **slots).compile().as_text()
+
+    slab = re.compile(rf"= bf16\[(1,)?{NP},{PS},(1,)?{Dl}\]\S* "
+                      r"(copy|reshape|dynamic-slice|slice|fusion)\(")
+    whole = re.compile(rf"= bf16\[{L},{NP},{PS},(1,)?{Dl}\]\S* "
+                       r"(copy|reshape|dynamic-slice|slice)\(")
+
+    def moved(text):
+        """(a layer's slab anywhere, the whole pool inside a loop or a
+        branch, the whole pool in the entry computation)."""
+        slabs, inner, outer, entry = [], [], [], False
+        for l in text.splitlines():
+            if l.startswith(("ENTRY", "}")):
+                entry = l.startswith("ENTRY")
+            if slab.search(l):
+                slabs.append(l.strip()[:160])
+            elif whole.search(l):
+                (outer if entry else inner).append(l.strip()[:160])
+        return slabs, inner, outer
+
+    text = compiled()
+    kernels = {l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for l in text.splitlines() if "tpu_custom_call" in l and " = " in l}
+    kernel = "decode_mla_attention" if program == "decode" else "prefill_mla_attention"
+    others = {"routed_experts"} if program == "decode" else set()
+    if c.is_kda:
+        others |= {"kda_update"} if program == "decode" else {"kda_chunk"}
+    assert kernels == {kernel} | others
+    slabs, inner, outer = moved(text)
+    assert slabs == [] and inner == []
+    if Dl % 128 == 0:
+        assert outer == []
+
+    if program != "decode":
+        return
+    orig = getattr(mla_ops, kernel)
+
+    def sliced(q, pool, *rest, **kw):  # the operand until PR 51
+        *rows, layer = rest
+        return orig(q, jax.tree.map(lambda a: a[layer], pool), *rows, **kw)
+
+    monkeypatch.setattr(mla_ops, kernel, sliced)
+    assert moved(compiled())[0]
+
+
 # -- the delta-rule kernels (ops/kda.py) ---------------------------------------
 
 
