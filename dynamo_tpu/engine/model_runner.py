@@ -1318,12 +1318,14 @@ class ModelRunner(Runner):
         log.info(
             "runner ready: %s params+pool placed in %.1fs (mesh %s, %d pages "
             "x %d tokens) on %s %r devices %s; attn_impl=%s (%s), "
-            "decode_page_routine=%s, ragged_page_routine=%s, ragged_mixed=%s, "
+            "decode_page_routine=%s, decode_step_pages=%s, "
+            "ragged_page_routine=%s, ragged_mixed=%s, "
             "kv_copy_kernel=%s (interpret=%s)",
             config.name, placed_s, self.mesh_config.shape, num_pages,
             page_size, rep["platform"], rep["device_kind"], rep["device_ids"],
             self.attn_impl, self.attn_impl_reason, rep["decode_page_routine"],
-            rep["ragged_page_routine"], self.ragged_mixed,
+            rep["decode_step_pages"], rep["ragged_page_routine"],
+            self.ragged_mixed,
             self._kv_copy_kernel, self._kv_copy_interpret,
         )
 
@@ -1341,20 +1343,32 @@ class ModelRunner(Runner):
         wrapper and its walk make: one shard's KV heads, the query heads on
         each, the pool's dtype, a sink, values narrower than keys); a name
         a kind, {"global", "window"}, where window layers keep a pool of
-        their own; None where that kernel is not on the path (the jnp
-        gather, latent attention). `ragged_page_routine` is the same of the
-        ragged (mixed-step) kernel, from its own decision
+        their own; None where no Pallas decode kernel is on the path (the
+        jnp gather). Latent attention's kernel walks the same list over its
+        one pool, and `decode_step_pages` is the pages a grid step of it
+        brings under this worker's page table (`decode_step`, the walk's
+        own decision; None for every other model). `ragged_page_routine` is
+        the same of the ragged (mixed-step) kernel, from its own decision
         (ops/ragged_paged_attention.py `ragged_page_routine`); None also
         where the mixed step is not the ragged program."""
-        from dynamo_tpu.ops.paged_attention import page_routine
+        from dynamo_tpu.models.mla import latent_decode_on_kernel
+        from dynamo_tpu.ops.paged_attention import decode_step, page_routine
         from dynamo_tpu.ops.ragged_paged_attention import ragged_page_routine
 
         devs = list(self.mesh.devices.flat)
         k_leaf = jax.tree.leaves(self.k_pool)[0]
         shard_shape = list(k_leaf.sharding.shard_shape(k_leaf.shape))
-        decode_routine = ragged_routine = None
-        if self.attn_impl == "pallas" and not self.config.is_mla:
-            c = self.config
+        decode_routine = ragged_routine = step_pages = None
+        c = self.config
+        if (self.attn_impl == "pallas" and c.is_mla
+                and latent_decode_on_kernel(isinstance(self.k_pool, dict),
+                                            self.mesh_config.model > 1)):
+            # (one KV head, replicated: the heads a shard is left with are
+            # the query's, and the decision does not hang on them)
+            decode_routine, step_pages = decode_step(
+                (1, c.n_heads), self.k_pool, None, self.max_pages_per_seq,
+                False)
+        elif self.attn_impl == "pallas" and not c.is_mla:
             shards = k_leaf.shape[3] // shard_shape[3]
             v_width = jax.tree.leaves(self.v_pool)[0].shape[-1]
             kinds = {"global": (c.n_kv_heads, c.sink_global)}
@@ -1396,6 +1410,7 @@ class ModelRunner(Runner):
             "attn_impl": self.attn_impl,
             "attn_impl_reason": self.attn_impl_reason,
             "decode_page_routine": decode_routine,
+            "decode_step_pages": step_pages,
             "ragged_page_routine": ragged_routine,
             "ragged_mixed": self.ragged_mixed,
             "kv_copy_kernel": self._kv_copy_kernel,

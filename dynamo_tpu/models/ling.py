@@ -326,6 +326,13 @@ def forward(
     q_start = safe_pos[:, 0]
     q_len = jnp.sum(real_rows.astype(jnp.int32), axis=1)
     moe_stack = experts_kernel_stack(c, params["layers"], T, mesh, attn_impl)
+    # the latent decode kernel's list of live pages hangs on the lengths
+    # alone: built here, once a step, for every MLA layer (models/llama.py)
+    pages_walk = None
+    if attn_impl == "pallas" and S == 1:
+        from dynamo_tpu.ops.mla_attention import latent_walk
+
+        pages_walk = latent_walk(H, k_pool, page_table, kv_lens)
 
     def ffn(h, lp, i_ffn, routed: bool):
         with jax.named_scope("ffn"):
@@ -353,7 +360,7 @@ def forward(
                 c, {**mp, "attn_norm": lp["attn_norm"]}, h, k_pool,
                 jnp.asarray(rank, jnp.int32), page_table, positions, safe_pos,
                 kv_lens, attn_impl=attn_impl, q_start=q_start, q_len=q_len,
-                ik_pool=v_pool)
+                ik_pool=v_pool, walk=pages_walk)
             with jax.named_scope("attn.proj"):
                 x = rms_norm(h, lp["attn_norm"], c.norm_eps)
                 gate = jax.nn.sigmoid(mm(x, mp["w_g"]).astype(jnp.float32))

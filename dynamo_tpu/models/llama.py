@@ -365,9 +365,16 @@ def forward(
     # a layer). A model whose layers alternate sliding and global gets
     # both lists and each layer picks one.
     walk_sliding = walk_global = None
-    if attn_impl == "pallas" and not c.is_mla and (S == 1 or ragged is not None):
-        # the heads ONE call of the kernel sees: a model-axis shard's
-        shards = mesh.shape.get("model", 1) if mesh is not None else 1
+    # the heads ONE call of the kernel sees: a model-axis shard's
+    shards = mesh.shape.get("model", 1) if mesh is not None else 1
+    if attn_impl == "pallas" and c.is_mla and S == 1:
+        # latent attention's decode kernel walks the same list over its
+        # one pool (its chunk kernel and the ragged program do not yet)
+        from dynamo_tpu.ops.mla_attention import latent_walk
+
+        walk_global = latent_walk(c.n_heads // shards, k_pool, page_table,
+                                  kv_lens)
+    elif attn_impl == "pallas" and not c.is_mla and (S == 1 or ragged is not None):
         heads = (c.n_kv_heads // shards, c.n_heads // c.n_kv_heads)
         if ragged is not None:
             from dynamo_tpu.ops.ragged_paged_attention import ragged_walk
@@ -458,6 +465,7 @@ def forward(
                 c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
                 kv_lens, attn_impl=attn_impl, mesh=mesh,
                 q_start=q_start, q_len=q_len, ik_pool=v_pool,
+                walk=walk_global,
             )
             with jax.named_scope("attn.proj"):
                 h = h + mm(attn, lp["wo"])

@@ -5,6 +5,8 @@ per-token latent cache instead of full-head K/V pools.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -21,7 +23,7 @@ from dynamo_tpu.models.toolkit import (
 
 def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
                    kv_lens, attn_impl="jnp", mesh=None, q_start=None,
-                   q_len=None, ik_pool=None):
+                   q_len=None, ik_pool=None, walk=None):
     """Multi-head latent attention (DeepSeek V2/V3/R1), absorbed form.
 
     Per token the pool caches one [d_c + d_rh] vector: the RMS-normed KV
@@ -47,6 +49,9 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
     `chosen` (None without an indexer) is the set each query attended to,
     int32 [B, S, W] bit words over the page table's width (`pack_chosen`):
     what a check that follows the served selection asks the program for.
+    `walk`: the Pallas decode kernel's list of the step's live pages
+    (ops/mla_attention.py `latent_walk`), which the caller builds once a
+    step above its layer scan; None: the kernel builds its own.
 
     Named scopes, as on the GQA path (models/llama.py): `attn.proj` the
     query and latent projections with their norms, RoPE and the cache
@@ -110,7 +115,7 @@ def _mla_attention(c, lp, h, k_pool, l_idx, page_table, positions, safe_pos,
             return _latent_attention(
                 k_pool, l_idx, q_abs, q_r, page_table, safe_pos, kv_lens,
                 quantized=quantized, attn_impl=attn_impl, tp=tp, mesh=mesh,
-                q_start=q_start, q_len=q_len, dc=dc, scale=scale)
+                q_start=q_start, q_len=q_len, dc=dc, scale=scale, walk=walk)
 
     chosen = None
     if c.has_indexer:
@@ -389,12 +394,25 @@ def _selected_attention(c, k_pool, ik_pool, l_idx, q_abs, q_r, qi, wi,
     return jnp.moveaxis(out, 0, 1).reshape(B, S, H, dc), words
 
 
+def latent_decode_on_kernel(quantized: bool, tp: bool) -> bool:
+    """Whether a decode step under attn_impl "pallas" runs the latent
+    kernel (ops/mla_attention.py `decode_mla_attention`): always on a dense
+    pool; on int8 latent pages (scales fold into scores and values per
+    token) only off a model mesh and opted in by DYN_MLA_INT8_KERNEL, until
+    the hardware parity gate proves the (1, PS) scale tile in compiled
+    Mosaic (same rollout policy as DYN_KV_COPY_KERNEL). `_latent_attention`
+    asks here, and so does `ModelRunner.device_report`."""
+    return not quantized or (not tp and os.environ.get(
+        "DYN_MLA_INT8_KERNEL", "").lower() in ("1", "true", "on", "yes"))
+
+
 def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
                       kv_lens, *, quantized, attn_impl, tp, mesh, q_start,
-                      q_len, dc, scale):
+                      q_len, dc, scale, walk=None):
     """Attention of the absorbed query over the layer's latent pages, by
     the path the pool's dtype, the platform and the step's shape select.
-    Returns the attended latent [B, S, H, d_c]."""
+    Returns the attended latent [B, S, H, d_c]. `walk`: the decode
+    kernel's (`_mla_attention`)."""
     S = q_abs.shape[1]
 
     def query():  # [B, S, H, the pool's width], built where a path wants it
@@ -403,27 +421,19 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
     # the Pallas paths read the stacked pool in place, at l_idx; the jnp
     # gathers take the layer's slab
     if quantized:
-        # int8 latent pages. Decode can ride the Pallas kernel (scales
-        # fold into scores/values per token) — opt-in via
-        # DYN_MLA_INT8_KERNEL until the hardware parity gate proves the
-        # (PS,) scale tile in compiled Mosaic (same rollout policy as
-        # DYN_KV_COPY_KERNEL). Default, and all prefill, uses the jnp
-        # gather: the value view slices q's leading d_c columns while
+        # int8 latent pages: the decode kernel where it is opted in
+        # (`latent_decode_on_kernel`). Default, and all prefill, uses the
+        # jnp gather: the value view slices q's leading d_c columns while
         # KEEPING the per-vector scale — elementwise dequant makes
         # column slicing scale-exact.
-        import os as _os
-
-        use_kernel = (
-            attn_impl == "pallas" and S == 1 and not tp
-            and _os.environ.get("DYN_MLA_INT8_KERNEL", "").lower()
-            in ("1", "true", "on", "yes")
-        )
-        if use_kernel:
+        if (attn_impl == "pallas" and S == 1
+                and latent_decode_on_kernel(quantized, tp)):
             from dynamo_tpu.ops.mla_attention import decode_mla_attention
 
             qd = query()[:, 0]
             attn_lat = decode_mla_attention(
-                qd, k_pool, page_table, kv_lens, l_idx, dc=dc, scale=scale,
+                qd, k_pool, page_table, kv_lens, l_idx, work=walk, dc=dc,
+                scale=scale,
             )[:, None]
         else:
             lat_pool_l = jax.tree.map(lambda a: a[l_idx], k_pool)
@@ -454,8 +464,9 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
                 dc=dc, scale=scale,
             )
     elif attn_impl == "pallas" and S == 1:
-        # decode hot path: Pallas streams latent pages once — the same
-        # DMA feeds both score (full latent) and value (first d_c cols)
+        # decode hot path: Pallas streams the rows' live latent pages
+        # once, several a grid step — the same DMA feeds both score (full
+        # latent) and value (first d_c cols)
         from dynamo_tpu.ops.mla_attention import (
             decode_mla_attention,
             decode_mla_attention_sharded,
@@ -465,11 +476,12 @@ def _latent_attention(k_pool, l_idx, q_abs, q_r, page_table, safe_pos,
         if tp:
             attn_lat = decode_mla_attention_sharded(
                 qd, k_pool, page_table, kv_lens, mesh, layer=l_idx,
-                dc=dc, scale=scale,
+                work=walk, dc=dc, scale=scale,
             )[:, None]
         else:
             attn_lat = decode_mla_attention(
-                qd, k_pool, page_table, kv_lens, l_idx, dc=dc, scale=scale,
+                qd, k_pool, page_table, kv_lens, l_idx, work=walk, dc=dc,
+                scale=scale,
             )[:, None]  # [B, 1, H, d_c]
     else:
         lat_pool_l = k_pool[l_idx]

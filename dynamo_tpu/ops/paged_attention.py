@@ -47,7 +47,11 @@ there too), one walk whatever the answer:
   query rows against the step's pages read as one [N * Hk, D] matrix in
   the pool's dtype, the columns of other KV heads masked, the values
   through `_pv_exact` at their own width; scores, softmax state and
-  accumulator float32.
+  accumulator float32. Latent attention's decode kernel
+  (ops/mla_attention.py `decode_mla_attention`) takes it too, on this
+  walk and this body with a call of its own: one KV head whose values are
+  the leading columns of the key tiles, ONE block a page (`v_pool` None
+  to `decode_walk` and `page_bytes`).
 
 Why no head is ever brought together. The pools lie in HBM as the step
 programs carry them, `T(4,128)(2,1)` / `T(8,128)(2,1)` over the minor pair
@@ -194,9 +198,11 @@ def page_bytes(Hk: int, k_pool, v_pool) -> int:
     """K + V of one page of one layer as ONE call of the kernel holds them:
     `Hk` KV heads (a tensor-parallel shard's local heads, NOT the pool's
     head axis: a walk is built outside `shard_map`, where the pool still
-    has every head), a head's vector padded to whole lane rows."""
+    has every head), a head's vector padded to whole lane rows. `v_pool`
+    None: the values are columns of the key page (latent attention), ONE
+    block, reckoned once."""
     def lanes(a):
-        return -(-a.shape[-1] // 128) * 128
+        return 0 if a is None else -(-a.shape[-1] // 128) * 128
     PS = k_pool.shape[-3]
     return PS * Hk * (lanes(k_pool) + lanes(v_pool)) * k_pool.dtype.itemsize
 
@@ -235,14 +241,32 @@ class Walk:
     tiles: int  # pages a grid step (1 unless by_tiles)
 
 
+def decode_step(heads, k_pool, v_pool, max_pages: int, sinked):
+    """(routine, pages a grid step) of a decode call under a page table
+    `max_pages` wide: `page_routine` at `heads` = (Hk, G) of ONE call, the
+    pools' dtype and widths and `sinked`, and for "by_tiles" `step_tiles`
+    of `page_bytes` at THOSE heads (every other routine: 1). `v_pool` None:
+    latent attention's one pool, the values the leading columns of the key
+    page (ops/mla_attention.py), so never one width. Shapes and dtypes
+    alone are read: `decode_walk` asks with the step's arrays,
+    `ModelRunner.device_report` with the pools it holds."""
+    quantized = isinstance(k_pool, dict)
+    kq, vq = (p["q"] if isinstance(p, dict) else p for p in (k_pool, v_pool))
+    routine = page_routine(*heads, kq.dtype, quantized, sinked,
+                           vq is not None and kq.shape[-1] == vq.shape[-1])
+    if routine != "by_tiles":
+        return routine, 1
+    return routine, step_tiles(page_bytes(heads[0], kq, vq), max_pages)
+
+
 def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window,
                 sinked) -> Walk:
     """The `Walk` of a decode call, for a caller that runs many layers on
     one set of lengths and builds it once. `heads` = (Hk, G) of ONE call (a
     shard's local heads); with the pools' dtype and widths and `sinked`
-    they decide the routine (`page_routine`) and the pages a step
-    (`step_tiles` of `page_bytes` at THOSE heads), and the call takes both
-    from the walk. `decode_work_list`'s pair for "by_rows" and "by_heads";
+    they decide the routine and the pages a step (`decode_step`), and the
+    call takes both from the walk. `v_pool` None: latent attention's one
+    pool. `decode_work_list`'s pair for "by_rows" and "by_heads";
     for "by_tiles" the same walk over steps of `tiles` pages, entry w =
     `row * steps_a_row + step`, so that `entry * tiles + t` is the place of
     the step's t-th tile in `pages`, the page table flattened with every
@@ -252,15 +276,12 @@ def decode_walk(heads, k_pool, v_pool, page_table, kv_lens, window,
     no dead page. Built from compares and masked sums alone: a gather of
     B * MP scalars cost more than the kernel's call (175 us against 104, my
     chip run, PR 39)."""
-    quantized = isinstance(k_pool, dict)
-    kq, vq = (p["q"] if quantized else p for p in (k_pool, v_pool))
+    kq = k_pool["q"] if isinstance(k_pool, dict) else k_pool
     PS, MP = kq.shape[-3], page_table.shape[1]
-    routine = page_routine(*heads, kq.dtype, quantized, sinked,
-                           kq.shape[-1] == vq.shape[-1])
+    routine, tiles = decode_step(heads, k_pool, v_pool, MP, sinked)
     if routine != "by_tiles":
         work, n_work = decode_work_list(kv_lens, window, PS, MP)
         return Walk(work, n_work, None, None, routine, 1)
-    tiles = step_tiles(page_bytes(heads[0], kq, vq), MP)
     work, n_work = decode_work_list(kv_lens, window, PS * tiles, MP // tiles)
     first, last = live_pages(
         kv_lens - 1, kv_lens - 1, kv_lens, window, PS, MP)
@@ -377,7 +398,7 @@ def _decode_kernel_body(
     max_pages: int,
     scale: float,
     softcap: float = 0.0,  # Gemma-2 attention-score soft capping (0 = off)
-    routine: str = "by_heads",  # the per-page routine (page_routine)
+    routine,  # the per-page routine, the function (_PAGE_ROUTINES)
     sink_ref=None,  # f32 like m_ref: a learned sink logit a query head, one
     #   more column of the softmax that gives no value (None: no column)
 ):
@@ -404,7 +425,7 @@ def _decode_kernel_body(
     n_valid = jnp.minimum(kv_len - i * page_size, page_size)
     lo_in_page = jnp.clip(
         _window_lo(kv_len - 1, window) - i * page_size, 0, page_size)
-    _PAGE_ROUTINES[routine](
+    routine(
         q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
         n_valid, lo_in_page, scale=scale, softcap=softcap)
 
@@ -538,7 +559,9 @@ def _pages_by_tiles(q_ref, k_refs, v_refs, ks_ref, vs_ref, m_ref, l_ref,
     MXU does Hk times the useful products, and has them to spare. At one
     KV head a tile is the [PS, D] block of the 4-d view and no column is
     another head's. A tile that repeats a live page (`decode_walk`) sits
-    past n_valid or below lo_in_page like any dead slot."""
+    past n_valid or below lo_in_page like any dead slot. `v_refs is k_refs`
+    (latent attention, ops/mla_attention.py): the values are the leading
+    columns of the same tiles, as wide as the accumulator."""
     del ks_ref, vs_ref  # dense pools only (page_routine)
 
     def rows(refs):  # [N * Hk, width], rows (tile, token, head)
@@ -569,7 +592,8 @@ def _pages_by_tiles(q_ref, k_refs, v_refs, ks_ref, vs_ref, m_ref, l_ref,
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_add = jnp.sum(p, axis=1, keepdims=True)
-    pv = _pv_exact(p, rows(v_refs))
+    v = k[:, :acc_ref.shape[-1]] if v_refs is k_refs else rows(v_refs)
+    pv = _pv_exact(p, v)
     acc_ref[...] = acc_ref[...] * alpha + pv
     l_ref[...] = l_ref[...] * alpha + l_add
     m_ref[...] = m_new
@@ -749,7 +773,7 @@ def decode_paged_attention(
     # single DMA, with a legal (PS, Hk, D) tile (minor dims (Hk, D))
     kv_spec = pl.BlockSpec((None, None, PS, Hk, D), kv_index)
     kw = dict(page_size=PS, max_pages=MP, scale=scale, softcap=softcap,
-              routine=routine)
+              routine=_PAGE_ROUTINES[routine])
     if quantized:
         kernel = functools.partial(
             _decode_kernel_int8_win if windowed else _decode_kernel_int8, **kw

@@ -13,6 +13,13 @@ for MQA the cell's decode step (34 rows of 3-34 pages in a bucket of 64).
 beside values of 128, over the cell's pools of 4096 and 165 pages under a
 page table 96 wide), each with mimo2-agent-steady's decode step: 14 rows
 of 30 pages in a bucket of 16, of which the window shows 3.
+`mla-256` and `mla-512` are latent attention (`decode_mla_attention`: one
+pool, the values its first 256 / 512 columns of 320 / 640 lanes) on the
+decode steps of its cells: mistral4-chat-steady's (8 and 32 rows of 5
+pages under a page table 64 wide over 6 x 768 pages), ling3-reasoning-
+steady's (10 rows of 14 pages in a bucket of 16 under 128 over 3 x 4096)
+and dsv32-docqa-steady's selecting arm (4 rows of 32 pages of a gathered
+buffer under its identity table, 128 heads).
 `--tiles-sweep` (with `--only NAME`) times that step at 1, 2, 4 and 8
 pages a grid step where the checkout's kernel has the rule to set
 (`step_tiles`), which is how the rule's constant was chosen.
@@ -99,6 +106,19 @@ GEOMETRIES = {
                         pool_pages=4096, mp=96, cell=MIMO_CELL_TOKENS),
     "mimo-window": dict(Hk=8, G=8, D=256, Dv=128, window=128, sink=True,
                         pool_pages=165, mp=96, cell=MIMO_CELL_TOKENS),
+}
+# latent attention (ops/mla_attention.py): one pool [L, NP, PS, 1, Dl], the
+# values its first dc columns. A cell's decode step is (label, heads, rows
+# live, bucket, pages a row, page table, (layers, pages) of the pool; None:
+# the selecting arm's gathered buffer, `bucket * table` pages under the
+# identity table and no layer)
+LATENT_GEOMETRIES = {
+    "mla-256": dict(Dl=320, dc=256, steps=(
+        ("mistral4 8 of 8", 32, 8, 8, 5, 64, (6, 768)),
+        ("mistral4 32 of 32", 32, 32, 32, 5, 64, (6, 768)))),
+    "mla-512": dict(Dl=640, dc=512, steps=(
+        ("ling3 10 of 16", 32, 10, 16, 14, 128, (3, 4096)),
+        ("dsv32 selected 4 of 4", 128, 4, 4, 32, 32, None))),
 }
 # one query head a KV head (`--ragged --routines`): the tile routine does Hk
 # times the useful products there with no group to fill the rows
@@ -232,6 +252,62 @@ def bench_decode_table(impls) -> None:
         del pools
 
 
+@partial(jax.jit, static_argnames=("dc",), donate_argnames=("pool",))
+def latent_loop(q, pool, pt, kv_lens, dc):
+    """ITERS chained calls of the latent decode kernel, the pool donated
+    and handed back; the walk built once above them where the checkout's
+    kernel takes one (models/llama.py builds it above its layer scan)."""
+    from dynamo_tpu.ops import mla_attention as mla
+
+    kw = dict(dc=dc, scale=0.1)
+    if hasattr(mla, "latent_walk"):
+        kw["work"] = mla.latent_walk(q.shape[1], pool, pt, kv_lens)
+    stacked = pool.ndim == 5
+
+    def body(q, i):
+        layer = (jnp.minimum(i, pool.shape[0] - 1),) if stacked else ()
+        o = mla.decode_mla_attention(q, pool, pt, kv_lens, *layer, **kw)
+        o = jnp.concatenate([o, o[..., :q.shape[-1] - dc]], axis=-1)
+        return o.astype(q.dtype), None
+
+    q, _ = lax.scan(body, q, jnp.arange(ITERS) + 1)
+    return q, pool
+
+
+def bench_latent_table(label="") -> None:
+    """`decode_mla_attention` on its cells' decode steps, one JSON line a
+    point: us a call, us a live page, the page's DMA time at the HBM peak
+    (one block for keys and values)."""
+    for gname, geom in LATENT_GEOMETRIES.items():
+        dl, dc = geom["Dl"], geom["dc"]
+        for name, heads, live_rows, rows, pages, mp, stack in geom["steps"]:
+            rng = np.random.default_rng(0)
+            shape = (rows * mp,) if stack is None else stack
+            pool = jax.random.normal(jax.random.key(0),
+                                     shape + (PS, 1, dl), jnp.bfloat16)
+            pt = np.arange(rows * mp, dtype=np.int32).reshape(rows, mp)
+            if stack is not None:
+                pt = rng.integers(stack[1], size=(rows, mp)).astype(np.int32)
+            kv = np.where(np.arange(rows) < live_rows, pages * PS - 7, 0)
+            q = jnp.asarray(rng.standard_normal((rows, heads, dl)),
+                            jnp.bfloat16)
+            box = [pool]
+
+            def call():
+                out, box[0] = latent_loop(q, box[0], jnp.asarray(pt),
+                                          jnp.asarray(kv, jnp.int32), dc=dc)
+                return out
+
+            us, live = _time(call), live_rows * pages
+            print(json.dumps({
+                "point": f"{gname} {name}{label}", "rows": rows,
+                "pages_a_row": pages, "page_table": mp, "live_pages": live,
+                "dma_us_page": round(PS * dl * 2 / HBM_BYTES_PER_S * 1e6, 3),
+                "us_call": round(us, 1),
+                "us_live_page": round(us / live, 3)}), flush=True)
+            del pool, box
+
+
 def bench_tiles_sweep(impls) -> None:
     """The cell's decode step at 1, 2, 4 and 8 pages a grid step: the
     kernel's rule (`step_tiles`) replaced for the sweep, the programs
@@ -254,6 +330,15 @@ def bench_tiles_sweep(impls) -> None:
         pa.step_tiles = rule
         jax.clear_caches()
         del pools
+    from dynamo_tpu.ops import mla_attention as mla
+
+    # (a checkout whose latent kernel takes no walk has no count to set)
+    for tiles in (1, 2, 4, 8) if hasattr(mla, "latent_walk") else ():
+        pa.step_tiles = lambda nbytes, mp, t=tiles: math.gcd(mp, t)
+        jax.clear_caches()
+        bench_latent_table(f" tiles {tiles}")
+    pa.step_tiles = rule
+    jax.clear_caches()
 
 
 def check_decode(interpret: bool) -> None:
@@ -492,8 +577,9 @@ def main() -> None:
     args = sys.argv[1:]
     if "--only" in args:
         only = {args[args.index("--only") + 1]}
-        for name in GEOMETRIES.keys() - only:
-            del GEOMETRIES[name]
+        for table in (GEOMETRIES, LATENT_GEOMETRIES):
+            for name in table.keys() - only:
+                del table[name]
     if "--q-block" in args:
         global Q_BLOCK
         Q_BLOCK = int(args[args.index("--q-block") + 1])
@@ -510,6 +596,7 @@ def main() -> None:
         bench_tiles_sweep(impls)
     elif "--ragged-only" not in args:
         bench_decode_table(impls)
+        bench_latent_table()
 
 
 if __name__ == "__main__":

@@ -501,3 +501,34 @@ def test_mla_int8_kernel_full_layer_matches_jnp(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=3e-2, atol=3e-2
     )
+
+
+def test_the_runner_names_the_latent_decode_kernels_routine(monkeypatch, caplog):
+    """`runner ready` and /debug/device say what the latent decode kernel
+    does with its pages, from the walk's own decision (`decode_step`): the
+    tile routine, eight pages a grid step where they tile the page table;
+    an int8 latent pool rides the kernel only where it is opted in, its
+    pages one a step by their scales; the jnp gather names nothing."""
+    import logging
+
+    from dynamo_tpu.engine.model_runner import ModelRunner
+
+    c = get_config("tiny-mla")
+    kw = dict(num_pages=8, page_size=4)
+    with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.runner"):
+        rep = ModelRunner(c, attn_impl="pallas", **kw).device_report()
+    assert (rep["decode_page_routine"], rep["decode_step_pages"]) == ("by_tiles", 8)
+    assert "decode_page_routine=by_tiles, decode_step_pages=8" in caplog.text
+    rep = ModelRunner(c, attn_impl="pallas", max_pages_per_seq=12, **kw).device_report()
+    assert (rep["decode_page_routine"], rep["decode_step_pages"]) == ("by_tiles", 4)
+    rep = ModelRunner(c, **kw).device_report()
+    assert rep["decode_page_routine"] is rep["decode_step_pages"] is None
+    int8 = ModelRunner(c, attn_impl="pallas", kv_quantize="int8", **kw)
+    rep = int8.device_report()
+    assert rep["decode_page_routine"] is rep["decode_step_pages"] is None
+    monkeypatch.setenv("DYN_MLA_INT8_KERNEL", "1")
+    rep = int8.device_report()
+    assert (rep["decode_page_routine"], rep["decode_step_pages"]) == ("by_heads", 1)
+    # a model without latent attention names its routine and no count
+    rep = ModelRunner(get_config("tiny"), attn_impl="pallas", **kw).device_report()
+    assert (rep["decode_page_routine"], rep["decode_step_pages"]) == ("by_tiles", None)

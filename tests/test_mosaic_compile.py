@@ -244,6 +244,7 @@ def test_sharded_ragged_kernel_compiles_for_v5e_2x2(topo, name, tiles):
 # a page table 64 wide. name -> (kernel, rows or chunk length)
 _MLA_SHAPES = {
     "decode-b1": ("decode", 1), "decode-b16": ("decode", 16),
+    "decode-b32": ("decode", 32),  # the cell's widest bucket
     "prefill-s16": ("prefill", 16), "prefill-s256": ("prefill", 256),
     # the acc cap alone allowed a 128-row query block here (4096 rows x 32
     # heads), which Mosaic refuses: 19.7 MB of scoped VMEM against 16
@@ -254,6 +255,7 @@ _MLA_SHAPES = {
 @pytest.mark.parametrize("name", list(_MLA_SHAPES))
 def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
     from dynamo_tpu.ops.mla_attention import decode_mla_attention, prefill_mla_attention
+    from dynamo_tpu.ops.paged_attention import decode_step
 
     one_chip = SingleDeviceSharding(topo.devices[0])
 
@@ -265,6 +267,8 @@ def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
     kw = dict(dc=dc, scale=128 ** -0.5 * (0.1 * np.log(128) + 1) ** 2)
     pool = s((NP, PS, 1, Dl), jnp.bfloat16)
     if kind == "decode":
+        # the walk's decision at these shapes: eight 41 KB pages a grid step
+        assert decode_step((1, H), pool, None, MP, False) == ("by_tiles", 8)
         fn = lambda q, l, pt, kl: decode_mla_attention(q, l, pt, kl, **kw)
         args = (s((n, H, Dl), jnp.bfloat16), pool, s((n, MP), jnp.int32), s((n,), jnp.int32))
     else:
@@ -284,9 +288,16 @@ def test_latent_attention_kernels_compile_for_v5e_at_rank_256(topo, name):
 _DSA = dict(H=128, Dl=640, dc=512, NP=4096, PS=64, MP=576)
 
 
-@pytest.mark.parametrize("kind, n", [("decode", 32), ("prefill", 256), ("prefill", 1024)])
+@pytest.mark.parametrize("kind, n", [("decode", 32), ("prefill", 256), ("prefill", 1024),
+                                     ("decode-selected", 4), ("decode-selected", 32),
+                                     ("decode-ling3", 16)])
 def test_latent_attention_kernels_compile_for_v5e_at_rank_512(topo, kind, n):
+    """`decode-selected`: the selecting arm's call, `index_topk` 2048 gathered
+    rows a query as 32 pages of a buffer of their own under the identity
+    table. `decode-ling3`: ling-3.0-flash-vl's 32 heads under its page table
+    128 wide. Eight 82 KB pages a grid step at every table."""
     from dynamo_tpu.ops.mla_attention import decode_mla_attention, prefill_mla_attention
+    from dynamo_tpu.ops.paged_attention import decode_step
 
     one_chip = SingleDeviceSharding(topo.devices[0])
 
@@ -294,9 +305,14 @@ def test_latent_attention_kernels_compile_for_v5e_at_rank_512(topo, kind, n):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     H, Dl, dc, NP, PS, MP = (_DSA[k] for k in ("H", "Dl", "dc", "NP", "PS", "MP"))
+    if kind == "decode-selected":
+        NP, MP = n * 32, 32
+    elif kind == "decode-ling3":
+        H, MP = 32, 128
     kw = dict(dc=dc, scale=0.1352)
     pool = s((NP, PS, 1, Dl), jnp.bfloat16)
-    if kind == "decode":
+    if kind.startswith("decode"):
+        assert decode_step((1, H), pool, None, MP, False) == ("by_tiles", 8)
         fn = lambda q, l, pt, kl: decode_mla_attention(q, l, pt, kl, **kw)
         args = (s((n, H, Dl), jnp.bfloat16), pool, s((n, MP), jnp.int32), s((n,), jnp.int32))
     else:
@@ -742,7 +758,11 @@ def test_a_latent_models_step_programs_read_the_latent_pool_in_place(topo, name,
     exit of a step program, before and after this PR: ModelConfig.mla_pool_dim
     says why; a pool of whole 128-lane rows is not.) The same decode loop with
     `k_pool[l_idx]` handed to the kernel does have the slab, which is how the
-    check can see one."""
+    check can see one. The decode kernel walks a list of the rows' live pages
+    (since PR 52), which hangs on the lengths alone: the decode loop builds it
+    once a step, in the step loop, and no instruction of it (`attn.walk` in
+    the metadata) sits inside the layer scan; with the walk left to the
+    kernel's wrapper, inside the scan, the same check finds it there."""
     import json
     import os
     import re
@@ -833,6 +853,19 @@ def test_a_latent_models_step_programs_read_the_latent_pool_in_place(topo, name,
 
     if program != "decode":
         return
+
+    def walks_built(text):
+        """(instructions that build a walk's lists (`latent_walk` names
+        them `attn.walk`), those of them inside a loop inside the step
+        loop: the layer scan)."""
+        built = [l for l in text.splitlines() if "attn.walk" in l and " = " in l]
+        return built, [l.strip()[:160] for l in built
+                       if l.count("while/body") > 1]
+
+    # the decode kernel's lists of live pages hang on the lengths alone:
+    # built once a step, in the step loop, and carried into the layer scan
+    built, in_scan = walks_built(text)
+    assert built and in_scan == []
     orig = getattr(mla_ops, kernel)
 
     def sliced(q, pool, *rest, **kw):  # the operand until PR 51
@@ -841,6 +874,12 @@ def test_a_latent_models_step_programs_read_the_latent_pool_in_place(topo, name,
 
     monkeypatch.setattr(mla_ops, kernel, sliced)
     assert moved(compiled())[0]
+
+    def relisted(*args, work=None, **kw):  # the walk built where it is used
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(mla_ops, kernel, relisted)
+    assert walks_built(compiled())[1]
 
 
 # -- the delta-rule kernels (ops/kda.py) ---------------------------------------

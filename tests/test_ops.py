@@ -403,6 +403,28 @@ def test_page_routine_follows_heads_and_dtype():
     assert step_tiles(64 * 4 * (256 + 128) * 2, 96) == 4
     assert step_tiles(64 * 8 * (256 + 128) * 2, 96) == 2
     assert step_tiles(64 * 8 * (256 + 128) * 2, 7) == 1
+    # latent attention: ONE block for keys and values, reckoned once (49 KB
+    # at rank 256's 320 -> 384 lanes, 82 KB at rank 512's 640): eight a
+    # step under its cells' page tables and the selecting arm's identity
+    # table; an int8 latent pool takes its pages one a step, by its scales
+    from dynamo_tpu.ops.paged_attention import decode_step, page_bytes
+
+    def latent(dl, dtype=bf):
+        return jax.ShapeDtypeStruct((6, 768, 64, 1, dl), dtype)
+
+    assert page_bytes(1, latent(320), None) == 64 * 384 * 2
+    assert page_bytes(1, latent(640), None) == 64 * 640 * 2
+    assert page_bytes(1, latent(640), latent(640)) == 2 * 64 * 640 * 2
+    for dl, mp in ((320, 64), (640, 128), (640, 32), (640, 576)):
+        assert decode_step((1, 32), latent(dl), None, mp, False) == ("by_tiles", 8)
+    assert decode_step((1, 1), latent(320, f32), None, 64, False) == ("by_tiles", 8)
+    int8 = {"q": latent(320, i8), "s": jax.ShapeDtypeStruct((6, 768, 64, 1), f32)}
+    assert decode_step((1, 32), int8, None, 64, False) == ("by_heads", 1)
+    # and the GQA pools answer as before
+    assert decode_step((1, 20), latent(128), latent(128), 64, False) == ("by_tiles", 8)
+    assert decode_step((32, 1), jax.ShapeDtypeStruct((2, 8, 64, 32, 96), bf),
+                       jax.ShapeDtypeStruct((2, 8, 64, 32, 96), bf), 64,
+                       False) == ("by_rows", 1)
 
 
 @pytest.mark.parametrize("kernel", ["decode", "ragged"])
@@ -536,73 +558,184 @@ def test_decode_walk_stays_inside_the_page_table(geom):
 
 
 # -- MLA decode kernel -------------------------------------------------------
+# The latent decode kernel walks `decode_walk`'s list over its one pool, a
+# step `step_tiles` pages of the row. name -> (PS, MP, the rows' lengths,
+# the pool's dtype, "dense" / "int8" / "identity": the selecting arm's
+# gathered buffer under its own identity table): the toy sizes (2 pages a
+# step of a table 6 wide); pages of 64 tokens under a table 16 wide (8 a
+# step): one token, a context that ends inside a tile, on a page's edge,
+# one token into the next page, on a step's edge and one token past it; a
+# pad row between live rows; tables whose width makes a step 1, 2, 4 and 8
+# pages; the bf16 pool (the MXU takes it as it lies) beside the float32
+# one; the int8 routine, one page a step on the same list
+_BF16, _F32_ = jnp.bfloat16, jnp.float32
+_MLA_CASES = {
+    "toy-1-9-24": (4, 6, [1, 9, 24], _F32_, "dense"),
+    "toy-4-4-4": (4, 6, [4, 4, 4], _F32_, "dense"),
+    "toy-24-1-13": (4, 6, [24, 1, 13], _F32_, "dense"),
+    "page64-edges": (64, 16, [1, 63, 64, 65, 8 * 64, 8 * 64 + 1], _F32_, "dense"),
+    "page64-edges-bf16": (64, 16, [1, 63, 64, 65, 8 * 64, 8 * 64 + 1], _BF16, "dense"),
+    "pad-row-between": (4, 6, [9, 0, 24, 0], _F32_, "dense"),
+    "pad-rows-bf16": (4, 8, [0, 32, 0, 5], _BF16, "dense"),
+    "tiles-1": (4, 7, [28, 1, 13], _F32_, "dense"),
+    "tiles-2": (4, 6, [24, 5, 13], _F32_, "dense"),
+    "tiles-4": (4, 12, [48, 16, 17], _F32_, "dense"),
+    "tiles-8": (4, 8, [32, 1, 13], _F32_, "dense"),
+    "tiles-8-two-steps-bf16": (4, 16, [64, 33, 32, 0], _BF16, "dense"),
+    "whole-table-bf16": (4, 6, [24, 24, 24], _BF16, "dense"),
+    "int8": (4, 6, [1, 9, 24], _F32_, "int8"),
+    "int8-pad-row": (4, 8, [32, 0, 13], _F32_, "int8"),
+    "int8-page64": (64, 16, [65, 8 * 64 + 1, 1], _F32_, "int8"),
+    "identity-table": (4, 8, [32, 7, 0, 20], _F32_, "identity"),
+    "identity-table-bf16": (64, 4, [256, 100, 3], _BF16, "identity"),
+}
+_MLA_TILES = {"tiles-1": 1, "tiles-2": 2, "tiles-4": 4, "tiles-8": 8,
+              "page64-edges": 8, "toy-1-9-24": 2, "int8": 1,
+              "identity-table": 8, "identity-table-bf16": 4}
 
 
-def _mla_setup(B=3, H=4, dc=32, dr=16, NP=32, PS=4, MP=6, seed=3):
+def _mla_setup(B=3, H=4, dc=32, dr=16, NP=32, PS=4, MP=6, seed=3,
+               dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     Dl = dc + dr
-    q = jnp.asarray(rng.standard_normal((B, H, Dl)), jnp.float32)
-    lat = jnp.asarray(rng.standard_normal((NP, PS, 1, Dl)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, H, Dl)), dtype)
+    lat = jnp.asarray(rng.standard_normal((NP, PS, 1, Dl)), dtype)
     pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP).astype(np.int32))
     return q, lat, pt
 
 
-@pytest.mark.parametrize("kv_lens", [[1, 9, 24], [4, 4, 4], [24, 1, 13]])
-def test_decode_mla_attention_matches_reference(kv_lens):
-    from dynamo_tpu.models.llama import paged_attention_jnp
-    from dynamo_tpu.ops.mla_attention import decode_mla_attention
+def _mla_case(case, dc=32, dr=16):
+    """(q, the pool operand, the float32 / int8 pool the reference reads,
+    page table, lengths)."""
+    PS, MP, kv_lens, dtype, kind = _MLA_CASES[case]
+    B = len(kv_lens)
+    q, lat, pt = _mla_setup(B=B, dc=dc, dr=dr, NP=B * MP + 2, PS=PS, MP=MP,
+                            dtype=dtype)
+    # (the pool's last two pages are nobody's: what dead entries point at)
+    pt = jnp.asarray(np.random.default_rng(4).permutation(B * MP)
+                     .reshape(B, MP).astype(np.int32))
+    if kind == "identity":  # a row's buffer is its own pages, in order
+        pt = jnp.arange(B * MP, dtype=jnp.int32).reshape(B, MP)
+    pool = lat
+    if kind == "int8":
+        from dynamo_tpu.models.quant import kv_pool_quantize
+
+        pool = kv_pool_quantize(lat)
+    return q, pool, pt, jnp.asarray(kv_lens, jnp.int32)
+
+
+def _mla_ref(q, pool, pt, kv, dc, scale):
+    if isinstance(pool, dict):
+        v_view = {"q": pool["q"][..., :dc], "s": pool["s"]}
+    else:
+        pool = pool.astype(jnp.float32)
+        v_view = pool[..., :dc]
+    qg = q.astype(jnp.float32)[:, None, None]  # [B, 1, 1, H, Dl]
+    return np.asarray(paged_attention_jnp(
+        qg, pool, v_view, pt, jnp.maximum(kv - 1, 0)[:, None], kv,
+        scale=scale)[:, 0, 0])  # [B, H, dc]
+
+
+@pytest.mark.parametrize("case", list(_MLA_CASES))
+def test_decode_mla_attention_matches_reference(case):
+    from dynamo_tpu.ops.mla_attention import decode_mla_attention, latent_walk
 
     dc, dr = 32, 16
-    q, lat, pt = _mla_setup(dc=dc, dr=dr)
-    kv = jnp.asarray(kv_lens, jnp.int32)
+    q, pool, pt, kv = _mla_case(case, dc, dr)
     scale = (24 + dr) ** -0.5  # distinct from Dl**-0.5: must be honored
-    out = decode_mla_attention(q, lat, pt, kv, dc=dc, scale=scale,
-                               interpret=True)
-    B, H, Dl = q.shape
-    qg = q[:, None, None, :, :].transpose(0, 2, 1, 3, 4)  # [B,1,1,H,Dl]
-    ref = paged_attention_jnp(
-        qg, lat, lat[..., :dc], pt,
-        (kv - 1)[:, None], kv, scale=scale,
-    )[:, 0, 0]  # [B, H, dc]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    if case in _MLA_TILES:
+        assert latent_walk(q.shape[1], pool, pt, kv).tiles == _MLA_TILES[case]
+    out = np.asarray(decode_mla_attention(q, pool, pt, kv, dc=dc, scale=scale,
+                                          interpret=True), np.float32)
+    ref = _mla_ref(q, pool, pt, kv, dc, scale)
+    live = np.asarray(kv) > 0
+    assert np.all(out[~live] == 0.0)  # a pad row: defined, and zero
+    tol = 2e-5 if q.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
 
 
-def test_decode_mla_attention_ignores_garbage_pages():
+@pytest.mark.parametrize("case", ["toy-1-9-24", "tiles-1", "tiles-4", "tiles-8",
+                                  "tiles-8-two-steps-bf16", "pad-row-between",
+                                  "page64-edges-bf16", "int8-pad-row"])
+def test_decode_mla_attention_ignores_garbage_pages(case):
+    """Every page outside the rows' live runs holds NaN (int8: its scale),
+    and every dead page-table entry names such a page: the bits do not
+    change, so neither a dead entry nor a dead page was read."""
     from dynamo_tpu.ops.mla_attention import decode_mla_attention
 
     dc = 32
-    q, lat, pt = _mla_setup(dc=dc)
-    kv = jnp.asarray([2, 5, 9], jnp.int32)
-    # clobber page-table entries past each sequence's last valid page
-    pt_bad = np.asarray(pt).copy()
-    pt_bad[0, 1:] = 31
-    pt_bad[1, 2:] = 30
-    out_a = decode_mla_attention(q, lat, pt, kv, dc=dc, scale=0.1,
-                                 interpret=True)
-    out_b = decode_mla_attention(q, lat, jnp.asarray(pt_bad), kv, dc=dc,
+    q, pool, pt, kv = _mla_case(case, dc)
+    PS = jax.tree.leaves(pool)[0].shape[1]
+    pt, n_pages = np.asarray(pt), -(-np.asarray(kv) // PS)
+    live = np.arange(pt.shape[1])[None, :] < n_pages[:, None]
+    NP = jax.tree.leaves(pool)[0].shape[0]
+    dead_pages = jnp.asarray(np.setdiff1d(np.arange(NP), pt[live]))
+    assert NP - 1 in np.asarray(dead_pages)  # nobody's
+    if isinstance(pool, dict):
+        bad = {"q": pool["q"], "s": pool["s"].at[dead_pages].set(jnp.nan)}
+    else:
+        bad = pool.at[dead_pages].set(jnp.nan)
+    pt_bad = jnp.asarray(np.where(live, pt, NP - 1).astype(np.int32))
+    out_a = decode_mla_attention(q, pool, jnp.asarray(pt), kv, dc=dc,
                                  scale=0.1, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
+    out_b = decode_mla_attention(q, bad, pt_bad, kv, dc=dc, scale=0.1,
+                                 interpret=True)
+    assert np.asarray(out_a, np.float32)[np.asarray(kv) > 0].any()
+    np.testing.assert_array_equal(np.asarray(out_a, np.float32),
+                                  np.asarray(out_b, np.float32))
 
 
-def test_decode_mla_attention_sharded_matches_reference():
+@pytest.mark.parametrize("walk", ["built-in-the-wrapper", "handed-in"])
+@pytest.mark.parametrize("case", ["toy-1-9-24", "pad-row-between", "tiles-8",
+                                  "tiles-8-two-steps-bf16"])
+def test_decode_mla_attention_sharded_matches_reference(case, walk):
+    """Heads over two shards against the replicated pool; the walk is
+    built once outside `shard_map`, by the wrapper or by its caller (a
+    model's forward, above its layer scan), and rides in replicated."""
     from dynamo_tpu.ops.mla_attention import (
         decode_mla_attention,
         decode_mla_attention_sharded,
+        latent_walk,
     )
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
     dc = 32
-    q, lat, pt = _mla_setup(H=4, dc=dc)
-    kv = jnp.asarray([3, 11, 20], jnp.int32)
+    q, pool, pt, kv = _mla_case(case, dc)
     mesh = make_mesh(MeshConfig(model=2))
+    work = None
+    if walk == "handed-in":
+        work = latent_walk(q.shape[1] // 2, pool, pt, kv)
     out = decode_mla_attention_sharded(
-        q, lat, pt, kv, mesh, dc=dc, scale=0.12, interpret=True
+        q, pool, pt, kv, mesh, work=work, dc=dc, scale=0.12, interpret=True
     )
-    ref = decode_mla_attention(q, lat, pt, kv, dc=dc, scale=0.12,
+    ref = decode_mla_attention(q, pool, pt, kv, dc=dc, scale=0.12,
                                interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
+def test_decode_mla_attention_grid_is_the_live_steps():
+    """One traced grid bound, the rows' live steps, whatever the page
+    table's width: 64 x 64 tokens under a table 64 wide is 8 steps of 8
+    pages, not 64; and the page block appears once a tile on one operand."""
+    from dynamo_tpu.ops.mla_attention import decode_mla_attention, latent_walk
+
+    PS, MP, H, Dl, dc = 64, 64, 4, 48, 32
+    kv = jnp.asarray([0, 1, 64 * 5, 64 * 8, 64 * 8 + 1, 64 * 64], jnp.int32)
+    pool = jnp.zeros((2, 8, PS, 1, Dl), jnp.bfloat16)
+    pt = jnp.zeros((len(kv), MP), jnp.int32)
+    work = latent_walk(H, pool, pt, kv)
+    assert (work.routine, work.tiles) == ("by_tiles", 8)
+    assert int(work.n_work) == 0 + 1 + 1 + 1 + 2 + 8
+    jaxpr = jax.make_jaxpr(functools.partial(
+        decode_mla_attention, dc=dc, scale=0.1, interpret=True))(
+        jnp.zeros((len(kv), H, Dl), jnp.bfloat16), pool, pt, kv, jnp.int32(1))
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    gm = call.params["grid_mapping"]
+    assert len(gm.grid) == 1 and gm.num_dynamic_grid_bounds == 1
+    blocks = [v.aval.shape for v in call.invars
+              if v.aval.shape == (2, 8, PS, Dl)]
+    assert len(blocks) == 8  # the 4-d view, a block a tile
 
 
 def test_mla_forward_pallas_decode_matches_jnp():
@@ -1732,6 +1865,34 @@ def _stacked_case(kernel, *, quant=False, window=None, softcap=0.0,
                 q[:, None], slab(kp, l), slab(vp, l), pt, (kv - 1)[:, None],
                 kv, softcap=softcap, window=win)[:, 0]
 
+    elif kernel == "latent":  # one pool, the values its first dc columns
+        from dynamo_tpu.ops.mla_attention import (
+            decode_mla_attention, decode_mla_attention_sharded,
+        )
+
+        B, dc = 4, 48
+        q = jnp.asarray(rng.standard_normal((B, G, D)), jnp.bfloat16)
+        pt = jnp.asarray(rng.permutation(NP)[: B * MP].reshape(B, MP)
+                         .astype(np.int32))
+        kv = jnp.asarray([5, 17, 64, 1], jnp.int32)
+        kw = dict(dc=dc, scale=0.11, interpret=True)
+
+        def run(k, v, layer):
+            if sharded:
+                return decode_mla_attention_sharded(q, k, pt, kv, mesh,
+                                                    layer=layer, **kw)
+            return decode_mla_attention(q, k, pt, kv, layer, **kw)
+
+        def ref(l):
+            lat = slab(kp, l)
+            if quant:
+                values = {"q": lat["q"][..., :dc], "s": lat["s"]}
+            else:
+                values = lat[..., :dc]
+            return paged_attention_jnp(
+                q[:, None, None], lat, values, pt, (kv - 1)[:, None], kv,
+                scale=0.11)[:, 0, 0]
+
     elif kernel == "prefill":
         from dynamo_tpu.ops.flash_prefill import (
             prefill_paged_attention_sharded,
@@ -1800,6 +1961,11 @@ _STACKED_CASES = {
     "ragged-mqa": dict(kernel="ragged", heads=(1, 20)),
     "decode-mqa-int8": dict(kernel="decode", heads=(1, 20), quant=True),
     "ragged-sharded-window": dict(kernel="ragged", sharded=True, window=16),
+    # latent attention's decode kernel on the same walk: its one pool
+    # [L, NP, PS, 1, Dl], 8 pages a step, 20 query heads
+    "latent": dict(kernel="latent", heads=(1, 20)),
+    "latent-int8": dict(kernel="latent", heads=(1, 20), quant=True),
+    "latent-sharded": dict(kernel="latent", heads=(1, 20), sharded=True),
 }
 
 
@@ -1827,7 +1993,8 @@ def test_kernels_read_stacked_pool_at_layer(case, layer):
 
 @pytest.mark.parametrize(
     "case", ["decode", "prefill", "ragged", "decode-int8", "ragged-window",
-             "decode-sharded", "decode-mqa-window", "ragged-mqa"],
+             "decode-sharded", "decode-mqa-window", "ragged-mqa", "latent",
+             "latent-int8", "latent-sharded"],
 )
 def test_per_layer_pool_is_the_one_layer_stack(case):
     """A pool of rank 4 is viewed as `pool[None]` at layer 0: the same
