@@ -1620,6 +1620,7 @@ class InferenceEngine:
         )
         if self.side is not None:
             self.side.record(record, rinfo, self.scheduler.active)
+        self.runner.fill_record(record)
         clock = self.step_clock  # (there is one: rec.enabled)
         clock.cut(now_ns)
         rec.take_clock(record, clock)
@@ -2162,6 +2163,10 @@ class InferenceEngine:
             mm=mm_chunk,
             **({"side": self.side.operand(seq)}
                if self.side is not None else {}),
+            # (nobody reads the logits of a chunk that does not end its
+            # prompt: a runner that can leave work out for that is told)
+            **({"sampled": False} if self.runner.skips_unsampled
+               and not plan.is_last_chunk else {}),
         )
         if self.runner.has_draft and seq.disagg != "prefill":
             # keep the draft model's KV pools in lockstep so spec decode

@@ -36,7 +36,7 @@ from dynamo_tpu.engine.runner_api import (
     refusal,
 )
 from dynamo_tpu.engine.sampling import SamplingParams, sample
-from dynamo_tpu.models import jamba, ling, llama, mimo
+from dynamo_tpu.models import jamba, ling, llama, mimo, sambay
 from dynamo_tpu.models.config import ModelConfig, mean_over_layers
 from dynamo_tpu.models.moe import routing_stats
 from dynamo_tpu.parallel.mesh import MeshConfig, ShardingPolicy, make_mesh
@@ -86,6 +86,8 @@ def _forward(config: ModelConfig, params, tokens, positions, k_pool, v_pool,
 def _side_ops(config: ModelConfig):
     """The record (models/toolkit.SideCacheOps) of the module whose models
     keep a cache beside their KV pages; None for every other model."""
+    if config.is_sambay:
+        return sambay.SIDE
     if config.is_hybrid:
         return jamba.SIDE
     if config.has_window_pool:
@@ -988,12 +990,18 @@ class ModelRunner(Runner):
         self.side_kind = self._side_mod.kind if self._side_mod else None
         self.state = None  # the pool, once ensured
         self.side_units = 0
+        # a model whose last layers only a sampled row's logits need: told
+        # `sampled=False`, a prefill chunk is served without them, and the
+        # dispatches count the rows they ran on (fill_record)
+        self.skips_unsampled = config.has_cross_decoder
+        self._sampled_rows = self._skipped_tokens = 0
         mc = self.mesh_config
+        kinds = self.side_kind.split("+") if self.side_kind else ()
         if self._side_mod is not None:
             self.has_verify_spec = False  # no rollback of it
             self.side_unit_bytes = self._side_mod.unit_bytes(
                 config, page_size, dtype)
-        if self.side_kind == "state":
+        if "state" in kinds:
             if mc.n_devices > 1:
                 raise NotImplementedError(
                     "a state-space model is not sharded yet: its state pool "
@@ -1003,7 +1011,7 @@ class ModelRunner(Runner):
                     "speculative decoding with a draft model (and LoRA)")
             if config.is_kda and kv_quantize:
                 self._no_side("a quantized KV cache (--kv-quantize)")
-        if self.side_kind == "window":
+        if "window" in kinds:
             if mc.n_devices > 1:
                 raise NotImplementedError(
                     "a window-pool model is not sharded yet: both its caches "
@@ -1371,9 +1379,12 @@ class ModelRunner(Runner):
         elif self.attn_impl == "pallas" and not c.is_mla:
             shards = k_leaf.shape[3] // shard_shape[3]
             v_width = jax.tree.leaves(self.v_pool)[0].shape[-1]
-            kinds = {"global": (c.n_kv_heads, c.sink_global)}
-            if c.has_window_pool:
-                kinds["window"] = (c.n_kv_heads_window, c.sink_window)
+            # (a pool head, where a pool holds the model's KV heads paired)
+            Hk_pool = k_leaf.shape[3]
+            kinds = {"global": (Hk_pool, c.sink_global)}
+            if "window" in self._by_kind(self.side_units):
+                kinds["window"] = (c.n_kv_heads_window or Hk_pool,
+                                   c.sink_window)
 
             quantized = isinstance(self.k_pool, dict)
 
@@ -1400,8 +1411,10 @@ class ModelRunner(Runner):
                     for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
                     if k in st
                 }
-        # (the side pool under its kind's keys; 0 under the other's)
-        units = {"state": 0, "window": 0, self.side_kind: self.side_units}
+        # (a side pool under its kind's keys; 0 under a kind it has not)
+        units = {"state": 0, "window": 0, **self._by_kind(self.side_units)}
+        unit_bytes = {"state": 0, "window": 0,
+                      **self._by_kind(self.side_unit_bytes)}
         return {
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
@@ -1421,9 +1434,9 @@ class ModelRunner(Runner):
             },
             "kv_pool_bytes": self.kv_pool_bytes(),
             "state_slots": units["state"],
-            "state_pool_bytes": units["state"] * self.side_unit_bytes,
+            "state_pool_bytes": units["state"] * unit_bytes["state"],
             "window_pages": units["window"],
-            "window_pool_bytes": units["window"] * self.side_unit_bytes,
+            "window_pool_bytes": units["window"] * unit_bytes["window"],
             "memory": memory,
         }
 
@@ -1440,6 +1453,9 @@ class ModelRunner(Runner):
         #   there (None: scratch). A state slot: a chunk at start_pos 0
         #   starts from zeros, a later one from what the slot holds. A
         #   window page table: a list.
+        sampled: bool = True,  # False: nobody reads this chunk's logits (it
+        #   does not end its prompt); a runner that `skips_unsampled`
+        #   leaves out what only they need and returns zeros
     ) -> jax.Array:
         """Run one prefill chunk for a single sequence. `tokens` are the
         uncomputed prompt tokens starting at absolute position `start_pos`;
@@ -1470,8 +1486,12 @@ class ModelRunner(Runner):
                 jnp.int32(n - 1), attn_impl=self.attn_impl,
                 mesh=self._fwd_mesh,
                 **self._state_kw([side], 1),
+                # (traced: one compiled program serves both)
+                **({"sampled": jnp.asarray(bool(sampled))}
+                   if self.skips_unsampled else {}),
             )
             self._note_routed(self._keep_state(routed), 1, chunk_lens=[n])
+            self._note_sampled(0, [n], int(bool(sampled)))
             return logits[0, 0]
         impl = "ring" if self.sp_enabled else self.attn_impl
         logits, self.k_pool, self.v_pool, *routed = self._jit_forward(
@@ -1487,20 +1507,32 @@ class ModelRunner(Runner):
         return logits[0, 0]
 
     # -- the side cache (a model with a cache beside its KV pages) ----------
-    def ensure_side_cache(self, units: int) -> int:
+    def ensure_side_cache(self, units):
         """Hold a side pool of at least `units` units (unit 0 is scratch)
-        and say how many it has: 0 where the model keeps none. Growing
-        allocates a zeroed pool, so it is for construction, before any
-        sequence holds a unit."""
+        and say how many it has: 0 where the model keeps none. A model of
+        several kinds (`side_kind` "a+b") takes and gives a tuple, one
+        count a kind. Growing allocates a zeroed pool, so it is for
+        construction, before any sequence holds a unit."""
         if self._side_mod is None:
             return 0
-        if self.state is None or units > self.side_units:
+        want, have = self._by_kind(units), self._by_kind(self.side_units)
+        if self.state is None or any(want[k] > have[k] for k in want):
+            units = (tuple(int(u) for u in units) if "+" in self.side_kind
+                     else int(units))
             self.state = jax.jit(
-                partial(self._side_mod.make_pool, self.config, int(units),
+                partial(self._side_mod.make_pool, self.config, units,
                         self.page_size, self.dtype),
                 out_shardings=self.policy.replicated())()
-            self.side_units = int(units)
+            self.side_units = units
         return self.side_units
+
+    def _by_kind(self, value) -> Dict[str, int]:
+        """{kind: its entry} of a fact the door states once a kind
+        (`side_units`, `side_unit_bytes`: an int where there is one kind, a
+        tuple in `side_kind`'s order where there are several; 0: not yet)."""
+        kinds = self.side_kind.split("+") if self.side_kind else []
+        entries = value if isinstance(value, tuple) else (value,) * len(kinds)
+        return dict(zip(kinds, entries))
 
     def _state_pool(self):
         """The pool a step hands its program: whoever takes the runner
@@ -1565,6 +1597,25 @@ class ModelRunner(Runner):
                 p.picks = None
         self._routed_parts.append(
             _RoutedPart(routed[0], forwards, n_dec, chunk_lens))
+
+    # -- the rows only logits need ------------------------------------------
+    def _note_sampled(self, decode_rows: int, chunk_lens: Sequence[int] = (),
+                      chunk_rows: int = 0) -> None:
+        """A dispatch handed its program these real rows to run the
+        cross-decoder on: `decode_rows` (a row a step) and, of chunks of
+        these lengths, the `chunk_rows` at `last_index` (none of a chunk
+        told `sampled=False`); their other tokens went without."""
+        if self.skips_unsampled:
+            self._sampled_rows += decode_rows + chunk_rows
+            self._skipped_tokens += sum(chunk_lens) - chunk_rows
+
+    def fill_record(self, record) -> None:
+        """The dispatches since the last record, on this one (a decode
+        dispatch the engine ran ahead counts where it was enqueued)."""
+        if self.skips_unsampled:
+            record.yoco_cross_rows = self._sampled_rows
+            record.yoco_skipped_tokens = self._skipped_tokens
+            self._sampled_rows = self._skipped_tokens = 0
 
     def _readback(self, x):
         """jax.device_get of a dispatch's results and, in the same call,
@@ -1876,6 +1927,8 @@ class ModelRunner(Runner):
                 samp, self.lora, **mkw, **self._state_kw(side, B),
             )
             routed = self._keep_state(routed)
+            if self.skips_unsampled:  # (pad rows, position -1, are no rows)
+                self._note_sampled(n_steps * sum(p >= 0 for p in positions))
             if routed:
                 # the handle's own until it is collected: a readback of
                 # another dispatch must not wait for these counters
@@ -2282,8 +2335,11 @@ class ModelRunner(Runner):
             samp, row_seq, row_j, step_dev, seg_mask, seg_bias, **skw,
         )
         routed = self._keep_state(routed)
-        self._note_routed(routed, 1, n_dec,
-                          [len(c["tokens"]) for c in chunks])
+        chunk_lens = [len(c["tokens"]) for c in chunks]
+        self._note_routed(routed, 1, n_dec, chunk_lens)
+        # (the program gathers one row a segment, `gather`, whether or not
+        # the segment's chunk ends its prompt: one row of a fixed shape)
+        self._note_sampled(n_dec, chunk_lens, len(chunks))
         B = _next_bucket(self.decode_buckets, n_dec)
         # both slices are eager programs enqueued behind the ragged step,
         # before the host blocks: the device never waits for them
@@ -2317,6 +2373,7 @@ class ModelRunner(Runner):
             )
             routed = self._keep_state(routed)
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
+            self._note_sampled((n_steps - 1) * n_dec)
             with phase(READBACK):
                 tok0_h, rest_h = self._readback((tok0, rest))
                 rows = _chunk_rows(chunk_logits, len(chunks))

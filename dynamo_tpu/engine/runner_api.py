@@ -10,7 +10,8 @@ multi-host group replays). The engine reads these names plainly; it never
 asks a runner what it has by `getattr`/`hasattr`. What a sequence keeps
 beside its KV pages is four of them (`side_kind`, `side_units`,
 `side_unit_bytes`, `ensure_side_cache`) and one keyword of the steps
-(`side=`); engine/side_cache.py is the host side of it.
+(`side=`); engine/side_cache.py is the host side of it, one kind or
+several composed.
 
 This module imports nothing heavy: mocker processes stay jax-free.
 """
@@ -78,8 +79,9 @@ def refusal(kind: str, model_name: str, what: str) -> str:
     """Why a worker whose model keeps a cache of `kind` (a side cache's
     `Runner.side_kind`, or "indexer") does not do `what`: the one sentence
     every such refusal raises or streams, from the runner and the engine
-    alike."""
-    return _REFUSALS[kind].format(what=what, model=model_name)
+    alike; a model of several kinds ("a+b") hears each kind's."""
+    return " ".join(_REFUSALS[k].format(what=what, model=model_name)
+                    for k in kind.split("+"))
 
 
 def device_step(fn):
@@ -138,15 +140,25 @@ class Runner:
     #   `side=` (a row a sequence, a chunk's in its dict as "side"; None: the
     #   scratch unit), and whatever matches, moves, forks or rolls back KV
     #   by pages alone is refused (`refusal`)
+    #   A model that keeps several kinds names them joined by "+"
+    #   ("state+window", models/sambay.py): a row's operand is then the
+    #   tuple of what its sequence holds in each, `side_units` and
+    #   `side_unit_bytes` are tuples, one entry a kind, and a refusal says
+    #   each kind's sentence
     side_units = 0  # units of that pool (slots, pages), scratch unit 0
     #   among them (ensure_side_cache)
     side_unit_bytes = 0  # one unit, all the layers that keep it
+    skips_unsampled = False  # `prefill(..., sampled=False)` is understood:
+    #   a chunk that does not end its prompt is served without what only
+    #   its logits need (models/sambay.py: the cross-decoder and the head)
 
     # -- steps ---------------------------------------------------------------
     @device_step
     def prefill(self, tokens, start_pos, page_table_row, prior_len,
-                adapter=0, mm=None, side=None):
-        """One prefill chunk of one sequence; its last-token logits."""
+                adapter=0, mm=None, side=None, sampled=True):
+        """One prefill chunk of one sequence; its last-token logits
+        (`sampled` False, to a runner that `skips_unsampled`: nobody reads
+        them)."""
         raise NotImplementedError
 
     @device_step
@@ -255,9 +267,10 @@ class Runner:
         raise NotImplementedError
 
     @device_step
-    def ensure_side_cache(self, units: int) -> int:
+    def ensure_side_cache(self, units):
         """Hold a side pool of at least `units` units and say how many
-        there are (0 where side_kind is None). The engine's side cache
+        there are (0 where side_kind is None; a tuple in and out, one
+        count a kind, where side_kind names several). The engine's side cache
         calls it once, when the engine takes the runner, with what its
         scheduler's limits need."""
         return 0
@@ -337,6 +350,13 @@ class Runner:
         """Platform, devices and the dispatch paths in effect; empty where
         there is no device."""
         return {}
+
+    def fill_record(self, record) -> None:
+        """Write on an iteration's record (runtime/flight_recorder.
+        IterationRecord) what this runner alone knows of its dispatches
+        since the last one; nothing by default. A runner that
+        `skips_unsampled`: the rows it ran the cross-decoder on and the
+        chunk tokens it did not."""
 
     def charged_tokens(self) -> Optional[int]:
         """Cumulative prefill tokens a cost model billed; None where

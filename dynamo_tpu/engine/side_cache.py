@@ -6,15 +6,20 @@ what a step hands its program for a row, what an iteration records, the
 `/metrics` series, and the sentence with which it refuses what moves KV by
 pages alone. The scheduler calls `admit` / `cover` / `release` where it
 takes and gives back KV pages, the engine `operand` / `record`, the worker
-`gauges`; none of them knows which kind a model has. Two kinds exist:
-`StateSlots` (models/jamba.py and models/ling.py: one slot of recurrent
-state a sequence, Mamba's or KDA's) and
+`gauges`; none of them knows which kind a model has, nor how many. Two
+kinds exist: `StateSlots` (models/jamba.py and models/ling.py: one slot of
+recurrent state a sequence, Mamba's or KDA's) and
 `WindowPages` (models/mimo.py: a second page table into the window layers'
-pool). A pool that rides the KV page table (models/mla.py's index keys) is
-no side cache: pages carry it.
+pool). A model that keeps both (models/sambay.py: `side_kind`
+"state+window") gets `Composed` over one of each: a sequence holds the list
+of what it holds in each part, and takes all of it or nothing. A pool that
+rides the KV page table (models/mla.py's index keys) is no side cache:
+pages carry it.
 
 A family with a new kind writes its model module's `SIDE` record
-(models/toolkit.SideCacheOps) and one class here (docs/FAMILIES.md).
+(models/toolkit.SideCacheOps) and one class here (docs/FAMILIES.md); one
+that keeps kinds that exist names them in its `SIDE` and writes nothing
+here.
 
 No jax here: mocker processes import the scheduler.
 """
@@ -36,6 +41,21 @@ class SideCache:
 
     waits = 0  # admissions put off, and chunks a plan dropped, for want of
     #   a unit where the KV pool had room (the scheduler counts them)
+
+    @classmethod
+    def need(cls, runner: Runner, **limits) -> int:
+        """Units a scheduler of these limits needs of this kind."""
+        raise NotImplementedError
+
+    @classmethod
+    def build(cls, runner: Runner, units: int) -> "SideCache":
+        """The cache over `units` units the runner holds."""
+        raise NotImplementedError
+
+    @classmethod
+    def for_runner(cls, runner: Runner, **limits) -> "SideCache":
+        return cls.build(runner,
+                         runner.ensure_side_cache(cls.need(runner, **limits)))
 
     def check_limits(self, page_size: int, max_batch: int) -> None:
         """ValueError where this cache cannot serve a scheduler of these."""
@@ -88,12 +108,15 @@ class StateSlots(SideCache):
         self._free: List[int] = list(range(self.units - 1, 0, -1))
 
     @classmethod
-    def for_runner(cls, runner: Runner, *, max_batch: int, **_limits):
+    def need(cls, runner: Runner, *, max_batch: int, **_limits) -> int:
         # one slot for every sequence that can be active (a chunk a step
         # packs beside the batch is an active sequence's), and scratch
+        return max_batch + 1
+
+    @classmethod
+    def build(cls, runner: Runner, units: int):
         # (a cost model's runner may carry no ModelConfig)
-        return cls(runner.ensure_side_cache(max_batch + 1),
-                   kda=getattr(runner.config, "is_kda", False))
+        return cls(units, kda=getattr(runner.config, "is_kda", False))
 
     def check_limits(self, page_size: int, max_batch: int) -> None:
         if self.units - 1 < max_batch:
@@ -172,9 +195,9 @@ class WindowPages(SideCache):
         self._rec_freed = 0  # `freed` at the last record
 
     @classmethod
-    def for_runner(cls, runner: Runner, *, max_batch: int, chunk_size: int,
-                   decode_steps: int, mixed_prefill_tokens: int,
-                   mixed_prefill_seqs: int):
+    def need(cls, runner: Runner, *, max_batch: int, chunk_size: int,
+             decode_steps: int, mixed_prefill_tokens: int,
+             mixed_prefill_seqs: int) -> int:
         # sized from what the scheduler can have in flight at once: every
         # active sequence the pages its fused decode steps see and write,
         # and on top the chunks of one iteration (one standalone chunk of
@@ -186,10 +209,12 @@ class WindowPages(SideCache):
         a_row = window_pages_needed(w, ps, max(1, decode_steps))
         mixed = (mixed_prefill_seqs * window_pages_needed(w, ps, 1)
                  + -(-mixed_prefill_tokens // ps) + mixed_prefill_seqs)
-        pages = runner.ensure_side_cache(
-            1 + max_batch * a_row
-            + max(window_pages_needed(w, ps, chunk_size), mixed))
-        return cls(pages, ps, w)
+        return (1 + max_batch * a_row
+                + max(window_pages_needed(w, ps, chunk_size), mixed))
+
+    @classmethod
+    def build(cls, runner: Runner, units: int):
+        return cls(units, runner.page_size, runner.config.sliding_window)
 
     def check_limits(self, page_size: int, max_batch: int) -> None:
         if self.window <= 0 or self.pool.page_size != page_size:
@@ -285,14 +310,116 @@ class WindowPages(SideCache):
                self.waits)
 
 
+class _Part:
+    """A sequence as ONE part of a composed cache sees it: `side` is the
+    part's entry of the list the sequence holds; whatever else a part reads
+    of a sequence (`computed_len`) is the sequence's."""
+
+    __slots__ = ("seq", "i")
+
+    def __init__(self, seq, i: int):
+        self.seq, self.i = seq, i
+
+    @property
+    def side(self):
+        return None if self.seq.side is None else self.seq.side[self.i]
+
+    @side.setter
+    def side(self, held) -> None:
+        if self.seq.side is not None:
+            self.seq.side[self.i] = held
+
+    def __getattr__(self, name):
+        return getattr(self.seq, name)
+
+
+class Composed(SideCache):
+    """Several kinds at once (models/sambay.py: a state slot AND window
+    pages): a sequence holds the list of what it holds in each part, in the
+    parts' order, and a step's operand for its row is that tuple. `admit`
+    gives back what the earlier parts gave when a later one has none;
+    at most one part's `cover` takes anything, so a `cover` that raises has
+    taken nothing."""
+
+    def __init__(self, parts: List[SideCache]):
+        if sum(type(p).cover is not SideCache.cover for p in parts) > 1:
+            raise ValueError(
+                "a composed side cache can undo no `cover`: at most one of "
+                "its parts may take units chunk by chunk")
+        self.parts = list(parts)
+        self.kind = "+".join(p.kind for p in parts)
+        self.units = tuple(p.units for p in parts)
+        self.no_prefix = " ".join(p.no_prefix for p in parts)
+        self._waits = 0
+
+    @classmethod
+    def for_kinds(cls, kinds, runner: Runner, **limits) -> "Composed":
+        units = runner.ensure_side_cache(
+            tuple(k.need(runner, **limits) for k in kinds))
+        return cls([k.build(runner, u) for k, u in zip(kinds, units)])
+
+    @property
+    def waits(self) -> int:
+        return self._waits
+
+    @waits.setter
+    def waits(self, n: int) -> None:  # (every part's gauge says the whole's)
+        self._waits = n
+        for p in self.parts:
+            p.waits = n
+
+    def _each(self, seq):
+        return ((p, _Part(seq, i)) for i, p in enumerate(self.parts))
+
+    def check_limits(self, page_size: int, max_batch: int) -> None:
+        for p in self.parts:
+            p.check_limits(page_size, max_batch)
+
+    def admit(self, seq, first_tokens: int) -> None:
+        seq.side = [None] * len(self.parts)
+        taken = []
+        try:
+            for p, view in self._each(seq):
+                p.admit(view, first_tokens)
+                taken.append((p, view))
+        except Exception:  # (NoSpace; a free list that ran dry)
+            for p, view in taken:
+                p.release(view)
+            seq.side = None
+            raise
+
+    def cover(self, seq, first_query: int, last_query: int) -> None:
+        for p, view in self._each(seq):
+            p.cover(view, first_query, last_query)
+
+    def release(self, seq) -> None:
+        for p, view in self._each(seq):
+            p.release(view)
+        seq.side = None
+
+    def operand(self, seq) -> Any:
+        return None if seq.side is None else tuple(seq.side)
+
+    def record(self, record, rinfo: dict, active: List[Any]) -> None:
+        for i, p in enumerate(self.parts):
+            p.record(record, rinfo, [_Part(s, i) for s in active])
+
+    def gauges(self) -> Iterator[Tuple[str, str, float]]:
+        for p in self.parts:
+            yield from p.gauges()
+
+
 KINDS = {cls.kind: cls for cls in (StateSlots, WindowPages)}
 
 
 def for_runner(runner: Runner, **limits) -> Optional[SideCache]:
-    """The side cache of `runner`'s kind, its pool sized on the runner for a
+    """The side cache of `runner`'s kind (or kinds), its pool sized on the runner for a
     scheduler of these limits (max_batch, chunk_size, decode_steps,
     mixed_prefill_tokens, mixed_prefill_seqs); None where a sequence keeps
     nothing beside its pages."""
     if runner.side_kind is None:
         return None
-    return KINDS[runner.side_kind].for_runner(runner, **limits)
+    kinds = [KINDS[k] for k in runner.side_kind.split("+")]
+    if len(kinds) == 1:
+        return kinds[0].for_runner(runner, **limits)
+    return Composed.for_kinds(kinds, runner, **limits)

@@ -48,6 +48,14 @@ def load_hf_checkpoint(
     import ml_dtypes
     from safetensors import safe_open
 
+    if config.is_sambay:
+        raise NotImplementedError(
+            f"{config.name}: no checkpoint loader for a decoder-hybrid-decoder "
+            "(models/sambay.py) yet: the published config gives the family's "
+            "keys and no tensor names, so the tree's leaves cannot be mapped "
+            "until a checkpoint's index is in the repository; it serves drawn "
+            "weights (--model tiny-phi4flash, "
+            "benchmark/configs/phi-4-mini-flash-reasoning.json)")
     if config.is_kda:
         raise NotImplementedError(
             f"{config.name}: no checkpoint loader for a model with KDA layers "

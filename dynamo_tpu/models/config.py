@@ -222,6 +222,21 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_gate_lower: float = -5.0
+    # A decoder-hybrid-decoder (Phi-4-mini-flash-reasoning's `mb_per_layer`;
+    # models/sambay.py): the first half of the n_layers (a multiple of 4)
+    # alternates Mamba-1 mixers (even layers up to n/2) with differential
+    # attention under `sliding_window` (odd layers below n/2); layer n/2 + 1
+    # is the one layer of full differential attention, whose keys and values
+    # the odd layers from n/2 + 3 on read again with queries of their own
+    # (cross attention: no cache of theirs), and the even layers from n/2 + 2
+    # on are gated memory units on layer n/2's scan output (no cache, no
+    # recurrence): `layer_kinds`. 0 = no such model: every preset but the
+    # phi4flash ones; 2 is the only period the walk is written for. A
+    # sequence then carries a state slot AND window pages beside the pages
+    # of the one full layer; no position term enters the scores; the norms
+    # are LayerNorms with bias. Heads are paired (differential attention):
+    # the pools hold a pair of KV heads as one head of 2 x head_dim.
+    mb_per_layer: int = 0
 
     def __post_init__(self):
         if self.layer_pattern:
@@ -303,6 +318,28 @@ class ModelConfig:
             raise ValueError(
                 "kda_head_dim is read by the walk over KDA layers alone "
                 "(models/ling.py): state kda_layer_period")
+        if self.is_sambay:
+            if not (self.mb_per_layer == 2 and self.n_layers % 4 == 0
+                    and self.n_layers >= 8 and self.sliding_window > 0
+                    and self.mamba_d_state > 0 and self.mamba_dt_rank > 0
+                    and self.n_heads % 2 == 0 and self.n_kv_heads % 2 == 0
+                    and self.n_heads % self.n_kv_heads == 0):
+                raise ValueError(
+                    "a decoder-hybrid-decoder (models/sambay.py) needs "
+                    "mb_per_layer 2, n_layers a multiple of 4 and at least 8 "
+                    "(the fewest with every kind of layer), sliding_window > "
+                    "0, mamba_d_state > 0, mamba_dt_rank > 0, and paired "
+                    "heads: n_heads and n_kv_heads even, the one dividing "
+                    "the other")
+            if (self.is_mla or self.is_moe or self.layer_pattern
+                    or self.is_kda or self.attn_layer_period
+                    or not self.tie_embeddings or self.qk_norm
+                    or self.post_norms or not self.pre_norms
+                    or self.act != "silu"):
+                raise ValueError(
+                    "a decoder-hybrid-decoder is Phi-4-mini-flash's: dense "
+                    "gated MLPs, tied embedding, differential attention with "
+                    "no position term, no experts, latents or layer pattern")
         if self.is_hybrid:
             if not (0 <= self.attn_layer_offset < self.attn_layer_period
                     and self.mamba_dt_rank > 0):
@@ -324,7 +361,30 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         """State-space layers beside the attention layers (Jamba)."""
-        return self.mamba_d_state > 0
+        return self.mamba_d_state > 0 and not self.is_sambay
+
+    @property
+    def is_sambay(self) -> bool:
+        """A decoder-hybrid-decoder (Phi-4-mini-flash; models/sambay.py)."""
+        return self.mb_per_layer > 0
+
+    @property
+    def has_cross_decoder(self) -> bool:
+        """The layers behind the last cache write no cache and no state (a
+        decoder-hybrid-decoder's gated memory units and cross attention):
+        only a row whose logits are read needs them, and a runner serves a
+        prefill chunk nobody samples without them (Runner.skips_unsampled)."""
+        return self.is_sambay
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """A decoder-hybrid-decoder's layers, one word each: "mamba",
+        "window", "full" (the layer whose KV is shared), "gmu", "cross"."""
+        half = self.n_layers // 2
+        return tuple(
+            ("mamba" if l <= half else "gmu") if l % 2 == 0 else
+            "window" if l < half else "full" if l == half + 1 else "cross"
+            for l in range(self.n_layers))
 
     @property
     def is_kda(self) -> bool:
@@ -334,6 +394,8 @@ class ModelConfig:
     def is_attn_layer(self, l: int) -> bool:
         if self.is_kda:
             return (l + 1) % self.kda_layer_period == 0
+        if self.is_sambay:  # the one layer whose keys and values are kept
+            return l == self.n_layers // 2 + 1
         return (not self.is_hybrid
                 or l % self.attn_layer_period == self.attn_layer_offset)
 
@@ -349,7 +411,7 @@ class ModelConfig:
         window-pool model's global layers, else all."""
         if self.has_window_pool:
             return len(self.global_layers)
-        if self.is_hybrid or self.is_kda:
+        if self.is_hybrid or self.is_kda or self.is_sambay:
             return len(self.attn_layers)
         return self.n_layers
 
@@ -386,12 +448,28 @@ class ModelConfig:
         return d if d <= 128 else -(-d // 128) * 128
 
     @property
+    def pool_heads(self) -> int:
+        """Heads of a decoder-hybrid-decoder's pools (models/sambay.py): a
+        pair of the model's KV heads as one head of 2 x head_dim, and the
+        count rounded up to whole 8-row tiles where it spans more than one
+        (10 -> 16, zeros behind the pairs). The device pads a token's (10,
+        128) slab to 16 rows anyway, and a pool of 10-row slabs and
+        thousands of pages is laid out head-major by XLA and converted,
+        whole, at every step program's entry and exit (the v5e compiler,
+        PR 54: 18.96 GB of 15.75 for one decode step; `key_pool_dim` is the
+        same finding on the lane axis)."""
+        pairs = self.n_kv_heads // 2
+        return pairs if pairs <= 8 else -(-pairs // 8) * 8
+
+    @property
     def value_dim(self) -> int:
         """A value head's size on gqa (the keys' unless told)."""
         return self.v_head_dim or self.head_dim
 
     @property
     def mamba_layers(self) -> int:
+        if self.is_sambay:
+            return self.layer_kinds.count("mamba")
         return self.n_layers - self.kv_layers
 
     @property
@@ -783,6 +861,26 @@ PRESETS: Dict[str, ModelConfig] = {
         name="tiny-jamba", n_layers=8, n_heads=4, n_kv_heads=1,
         tie_embeddings=True, norm_eps=1e-6, mamba_d_state=4,
         mamba_dt_rank=8, attn_layer_period=4, attn_layer_offset=2,
+    ),
+    # Phi-4-mini-flash's structure at test size (models/sambay.py): the
+    # fewest layers with every kind (Mamba 0 2 4, window 1 3, the full layer
+    # 5, a gated memory unit 6, cross attention 7); 4 query heads on 2 KV
+    # heads of 16 (two query pairs on one KV pair of 32), window 16 (two
+    # pages at page size 8)
+    "tiny-phi4flash": ModelConfig(
+        name="tiny-phi4flash", n_layers=8, n_heads=4, n_kv_heads=2,
+        tie_embeddings=True, norm_eps=1e-5, sliding_window=16,
+        mamba_d_state=4, mamba_dt_rank=8, mb_per_layer=2,
+    ),
+    # Phi-4-mini-flash-reasoning as published (3.85 B): 32 layers, 40 query
+    # heads on 20 KV heads of 64, window 512, Mamba-1 at the family's
+    # defaults (benchmark/configs/phi-4-mini-flash-reasoning.json `assumed`)
+    "phi-4-mini-flash-reasoning": ModelConfig(
+        name="phi-4-mini-flash-reasoning", vocab_size=200064, dim=2560,
+        n_layers=32, n_heads=40, n_kv_heads=20, ffn_dim=10240,
+        max_seq_len=262144, norm_eps=1e-5, tie_embeddings=True,
+        sliding_window=512, mamba_d_state=16, mamba_d_conv=4,
+        mamba_dt_rank=160, mamba_expand=2, mb_per_layer=2,
     ),
     # Ling-3.0's structure at test size (CPU CI; models/ling.py): two periods
     # of three (KDA, KDA, MLA), the first layer dense, 4 heads of 16 in the

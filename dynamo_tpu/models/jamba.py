@@ -234,8 +234,12 @@ def _conv(c: ModelConfig, mp, a, plan: _Plan, conv_pool, m_idx):
     return out, conv_pool.at[m_idx, dst].set(jnp.stack(rows, axis=1), mode="drop")
 
 
-def _mamba_mixer(c: ModelConfig, mp, x, plan: _Plan, state, m_idx, impl: str):
-    """x [T, E] (normed) -> ([T, E], state)."""
+def _mamba_mixer(c: ModelConfig, mp, x, plan: _Plan, state, m_idx, impl: str,
+                 inner_norms: bool = True, scan_out: bool = False):
+    """x [T, E] (normed) -> ([T, E], state). Without `inner_norms` the step,
+    B and C go on as projected (Mamba-1 as published; the three norms are
+    Jamba's); with `scan_out` the scan's output `y + D * c` [T, d] f32,
+    before the mixer's own gate, follows the state (models/sambay.py)."""
     d, N, R = c.mamba_d_inner, c.mamba_d_state, c.mamba_dt_rank
     with jax.named_scope("ssm.proj"):
         az = mm(x, mp["w_in"])
@@ -245,9 +249,14 @@ def _mamba_mixer(c: ModelConfig, mp, x, plan: _Plan, state, m_idx, impl: str):
         cc = jax.nn.silu(pre)  # [T, d] f32
     with jax.named_scope("ssm.proj"):
         dbc = mm(cc.astype(x.dtype), mp["w_x"])
-        dtp = _rms32(dbc[:, :R], mp["dt_norm"], c.norm_eps).astype(x.dtype)
-        Bm = _rms32(dbc[:, R:R + N], mp["b_norm"], c.norm_eps)
-        Cm = _rms32(dbc[:, R + N:], mp["c_norm"], c.norm_eps)
+        if inner_norms:
+            dtp = _rms32(dbc[:, :R], mp["dt_norm"], c.norm_eps).astype(x.dtype)
+            Bm = _rms32(dbc[:, R:R + N], mp["b_norm"], c.norm_eps)
+            Cm = _rms32(dbc[:, R + N:], mp["c_norm"], c.norm_eps)
+        else:
+            dtp = dbc[:, :R]
+            Bm = dbc[:, R:R + N].astype(jnp.float32)
+            Cm = dbc[:, R + N:].astype(jnp.float32)
         # the step size in float32 from the product on: it sits in an
         # exponent that compounds over the sequence (a bf16 result, 3
         # digits of a value near -5, would be a 2 % error of the step)
@@ -265,9 +274,11 @@ def _mamba_mixer(c: ModelConfig, mp, x, plan: _Plan, state, m_idx, impl: str):
             y, S = op(state["S"], m_idx, plan.tok_slot, plan.flags,
                       cc, dt, Bm, Cm, A)
     with jax.named_scope("ssm.proj"):
-        y = (y + mp["D"] * cc) * jax.nn.silu(z.astype(jnp.float32))
+        m = y + mp["D"] * cc
+        y = m * jax.nn.silu(z.astype(jnp.float32))
         out = mm(y.astype(x.dtype), mp["w_out"])
-    return out, {"S": S, "conv": conv_pool}
+    state = {"S": S, "conv": conv_pool}
+    return (out, state, m) if scan_out else (out, state)
 
 
 # --------------------------------------------------------------------------

@@ -69,6 +69,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
     a SECOND stacked tree `layers_dense` for the leading dense-FFN layers
     — the forward runs two scans, one compiled body each."""
     c = config
+    if c.is_sambay:  # Phi-4-mini-flash: a decoder-hybrid-decoder
+        from dynamo_tpu.models import sambay
+
+        return sambay.init_params(c, key, dtype)
     if c.is_hybrid:  # Jamba: a tree and a forward of its own
         from dynamo_tpu.models import jamba
 
@@ -288,6 +292,11 @@ def forward(
     """
     c = config
     B, S = tokens.shape
+    if c.is_sambay:
+        raise NotImplementedError(
+            "a decoder-hybrid-decoder runs models/sambay.forward, which takes "
+            "and returns its state pool and its window pool; this path has "
+            "one pool")
     if c.is_hybrid:
         raise NotImplementedError(
             "a model with state-space layers runs models/jamba.forward, which "

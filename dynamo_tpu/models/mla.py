@@ -15,6 +15,7 @@ from dynamo_tpu.models.quant import mm
 from dynamo_tpu.models.toolkit import (
     _write_kv,
     attn_score_scale,
+    layer_norm,
     paged_attention_jnp,
     rms_norm,
     rope,
@@ -146,13 +147,6 @@ def _to_pool_width(x, k_pool):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
-def _layer_norm(x, w, b, eps):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    return ((xf - mu) * lax.rsqrt(var + eps) * w + b).astype(x.dtype)
-
-
 def _index_parts(c, lp, x, q_lat, safe_pos):
     """The lightning indexer's three projections of a step's tokens: index
     queries [B, S, Hi, Di] from the normed compressed query, a weight a head
@@ -170,7 +164,7 @@ def _index_parts(c, lp, x, q_lat, safe_pos):
              v[..., dr:]], axis=-1)
 
     qi = turned(mm(q_lat, lp["wi_q"]).reshape(B, S, hi, di))
-    ki = _layer_norm(mm(x, lp["wi_k"]), lp["ik_norm"], lp["ik_norm_b"],
+    ki = layer_norm(mm(x, lp["wi_k"]), lp["ik_norm"], lp["ik_norm_b"],
                      c.norm_eps)
     ki = turned(ki[:, :, None, :])[:, :, 0]
     wi = mm(x, lp["wi_w"]).astype(jnp.float32) * (hi ** -0.5 * di ** -0.5)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 DEFAULT_QUANT_NAMES = (
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     "we_gate", "we_up", "we_down", "ws_gate", "ws_up", "ws_down",
+    "w_fc1", "w_fc2",  # a fused gate-and-up MLP (models/sambay.py)
     "embed", "lm_head",
 )
 

@@ -30,9 +30,13 @@ class SideCacheOps(NamedTuple):
     return it last, donated; `rows` and `segs` build what they take as
     `slots=` and `seg_slots=`, in the format the model's forward reads, from
     the operands the engine's side cache hands a step (`Runner` steps'
-    `side=`; None: the scratch unit)."""
+    `side=`; None: the scratch unit). A model that keeps several kinds
+    (models/sambay.py: a state slot and window pages) names them joined by
+    "+", in the order of engine/side_cache.KINDS' parts: its `units` and
+    unit bytes are tuples, one entry a kind, its pool whatever its forward
+    reads, and a sequence's operand the tuple of what it holds in each."""
 
-    kind: str  # Runner.side_kind (engine/side_cache.KINDS)
+    kind: str  # Runner.side_kind (engine/side_cache.KINDS; "a+b": both)
     make_pool: Callable  # (config, units, page_size, dtype) -> the pool:
     #   zeros, unit 0 scratch
     unit_bytes: Callable  # (config, page_size, dtype) -> bytes of one unit
@@ -105,6 +109,15 @@ def make_kv_pool(
         return jnp.zeros(lat, dtype=dtype), jnp.zeros(stub, dtype=dtype)
     # a hybrid model's pool holds its attention layers alone (kv_layers)
     shape = (config.kv_layers, num_pages, page_size, config.n_kv_heads, config.head_dim)
+    if config.is_sambay:
+        # the one full layer's pool, a pair of KV heads as one head twice
+        # as wide, whole 8-row tiles of them (`ModelConfig.pool_heads`; that
+        # module also makes the window pool)
+        if kv_quantize is not None:
+            raise ValueError(
+                "a decoder-hybrid-decoder's caches are not quantized")
+        shape = shape[:3] + (config.pool_heads, 2 * config.head_dim)
+        return jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype)
     if config.has_window_pool:
         # the global layers' pool, values narrower than keys (the window
         # layers' is models/mimo.make_window_pool)
@@ -135,6 +148,15 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     normed = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     w = weight + 1.0 if zero_centered else weight
     return (normed * w).astype(x.dtype)
+
+
+def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm with bias, in float32 (models/mla.py's index keys,
+    models/sambay.py's every norm)."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return ((xf - mu) * lax.rsqrt(var + eps) * w + b).astype(x.dtype)
 
 
 def _yarn_mscale(scale: float, mscale: float) -> float:

@@ -250,6 +250,14 @@ class IterationRecord:
     window_pages_freed: int = 0
     decode_pages_live_window: int = 0
     decode_pages_live_global: int = 0
+    # a model whose cross-decoder runs on the sampled rows alone
+    # (models/sambay.py; it keeps a state slot and window pages too): the
+    # rows it ran on this iteration (every decode row of every step, the
+    # last row of each segment of a ragged step, a chunk served alone that
+    # ended its prompt) and the chunk tokens it did not run on, as the
+    # runner counted its dispatches (Runner.fill_record)
+    yoco_cross_rows: int = 0
+    yoco_skipped_tokens: int = 0
     # causal tracing: trace ids of the requests this iteration served
     # (bounded by the engine at append time) — joins the per-iteration
     # timeline to the distributed span rings and incident bundles

@@ -13,7 +13,7 @@ from dynamo_tpu.engine.runner_api import DEVICE_STEPS, Runner, refusal
 from dynamo_tpu.engine.scheduler import (
     DecodePlan, MixedPlan, PrefillPlan, Scheduler, SeqState, Sequence,
 )
-from dynamo_tpu.engine.side_cache import SideCache, StateSlots, WindowPages
+from dynamo_tpu.engine.side_cache import Composed, SideCache, StateSlots, WindowPages
 
 PS = 4
 
@@ -229,6 +229,106 @@ def test_state_slots_serve_two_families_and_record_each_ones_kernels(is_kda):
     # a runner with no ModelConfig at all (a cost model's) serves Mamba's
     R.config = None
     assert side_cache.for_runner(R(), max_batch=2).kda is False
+
+
+def _both(slots=3, pages=4):
+    return Composed([StateSlots(slots), WindowPages(pages, PS, 8)])
+
+
+@pytest.mark.parametrize("short", ["state", "window"])
+def test_a_composed_cache_admits_all_of_it_or_nothing(short):
+    """A sequence of a model with two kinds takes a unit of each or waits: a
+    NoSpace from the second part gives the first's back, and one from the
+    first never reaches the second."""
+    side = _both()
+    slots, window = side.parts
+    a, b = _seq("a", 8), _seq("b", 8)
+    if short == "state":
+        side.admit(a, 4), side.admit(_seq("x", 4), 4)  # both slots gone
+    else:
+        side.admit(a, 8)  # two of the three window pages gone
+    free = (len(slots._free), window.pool.n_free)
+    with pytest.raises((NoSpace, IndexError)):
+        side.admit(b, 8)
+    assert b.side is None and side.operand(b) is None
+    assert (len(slots._free), window.pool.n_free) == free
+    side.release(b)  # (a sequence that holds nothing gives nothing back)
+    assert (len(slots._free), window.pool.n_free) == free
+
+
+def test_a_composed_cache_is_each_part_under_the_names_it_has():
+    import types
+
+    side = _both(pages=6)
+    slots, window = side.parts
+    assert side.kind == "state+window" and side.units == (3, 6)
+    side.check_limits(PS, 2)
+    with pytest.raises(ValueError, match="3 state slots"):
+        side.check_limits(PS, 3)
+    with pytest.raises(ValueError, match="undo no `cover`"):
+        Composed([WindowPages(4, PS, 8), WindowPages(4, PS, 8)])
+    a = _seq("a", 12)
+    side.admit(a, 4)
+    slot, table = a.side
+    assert slot == 1 and [p for p in table if p] == [1] and side.operand(a) == (1, table)
+    side.cover(a, 4, 11)  # the chunk by chunk part alone takes more
+    assert a.side[0] == 1 and len([p for p in a.side[1] if p]) == 3
+    with pytest.raises(NoSpace):  # four more pages where two are free and
+        side.cover(a, 12, 27)     # one would come back: nothing moves
+    assert len([p for p in a.side[1] if p]) == 3 and window.pool.n_free == 2
+    a.computed_len = 12
+    rec = types.SimpleNamespace()
+    side.record(rec, {"decode_seqs": 2, "decode_steps": 3, "n_chunks": 2,
+                      "chunk_tokens": 20, "ragged": False}, [a])
+    assert (rec.state_slots_used, rec.state_slots_total) == (1, 2)
+    assert (rec.window_pages_used, rec.window_pages_total) == (3, 5)
+    assert rec.window_tokens_resident == rec.context_tokens_live == 12
+    assert (rec.ssm_scan_tokens, rec.ssm_scan_segments) == (20, 2)
+    # (what the forward ran on is the runner's to say, not a pool's)
+    assert not hasattr(rec, "yoco_cross_rows")
+    side.waits += 1
+    gauges = {name: value for name, _, value in side.gauges()}
+    assert gauges["state_slots_used"] == 1 and gauges["window_pages_used"] == 3
+    assert gauges["window_admission_waits_total"] == side.waits == 1
+    side.release(a)
+    assert a.side is None and slots.used == 0 and window.pool.n_free == 5
+    # the door's sentences are both kinds', and the scheduler's too
+    said = side.refusal("m", "X")
+    assert said == refusal("state+window", "m", "X") == " ".join(
+        refusal(k, "m", "X") for k in ("state", "window"))
+    assert StateSlots.no_prefix in side.no_prefix and WindowPages.no_prefix in side.no_prefix
+    with pytest.raises(ValueError, match="state-space layers matches no prefix"):
+        Scheduler(PagePool(8, PS), side=_both(), max_batch=2, enable_prefix_cache=True)
+
+
+def test_a_runner_of_two_kinds_gets_the_composed_cache_sized_a_kind():
+    import types
+
+    class R(Runner):
+        side_kind = "state+window"
+        page_size = PS
+        config = types.SimpleNamespace(sliding_window=8, is_kda=False)
+
+        def ensure_side_cache(self, units):
+            self.side_units = tuple(u + 1 for u in units)  # (it may hold more)
+            return self.side_units
+
+    r = R()
+    side = side_cache.for_runner(r, max_batch=4, chunk_size=8, decode_steps=2,
+                                 mixed_prefill_tokens=8, mixed_prefill_seqs=1)
+    assert type(side) is Composed
+    assert [type(p) for p in side.parts] == [StateSlots, WindowPages]
+    assert side.units == r.side_units and side.units[0] == 4 + 1 + 1
+    assert side.parts[1].window == 8 and side.parts[1].pool.page_size == PS
+    sched = Scheduler(PagePool(64, PS), side=side, max_batch=4, chunk_size=8,
+                      decode_steps=2, mixed_prefill_tokens=8,
+                      mixed_prefill_seqs=1, enable_prefix_cache=False)
+    a = _seq("a", 12)
+    sched.add(a)
+    _prefill(sched, a)
+    assert a.side[0] == 1 and any(a.side[1])
+    sched.abort("a")
+    assert a.side is None and side.parts[0].used == 0
 
 
 def test_a_runner_that_says_nothing_has_no_side_cache():
