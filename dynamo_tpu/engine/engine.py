@@ -46,6 +46,7 @@ from dynamo_tpu.engine.ngram_draft import (
 from dynamo_tpu.frontend.protocols import engine_output
 from dynamo_tpu.models.config import mean_over_layers
 from dynamo_tpu.runtime.annotations import (
+    DELIVER,
     EMIT,
     INBOX,
     PREP,
@@ -66,6 +67,12 @@ log = logging.getLogger("dynamo_tpu.engine")
 
 # per-request ITL sample cap: bounds the spine's memory on long generations
 _ITL_CAP = 512
+
+# inbox ops that only queue or drop something on the host: every other one
+# may block, so what is undelivered goes out before it (_drain_inbox)
+_INBOX_LIGHT = frozenset((
+    "add", "abort", "add_kv", "embed", "prefetch", "prefetch_disk",
+    "prefetch_obj", "obj_event"))
 
 # cached on a matcher whose schema exceeded the device DFA table budget,
 # so the build (and its warning) happens once per matcher, not per dispatch
@@ -447,6 +454,13 @@ class InferenceEngine:
         self._routed_out: Dict[str, tuple] = {}  # rid -> (item field,
         #   whether it holds one position per emitted token: a decode row)
         self._rec_late: Optional[tuple] = None  # (IterationRecord, MoeLoad)
+        # what commits have left for the clients and the observers, in
+        # commit order, until _deliver hands it over (under the next
+        # program where one is enqueued first): a stream's item as
+        # (seq, item, whether its tokens stamp the latency spine), an
+        # iteration's publish as (None, its ForwardPassMetrics, None or
+        # (its IterationRecord or None, its MoeLoad or None))
+        self._undelivered: List[tuple] = []
         # the step loop's decode dispatch in flight (_loop_once), the
         # moment the last iteration was committed (walls run from it), and
         # iterations by how they were enqueued: "ahead" of the read-back
@@ -575,6 +589,7 @@ class InferenceEngine:
                 log.debug("error emit to %s failed during fail-everything "
                           "(stream already gone)", seq.request_id,
                           exc_info=True)
+        self._deliver()  # (behind whatever their streams were still owed)
 
     # -- guided decoding ---------------------------------------------------
     def _compile_guided(self, spec: Dict[str, Any]):
@@ -708,6 +723,7 @@ class InferenceEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+            self._deliver()  # (the loop's own last one, where it died)
             self._flush_late_record()
         if self.prefetch is not None:
             self.prefetch.stop()
@@ -1077,6 +1093,7 @@ class InferenceEngine:
             self._commit_inflight()
         except Exception:
             log.exception("commit of the dispatch in flight failed")
+        self._deliver()
         unbind_clock()
         log.info("engine step loop stopped")
 
@@ -1091,7 +1108,11 @@ class InferenceEngine:
         as a pad row), and needs nothing of their tokens on the host;
         whenever it does not (`_why_not_ahead` names why) what is in
         flight is committed first and the iteration at hand runs in the
-        serial order: plan, enqueue, read back, emit, publish."""
+        serial order: plan, enqueue, read back, emit, publish. Either way
+        a commit only queues what the clients and the observers are owed
+        (`_undelivered`), and `_deliver` hands it over once the next
+        program is enqueued, under it: the device waits for the half of a
+        commit the next plan needs and for no more."""
         from dynamo_tpu.parallel.multihost import GroupBroken
 
         sched = self.scheduler
@@ -1125,6 +1146,9 @@ class InferenceEngine:
                 if why == "idle":  # the commit left something to plan
                     why = self._why_not_ahead(plan)
         if plan is None:
+            # nothing to enqueue: nothing stays pending across a wait that
+            # no program covers
+            self._deliver()
             self._flush_late_record()
             if not sched.has_work():
                 with phase(WAIT):
@@ -1298,10 +1322,13 @@ class InferenceEngine:
     def _publish_step(self, kind: str, n_tok: int, ts_wall: float, rinfo,
                       t0: Optional[float] = None,
                       rec_kind: Optional[str] = None) -> None:
-        """The end of an iteration's commit: FPM, KV events, the flight
-        record, and the mark the next iteration's wall runs from. Walls
-        run from that mark, commit to commit (IterationRecord.wall_s);
-        `t0`: where a two-dispatch iteration published its first half."""
+        """The end of an iteration's commit: its FPM and flight record
+        as the world stands at the commit, queued behind its items for
+        `_deliver` (the listeners, the KV events and the record's append
+        are the observers' and wait with them), and the mark the next
+        iteration's wall runs from. Walls run from that mark, commit to
+        commit (IterationRecord.wall_s); `t0`: where a two-dispatch
+        iteration published its first half."""
         t_start = self._t_mark
         if t0 is None:
             t0 = t_start
@@ -1310,16 +1337,20 @@ class InferenceEngine:
                 # arms the transfer guard + freezes the compiled-family
                 # baseline after warmup; a new variant past that is a leak
                 self.sanitizer.note_step(self.runner)
-            self._publish_fpm(kind, time.monotonic() - t0, n_tok)
-            self._publish_kv_events()
             # the commit mark: this wall and the host clock's interval end
             # on it and the next ones start on it, so the walls add up to
             # the loop's busy time and a record's phases to at most its wall
             now_ns = time.monotonic_ns()
-            self._record_iteration(
-                ts_wall, now_ns * 1e-9 - t_start, rec_kind or kind, rinfo,
-                now_ns)
+            self._publish_fpm(
+                kind, now_ns * 1e-9 - t0, n_tok, self._record_iteration(
+                    ts_wall, now_ns * 1e-9 - t_start, rec_kind or kind,
+                    rinfo, now_ns))
             self._t_mark = now_ns * 1e-9
+        if self._ahead_blocker(()) is not None:
+            # the next program is a whole step (a runner that cannot run
+            # ahead, speculation) or none (shutdown): no enqueue to deliver
+            # under, so at once, as ever
+            self._deliver()
 
     def _fail_step(self, seqs) -> None:
         """One bad step fails ITS sequences and never the step thread."""
@@ -1333,6 +1364,8 @@ class InferenceEngine:
             except Exception:
                 log.exception("failed to fail sequence %s", seq.request_id)
         self._recover_poisoned_pools()
+        # (the error items queued behind what their streams were still owed)
+        self._deliver()
 
     # -- the decode dispatch in flight ---------------------------------------
     def _ahead_blocker(self, seqs: List[Sequence]) -> Optional[str]:
@@ -1385,9 +1418,9 @@ class InferenceEngine:
 
     def _step_decode(self, plan: DecodePlan, why: Optional[str]) -> None:
         """A plain decode iteration. `why` None: it is enqueued on the
-        tokens of the dispatch in flight, which is read back, emitted and
-        published after, under it. Then it stays in flight itself unless
-        its own rows rule that out."""
+        tokens of the dispatch in flight, which is read back, committed,
+        published and delivered after, under it. Then it stays in flight
+        itself unless its own rows rule that out."""
         from dynamo_tpu.parallel.multihost import GroupBroken
 
         prev = self._inflight if why is None else None
@@ -1409,6 +1442,8 @@ class InferenceEngine:
             if nxt.handle is not None and self.step_clock is not None:
                 self.step_clock.handles -= 1
             raise
+        # (enqueued ahead: the commit ran under nxt, and its delivery does)
+        self._deliver()
         if self._ahead_blocker(plan.seqs) is None:
             self._inflight = nxt
         else:
@@ -1493,11 +1528,17 @@ class InferenceEngine:
             else:
                 # a runner of whole steps (a multi-host group replays
                 # decode_multi): the readback is part of the call
+                self._deliver()
                 fl.sampled = self.runner.decode_multi(
                     *args, adapters=adapters, **mkw)
             for s, ok in zip(rows, live):
                 if ok:
                     s.inflight += T
+        # what the commit before this enqueue left pending (a drain: the
+        # dispatch that was in flight, a mixed or prefill iteration) goes
+        # out under this program (a runner of whole steps left nothing:
+        # _publish_step)
+        self._deliver()
         return fl
 
     def _finish_decode(self, fl: "_InFlight") -> None:
@@ -1542,21 +1583,22 @@ class InferenceEngine:
         self._publish_step("decode", sum(fl.live), fl.ts, fl.rinfo)
 
     def _record_iteration(self, ts: float, wall: float, kind: str,
-                          rinfo: Dict[str, Any], now_ns: int) -> None:
-        """Assemble and append this iteration's flight record (step
-        thread; cheap field reads only — see DYN-R004). `now_ns`: the
-        commit mark `wall` ends on (time.monotonic_ns)."""
+                          rinfo: Dict[str, Any], now_ns: int) -> tuple:
+        """Assemble this iteration's flight record as the world stands at
+        its commit (step thread; cheap field reads only — see DYN-R004):
+        (the record, None where the recorder is off; its expert-load
+        counters, None for a dense model), for `_deliver` to settle and
+        append. `now_ns`: the commit mark `wall` ends on
+        (time.monotonic_ns)."""
         rec = self.recorder
         outcome = "ahead" if rinfo.get("ahead") else rinfo.get("drain", "")
         self.run_ahead_totals[outcome] = self.run_ahead_totals.get(
             outcome, 0) + 1
-        self._flush_late_record()
         # a routed model's expert-load counters came back with the sampled
         # tokens; taken every iteration so the runner forgets the dispatch
         load = self.runner.take_moe_load() if self._routed_ok else None
         if not rec.enabled:
-            self._settle_record(None, load)
-            return
+            return None, load
         st = self.scheduler.stats
         g2 = g3 = 0
         if self.host_pool is not None:
@@ -1624,7 +1666,7 @@ class InferenceEngine:
         clock = self.step_clock  # (there is one: rec.enabled)
         clock.cut(now_ns)
         rec.take_clock(record, clock)
-        self._settle_record(record, load)
+        return record, load
 
     def _layer_mean(self, kinds) -> int:
         """(on a global layer, on a sliding one) as the mean over the
@@ -1753,6 +1795,7 @@ class InferenceEngine:
                 except Exception:
                     log.debug("error emit to pending %s failed (stream "
                               "already gone)", seq.request_id, exc_info=True)
+        self._deliver()  # (before the device is waited for)
         self.runner.reset_kv_pools()
         self.pool.reset()
         if clear_tiers and self.host_pool is not None:
@@ -1765,6 +1808,9 @@ class InferenceEngine:
                 op, arg = self._inbox.get_nowait()
             except thread_queue.Empty:
                 break
+            if op not in _INBOX_LIGHT:
+                # the op may wait for the device, a disk or a peer
+                self._deliver()
             if op == "add":
                 self.scheduler.add(arg)
             elif op == "abort":
@@ -1842,6 +1888,8 @@ class InferenceEngine:
                 except Exception as e:
                     log.exception("weight reload failed")
                     loop.call_soon_threadsafe(_set_future_exc, fut, e)
+        if self._kv_pending or self._embed_pending:
+            self._deliver()  # (imports and the encoder wait for the device)
         self._admit_kv_pending()
         self._expire_parked()
         self._run_embeds()
@@ -2135,6 +2183,7 @@ class InferenceEngine:
             return
         with annotate("engine.prefill_packed", chunks=len(plans),
                       tokens=sum(len(p.chunk) for p in plans)):
+            self._deliver()  # (a whole step: it returns when it is done)
             logits_rows = self.runner.prefill_packed([
                 {
                     "tokens": p.chunk,
@@ -2149,11 +2198,14 @@ class InferenceEngine:
                 for plan, lg in zip(plans, logits_rows):
                     self.scheduler.complete_prefill(plan)
                     self._finish_prefill(plan, lg)
+            self._deliver_first_tokens()
 
     def _run_prefill_inner(self, plan: PrefillPlan) -> None:
         seq = plan.seq
         with phase(PREP):
             mm_chunk = self._mm_chunk(seq, plan.start_pos, len(plan.chunk))
+        if not self.runner.prefill_enqueues:
+            self._deliver()
         logits = self.runner.prefill(
             plan.chunk,
             plan.start_pos,
@@ -2177,10 +2229,14 @@ class InferenceEngine:
                 plan.chunk, plan.start_pos, seq.pages, prior_len=plan.start_pos,
                 mm=mm_chunk,
             )
+        # the chunk is enqueued and nobody has waited for it (its first
+        # token is sampled and read below, inside emit)
+        self._deliver()
         self._collect_routed([], 0, [plan])
         with phase(EMIT):
             self.scheduler.complete_prefill(plan)
             self._finish_prefill(plan, logits)
+        self._deliver_first_tokens()
 
     def _finish_prefill(self, plan: PrefillPlan, logits) -> None:
         """Post-chunk bookkeeping shared by the standalone and fused mixed
@@ -2344,6 +2400,7 @@ class InferenceEngine:
                         log.exception("failed to fail sequence %s",
                                       pplan.seq.request_id)
                     self._recover_poisoned_pools()
+        self._deliver_first_tokens()
 
     # -- speculative decoding (n-gram drafting + ragged verify) -------------
     def _warn_spec_once(self, rid: str, what: str) -> None:
@@ -2580,6 +2637,7 @@ class InferenceEngine:
                         i: brows[i] for i, s in enumerate(seqs) if s.logit_bias
                     }
                 n_branch_rows = sum(len(r) for r in branch_rows)
+            self._deliver()  # (a whole step; nothing is left where drafts run)
             try:
                 with self._san_scope("spec_verify"):
                     out = self.runner.verify_spec(
@@ -2783,7 +2841,7 @@ class InferenceEngine:
                 # their pages are already held). Chunks are shed strictly
                 # from the tail: what is served is a prefix of the plan's.
                 try:
-                    out = self.runner.decode_multi_with_prefills(
+                    out = self._mixed_step(
                         T, tokens, positions, tables, sp, step0,
                         [
                             {
@@ -2816,6 +2874,26 @@ class InferenceEngine:
             with phase(EMIT):
                 self._commit_decoded(seqs, out[0])
         return prefills, out
+
+    def _mixed_step(self, *args, **kw):
+        """The runner's fused mixed step with, between its enqueue and
+        its readback, the delivery of what the commit before it left
+        pending (at a drain: the dispatch that was in flight). A runner
+        of whole steps has no such seam and nothing pending."""
+        r = self.runner
+        if not r.can_run_ahead:
+            self._deliver()
+            return r.decode_multi_with_prefills(*args, **kw)
+        handle = r.mixed_dispatch(*args, **kw)
+        clock = self.step_clock
+        if clock is not None:
+            clock.handles += 1  # enqueued and not collected, as a decode's
+        try:
+            self._deliver()
+            return r.mixed_collect(handle)
+        finally:
+            if clock is not None:
+                clock.handles -= 1
 
     def _run_decode(self, plan: DecodePlan) -> None:
         """A decode batch run and read back on the spot: the decode half
@@ -2867,6 +2945,7 @@ class InferenceEngine:
                 else:
                     R = T // (gamma + 1)
                 self._step_counter += R
+            self._deliver()  # (a whole step)
             toks, counts = self.runner.spec_decode_multi(
                 R, tokens, positions, page_tables, _sampling_params(seqs),
                 step0, gamma=gamma, adapters=[s.adapter_idx for s in seqs],
@@ -2896,6 +2975,11 @@ class InferenceEngine:
         return self._step_counter
 
     # -- emission ----------------------------------------------------------
+    # Two halves (docs/concurrency.md, "Commit, enqueue, deliver"). The
+    # commit half, `_emit` / `_emit_item`, builds a stream's item and
+    # queues it; `_deliver` stamps the latency spine, closes a finished
+    # request's phases and hands every queued item to its event loop, one
+    # call a loop, with the iterations' publishes in their places between.
     def _emit(
         self,
         seq: Sequence,
@@ -2903,85 +2987,193 @@ class InferenceEngine:
         finish: Optional[str],
         logprobs: Optional[List[Dict[str, Any]]] = None,
     ) -> None:
+        """Queue a row's tokens for its stream; they stamp the latency
+        spine (`ttft_s`, `itl`) when they are delivered, which is when
+        the client can see them."""
         extra = {"logprobs": logprobs} if logprobs else {}
-        if token_ids:
-            # latency spine: first emitted token fixes TTFT; later emit
-            # groups contribute per-token ITL samples (bounded list — a
-            # long generation keeps its first _ITL_CAP samples)
-            now = time.monotonic()
-            if "ttft_s" not in seq.phases:
-                if seq.arrival:
-                    ph = seq.phases
-                    ph["ttft_s"] = max(0.0, now - seq.arrival)
-                    # what admission did not explain: first admission to
-                    # the first emitted token (the prompt's chunks, the
-                    # iterations between them, a re-prefill after a
-                    # preemption), so that ttft_s = queue_wait_s +
-                    # kv_onboard_s + prefill_s by construction
-                    ph["prefill_s"] = max(0.0, ph["ttft_s"] - ph.get(
-                        "queue_wait_s", 0.0) - ph.get("kv_onboard_s", 0.0))
-            elif seq.t_last_emit and len(seq.itl) < _ITL_CAP:
-                # a multi-token group (fused steps, accepted speculative
-                # drafts) contributes ONE ITL sample PER TOKEN — the step
-                # wall divided across the group — so itl percentiles, SLO
-                # burn rates, and goodput weight a 4-token step as 4 fast
-                # inter-token gaps, not one slow one
-                per = max(0.0, now - seq.t_last_emit) / len(token_ids)
-                n = min(len(token_ids), _ITL_CAP - len(seq.itl))
-                seq.itl.extend([per] * n)
-            seq.t_last_emit = now
-        self._emit_item(seq, engine_output(token_ids, finish, **extra))
+        self._emit_item(seq, engine_output(token_ids, finish, **extra), True)
 
-    def _emit_item(self, seq: Sequence, item: Dict[str, Any]) -> None:
-        routed = None
+    def _emit_item(self, seq: Sequence, item: Dict[str, Any],
+                   spine: bool = False) -> None:
+        """Queue `item` for `seq`'s stream, behind everything queued
+        before it. What the item holds of the dispatch is taken here, at
+        the commit (the `routed_experts` the runner handed out for it);
+        what it holds of the clock is `_deliver`'s."""
         if self._routed_out:  # empty unless a request of this dispatch asked
             routed, per_token = self._routed_out.pop(
                 seq.request_id, (None, False))
-        if routed is not None:
-            if per_token:
-                # a decode row: one position per emitted token (a step
-                # whose token was dropped at a stop, or that ran past the
-                # stop, has no reader)
-                routed["ids"] = routed["ids"][: len(item["token_ids"])]
-            if routed["ids"]:
-                item["routed_experts"] = routed
-        if item.get("finish_reason"):
-            # final item carries the request's phase spine downstream
-            # (loadgen/goodput aggregate it; the frontend adds span events)
-            phases = dict(seq.phases)
-            phases["preemptions"] = seq.n_preemptions
-            # inside prefill_s: what its chunks waited for the commit of a
-            # dispatch in flight (_loop_once); 0.0 where they waited for none
-            phases.setdefault("drain_wait_s", 0.0)
-            if seq.arrival:
-                phases["e2e_s"] = max(0.0, time.monotonic() - seq.arrival)
-            if seq.itl:
-                phases["itl_s"] = list(seq.itl)
-            pctx = tracing.parse_traceparent(seq.tp)
-            if pctx is not None:
-                # trace id rides the spine so digests / incident bundles
-                # can join aggregates back to individual traces
-                phases["trace_id"] = pctx.trace_id
-            try:
-                self._emit_worker_spans(seq, phases,
-                                        item.get("finish_reason"))
-            except Exception:  # pragma: no cover
-                log.exception("worker span synthesis failed")
-            item.setdefault("phases", phases)
-            for cb in self._phase_listeners:
+            if routed is not None:
+                if per_token:
+                    # a decode row: one position per emitted token (a step
+                    # whose token was dropped at a stop, or that ran past
+                    # the stop, has no reader)
+                    routed["ids"] = routed["ids"][: len(item["token_ids"])]
+                if routed["ids"]:
+                    item["routed_experts"] = routed
+        self._undelivered.append((seq, item, spine))
+
+    def _deliver(self) -> None:
+        """Hand over what the commits since the last call have queued, in
+        commit order: each stream's items to its event loop, ALL of them
+        in one `call_soon_threadsafe` a loop (a wake-up of the loop's
+        thread costs a write to its self-pipe and a contest for the
+        interpreter lock, a row an iteration where each item made its
+        own), and then each iteration's publish (its FPM to the listeners,
+        the KV events, its record to the ring), in commit order. The
+        step loop calls this right after it has enqueued a program and
+        before it blocks on one, so that the device works meanwhile;
+        before the idle sleep, a whole-step runner's call, a failure's
+        recovery and the loop's end, so that nothing waits across a wait
+        no program covers. An item never overtakes an earlier one of its
+        stream, and a finish item is its stream's last. Never raises."""
+        queued = self._undelivered
+        if queued:
+            self._undelivered = []
+            self._hand_over(queued)
+
+    def _hand_over(self, queued: List[tuple]) -> None:
+        """_deliver's work, on these entries of the queue."""
+        with phase(DELIVER):
+            clock = self.step_clock
+            # a program of this engine's is enqueued and not collected (the
+            # step clock's own test of what is hidden)
+            under = clock is not None and bool(clock.handles or clock.serial)
+            now = time.monotonic()
+            batches: Dict[Any, list] = {}  # event loop -> [(queue, item)]
+            publishes = []
+            for seq, item, spine in queued:
                 try:
-                    cb(phases)
-                except Exception:  # pragma: no cover
-                    log.exception("phase listener failed")
-        if seq.branch_of is not None or seq.n_branches > 1:
-            # branched choices multiplex the parent's stream; the index
-            # tells the consumer which choice each item belongs to
-            item.setdefault("index", seq.branch_index)
-        entry = self._streams.get(seq.branch_of or seq.request_id)
-        if entry is None:
-            return
-        out, loop = entry
-        loop.call_soon_threadsafe(out.put_nowait, item)
+                    if seq is None:  # a publish: (None, its FPM, settled)
+                        publishes.append((item, spine))
+                        continue
+                    if spine and item["token_ids"]:
+                        self._stamp_spine(seq, len(item["token_ids"]), now)
+                    if item.get("finish_reason"):
+                        self._close_phases(seq, item, now)
+                    if seq.branch_of is not None or seq.n_branches > 1:
+                        # branched choices multiplex the parent's stream;
+                        # the index tells the consumer which choice each
+                        # item belongs to
+                        item.setdefault("index", seq.branch_index)
+                    entry = self._streams.get(seq.branch_of or seq.request_id)
+                    if entry is not None:
+                        batches.setdefault(entry[1], []).append(
+                            (entry[0], item))
+                except Exception:
+                    log.exception("delivery of an item failed")
+            for loop, pairs in batches.items():
+                try:
+                    loop.call_soon_threadsafe(_put_all, pairs)
+                except RuntimeError:  # the loop is closed: nobody listens
+                    log.debug("hand-off to a closed event loop dropped "
+                              "%d item(s)", len(pairs), exc_info=True)
+            # the observers after the clients: a listener's milliseconds
+            # are not a token's
+            for m, settled in publishes:
+                try:
+                    self._deliver_publish(m, settled, under)
+                except Exception:
+                    log.exception("delivery of an iteration's publish failed")
+
+    def _deliver_first_tokens(self) -> None:
+        """Deliver, of what is queued, the prompts' first tokens alone,
+        where they were committed: a first token is the one item whose
+        wait a client feels whole (TTFT), there is one a joiner and not
+        one a row, and nothing of its stream is queued before it. The
+        rows' items and the publishes keep their places and wait for the
+        next enqueue."""
+        first, rest, held = [], [], set()
+        for entry in self._undelivered:
+            seq, item, spine = entry
+            if seq is not None:
+                stream = seq.branch_of or seq.request_id
+                if (spine and item["token_ids"] and stream not in held
+                        and "ttft_s" not in seq.phases):
+                    first.append(entry)
+                    continue
+                held.add(stream)  # (nothing overtakes what stays)
+            rest.append(entry)
+        if first:
+            self._undelivered = rest
+            self._hand_over(first)
+
+    def _deliver_publish(self, m: ForwardPassMetrics, settled,
+                         under: bool) -> None:
+        """An iteration's publish, delivered: the FPM to the listeners,
+        the KV events since the last one, and (where it
+        closed an iteration: `settled`, _record_iteration's pair) the
+        flight record to the ring, in commit order."""
+        self.fpm_history.append(m)
+        if len(self.fpm_history) > 2048:
+            del self.fpm_history[:1024]
+        for cb in self._fpm_listeners:
+            try:
+                cb(m)
+            except Exception:  # pragma: no cover
+                log.exception("fpm listener failed")
+        if settled is None:
+            return  # the first half of a two-dispatch iteration
+        self._publish_kv_events()
+        record, load = settled
+        self._flush_late_record()
+        if record is not None:
+            record.deliver_under = under
+        self._settle_record(record, load)
+
+    def _stamp_spine(self, seq: Sequence, n_tokens: int, now: float) -> None:
+        """Latency spine: the first delivered token fixes TTFT; later
+        groups contribute per-token ITL samples (bounded list — a long
+        generation keeps its first _ITL_CAP samples)."""
+        if "ttft_s" not in seq.phases:
+            if seq.arrival:
+                ph = seq.phases
+                ph["ttft_s"] = max(0.0, now - seq.arrival)
+                # what admission did not explain: first admission to
+                # the first delivered token (the prompt's chunks, the
+                # iterations between them, a re-prefill after a
+                # preemption), so that ttft_s = queue_wait_s +
+                # kv_onboard_s + prefill_s by construction
+                ph["prefill_s"] = max(0.0, ph["ttft_s"] - ph.get(
+                    "queue_wait_s", 0.0) - ph.get("kv_onboard_s", 0.0))
+        elif seq.t_last_emit and len(seq.itl) < _ITL_CAP:
+            # a multi-token group (fused steps, accepted speculative
+            # drafts) contributes ONE ITL sample PER TOKEN — the step
+            # wall divided across the group — so itl percentiles, SLO
+            # burn rates, and goodput weight a 4-token step as 4 fast
+            # inter-token gaps, not one slow one
+            per = max(0.0, now - seq.t_last_emit) / n_tokens
+            n = min(n_tokens, _ITL_CAP - len(seq.itl))
+            seq.itl.extend([per] * n)
+        seq.t_last_emit = now
+
+    def _close_phases(self, seq: Sequence, item: Dict[str, Any],
+                      now: float) -> None:
+        """The final item carries the request's phase spine downstream
+        (loadgen/goodput aggregate it; the frontend adds span events)."""
+        phases = dict(seq.phases)
+        phases["preemptions"] = seq.n_preemptions
+        # inside prefill_s: what its chunks waited for the commit of a
+        # dispatch in flight (_loop_once); 0.0 where they waited for none
+        phases.setdefault("drain_wait_s", 0.0)
+        if seq.arrival:
+            phases["e2e_s"] = max(0.0, now - seq.arrival)
+        if seq.itl:
+            phases["itl_s"] = list(seq.itl)
+        pctx = tracing.parse_traceparent(seq.tp)
+        if pctx is not None:
+            # trace id rides the spine so digests / incident bundles
+            # can join aggregates back to individual traces
+            phases["trace_id"] = pctx.trace_id
+        try:
+            self._emit_worker_spans(seq, phases, item.get("finish_reason"))
+        except Exception:  # pragma: no cover
+            log.exception("worker span synthesis failed")
+        item.setdefault("phases", phases)
+        for cb in self._phase_listeners:
+            try:
+                cb(phases)
+            except Exception:  # pragma: no cover
+                log.exception("phase listener failed")
 
     def _emit_worker_spans(self, seq: Sequence, phases: Dict[str, Any],
                            finish: str) -> None:
@@ -3079,9 +3271,13 @@ class InferenceEngine:
                 return
             yield payload
 
-    def _publish_fpm(self, kind: str, wall: float, n_tok: int) -> None:
+    def _publish_fpm(self, kind: str, wall: float, n_tok: int,
+                     settled: Optional[tuple] = None) -> None:
+        """Queue a dispatch's ForwardPassMetrics, as the scheduler stands
+        now, for `_deliver` (`_deliver_publish`); `settled`: the record
+        and expert load of the iteration it closes."""
         st = self.scheduler.stats
-        m = ForwardPassMetrics(
+        self._undelivered.append((None, ForwardPassMetrics(
             ts=time.time(),
             kind=kind,
             wall_time_s=wall,
@@ -3089,15 +3285,7 @@ class InferenceEngine:
             n_running=st.n_running,
             n_waiting=st.n_waiting,
             kv_usage=st.kv_usage,
-        )
-        self.fpm_history.append(m)
-        if len(self.fpm_history) > 2048:
-            del self.fpm_history[:1024]
-        for cb in self._fpm_listeners:
-            try:
-                cb(m)
-            except Exception:  # pragma: no cover
-                log.exception("fpm listener failed")
+        ), settled))
 
     def _publish_kv_events(self) -> None:
         events = self.pool.drain_events() + self._host_events
@@ -3303,6 +3491,13 @@ class InferenceEngine:
         alpha = 0.25
         e["s_per_block"] = alpha * per_block + (1 - alpha) * e["s_per_block"]
         e["n"] += n_blocks
+
+
+def _put_all(pairs) -> None:
+    """On the event loop's side of `_deliver`'s one hand-off: every item
+    into its stream's queue, in the order they were committed."""
+    for out, item in pairs:
+        out.put_nowait(item)
 
 
 def _set_future(fut: asyncio.Future, value) -> None:

@@ -749,6 +749,24 @@ class DecodeHandle:
         self.rows = rows
 
 
+class MixedHandle:
+    """A fused mixed dispatch that nobody has read back (ModelRunner.
+    mixed_dispatch): the decode rows' tokens on the device (`tok0` [B],
+    the ragged step's, None where the padded program ran; `rest`
+    [B, steps], the steps chained on them or the padded program's all,
+    None where one ragged step was everything), the chunks' last-token
+    logits, and what MixedOut says of the program."""
+
+    __slots__ = ("tok0", "rest", "chunk_logits", "n_chunks", "ragged",
+                 "pages_live")
+
+    def __init__(self, tok0, rest, chunk_logits, n_chunks: int,
+                 ragged: bool, pages_live: int = 0):
+        self.tok0, self.rest = tok0, rest
+        self.chunk_logits, self.n_chunks = chunk_logits, n_chunks
+        self.ragged, self.pages_live = ragged, pages_live
+
+
 # Wire layout version for P→D / cross-worker KV payloads. v2 = token-major
 # [L, n, PS, Hk, D]; v1 (implicit, no field) was head-major. Mirrors the
 # disk tier's BLOCK_LAYOUT_VERSION: in a mixed-version cluster (rolling
@@ -909,6 +927,7 @@ def _chunk_rows(chunk_logits: jax.Array, n: int) -> List[jax.Array]:
 
 class ModelRunner(Runner):
     supports_logit_bias = True  # engine gates biased requests on this
+    prefill_enqueues = True  # (its logits are read where they are sampled)
     static_shapes = True
     holds_kv = True
     has_verify_spec = True
@@ -2020,7 +2039,16 @@ class ModelRunner(Runner):
         return (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(pt),
                 jnp.asarray(kvl), jnp.asarray(last), padapter)
 
-    def decode_multi_with_prefills(
+    def decode_multi_with_prefills(self, *args, **kw) -> MixedOut:
+        """Fused mixed iteration, enqueued and read back in one call
+        (mixed_dispatch's signature): MixedOut(sampled [B_bucket, n_steps]
+        host, last-token logits [V] device per chunk, which program ran).
+        What a multi-host group replays and benchmark/serve.py's walk
+        calls; the engine's step loop runs the two halves with its
+        delivery between them."""
+        return self.mixed_collect(self.mixed_dispatch(*args, **kw))
+
+    def mixed_dispatch(
         self,
         n_steps: int,
         tokens: List[int],
@@ -2037,18 +2065,18 @@ class ModelRunner(Runner):
         guided_dev=None,  # device guided DFA plan for the fused tail
         side: Optional[list] = None,  # a model with a side cache: what each
         # decode row's sequence holds there; a chunk's rides its dict as "side"
-    ) -> MixedOut:
-        """Fused mixed iteration: the decode batch's n_steps AND the
-        token-budgeted prefill chunk set, one chunk or many, in one
-        dispatch (two on the ragged path, chained on the device). The one
-        place that picks the program: the ragged flat-token step where
-        the runner has it and the plan fits its segments; the padded
-        [N, S] program (_mixed_loop, each chunk a row) otherwise, and
-        when an unconstrained plan overflows the ragged T buckets.
-        Returns MixedOut(sampled [B_bucket, n_steps] host, last-token
-        logits [V] device per chunk, which program ran). The engine
-        takes the two-dispatch path for the feature planes this doesn't
-        carry (can_fuse; logprobs/penalties/multimodal chunks)."""
+    ) -> MixedHandle:
+        """Stage and enqueue a fused mixed iteration: the decode batch's
+        n_steps AND the token-budgeted prefill chunk set, one chunk or
+        many, in one dispatch (two on the ragged path, chained on the
+        device), and return without reading anything back (mixed_collect
+        does). The one place that picks the program: the ragged
+        flat-token step where the runner has it and the plan fits its
+        segments; the padded [N, S] program (_mixed_loop, each chunk a
+        row) otherwise, and when an unconstrained plan overflows the
+        ragged T buckets. The engine takes the two-dispatch path for the
+        feature planes this doesn't carry (can_fuse;
+        logprobs/penalties/multimodal chunks)."""
         if self.pp:
             raise NotImplementedError("fused mixed step has no PP path")
         constrained = (masks is not None or mask_fn is not None
@@ -2098,10 +2126,23 @@ class ModelRunner(Runner):
         )
         self._note_routed(routed, 1 + n_steps, n_dec,
                           [len(c["tokens"]) for c in chunks])
+        return MixedHandle(None, toks, chunk_logits, len(chunks), False)
+
+    def mixed_collect(self, handle: MixedHandle) -> MixedOut:
+        """Read a mixed_dispatch back: decode_multi_with_prefills'
+        MixedOut."""
+        h = handle
         with phase(READBACK):
-            sampled = np.asarray(self._readback(toks))
-            rows = _chunk_rows(chunk_logits, len(chunks))
-        return MixedOut(sampled, rows, False)
+            tok0, rest = self._readback((h.tok0, h.rest))
+            rows = _chunk_rows(h.chunk_logits, h.n_chunks)
+        if tok0 is None:
+            toks = np.asarray(rest)
+        elif rest is None:
+            toks = np.asarray(tok0)[:, None]
+        else:
+            toks = np.concatenate(
+                [np.asarray(tok0)[:, None], np.asarray(rest)], axis=1)
+        return MixedOut(toks, rows, h.ragged, h.pages_live)
 
     # -- guided sampling masks --------------------------------------------
     def _true_mask(self, rows: int) -> jax.Array:
@@ -2308,7 +2349,7 @@ class ModelRunner(Runner):
         # rides the ragged mask operand (`masks`), the fused tail rides
         # the in-XLA advance
         side: Optional[list] = None,
-    ) -> MixedOut:
+    ) -> MixedHandle:
         """Ragged mixed iteration, two dispatches with T-bucket-only and
         decode-bucket-only compile keys respectively:
         1. _ragged_step: flat forward over [T] (decode step 0 + all
@@ -2316,7 +2357,8 @@ class ModelRunner(Runner):
         2. steps 1..n-1 through the UNCHANGED _decode_loop, chained on
            the step-0 tokens (positions/step advanced by one, so row
            seeds and step indices match the legacy fused loop exactly).
-        Returns decode_multi_with_prefills' MixedOut."""
+        Returns mixed_dispatch's handle: both are enqueued, neither is
+        read back."""
         n_dec = len(positions)
         with phase(STAGE):
             (ftok, fpos, tok_pt, tok_kvl, seg_pt, seg_kvl, meta, gather,
@@ -2374,17 +2416,10 @@ class ModelRunner(Runner):
             routed = self._keep_state(routed)
             self._note_routed(routed, n_steps - 1, n_dec=n_dec, chained=True)
             self._note_sampled((n_steps - 1) * n_dec)
-            with phase(READBACK):
-                tok0_h, rest_h = self._readback((tok0, rest))
-                rows = _chunk_rows(chunk_logits, len(chunks))
-            toks = np.concatenate(
-                [np.asarray(tok0_h)[:, None], np.asarray(rest_h)], axis=1
-            )
         else:
-            with phase(READBACK):
-                toks = np.asarray(self._readback(tok0))[:, None]
-                rows = _chunk_rows(chunk_logits, len(chunks))
-        return MixedOut(toks, rows, True, pages_live)
+            rest = None
+        return MixedHandle(tok0, rest, chunk_logits, len(chunks), True,
+                           pages_live)
 
     def _meet_row_slices(self, sampled: jax.Array) -> None:
         """`sampled[:B]` is one eager program a (SEG_CAP, decode bucket)

@@ -131,7 +131,14 @@ class Runner:
     has_prefill_packed = False  # prefill_packed
     can_run_ahead = False  # decode_dispatch / decode_collect: a decode
     #   dispatch may stay in flight while the next is chained on its tokens
-    #   (the engine's step loop keeps one ahead where this says so)
+    #   (the engine's step loop keeps one ahead where this says so), and
+    #   mixed_dispatch / mixed_collect where the runner fuses mixed plans:
+    #   between an enqueue and its readback the engine delivers what the
+    #   commit before left for the clients (engine._deliver)
+    prefill_enqueues = False  # `prefill` returns once its program is
+    #   enqueued, its logits a device value nobody has waited for, so the
+    #   engine delivers under it; False: it returns when the chunk is done
+    #   (a cost model that sleeps), and the engine delivers before it
     side_kind: Optional[str] = None  # what a sequence keeps beside its KV
     #   pages (engine/side_cache.py): "state", a slot of recurrent state
     #   (models/jamba.py); "window", a second page table into the window
@@ -233,6 +240,21 @@ class Runner:
                                    adapters=None, masks=None, mask_fn=None,
                                    biases=None, guided_dev=None,
                                    side=None) -> MixedOut:
+        raise NotImplementedError
+
+    # The pair decode_multi_with_prefills is made of, for a runner that
+    # can_run_ahead and fuses mixed plans (not device steps of a
+    # multi-host group, as the decode pair is not).
+    def mixed_dispatch(self, n_steps, tokens, positions, page_tables,
+                       sampling, step, chunks, adapters=None, masks=None,
+                       mask_fn=None, biases=None, guided_dev=None, side=None):
+        """Stage and enqueue decode_multi_with_prefills' work and return a
+        handle without reading anything back."""
+        raise NotImplementedError
+
+    def mixed_collect(self, handle) -> MixedOut:
+        """What decode_multi_with_prefills returns, for the dispatch
+        behind `handle`."""
         raise NotImplementedError
 
     @device_step

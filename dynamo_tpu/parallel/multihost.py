@@ -244,7 +244,8 @@ _COLOCATED_ONLY = ("export_pages_device", "import_pages_device")
 # what this class answers itself and never reads through to the wrapped
 # runner: the colocated-only refusals and the run-ahead door (a handle
 # names one process's arrays; the group replays whole steps)
-_OWN = _COLOCATED_ONLY + ("can_run_ahead", "decode_dispatch", "decode_collect")
+_OWN = _COLOCATED_ONLY + ("can_run_ahead", "decode_dispatch", "decode_collect",
+                          "mixed_dispatch", "mixed_collect")
 
 
 class ReplicatingRunner(Runner):
@@ -297,9 +298,10 @@ class ReplicatingRunner(Runner):
 
     def decode_dispatch(self, *a, **kw):
         raise RuntimeError("a multihost group replays whole device steps: "
-                           "decode_multi, not its dispatch and collect halves")
+                           "decode_multi and decode_multi_with_prefills, not "
+                           "their dispatch and collect halves")
 
-    decode_collect = decode_dispatch
+    decode_collect = mixed_dispatch = mixed_collect = decode_dispatch
 
 
 def follower_loop(runner, sock: socket.socket) -> None:

@@ -6,8 +6,10 @@ On TPU the profiler is XLA's: `jax.profiler.start_server` exposes the
 worker to TensorBoard/xprof capture, and `TraceAnnotation` ranges put
 the engine iteration's host work (`engine.wait/inbox/schedule`, a step
 parent `engine.decode/mixed/prefill/...` tiled by `engine.prep/stage/
-dispatch/readback/emit`, then `engine.publish`) on the captured
-host+device timeline, so every idle gap on the device has an owner.
+dispatch/readback/emit`, then `engine.publish`, and `engine.deliver`
+wherever what a commit left for the clients and observers is handed
+over: under the next program) on the captured host+device timeline, so
+every idle gap on the device has an owner.
 Gated by `DYN_ENABLE_JAX_TRACE=1`; when off, `annotate` returns one
 shared no-op context manager (a cached check, no allocation per call).
 
@@ -93,8 +95,9 @@ def annotate(name: str, **kwargs):
 # -- the door: the step thread's phases -------------------------------------
 
 PHASES = ("inbox", "schedule", "prep", "stage", "dispatch", "readback",
-          "emit", "publish", "wait")
-INBOX, SCHEDULE, PREP, STAGE, DISPATCH, READBACK, EMIT, PUBLISH, WAIT = range(9)
+          "emit", "publish", "deliver", "wait")
+(INBOX, SCHEDULE, PREP, STAGE, DISPATCH, READBACK, EMIT, PUBLISH, DELIVER,
+ WAIT) = range(10)
 SPAN_NAMES = tuple("engine." + p for p in PHASES)
 # an iteration's own: `wait` is timed, and belongs to no iteration
 RECORD_PHASES = PHASES[:WAIT]

@@ -85,13 +85,24 @@ class IterationRecord:
     The step loop's host clock (runtime/annotations.py, the door): what
     the step thread did between the two commits `wall_s` runs between,
     whichever iteration the work was for (under run-ahead the staging in
-    an iteration's interval is the NEXT iteration's). `host_<phase>_s` for
-    the eight phases the profiler's spans name (`engine.inbox`, `schedule`,
-    `prep`, `stage`, `dispatch`, `readback`, `emit`, `publish`): seconds
+    an iteration's interval is the NEXT iteration's, the delivery after a
+    drain the iteration BEFORE's). `host_<phase>_s` for the nine phases
+    the profiler's spans name (`engine.inbox`, `schedule`, `prep`,
+    `stage`, `dispatch`, `readback`, `emit`, `publish`, `deliver`): seconds
     inside that span and no span inside it, so they add up to at most
     `wall_s` and the rest is the loop's own glue. `host_readback_s` is
     the time the step thread was blocked on the device: the device-bound
-    share of the loop. `exposed_s`: the part of the other seven that ran
+    share of the loop. `host_emit_s` is the commit half of an emit (stop
+    checks, the guided DFA, the rows' items), `host_deliver_s` the other
+    half: the latency spine, the hand-off of the items to the event
+    loops, the FPM and KV-event listeners and the record's append, which
+    the engine runs once the next program is enqueued (docs/concurrency.md,
+    "Commit, enqueue, deliver"). `deliver_under`: THIS iteration's items
+    and publish were delivered while a program of the engine's was
+    enqueued and not collected (the step clock's `handles`), so the device
+    had work meanwhile; False where nothing was left to enqueue (the
+    loop idled after it), a step failed, or the engine stopped.
+    `exposed_s`: the part of the other eight that ran
     while the step thread had NOTHING enqueued on the device and not
     collected, so the device was provably idle for want of the host;
     `exposed_stage_s`, `exposed_emit_s` its two largest owners. What is
@@ -201,6 +212,8 @@ class IterationRecord:
     host_readback_s: float = 0.0
     host_emit_s: float = 0.0
     host_publish_s: float = 0.0
+    host_deliver_s: float = 0.0
+    deliver_under: bool = False   # delivered under an enqueued program
     exposed_s: float = 0.0        # of those but readback, nothing enqueued
     exposed_stage_s: float = 0.0
     exposed_emit_s: float = 0.0

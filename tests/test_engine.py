@@ -354,14 +354,14 @@ async def test_fused_mixed_dispatch_matches_sequential(monkeypatch):
                                  mixed_prefill_tokens=8)
         engine.start()
         fused_calls = 0
-        orig = runner.decode_multi_with_prefills
+        orig = runner.mixed_dispatch  # (the engine runs the step's halves)
 
         def counting(*a, **k):
             nonlocal fused_calls
             fused_calls += 1
             return orig(*a, **k)
 
-        runner.decode_multi_with_prefills = counting
+        runner.mixed_dispatch = counting
         try:
             async def one(p):
                 toks = []

@@ -19,7 +19,7 @@ PARENTS = {"engine.decode", "engine.mixed", "engine.prefill",
 TABLE_A = PARENTS | {
     "engine.wait", "engine.inbox", "engine.schedule", "engine.prep",
     "engine.stage", "engine.dispatch", "engine.readback", "engine.emit",
-    "engine.publish"}
+    "engine.publish", "engine.deliver"}
 # trace_reduce.owner() looks back over this many spans started before a gap
 LOOKBACK = 8
 
@@ -146,23 +146,33 @@ STEP = ["engine.stage", "engine.dispatch"]
 # the loop runs AFTER the next iteration's enqueue where it ran ahead
 ENQUEUE = ["engine.prep"] + STEP
 COMMIT = ["engine.readback", "engine.emit"]
+# what the commit before left for the clients and the observers goes out
+# right after the program is enqueued and before anything blocks on it
+# (engine._deliver); no span where nothing was left
+DELIVER = ["engine.deliver"]
 WANT = {
     # parent -> the children it may show
     "engine.decode": [ENQUEUE, COMMIT],
     "engine.mixed": [
-        ["engine.prep"] + STEP + ["engine.readback", "engine.emit"],
-        ["engine.prep"] + STEP + STEP + ["engine.readback", "engine.emit"]],
-    "engine.prefill": [["engine.prep"] + STEP + ["engine.emit"]],
+        ["engine.prep"] + steps + d + ["engine.readback", "engine.emit"]
+        for steps in (STEP, STEP + STEP) for d in ([], DELIVER)],
+    # (a prompt's first token goes out where it is committed: after emit)
+    "engine.prefill": [["engine.prep"] + STEP + d + ["engine.emit"] + f
+                       for d in ([], DELIVER) for f in ([], DELIVER)],
 }
 LETTER = {"engine.inbox": "I", "engine.schedule": "S", "engine.publish": "P",
           "engine.mixed": "M", "engine.emit": "E", "engine.prefill": "F",
-          "engine.wait": "W"}
+          "engine.wait": "W", "engine.deliver": "L"}
 # the top-level spans of an iteration, D / C a decode's enqueue / commit:
 # what is in flight is committed first where the plan cannot run ahead of
 # it (CP, then a second schedule), then the plan at hand: a decode enqueued
 # (D: left in flight; DCP: ahead of the commit of the one before, or
-# committed at once), a mixed step, a prefill, or nothing
-TOP = re.compile(r"^IS(CPS)?(D(CP)?|MEP|FP|W?)$")
+# committed at once), a mixed step, a prefill, or nothing. L, a delivery at
+# the top level: after a decode's enqueue (what a drain or a mixed or
+# prefill iteration left), after a commit under a decode enqueued ahead,
+# after a mixed step's emit (its prompts' first tokens), before the loop
+# idles
+TOP = re.compile(r"^IS(CPS)?(DL?(CPL?)?|MEL?P|FP|L?W?)$")
 
 
 def _letters(it):
@@ -198,7 +208,9 @@ def test_decode_runs_ahead_under_the_recorder(recorded):
     decode enqueued was committed once."""
     rec, finals = recorded
     tops = [_letters(it) for it in rec.iterations()]
-    assert "ISD" in tops and "ISDCP" in tops, tops
+    # (the cold decode delivers the prompt's first token, the ones enqueued
+    # ahead what they committed, under themselves)
+    assert "ISDL" in tops and "ISDCPL" in tops, tops
     flat = "".join(tops)
     assert flat.count("D") == flat.count("C"), tops
     assert all(f["finish_reason"] == "length" for f in finals)
@@ -246,7 +258,7 @@ def test_gate_off_every_call_site_gets_a_shared_object(monkeypatch):
     names = {n for n, _ in got}
     assert {"engine.inbox", "engine.schedule", "engine.prefill", "engine.decode",
             "engine.prep", "engine.stage", "engine.dispatch", "engine.readback",
-            "engine.emit", "engine.publish"} <= names, names
+            "engine.emit", "engine.publish", "engine.deliver"} <= names, names
     assert names <= TABLE_A, names - TABLE_A
     made = {p.name: p for p in engine.step_clock._phases}
     for name, cm in got:

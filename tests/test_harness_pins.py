@@ -208,7 +208,10 @@ def test_the_doors_phases_are_the_spans_the_reduction_owns_gaps_by():
         "_bench_idle_pins", os.path.join(ROOT, "benchmark", "layers", "_idle.py"))
     idle = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(idle)
-    assert sorted(annotations.SPAN_NAMES) == sorted(idle.CHILDREN)
+    # (engine.deliver, PR 55, is no reader's owner: idle_gaps names it
+    # should it ever own a gap, and no idle share counts it)
+    assert sorted(set(annotations.SPAN_NAMES) - {"engine.deliver"}) == sorted(
+        idle.CHILDREN)
     assert annotations.SPAN_NAMES[annotations.WAIT] == "engine.wait"
     fields = {f.name for f in dataclasses.fields(IterationRecord)}
     assert {f"host_{p}_s" for p in annotations.RECORD_PHASES} == {

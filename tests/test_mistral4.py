@@ -476,10 +476,15 @@ async def test_metrics_show_the_held_slots(served):
         assert items[-1]["finish_reason"] == "length"
         for _ in range(100):
             if (engine._rec_late is None and engine._inflight is None
+                    and not engine._undelivered
                     and not engine.scheduler.has_work()):
                 break
             await asyncio.sleep(0.01)
-        engine._publish_fpm("decode", 0.0, 0)
+        engine._publish_fpm("decode", 0.0, 0)  # (the hook refreshes /metrics)
+        for _ in range(100):  # the loop's idle pass delivers it
+            if not engine._undelivered:
+                break
+            await asyncio.sleep(0.01)
         lines = rt.metrics.render().decode().splitlines()
         totals = dict(engine.moe_totals)
         await w.stop()

@@ -196,6 +196,7 @@ def test_iteration_record_says_which_mixed_program_ran(monkeypatch, ragged):
     engine._loop_once()  # a decodes alone
     add("b", _PROMPTS[1])
     engine._loop_once()  # a's decode + b's only chunk, one dispatch
+    engine._deliver()  # (its record waits for the next enqueue, or this)
     engine._flush_late_record()
     rec = engine.recorder.snapshot()[-1]
     assert (rec.kind, rec.fused, rec.n_chunks, rec.decode_seqs) == (

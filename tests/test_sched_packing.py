@@ -269,14 +269,14 @@ async def test_packed_fused_dispatch_byte_identity(monkeypatch):
                                  mixed_prefill_seqs=4, mixed_min_chunk=2)
         engine.start()
         packed_calls = 0
-        orig = runner.decode_multi_with_prefills
+        orig = runner.mixed_dispatch  # (the engine runs the step's halves)
 
         def counting(n_steps, *a, **k):
             nonlocal packed_calls
             packed_calls += 1
             return orig(n_steps, *a, **k)
 
-        runner.decode_multi_with_prefills = counting
+        runner.mixed_dispatch = counting
         try:
             async def one(p):
                 toks = []

@@ -279,6 +279,8 @@ def _iteration(ann):
         pass
     with ann.phase(ann.PUBLISH):
         pass
+    with ann.phase(ann.DELIVER):
+        pass
 
 
 def _blank_record():
@@ -300,7 +302,7 @@ def test_door_gate_off_allocates_nothing(door):
         for _ in range(50):  # the slots' first big ints, the kwargs' dict
             _iteration(ann)
         before = tracemalloc.get_traced_memory()[0]
-        for _ in range(1250):  # 10,000 phases
+        for _ in range(1250):  # 11,250 phases
             _iteration(ann)
         after = tracemalloc.get_traced_memory()[0]
     finally:
@@ -419,7 +421,7 @@ def test_door_gate_on_opens_the_names_it_accounts(door, monkeypatch):
     opened = [n for ev, n, _ in events if ev == "B"]
     assert opened == ["engine." + p for p in (
         "inbox", "schedule", "prep", "stage", "dispatch", "readback", "emit",
-        "publish", "emit", "dispatch", "readback")]
+        "publish", "deliver", "emit", "dispatch", "readback")]
     assert [n for ev, n, _ in events[-6:]] == [
         "engine.emit", "engine.dispatch", "engine.dispatch",
         "engine.readback", "engine.readback", "engine.emit"]
@@ -434,7 +436,7 @@ def test_door_gate_on_opens_the_names_it_accounts(door, monkeypatch):
     # owns, so emit keeps 10 + 3 x 10 around its two children's 10 each
     assert clock.ns[ann.DISPATCH] == 20 and clock.ns[ann.READBACK] == 20
     assert clock.ns[ann.EMIT] == 10 + 30 and clock.ns[ann.STAGE] == 10
-    assert sum(clock.ns) == 8 * 10 + 50
+    assert sum(clock.ns) == 9 * 10 + 50
 
 
 def test_door_exposed_is_decided_by_what_is_enqueued(door, monkeypatch):
