@@ -60,7 +60,12 @@ from dynamo_tpu.runtime.annotations import (
     unbind_clock,
 )
 from dynamo_tpu.runtime.context import Context
-from dynamo_tpu.runtime.flight_recorder import FlightRecorder, IterationRecord
+from dynamo_tpu.runtime.flight_recorder import (
+    ITERATION_CLASSES,
+    FlightRecorder,
+    IterationRecord,
+    iteration_class,
+)
 from dynamo_tpu.runtime import tracing
 
 log = logging.getLogger("dynamo_tpu.engine")
@@ -468,6 +473,14 @@ class InferenceEngine:
         self._inflight: Optional[_InFlight] = None
         self._t_mark = time.monotonic()
         self.run_ahead_totals: Dict[str, int] = {}
+        # what the loop has spent between its marks, by class of iteration
+        # (flight_recorder.iteration_class), ns: monotone totals that tile
+        # the loop's clock from `_acct_ns` back, of which a request's spine
+        # takes two copies (_charge); and the sequences whose first or last
+        # token the commit at hand has queued, until its mark
+        self.class_ns: Dict[str, int] = dict.fromkeys(ITERATION_CLASSES, 0)
+        self._acct_ns = time.monotonic_ns()
+        self._spine_due: List[tuple] = []  # (Sequence, its last?)
         # the step thread's host clock (runtime/annotations.py, the door):
         # bound to the step thread while the loop runs, emptied into each
         # iteration's record; on with the recorder and off with it
@@ -1058,7 +1071,8 @@ class InferenceEngine:
         self.runner.name_step_thread()
         if self.step_clock is not None:
             bind_clock(self.step_clock)
-        self._t_mark = time.monotonic()
+        self._acct_ns = time.monotonic_ns()
+        self._t_mark = self._acct_ns * 1e-9
         if self._routed_ok:
             # what the runner holds of dispatches that were not this
             # engine's (a warm-up walk) is none of its first iteration's load
@@ -1153,7 +1167,9 @@ class InferenceEngine:
             if not sched.has_work():
                 with phase(WAIT):
                     time.sleep(self.idle_sleep_s)
-            self._t_mark = time.monotonic()
+            now_ns = time.monotonic_ns()
+            self._charge("wait", now_ns)
+            self._t_mark = now_ns * 1e-9
             if self.step_clock is not None:
                 self.step_clock.clear()  # as the wall: no iteration's
             return
@@ -1221,7 +1237,7 @@ class InferenceEngine:
                     else:
                         _, sinfo = res
                         decode_done = True
-                        t1 = time.monotonic()
+                        t1 = self._half_mark()
                         self._publish_fpm(
                             "decode", t1 - t0, len(plan.decode.seqs)
                         )
@@ -1265,7 +1281,7 @@ class InferenceEngine:
                     # models keep clean samples.
                     self._run_decode(plan.decode)
                     decode_done = True
-                    t1 = time.monotonic()
+                    t1 = self._half_mark()
                     self._publish_fpm(
                         "decode", t1 - t0, len(plan.decode.seqs)
                     )
@@ -1346,11 +1362,51 @@ class InferenceEngine:
                     ts_wall, now_ns * 1e-9 - t_start, rec_kind or kind,
                     rinfo, now_ns))
             self._t_mark = now_ns * 1e-9
+            self._charge(iteration_class(rec_kind or kind,
+                                         bool(rinfo.get("ahead"))), now_ns)
         if self._ahead_blocker(()) is not None:
             # the next program is a whole step (a runner that cannot run
             # ahead, speculation) or none (shutdown): no enqueue to deliver
             # under, so at once, as ever
             self._deliver()
+
+    def _charge(self, cls: str, now_ns: int) -> None:
+        """Charge the loop's time since the last charge to class `cls`
+        (flight_recorder.ITERATION_CLASSES) and open or close the decode
+        interval of every sequence whose first or last token was committed
+        since (`_emit`): the first takes a copy of the totals, the last
+        puts the differences on its spine, `decode_s` and its six parts,
+        which add up to it because the totals tile the clock. Called where
+        the commit mark moves, with the mark (`time.monotonic_ns`)."""
+        tot = self.class_ns
+        tot[cls] += now_ns - self._acct_ns
+        self._acct_ns = now_ns
+        if not self._spine_due:
+            return
+        for seq, last in self._spine_due:
+            t0 = seq.decode_mark
+            if not last:
+                if t0 is None:
+                    seq.decode_mark = dict(tot)
+                continue
+            ph, whole = seq.phases, 0
+            for c, ns in tot.items():
+                ph[f"decode_{c}_s"] = (ns - t0[c]) * 1e-9
+                whole += ns - t0[c]
+            ph["decode_s"] = whole * 1e-9
+            ph["decode_tokens"] = seq.decode_tokens
+        self._spine_due.clear()
+
+    def _half_mark(self) -> float:
+        """Where a two-dispatch mixed iteration has committed its decode
+        half: that half of the wall is charged here, to the class its one
+        record's wall goes to, so that a row it finished closes its decode
+        interval before the chunks' enqueue delivers its last item. The
+        commit mark itself stays (the record's wall is the whole
+        iteration's). Returns the moment (time.monotonic's seconds)."""
+        now_ns = time.monotonic_ns()
+        self._charge("mixed", now_ns)
+        return now_ns * 1e-9
 
     def _fail_step(self, seqs) -> None:
         """One bad step fails ITS sequences and never the step thread."""
@@ -2991,6 +3047,16 @@ class InferenceEngine:
         spine (`ttft_s`, `itl`) when they are delivered, which is when
         the client can see them."""
         extra = {"logprobs": logprobs} if logprobs else {}
+        seq.decode_tokens += len(token_ids)
+        # the decode interval (_charge) opens at the mark of the commit
+        # that queued the stream's first tokens and closes at the mark of
+        # the one that queued its last; a stream that ends with its first
+        # group, or on an error, has none
+        if seq.decode_mark is None:
+            if token_ids and not finish:
+                self._spine_due.append((seq, False))
+        elif finish and finish != "error":
+            self._spine_due.append((seq, True))
         self._emit_item(seq, engine_output(token_ids, finish, **extra), True)
 
     def _emit_item(self, seq: Sequence, item: Dict[str, Any],
@@ -3223,7 +3289,11 @@ class InferenceEngine:
                             attributes=attrs)
         tracing.record_span(
             "worker.stream", cut[2], end_ns, parent=wtp,
-            attributes=dict(attrs, n_itl_samples=len(seq.itl)))
+            attributes=dict(
+                attrs, n_itl_samples=len(seq.itl),
+                # what the stream sat under, by class of iteration
+                **{k: v for k, v in phases.items()
+                   if k.startswith("decode_")}))
 
     # -- disagg export (called from the asyncio side) -----------------------
     async def export_host_blocks(self, hashes: List[int]) -> Dict[str, Any]:

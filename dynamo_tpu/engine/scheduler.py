@@ -94,6 +94,11 @@ class Sequence:
     onboard_tier: Optional[str] = None
     itl: List[float] = field(default_factory=list)  # bounded ITL samples
     t_last_emit: float = 0.0  # monotonic time of the last token emission
+    # the decode interval (engine._charge): tokens queued for the stream,
+    # and the engine's totals by class of iteration as they stood at the
+    # commit mark of its first token (None until then)
+    decode_tokens: int = 0
+    decode_mark: Optional[Dict[str, int]] = None
     # speculative decoding: draft tokens proposed for THIS iteration
     # (engine sets before step_plan; the scheduler trims them to the
     # mixed token budget; the engine consumes and clears after verify)

@@ -192,7 +192,6 @@ class WindowPages(SideCache):
         self.pool = PagePool(self.units, page_size)
         self.pool.alloc(1)  # page 0: scratch, never handed out
         self.freed = 0  # pages given back as they left the window
-        self._rec_freed = 0  # `freed` at the last record
 
     @classmethod
     def need(cls, runner: Runner, *, max_batch: int, chunk_size: int,
@@ -286,8 +285,6 @@ class WindowPages(SideCache):
         record.window_pages_used = self.pool.num_pages - 1 - self.pool.n_free
         record.window_tokens_resident = self.tokens_resident(active)
         record.context_tokens_live = sum(s.computed_len for s in active)
-        record.window_pages_freed = self.freed - self._rec_freed
-        self._rec_freed = self.freed
         kinds = rinfo.get("pages_live_kinds")
         if kinds:
             step0 = rinfo["pages_step0_kinds"] if rinfo["ragged"] else (0, 0)

@@ -252,15 +252,15 @@ class IterationRecord:
     # pages in use after the step and the pages there are (scratch left
     # out); the tokens of context those pages hold and the tokens of
     # context alive (the active sequences' computed lengths: what a uniform
-    # pool would hold for the window layers too); window pages given back
-    # this iteration as they left the window; and `decode_pages_live` by
-    # kind: the live pages one global layer's, and one window layer's,
-    # decode-kernel calls walk over the iteration's steps
+    # pool would hold for the window layers too); and `decode_pages_live`
+    # by kind: the live pages one global layer's, and one window layer's,
+    # decode-kernel calls walk over the iteration's steps (the pages given
+    # back as they leave the window are the side cache's own total, on
+    # /metrics as `dynamo_window_pages_freed_total`)
     window_pages_used: int = 0
     window_pages_total: int = 0
     window_tokens_resident: int = 0
     context_tokens_live: int = 0
-    window_pages_freed: int = 0
     decode_pages_live_window: int = 0
     decode_pages_live_global: int = 0
     # a model whose cross-decoder runs on the sampled rows alone
@@ -279,6 +279,30 @@ class IterationRecord:
 
 # the record's fields for the step clock's phases, in the clock's order
 _HOST_FIELDS = tuple(f"host_{p}_s" for p in RECORD_PHASES)
+
+# What the step loop was doing between two commit marks, by class of
+# iteration: the engine's running totals (`InferenceEngine.class_ns`) and a
+# request's `decode_<class>_s` on the latency spine are keyed by these
+# (docs/observability.md "Run-ahead"). `wait` is no iteration's: the time
+# between a mark and the loop's next one when it found nothing to enqueue.
+ITERATION_CLASSES = ("ahead", "cold", "mixed", "prefill", "other", "wait")
+
+
+def iteration_class(kind: str, ahead: bool) -> str:
+    """The class of an iteration, from its record's `kind` and `ahead`: a
+    plain decode is `ahead` where it was enqueued before the one before it
+    was read back and `cold` where that one was drained first (whatever
+    `drain` says why: a joiner, a bucket, speculation's verify); `mixed`
+    carried prompt chunks beside decode rows, as one dispatch or two;
+    `prefill` served prompt chunks alone; `other` is any kind the loop
+    grows that this rule has not been told of."""
+    if kind == "decode":
+        return "ahead" if ahead else "cold"
+    if kind == "mixed":
+        return "mixed"
+    if kind in ("prefill", "prefill_packed"):
+        return "prefill"
+    return "other"
 
 
 @dataclass

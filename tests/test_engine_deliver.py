@@ -35,9 +35,10 @@ class Recording(SimRunner):
 
     prefill_enqueues = True
 
-    def __init__(self):
-        super().__init__(num_pages=64, page_size=PAGE, max_pages_per_seq=16,
-                         vocab_size=300, timing=SimTiming(speed=0.0))
+    def __init__(self, **pool):
+        args = dict(num_pages=64, page_size=PAGE, max_pages_per_seq=16)
+        args.update(pool)
+        super().__init__(vocab_size=300, timing=SimTiming(speed=0.0), **args)
         self.events = []
         self.t_enqueue = []  # time.monotonic() of every enqueue
         self._n = 0

@@ -709,43 +709,61 @@ def test_recorder_off_turns_the_account_off():
     assert len(toks["a"]) == 9 and recs == []
 
 
-def test_a_stalled_iteration_logs_who_held_it(caplog):
+def test_a_stalled_iteration_logs_who_held_it():
     """The EWMA trigger fires once an excursion; the recorder's writer
     thread (never the step thread) logs one line with the record's phases,
     so an untraced run's log tells a blocked readback from a host phase, a
-    collection or a compile."""
+    collection or a compile. On billed time: the recorder is fed records
+    whose walls are chosen, and the writer thread is waited for on an event
+    its own log line sets."""
     import logging
+    import threading
 
-    runner = _runner()
-    eng = _engine(runner, True, anomaly_k=3.0, decode_steps=1)
-    real, calls = runner._readback, [0]
+    from dynamo_tpu.runtime.flight_recorder import FlightRecorder
+    from tests.test_flight_recorder import _rec
 
-    def stall_once(x):
-        # late enough that the first call's compile has left the EWMA
-        calls[0] += 1
-        if calls[0] == 50:
-            time.sleep(0.5)
-        return real(x)
+    class Lines(logging.Handler):
+        def __init__(self):
+            super().__init__(logging.WARNING)
+            self.records, self.logged = [], threading.Event()
 
-    runner._readback = stall_once
-    def lines():
-        return [r.getMessage() for r in caplog.records
-                if r.getMessage().startswith("stalled iteration")]
+        def emit(self, record):
+            if record.getMessage().startswith("stalled iteration"):
+                self.records.append(record)
+                self.logged.set()
 
-    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.flight_recorder"):
-        _, _, recs = _drive(eng, [{"rid": "a", "prompt": _prompt(6, 83), "n": 56}])
-        fired = [r for r in recs if r.anomaly]
-        deadline = time.monotonic() + 5.0
-        while len(lines()) < len(fired) and time.monotonic() < deadline:
-            time.sleep(0.01)
-    assert all(r.threadName == "flight-recorder-dump" for r in caplog.records
-               if r.getMessage().startswith("stalled iteration"))
-    lines = lines()
-    assert len(fired) == len(lines) >= 1, (len(fired), lines)
-    held = max(fired, key=lambda r: r.wall_s)
+    def rec(i, wall_s, readback, **over):
+        host = {f"host_{p}_s": 0.0004 for p in HOST + ("deliver",)}
+        host.update(host_readback_s=readback, exposed_s=0.0012, gc_s=0.0002)
+        return _rec(i, wall_s=wall_s, **host, **over)
+
+    fr = FlightRecorder(capacity=128, anomaly_k=3.0)
+    rlog, seen = logging.getLogger("dynamo_tpu.flight_recorder"), Lines()
+    level = rlog.level
+    rlog.addHandler(seen)
+    rlog.setLevel(logging.WARNING)
+    try:
+        # a steady decode loop, late enough that the trigger is armed ...
+        for i in range(49):
+            fr.append(rec(i, 0.010, 0.006, ahead=True))
+        # ... one read-back that blocked half a second, and on it goes
+        held = rec(49, 0.512, 0.5, drain="rows")
+        fr.append(held)
+        for i in range(50, 56):
+            fr.append(rec(i, 0.010, 0.006, ahead=True))
+        fired = [r for r in fr.snapshot() if r.anomaly]
+        assert fired == [held] and fr.anomalies_fired == 1
+        assert seen.logged.wait(120), "the writer thread logged nothing"
+    finally:
+        rlog.removeHandler(seen)
+        rlog.setLevel(level)
+    assert [r.threadName for r in seen.records] == ["flight-recorder-dump"]
+    assert threading.current_thread().name != "flight-recorder-dump"
+    line = seen.records[0].getMessage()
     assert held.host_readback_s >= 0.45
-    line = next(m for m in lines if f"seq={held.seq} " in m)
-    for key in ("kind=decode", "drain=", "wall_s=", "readback=", "exposed_s=",
-                "gc_s=", "variants_grew=0") + tuple(p + "=" for p in HOST):
+    assert f"seq={held.seq} " in line
+    for key in ("kind=decode", "drain=rows", "wall_s=0.5120", "readback=",
+                "exposed_s=0.0012", "gc_s=0.0002", "variants_grew=0") + tuple(
+                    p + "=" for p in HOST):
         assert key in line, (key, line)
     assert f"readback={held.host_readback_s:.4f}" in line

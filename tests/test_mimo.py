@@ -604,7 +604,7 @@ async def test_engine_sizes_the_window_pool_and_serves_through_every_program(mon
         recs = engine.recorder.snapshot()
         assert all(r.window_pages_total == wp.num_pages - 1 for r in recs)
         assert max(r.window_pages_used for r in recs) >= 4
-        assert sum(r.window_pages_freed for r in recs) == sched.side.freed > 0
+        assert sched.side.freed > 0  # (/metrics' window_pages_freed_total)
         assert all(r.window_tokens_resident <= r.context_tokens_live for r in recs)
         assert any(0 < r.window_tokens_resident < r.context_tokens_live for r in recs)
         assert any(0 < r.decode_pages_live_window < r.decode_pages_live_global for r in recs)
